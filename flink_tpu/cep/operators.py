@@ -51,11 +51,10 @@ class MeshCepOperator(Operator):
         self.engine: Optional[MeshCepEngine] = None
 
     def open(self, ctx) -> None:
-        import jax
-
-        effective = max(min(getattr(ctx, "parallelism", 1),
-                            len(jax.devices())), 1)
         from flink_tpu.parallel.mesh import make_mesh
+
+        # make_mesh refuses a request larger than the devices that exist
+        effective = max(getattr(ctx, "parallelism", 1), 1)
 
         kwargs = dict(
             key_field=self.key_field,

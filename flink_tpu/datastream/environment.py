@@ -171,14 +171,11 @@ class StreamExecutionEnvironment:
             restore_mode = os.environ.get("FLINK_TPU_RESTORE_MODE",
                                           restore_mode)
         graph = self.get_stream_graph()
-        # bounded backend probe + CPU fallback BEFORE the first
-        # device-touching op (but after cheap graph validation, so a
-        # user error like "no sinks" doesn't pay the probe timeout):
-        # a dead accelerator transport must degrade the job to CPU,
-        # not hang it (see platform.ensure_live_backend)
-        from flink_tpu.platform import ensure_live_backend
+        # the job runs on the devices JAX gives it and fails when JAX
+        # fails — no probe, no fallback
+        from flink_tpu.platform import enable_compilation_cache
 
-        ensure_live_backend()
+        enable_compilation_cache()
         config = self._effective_config()
         # subtask-expansion mode (execution.stage-parallelism > 0) expands
         # the pipeline into source + keyed subtasks wired by the shuffle

@@ -101,7 +101,7 @@ def build_join_exchange_put(mesh: Mesh, dtypes: Tuple[str, ...]):
 def _build_join_exchange_put(mesh: Mesh, n_cols: int,
                              rank_backend: str = "xla"):
     num_shards = int(mesh.devices.size)
-    sm_kwargs = {"check_rep": False} if rank_backend == "pallas" else {}
+    sm_kwargs = {"check_vma": False} if rank_backend == "pallas" else {}
 
     def _exchange(block):
         if num_shards == 1:

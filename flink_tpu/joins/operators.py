@@ -31,12 +31,10 @@ from flink_tpu.runtime.operators import Operator
 def _engine_kwargs(ctx, capacity: int, max_device_slots: int,
                    spill_dir: Optional[str],
                    spill_host_max_bytes: int = 0):
-    import jax
-
-    effective = max(min(getattr(ctx, "parallelism", 1),
-                        len(jax.devices())), 1)
     from flink_tpu.parallel.mesh import make_mesh
 
+    # make_mesh refuses a request larger than the devices that exist
+    effective = max(getattr(ctx, "parallelism", 1), 1)
     mesh = getattr(ctx, "mesh", None) or make_mesh(effective)
     return dict(
         mesh=mesh,

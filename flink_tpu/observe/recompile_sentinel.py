@@ -78,23 +78,20 @@ def install() -> None:
         return
     import jax
 
+    # the installed jaxlib's concrete array type; an installation this
+    # cannot hook raises here rather than counting 0 transfers for ever
+    from jax._src.array import ArrayImpl
+
+    orig_array = ArrayImpl.__array__
+
+    def _counting_array(self, *args, **kwargs):
+        _probe_counts["transfers"] += 1
+        for cb in _transfer_listeners:
+            cb()
+        return orig_array(self, *args, **kwargs)
+
+    ArrayImpl.__array__ = _counting_array
     jax.monitoring.register_event_duration_secs_listener(_on_duration_event)
-    try:
-        import jaxlib.xla_extension as _xe
-
-        orig_array = _xe.ArrayImpl.__array__
-
-        def _counting_array(self, *args, **kwargs):
-            _probe_counts["transfers"] += 1
-            for cb in _transfer_listeners:
-                cb()
-            return orig_array(self, *args, **kwargs)
-
-        _xe.ArrayImpl.__array__ = _counting_array
-    except (ImportError, AttributeError, TypeError):  # pragma: no cover
-        # transfer counting is best-effort; compile counting (the exact
-        # signal) installed above regardless
-        pass
     _installed = True
 
 

@@ -229,7 +229,7 @@ def _stage1_route(mesh2, H: int, L: int, fill_specs,
     over the intra-host axis. Returns per-column received buckets
     flattened ``[L * W1]`` in (source-local, rank) order."""
     num_shards = H * L
-    sm_kwargs = {"check_rep": False} if rank_backend == "pallas" else {}
+    sm_kwargs = {"check_vma": False} if rank_backend == "pallas" else {}
 
     def _xc_local(block):
         if L == 1:
@@ -337,7 +337,7 @@ def _build_fold_stage2(mesh, topology: HostTopology, agg, valued: bool,
     leaves = agg.leaves
     methods = tuple(SCATTER_METHOD[l.reduce] for l in leaves)
     n_leaves = len(leaves)
-    sm_kwargs = {"check_rep": False} if rank_backend == "pallas" else {}
+    sm_kwargs = {"check_vma": False} if rank_backend == "pallas" else {}
 
     def _xc_hosts(block):
         if H == 1:
@@ -424,7 +424,7 @@ def _build_join_stage2(mesh, topology: HostTopology, dtypes,
     num_shards = H * L
     mesh2 = pod_mesh_view(mesh, topology)
     n_cols = len(dtypes)
-    sm_kwargs = {"check_rep": False} if rank_backend == "pallas" else {}
+    sm_kwargs = {"check_vma": False} if rank_backend == "pallas" else {}
 
     def _xc_hosts(block):
         if H == 1:

@@ -34,10 +34,7 @@ KEY_AXIS = "keygroups"
 HOST_AXIS = "hosts"
 LOCAL_AXIS = "local"
 
-try:  # jax >= 0.5 exposes shard_map at the top level
-    shard_map = jax.shard_map
-except AttributeError:  # 0.4.x keeps it in jax.experimental
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+shard_map = jax.shard_map
 
 
 @dataclasses.dataclass(frozen=True)
@@ -159,10 +156,7 @@ def initialize_distributed(coordinator_address: str,
     and calls ``jax.distributed.initialize``. Must run before the first
     backend touch; real TPU pods skip the gloo step (ICI/DCN collectives
     are native) but the call is harmless there."""
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # older jaxlib without gloo: initialize may
-        pass           # still serve collective-free runs
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id)

@@ -343,7 +343,7 @@ def _build_exchange_scatter(mesh: Mesh, agg, valued: bool,
     # pallas_call has no shard_map replication rule — disable the check
     # for the pallas-ranked build only (the xla build stays byte-
     # identical in behavior to the pre-stateplane program)
-    sm_kwargs = {"check_rep": False} if rank_backend == "pallas" else {}
+    sm_kwargs = {"check_vma": False} if rank_backend == "pallas" else {}
 
     def _exchange(block):
         # [P, W] local block, dim0 = destination shard -> [P, W] with

@@ -258,13 +258,7 @@ class WindowAggOperator(Operator):
         self._max_dispatch_ahead = 4  # overridden from ctx in open()
 
     def open(self, ctx):
-        import jax
-
-        # reactive clamp: never build a mesh larger than the devices that
-        # exist (reference: AdaptiveScheduler scales the plan to available
-        # resources rather than failing the job)
-        effective = min(ctx.parallelism, len(jax.devices()))
-        if effective > 1:
+        if ctx.parallelism > 1:
             # parallelism > 1 selects the mesh-sharded engine: state lives
             # in [P, capacity] device arrays sharded over the key-group
             # mesh axis, records are routed by the reference's key-group
@@ -276,7 +270,9 @@ class WindowAggOperator(Operator):
             from flink_tpu.parallel.sharded_windower import MeshWindowEngine
 
             self._reject_backend_on_mesh()
-            mesh = getattr(ctx, "mesh", None) or make_mesh(effective)
+            # make_mesh refuses a request larger than the devices that
+            # exist, naming both counts — never a silently smaller mesh
+            mesh = getattr(ctx, "mesh", None) or make_mesh(ctx.parallelism)
             spill = dict(self.spill or {})
             self.windower = MeshWindowEngine(
                 self.assigner, self.agg, mesh,
@@ -746,12 +742,9 @@ class SessionWindowAggOperator(WindowAggOperator):
         self.gap = gap
 
     def open(self, ctx):
-        import jax
-
         from flink_tpu.windowing.sessions import SessionWindower
 
-        effective = min(ctx.parallelism, len(jax.devices()))
-        if effective > 1:
+        if ctx.parallelism > 1:
             # parallelism > 1 selects the mesh-sharded session engine —
             # session merges are shard-local (keys own their sessions), so
             # the metadata stays global and only state shards (reference:
@@ -760,7 +753,7 @@ class SessionWindowAggOperator(WindowAggOperator):
             from flink_tpu.parallel.sharded_sessions import MeshSessionEngine
 
             self._reject_backend_on_mesh()
-            mesh = getattr(ctx, "mesh", None) or make_mesh(effective)
+            mesh = getattr(ctx, "mesh", None) or make_mesh(ctx.parallelism)
             spill = dict(self.spill or {})
             self.windower = MeshSessionEngine(
                 self.gap, self.agg, mesh,

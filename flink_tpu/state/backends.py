@@ -14,7 +14,7 @@ committing the state to a device is the whole backend:
   beyond HBM (state.slot-table.max-device-slots).
 - ``host-heap``: accumulators committed to the host CPU device —
   NOTHING crosses the accelerator link. The HashMapStateBackend role:
-  right for small-state jobs where a tunneled accelerator's per-dispatch
+  right for small-state jobs where the accelerator's per-dispatch
   latency exceeds the compute (control-plane-heavy pipelines, tests).
 
 Third-party backends register a placement factory under a name

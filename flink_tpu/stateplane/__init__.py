@@ -16,7 +16,6 @@ from flink_tpu.stateplane.backends import (
     backend_of,
     backend_scope,
     configure_backends,
-    pallas_available,
     set_backend,
 )
 from flink_tpu.stateplane.families import (
@@ -59,7 +58,6 @@ __all__ = [
     "flat_segment_fire",
     "flat_segment_fire_projected",
     "flat_segment_merge",
-    "pallas_available",
     "pallas_rank",
     "pane_programs",
     "set_backend",

@@ -25,7 +25,7 @@ running XLA (a config typo must not vacuously pass an A/B experiment).
 from __future__ import annotations
 
 import contextlib
-from typing import Dict, Optional
+from typing import Dict
 
 from flink_tpu.observe.lock_sentinel import named_lock
 
@@ -38,30 +38,6 @@ _lock = named_lock("stateplane.backends")
 _overrides: Dict[str, str] = {}
 
 _CONFIG_PREFIX = "stateplane.backend."
-
-
-def pallas_available() -> bool:
-    """True when the Pallas counting-sort kernel actually runs on this
-    host (interpret mode counts — that is the CPU CI configuration).
-    Probed once, cached; a broken pallas install degrades to False so
-    callers can emit a LOUD skip instead of crashing."""
-    global _PALLAS_OK
-    if _PALLAS_OK is None:
-        try:
-            import numpy as np
-
-            from flink_tpu.stateplane.rank import pallas_rank, xla_rank
-
-            d = np.array([0, 1, 0, 2, 1, 0], dtype=np.int32)
-            got = np.asarray(pallas_rank(d, 3))
-            want = np.asarray(xla_rank(d, 3))
-            _PALLAS_OK = bool((got == want).all())
-        except Exception:
-            _PALLAS_OK = False
-    return _PALLAS_OK
-
-
-_PALLAS_OK: Optional[bool] = None
 
 
 def _validate(family: str, backend: str) -> str:

@@ -15,8 +15,10 @@ Two backends compute the same rank:
   O(n*D) matmul-shaped program standing in for a counting sort
   (ROADMAP item 3b's named worst offender).
 - ``pallas``: a ``pl.pallas_call`` counting-sort kernel — one O(n)
-  sequential pass over an SMEM count array. Interpret mode on CPU CI;
-  real-TPU numbers belong to the item-3b revalidation round.
+  sequential pass, ``d`` and the ranks blocked through SMEM over a
+  sequential grid with the per-destination counts carried in SMEM
+  scratch. Interpreted on the ``cpu`` backend only; any other backend
+  compiles it (Mosaic) or raises.
 
 Both are A/B gated bit-identical for ALL int32 inputs (including
 negative and out-of-range sentinel lanes): rank(i) = #{j < i :
@@ -32,15 +34,15 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from flink_tpu.tenancy.program_cache import PROGRAM_CACHE
 
-try:  # pallas ships with jax but may be absent/broken on some builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover - import-time environment gate
-    pl = None
-    pltpu = None
+#: records per grid step: the ``d`` block and the rank block each sit
+#: in SMEM double-buffered (4 x 32 KiB), which the v5e compiler accepts
+#: at every staged-column size (tests/test_chip_compile.py)
+RANK_BLOCK = 8192
 
 
 def xla_rank(d, num_dests: int):
@@ -53,13 +55,22 @@ def xla_rank(d, num_dests: int):
 
 
 def _rank_kernel(d_ref, out_ref, counts_ref, *, num_dests: int):
-    """Counting sort: one sequential pass, counts in SMEM.
+    """Counting sort: one sequential pass per block, counts in SMEM
+    scratch carried across the (sequential) grid.
 
     Bit-compatible with :func:`xla_rank` for every int32 input: lanes
     with ``d`` outside ``[0, num_dests)`` READ the count at the clipped
     bucket (what take_along_axis does) but never increment (their
-    one-hot row is all zero)."""
-    counts_ref[...] = jnp.zeros_like(counts_ref)
+    one-hot row is all zero). SMEM takes scalar stores only, hence the
+    scalar loop that zeroes the counts on the first block."""
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        def zero(b, carry):
+            counts_ref[b] = 0
+            return carry
+
+        jax.lax.fori_loop(0, num_dests, zero, 0)
 
     def body(i, carry):
         d = d_ref[i]
@@ -74,16 +85,28 @@ def _rank_kernel(d_ref, out_ref, counts_ref, *, num_dests: int):
 
 def pallas_rank(d, num_dests: int):
     """Rank within destination as a Pallas counting-sort kernel."""
-    if pl is None or pltpu is None:  # pragma: no cover
-        raise RuntimeError("pallas backend requested but "
-                           "jax.experimental.pallas is unavailable")
-    interpret = jax.default_backend() != "tpu"
-    return pl.pallas_call(
+    n = d.shape[0]
+    if n == 0:
+        return jnp.zeros((0,), jnp.int32)
+    block = min(RANK_BLOCK, n)
+    pad = -n % block
+    d = d.astype(jnp.int32)
+    if pad:  # out-of-range lanes never increment a count
+        d = jnp.concatenate([d, jnp.full((pad,), -1, jnp.int32)])
+    rank = pl.pallas_call(
         partial(_rank_kernel, num_dests=int(num_dests)),
         out_shape=jax.ShapeDtypeStruct(d.shape, jnp.int32),
+        grid=(d.shape[0] // block,),
+        in_specs=[pl.BlockSpec((block,), lambda i: (i,),
+                               memory_space=pltpu.SMEM)],
+        out_specs=pl.BlockSpec((block,), lambda i: (i,),
+                               memory_space=pltpu.SMEM),
         scratch_shapes=[pltpu.SMEM((int(num_dests),), jnp.int32)],
-        interpret=interpret,
-    )(d.astype(jnp.int32))
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=jax.default_backend() == "cpu",
+    )(d)
+    return rank[:n] if pad else rank
 
 
 _RANK_FNS = {"xla": xla_rank, "pallas": pallas_rank}

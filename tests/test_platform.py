@@ -1,89 +1,78 @@
-"""Platform selection: bounded backend probe + CPU fallback.
+"""Start-up: the compile cache is placed from outside, and nothing hides
+the device.
 
-The execution path must survive an environment whose configured
-accelerator backend has a dead transport (plugin hangs in native init) —
-``env.execute()`` degrades to CPU after a bounded probe instead of
-hanging forever. See tools/tpu_diagnose.py + tpu_results/ for the
-committed failure-layer evidence this guards against."""
+``env.execute()`` runs on the devices JAX gives it and fails when JAX
+fails: no probe child, no fallback to another platform, no silently
+smaller mesh, no counter that reads 0 because its hook did not take."""
 
 import os
+import subprocess
+import sys
 
+import jax
+import jax.numpy as jnp
 import pytest
 
 import flink_tpu.platform as platform
 
-
-@pytest.fixture(autouse=True)
-def _reset_memo():
-    platform._live_backend = None
-    yield
-    platform._live_backend = None
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def test_cpu_selection_skips_probe(monkeypatch):
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    assert platform.ensure_live_backend() == "cpu"
+@pytest.fixture
+def config_updates(monkeypatch):
+    """The keys ``enable_compilation_cache`` sets through jax.config,
+    from a fresh (not yet enabled) module state."""
+    seen = {}
+    monkeypatch.setattr(platform, "_cache_enabled", False)
+    monkeypatch.setattr(jax.config, "update",
+                        lambda key, value: seen.__setitem__(key, value))
+    return seen
 
 
-def test_probe_off_trusts_configuration(monkeypatch):
-    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
-    monkeypatch.setenv("FLINK_TPU_BACKEND_PROBE", "off")
-    assert platform.ensure_live_backend() == "unprobed"
+def test_cache_dir_is_not_set_in_code_when_the_environment_sets_it(
+        monkeypatch, config_updates, tmp_path):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    platform.enable_compilation_cache()
+    assert config_updates  # thresholds are still set
+    assert "jax_compilation_cache_dir" not in config_updates
 
 
-def test_dead_backend_falls_back_to_cpu(monkeypatch, tmp_path):
-    """A selection whose init can't succeed within the bound degrades
-    to CPU with a warning — and jax keeps working afterwards."""
-    monkeypatch.setenv("JAX_PLATFORMS", "tpu")  # no TPU in CI
-    monkeypatch.setenv("FLINK_TPU_BACKEND_PROBE_TIMEOUT", "8")
-    monkeypatch.setenv("FLINK_TPU_BACKEND_PROBE_CACHE_TTL", "0")
-    # keep the machine-wide marker file out of the real tempdir — a
-    # 'dead' verdict from this deliberately-short probe must not
-    # degrade a real job on the same box
-    monkeypatch.setattr(
-        platform, "_probe_cache_path",
-        lambda sel: str(tmp_path / f"probe_{sel}.json"))
-    with pytest.warns(RuntimeWarning, match="falling back to CPU"):
-        got = platform.ensure_live_backend()
-    assert got == "cpu"
-    import jax
-    import jax.numpy as jnp
-
-    out = jax.jit(lambda x: x * 2)(jnp.arange(3))
-    assert out.tolist() == [0, 2, 4]
-    # memoized: second call must not probe again (would re-warn)
-    assert platform.ensure_live_backend() == "cpu"
+def test_default_cache_dir_is_fixed_inside_the_checkout(
+        monkeypatch, config_updates):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    platform.enable_compilation_cache()
+    assert config_updates["jax_compilation_cache_dir"] == \
+        os.path.join(REPO, ".jax_cache")
 
 
-def test_probe_verdict_cached_across_processes(monkeypatch, tmp_path):
-    """A fresh process (reset memo) reuses the marker-file verdict
-    instead of re-paying the probe timeout."""
-    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
-    monkeypatch.setenv("FLINK_TPU_BACKEND_PROBE_CACHE_TTL", "300")
-    monkeypatch.setattr(
-        platform, "_probe_cache_path",
-        lambda sel: str(tmp_path / f"probe_{sel}.json"))
-    platform._write_probe_cache("tpu", "dead")
-    import time
-
-    t0 = time.monotonic()
-    got = platform.ensure_live_backend()
-    assert got == "cpu"
-    assert time.monotonic() - t0 < 2.0  # no subprocess probe ran
+def test_default_cache_dir_is_identical_across_two_processes():
+    """The path is part of JAX's cache key: never temporary, pid- or
+    time-derived."""
+    code = ("from flink_tpu.platform import *\n"
+            "enable_compilation_cache()\n"
+            "print(compilation_cache_dir())\n")
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    seen = [subprocess.run([sys.executable, "-c", code], cwd=REPO, env=env,
+                           capture_output=True, text=True, timeout=120,
+                           check=True).stdout.strip() for _ in range(2)]
+    assert seen == [os.path.join(REPO, ".jax_cache")] * 2
 
 
-def test_execute_calls_probe(monkeypatch):
-    """env.execute() consults the probe before touching the device."""
-    calls = []
-    monkeypatch.setattr(platform, "ensure_live_backend",
-                        lambda timeout=45.0: calls.append(1) or "cpu")
+def test_platform_module_is_the_cache_set_up_and_nothing_else():
+    public = {n for n in vars(platform) if not n.startswith("_")}
+    assert public == {"annotations", "os", "enable_compilation_cache",
+                      "compilation_cache_dir"}
+
+
+def _tiny_job(conf=None):
     from flink_tpu import Configuration, StreamExecutionEnvironment
     from flink_tpu.connectors.sinks import CollectSink
     from flink_tpu.connectors.sources import DataGenSource
     from flink_tpu.runtime.watermarks import WatermarkStrategy
     from flink_tpu.windowing.assigners import TumblingEventTimeWindows
 
-    env = StreamExecutionEnvironment(Configuration())
+    env = StreamExecutionEnvironment(Configuration(conf or {}))
     sink = CollectSink()
     env.add_source(DataGenSource(total_records=100, num_keys=3,
                                  events_per_second_of_eventtime=100),
@@ -91,4 +80,40 @@ def test_execute_calls_probe(monkeypatch):
         .key_by("key").window(TumblingEventTimeWindows.of(1000)) \
         .sum("value").sink_to(sink)
     env.execute()
-    assert calls, "execute() must invoke ensure_live_backend"
+    return sink
+
+
+def test_execute_starts_no_child_process(monkeypatch):
+    """A parent that has touched JAX holds the chip; a child that needs
+    it then fails — so ``env.execute()`` starts none."""
+    from flink_tpu import native
+
+    assert all(native.build_all().values())  # g++ runs here, not below
+    started = []
+    real_init = subprocess.Popen.__init__
+
+    def spy(self, args, *a, **kw):
+        started.append(args)
+        return real_init(self, args, *a, **kw)
+
+    monkeypatch.setattr(subprocess.Popen, "__init__", spy)
+    monkeypatch.setattr(os, "fork", lambda: started.append("fork") or 0)
+    assert len(_tiny_job().rows()) > 0
+    assert started == []
+
+
+def test_parallelism_beyond_the_devices_raises_with_both_counts():
+    have = len(jax.devices())
+    with pytest.raises(ValueError) as e:
+        _tiny_job({"parallelism.default": have * 2})
+    assert f"{have * 2}-device mesh" in str(e.value)
+    assert f"only {have} device(s)" in str(e.value)
+
+
+def test_sentinel_install_counts_a_device_get():
+    from flink_tpu.observe import recompile_sentinel
+
+    recompile_sentinel.install()
+    before = recompile_sentinel.transfer_count()
+    jax.device_get(jnp.arange(8))
+    assert recompile_sentinel.transfer_count() == before + 1

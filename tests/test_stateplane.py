@@ -35,16 +35,10 @@ from flink_tpu.stateplane import (
     flat_put,
     flat_scatter_combine,
     flat_segment_fire,
-    pallas_available,
     set_backend,
     xla_rank,
 )
 from flink_tpu.windowing.aggregates import AvgAggregate, SumAggregate
-
-needs_pallas = pytest.mark.skipif(
-    not pallas_available(),
-    reason="pallas kernel unavailable on this host")
-
 
 # ------------------------------------------------------------- families
 
@@ -195,7 +189,6 @@ class TestBackendHook:
 # ---------------------------------------------------- rank kernel parity
 
 
-@needs_pallas
 class TestPallasRankParity:
     def test_random_shapes_bit_identical(self):
         """Property test: over random (num_dests, length, width) the
@@ -426,7 +419,6 @@ class TestGoldenBitIdentity:
         v_dev, v_host = vals_of(dev), vals_of(host)
         assert len(v_dev) > 0 and v_dev == v_host
 
-    @needs_pallas
     def test_session_fires_identical_under_pallas_rank(
             self, eight_device_mesh):
         """The engine-level half of the Pallas A/B gate: a device-mode

@@ -71,7 +71,7 @@ Regression gates:
 
 tools/tier1.sh pins all three.
 
-    BENCH_SKIP_PROBE=1 JAX_PLATFORMS=cpu python tools/bench_mesh_sessions.py
+    JAX_PLATFORMS=cpu python tools/bench_mesh_sessions.py
 
 Zipf mode (``--zipf`` or ``BENCH_MESH_ZIPF=1``): the same shape with the
 key column drawn Zipf(``BENCH_MESH_ZIPF_S``, default 1.1) over the 10M
@@ -393,9 +393,9 @@ def main():
     import warnings
 
     warnings.filterwarnings("ignore")
-    from flink_tpu.platform import sync_platform
+    from flink_tpu.platform import enable_compilation_cache
 
-    sync_platform()
+    enable_compilation_cache()
     import jax
 
     from flink_tpu.parallel.mesh import make_mesh

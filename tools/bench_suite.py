@@ -7,10 +7,14 @@ Rows (BASELINE.json):
   4. Flink SQL GROUP BY HOP over Kafka
   5. Session-window clickstream, 10M distinct keys (spill tier)
 
-Prints one JSON line per row and rewrites BENCHMARKS.md. Usage:
+Prints one JSON line per row and rewrites BENCHMARKS.md. The parent
+never touches JAX: every row runs in a child process, one after another
+(a chip belongs to one process at a time), and a failed row fails the
+suite. Usage:
 
-    BENCH_SKIP_PROBE=1 JAX_PLATFORMS=cpu python tools/bench_suite.py
-    python tools/bench_suite.py          # probes the TPU first
+    python tools/bench_suite.py                    # the default backend
+    JAX_PLATFORMS=cpu python tools/bench_suite.py  # explicit CPU run
+    python tools/bench_suite.py --row nexmark_q5   # one row, in-process
 """
 import json
 import os
@@ -19,15 +23,34 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("BENCH_PROBE_TIMEOUTS", "45,120")
-
 SCALE = float(os.environ.get("BENCH_SUITE_SCALE", "1.0"))
 
+_TOOLS = os.path.dirname(os.path.abspath(__file__))
 
-def _platform():
-    import jax
 
-    return jax.devices()[0].platform
+def _child_json(argv, env_defaults=None, env=None, min_lines=1):
+    """Run one child to its end; its stdout's JSON-object lines.
+    ``env_defaults`` yield to the caller's environment, ``env``
+    overrides it. A failed child raises."""
+    import subprocess
+
+    child_env = dict(os.environ)
+    for k, v in (env_defaults or {}).items():
+        child_env.setdefault(k, v)
+    child_env.update(env or {})
+    proc = subprocess.run([sys.executable] + argv, capture_output=True,
+                          text=True, env=child_env, timeout=3600,
+                          cwd=os.path.dirname(_TOOLS))
+    lines = [ln for ln in proc.stdout.splitlines() if ln.startswith("{")]
+    if proc.returncode != 0 or len(lines) < min_lines:
+        raise RuntimeError(
+            f"{argv[0]} rc={proc.returncode}: "
+            + (proc.stderr or proc.stdout).strip()[-300:])
+    return [json.loads(ln) for ln in lines]
+
+
+def _tool(name):
+    return os.path.join(_TOOLS, name)
 
 
 def row1_wordcount():
@@ -248,21 +271,9 @@ def row5b_mesh_sessions():
     """Row 5 on the MESH session engine (paged spill per shard) — runs
     in a subprocess so the CPU virtual-device flag the mesh needs cannot
     perturb the single-device rows' XLA threading."""
-    import subprocess
-
-    env = dict(os.environ)
-    env.setdefault("BENCH_MESH_SESSION_RECORDS",
-                   str(int(4_000_000 * SCALE)))
-    proc = subprocess.run(
-        [sys.executable,
-         os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                      "bench_mesh_sessions.py")],
-        capture_output=True, text=True, env=env, timeout=3600)
-    lines = [ln for ln in proc.stdout.splitlines()
-             if ln.startswith("{")]
-    if proc.returncode != 0 or not lines:
-        raise RuntimeError((proc.stderr or proc.stdout).strip()[-300:])
-    return json.loads(lines[-1])
+    return _child_json(
+        [_tool("bench_mesh_sessions.py")],
+        {"BENCH_MESH_SESSION_RECORDS": str(int(4_000_000 * SCALE))})[-1]
 
 
 def row5c_mesh_sessions_zipf():
@@ -270,22 +281,10 @@ def row5c_mesh_sessions_zipf():
     live (load accounting -> key-group moves -> hot-key splitting);
     reports the recovered fraction of the uniform control's
     throughput. Subprocess for the virtual-device flag, like row5b."""
-    import subprocess
-
-    env = dict(os.environ)
-    env.setdefault("BENCH_MESH_SESSION_RECORDS",
-                   str(int(4_000_000 * SCALE)))
-    env["BENCH_MESH_ZIPF"] = "1"
-    proc = subprocess.run(
-        [sys.executable,
-         os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                      "bench_mesh_sessions.py"), "--zipf"],
-        capture_output=True, text=True, env=env, timeout=3600)
-    lines = [ln for ln in proc.stdout.splitlines()
-             if ln.startswith("{")]
-    if proc.returncode != 0 or not lines:
-        raise RuntimeError((proc.stderr or proc.stdout).strip()[-300:])
-    r = json.loads(lines[-1])
+    r = _child_json(
+        [_tool("bench_mesh_sessions.py"), "--zipf"],
+        {"BENCH_MESH_SESSION_RECORDS": str(int(4_000_000 * SCALE))},
+        env={"BENCH_MESH_ZIPF": "1"})[-1]
     sk = r.get("skew") or {}
     r["shape"] = (
         f"{r['shape']}; recovered "
@@ -305,28 +304,16 @@ def row6_queryable_lookups():
     client threads issuing 256-key batched point lookups (the tenancy
     serving plane). Subprocess for the virtual-device flag, like the
     mesh row."""
-    import subprocess
-
-    env = dict(os.environ)
-    env.setdefault("SERVING_SMOKE_RECORDS",
-                   str(int(400_000 * SCALE)))
-    env.setdefault("SERVING_SMOKE_CLIENTS", "16")
-    env.setdefault("SERVING_SMOKE_LOOKUP_BATCH", "256")
-    env.setdefault("SERVING_SMOKE_KEYS", "4096")
-    # the r19 native-fast-path operating point: 2 ms client pause (the
-    # packed path holds the staleness SLO there; the dict control does
-    # NOT — its recorded number stays at its own best point, 5 ms)
-    env.setdefault("SERVING_SMOKE_CLIENT_PAUSE_MS", "2")
-    proc = subprocess.run(
-        [sys.executable,
-         os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                      "serving_smoke.py")],
-        capture_output=True, text=True, env=env, timeout=3600)
-    lines = [ln for ln in proc.stdout.splitlines()
-             if ln.startswith("{")]
-    if proc.returncode != 0 or not lines:
-        raise RuntimeError((proc.stderr or proc.stdout).strip()[-300:])
-    return json.loads(lines[-1])
+    return _child_json([_tool("serving_smoke.py")], {
+        "SERVING_SMOKE_RECORDS": str(int(400_000 * SCALE)),
+        "SERVING_SMOKE_CLIENTS": "16",
+        "SERVING_SMOKE_LOOKUP_BATCH": "256",
+        "SERVING_SMOKE_KEYS": "4096",
+        # the r19 native-fast-path operating point: 2 ms client pause
+        # (the packed path holds the staleness SLO there; the dict
+        # control does NOT — its recorded number stays at its own best
+        # point, 5 ms)
+        "SERVING_SMOKE_CLIENT_PAUSE_MS": "2"})[-1]
 
 
 def row7_shard_loss_recovery():
@@ -335,26 +322,14 @@ def row7_shard_loss_recovery():
     paged eviction) and report wall-clock recovery: survivor
     evacuation + mesh rebuild + checkpoint-unit restore of ONLY the
     dead range + bounded replay of ONLY its records."""
-    import subprocess
-
-    env = dict(os.environ)
-    env.setdefault("CHAOS_SHARD_LOSS_KEYS",
-                   str(int(1_000_000 * SCALE)))
-    env.setdefault("CHAOS_SHARD_LOSS_PER_STEP",
-                   str(int(125_000 * SCALE)))
-    env.setdefault("CHAOS_SHARD_LOSS_SLOTS", str(1 << 14))
-    proc = subprocess.run(
-        [sys.executable, "-c",
+    r = _child_json(
+        ["-c",
          "import sys; sys.argv=['chaos_smoke']; "
          "import tools.chaos_smoke as cs; "
          "sys.exit(cs.shard_loss_scenario())"],
-        capture_output=True, text=True, env=env, timeout=3600,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    lines = [ln for ln in proc.stdout.splitlines()
-             if ln.startswith("{")]
-    if proc.returncode != 0 or not lines:
-        raise RuntimeError((proc.stderr or proc.stdout).strip()[-300:])
-    r = json.loads(lines[-1])
+        {"CHAOS_SHARD_LOSS_KEYS": str(int(1_000_000 * SCALE)),
+         "CHAOS_SHARD_LOSS_PER_STEP": str(int(125_000 * SCALE)),
+         "CHAOS_SHARD_LOSS_SLOTS": str(1 << 14)})[-1]
     return {
         "metric": "shard_loss_recovery_ms",
         "value": r["shard_loss_recovery_ms"],
@@ -378,20 +353,9 @@ def row8_mesh_sessions_2proc():
     same-box 1-process run — near-linear on real multi-core/multi-host
     boxes; a 1-core CI box time-shares the clock and reports the
     pod-protocol overhead instead (NOTES_r18.md)."""
-    import subprocess
-
-    env = dict(os.environ)
-    env.setdefault("MP_SMOKE_RECORDS", str(int(262_144 * SCALE)))
-    proc = subprocess.run(
-        [sys.executable,
-         os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                      "multiproc_smoke.py")],
-        capture_output=True, text=True, env=env, timeout=3600)
-    lines = [ln for ln in proc.stdout.splitlines()
-             if ln.startswith("{")]
-    if proc.returncode != 0 or not lines:
-        raise RuntimeError((proc.stderr or proc.stdout).strip()[-300:])
-    r = json.loads(lines[-1])
+    r = _child_json(
+        [_tool("multiproc_smoke.py")],
+        {"MP_SMOKE_RECORDS": str(int(262_144 * SCALE))})[-1]
     r["unit"] = "events/s aggregate"
     r["shape"] += (
         f"; 1-proc same-box {r['single_proc_events_per_s']:,.0f} ev/s "
@@ -410,42 +374,19 @@ def row9_serving_mp():
     counters (fe_stats, not wall division) and the scaling factor vs
     the owner's own 1-process packed loop; near-linear on multi-core
     boxes, time-shared on a 1-core CI box (NOTES_r21.md)."""
-    import subprocess
-
-    env = dict(os.environ)
-    env.setdefault("BENCH_SERVING_MP_BATCHES",
-                   str(int(2000 * SCALE)))
-    proc = subprocess.run(
-        [sys.executable,
-         os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                      "bench_serving_mp.py")],
-        capture_output=True, text=True, env=env, timeout=3600)
-    lines = [ln for ln in proc.stdout.splitlines()
-             if ln.startswith("{")]
-    if proc.returncode != 0 or not lines:
-        raise RuntimeError((proc.stderr or proc.stdout).strip()[-300:])
-    return json.loads(lines[-1])
+    return _child_json(
+        [_tool("bench_serving_mp.py")],
+        {"BENCH_SERVING_MP_BATCHES": str(int(2000 * SCALE))})[-1]
 
 
 def _join_rows():
     """Both join rows from tools/bench_joins.py in ONE subprocess (the
     mesh needs the virtual-device flag, like row5b; the tool prints one
     JSON line per row)."""
-    import subprocess
-
-    env = dict(os.environ)
-    env.setdefault("BENCH_JOIN_RECORDS", str(int(4_000_000 * SCALE)))
-    env.setdefault("BENCH_JOIN_REQUIRE_SPILL", "1")
-    proc = subprocess.run(
-        [sys.executable,
-         os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                      "bench_joins.py")],
-        capture_output=True, text=True, env=env, timeout=3600)
-    lines = [ln for ln in proc.stdout.splitlines()
-             if ln.startswith("{")]
-    if proc.returncode != 0 or len(lines) < 2:
-        raise RuntimeError((proc.stderr or proc.stdout).strip()[-300:])
-    return [json.loads(ln) for ln in lines[-2:]]
+    return _child_json(
+        [_tool("bench_joins.py")],
+        {"BENCH_JOIN_RECORDS": str(int(4_000_000 * SCALE)),
+         "BENCH_JOIN_REQUIRE_SPILL": "1"}, min_lines=2)[-2:]
 
 
 def row_cep():
@@ -455,22 +396,11 @@ def row_cep():
     oracle at the same shape — the bench FAILS itself if the device
     engine loses or the spill tier never engages. Subprocess for the
     virtual-device flag, like row5b."""
-    import subprocess
-
-    env = dict(os.environ)
-    env.setdefault("BENCH_CEP_RECORDS", str(int(4_000_000 * SCALE)))
-    env.setdefault("BENCH_CEP_REQUIRE_SPILL", "1")
-    env.setdefault("BENCH_CEP_REQUIRE_WIN", "1")
-    proc = subprocess.run(
-        [sys.executable,
-         os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                      "bench_cep.py")],
-        capture_output=True, text=True, env=env, timeout=3600)
-    lines = [ln for ln in proc.stdout.splitlines()
-             if ln.startswith("{")]
-    if proc.returncode != 0 or not lines:
-        raise RuntimeError((proc.stderr or proc.stdout).strip()[-300:])
-    return json.loads(lines[-1])
+    return _child_json(
+        [_tool("bench_cep.py")],
+        {"BENCH_CEP_RECORDS": str(int(4_000_000 * SCALE)),
+         "BENCH_CEP_REQUIRE_SPILL": "1",
+         "BENCH_CEP_REQUIRE_WIN": "1"})[-1]
 
 
 _JOIN_CACHE = {}
@@ -501,27 +431,44 @@ ROWS = [("wordcount_socket", row1_wordcount),
         ("serving_mp_lookups", row9_serving_mp)]
 
 
-def main():
+#: rows whose job runs inside the process that calls them; the suite
+#: runs each as a ``--row`` child. Every other row starts its own child.
+IN_PROCESS_ROWS = ("wordcount_socket", "nexmark_q5", "nexmark_q7",
+                   "sql_hop_kafka", "sessions_10m_keys")
+
+
+def run_row(name):
+    """Child mode (``--row NAME``): one in-process row, its line naming
+    the device it ran on."""
     import warnings
 
     warnings.filterwarnings("ignore")
-    if os.environ.get("BENCH_SKIP_PROBE") != "1":
-        from bench import probe_backend
+    from flink_tpu.platform import enable_compilation_cache
 
-        ok, info = probe_backend()
-        if not ok:
-            os.environ["JAX_PLATFORMS"] = "cpu"
-    from flink_tpu.platform import sync_platform
+    enable_compilation_cache()
+    r = dict(ROWS)[name]()
+    import jax
 
-    sync_platform()
-    platform = _platform()
+    dev = jax.devices()[0]
+    r["backend"] = dev.platform
+    r["device_kind"] = dev.device_kind
+    print(json.dumps(r), flush=True)
+
+
+def main():
+    if len(sys.argv) == 3 and sys.argv[1] == "--row":
+        return run_row(sys.argv[2])
     results = []
     for name, fn in ROWS:
-        try:
+        # no try/except: a failed row fails the suite
+        if name in IN_PROCESS_ROWS:
+            r = _child_json([os.path.abspath(__file__), "--row", name])[-1]
+        else:
             r = fn()
-        except Exception as e:  # noqa: BLE001 — a row must not kill the suite
-            r = {"metric": name, "error": repr(e)}
-        r["backend"] = platform
+        # the child-tool rows do not name their device: label them with
+        # what the in-process rows of this same run reported
+        if "backend" not in r:
+            r["backend"] = results[0]["backend"]
         lat = r.get("fire_latency_ms")
         if lat and lat.get("count", 0) < 30:
             # a windowed row that fired < 30 times has vacuous
@@ -530,6 +477,7 @@ def main():
             r["fire_latency_low_confidence"] = True
         results.append(r)
         print(json.dumps(r), flush=True)
+    platform = results[0]["backend"]
     lines = [
         "# BENCHMARKS — all BASELINE.md rows",
         "",
@@ -540,8 +488,7 @@ def main():
         "|---|---|---|---|",
     ]
     for (name, _), r in zip(ROWS, results):
-        val = (f"{r['value']:,.0f}" if "value" in r
-               else f"error: {r.get('error', '?')[:60]}")
+        val = f"{r['value']:,.0f}"
         extra = ""
         if r.get("shape"):
             extra = f" — {r['shape']}"

@@ -4,9 +4,9 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from flink_tpu.platform import sync_platform
+from flink_tpu.platform import enable_compilation_cache
 
-sync_platform()
+enable_compilation_cache()
 
 import numpy as np
 

@@ -13,11 +13,10 @@ replaces, bit-for-bit at three levels:
   fires IN ORDER vs the default backend — same ranks means same bucket
   positions means same downstream fold order.
 
-On CPU the kernel runs in Pallas interpret mode — that IS the CI
-configuration; on TPU the same code path compiles to Mosaic. When the
-pallas kernel is unavailable on this host the gate SKIPS LOUDLY and
-exits 0 (the migration must not brick hosts without it), printing an
-unmistakable marker line for the tier-1 log.
+On the ``cpu`` backend the kernel runs in Pallas interpret mode — that
+IS the CI configuration; on any other backend the same code path
+compiles (Mosaic) or raises. Lengths straddle ``RANK_BLOCK`` so both
+the single-block and the padded multi-block grid are compared.
 
     JAX_PLATFORMS=cpu python tools/pallas_ab_gate.py
 """
@@ -46,6 +45,7 @@ NUM_KEYS = 15_000
 
 def _kernel_leg(errs):
     from flink_tpu.stateplane.rank import (
+        RANK_BLOCK,
         exchange_rank_flat,
         pallas_rank,
         xla_rank,
@@ -55,6 +55,8 @@ def _kernel_leg(errs):
     for i in range(SHAPES):
         D = int(rng.integers(1, 17))
         n = int(rng.integers(1, 600))
+        if i % 8 == 7:  # multi-block grid, last block padded
+            n += RANK_BLOCK
         W = int(rng.integers(1, 64))
         d = rng.integers(-2, D + 3, size=n).astype(np.int32)
         pr = np.asarray(pallas_rank(d, D))
@@ -136,17 +138,8 @@ def main():
     import jax
 
     from flink_tpu.parallel.mesh import make_mesh
-    from flink_tpu.stateplane import pallas_available
 
     t0 = time.perf_counter()
-    if not pallas_available():
-        print("PALLAS A/B GATE: SKIPPED — pallas kernel unavailable "
-              "on this host (no pallas install, or the interpret-mode "
-              "probe failed); the exchange-rank backend stays XLA and "
-              "the bit-identity claim is NOT verified here",
-              file=sys.stderr)
-        print(json.dumps({"pallas_ab_gate": "SKIPPED"}))
-        return 0
     errs = []
     _kernel_leg(errs)
     _program_leg(errs)

@@ -11,11 +11,9 @@ import os
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("BENCH_SKIP_PROBE", "1")
+from flink_tpu.platform import enable_compilation_cache
 
-from flink_tpu.platform import sync_platform
-
-sync_platform()
+enable_compilation_cache()
 
 from bench import run
 

@@ -244,16 +244,10 @@ def check_pallas_backend_phase(mesh, budget):
     scope) must compile NOTHING. A backend hook that leaked into the
     key unstably (per-engine closure, config object identity) or that
     failed to key at all (silent retrace on every scope flip) shows up
-    here as a steady-state compile. Skips LOUDLY when the pallas kernel
-    is unavailable on this host."""
+    here as a steady-state compile."""
     from flink_tpu.observe import RecompileSentinel
-    from flink_tpu.stateplane import backend_scope, pallas_available
+    from flink_tpu.stateplane import backend_scope
 
-    if not pallas_available():
-        print("  pallas-backend tiers: SKIPPED — pallas kernel "
-              "unavailable on this host; the backend-swap "
-              "zero-recompile claim is NOT verified here")
-        return True
     with backend_scope("exchange-rank", "pallas"):
         warm_eng = _make_sessions(mesh, budget)
         warm_fired = _drive_sized(warm_eng, TIER_WALK_WARM, offset=0)

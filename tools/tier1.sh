@@ -54,7 +54,7 @@ fi
 if [ "${SKIP_BENCH_SMOKE:-0}" != "1" ]; then
   # CPU bench smoke: a reduced Q5 run must still emit its JSON line
   # (catches import/config regressions the unit tests cannot)
-  BENCH_SKIP_PROBE=1 BENCH_RECORDS=$((1 << 20)) BENCH_REPS=1 \
+  BENCH_RECORDS=$((1 << 20)) BENCH_REPS=1 \
     JAX_PLATFORMS=cpu timeout -k 10 600 python bench.py || exit 1
 
   # Mesh-sessions smoke with two gates pinned:
@@ -88,7 +88,7 @@ if [ "${SKIP_BENCH_SMOKE:-0}" != "1" ]; then
   # amplification gate would be vacuous. 3 reps: all gates read the
   # MEDIAN rep (the bench's own methodology) — a single-rep gate at a
   # tight budget tripped on scheduler noise, not regressions.
-  BENCH_SKIP_PROBE=1 BENCH_MESH_SESSION_RECORDS=$((1 << 21)) \
+  BENCH_MESH_SESSION_RECORDS=$((1 << 21)) \
     BENCH_MESH_REPS=3 BENCH_MESH_AMP_BUDGET=0.5 \
     BENCH_HOST_PREP_BUDGET=0.35 \
     BENCH_FIRE_P99_BUDGET=140 BENCH_MESH_FIRE_DEADLINE_MS=25 \
@@ -169,9 +169,8 @@ if [ "${SKIP_BENCH_SMOKE:-0}" != "1" ]; then
   # (random shapes incl. out-of-range/negative lanes), the cached-
   # program level (xla and pallas keys must also be DISTINCT cache
   # entries), or the engine level (device-mode session fires must be
-  # bit-identical IN ORDER across backends). Interpret mode on CPU;
-  # SKIPS LOUDLY (exit 0, unmistakable marker line) when the pallas
-  # kernel is unavailable on this host. ~20 s on CPU.
+  # bit-identical IN ORDER across backends). Interpret mode on CPU.
+  # ~35 s on CPU.
   JAX_PLATFORMS=cpu timeout -k 10 300 \
     python tools/pallas_ab_gate.py || exit 1
 
