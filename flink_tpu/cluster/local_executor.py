@@ -1248,10 +1248,9 @@ class LocalExecutor:
         chaos.fault_point("task.batch", op=node.transformation.name,
                           job=getattr(self, "_chaos_job", None))
         node.records_in += len(batch)
-        t0 = time.perf_counter()
-        with flight.span("op.process"):
+        with flight.span("op.process", timed=True) as span:
             outs = node.operator.process_batch(batch, input_idx)
-        node.busy_s += time.perf_counter() - t0
+        node.busy_s += span.duration_s
         if node.marker_hist is not None:
             self._lat_plane.observe(node.marker_hist)
         for out in outs:
@@ -1261,10 +1260,10 @@ class LocalExecutor:
         advanced = node.valve.advance(input_idx, wm)
         if advanced is None:
             return
-        t0 = time.perf_counter()
-        with flight.span("op.watermark", watermark=int(advanced)):
+        with flight.span("op.watermark", watermark=int(advanced),
+                         timed=True) as span:
             outs = node.operator.process_watermark(advanced)
-        node.busy_s += time.perf_counter() - t0
+        node.busy_s += span.duration_s
         for out in outs:
             self._forward(node, out)
         if node.operator.has_pending_output():

@@ -23,7 +23,12 @@ KNOWN_SPAN_KINDS = (
     # per-batch lifecycle (the engines' ingest -> emit pipeline)
     "batch.ingest",        # one engine process_batch (host prep + dispatch)
     "prep.meta_sweep",     # session-metadata absorb (native C or Python)
-    "prep.stage",          # shuffle staging / bucketing into [P, B] blocks
+    "prep.resolve",        # slice assignment + (key, slice) -> slot
+                           # resolution on the host index (work: pairs
+                           # newly given a slot)
+    "prep.stage",          # input mapping, padding to the sticky bucket,
+                           # shuffle staging into [P, B] blocks (work:
+                           # bytes handed to the device, padding included)
     "device.dispatch",     # inline device interactions on the ingest path
     "device.fence_wait",   # host blocked on dispatch-ahead fences
     "exchange.stage1",     # two-level exchange: intra-host (ICI) route
@@ -33,6 +38,11 @@ KNOWN_SPAN_KINDS = (
     "fire.shard",          # one shard's fire-path host work (resolve,
                            # cold page extraction) — the per-shard track
     "fire.harvest",        # D2H materialization of fire/query results
+                           # (work: bytes fetched)
+    "slice.retire",        # expired slices' pairs erased from the host
+                           # index + their device rows reset (work:
+                           # pairs erased; page faults summed)
+    "sink.write",          # one batch handed to the sink (work: rows)
     "op.process",          # executor: one operator's process_batch
     "op.watermark",        # executor: one operator's process_watermark
     "emit",                # executor: one output left its operator

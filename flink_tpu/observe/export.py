@@ -70,6 +70,10 @@ def chrome_trace(records: List[SpanRecord],
                 r.thread, HOST_TID_BASE + len(host_tids))
             seen_tids[(pid, tid)] = f"host:{r.thread}"
         args: Dict[str, Any] = {"batch": r.batch_id, "thread": r.thread}
+        if r.parent is not None:
+            args["parent"] = r.parent
+        if r.work:
+            args["work"] = r.work
         if r.watermark is not None:
             args["watermark"] = r.watermark
         if r.shard >= 0:
@@ -139,76 +143,11 @@ def validate_trace_schema(trace: Dict[str, Any],
     return problems
 
 
-def span_rollup(kind_totals: Dict[str, Dict[str, float]],
-                wall_s: float,
-                buckets: Dict[str, Any]) -> Dict[str, float]:
-    """Sum per-kind span totals into named wall-time buckets — THE
-    bench drivers' rollup primitive. ``buckets`` maps an output field
-    to one span kind or a sequence of kinds; absent kinds contribute
-    0.0 (a driver may name kinds its engine doesn't emit yet). Always
-    appends ``total_s`` (the measured wall clock) so every driver's
-    breakdown dict carries the same denominator. Buckets may overlap
-    (a kind can appear in several) and are not guaranteed to sum to
-    ``total_s`` — they attribute, they don't partition."""
-
-    def total(kind: str) -> float:
-        return kind_totals.get(kind, {}).get("total_s", 0.0)
-
-    out: Dict[str, float] = {}
-    for name, kinds in buckets.items():
-        if isinstance(kinds, str):
-            kinds = (kinds,)
-        out[name] = round(sum(total(k) for k in kinds), 3)
-    out["total_s"] = round(wall_s, 3)
-    return out
-
-
-def breakdown_from_kind_totals(kind_totals: Dict[str, Dict[str, float]],
-                               wall_s: float) -> Dict[str, float]:
-    """The canonical host-prep / device / harvest wall-time breakdown,
-    derived from flight-recorder span aggregates — the bench drivers
-    report THIS dict, so their gates and a captured trace read the same
-    numbers from the same spans by construction.
-
-    ``host_prep_s`` approximates genuine host work on the ingest path:
-    ``batch.ingest`` total minus ALL inline device interactions
-    (``device.dispatch``) and fence blocks (``device.fence_wait``).
-    The subtraction uses the process totals, and some device spans
-    open on the FIRE path (cold-page reloads, eviction gathers), so
-    host prep can be slightly UNDER-stated at spill-heavy shapes —
-    the same approximation the pre-recorder engine counters
-    (``device_inline_s`` accumulated on both paths, subtracted from
-    an ingest-only timer) made, so gate budgets calibrated against
-    them carry over unchanged. ``device_step_s`` is the device spans
-    plus the fire dispatches; ``harvest_s`` is ALL D2H
-    materializations (``fire.harvest``), including ones nested inside
-    device interactions or synchronous fires — buckets may overlap
-    and are not guaranteed to sum to ``total_s``."""
-
-    def total(kind: str) -> float:
-        return kind_totals.get(kind, {}).get("total_s", 0.0)
-
-    host_prep = max(total("batch.ingest") - total("device.dispatch")
-                    - total("device.fence_wait"), 0.0)
-    out = {"host_prep_s": round(host_prep, 3)}
-    out.update(span_rollup(kind_totals, wall_s, {
-        "meta_sweep_s": "prep.meta_sweep",
-        "stage_s": "prep.stage",
-        "device_step_s": ("fire.dispatch", "device.dispatch",
-                          "device.fence_wait"),
-        "harvest_s": "fire.harvest",
-        "device_in_prep_s": ("device.dispatch", "device.fence_wait"),
-    }))
-    out["host_prep_fraction"] = round(host_prep / wall_s, 4) \
-        if wall_s > 0 else 0.0
-    return out
-
-
 def register_flight_metrics(group,
                             rec: Optional[FlightRecorder] = None):
     """Per-span-kind duration aggregates as gauges under
-    ``<scope>.flight`` (count / total_ms / p50_ms / p99_ms per kind,
-    names Prometheus-safe). Suppliers read the recorder's merged
+    ``<scope>.flight`` (count / total_s / self_s / work / p50_ms /
+    p99_ms per kind, names Prometheus-safe). Suppliers read the recorder's merged
     per-thread aggregates at scrape time — nothing is added to the
     hot path, and ``kind_totals`` is memoized so a scrape of all the
     gauges pays one merge. The aggregates are PROCESS-GLOBAL (the
@@ -231,6 +170,8 @@ def register_flight_metrics(group,
         base = _sanitize(kind)
         fg.gauge(f"{base}_count", _stat(kind, "count"))
         fg.gauge(f"{base}_total_s", _stat(kind, "total_s"))
+        fg.gauge(f"{base}_self_s", _stat(kind, "self_s"))
+        fg.gauge(f"{base}_work", _stat(kind, "work"))
         fg.gauge(f"{base}_p50_ms", _stat(kind, "p50_ms"))
         fg.gauge(f"{base}_p99_ms", _stat(kind, "p99_ms"))
     fg.gauge("records_dropped", lambda: rec.dropped())

@@ -32,6 +32,22 @@ Design constraints, in order:
   injections) interleave in the same ring, so a mystery fire-p99 spike
   reads directly as "compile under fire span on shard 3" in Perfetto.
 
+- **A span says what it is.** Each thread keeps the chain of its open
+  spans, so a record carries the kind of the span that enclosed it
+  (``parent``) and the per-kind aggregates carry *self time* — a
+  span's duration minus what its children on the same thread covered
+  (an externally timed ``instant(..., duration_s=dt)`` counts as a
+  child of the span open on that thread, clipped to it; a child on
+  another thread is not subtracted). A span may state how much work it
+  did (``s.work = n``: events, pairs, bytes — whatever the kind
+  counts), summed per kind at the same boundary that times it, and
+  ``faults=True`` adds the thread's page faults over the span.
+- **One clock with the device.** While a profiler session is active, a
+  span of the batch / fire lifecycle is also open as a
+  ``jax.profiler.TraceAnnotation`` named ``flink.<kind>``, so the
+  session holds the program's spans on the profiler's own clock beside
+  the device rows. Without a session that costs one check per span.
+
 Span kinds are a closed registry (:data:`flink_tpu.observe.
 KNOWN_SPAN_KINDS`): an unregistered kind raises at the call site, and
 flint's REG03 cross-checks every literal producer statically — the
@@ -42,8 +58,9 @@ Usage::
     from flink_tpu.observe import flight_recorder as flight
 
     flight.set_job("pipeline-a")
-    with flight.span("batch.ingest", shard=-1, batch=seq):
+    with flight.span("batch.ingest", shard=-1, batch=seq) as s:
         ...
+        s.work = len(batch)
     flight.instant("watchdog.miss", shard=3)
 
 Disable with ``FLINK_TPU_FLIGHT_RECORDER=0`` (spans become no-ops that
@@ -53,6 +70,7 @@ cost one module-global check), or per-region with :func:`disabled`.
 from __future__ import annotations
 
 import os
+import resource
 import threading
 import time
 from typing import Dict, Iterator, List, NamedTuple, Optional
@@ -73,6 +91,27 @@ _RESERVOIR = 256
 
 _enabled = os.environ.get("FLINK_TPU_FLIGHT_RECORDER", "1") != "0"
 
+#: first components of the kinds mirrored into a profiler session as
+#: ``flink.<kind>`` rows: the batch / fire lifecycle on the task loop
+#: (control-plane spans and instants stay in the recorder only)
+_MIRRORED = frozenset(
+    ("op", "batch", "prep", "device", "exchange", "fire", "slice", "sink"))
+#: ``resource.RUSAGE_THREAD`` (Linux); absent elsewhere, where
+#: ``faults=True`` then counts nothing
+_RUSAGE_THREAD = getattr(resource, "RUSAGE_THREAD", None)
+#: ``jax.profiler.TraceAnnotation``, looked up at the first span (the
+#: recorder itself never imports jax at module import)
+_trace_annotation = None
+
+
+def _annotation_class():
+    global _trace_annotation
+    if _trace_annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation
+
 
 class SpanRecord(NamedTuple):
     """One decoded record (``snapshot()`` output)."""
@@ -86,6 +125,10 @@ class SpanRecord(NamedTuple):
     batch_id: int
     watermark: Optional[int]
     thread: str
+    #: kind of the span that enclosed this record on its thread
+    parent: Optional[str] = None
+    #: what the span said it did (0 where it said nothing)
+    work: int = 0
 
     @property
     def duration_s(self) -> float:
@@ -94,32 +137,100 @@ class SpanRecord(NamedTuple):
 
 class _SpanCtx:
     """Reusable span context manager (pooled per thread — entering a
-    span allocates nothing once the pool is warm)."""
+    span allocates nothing once the pool is warm). ``work`` is the
+    caller's to set inside the ``with``; ``duration_s`` is readable
+    right after it."""
 
     __slots__ = ("_ring", "_kind", "_shard", "_batch", "_wm", "_job",
-                 "_t0")
+                 "_t0", "_outer", "_child", "_mirror", "_tm", "_faults",
+                 "_flt", "work", "duration_s")
 
     def __init__(self, ring: "_ThreadRing") -> None:
         self._ring = ring
+        self._tm = None
 
     def __enter__(self) -> "_SpanCtx":
+        r = self._ring
+        self._outer = r.open
+        r.open = self
+        self._child = 0.0
+        self.work = 0
+        name = self._mirror
+        if name is not None:
+            cls = _annotation_class()
+            if cls.is_enabled():
+                # the TraceMe starts at construction: opened before and
+                # closed after the span's own clock reads, so the
+                # session's row encloses the record
+                self._tm = cls(name)
+        if self._faults:
+            ru = resource.getrusage(_RUSAGE_THREAD)
+            self._flt = (ru.ru_minflt, ru.ru_majflt)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        t1 = time.perf_counter()
         r = self._ring
-        r.write(self._kind, 0, self._t0, time.perf_counter(),
-                self._job, self._shard, self._batch, self._wm)
+        d = t1 - self._t0
+        self.duration_s = d
+        outer = self._outer
+        r.open = outer
+        self._outer = None
+        parent = -1
+        if outer is not None:
+            outer._child += d
+            parent = outer._kind
+        kind = self._kind
+        r.write(kind, 0, self._t0, t1, self._job, self._shard,
+                self._batch, self._wm, parent, d - self._child,
+                self.work)
+        if self._faults:
+            ru = resource.getrusage(_RUSAGE_THREAD)
+            r.k_minflt[kind] += ru.ru_minflt - self._flt[0]
+            r.k_majflt[kind] += ru.ru_majflt - self._flt[1]
+        tm = self._tm
+        if tm is not None:
+            self._tm = None
+            tm.__exit__(exc_type, exc, tb)
         r.pool.append(self)
 
 
 class _NullSpan:
+    """What ``span()`` returns with the recorder off: nothing is timed,
+    and ``work`` swallows what the call site states."""
+
     __slots__ = ()
+    duration_s = 0.0
 
     def __enter__(self):
         return self
 
     def __exit__(self, *exc):
+        return None
+
+    @property
+    def work(self) -> int:
+        return 0
+
+    @work.setter
+    def work(self, n) -> None:
+        pass
+
+
+class _TimerSpan(_NullSpan):
+    """``span(..., timed=True)`` with the recorder off: records
+    nothing, but still times — for the call sites whose own accounting
+    reads the span's ``duration_s`` (the executor's ``busy_s``)."""
+
+    __slots__ = ("_t0", "duration_s")
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.duration_s = time.perf_counter() - self._t0
         return None
 
 
@@ -146,20 +257,32 @@ class _ThreadRing:
         self.shard = np.full(cap, -1, dtype=np.int32)
         self.batch = np.full(cap, -1, dtype=np.int64)
         self.wm = np.full(cap, WM_NONE, dtype=np.int64)
+        self.parent = np.full(cap, -1, dtype=np.int16)
+        self.work = np.zeros(cap, dtype=np.int64)
         # per-kind duration aggregates (merged across threads on read)
         self.k_count = np.zeros(n_kinds, dtype=np.int64)
         self.k_total = np.zeros(n_kinds, dtype=np.float64)
+        self.k_self = np.zeros(n_kinds, dtype=np.float64)
         self.k_max = np.zeros(n_kinds, dtype=np.float64)
+        self.k_work = np.zeros(n_kinds, dtype=np.int64)
+        # the thread's page faults over spans opened with faults=True
+        self.k_minflt = np.zeros(n_kinds, dtype=np.int64)
+        self.k_majflt = np.zeros(n_kinds, dtype=np.int64)
         self.k_res = np.zeros((n_kinds, _RESERVOIR), dtype=np.float32)
         self.k_cursor = np.zeros(n_kinds, dtype=np.int64)
         # ambient attribution context (set by the layer that knows)
         self.ctx_job = -1
         self.ctx_batch = -1
         self.ctx_wm = WM_NONE
+        #: innermost span open on this thread (the spans chain through
+        #: ``_outer``: the stack is the contexts themselves)
+        self.open: Optional[_SpanCtx] = None
         self.pool: List[_SpanCtx] = [_SpanCtx(self) for _ in range(8)]
 
     def write(self, kind_id: int, flags: int, t0: float, t1: float,
-              job: int, shard: int, batch: int, wm: int) -> None:
+              job: int, shard: int, batch: int, wm: int,
+              parent: int = -1, self_s: float = 0.0,
+              work: int = 0) -> None:
         i = self.cursor & self.mask
         self.cursor += 1
         self.kind[i] = kind_id
@@ -170,13 +293,19 @@ class _ThreadRing:
         self.shard[i] = shard
         self.batch[i] = batch
         self.wm[i] = wm
+        self.parent[i] = parent
+        self.work[i] = work
         # counts aggregate for EVERY record (an operator reading
         # flight.chaos_inject_count must see armed injections);
         # durations only for spans — instants' quantiles stay 0
         self.k_count[kind_id] += 1
+        if work:
+            self.k_work[kind_id] += work
         if not flags:
             d = t1 - t0
             self.k_total[kind_id] += d
+            if self_s > 0.0:
+                self.k_self[kind_id] += self_s
             if d > self.k_max[kind_id]:
                 self.k_max[kind_id] = d
             self.k_res[kind_id, self.k_cursor[kind_id] % _RESERVOIR] = d
@@ -193,6 +322,10 @@ class FlightRecorder:
         self._kind_id = {k: i for i, k in enumerate(self.kinds)}
         if len(self._kind_id) != len(self.kinds):
             raise ValueError("duplicate span kinds")
+        #: per kind id, the name of its row in a profiler session
+        self._mirror = tuple(
+            "flink." + k if k.split(".", 1)[0] in _MIRRORED else None
+            for k in self.kinds)
         self._lock = threading.Lock()
         self._rings: List[_ThreadRing] = []
         self._tl = threading.local()
@@ -214,15 +347,21 @@ class FlightRecorder:
         return ring
 
     def span(self, kind: str, shard: int = -1, batch: int = -1,
-             watermark: int = WM_NONE, job: Optional[str] = None):
+             watermark: int = WM_NONE, job: Optional[str] = None,
+             faults: bool = False, timed: bool = False):
         """Context manager timing one lifecycle section. Unspecified
-        attribution falls back to the thread's ambient context."""
+        attribution falls back to the thread's ambient context.
+        ``faults`` also sums the thread's minor / major page faults
+        over the span per kind; ``timed`` keeps ``duration_s`` readable
+        after the ``with`` even when the recorder is off."""
         if not _enabled:
-            return _NULL_SPAN
+            return _TimerSpan() if timed else _NULL_SPAN
         ring = self._ring()
         pool = ring.pool
         ctx = pool.pop() if pool else _SpanCtx(ring)
-        ctx._kind = self._kind_id[kind]
+        kid = ctx._kind = self._kind_id[kind]
+        ctx._mirror = self._mirror[kid]
+        ctx._faults = faults and _RUSAGE_THREAD is not None
         ctx._shard = shard
         ctx._batch = batch if batch >= 0 else ring.ctx_batch
         ctx._wm = watermark if watermark != WM_NONE else ring.ctx_wm
@@ -232,21 +371,30 @@ class FlightRecorder:
     def instant(self, kind: str, shard: int = -1, batch: int = -1,
                 watermark: int = WM_NONE, job: Optional[str] = None,
                 t0: Optional[float] = None,
-                duration_s: float = 0.0) -> None:
+                duration_s: float = 0.0, work: int = 0) -> None:
         """Record an instant event (or a short externally-timed span,
         e.g. an XLA compile whose duration arrives via monitoring:
-        pass ``duration_s`` and it lands as ``[now - d, now]``)."""
+        pass ``duration_s`` and it lands as ``[now - d, now]``, a child
+        of the span open on this thread, clipped to it)."""
         if not _enabled:
             return
         ring = self._ring()
         now = time.perf_counter() if t0 is None else t0
+        parent = -1
+        outer = ring.open
+        if outer is not None:
+            parent = outer._kind
+            if duration_s > 0.0:
+                outer._child += max(
+                    min(duration_s, now - outer._t0), 0.0)
         ring.write(
             self._kind_id[kind], 0 if duration_s > 0.0 else 1,
             now - duration_s, now,
             self.job_id(job) if job is not None else ring.ctx_job,
             shard,
             batch if batch >= 0 else ring.ctx_batch,
-            watermark if watermark != WM_NONE else ring.ctx_wm)
+            watermark if watermark != WM_NONE else ring.ctx_wm,
+            parent, duration_s, work)
 
     # ------------------------------------------------------ ambient context
 
@@ -295,6 +443,7 @@ class FlightRecorder:
             for i in range(n):
                 jid = int(ring.job[i])
                 wm = int(ring.wm[i])
+                par = int(ring.parent[i])
                 out.append(SpanRecord(
                     kind=self.kinds[int(ring.kind[i])],
                     instant=bool(ring.flags[i]),
@@ -303,7 +452,9 @@ class FlightRecorder:
                     shard=int(ring.shard[i]),
                     batch_id=int(ring.batch[i]),
                     watermark=None if wm == WM_NONE else wm,
-                    thread=ring.name))
+                    thread=ring.name,
+                    parent=self.kinds[par] if par >= 0 else None,
+                    work=int(ring.work[i])))
         out.sort(key=lambda r: r.t0)
         return out
 
@@ -314,8 +465,11 @@ class FlightRecorder:
 
     def kind_totals(self) -> Dict[str, Dict[str, float]]:
         """Per-kind aggregates merged across threads: ``{kind: {count,
-        total_s, max_s, p50_ms, p99_ms}}`` (quantiles over the bounded
-        recent-window reservoirs; instants contribute counts only).
+        total_s, self_s, max_s, work, minor_faults, major_faults,
+        p50_ms, p99_ms}}`` (``self_s``: total minus what children on
+        the same thread covered; ``work``: what the spans said they
+        did; quantiles over the bounded recent-window reservoirs;
+        instants contribute counts and work only).
         Memoized on the rings' cursors: a metrics scrape reading many
         gauges pays ONE merge, not one per gauge."""
         from flink_tpu.metrics.core import quantile_sorted
@@ -327,12 +481,20 @@ class FlightRecorder:
         n = len(self.kinds)
         count = np.zeros(n, dtype=np.int64)
         total = np.zeros(n, dtype=np.float64)
+        self_s = np.zeros(n, dtype=np.float64)
         kmax = np.zeros(n, dtype=np.float64)
+        work = np.zeros(n, dtype=np.int64)
+        minflt = np.zeros(n, dtype=np.int64)
+        majflt = np.zeros(n, dtype=np.int64)
         samples: List[List[float]] = [[] for _ in range(n)]
         for ring in self._iter_rings():
             count += ring.k_count
             total += ring.k_total
+            self_s += ring.k_self
             kmax = np.maximum(kmax, ring.k_max)
+            work += ring.k_work
+            minflt += ring.k_minflt
+            majflt += ring.k_majflt
             for k in range(n):
                 m = int(min(ring.k_cursor[k], _RESERVOIR))
                 if m:
@@ -345,7 +507,11 @@ class FlightRecorder:
             out[kind] = {
                 "count": int(count[k]),
                 "total_s": float(total[k]),
+                "self_s": float(self_s[k]),
                 "max_s": float(kmax[k]),
+                "work": int(work[k]),
+                "minor_faults": int(minflt[k]),
+                "major_faults": int(majflt[k]),
                 "p50_ms": quantile_sorted(data, 0.5) * 1e3,
                 "p99_ms": quantile_sorted(data, 0.99) * 1e3,
             }
@@ -362,7 +528,11 @@ class FlightRecorder:
             ring.cursor = 0
             ring.k_count[:] = 0
             ring.k_total[:] = 0.0
+            ring.k_self[:] = 0.0
             ring.k_max[:] = 0.0
+            ring.k_work[:] = 0
+            ring.k_minflt[:] = 0
+            ring.k_majflt[:] = 0
             ring.k_cursor[:] = 0
 
 
@@ -388,21 +558,24 @@ def recorder() -> FlightRecorder:
 
 
 def span(kind: str, shard: int = -1, batch: int = -1,
-         watermark: int = WM_NONE, job: Optional[str] = None):
+         watermark: int = WM_NONE, job: Optional[str] = None,
+         faults: bool = False, timed: bool = False):
     if not _enabled:
-        return _NULL_SPAN
+        return _TimerSpan() if timed else _NULL_SPAN
     return recorder().span(kind, shard=shard, batch=batch,
-                           watermark=watermark, job=job)
+                           watermark=watermark, job=job, faults=faults,
+                           timed=timed)
 
 
 def instant(kind: str, shard: int = -1, batch: int = -1,
             watermark: int = WM_NONE, job: Optional[str] = None,
-            t0: Optional[float] = None, duration_s: float = 0.0) -> None:
+            t0: Optional[float] = None, duration_s: float = 0.0,
+            work: int = 0) -> None:
     if not _enabled:
         return
     recorder().instant(kind, shard=shard, batch=batch,
                        watermark=watermark, job=job, t0=t0,
-                       duration_s=duration_s)
+                       duration_s=duration_s, work=work)
 
 
 def set_job(name: Optional[str]) -> None:

@@ -515,10 +515,10 @@ class MeshSessionEngine(MeshPagedSpillSupport):
                     fills=fills, pool=self._shuffle_pool,
                     traffic=self._exchange2_traffic)
             s1, s2 = self._exchange2_steps
-            with self._device_span(), flight.span("exchange.stage1"):
+            with flight.span("exchange.stage1"), self._device_span():
                 put = jax.device_put((dst, *staged), self._sharding)
                 inter = s1(put[0], put[1], tuple(put[2:]), w1)
-            with self._device_span(), flight.span("exchange.stage2"):
+            with flight.span("exchange.stage2"), self._device_span():
                 self.accs = s2(self.accs, inter[0], inter[1],
                                tuple(inter[2:]), w2)
             chaos.fault_point("shuffle.device_exchange", records=n)

@@ -68,24 +68,25 @@ _FENCE_STEP = jax.jit(lambda a: a[:1, :1])
 
 
 class _DeviceSpan:
-    """Times a device-interaction block into the owner's
-    ``device_inline_s`` (see MeshSpillSupport._init_pipeline)."""
+    """One device-interaction block: a ``device.dispatch`` span of the
+    flight recorder (the ``with`` yields it, so a block can state its
+    ``work``: bytes put through the exchange) whose duration also lands
+    in the owner's ``device_inline_s`` (see
+    MeshSpillSupport._init_pipeline) — the bench breakdown and a
+    Perfetto trace read ONE measurement."""
 
-    __slots__ = ("_owner", "_t0")
+    __slots__ = ("_owner", "_span")
 
     def __init__(self, owner) -> None:
         self._owner = owner
 
-    def __enter__(self) -> "_DeviceSpan":
-        self._t0 = time.perf_counter()
-        return self
+    def __enter__(self):
+        self._span = flight.span("device.dispatch", timed=True)
+        return self._span.__enter__()
 
     def __exit__(self, *exc) -> None:
-        dt = time.perf_counter() - self._t0
-        self._owner.device_inline_s += dt
-        # same section, same number, into the flight-recorder timeline —
-        # the bench breakdown and a Perfetto trace read ONE measurement
-        flight.instant("device.dispatch", duration_s=dt)
+        self._span.__exit__(*exc)
+        self._owner.device_inline_s += self._span.duration_s
 
 
 class MeshSpillSupport:
@@ -96,6 +97,10 @@ class MeshSpillSupport:
     the ``_gather_step/_reset_step/_put_step`` programs."""
 
     max_device_slots: int = 0
+    #: bytes of padded fire slot matrices handed to the device so far
+    #: (one watermark advance's growth is its ``fire.dispatch`` span's
+    #: work)
+    _fire_matrix_bytes = 0
     #: (MemoryManager, owner) — managed accounting of the [P, capacity]
     #: device footprint (flink_tpu/core/memory.py); None = unmanaged
     _memory = None
@@ -305,8 +310,11 @@ class MeshSpillSupport:
     def _harvest_get(self, tree, op: str = "fire_harvest"):
         """The watchdog-sectioned form of the batched-D2H harvest (ONE
         ``jax.device_get`` per harvest point — the TRC01 discipline)."""
-        with flight.span("fire.harvest"), self._wd_section(op):
-            return jax.device_get(tree)
+        with flight.span("fire.harvest") as span, self._wd_section(op):
+            host = jax.device_get(tree)
+            span.work = sum(np.asarray(a).nbytes
+                            for a in jax.tree_util.tree_leaves(host))
+            return host
 
     # ---------------------------------------------------- read replica
     # (tenancy/replica.py — the boundary-published serving plane)
@@ -540,17 +548,15 @@ class MeshSpillSupport:
         before this batch's staging buffers are (re)written."""
         if len(self._dispatch_fences) < self._pipeline_depth:
             return
-        t0 = time.perf_counter()
-        with self._wd_section("fence_drain"):
+        with flight.span("device.fence_wait", timed=True) as wait, \
+                self._wd_section("fence_drain"):
             while len(self._dispatch_fences) >= self._pipeline_depth:
                 # flint: disable=TRC01 -- the depth-bounded fence drain
                 # IS the dispatch-ahead backpressure point: it blocks
                 # only when the host ran a full pipeline depth ahead of
                 # the device
                 self._dispatch_fences.popleft().block_until_ready()
-        dt = time.perf_counter() - t0
-        self.pipeline_wait_s += dt
-        flight.instant("device.fence_wait", duration_s=dt)
+        self.pipeline_wait_s += wait.duration_s
 
     def _push_dispatch_fence(self) -> None:
         # chaos: a fence failure mid-dispatch-ahead — the batch's device
@@ -2228,10 +2234,13 @@ class MeshWindowEngine(MeshSpillSupport):
     def process_batch(self, batch: RecordBatch) -> None:
         if len(batch) == 0:
             return
-        with self._flight_ingest():
-            self._process_batch_inner(batch)
+        with self._flight_ingest() as ingest:
+            if self._process_batch_inner(batch) is not False:
+                ingest.work = len(batch)
 
-    def _process_batch_inner(self, batch: RecordBatch) -> None:
+    def _process_batch_inner(self, batch: RecordBatch):
+        """Returns False where the batch was handed on as sub-batches
+        (each its own ``batch.ingest``, stating its own events)."""
         n = len(batch)
         # batch boundary: the engine is consistent at a known source
         # position — the one point the watchdog may declare a shard dead
@@ -2245,17 +2254,18 @@ class MeshWindowEngine(MeshSpillSupport):
                     mask = np.isin(slice_ends, np.asarray(g))
                     if mask.any():
                         self._ingest_subbatch(batch.filter(mask))
-                return
-        live = self.book.live_mask(slice_ends)
-        if live is not None:
-            key_ids, slice_ends = key_ids[live], slice_ends[live]
-            batch = batch.filter(live)
-            if len(batch) == 0:
-                return
-        self.book.register_slices(slice_ends)
+                return False
+        with flight.span("prep.resolve"):
+            live = self.book.live_mask(slice_ends)
+            if live is not None:
+                key_ids, slice_ends = key_ids[live], slice_ends[live]
+                batch = batch.filter(live)
+                if len(batch) == 0:
+                    return
+            self.book.register_slices(slice_ends)
 
-        # route to owning shard, bucket into [P, B] blocks
-        shards = self._route(key_ids)
+            # route to owning shard, bucket into [P, B] blocks
+            shards = self._route(key_ids)
         from flink_tpu.runtime.local_agg import (
             is_partial_batch,
             partial_leaf_values,
@@ -2303,15 +2313,18 @@ class MeshWindowEngine(MeshSpillSupport):
         # per-shard slot assignment (host)
         B = key_block.shape[1]
         slot_block = np.zeros((self.P, B), dtype=np.int32)
-        for p in range(self.P):
-            c = int(counts[p])
-            if not c:
-                continue
-            self._reserve(p, key_block[p, :c], ns_block[p, :c])
-            slot_block[p, :c] = self.indexes[p].lookup_or_insert(
-                key_block[p, :c], ns_block[p, :c])
-            self._dirty[p, slot_block[p, :c]] = True
-            self._rep_mark(p, slot_block[p, :c])
+        with flight.span("prep.resolve") as resolve:
+            inserted = self._pairs_inserted()
+            for p in range(self.P):
+                c = int(counts[p])
+                if not c:
+                    continue
+                self._reserve(p, key_block[p, :c], ns_block[p, :c])
+                slot_block[p, :c] = self.indexes[p].lookup_or_insert(
+                    key_block[p, :c], ns_block[p, :c])
+                self._dirty[p, slot_block[p, :c]] = True
+                self._rep_mark(p, slot_block[p, :c])
+            resolve.work = self._pairs_inserted() - inserted
 
         step = self._valued_scatter_step if partial else self._scatter_step
         with self._device_span():
@@ -2322,6 +2335,11 @@ class MeshWindowEngine(MeshSpillSupport):
             )
         self._push_dispatch_fence()
 
+    def _pairs_inserted(self) -> int:
+        """(key, slice) pairs the shards' host indexes have given a slot
+        so far (one batch's growth is its ``prep.resolve`` work)."""
+        return sum(idx.pairs_inserted for idx in self.indexes)
+
     def _process_batch_device(self, key_ids, slice_ends, shards, values,
                               leaves, partial: bool) -> None:
         """Device-shuffle ingest: the host resolves slots (the index is
@@ -2330,34 +2348,37 @@ class MeshWindowEngine(MeshSpillSupport):
         exchange+scatter program (segment sort + all_to_all + scatter,
         one XLA program) routes them to their owner shards."""
         n = len(key_ids)
-        # per-shard grouping for the HOST index work only: one stable
-        # argsort over the destinations, contiguous slices per shard
-        order = np.argsort(shards, kind="stable")
-        counts = np.bincount(shards, minlength=self.P)
-        offsets = np.zeros(self.P + 1, dtype=np.int64)
-        np.cumsum(counts, out=offsets[1:])
-        s_keys = key_ids[order]
-        s_ns = slice_ends[order]
-        if self._spill_active:
-            touched = {
-                p: np.unique(s_ns[offsets[p]:offsets[p + 1]])
-                for p in range(self.P) if counts[p]}
-            self._ensure_resident(touched)
-            for p, nss in touched.items():
-                self._touch(p, nss.tolist())
-        slots_sorted = np.empty(n, dtype=np.int32)
-        for p in range(self.P):
-            a, b = int(offsets[p]), int(offsets[p + 1])
-            if a == b:
-                continue
-            self._reserve(p, s_keys[a:b], s_ns[a:b])
-            slots = self.indexes[p].lookup_or_insert(
-                s_keys[a:b], s_ns[a:b])
-            slots_sorted[a:b] = slots
-            self._dirty[p, slots] = True
-            self._rep_mark(p, slots)
-        rec_slots = np.empty(n, dtype=np.int32)
-        rec_slots[order] = slots_sorted
+        with flight.span("prep.resolve") as resolve:
+            inserted = self._pairs_inserted()
+            # per-shard grouping for the HOST index work only: one stable
+            # argsort over the destinations, contiguous slices per shard
+            order = np.argsort(shards, kind="stable")
+            counts = np.bincount(shards, minlength=self.P)
+            offsets = np.zeros(self.P + 1, dtype=np.int64)
+            np.cumsum(counts, out=offsets[1:])
+            s_keys = key_ids[order]
+            s_ns = slice_ends[order]
+            if self._spill_active:
+                touched = {
+                    p: np.unique(s_ns[offsets[p]:offsets[p + 1]])
+                    for p in range(self.P) if counts[p]}
+                self._ensure_resident(touched)
+                for p, nss in touched.items():
+                    self._touch(p, nss.tolist())
+            slots_sorted = np.empty(n, dtype=np.int32)
+            for p in range(self.P):
+                a, b = int(offsets[p]), int(offsets[p + 1])
+                if a == b:
+                    continue
+                self._reserve(p, s_keys[a:b], s_ns[a:b])
+                slots = self.indexes[p].lookup_or_insert(
+                    s_keys[a:b], s_ns[a:b])
+                slots_sorted[a:b] = slots
+                self._dirty[p, slots] = True
+                self._rep_mark(p, slots)
+            rec_slots = np.empty(n, dtype=np.int32)
+            rec_slots[order] = slots_sorted
+            resolve.work = self._pairs_inserted() - inserted
         # pipelining: claim a dispatch slot BEFORE rewriting the pooled
         # flat staging buffers (their previous consumer must have
         # finished — the same fence discipline as the host blocks)
@@ -2378,27 +2399,37 @@ class MeshWindowEngine(MeshSpillSupport):
                 stage_two_level_exchange,
             )
 
-            with flight.span("prep.stage"):
+            with flight.span("prep.stage") as stage:
                 dst, staged, w1, w2 = stage_two_level_exchange(
                     shards, self.host_topology, columns=columns,
                     fills=fills, pool=self._shuffle_pool,
                     traffic=self._exchange2_traffic)
+                stage.work = dst.nbytes + sum(c.nbytes for c in staged)
             s1, s2 = (self._exchange2_valued if partial
                       else self._exchange2_steps)
-            with self._device_span(), flight.span("exchange.stage1"):
+            # bytes of one record's exchanged columns: each stage sends
+            # a [destinations, width] block of them per source shard
+            row = sum(c.nbytes for c in staged) // len(dst)
+            topo = self.host_topology
+            hosts, local = topo.num_hosts, topo.local_devices
+            with flight.span("exchange.stage1") as x1, self._device_span():
                 put = jax.device_put((dst, *staged), self._sharding)
                 inter = s1(put[0], put[1], tuple(put[2:]), w1)
-            with self._device_span(), flight.span("exchange.stage2"):
+                x1.work = self.P * local * w1 * row
+            with flight.span("exchange.stage2") as x2, self._device_span():
                 self.accs = s2(self.accs, inter[0], inter[1],
                                tuple(inter[2:]), w2)
+                x2.work = self.P * hosts * w2 * row
         else:
-            dst, staged, width = stage_device_exchange(
-                shards, self.P,
-                columns=columns,
-                fills=fills,
-                pool=self._shuffle_pool,
-            )
-            with self._device_span():
+            with flight.span("prep.stage") as stage:
+                dst, staged, width = stage_device_exchange(
+                    shards, self.P,
+                    columns=columns,
+                    fills=fills,
+                    pool=self._shuffle_pool,
+                )
+                stage.work = dst.nbytes + sum(c.nbytes for c in staged)
+            with self._device_span() as dispatch:
                 # ONE host->device hop for the whole batch: every flat
                 # column in a single device_put against the key-group
                 # sharding
@@ -2407,6 +2438,12 @@ class MeshWindowEngine(MeshSpillSupport):
                         else self._exchange_scatter_step)
                 self.accs = step(self.accs, put[0], put[1],
                                  tuple(put[2:]), width)
+                # through all_to_all: every shard sends each of the P
+                # destinations a [width] bucket of every exchanged
+                # column (the block shapes at dispatch; no pass over
+                # the batch)
+                dispatch.work = self.P * self.P * width * (
+                    sum(c.nbytes for c in staged) // len(dst))
         # "crash mid-batch after the fused dispatch": the scatter is in
         # flight on the device queue, the host dies before the fence —
         # the hardest restore case for the device data plane
@@ -2425,8 +2462,10 @@ class MeshWindowEngine(MeshSpillSupport):
     def on_watermark(self, watermark: int,
                      async_ok: bool = False) -> List[RecordBatch]:
         self._wd_boundary()
-        with flight.fire_span(watermark):
+        with flight.fire_span(watermark) as fire:
+            staged = self._fire_matrix_bytes
             out = self._on_watermark_inner(watermark, async_ok)
+            fire.work = self._fire_matrix_bytes - staged
         # replica publish AFTER the fires/frees of this boundary (and
         # outside the fire span — it is serving-plane work, budgeted
         # under its own serving.replica_publish span)
@@ -2450,7 +2489,8 @@ class MeshWindowEngine(MeshSpillSupport):
             # the donated reset is device-queue-ordered BEHIND the fire
             # kernels dispatched above, so a deferred (async) host read
             # of the fire outputs never races the frees
-            self._free_slices(expired)
+            with flight.span("slice.retire", faults=True) as retire:
+                retire.work = self._free_slices(expired)
         return out
 
     def _fire_window(self, window_end: int,
@@ -2470,21 +2510,9 @@ class MeshWindowEngine(MeshSpillSupport):
         per_shard_keys: List[np.ndarray] = []
         w_max = 0
         for p in range(self.P):
-            idx = self.indexes[p]
-            chunks = [(i, idx.slots_for_namespace(se))
-                      for i, se in enumerate(slice_ends)]
-            chunks = [(i, s) for i, s in chunks if len(s) > 0]
-            if not chunks:
-                per_shard_mats.append(np.zeros((0, k), dtype=np.int32))
-                per_shard_keys.append(np.empty(0, dtype=np.int64))
-                continue
-            all_slots = np.concatenate([s for _, s in chunks])
-            all_sidx = np.concatenate(
-                [np.full(len(s), i, dtype=np.int32) for i, s in chunks])
-            all_keys = idx.slot_key[all_slots]
-            keys, inv = np.unique(all_keys, return_inverse=True)
-            mat = np.zeros((len(keys), k), dtype=np.int32)
-            mat[inv, all_sidx] = all_slots
+            with flight.span("fire.shard", shard=p) as resolve:
+                keys, mat, resolve.work = self._shard_slice_matrix(
+                    p, slice_ends)
             per_shard_mats.append(mat)
             per_shard_keys.append(keys)
             w_max = max(w_max, len(keys))
@@ -2495,6 +2523,7 @@ class MeshWindowEngine(MeshSpillSupport):
         sm = np.zeros((self.P, W, k), dtype=np.int32)
         for p, mat in enumerate(per_shard_mats):
             sm[p, : len(mat)] = mat
+        self._fire_matrix_bytes += sm.nbytes
         fire_out = self._fire_step(self.accs, self._put_sharded(sm))
         names = sorted(fire_out.keys())
         projector = self.fire_projector
@@ -2537,6 +2566,28 @@ class MeshWindowEngine(MeshSpillSupport):
                                watchdog=self._watchdog)
         # sync path still batches all columns into ONE device_get
         return build(self._harvest_get([fire_out[n] for n in names]))
+
+    def _shard_slice_matrix(self, p: int, slice_ends):
+        """One shard's fire-path resolve: ``(keys, [num_keys, k] slot
+        matrix, live (key, slice) cells gathered)`` over the shard's
+        resident slices of a window — missing cells point at the
+        identity slot 0."""
+        k = len(slice_ends)
+        idx = self.indexes[p]
+        chunks = [(i, idx.slots_for_namespace(se))
+                  for i, se in enumerate(slice_ends)]
+        chunks = [(i, s) for i, s in chunks if len(s) > 0]
+        if not chunks:
+            return (np.empty(0, dtype=np.int64),
+                    np.zeros((0, k), dtype=np.int32), 0)
+        all_slots = np.concatenate([s for _, s in chunks])
+        all_sidx = np.concatenate(
+            [np.full(len(s), i, dtype=np.int32) for i, s in chunks])
+        all_keys = idx.slot_key[all_slots]
+        keys, inv = np.unique(all_keys, return_inverse=True)
+        mat = np.zeros((len(keys), k), dtype=np.int32)
+        mat[inv, all_sidx] = all_slots
+        return keys, mat, len(all_slots)
 
     def _fire_window_hybrid(self, window_end: int,
                             slice_ends) -> Optional[RecordBatch]:
@@ -2628,7 +2679,9 @@ class MeshWindowEngine(MeshSpillSupport):
         cols.update(merged_cols)
         return RecordBatch(cols)
 
-    def _free_slices(self, ends: List[int]) -> None:
+    def _free_slices(self, ends: List[int]) -> int:
+        """Erase the expired slices' pairs from every shard's host index
+        and reset their device rows; returns the pairs erased."""
         f_max = 0
         freed: List[Optional[np.ndarray]] = []
         self._freed_ns.append(np.asarray(list(ends), dtype=np.int64))
@@ -2640,7 +2693,7 @@ class MeshWindowEngine(MeshSpillSupport):
                 self._dirty[p, slots] = False
                 f_max = max(f_max, len(slots))
         if f_max == 0:
-            return
+            return 0
         F = sticky_bucket(f_max, getattr(self, "_reset_bucket", 0))
         self._reset_bucket = F
         block = np.zeros((self.P, F), dtype=np.int32)
@@ -2648,6 +2701,7 @@ class MeshWindowEngine(MeshSpillSupport):
             if slots is not None:
                 block[p, : len(slots)] = slots
         self.accs = self._reset_step(self.accs, self._put_sharded(block))
+        return sum(len(slots) for slots in freed if slots is not None)
 
     # ---------------------------------------------------------- point query
 

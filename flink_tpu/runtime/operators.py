@@ -20,6 +20,7 @@ from typing import Any, Callable, Dict, List, Optional
 import numpy as np
 
 from flink_tpu.core.records import KEY_ID_FIELD, RecordBatch
+from flink_tpu.observe import flight_recorder as flight
 from flink_tpu.runtime.elements import Watermark
 from flink_tpu.runtime.watermarks import WatermarkValve
 from flink_tpu.state.keygroups import hash_keys_to_i64
@@ -440,12 +441,18 @@ class WindowAggOperator(Operator):
             fence = fence_src() if fence_src is not None else None
             if fence is not None:
                 self._fences.append(fence)
-                while len(self._fences) > self._max_dispatch_ahead:
-                    # flint: disable=TRC01 -- the depth-bounded fence
-                    # drain IS the task loop's dispatch-ahead
-                    # backpressure point (blocks only past the bound)
-                    self._fences.popleft().block_until_ready()
+                if len(self._fences) > self._max_dispatch_ahead:
+                    self._await_fences()
         return []
+
+    def _await_fences(self) -> None:
+        """The task loop's dispatch-ahead backpressure point: the host
+        waits for the device here, and only past the bound."""
+        with flight.span("device.fence_wait"):
+            while len(self._fences) > self._max_dispatch_ahead:
+                # flint: disable=TRC01 -- the depth-bounded fence drain
+                # IS the backpressure point (blocks only past the bound)
+                self._fences.popleft().block_until_ready()
 
     def process_watermark(self, watermark, input_index=0):
         from flink_tpu.runtime.elements import MAX_WATERMARK
@@ -858,7 +865,9 @@ class SinkOperator(Operator):
         self.sink.open(ctx.operator_index)
 
     def process_batch(self, batch, input_index=0):
-        self.sink.write(batch)
+        with flight.span("sink.write") as span:
+            self.sink.write(batch)
+            span.work = len(batch)
         return []
 
     def snapshot_state(self):

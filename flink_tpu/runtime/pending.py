@@ -69,10 +69,12 @@ class PendingFire:
         # D2H results never land (link loss mid-coalesced-harvest)
         chaos.fault_point("harvest.pending_fire",
                           arrays=len(self.arrays))
-        with flight.span("fire.harvest"):
+        with flight.span("fire.harvest") as span:
             if self.watchdog is not None:
                 with self.watchdog.section("pending_harvest"):
                     host = jax.device_get(self.arrays)
             else:
                 host = jax.device_get(self.arrays)
-            return self.build([np.asarray(a) for a in host])
+            host = [np.asarray(a) for a in host]
+            span.work = sum(a.nbytes for a in host)
+            return self.build(host)

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from flink_tpu.core.annotations import internal
+from flink_tpu.observe import flight_recorder as flight
 from flink_tpu.ops.segment_ops import (
     pad_i32,
     sticky_bucket,
@@ -225,47 +226,42 @@ class PaneTable:
     def scatter_flat(self, flat: np.ndarray,
                      values: Tuple[np.ndarray, ...],
                      valued: bool = False) -> None:
-        """Scatter with a prebuilt flat index (see ingest_indices)."""
-        size = sticky_bucket(len(flat), self._scatter_bucket)
-        self._scatter_bucket = size
-        if valued:
-            from flink_tpu.ops.segment_ops import pad_values
+        """Scatter with a prebuilt flat index (see ingest_indices): pad
+        to the sticky bucket (``prep.stage``; work: the bytes handed to
+        the device, padding included), then dispatch."""
+        with flight.span("prep.stage") as stage:
+            size = sticky_bucket(len(flat), self._scatter_bucket)
+            self._scatter_bucket = size
+            padded_flat = pad_i32(flat, size, fill=0)
+            if valued:
+                from flink_tpu.ops.segment_ops import pad_values
 
-            self.accs = self._scatter2d_valued(
-                self.accs, pad_i32(flat, size, fill=0),
-                tuple(pad_values(np.asarray(v, dtype=l.dtype), size,
-                                 l.identity)
-                      for v, l in zip(values, self.agg.leaves)))
-        else:
-            self.accs = self._scatter2d(
-                self.accs, pad_i32(flat, size, fill=0),
-                self.agg.pad_input_values(values, size))
+                step = self._scatter2d_valued
+                padded_vals = tuple(
+                    pad_values(np.asarray(v, dtype=l.dtype), size,
+                               l.identity)
+                    for v, l in zip(values, self.agg.leaves))
+            else:
+                step = self._scatter2d
+                padded_vals = self.agg.pad_input_values(values, size)
+            stage.work = padded_flat.nbytes + sum(
+                v.nbytes for v in padded_vals)
+        with flight.span("device.dispatch"):
+            self.accs = step(self.accs, padded_flat, padded_vals)
 
     def upsert(self, key_ids: np.ndarray, slice_ends: np.ndarray,
-               values: Tuple[np.ndarray, ...], slice_plan=None) -> None:
-        flat = self._flat_indices(key_ids, slice_ends, slice_plan)
-        size = sticky_bucket(len(flat), self._scatter_bucket)
-        self._scatter_bucket = size
-        self.accs = self._scatter2d(
-            self.accs,
-            pad_i32(flat, size, fill=0),
-            self.agg.pad_input_values(values, size))
+               values: Tuple[np.ndarray, ...], slice_plan=None,
+               valued: bool = False) -> None:
+        with flight.span("prep.resolve"):
+            flat = self._flat_indices(key_ids, slice_ends, slice_plan)
+        self.scatter_flat(flat, values, valued)
 
     def upsert_valued(self, key_ids: np.ndarray, slice_ends: np.ndarray,
                       values: Tuple[np.ndarray, ...],
                       slice_plan=None) -> None:
         """Fold locally pre-aggregated partials (every leaf valued; see
         flink_tpu.runtime.local_agg)."""
-        from flink_tpu.ops.segment_ops import pad_values
-
-        flat = self._flat_indices(key_ids, slice_ends, slice_plan)
-        size = sticky_bucket(len(flat), self._scatter_bucket)
-        self._scatter_bucket = size
-        self.accs = self._scatter2d_valued(
-            self.accs,
-            pad_i32(flat, size, fill=0),
-            tuple(pad_values(np.asarray(v, dtype=l.dtype), size, l.identity)
-                  for v, l in zip(values, self.agg.leaves)))
+        self.upsert(key_ids, slice_ends, values, slice_plan, valued=True)
 
     # ------------------------------------- incremental pane pre-aggregation
 
@@ -484,22 +480,30 @@ class PaneTable:
 
     # ----------------------------------------------------------------- frees
 
-    def free_slices(self, slice_ends: List[int]) -> None:
+    def free_slices(self, slice_ends: List[int]) -> int:
+        """Reset and release the expired slices' ring rows; returns how
+        many rows went (what ``slice.retire`` counts in this layout)."""
+        freed = 0
         for se in slice_ends:
             row = self.slice_row.pop(int(se), None)
             if row is None:
                 continue
+            freed += 1
             self.accs = self._reset_row(self.accs, row)
             self._free_rows.append(row)
             self._dirty_slices.discard(int(se))
             self._freed_ns.append(int(se))
         self._maybe_compact()
+        return freed
 
     #: alias so PaneWindower shares SliceSharedWindower.on_watermark
     free_namespaces = free_slices
 
     #: no spill tier in the pane layout (the slot layout covers that)
     spill = frozenset()
+    #: and no host-built slot matrix: a fire hands the device nothing
+    #: (the slot layout counts its matrices' bytes under this name)
+    fire_matrix_bytes = 0
 
     _COMPACT_MIN_KEYS = 4096
 

@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from flink_tpu.observe import flight_recorder as flight
 from flink_tpu.state.keygroups import assign_key_groups
 from flink_tpu.stateplane import flat_fence
 from flink_tpu.windowing.aggregates import AggregateFunction
@@ -178,6 +179,10 @@ class _NamespaceRegistry:
         #: entirely (the session tables: one row per ns, millions of ns;
         #: registry upkeep was O(sessions) Python per batch)
         self._track_ns = track
+        #: (key, namespace) pairs ``lookup_or_insert`` has given a new
+        #: slot so far (the table states one batch's growth as its
+        #: ``prep.resolve`` span's work)
+        self.pairs_inserted = 0
 
     @property
     def namespaces(self) -> List[int]:
@@ -287,6 +292,7 @@ class HostSlotIndex(_NamespaceRegistry):
                 self.slot_ns[slot] = pair[1]
                 self.slot_used[slot] = True
                 new_by_ns.setdefault(pair[1], []).append(slot)
+                self.pairs_inserted += 1
             uslots[j] = slot
         if self._track_ns:
             for ns, slots in new_by_ns.items():
@@ -460,8 +466,13 @@ class NativeSlotIndex(_NamespaceRegistry):
             if self.on_grow is not None:
                 self.on_grow(old_cap, self.capacity)
         new_mask = is_new.view(bool)
-        if new_mask.any() and self._track_ns:
+        if not new_mask.any():
+            return out
+        if not self._track_ns:
+            self.pairs_inserted += int(np.count_nonzero(new_mask))
+        else:
             new_slots = out[new_mask]
+            self.pairs_inserted += len(new_slots)
             new_ns = nss[new_mask]
             # group new slots by namespace: sort + split (O(n log n), not a
             # per-namespace mask scan)
@@ -865,6 +876,10 @@ class SlotTable:
         #: entry-granular analog of _freed_ns for incremental snapshots
         self._freed_pairs: List[Tuple[np.ndarray, np.ndarray]] = []
         self._gather_bucket = 0
+        #: bytes of padded fire slot matrices handed to the device so
+        #: far (the windower states the growth over one watermark
+        #: advance as its ``fire.dispatch`` span's work)
+        self.fire_matrix_bytes = 0
 
     # ------------------------------------------------------------- memory
 
@@ -1127,6 +1142,7 @@ class SlotTable:
         scatter_valued instead of the map_input scatter."""
         emit = self.scatter_valued if valued else self.scatter
         namespaces = np.asarray(namespaces, dtype=np.int64)
+        resolve = self._resolve
         if self.max_device_slots:
             # slots are consumed per unique (key, ns) PAIR, not per record
             # — chunk only when the pair working set exceeds the budget
@@ -1148,18 +1164,28 @@ class SlotTable:
                 for g in groups:
                     mask = np.isin(namespaces, g)
                     pmask = np.isin(pair_ns, g)
-                    slots = self.lookup_or_insert(
+                    slots = resolve(
                         key_ids[mask], namespaces[mask],
                         _pairs=(pair_k[pmask], pair_ns[pmask]))
                     emit(slots, tuple(np.asarray(v)[mask]
                                       for v in values))
                 return
-            slots = self.lookup_or_insert(key_ids, namespaces,
-                                          _pairs=(pair_k, pair_ns))
+            slots = resolve(key_ids, namespaces, _pairs=(pair_k, pair_ns))
             emit(slots, values)
             return
-        slots = self.lookup_or_insert(key_ids, namespaces)
+        slots = resolve(key_ids, namespaces)
         emit(slots, values)
+
+    def _resolve(self, key_ids, namespaces, _pairs=None) -> np.ndarray:
+        """``lookup_or_insert`` under a ``prep.resolve`` span whose work
+        is the pairs newly given a slot (the index's own count: no pass
+        over the batch is added to find the distinct ones)."""
+        with flight.span("prep.resolve") as span:
+            before = self.index.pairs_inserted
+            slots = self.lookup_or_insert(key_ids, namespaces,
+                                          _pairs=_pairs)
+            span.work = self.index.pairs_inserted - before
+        return slots
 
     # ------------------------------------------------------------ spill tier
 
@@ -1292,17 +1318,29 @@ class SlotTable:
             self._slot_touch = np.concatenate(
                 [self._slot_touch, np.zeros(new - old, dtype=np.int64)])
 
-    def scatter(self, slots: np.ndarray, values: Tuple[np.ndarray, ...]) -> None:
-        """Accumulate a batch: one donated XLA scatter per leaf."""
+    def _staged_scatter(self, slots: np.ndarray, pad_vals, step) -> None:
+        """Pad to the sticky bucket (``prep.stage``; work: the bytes
+        handed to the device, padding included), then dispatch ``step``
+        (``device.dispatch``)."""
         n = len(slots)
         if n == 0:
             return
-        self._dirty[slots] = True
-        size = sticky_bucket(n, self._scatter_bucket)
-        self._scatter_bucket = size
-        padded_slots = pad_i32(slots, size, fill=0)
-        padded_vals = self.agg.pad_input_values(values, size)
-        self.accs = self.agg._scatter_jit(self.accs, padded_slots, padded_vals)
+        with flight.span("prep.stage") as stage:
+            self._dirty[slots] = True
+            size = sticky_bucket(n, self._scatter_bucket)
+            self._scatter_bucket = size
+            padded_slots = pad_i32(slots, size, fill=0)
+            padded_vals = pad_vals(size)
+            stage.work = padded_slots.nbytes + sum(
+                v.nbytes for v in padded_vals)
+        with flight.span("device.dispatch"):
+            self.accs = step(self.accs, padded_slots, padded_vals)
+
+    def scatter(self, slots: np.ndarray, values: Tuple[np.ndarray, ...]) -> None:
+        """Accumulate a batch: one donated XLA scatter per leaf."""
+        self._staged_scatter(
+            slots, lambda size: self.agg.pad_input_values(values, size),
+            self.agg._scatter_jit)
 
     def make_fence(self):
         """A tiny non-donated device value enqueued AFTER everything
@@ -1320,18 +1358,11 @@ class SlotTable:
         """Merge pre-aggregated partials: every leaf valued, each folded
         by its own reduce kind (two-phase aggregation's global side). Pad
         lanes carry each leaf's identity into the reserved slot 0."""
-        n = len(slots)
-        if n == 0:
-            return
-        self._dirty[slots] = True
-        size = sticky_bucket(n, self._scatter_bucket)
-        self._scatter_bucket = size
-        padded_slots = pad_i32(slots, size, fill=0)
-        padded_vals = tuple(
-            pad_values(np.asarray(v, dtype=l.dtype), size, l.identity)
-            for v, l in zip(values, self.agg.leaves))
-        self.accs = self.agg._scatter_valued_jit(
-            self.accs, padded_slots, padded_vals)
+        self._staged_scatter(
+            slots, lambda size: tuple(
+                pad_values(np.asarray(v, dtype=l.dtype), size, l.identity)
+                for v, l in zip(values, self.agg.leaves)),
+            self.agg._scatter_valued_jit)
 
     def upsert_valued(self, key_ids: np.ndarray, namespaces: np.ndarray,
                       values: Tuple[np.ndarray, ...]) -> None:
@@ -1346,18 +1377,11 @@ class SlotTable:
         """Changelog fold: values carry their sign (+accumulate /
         -retract), every leaf valued (see AggregateFunction.map_input_signed).
         Pad lanes contribute 0 to the reserved identity slot."""
-        n = len(slots)
-        if n == 0:
-            return
-        self._dirty[slots] = True
-        size = sticky_bucket(n, self._scatter_bucket)
-        self._scatter_bucket = size
-        padded_slots = pad_i32(slots, size, fill=0)
-        padded_vals = tuple(
-            pad_values(np.asarray(v, dtype=l.dtype), size, 0)
-            for v, l in zip(values, self.agg.leaves))
-        self.accs = self.agg._scatter_signed_jit(
-            self.accs, padded_slots, padded_vals)
+        self._staged_scatter(
+            slots, lambda size: tuple(
+                pad_values(np.asarray(v, dtype=l.dtype), size, 0)
+                for v, l in zip(values, self.agg.leaves)),
+            self.agg._scatter_signed_jit)
 
     # ------------------------------------------------------------- fire path
 
@@ -1390,6 +1414,7 @@ class SlotTable:
         self._fire_bucket = wp
         padded = np.zeros((wp, k), dtype=np.int32)
         padded[:w] = slot_matrix
+        self.fire_matrix_bytes += padded.nbytes
         return padded
 
     def fire_projected(self, slot_matrix: np.ndarray, keys: np.ndarray,
@@ -1590,8 +1615,9 @@ class SlotTable:
         self.accs = self.agg._reset_jit(self.accs,
                                         pad_i32(slots, size, fill=0))
 
-    def free_namespaces(self, namespaces: List[int]) -> None:
-        """Release all slots of the given namespaces (windows fully fired)."""
+    def free_namespaces(self, namespaces: List[int]) -> int:
+        """Release all slots of the given namespaces (windows fully
+        fired); returns how many (key, namespace) pairs were erased."""
         slots = self.index.free_namespaces(namespaces)
         self._freed_ns.extend(int(n) for n in namespaces)
         if self._paged:
@@ -1605,11 +1631,12 @@ class SlotTable:
             for ns in namespaces:
                 self._ns_touch.pop(int(ns), None)
         if slots is None:
-            return
+            return 0
         self._dirty[slots] = False
         size = sticky_bucket(len(slots), self._reset_bucket)
         self._reset_bucket = size
         self.accs = self.agg._reset_jit(self.accs, pad_i32(slots, size, fill=0))
+        return len(slots)
 
     # ------------------------------------------------------------ point query
 

@@ -27,6 +27,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from flink_tpu.core.records import KEY_ID_FIELD, TIMESTAMP_FIELD, RecordBatch
+from flink_tpu.observe import flight_recorder as flight
 from flink_tpu.runtime.local_agg import is_partial_batch, partial_leaf_values
 from flink_tpu.state.slot_table import SlotTable
 from flink_tpu.windowing.aggregates import AggregateFunction
@@ -73,6 +74,9 @@ class SliceSharedWindower:
     #: on_watermark(async_ok=True) may return PendingFire handles (the
     #: hosting operator/executor owns harvest + watermark holdback)
     supports_async_fires = True
+    #: per-engine batch sequence: the flight recorder's batch_id (the
+    #: windower numbers its batches as the mesh engines do)
+    _flight_batch = 0
 
     def __init__(
         self,
@@ -104,43 +108,53 @@ class SliceSharedWindower:
         n = len(batch)
         if n == 0:
             return
+        self._flight_batch += 1
+        with flight.ingest_span(self._flight_batch) as ingest:
+            ingest.work = n
+            self._ingest(batch)
+
+    def _values_of(self, batch: RecordBatch):
+        """``(values, valued)`` of a batch for the scatter: explicit
+        per-leaf partials for locally pre-aggregated rows (two-phase
+        agg), else the aggregate's mapped raw inputs."""
+        with flight.span("prep.stage"):
+            if is_partial_batch(batch):
+                return partial_leaf_values(batch, self.agg), True
+            return self.agg.map_input(batch), False
+
+    def _ingest(self, batch: RecordBatch) -> None:
         fused = getattr(self.table, "ingest_indices", None)
         if fused is not None:
-            out = fused(batch.key_ids, batch.timestamps,
-                        self.assigner.offset, self.assigner.slice_width)
+            with flight.span("prep.resolve"):
+                out = fused(batch.key_ids, batch.timestamps,
+                            self.assigner.offset, self.assigner.slice_width)
+                if out is not None:
+                    flat, uniq, sinv = out
+                    self._register_fused(uniq, sinv)
             if out is not None:
-                flat, uniq, sinv = out
-                self._register_fused(uniq, sinv)
-                if is_partial_batch(batch):
-                    self.table.scatter_flat(
-                        flat, partial_leaf_values(batch, self.agg),
-                        valued=True)
-                else:
-                    self.table.scatter_flat(flat,
-                                            self.agg.map_input(batch))
+                values, valued = self._values_of(batch)
+                self.table.scatter_flat(flat, values, valued=valued)
                 return
-        slice_ends = self.assigner.assign_slice_ends(batch.timestamps)
-        live = self.book.live_mask(slice_ends)
-        if live is not None:
-            slice_ends = slice_ends[live]
-            batch = batch.filter(live)
-            if len(batch) == 0:
-                return
-        # one O(n) pass finds the distinct slice ends + inverse; shared by
-        # the bookkeeper AND the state table so neither re-sorts the batch
-        plan = self.assigner.slice_plan(slice_ends)
-        self.book.register_slices(slice_ends, uniq=plan[0])
+        with flight.span("prep.resolve"):
+            slice_ends = self.assigner.assign_slice_ends(batch.timestamps)
+            live = self.book.live_mask(slice_ends)
+            if live is not None:
+                slice_ends = slice_ends[live]
+                batch = batch.filter(live)
+                if len(batch) == 0:
+                    return
+            # one O(n) pass finds the distinct slice ends + inverse;
+            # shared by the bookkeeper AND the state table so neither
+            # re-sorts the batch
+            plan = self.assigner.slice_plan(slice_ends)
+            self.book.register_slices(slice_ends, uniq=plan[0])
         accepts_plan = getattr(self.table, "accepts_slice_plan", False)
         kw = {"slice_plan": plan} if accepts_plan else {}
-        if is_partial_batch(batch):
-            # locally pre-aggregated rows (two-phase agg): fold explicit
-            # per-leaf partials instead of re-mapping raw inputs
-            self.table.upsert_valued(
-                batch.key_ids, slice_ends,
-                partial_leaf_values(batch, self.agg), **kw)
+        values, valued = self._values_of(batch)
+        if valued:
+            self.table.upsert_valued(batch.key_ids, slice_ends, values, **kw)
         else:
-            self.table.upsert(batch.key_ids, slice_ends,
-                              self.agg.map_input(batch), **kw)
+            self.table.upsert(batch.key_ids, slice_ends, values, **kw)
 
     def _register_fused(self, uniq: np.ndarray, sinv: np.ndarray) -> None:
         """Bookkeeping for the fused ingest path. Late records are NOT
@@ -170,18 +184,22 @@ class SliceSharedWindower:
         are device-queue-ordered behind them, so deferring the host read
         never races the reset."""
         out: List[RecordBatch] = []
-        while True:
-            w_end = self.book.next_window(watermark)
-            if w_end is None:
-                break
-            batch = self._fire_window(w_end, async_ok=async_ok)
-            if batch is not None and (not hasattr(batch, "__len__")
-                                      or len(batch) > 0):
-                out.append(batch)
-            self.book.mark_fired(w_end)
-        expired = self.book.expired_slices(watermark)
-        if expired:
-            self.table.free_namespaces(expired)
+        with flight.fire_span(watermark) as fire:
+            staged = self.table.fire_matrix_bytes
+            while True:
+                w_end = self.book.next_window(watermark)
+                if w_end is None:
+                    break
+                batch = self._fire_window(w_end, async_ok=async_ok)
+                if batch is not None and (not hasattr(batch, "__len__")
+                                          or len(batch) > 0):
+                    out.append(batch)
+                self.book.mark_fired(w_end)
+            expired = self.book.expired_slices(watermark)
+            if expired:
+                with flight.span("slice.retire", faults=True) as retire:
+                    retire.work = self.table.free_namespaces(expired)
+            fire.work = self.table.fire_matrix_bytes - staged
         return out
 
     def _wrap_pending(self, pending, window_end: int):
@@ -235,18 +253,26 @@ class SliceSharedWindower:
             cols.update(results)
             return RecordBatch(cols)
         k = len(slice_ends)
-        if k == 1:
-            # single-slice (tumbling) fast path: no cross-slice unique
-            slots = self.table.slots_for_namespace(slice_ends[0])
-            if len(slots) == 0:
-                return None
-            keys = self.table.keys_of_slots(slots)
-            matrix = slots[:, None].astype(np.int32)
-        else:
-            keys, matrix = self.table.build_slice_matrix(
-                [int(se) for se in slice_ends])
-            if keys is None:
-                return None
+        # shard 0: a single device is a mesh of one (the mesh engines
+        # record one fire.shard per shard's resolve)
+        with flight.span("fire.shard", shard=0) as resolve:
+            if k == 1:
+                # single-slice (tumbling) fast path: no cross-slice unique
+                slots = self.table.slots_for_namespace(slice_ends[0])
+                if len(slots) == 0:
+                    return None
+                keys = self.table.keys_of_slots(slots)
+                matrix = slots[:, None].astype(np.int32)
+                resolve.work = len(slots)
+            else:
+                ends = [int(se) for se in slice_ends]
+                keys, matrix = self.table.build_slice_matrix(ends)
+                if keys is None:
+                    return None
+                # live (key, slice) cells gathered: the registry's own
+                # per-slice lists, merged by the call above
+                resolve.work = sum(
+                    len(self.table.slots_for_namespace(se)) for se in ends)
         if self.fire_projector is not None:
             if async_ok:
                 return self._wrap_pending(
@@ -387,50 +413,45 @@ class PaneWindower(SliceSharedWindower):
 
     # --------------------------------------------------------------- ingest
 
-    def process_batch(self, batch: RecordBatch) -> None:
+    def _ingest(self, batch: RecordBatch) -> None:
         if not self._preagg:
-            return super().process_batch(batch)
-        n = len(batch)
-        if n == 0:
-            return
+            return super()._ingest(batch)
         table = self.table
-        flat = uniq = sinv = None
-        fused = getattr(table, "ingest_indices", None)
-        if fused is not None:
-            out = fused(batch.key_ids, batch.timestamps,
-                        self.assigner.offset, self.assigner.slice_width)
-            if out is not None:
-                flat, uniq, sinv = out
-                self._register_fused(uniq, sinv)
-        if flat is None:
-            slice_ends = self.assigner.assign_slice_ends(batch.timestamps)
-            live = self.book.live_mask(slice_ends)
-            if live is not None:
-                slice_ends = slice_ends[live]
-                batch = batch.filter(live)
-                if len(batch) == 0:
-                    return
-            plan = self.assigner.slice_plan(slice_ends)
-            self.book.register_slices(slice_ends, uniq=plan[0])
-            uniq, sinv = plan
-            flat = table._flat_indices(batch.key_ids, slice_ends, plan)
-        # combine-on-absorb: fold each record into its pending windows'
-        # partial rows in the SAME scatter. Only windows that already
-        # have a row get direct folds — everything else (new windows,
-        # late re-registrations, restored/compacted state) is refolded
-        # from the authoritative panes right after.
-        pending = self.book.pending_windows()
-        wins = [[w for w in self.assigner.window_ends_for_slice(int(se))
-                 if w in pending and table.has_window_partial(w)]
-                for se in uniq.tolist()]
-        win = table.window_flat(flat % np.int32(table.capacity), sinv,
-                                wins)
-        if is_partial_batch(batch):
-            table.scatter_combined(
-                flat, win, partial_leaf_values(batch, self.agg),
-                valued=True)
-        else:
-            table.scatter_combined(flat, win, self.agg.map_input(batch))
+        with flight.span("prep.resolve"):
+            flat = uniq = sinv = None
+            fused = getattr(table, "ingest_indices", None)
+            if fused is not None:
+                out = fused(batch.key_ids, batch.timestamps,
+                            self.assigner.offset, self.assigner.slice_width)
+                if out is not None:
+                    flat, uniq, sinv = out
+                    self._register_fused(uniq, sinv)
+            if flat is None:
+                slice_ends = self.assigner.assign_slice_ends(
+                    batch.timestamps)
+                live = self.book.live_mask(slice_ends)
+                if live is not None:
+                    slice_ends = slice_ends[live]
+                    batch = batch.filter(live)
+                    if len(batch) == 0:
+                        return
+                plan = self.assigner.slice_plan(slice_ends)
+                self.book.register_slices(slice_ends, uniq=plan[0])
+                uniq, sinv = plan
+                flat = table._flat_indices(batch.key_ids, slice_ends, plan)
+            # combine-on-absorb: fold each record into its pending
+            # windows' partial rows in the SAME scatter. Only windows
+            # that already have a row get direct folds — everything else
+            # (new windows, late re-registrations, restored/compacted
+            # state) is refolded from the authoritative panes right after.
+            pending = self.book.pending_windows()
+            wins = [[w for w in self.assigner.window_ends_for_slice(int(se))
+                     if w in pending and table.has_window_partial(w)]
+                    for se in uniq.tolist()]
+            win = table.window_flat(flat % np.int32(table.capacity), sinv,
+                                    wins)
+        values, valued = self._values_of(batch)
+        table.scatter_combined(flat, win, values, valued=valued)
         table.rebuild_window_partials(pending)
 
     # ----------------------------------------------------------------- fire

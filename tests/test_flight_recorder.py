@@ -5,6 +5,7 @@ reference test model: the reference's metric/trace reporting tests
 per-batch recorder of flink_tpu.observe.flight_recorder.
 """
 
+import threading
 import time
 
 import numpy as np
@@ -13,7 +14,6 @@ import pytest
 from flink_tpu.observe import KNOWN_SPAN_KINDS
 from flink_tpu.observe import flight_recorder as flight
 from flink_tpu.observe.export import (
-    breakdown_from_kind_totals,
     chrome_trace,
     register_flight_metrics,
     validate_trace_schema,
@@ -156,25 +156,213 @@ class TestChromeExport:
         assert validate_trace_schema(no_batch, KNOWN_SPAN_KINDS)
 
 
-class TestBreakdown:
-    def test_host_prep_excludes_device_and_fence(self):
-        kt = {
-            "batch.ingest": {"total_s": 10.0},
-            "device.dispatch": {"total_s": 3.0},
-            "device.fence_wait": {"total_s": 2.0},
-            "fire.dispatch": {"total_s": 1.5},
-            "fire.harvest": {"total_s": 0.5},
-        }
-        b = breakdown_from_kind_totals(kt, wall_s=20.0)
-        assert b["host_prep_s"] == pytest.approx(5.0)
-        assert b["device_step_s"] == pytest.approx(6.5)
-        assert b["harvest_s"] == pytest.approx(0.5)
-        assert b["host_prep_fraction"] == pytest.approx(0.25)
+def _nested(rec):
+    # host prep excludes device and fence: they are children
+    with flight.span("batch.ingest") as outer:
+        with flight.span("prep.stage"):
+            time.sleep(0.001)
+        with flight.span("device.dispatch"):
+            with flight.span("fire.harvest"):
+                time.sleep(0.001)
+        with flight.span("device.fence_wait"):
+            time.sleep(0.001)
+        time.sleep(0.001)
+    return outer
 
-    def test_empty_totals_zero_breakdown(self):
-        b = breakdown_from_kind_totals({}, wall_s=1.0)
-        assert b["host_prep_s"] == 0.0
-        assert b["device_step_s"] == 0.0
+
+def _external_child(rec):
+    with flight.span("batch.ingest") as outer:
+        time.sleep(0.003)
+        # timed by the caller, recorded after the fact: [now - d, now]
+        flight.instant("device.dispatch", duration_s=0.002)
+    return outer
+
+
+def _external_child_clipped(rec):
+    with flight.span("batch.ingest") as outer:
+        # reported longer than the enclosing span has been open (a
+        # compile that began before it): only the overlap is a child
+        flight.instant("xla.compile", duration_s=5.0)
+        time.sleep(0.001)
+    return outer
+
+
+def _child_on_another_thread(rec):
+    def other():
+        with flight.span("fire.harvest"):
+            time.sleep(0.002)
+
+    with flight.span("batch.ingest") as outer:
+        t = threading.Thread(target=other)
+        t.start()
+        t.join()
+    return outer
+
+
+def _nothing(rec):
+    return None
+
+
+class TestSelfTime:
+    """``self_s`` = a span's duration minus what its children on the
+    same thread covered — what the bench drivers' "host prep =
+    batch.ingest - device - fence" subtraction used to approximate."""
+
+    @pytest.mark.parametrize("case", [
+        _nested, _external_child, _external_child_clipped,
+        _child_on_another_thread, _nothing], ids=lambda f: f.__name__)
+    def test_self_is_total_minus_children_on_the_thread(self, rec, case):
+        outer = case(rec)
+        kt = rec.kind_totals()
+        if outer is None:
+            # an empty recorder aggregates to nothing, not to zeros
+            assert kt == {}
+            return
+        recs = rec.snapshot()
+        ingest = next(r for r in recs if r.kind == "batch.ingest")
+        assert ingest.duration_s == pytest.approx(outer.duration_s)
+        me = threading.current_thread().name
+        covered = sum(
+            min(r.duration_s, r.t1 - ingest.t0) for r in recs
+            if r.parent == "batch.ingest" and r.thread == me)
+        got = kt["batch.ingest"]
+        assert got["total_s"] == pytest.approx(ingest.duration_s)
+        assert got["self_s"] == pytest.approx(
+            ingest.duration_s - covered, abs=1e-9)
+        assert 0.0 < got["self_s"] <= got["total_s"]
+        if case is _nested:
+            # grandchildren are the child's, not the parent's
+            assert kt["device.dispatch"]["self_s"] == pytest.approx(
+                kt["device.dispatch"]["total_s"]
+                - kt["fire.harvest"]["total_s"], abs=1e-9)
+            assert covered == pytest.approx(
+                kt["prep.stage"]["total_s"]
+                + kt["device.dispatch"]["total_s"]
+                + kt["device.fence_wait"]["total_s"], abs=1e-9)
+        if case is _external_child:
+            assert covered == pytest.approx(0.002)
+            assert kt["device.dispatch"]["self_s"] == pytest.approx(0.002)
+        if case is _external_child_clipped:
+            assert covered < 0.1
+        if case is _child_on_another_thread:
+            assert covered == 0.0
+            assert got["self_s"] == pytest.approx(got["total_s"])
+            assert kt["fire.harvest"]["total_s"] >= 0.002
+
+    def test_parent_in_records_and_chrome_trace(self, rec):
+        with flight.span("op.watermark", job="p-job"):
+            with flight.span("fire.dispatch", job="p-job"):
+                flight.instant("d2h.transfer", job="p-job")
+        by_kind = {r.kind: r for r in rec.snapshot() if r.job == "p-job"}
+        assert by_kind["op.watermark"].parent is None
+        assert by_kind["fire.dispatch"].parent == "op.watermark"
+        assert by_kind["d2h.transfer"].parent == "fire.dispatch"
+        trace = chrome_trace(list(by_kind.values()))
+        args = {e["name"]: e["args"] for e in trace["traceEvents"]
+                if e["ph"] != "M"}
+        assert "parent" not in args["op.watermark"]
+        assert args["fire.dispatch"]["parent"] == "op.watermark"
+        assert args["d2h.transfer"]["parent"] == "fire.dispatch"
+
+    def test_work_summed_per_kind(self, rec):
+        for n in (3, 4):
+            with flight.span("sink.write") as s:
+                s.work = n
+        flight.instant("serving.cache_hit", work=5)
+        with flight.span("prep.stage"):
+            pass
+        kt = rec.kind_totals()
+        assert kt["sink.write"]["work"] == 7
+        assert kt["serving.cache_hit"]["work"] == 5
+        assert kt["prep.stage"]["work"] == 0
+        works = sorted(r.work for r in rec.snapshot()
+                       if r.kind == "sink.write")
+        assert works == [3, 4]
+        trace = chrome_trace(rec.snapshot())
+        assert sorted(e["args"]["work"] for e in trace["traceEvents"]
+                      if e.get("name") == "sink.write") == [3, 4]
+
+    def test_span_that_raises_still_pops_the_stack(self, rec):
+        with flight.span("op.process"):
+            with pytest.raises(RuntimeError):
+                with flight.span("batch.ingest"):
+                    raise RuntimeError("boom")
+            # the failed span is closed: the next one is op.process's
+            # child, and nothing is left open underneath it
+            with flight.span("sink.write"):
+                pass
+        assert rec._ring().open is None
+        by_kind = {r.kind: r for r in rec.snapshot()}
+        assert by_kind["batch.ingest"].parent == "op.process"
+        assert by_kind["sink.write"].parent == "op.process"
+        kt = rec.kind_totals()
+        assert kt["op.process"]["self_s"] == pytest.approx(
+            kt["op.process"]["total_s"] - kt["batch.ingest"]["total_s"]
+            - kt["sink.write"]["total_s"], abs=1e-9)
+
+    def test_page_faults_summed_for_spans_that_ask(self, rec):
+        import mmap
+
+        def touch_fresh_pages():
+            # a new anonymous mapping: every page written faults once
+            with mmap.mmap(-1, 1 << 22) as m:
+                m.write(b"\1" * (1 << 22))
+
+        with flight.span("slice.retire", faults=True):
+            touch_fresh_pages()
+        with flight.span("fire.shard"):
+            touch_fresh_pages()
+        kt = rec.kind_totals()
+        assert kt["slice.retire"]["minor_faults"] >= 2
+        assert kt["slice.retire"]["major_faults"] >= 0
+        assert kt["fire.shard"]["minor_faults"] == 0
+
+    def test_recorder_off_spans_swallow_work_and_timed_ones_time(self, rec):
+        with flight.disabled():
+            with flight.span("sink.write") as s:
+                s.work = 9
+            assert s.work == 0 and s.duration_s == 0.0
+            with flight.span("op.process", timed=True) as t:
+                time.sleep(0.001)
+            assert t.duration_s >= 0.001
+        assert rec.kind_totals() == {}
+
+    def test_lifecycle_spans_mirrored_into_a_profiler_session(
+            self, rec, tmp_path):
+        import glob
+
+        import jax
+
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with jax.profiler.TraceAnnotation("bench.process_batch"):
+                with flight.span("batch.ingest"):
+                    with flight.span("prep.resolve"):
+                        time.sleep(0.001)
+            with flight.span("checkpoint.write"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        with flight.span("batch.ingest"):   # no session: not mirrored
+            pass
+        (path,) = glob.glob(str(
+            tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+        data = jax.profiler.ProfileData.from_file(path)
+        rows = {}
+        for plane in data.planes:
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith(("flink.", "bench.")):
+                        rows.setdefault(ev.name, []).append(
+                            (ev.start_ns, ev.start_ns + ev.duration_ns))
+        assert sorted(rows) == ["bench.process_batch",
+                                "flink.batch.ingest", "flink.prep.resolve"]
+        (bench,), (ingest,), (resolve,) = (
+            rows["bench.process_batch"], rows["flink.batch.ingest"],
+            rows["flink.prep.resolve"])
+        # one clock: the program's spans nest inside the benchmark's
+        assert bench[0] <= ingest[0] <= resolve[0]
+        assert resolve[1] <= ingest[1] <= bench[1]
 
 
 class TestMetricExport:

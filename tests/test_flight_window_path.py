@@ -1,0 +1,148 @@
+"""The single-device window path as the flight recorder sees it: a small
+NEXmark-Q5-shaped job (keyBy -> HOP window -> COUNT with a device top-k
+fire -> sink) through ``env.execute()``, every span kind of the batch and
+fire lifecycle recorded where the work happens, with counts that match
+batches and fired windows, children inside their parents, and a bounded
+number of spans per batch and per fired window (the overhead budget, as a
+count — a timing belongs to the chip)."""
+
+from collections import Counter, defaultdict
+
+import pytest
+
+from flink_tpu import Configuration, StreamExecutionEnvironment
+from flink_tpu.connectors.sinks import CollectSink
+from flink_tpu.connectors.sources import DataGenSource
+from flink_tpu.observe import flight_recorder as flight
+from flink_tpu.runtime.watermarks import WatermarkStrategy
+from flink_tpu.windowing.aggregates import CountAggregate
+from flink_tpu.windowing.assigners import SlidingEventTimeWindows
+from flink_tpu.windowing.fire_projectors import TopKFireProjector
+
+BATCH = 4096
+BATCHES = 10
+KEYS = 500
+TOP_K = 4
+
+
+def run_q5(layout):
+    env = StreamExecutionEnvironment(Configuration({
+        "execution.micro-batch.size": BATCH,
+        "state.window-layout": layout}))
+    sink = CollectSink()
+    (env.add_source(
+        DataGenSource(total_records=BATCH * BATCHES, num_keys=KEYS,
+                      events_per_second_of_eventtime=10_000),
+        WatermarkStrategy.for_bounded_out_of_orderness(0))
+        .key_by("key")
+        .window(SlidingEventTimeWindows.of(1000, 200))
+        .aggregate(CountAggregate(),
+                   fire_projector=TopKFireProjector("count", k=TOP_K))
+        .sink_to(sink))
+    rec = flight.recorder()
+    rec.clear()
+    env.execute("q5-" + layout)
+    rows = sink.rows()
+    windows = len({r["window_end"] for r in rows})
+    return rec.kind_totals(), rec.snapshot(), rows, windows
+
+
+@pytest.fixture(scope="module", params=["slots", "panes"])
+def q5(request):
+    return (request.param,) + run_q5(request.param)
+
+
+def test_every_kind_is_recorded_with_the_count_of_its_boundary(q5):
+    layout, kt, _, rows, windows = q5
+    assert windows > 10 and len(rows) == windows * TOP_K
+    # ingest: one batch.ingest per micro-batch, stating its events
+    assert kt["batch.ingest"]["count"] == BATCHES
+    assert kt["batch.ingest"]["work"] == BATCH * BATCHES
+    # slots: slice planning in the windower + the slot lookup in the
+    # table; panes: one fused index build
+    per_batch = 2 if layout == "slots" else 1
+    assert kt["prep.resolve"]["count"] == per_batch * BATCHES
+    assert kt["prep.stage"]["count"] == 2 * BATCHES
+    assert kt["device.dispatch"]["count"] == BATCHES
+    # bytes handed over: one padded int32 index per event, COUNT sends
+    # no value column (at least the batch, at most the 4x sticky bucket;
+    # panes fold each event into its pane and its 5 windows' partials)
+    copies = 1 if layout == "slots" else 6
+    assert BATCH * BATCHES * 4 <= kt["prep.stage"]["work"] \
+        <= copies * 4 * BATCH * BATCHES * 4
+    assert 0 < kt["device.fence_wait"]["count"] <= BATCHES
+    # fire: one fire.dispatch per watermark advance of the window
+    # operator, one harvest and one sink write per fired window
+    assert windows / 5 <= kt["fire.dispatch"]["count"] \
+        <= kt["op.watermark"]["count"]
+    assert kt["fire.harvest"]["count"] == windows
+    assert kt["fire.harvest"]["work"] > 0
+    assert kt["sink.write"]["count"] == windows
+    assert kt["sink.write"]["work"] == len(rows)
+    assert 0 < kt["slice.retire"]["count"] <= kt["fire.dispatch"]["count"]
+    if layout == "slots":
+        assert kt["fire.shard"]["count"] == windows
+        # every (key, slice) pair given a slot is erased again by the
+        # end-of-input flush, and each is gathered by the 5 windows
+        # its slice belongs to
+        pairs = kt["prep.resolve"]["work"]
+        assert 0 < pairs == kt["slice.retire"]["work"]
+        assert kt["fire.shard"]["work"] == 5 * pairs
+        # the padded slot matrices: 5 int32 slots per row, >= 64 rows
+        assert kt["fire.dispatch"]["work"] >= windows * 64 * 5 * 4
+    else:
+        assert "fire.shard" not in kt
+        assert kt["slice.retire"]["work"] > 0      # ring rows freed
+
+
+def test_children_lie_inside_their_parents(q5):
+    _, kt, records, _, _ = q5
+    parents = Counter((r.kind, r.parent) for r in records if not r.instant)
+    want = {"batch.ingest": "op.process", "prep.resolve": "batch.ingest",
+            "prep.stage": "batch.ingest", "device.dispatch": "batch.ingest",
+            "device.fence_wait": "op.process", "sink.write": "op.process",
+            "fire.dispatch": "op.watermark", "fire.shard": "fire.dispatch",
+            "slice.retire": "fire.dispatch"}
+    for (kind, parent), _ in parents.items():
+        if kind in want:
+            assert parent == want[kind], (kind, parent)
+    for kind, t in kt.items():
+        assert 0.0 <= t["self_s"] <= t["total_s"] + 1e-12, kind
+    # batch by batch: what the children cover fits in the ingest span
+    covered = defaultdict(float)
+    for r in records:
+        if r.parent == "batch.ingest":
+            covered[r.batch_id] += r.duration_s
+    ingests = [r for r in records if r.kind == "batch.ingest"]
+    assert sorted(r.batch_id for r in ingests) == list(
+        range(1, BATCHES + 1))
+    for r in ingests:
+        assert 0.0 < covered[r.batch_id] <= r.duration_s
+    # and over the run: the parts and the remainder make the whole
+    parts = sum(kt[k]["total_s"] for k in
+                ("prep.resolve", "prep.stage", "device.dispatch")) \
+        + sum(r.duration_s for r in records
+              if r.kind == "xla.compile" and r.parent == "batch.ingest")
+    assert parts + kt["batch.ingest"]["self_s"] == pytest.approx(
+        kt["batch.ingest"]["total_s"], rel=1e-6)
+    fire_children = sum(
+        r.duration_s for r in records if r.parent == "fire.dispatch"
+        and not r.instant)
+    assert fire_children + kt["fire.dispatch"]["self_s"] == pytest.approx(
+        kt["fire.dispatch"]["total_s"], rel=1e-6)
+
+
+def test_the_span_budget_per_batch_and_per_fired_window(q5):
+    _, kt, records, _, windows = q5
+    ingest_side = ("batch.ingest", "prep.resolve", "prep.stage",
+                   "device.dispatch", "device.fence_wait")
+    fire_side = ("fire.dispatch", "fire.shard", "slice.retire",
+                 "fire.harvest", "sink.write")
+    assert sum(kt[k]["count"] for k in ingest_side) <= 8 * BATCHES
+    assert sum(kt[k]["count"] for k in fire_side if k in kt) \
+        <= 4 * windows + 2 * BATCHES
+    # everything the operator thread times (compiles aside: a warm
+    # process has none): at most 12 spans per batch and 10 per fire
+    spans = sum(1 for r in records
+                if not r.instant and r.kind != "xla.compile")
+    assert spans <= 12 * BATCHES + 10 * windows

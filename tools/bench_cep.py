@@ -83,10 +83,9 @@ def _drive(engine, total, seed):
     """Keyed batches at RATE ev/s of event time, a trailing-watermark
     fire after every batch, and a final drain fire. Returns (events,
     matches, emit-latency samples, wall seconds, breakdown) with the
-    breakdown rolled up from this pass's flight-recorder spans (the
-    shared ``observe.export.span_rollup`` — same primitive as the
-    session and join rows, so the matrix attributes time the same
-    way everywhere)."""
+    breakdown read from this pass's flight-recorder span aggregates
+    (``kind_totals()`` — as the session and join rows read theirs, so
+    the matrix attributes time the same way everywhere)."""
     from flink_tpu.core.records import (
         KEY_ID_FIELD,
         TIMESTAMP_FIELD,
@@ -129,16 +128,16 @@ def _drive(engine, total, seed):
         wm = min(wm + step, t)
         matches += sum(len(b) for b in engine.on_watermark(wm))
     dt = time.perf_counter() - t0
-    from flink_tpu.observe.export import span_rollup
-
     # the CEP engine emits ingest/fire/harvest spans but no
     # device.dispatch/fence pair (yet), so — like the join row — no
     # host_prep_s line: report only what the spans attribute
-    breakdown = span_rollup(rec.kind_totals(), dt, {
-        "ingest_s": "batch.ingest",
-        "advance_fire_s": "fire.dispatch",
-        "harvest_s": "fire.harvest",
-    })
+    kt = rec.kind_totals()
+    breakdown = {
+        name: round(kt.get(kind, {}).get("total_s", 0.0), 3)
+        for name, kind in (("ingest_s", "batch.ingest"),
+                           ("advance_fire_s", "fire.dispatch"),
+                           ("harvest_s", "fire.harvest"))}
+    breakdown["total_s"] = round(dt, 3)
     return events, matches, lat, dt, breakdown
 
 

@@ -115,13 +115,13 @@ def _drive(engine, total, num_keys, rate, band_ms, seed):
     # the join engines don't (yet) emit device.dispatch/fence spans,
     # so a host_prep_s line here would claim their inline device work
     # as host time — report only what the spans actually attribute
-    from flink_tpu.observe.export import span_rollup
-
-    breakdown = span_rollup(rec.kind_totals(), dt, {
-        "ingest_s": "batch.ingest",
-        "probe_fire_s": "fire.dispatch",
-        "harvest_s": "fire.harvest",
-    })
+    kt = rec.kind_totals()
+    breakdown = {
+        name: round(kt.get(kind, {}).get("total_s", 0.0), 3)
+        for name, kind in (("ingest_s", "batch.ingest"),
+                           ("probe_fire_s", "fire.dispatch"),
+                           ("harvest_s", "fire.harvest"))}
+    breakdown["total_s"] = round(dt, 3)
     return events, matches, lat, dt, breakdown
 
 

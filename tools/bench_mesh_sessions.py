@@ -34,18 +34,19 @@ programs, then BENCH_MESH_REPS (default 3) measured reps; the headline
 is the MEDIAN rep, with ``best_events_per_s`` / ``rep_events_per_s`` as
 secondary fields. Each rep also reports a host-prep vs device-step vs
 harvest wall-time breakdown plus the spill counters. The breakdown is
-DERIVED FROM FLIGHT-RECORDER SPANS (``observe.flight_recorder`` +
-``observe.export.breakdown_from_kind_totals``), not private driver
+DERIVED FROM FLIGHT-RECORDER SPANS (``observe.flight_recorder``'s
+``kind_totals()``: per-kind totals and self times), not private driver
 timers — the host-prep gate, a captured Perfetto trace and the
 dashboard all read the same measurements, so they cannot disagree.
-Host-prep attribution is unchanged from the timer era: device work
+Host prep is the SELF time of the ingest path's host spans
+(``batch.ingest``, ``prep.meta_sweep``, ``prep.stage``): device work
 surfacing inside ``process_batch`` — fence blocks
 (``device.fence_wait``) plus inline device interactions
 (``device.dispatch``: the fused exchange dispatch, eviction gathers +
-D2H, reload puts; the CPU backend executes them inline) — counts as
-``device_step_s``, so ``host_prep_s`` / ``host_prep_fraction`` (the
-gated number) measure genuine host work: sessionization, slot
-resolution, flat staging. ``harvest_s`` now counts ALL D2H
+D2H, reload puts; the CPU backend executes them inline) — are child
+spans, so their time is not in it and counts as ``device_step_s``;
+``host_prep_s`` / ``host_prep_fraction`` (the gated number) measure
+genuine host work: sessionization, slot resolution, flat staging. ``harvest_s`` now counts ALL D2H
 materializations — including ones nested inside device interactions —
 so it can overlap ``device_step_s`` (the timer era reported only the
 post-loop drain there), and ``device_step_s`` includes the
@@ -129,7 +130,6 @@ def run(total: int, mesh, batch: int = 1 << 16, zipf: float = 0.0,
         RecordBatch,
     )
     from flink_tpu.observe import flight_recorder as flight
-    from flink_tpu.observe.export import breakdown_from_kind_totals
     from flink_tpu.parallel.sharded_sessions import MeshSessionEngine
     from flink_tpu.windowing.aggregates import SumAggregate
 
@@ -275,12 +275,12 @@ def run(total: int, mesh, batch: int = 1 << 16, zipf: float = 0.0,
         t_drain = time.perf_counter() - t5
         dt = time.perf_counter() - t0
         lat.sort()
-        # the breakdown comes FROM the recorder's span aggregates (see
-        # observe.export.breakdown_from_kind_totals for the attribution
-        # contract): host_prep = ingest spans minus the device.dispatch
-        # and device.fence_wait spans recorded under them — the same
-        # numbers a captured Perfetto trace of this pass shows
-        breakdown = breakdown_from_kind_totals(rec.kind_totals(), dt)
+        # the breakdown comes FROM the recorder's span aggregates:
+        # host_prep = self time of the ingest path's host spans (the
+        # device.dispatch / device.fence_wait spans under them are
+        # children, so their time is not in it) — the same numbers a
+        # captured Perfetto trace of this pass shows
+        breakdown = _breakdown(rec.kind_totals(), dt)
         # of which: time inside the NATIVE metadata sweeps (absorb /
         # shard-group / route / pop — 0.0 on the pure-Python plane);
         # pop sweeps land in the fire bucket, so this line can exceed
@@ -321,6 +321,33 @@ def run(total: int, mesh, batch: int = 1 << 16, zipf: float = 0.0,
                 fire_latency, skew)
     finally:
         gc.enable()
+
+
+def _breakdown(kind_totals, wall_s):
+    """Host-prep / device / harvest wall-time breakdown from the flight
+    recorder's per-kind aggregates. Buckets may overlap (a harvest
+    nested in a device interaction counts in both) and do not sum to
+    ``total_s``: they attribute, they do not partition."""
+
+    def stat(field, *kinds):
+        return sum(kind_totals.get(k, {}).get(field, 0.0) for k in kinds)
+
+    host_prep = stat("self_s", "batch.ingest", "prep.meta_sweep",
+                     "prep.stage")
+    device_in_prep = stat("total_s", "device.dispatch",
+                          "device.fence_wait")
+    return {
+        "host_prep_s": round(host_prep, 3),
+        "meta_sweep_s": round(stat("total_s", "prep.meta_sweep"), 3),
+        "stage_s": round(stat("total_s", "prep.stage"), 3),
+        "device_step_s": round(
+            device_in_prep + stat("total_s", "fire.dispatch"), 3),
+        "harvest_s": round(stat("total_s", "fire.harvest"), 3),
+        "device_in_prep_s": round(device_in_prep, 3),
+        "total_s": round(wall_s, 3),
+        "host_prep_fraction": round(host_prep / wall_s, 4)
+        if wall_s > 0 else 0.0,
+    }
 
 
 def main_zipf(mesh, P, total, reps_n, native_plane):
