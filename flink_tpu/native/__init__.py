@@ -250,8 +250,13 @@ def load_slotmap() -> Optional[ctypes.CDLL]:
         lib.sm_lookup.argtypes = [vp, i64, P(i64), P(i64), P(i32)]
         lib.sm_verify.restype = None
         lib.sm_verify.argtypes = [vp, i64, P(i64), P(i64), P(i32), P(i32)]
-        lib.sm_group_rows.restype = i64
-        lib.sm_group_rows.argtypes = [P(i64), i64, P(i64), P(i32)]
+        lib.sm_carry_create.restype = vp
+        lib.sm_carry_create.argtypes = []
+        lib.sm_carry_destroy.restype = None
+        lib.sm_carry_destroy.argtypes = [vp]
+        lib.sm_carry_advance.restype = i64
+        lib.sm_carry_advance.argtypes = [vp, i64, i64, i64, P(i32), P(i64),
+                                         P(i32), P(i64), P(i64), P(i32)]
         lib.sm_pane_ingest.restype = i32
         lib.sm_pane_ingest.argtypes = [vp, i64, P(i64), P(i64), i64, i64,
                                        i64, P(i32), P(u8), P(i32), P(i64),
@@ -525,29 +530,3 @@ def build_report() -> str:
     missing = sorted(n for n, ok in built.items() if not ok)
     return ("NATIVE: SKIPPED (no compiler or build failed: "
             + ", ".join(missing) + ")")
-
-
-def group_matrix(keys, slots, sidx, n_slices: int):
-    """(unique keys, [K, n_slices] slot matrix) grouped by key in O(n)
-    via the native hash table — the window-fire matrix build (absent
-    cells stay at identity slot 0). The matrix is allocated RIGHT-SIZED
-    at K distinct keys (the native call only assigns row ids), so the
-    memory cost matches the np.unique path it replaces. Returns None
-    when the native library is unavailable (callers fall back)."""
-    import numpy as np
-
-    lib = load_slotmap()
-    if lib is None:
-        return None
-    n = len(keys)
-    keys = np.ascontiguousarray(keys, dtype=np.int64)
-    out_keys = np.empty(n, dtype=np.int64)
-    row_of = np.empty(n, dtype=np.int32)
-    c = ctypes
-    rows = lib.sm_group_rows(
-        keys.ctypes.data_as(c.POINTER(c.c_int64)), n,
-        out_keys.ctypes.data_as(c.POINTER(c.c_int64)),
-        row_of.ctypes.data_as(c.POINTER(c.c_int32)))
-    matrix = np.zeros((rows, n_slices), dtype=np.int32)
-    matrix[row_of, np.asarray(sidx)] = slots
-    return out_keys[:rows], matrix

@@ -37,6 +37,10 @@ KNOWN_SPAN_KINDS = (
     "fire.dispatch",       # watermark advance -> fire programs enqueued
     "fire.shard",          # one shard's fire-path host work (resolve,
                            # cold page extraction) — the per-shard track
+                           # (work: (key, slice) cells the call resolved
+                           # into the slot matrix: the slices that
+                           # entered where the last window's matrix was
+                           # carried on, every live cell where it was not)
     "fire.harvest",        # D2H materialization of fire/query results
                            # (work: bytes fetched)
     "slice.retire",        # expired slices' pairs erased from the host

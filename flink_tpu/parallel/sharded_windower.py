@@ -2510,9 +2510,11 @@ class MeshWindowEngine(MeshSpillSupport):
         per_shard_keys: List[np.ndarray] = []
         w_max = 0
         for p in range(self.P):
+            # each shard's index carries its (keys, slot matrix) from
+            # one window to the next; work: the cells this call resolved
             with flight.span("fire.shard", shard=p) as resolve:
-                keys, mat, resolve.work = self._shard_slice_matrix(
-                    p, slice_ends)
+                keys, mat, resolve.work = self.indexes[p].slice_matrix(
+                    slice_ends)
             per_shard_mats.append(mat)
             per_shard_keys.append(keys)
             w_max = max(w_max, len(keys))
@@ -2567,28 +2569,6 @@ class MeshWindowEngine(MeshSpillSupport):
         # sync path still batches all columns into ONE device_get
         return build(self._harvest_get([fire_out[n] for n in names]))
 
-    def _shard_slice_matrix(self, p: int, slice_ends):
-        """One shard's fire-path resolve: ``(keys, [num_keys, k] slot
-        matrix, live (key, slice) cells gathered)`` over the shard's
-        resident slices of a window — missing cells point at the
-        identity slot 0."""
-        k = len(slice_ends)
-        idx = self.indexes[p]
-        chunks = [(i, idx.slots_for_namespace(se))
-                  for i, se in enumerate(slice_ends)]
-        chunks = [(i, s) for i, s in chunks if len(s) > 0]
-        if not chunks:
-            return (np.empty(0, dtype=np.int64),
-                    np.zeros((0, k), dtype=np.int32), 0)
-        all_slots = np.concatenate([s for _, s in chunks])
-        all_sidx = np.concatenate(
-            [np.full(len(s), i, dtype=np.int32) for i, s in chunks])
-        all_keys = idx.slot_key[all_slots]
-        keys, inv = np.unique(all_keys, return_inverse=True)
-        mat = np.zeros((len(keys), k), dtype=np.int32)
-        mat[inv, all_sidx] = all_slots
-        return keys, mat, len(all_slots)
-
     def _fire_window_hybrid(self, window_end: int,
                             slice_ends) -> Optional[RecordBatch]:
         from flink_tpu.ops.segment_ops import HOST_COMBINE
@@ -2601,21 +2581,7 @@ class MeshWindowEngine(MeshSpillSupport):
         per_shard_keys: List[np.ndarray] = []
         w_max = 0
         for p in range(self.P):
-            idx = self.indexes[p]
-            chunks = [(i, idx.slots_for_namespace(int(se)))
-                      for i, se in enumerate(slice_ends)]
-            chunks = [(i, s) for i, s in chunks if len(s) > 0]
-            if not chunks:
-                per_shard_mats.append(np.zeros((0, k), dtype=np.int32))
-                per_shard_keys.append(np.empty(0, dtype=np.int64))
-                continue
-            all_slots = np.concatenate([s for _, s in chunks])
-            all_sidx = np.concatenate(
-                [np.full(len(s), i, dtype=np.int32) for i, s in chunks])
-            all_keys = idx.slot_key[all_slots]
-            keys, inv = np.unique(all_keys, return_inverse=True)
-            mat = np.zeros((len(keys), k), dtype=np.int32)
-            mat[inv, all_sidx] = all_slots
+            keys, mat, _ = self.indexes[p].slice_matrix(slice_ends)
             per_shard_mats.append(mat)
             per_shard_keys.append(keys)
             w_max = max(w_max, len(keys))

@@ -183,6 +183,9 @@ class _NamespaceRegistry:
         #: slot so far (the table states one batch's growth as its
         #: ``prep.resolve`` span's work)
         self.pairs_inserted = 0
+        #: the last fired window's slot matrix, carried to the next fire
+        #: (:meth:`slice_matrix`); made on the first fire
+        self._slice_carry = None
 
     @property
     def namespaces(self) -> List[int]:
@@ -193,10 +196,58 @@ class _NamespaceRegistry:
         if not chunks:
             return np.empty(0, dtype=np.int32)
         if len(chunks) > 1:
-            merged = np.concatenate(chunks)
-            self._ns_slots[ns] = [merged]
-            return merged
+            # merged IN PLACE: a namespace's list object lives from its
+            # first slot to its drain, which is how the carried fire
+            # matrix tells a namespace from a later one of the same name
+            chunks[:] = [np.concatenate(chunks)]
         return chunks[0]
+
+    def slice_matrix(self, slice_ends
+                     ) -> Tuple[np.ndarray, np.ndarray, int]:
+        """``(keys, [rows, k] slot matrix, cells resolved)`` over the
+        slices of a window, in the order given: one row per key that
+        holds a slot in any of them, absent (key, slice) cells at the
+        identity slot 0. Row order is arbitrary.
+
+        The matrix is carried from one call to the next. Where the
+        slices asked for are the last call's moved on by some slices (or
+        the same ones), the columns that left are dropped, rows left
+        empty go, and only the cells that entered are resolved: the new
+        slices' and whatever was appended to a kept slice since (a
+        namespace's list only ever grows at its end until it is drained,
+        so a consumed length per kept slice finds them). Anything else —
+        other slices, a kept namespace drained since (a re-made one is
+        another list object), a per-slot free — resolves every cell, from
+        nothing. Either way the result is what a rebuild gives, and the
+        arrays handed out are the caller's: no later call writes them."""
+        ends = [int(se) for se in slice_ends]
+        k = len(ends)
+        carry = self._slice_carry
+        if carry is None:
+            carry = self._slice_carry = self._new_slice_carry()
+        reg = self._ns_slots
+        shift = k
+        if len(carry.ends) == k:
+            for s in range(k):
+                if carry.ends[s:] == ends[:k - s]:
+                    shift = s
+                    break
+        if any(carry.consumed[j + shift]
+               and reg.get(ends[j]) is not carry.lists[j + shift]
+               for j in range(k - shift)):
+            shift = k
+        parts: List[Tuple[int, np.ndarray]] = []
+        lists, consumed = [], []
+        for j, se in enumerate(ends):
+            slots = self.slots_for_namespace(se)
+            seen = carry.consumed[j + shift] if j < k - shift else 0
+            if len(slots) > seen:
+                parts.append((j, slots[seen:]))
+            lists.append(reg.get(se))
+            consumed.append(len(slots))
+        carry.ends, carry.lists, carry.consumed = ends, lists, consumed
+        keys, matrix = carry.advance(k, shift, parts, self.slot_key)
+        return keys, matrix, sum(len(slots) for _, slots in parts)
 
     def _registry_drain(self, namespaces: List[int]) -> Optional[np.ndarray]:
         """Remove and return all slots registered under ``namespaces``."""
@@ -216,6 +267,8 @@ class _NamespaceRegistry:
         namespace)."""
         if not self._track_ns:
             return
+        # a list thinned in its middle is no longer append-only
+        self._slice_carry = None
         uniq, counts = np.unique(namespaces, return_counts=True)
         slots_per_ns = dict(zip(uniq.tolist(), counts.tolist()))
         for ns, freed_here in slots_per_ns.items():
@@ -236,6 +289,70 @@ class _NamespaceRegistry:
                 self._ns_slots[int(ns)] = [kept]
             else:
                 self._ns_slots.pop(int(ns), None)
+
+
+class _SliceCarry:
+    """What :meth:`_NamespaceRegistry.slice_matrix` keeps of the matrix it
+    carries: the slices of the last call, each one's list object in the
+    registry and how much of that list the matrix holds. The matrix
+    itself is the subclass's."""
+
+    def __init__(self) -> None:
+        self.ends: List[int] = []
+        self.lists: List[Optional[List[np.ndarray]]] = []
+        self.consumed: List[int] = []
+
+
+class _DictSliceCarry(_SliceCarry):
+    """The carried matrix in NumPy with a dict as the key -> row table
+    (``HostSlotIndex``; the native index has ``sm_carry_advance``)."""
+
+    def __init__(self) -> None:
+        super().__init__()
+        self._keys = np.empty(0, dtype=np.int64)
+        self._mat = np.zeros((0, 0), dtype=np.int32)
+        self._row_of: Dict[int, int] = {}
+
+    def advance(self, k: int, shift: int,
+                parts: List[Tuple[int, np.ndarray]], slot_key: np.ndarray
+                ) -> Tuple[np.ndarray, np.ndarray]:
+        """Drop the ``shift`` leftmost columns (all of them: start from
+        nothing), sweep out the rows left empty, then enter ``parts``:
+        (column, slots) runs whose keys are ``slot_key[slots]``."""
+        if shift >= k or self._mat.shape[1] != k:
+            keys = np.empty(0, dtype=np.int64)
+            mat = np.zeros((0, k), dtype=np.int32)
+            row_of: Dict[int, int] = {}
+        else:
+            keys, mat, row_of = self._keys, self._mat, self._row_of
+            if shift:
+                mat = np.concatenate(
+                    [mat[:, shift:],
+                     np.zeros((len(mat), shift), dtype=np.int32)], axis=1)
+                live = mat.any(axis=1)
+                if not live.all():
+                    keys, mat = keys[live], mat[live]
+                    row_of = dict(zip(keys.tolist(), range(len(keys))))
+        if parts:
+            slots = np.concatenate([s for _, s in parts])
+            cols = np.repeat([j for j, _ in parts],
+                             [len(s) for _, s in parts])
+            fresh: List[int] = []
+            rows = np.empty(len(slots), dtype=np.int64)
+            for i, key in enumerate(slot_key[slots].tolist()):
+                r = row_of.get(key)
+                if r is None:
+                    r = row_of[key] = len(row_of)
+                    fresh.append(key)
+                rows[i] = r
+            if fresh:
+                keys = np.concatenate(
+                    [keys, np.asarray(fresh, dtype=np.int64)])
+                mat = np.concatenate(
+                    [mat, np.zeros((len(fresh), k), dtype=np.int32)])
+            mat[rows, cols] = slots
+        self._keys, self._mat, self._row_of = keys, mat, row_of
+        return keys.copy(), mat.copy()
 
 
 class HostSlotIndex(_NamespaceRegistry):
@@ -262,6 +379,8 @@ class HostSlotIndex(_NamespaceRegistry):
         self.slot_used = np.zeros(self.capacity, dtype=bool)
         self._free: List[int] = list(range(self.capacity - 1, 0, -1))
         self._init_registry(track_namespaces)
+
+    _new_slice_carry = _DictSliceCarry
 
     @property
     def num_used(self) -> int:
@@ -394,6 +513,43 @@ _I32P = _ct.POINTER(_ct.c_int32)
 _U8P = _ct.POINTER(_ct.c_uint8)
 
 
+class _NativeSliceCarry(_SliceCarry):
+    """The carried matrix kept by ``native/slotmap.cpp``: keys, matrix and
+    a key -> row table that persists between fires. One foreign call per
+    fire (each one is a GIL hand-over on the task loop)."""
+
+    def __init__(self, lib) -> None:
+        super().__init__()
+        self._lib = lib
+        self._h = lib.sm_carry_create()
+        self._rows = 0
+
+    def __del__(self):  # pragma: no cover - finalizer
+        lib, h = getattr(self, "_lib", None), getattr(self, "_h", None)
+        if lib is not None and h:
+            lib.sm_carry_destroy(h)
+            self._h = None
+
+    def advance(self, k: int, shift: int,
+                parts: List[Tuple[int, np.ndarray]], slot_key: np.ndarray
+                ) -> Tuple[np.ndarray, np.ndarray]:
+        seg_col = np.asarray([j for j, _ in parts], dtype=np.int32)
+        seg_len = np.asarray([len(s) for _, s in parts], dtype=np.int64)
+        slots = np.concatenate(
+            [s for _, s in parts] or [np.empty(0, dtype=np.int32)]
+        ).astype(np.int32, copy=False)
+        # at most the rows held plus one new row per cell given
+        bound = (self._rows if shift < k else 0) + len(slots)
+        keys = np.empty(bound, dtype=np.int64)
+        matrix = np.empty((bound, k), dtype=np.int32)
+        self._rows = rows = self._lib.sm_carry_advance(
+            self._h, k, shift, len(parts),
+            seg_col.ctypes.data_as(_I32P), seg_len.ctypes.data_as(_I64P),
+            slots.ctypes.data_as(_I32P), slot_key.ctypes.data_as(_I64P),
+            keys.ctypes.data_as(_I64P), matrix.ctypes.data_as(_I32P))
+        return keys[:rows], matrix[:rows]
+
+
 class NativeSlotIndex(_NamespaceRegistry):
     """C++-backed drop-in for HostSlotIndex (see native/slotmap.cpp).
 
@@ -440,6 +596,9 @@ class NativeSlotIndex(_NamespaceRegistry):
         if lib is not None and h:
             lib.sm_destroy(h)
             self._h = None
+
+    def _new_slice_carry(self) -> _NativeSliceCarry:
+        return _NativeSliceCarry(self._lib)
 
     @property
     def num_used(self) -> int:
@@ -1476,32 +1635,17 @@ class SlotTable:
 
     def build_slice_matrix(self, slice_ends: List[int]
                            ) -> Tuple[Optional[np.ndarray],
-                                      Optional[np.ndarray]]:
-        """(keys, [num_keys, k] slot matrix) for the resident slices of a
-        window — missing (key, slice) cells point at the identity slot 0.
-        Shared by the device fire path and the hybrid (spill) fire path."""
-        per_slice = [(i, self.index.slots_for_namespace(se))
-                     for i, se in enumerate(slice_ends)]
-        per_slice = [(i, s) for i, s in per_slice if len(s) > 0]
-        if not per_slice:
-            return None, None
-        all_slots = np.concatenate([s for _, s in per_slice])
-        all_sidx = np.concatenate(
-            [np.full(len(s), i, dtype=np.int32) for i, s in per_slice])
-        all_keys = self.index.slot_key[all_slots]
-        from flink_tpu.native import group_matrix
-
-        # O(n) native hash grouping beats np.unique's O(n log n) sort on
-        # the per-fire hot path; keys come back in first-seen order (the
-        # fire result order is key-insensitive)
-        native = group_matrix(all_keys, all_slots.astype(np.int32),
-                              all_sidx, len(slice_ends))
-        if native is not None:
-            return native
-        keys, inv = np.unique(all_keys, return_inverse=True)
-        matrix = np.zeros((len(keys), len(slice_ends)), dtype=np.int32)
-        matrix[inv, all_sidx] = all_slots
-        return keys, matrix
+                                      Optional[np.ndarray], int]:
+        """(keys, [num_keys, k] slot matrix, cells resolved) for the
+        resident slices of a window — missing (key, slice) cells point at
+        the identity slot 0; (None, None, cells) where no key holds a
+        slot. Shared by the device fire path and the hybrid (spill) fire
+        path; the index carries the matrix from one window to the next
+        (``slice_matrix``)."""
+        keys, matrix, cells = self.index.slice_matrix(slice_ends)
+        if len(keys) == 0:
+            return None, None, cells
+        return keys, matrix, cells
 
     def fire_hybrid(self, slice_ends: List[int]
                     ) -> Tuple[np.ndarray, Dict[str, np.ndarray]]:
@@ -1520,7 +1664,7 @@ class SlotTable:
         key_chunks: List[np.ndarray] = []
         leaf_chunks: List[List[np.ndarray]] = [[] for _ in self.agg.leaves]
         # device part
-        keys, matrix = self.build_slice_matrix(resident)
+        keys, matrix, _ = self.build_slice_matrix(resident)
         if keys is not None:
             wp = sticky_bucket(len(keys), self._fire_bucket, minimum=64)
             self._fire_bucket = wp

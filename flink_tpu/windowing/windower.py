@@ -265,14 +265,13 @@ class SliceSharedWindower:
                 matrix = slots[:, None].astype(np.int32)
                 resolve.work = len(slots)
             else:
-                ends = [int(se) for se in slice_ends]
-                keys, matrix = self.table.build_slice_matrix(ends)
+                # work: the cells this call resolved — the slice that
+                # entered where the matrix was carried from the last
+                # window, every live cell of the window where it was not
+                keys, matrix, resolve.work = self.table.build_slice_matrix(
+                    [int(se) for se in slice_ends])
                 if keys is None:
                     return None
-                # live (key, slice) cells gathered: the registry's own
-                # per-slice lists, merged by the call above
-                resolve.work = sum(
-                    len(self.table.slots_for_namespace(se)) for se in ends)
         if self.fire_projector is not None:
             if async_ok:
                 return self._wrap_pending(

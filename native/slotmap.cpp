@@ -80,6 +80,67 @@ int grow(SlotMap* m) {
   return 0;
 }
 
+// The fire path's carried slot matrix (see sm_carry_advance below).
+struct SliceCarry {
+  int64_t k;             // columns: slices per window
+  int64_t rows;          // live rows, dense in [0, rows)
+  int64_t row_cap;       // rows allocated
+  int64_t* keys;         // [row_cap] the row's key
+  int32_t* mat;          // [row_cap * k] slot per (row, slice), 0 = absent
+  int64_t bucket_count;  // power of two, >= 2 * row_cap
+  int32_t* buckets;      // key -> row, -1 empty (backward-shift deletion)
+  int32_t* emptied;      // [row_cap] scratch: rows an advance left empty
+};
+
+void carry_grow(SliceCarry* c) {
+  int64_t cap = c->row_cap ? c->row_cap * 2 : 1024;
+  c->keys = (int64_t*)realloc(c->keys, sizeof(int64_t) * cap);
+  c->mat = (int32_t*)realloc(c->mat, sizeof(int32_t) * cap * c->k);
+  c->emptied = (int32_t*)realloc(c->emptied, sizeof(int32_t) * cap);
+  c->row_cap = cap;
+  int64_t bc = c->bucket_count;
+  while (bc < cap * 2) bc <<= 1;
+  if (bc == c->bucket_count) return;
+  c->bucket_count = bc;
+  free(c->buckets);
+  c->buckets = (int32_t*)malloc(sizeof(int32_t) * bc);
+  memset(c->buckets, 0xff, sizeof(int32_t) * bc);
+  uint64_t mask = (uint64_t)bc - 1;
+  for (int64_t r = 0; r < c->rows; r++) {
+    uint64_t b = mix_hash((uint64_t)c->keys[r], 0) & mask;
+    while (c->buckets[b] >= 0) b = (b + 1) & mask;
+    c->buckets[b] = (int32_t)r;
+  }
+}
+
+inline uint64_t carry_bucket_of(const SliceCarry* c, int64_t key) {
+  uint64_t mask = (uint64_t)c->bucket_count - 1;
+  uint64_t b = mix_hash((uint64_t)key, 0) & mask;
+  while (c->keys[c->buckets[b]] != key) b = (b + 1) & mask;
+  return b;
+}
+
+// Drop row r (its last cell left): its key leaves the table, and the last
+// row takes its place so the rows stay dense.
+void carry_remove_row(SliceCarry* c, int64_t r) {
+  uint64_t mask = (uint64_t)c->bucket_count - 1;
+  uint64_t hole = carry_bucket_of(c, c->keys[r]);
+  for (uint64_t j = (hole + 1) & mask; c->buckets[j] != -1;
+       j = (j + 1) & mask) {
+    uint64_t home = mix_hash((uint64_t)c->keys[c->buckets[j]], 0) & mask;
+    if (((j - home) & mask) >= ((j - hole) & mask)) {
+      c->buckets[hole] = c->buckets[j];
+      hole = j;
+    }
+  }
+  c->buckets[hole] = -1;
+  int64_t last = --c->rows;
+  if (r == last) return;
+  c->buckets[carry_bucket_of(c, c->keys[last])] = (int32_t)r;
+  c->keys[r] = c->keys[last];
+  memcpy(c->mat + r * c->k, c->mat + last * c->k, sizeof(int32_t) * c->k);
+}
+
 }  // namespace
 
 extern "C" {
@@ -447,39 +508,139 @@ void sm_flat_fuse(int64_t n, const int32_t* cols, const int32_t* sinv,
   }
 }
 
-// Assign a dense row id per DISTINCT key (first-seen order) — the O(n)
-// replacement for np.unique(..., return_inverse=True) on the per-fire
-// hot path. out_keys needs n int64s (only the first K are written),
-// out_row_of needs n int32s. Returns K, the number of distinct keys;
-// the caller allocates the [K, n_slices] fire matrix right-sized and
-// scatters with one vectorized numpy assignment.
-int64_t sm_group_rows(const int64_t* keys, int64_t n, int64_t* out_keys,
-                      int32_t* out_row_of) {
-  if (n == 0) return 0;
-  uint64_t nb = 1;
-  while (nb < (uint64_t)n * 2) nb <<= 1;
-  int64_t* tbl_key = (int64_t*)malloc(sizeof(int64_t) * nb);
-  int32_t* tbl_row = (int32_t*)malloc(sizeof(int32_t) * nb);
-  memset(tbl_row, 0xff, sizeof(int32_t) * nb);  // -1 = empty
-  int64_t rows = 0;
-  for (int64_t i = 0; i < n; i++) {
-    int64_t k = keys[i];
-    uint64_t b = mix_hash((uint64_t)k, 0) & (nb - 1);
-    for (;;) {
-      if (tbl_row[b] < 0) {
-        tbl_key[b] = k;
-        tbl_row[b] = (int32_t)rows;
-        out_keys[rows++] = k;
-        break;
-      }
-      if (tbl_key[b] == k) break;
-      b = (b + 1) & (nb - 1);
+// ---- the fire path's carried slot matrix -------------------------------
+//
+// A window's (keys, [rows, k] slot matrix) kept from one fire to the next
+// (flink_tpu/state/slot_table.py, _NamespaceRegistry.slice_matrix). A
+// sliding window shares k-1 of its k slices with the window before it, so
+// an advance drops the columns that left, probes only the cells that
+// entered into a key -> row table that persists between calls, and sweeps
+// out the rows whose last cell left. The same call from an empty carry is
+// the from-nothing rebuild. Rows stay dense (an emptied row is overwritten
+// by the last one), so row order is arbitrary; columns are the caller's
+// slice order.
+
+void* sm_carry_create() {
+  SliceCarry* c = (SliceCarry*)calloc(1, sizeof(SliceCarry));
+  c->bucket_count = 64;
+  c->buckets = (int32_t*)malloc(sizeof(int32_t) * c->bucket_count);
+  memset(c->buckets, 0xff, sizeof(int32_t) * c->bucket_count);
+  return c;
+}
+
+void sm_carry_destroy(void* h) {
+  SliceCarry* c = (SliceCarry*)h;
+  free(c->keys);
+  free(c->mat);
+  free(c->buckets);
+  free(c->emptied);
+  free(c);
+}
+
+// One fire's advance. ``shift`` columns leave on the left (shift >= k, or
+// another k than the carry holds: everything leaves — a rebuild). The
+// cells that entered come as ``n_seg`` runs of ``slots``: run s holds
+// seg_len[s] slots of column seg_col[s]; a cell's key is
+// slot_key[slot]. Writes the advanced (keys, matrix) to out_keys /
+// out_mat, which the caller sized for the carry's rows + the cells
+// given, and returns the rows written. The out arrays are the caller's:
+// the carry never touches them again.
+int64_t sm_carry_advance(void* h, int64_t k, int64_t shift, int64_t n_seg,
+                         const int32_t* seg_col, const int64_t* seg_len,
+                         const int32_t* slots, const int64_t* slot_key,
+                         int64_t* out_keys, int32_t* out_mat) {
+  SliceCarry* c = (SliceCarry*)h;
+  int64_t n_emptied = 0;
+  if (k != c->k || shift >= k) {
+    if (k != c->k) {
+      if (c->row_cap && k)
+        c->mat = (int32_t*)realloc(c->mat, sizeof(int32_t) * c->row_cap * k);
+      c->k = k;
     }
-    out_row_of[i] = tbl_row[b];
+    c->rows = 0;
+    memset(c->buckets, 0xff, sizeof(int32_t) * c->bucket_count);
+  } else if (shift > 0) {
+    // every row moves left at once (rows are contiguous: one memmove of
+    // the flat matrix), which leaves the next row's first cells in each
+    // row's tail: clear those, and note the rows left empty. Most of
+    // their keys come back with the slice that enters, so they are
+    // judged after it
+    if (c->rows)
+      memmove(c->mat, c->mat + shift,
+              sizeof(int32_t) * (c->rows * k - shift));
+    for (int64_t r = c->rows - 1; r >= 0; r--) {
+      int32_t* row = c->mat + r * k;
+      int32_t live = 0;
+      for (int64_t j = 0; j < k - shift; j++) live |= row[j];
+      for (int64_t j = k - shift; j < k; j++) row[j] = 0;
+      if (!live) c->emptied[n_emptied++] = (int32_t)r;
+    }
   }
-  free(tbl_key);
-  free(tbl_row);
-  return rows;
+  constexpr int64_t CHUNK = 256;
+  int64_t ck[CHUNK];
+  uint64_t hashes[CHUNK];
+  const int32_t* seg_slots = slots;
+  for (int64_t s = 0; s < n_seg; seg_slots += seg_len[s], s++) {
+    int64_t col = seg_col[s], n = seg_len[s];
+    for (int64_t base = 0; base < n; base += CHUNK) {
+      int64_t end = base + CHUNK < n ? base + CHUNK : n;
+      // same prefetch discipline as the probe paths: slot_key is far
+      // larger than the caches, and every gather a likely miss
+      for (int64_t i = base; i < end; i++)
+        __builtin_prefetch(&slot_key[seg_slots[i]], 0, 1);
+      for (int64_t i = base; i < end; i++) {
+        ck[i - base] = slot_key[seg_slots[i]];
+        hashes[i - base] = mix_hash((uint64_t)ck[i - base], 0);
+        __builtin_prefetch(
+            &c->buckets[hashes[i - base] & ((uint64_t)c->bucket_count - 1)],
+            0, 1);
+      }
+      for (int64_t i = base; i < end; i++) {
+        int32_t r =
+            c->buckets[hashes[i - base] & ((uint64_t)c->bucket_count - 1)];
+        if (r >= 0) {
+          __builtin_prefetch(&c->keys[r], 0, 1);
+          __builtin_prefetch(&c->mat[(int64_t)r * k + col], 1, 1);
+        }
+      }
+      for (int64_t i = base; i < end; i++) {
+        int64_t key = ck[i - base];
+        uint64_t mask = (uint64_t)c->bucket_count - 1;
+        uint64_t b = hashes[i - base] & mask;
+        int32_t r;
+        for (;;) {
+          r = c->buckets[b];
+          if (r < 0) {
+            if (c->rows == c->row_cap) {
+              carry_grow(c);
+              mask = (uint64_t)c->bucket_count - 1;
+              b = hashes[i - base] & mask;
+              continue;
+            }
+            r = (int32_t)c->rows++;
+            c->buckets[b] = r;
+            c->keys[r] = key;
+            memset(c->mat + (int64_t)r * k, 0, sizeof(int32_t) * k);
+            break;
+          }
+          if (c->keys[r] == key) break;
+          b = (b + 1) & mask;
+        }
+        c->mat[(int64_t)r * k + col] = seg_slots[i];
+      }
+    }
+  }
+  // rows still empty go, highest first: whatever lies above the one
+  // being judged is live, so the last row may fill its place
+  for (int64_t i = 0; i < n_emptied; i++) {
+    const int32_t* row = c->mat + (int64_t)c->emptied[i] * k;
+    int32_t live = 0;
+    for (int64_t j = 0; j < k; j++) live |= row[j];
+    if (!live) carry_remove_row(c, c->emptied[i]);
+  }
+  memcpy(out_keys, c->keys, sizeof(int64_t) * c->rows);
+  memcpy(out_mat, c->mat, sizeof(int32_t) * c->rows * k);
+  return c->rows;
 }
 
 }  // extern "C"

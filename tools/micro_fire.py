@@ -40,7 +40,7 @@ def timeit(label, fn, reps=10):
     print(f"{label}: {dt:.2f} ms")
 
 
-kz, matrix = table.build_slice_matrix([1000 + s for s in range(K_SLICES)])
+kz, matrix, _ = table.build_slice_matrix([1000 + s for s in range(K_SLICES)])
 print(f"matrix {matrix.shape}")
 
 timeit("build_slice_matrix", lambda: table.build_slice_matrix(
