@@ -106,19 +106,25 @@ def measure(job, cfg, mix, seed, seconds, trace_dir):
         compiles=probe.compile_count() - compiles_before,
         flight_s={kind: s - flight_before.get(kind, 0.0)
                   for kind, s in flight_seconds().items()},
-        trace_span=tracer.span if tracer else None, trace=None)
+        trace_span=tracer.span if tracer else None,
+        trace_asked_s=tracer.seconds if tracer else None, trace=None)
 
 
 def reduce_slice(run, trace_dir):
-    """Reads the slice's trace into ``run.trace`` and removes the files."""
+    """Reads the slice's trace into ``run.trace`` and removes the files.
+    ``run.trace_span`` becomes the range the reduction was taken over, on
+    the host's clock: what a reader counts beside the trace, it counts
+    over the same range."""
     if run.trace_span is None:
         raise RuntimeError("the window ended before the traced slice began")
     rows = tracing.read_events(tracing.find_xplane(trace_dir))
     shutil.rmtree(trace_dir, ignore_errors=True)
-    run.trace_window_s = run.trace_span[1] - run.trace_span[0]
-    run.trace = tracing.reduce_trace(rows, run.trace_window_s)
+    run.trace = tracing.reduce_trace(rows, run.trace_asked_s)
     if run.trace is None:
         raise RuntimeError("no operation ran on the device in the slice")
+    clock = tracing.host_clock(rows, run.trace_span)
+    run.trace_span = tuple(clock(ns) for ns in run.trace["range_ns"])
+    run.trace["range_in_window_s"] = [t - run.t0 for t in run.trace_span]
 
 
 def check(job, cfg, run, seed):
@@ -198,7 +204,7 @@ def run_cell(man, cell, cfg, mix, device, seed, seconds, trace, t_process,
     if trace:
         reduce_slice(run, trace_dir)
         device["busy_s"] = run.trace["busy_s_mean"]
-        device["window_s"] = run.trace_window_s
+        device["window_s"] = run.trace["window_s"]
 
     t_check = time.perf_counter()
     correct, attempted, failed, compared = check(job, cfg, run, seed)

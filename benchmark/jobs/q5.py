@@ -16,6 +16,15 @@ from benchmark.jobs._hash import first_index_at, splitmix64
 
 SINK_COLUMNS = ("window_end", "auction", "count")
 
+#: the job at a size a CPU test can hold: laid over a configuration's
+#: ``options`` and ``job_options`` by the harness's tests, scale cut only
+TINY = {
+    "options": {"execution.micro-batch.size": 8192,
+                "state.slot-table.capacity": 1 << 16},
+    "job_options": {"num_auctions": 1000, "event_rate": 10_000,
+                    "warmup_events": 250_000, "control_lost_events": 8192},
+}
+
 
 def make_generator(seed, o):
     """``gen(first, n, columns=None)`` -> the bids with global indices

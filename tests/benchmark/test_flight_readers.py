@@ -87,6 +87,17 @@ def test_read_takes_the_programs_recorder_as_it_stands():
     rec.clear()
 
 
+READER = "flight_stat"
+
+
+def _files_of_this_reader():
+    """Names of the ``benchmark/metrics/*.json`` files whose reader is
+    this one: counted from the files, so a later PR's metric of this
+    reader is one more file and no edit here."""
+    return {name for name in manifest._files("metrics", ".json")
+            if manifest.metric_spec(name)["reader"] == READER}
+
+
 def test_every_metric_of_this_reader_names_kinds_the_program_registers():
     from flink_tpu.observe import KNOWN_SPAN_KINDS
 
@@ -94,11 +105,21 @@ def test_every_metric_of_this_reader_names_kinds_the_program_registers():
     seen = 0
     for m in man["per_layer"]:
         spec = manifest.metric_spec(m["name"])
-        if spec["reader"] != "flight_stat":
+        if spec["reader"] != READER:
             continue
         seen += 1
         assert m["source"] == "program_span", m["name"]
         assert set(spec["args"]["kinds"]) <= set(KNOWN_SPAN_KINDS), m
         assert spec["args"]["stat"] in flight_stat.STATS
         assert spec["args"]["per"] in flight_stat.PERS
-    assert seen == 18
+    assert seen == len(_files_of_this_reader()) > 0
+
+
+def test_every_metric_file_has_its_entry_and_every_entry_its_file():
+    man = manifest.manifest()
+    entries = {m["name"] for k in ("end_to_end", "per_layer")
+               for m in man[k]}
+    files = set(manifest._files("metrics", ".json"))
+    assert files - entries == set(), "metric files with no manifest entry"
+    assert entries - files == set(), "manifest entries with no metric file"
+    assert _files_of_this_reader() <= {m["name"] for m in man["per_layer"]}

@@ -4,6 +4,7 @@ trace itself) is not tested here; the reduction is, on a recorded table.
 """
 
 import copy
+import importlib
 import io
 import json
 import os
@@ -23,27 +24,39 @@ CPU = {"platform": "cpu", "kind": "cpu", "count": 1}
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
-#: each job at a size a test can hold: the cell's own files, scale cut
-TINY = {
-    "q5": dict(
-        options={"execution.micro-batch.size": 8192,
-                 "state.slot-table.capacity": 1 << 16},
-        job_options=dict(num_auctions=1000, event_rate=10_000,
-                         warmup_events=250_000, control_lost_events=8192)),
-}
+#: the program's option that sizes the keyed state: where a job's cut
+#: names it, the state shape a configuration expects is cut with it
+CAPACITY = "state.slot-table.capacity"
+#: where an answer is born: the program's one harvest class, whatever the
+#: engine; a configuration's ``expect.harvest`` may name another
+HARVEST = "flink_tpu.runtime.pending:PendingFire.harvest"
 CELLS = [w["name"] for w in MAN["workloads"]]
 
 
 def tiny(cell_name):
+    """The cell's own files with its job's ``TINY`` cut laid over them:
+    each job at a size a test can hold, scale cut only."""
     cell, cfg, mix = runner.resolve(MAN, cell_name)
     cfg = copy.deepcopy(cfg)
-    cut = TINY[cfg["job"]]
+    cut = manifest.job(cfg["job"]).TINY
     cfg["options"].update(cut["options"])       # parallelism stays
     cfg["job_options"].update(cut["job_options"])
-    if "state_shape" in cfg["expect"]:
-        cfg["expect"]["state_shape"][-1] = \
-            cut["options"]["state.slot-table.capacity"]
+    if "state_shape" in cfg["expect"] and CAPACITY in cut["options"]:
+        cfg["expect"]["state_shape"][-1] = cut["options"][CAPACITY]
     return cell, cfg, mix
+
+
+def engine_class(expect):
+    """The class a configuration's ``expect`` names, from the module of
+    the program that ``expect`` says it lives in."""
+    return getattr(importlib.import_module(expect["engine_module"]),
+                   expect["engine"])
+
+
+def harvest_method(expect):
+    module, _, path = expect.get("harvest", HARVEST).partition(":")
+    cls, method = path.split(".")
+    return getattr(importlib.import_module(module), cls), method
 
 
 def drive(cell_name, tmp_path, seed=2_147_483_659, seconds=1.0):
@@ -83,6 +96,13 @@ def test_every_name_of_a_cell_resolves_to_a_file(cell_name):
     for fn in ("make_generator", "build", "reference_rows", "compare",
                "work", "boundary_events", "warmup_events"):
         assert callable(getattr(job, fn))
+    cut = getattr(job, "TINY", None)
+    assert cut is not None and set(cut) == {"options", "job_options"}, \
+        f"jobs/{cfg['job']}.py exports no CPU-size cut TINY = " \
+        "{'options': ..., 'job_options': ...}"
+    assert set(cut["job_options"]) <= set(cfg["job_options"])
+    assert isinstance(engine_class(cfg["expect"]), type)
+    assert isinstance(harvest_method(cfg["expect"])[0], type)
     entry = next(c for c in MAN["configs"] if c["name"] == cell["config"])
     assert entry["source"] == cfg["source"]
     assert entry["reduced"] == cfg["reduced"]
@@ -115,6 +135,18 @@ def test_an_unknown_name_is_an_error_that_lists_what_exists(finder, kind):
         finder("no-such-name")
 
 
+def _code(path):
+    """A Python file's text without its docstrings and comments."""
+    with open(path, encoding="utf-8") as f:
+        text = f.read()
+    return re.sub(r"#.*", "", re.sub(r'""".*?"""', "", text, flags=re.S))
+
+
+def _quoted(names, code):
+    return [n for n in names
+            if re.search(rf"[\"']{re.escape(n)}[\"']", code)]
+
+
 def test_the_harness_holds_no_name_and_imports_no_other_harness():
     names = ([c["name"] for c in MAN["configs"]] + CELLS
              + [m["name"] for k in ("end_to_end", "per_layer")
@@ -132,11 +164,23 @@ def test_the_harness_holds_no_name_and_imports_no_other_harness():
                 r"^\s*(import|from)\s+(chip_smoke|bench|tools)\b", text,
                 re.M), f
             if f == "run.py" or os.path.basename(folder) == "harness":
-                code = re.sub(r'""".*?"""', "", text, flags=re.S)
-                code = re.sub(r"#.*", "", code)
-                hits = [n for n in names
-                        if re.search(rf"[\"']{re.escape(n)}[\"']", code)]
+                hits = _quoted(names, _code(os.path.join(folder, f)))
                 assert not hits, (f, hits)
+
+
+def test_the_tests_hold_no_name_of_a_job_configuration_cell_or_engine():
+    """A later PR adds a job, a configuration, a cell or an engine as
+    files and manifest entries; a test keyed by what exists today would
+    need an edit it may not make. (Metric and span-kind names as sample
+    data of a reader's test are fine.)"""
+    names = ([c["name"] for c in MAN["configs"]] + CELLS
+             + list(manifest._files("jobs", ".py"))
+             + [manifest.config(MAN, c["name"])["expect"]["engine"]
+                for c in MAN["configs"]])
+    here = os.path.dirname(os.path.abspath(__file__))
+    for f in (f for f in os.listdir(here) if f.endswith(".py")):
+        hits = _quoted(names, _code(os.path.join(here, f)))
+        assert not hits, (f, hits)
 
 
 # ------------------------------------------------- (ii') the timed source
@@ -366,24 +410,19 @@ def _alter_an_answer(original, counter):
     return harvest
 
 
-#: where each engine a configuration may expect lives in the program
-ENGINES = {"SliceSharedWindower": "flink_tpu.windowing.windower",
-           "MeshWindowEngine": "flink_tpu.parallel.sharded_windower",
-           "PendingFire": "flink_tpu.runtime.pending"}
-
-
 @pytest.mark.parametrize("cell_name", CELLS)
-@pytest.mark.parametrize("cls, method, fault", [
-    (None, "process_batch", _skip_a_batch),
-    (None, "process_batch", _half_a_batch),
-    ("PendingFire", "harvest", _alter_an_answer)],
+@pytest.mark.parametrize("target, fault", [
+    ("process_batch", _skip_a_batch),
+    ("process_batch", _half_a_batch),
+    ("harvest", _alter_an_answer)],
     ids=["state-unchanged", "half-a-batch", "answer-altered"])
 def test_a_run_on_a_broken_timed_path_is_not_correct(
-        cell_name, cls, method, fault, tmp_path, monkeypatch):
-    import importlib
-
-    cls = cls or tiny(cell_name)[1]["expect"]["engine"]
-    owner = getattr(importlib.import_module(ENGINES[cls]), cls)
+        cell_name, target, fault, tmp_path, monkeypatch):
+    expect = tiny(cell_name)[1]["expect"]
+    if target == "harvest":
+        owner, method = harvest_method(expect)
+    else:
+        owner, method = engine_class(expect), target
     counter = [0]
     monkeypatch.setattr(owner, method, fault(getattr(owner, method), counter))
     r = drive(cell_name, tmp_path)
@@ -464,24 +503,106 @@ def test_busy_union_and_gaps_on_a_synthetic_interval_set():
     assert trace.union_seconds([]) == 0
 
 
+MS = 1_000_000
+NO_SPAN = trace.NO_SPAN
+DEV, HOST = "/device:TPU:0", "/host:CPU"
+OPERATOR, PUMP, TRACER = "python3#8", "python3#9", "python3#10"
+
+
+def _table(ops, modules, host):
+    """Rows from ``(name, start_ms, end_ms)`` lists; ``host`` by line."""
+    return ([(DEV, "XLA Ops", n, a * MS, (b - a) * MS) for n, a, b in ops]
+            + [(DEV, "XLA Modules", n, a * MS, (b - a) * MS)
+               for n, a, b in modules]
+            + [(DEV, "Steps", "0", 0, 2000 * MS)]
+            + [(HOST, line, n, a * MS, (b - a) * MS)
+               for line, spans in host.items() for n, a, b in spans])
+
+
+#: one second of host rows (the tracer's two marks bound it); the last
+#: device program straddles its end and one more runs wholly after it
+SYNTHETIC = _table(
+    ops=[("fusion.1", 0, 100), ("scatter.2", 400, 600),
+         ("fusion.1", 600, 650), ("copy.3", 800, 810),
+         ("fusion.1", 900, 1400), ("fusion.1", 1500, 1600)],
+    modules=[("jit_scatter(1)", 0, 100), ("jit_scatter(1)", 400, 650),
+             ("jit_fire(2)", 800, 810), ("jit_fire(2)", 900, 1400),
+             ("jit_fire(2)", 1500, 1600)],
+    host={TRACER: [("bench.trace_mark", 0, 0.001),
+                   ("bench.trace_mark", 999.999, 1000)],
+          PUMP: [("bench.source_generate", 0.5, 999)],
+          OPERATOR: [("flink.op.process", 100, 500),
+                     ("bench.process_batch", 110, 490),
+                     ("flink.batch.ingest", 120, 480),
+                     ("flink.prep.resolve", 130, 400),
+                     ("bench.on_watermark", 650, 800)]})
+
+
 def test_reduction_of_a_synthetic_table():
-    dev, host = "/device:TPU:0", "/host:CPU"
-    rows = [
-        (dev, "XLA Ops", "fusion.1", 0, 400_000_000),
-        (dev, "XLA Ops", "scatter.2", 400_000_000, 100_000_000),
-        (dev, "XLA Ops", "fusion.1", 900_000_000, 100_000_000),
-        (dev, "XLA Modules", "jit_scatter(1)", 0, 500_000_000),
-        (dev, "XLA Modules", "jit_fire(2)", 900_000_000, 100_000_000),
-        (dev, "Steps", "0", 0, 1_000_000_000),
-        (host, "python", "bench.process_batch", 450_000_000, 300_000_000),
-        (host, "python", "bench.on_watermark", 760_000_000, 130_000_000),
-    ]
-    r = trace.reduce_trace(rows, window_s=2.0)
-    assert r["busy_s_busiest"] == pytest.approx(0.6)
-    assert r["idle_pct"] == pytest.approx(70.0)
-    assert r["device_ops"] == [["jit_scatter(1)", 0.5], ["jit_fire(2)", 0.1]]
-    assert r["idle_gaps"] == [["bench.process_batch", pytest.approx(0.4)]]
-    assert trace.reduce_trace(rows[6:], window_s=2.0) is None
+    r = trace.reduce_trace(SYNTHETIC, asked_s=1.0)
+    assert r["window_s"] == pytest.approx(1.0)
+    assert r["range_ns"] == [0, 1000 * MS]
+    assert r["operator_line"] == [HOST, OPERATOR]
+    # the straddling program counts up to the end of the host rows only
+    # (100 of its 500 ms), the one after it not at all
+    assert r["busy_s_busiest"] == pytest.approx(0.46)
+    assert r["busy_s_mean"] == pytest.approx(0.46)
+    assert r["idle_pct"] == pytest.approx(54.0)
+    assert r["overrun_s"] == pytest.approx(0.6)
+    assert r["busy_s_outside"] == pytest.approx(0.5)
+    assert r["device_ops"] == [["jit_scatter(1)", pytest.approx(0.35)],
+                               ["jit_fire(2)", pytest.approx(0.11)]]
+    # every idle nanosecond under the innermost span open on the OPERATOR
+    # thread's line: the pump thread's row covers all and names nothing
+    assert r["idle_by_host_span_s"] == {
+        "flink.prep.resolve": pytest.approx(0.27),
+        "bench.on_watermark": pytest.approx(0.15),
+        NO_SPAN: pytest.approx(0.09),
+        "flink.op.process": pytest.approx(0.01),
+        "bench.process_batch": pytest.approx(0.01),
+        "flink.batch.ingest": pytest.approx(0.01)}
+    assert sum(r["idle_by_host_span_s"].values()) == pytest.approx(
+        r["window_s"] - r["busy_s_busiest"])
+    # a gap under four nested spans is named by the inner one, a gap under
+    # none says so
+    assert r["idle_gaps"] == [["flink.prep.resolve", pytest.approx(0.3)],
+                              ["bench.on_watermark", pytest.approx(0.15)],
+                              [NO_SPAN, pytest.approx(0.09)]]
+    host_only = [row for row in SYNTHETIC if row[0] == HOST]
+    assert trace.reduce_trace(host_only, asked_s=1.0) is None
+
+
+
+def test_a_capture_with_too_few_host_rows_is_an_error_not_a_reading():
+    with pytest.raises(ValueError, match="cover 1.000 s of the 3.000 s"):
+        trace.reduce_trace(SYNTHETIC, asked_s=3.0)
+    device_only = [row for row in SYNTHETIC if row[0] == DEV]
+    with pytest.raises(LookupError, match="no host row"):
+        trace.reduce_trace(device_only, asked_s=1.0)
+    no_operator = [row for row in SYNTHETIC if row[1] != OPERATOR]
+    with pytest.raises(LookupError, match="window operator's rows"):
+        trace.reduce_trace(no_operator, asked_s=1.0)
+
+
+def test_spans_that_only_overlap_go_to_the_one_that_opened_last():
+    # two threads merged onto one line (the first recorded table): no
+    # nesting to lean on
+    spans = [("a", 0, 10), ("b", 5, 20), ("c", 6, 8), ("d", 30, 40)]
+    assert trace.innermost(spans) == [
+        (0, 5, "a"), (5, 6, "b"), (6, 8, "c"), (8, 20, "b"), (30, 40, "d")]
+    assert trace.idle_by_span([(4, 7), (20, 35)],
+                              trace.innermost(spans)) == [
+        {"a": 1, "b": 1, "c": 1}, {"d": 5, NO_SPAN: 10}]
+
+
+def test_the_marks_tie_the_captures_clock_to_the_hosts():
+    clock = trace.host_clock(SYNTHETIC, (50.0, 50.999999))
+    assert clock(0) == pytest.approx(50.0)
+    assert clock(1000 * MS) == pytest.approx(51.0)
+    with pytest.raises(LookupError, match="2 bench.trace_mark"):
+        trace.host_clock(SYNTHETIC, (50.0,))
+    with pytest.raises(ValueError, match="drift"):
+        trace.host_clock(SYNTHETIC, (50.0, 51.5))
 
 
 @pytest.mark.parametrize("lost", ["XLA Ops", "XLA Modules"])
@@ -491,22 +612,29 @@ def test_a_device_plane_without_its_ops_or_modules_line_is_an_error(lost):
             (dev, "XLA Modules", "jit_scatter(1)", 0, 500),
             (dev, "Async XLA Ops", "copy-start", 0, 100)]
     with pytest.raises(LookupError, match="Async XLA Ops"):
-        trace.reduce_trace([r for r in rows if r[1] != lost], window_s=1.0)
+        trace.reduce_trace([r for r in rows if r[1] != lost], asked_s=1.0)
 
 
-def test_reduction_of_the_recorded_chip_trace():
-    path = os.path.join(ROOT, "benchmark", "fixtures", "slice_table.json")
-    with open(path, encoding="utf-8") as f:
+FIXTURES = os.path.join(ROOT, "benchmark", "fixtures")
+
+
+@pytest.mark.parametrize("table", sorted(
+    f for f in os.listdir(FIXTURES) if f.endswith(".json")))
+def test_reduction_of_a_recorded_chip_trace(table):
+    with open(os.path.join(FIXTURES, table), encoding="utf-8") as f:
         fixture = json.load(f)
     r = trace.reduce_trace([tuple(x) for x in fixture["rows"]],
-                           fixture["window_s"])
+                           fixture["asked_s"])
     want = fixture["expect"]
-    assert r["busy_s_busiest"] == pytest.approx(want["busy_s_busiest"])
-    assert r["idle_pct"] == pytest.approx(want["idle_pct"])
+    for number in ("window_s", "busy_s_busiest", "idle_pct", "overrun_s"):
+        assert r[number] == pytest.approx(want[number]), number
     assert [n for n, _ in r["device_ops"]] == want["device_ops"]
+    assert list(r["idle_by_host_span_s"])[:3] == want["idle_top3"]
     assert 0 < r["idle_pct"] < 100
     assert all(s > 0 for _, s in r["device_ops"] + r["idle_gaps"])
     assert len(r["device_ops"]) <= 10 and len(r["idle_gaps"]) <= 10
+    assert sum(r["idle_by_host_span_s"].values()) == pytest.approx(
+        r["window_s"] - r["busy_s_busiest"])
 
 
 # ----------------------------------------------------- (vi) work counting
