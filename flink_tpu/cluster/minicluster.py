@@ -852,18 +852,28 @@ class DispatcherEndpoint(RpcEndpoint):
         self.cluster = cluster
         self._masters: Dict[str, JobMasterThread] = {}
         self._recovery_lock = threading.Lock()
+        #: ids between their put into the HA store and their master's
+        #: registration (``submit_job``): not recovery's to start
+        self._submitting: set = set()
 
     def submit_job(self, graph, config_dict: dict, job_name: str,
                    job_id: Optional[str] = None) -> str:
         job_id = job_id or uuid.uuid4().hex[:16]
         store = getattr(self.cluster, "job_graph_store", None)
-        if store is not None:
-            # persist BEFORE starting: a dispatcher that dies right after
-            # accepting the submission must still recover the job
-            store.put(job_id, job_name, graph, config_dict)
-        master = JobMasterThread(self.cluster, job_id, job_name, graph,
-                                 Configuration(config_dict))
-        self._masters[job_id] = master
+        # a leadership grant that lands between the put and the master's
+        # registration finds the job in the store with no master: the id
+        # is marked so that recovery leaves it to this submission
+        self._submitting.add(job_id)
+        try:
+            if store is not None:
+                # persist BEFORE starting: a dispatcher that dies right
+                # after accepting the submission must still recover the job
+                store.put(job_id, job_name, graph, config_dict)
+            master = JobMasterThread(self.cluster, job_id, job_name, graph,
+                                     Configuration(config_dict))
+            self._masters[job_id] = master
+        finally:
+            self._submitting.discard(job_id)
         return job_id
 
     def recover_jobs(self, leader_check=None) -> List[str]:
@@ -885,6 +895,8 @@ class DispatcherEndpoint(RpcEndpoint):
         for job_id in store.job_ids():
             if leader_check is not None and not leader_check():
                 return recovered  # leadership lost mid-recovery: stop
+            if job_id in self._submitting:
+                continue  # being submitted right now: not lost
             existing = self._masters.get(job_id)
             if existing is not None:
                 if existing._suspended.is_set():

@@ -22,7 +22,12 @@ traces, Prometheus histograms and event-time latency markers by
 KNOWN_SPAN_KINDS = (
     # per-batch lifecycle (the engines' ingest -> emit pipeline)
     "batch.ingest",        # one engine process_batch (host prep + dispatch)
-    "prep.meta_sweep",     # session-metadata absorb (native C or Python)
+    "prep.meta_sweep",     # session-metadata absorb (native C or Python;
+                           # work: sessions the sweep opened)
+    "session.merge",       # sessions whose accumulators a batch merged
+                           # into another's: the merge kernel and the
+                           # absorbed rows' free (work: sessions absorbed;
+                           # 0 on an in-order stream)
     "prep.resolve",        # slice assignment + (key, slice) -> slot
                            # resolution on the host index (work: pairs
                            # newly given a slot)

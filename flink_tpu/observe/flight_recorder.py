@@ -95,7 +95,8 @@ _enabled = os.environ.get("FLINK_TPU_FLIGHT_RECORDER", "1") != "0"
 #: ``flink.<kind>`` rows: the batch / fire lifecycle on the task loop
 #: (control-plane spans and instants stay in the recorder only)
 _MIRRORED = frozenset(
-    ("op", "batch", "prep", "device", "exchange", "fire", "slice", "sink"))
+    ("op", "batch", "prep", "session", "device", "exchange", "fire", "slice",
+     "sink"))
 #: ``resource.RUSAGE_THREAD`` (Linux); absent elsewhere, where
 #: ``faults=True`` then counts nothing
 _RUSAGE_THREAD = getattr(resource, "RUSAGE_THREAD", None)

@@ -28,6 +28,7 @@ from typing import Dict, List, Tuple
 import numpy as np
 
 from flink_tpu.core.records import KEY_ID_FIELD, TIMESTAMP_FIELD, RecordBatch
+from flink_tpu.observe import flight_recorder as flight
 from flink_tpu.ops.segment_ops import pad_bucket_size, pad_i32
 from flink_tpu.state.slot_table import SlotTable
 from flink_tpu.stateplane import flat_merge_pairs
@@ -79,6 +80,8 @@ class SessionWindower:
         #: session-interval metadata: the native C sweep when compiled,
         #: else the pure-Python plane (bit-identical fires/snapshots)
         self.meta = make_session_meta(self.gap, self.allowed_lateness)
+        #: batches ingested: the flight recorder's batch id
+        self._flight_batch = 0
 
     @property
     def late_records_dropped(self) -> int:
@@ -138,14 +141,26 @@ class SessionWindower:
         n = len(batch)
         if n == 0:
             return
+        self._flight_batch += 1
+        with flight.ingest_span(self._flight_batch) as ingest:
+            ingest.work = n
+            self._ingest(batch)
+
+    def _ingest(self, batch: RecordBatch) -> None:
+        n = len(batch)
         ts = np.asarray(batch.timestamps, dtype=np.int64)
         keys = np.asarray(batch.key_ids, dtype=np.int64)
 
-        res = self.meta.absorb_batch_ex(keys, ts, want_fresh=False)
+        with flight.span("prep.meta_sweep") as sweep:
+            opened = self.meta.sid_watermark
+            res = self.meta.absorb_batch_ex(keys, ts, want_fresh=False)
+            sweep.work = self.meta.sid_watermark - opened
         sess_key, sess_sid = res.sess_key, res.sess_sid
         rec_to_sess, order = res.rec_to_sess, res.order
-        for g in res.groups:
-            self._run_merge_group(g)
+        with flight.span("session.merge") as merge:
+            for g in res.groups:
+                self._run_merge_group(g)
+                merge.work += len(g.absorbed_sids)
 
         live_sess = sess_sid >= 0
         if not live_sess.all():
@@ -158,21 +173,26 @@ class SessionWindower:
         # ONE vectorized lookup for all session slots, then scatter
         # records; the native metadata plane's folded slots skip the
         # state-table hash probe for sessions whose fold is still valid
-        m = len(sess_key)
-        slot_of_sess = np.zeros(m, dtype=np.int32)
-        if live_sess.any():
-            slot_of_sess[live_sess] = self.table.lookup_or_insert(
-                sess_key[live_sess], sess_sid[live_sess],
-                hints=(None if res.slot_hint is None
-                       else res.slot_hint[live_sess]))
-            self.meta.note_slots(sess_key[live_sess],
-                                 sess_sid[live_sess],
-                                 slot_of_sess[live_sess],
-                                 rows=(None if res.meta_row is None
-                                       else res.meta_row[live_sess]))
-        rec_slots = np.empty(n, dtype=np.int32)
-        rec_slots[order] = slot_of_sess[rec_to_sess]
-        self.table.scatter(rec_slots, self.agg.map_input(batch))
+        with flight.span("prep.resolve") as resolve:
+            m = len(sess_key)
+            slot_of_sess = np.zeros(m, dtype=np.int32)
+            if live_sess.any():
+                inserted = self.table.index.pairs_inserted
+                slot_of_sess[live_sess] = self.table.lookup_or_insert(
+                    sess_key[live_sess], sess_sid[live_sess],
+                    hints=(None if res.slot_hint is None
+                           else res.slot_hint[live_sess]))
+                resolve.work = self.table.index.pairs_inserted - inserted
+                self.meta.note_slots(sess_key[live_sess],
+                                     sess_sid[live_sess],
+                                     slot_of_sess[live_sess],
+                                     rows=(None if res.meta_row is None
+                                           else res.meta_row[live_sess]))
+            rec_slots = np.empty(n, dtype=np.int32)
+            rec_slots[order] = slot_of_sess[rec_to_sess]
+        with flight.span("prep.stage"):
+            values = self.agg.map_input(batch)
+        self.table.scatter(rec_slots, values)
 
     def _run_merge_group(self, g: MergeGroup) -> None:
         """Resolve a chain-free merge group's slots and move accumulators
@@ -208,7 +228,17 @@ class SessionWindower:
 
     def on_watermark(self, watermark: int,
                      async_ok: bool = False) -> List[RecordBatch]:
-        pop = self.meta.pop_fired_ex(watermark)
+        with flight.fire_span(watermark) as fire:
+            staged = self.table.fire_matrix_bytes
+            out = self._fire_closed(watermark, async_ok)
+            fire.work = self.table.fire_matrix_bytes - staged
+        return out
+
+    def _fire_closed(self, watermark: int, async_ok: bool) -> List:
+        """Fire and free every session the watermark closed."""
+        # shard 0: a single device is a mesh of one
+        with flight.span("fire.shard", shard=0):
+            pop = self.meta.pop_fired_ex(watermark)
         fired_keys, fired_starts = pop.keys, pop.starts
         fired_ends, fired_sids = pop.ends, pop.sids
         if not len(fired_keys):
@@ -223,11 +253,13 @@ class SessionWindower:
         out: List[RecordBatch] = []
         for a in range(0, total, chunk):
             b = min(a + chunk, total)
-            fired_slots = self.table.lookup_or_insert(
-                np.asarray(fired_keys[a:b], dtype=np.int64),
-                np.asarray(fired_sids[a:b], dtype=np.int64),
-                hints=(None if pop.slot_hint is None
-                       else pop.slot_hint[a:b]))
+            with flight.span("fire.shard", shard=0) as resolve:
+                fired_slots = self.table.lookup_or_insert(
+                    np.asarray(fired_keys[a:b], dtype=np.int64),
+                    np.asarray(fired_sids[a:b], dtype=np.int64),
+                    hints=(None if pop.slot_hint is None
+                           else pop.slot_hint[a:b]))
+                resolve.work = b - a
             matrix = np.asarray(fired_slots, dtype=np.int32)[:, None]
             cols = {
                 KEY_ID_FIELD: np.asarray(fired_keys[a:b], dtype=np.int64),
@@ -243,7 +275,7 @@ class SessionWindower:
                 # the reset is device-queue-ordered BEHIND the fire
                 # kernel, so the deferred host read never races it
                 pending = self.table.fire_async(matrix, None)
-                self.table.free_rows(fired_slots, fired_sids[a:b])
+                self._free_fired(fired_slots, fired_sids[a:b])
                 if pending is None:
                     continue
                 inner = pending.build
@@ -258,10 +290,15 @@ class SessionWindower:
                 out.append(pending)
                 continue
             results = self.table.fire(matrix)
-            self.table.free_rows(fired_slots, fired_sids[a:b])
+            self._free_fired(fired_slots, fired_sids[a:b])
             cols.update(results)
             out.append(RecordBatch(cols))
         return out
+
+    def _free_fired(self, slots, sids) -> None:
+        with flight.span("slice.retire") as retire:
+            self.table.free_rows(slots, sids)
+            retire.work = len(slots)
 
     # -------------------------------------------------------------- snapshot
 

@@ -353,6 +353,12 @@ class TestJobGraphStoreAndBlobs:
             with open(lock + ".steal", "w") as f:
                 f.write(_json.dumps({"owner": "other-cluster",
                                      "ts": time.time()}))
+            # the other cluster keeps its lease (a lease is as old as its
+            # file): were it to lapse after 400 ms, this dispatcher would
+            # win it back and resume the job before the poll below has
+            # seen it suspended
+            held_until = time.time() + 60
+            os.utime(lock + ".steal", (held_until, held_until))
             os.replace(lock + ".steal", lock)
             deadline = time.monotonic() + 10
             while time.monotonic() < deadline:
@@ -364,6 +370,54 @@ class TestJobGraphStoreAndBlobs:
             store = JobGraphStore(ha)
             assert "long-job" in [store.get(j)["job_name"]
                                   for j in store.job_ids()]
+        finally:
+            cluster.shutdown()
+
+    def test_a_grant_during_submission_starts_the_job_once(
+            self, tmp_path, monkeypatch):
+        """A leadership grant that lands between the store's put and the
+        master's registration finds the job in the store with no master:
+        it must leave the job to the submission, not start it a second
+        time (the second master would outlive a suspend, fail on the
+        closed cluster and take the job out of the store)."""
+        import threading
+
+        from flink_tpu.cluster import minicluster
+
+        cluster = MiniCluster(Configuration({
+            "rest.port": -1,
+            "high-availability.type": "filesystem",
+            "high-availability.storageDir": str(tmp_path / "ha"),
+        }))
+        try:
+            started, grants, recovered = [], [], []
+
+            class CountedMaster(minicluster.JobMasterThread):
+                def __init__(self, *a, **kw):
+                    started.append(a[1])
+                    super().__init__(*a, **kw)
+
+            monkeypatch.setattr(minicluster, "JobMasterThread",
+                                CountedMaster)
+            put = cluster.job_graph_store.put
+
+            def put_then_grant(*a, **kw):
+                put(*a, **kw)
+                grants.append(threading.Thread(
+                    target=lambda: recovered.extend(
+                        cluster.dispatcher.recover_jobs())))
+                grants[0].start()
+                time.sleep(0.3)  # room for the recovery to get ahead
+
+            monkeypatch.setattr(cluster.job_graph_store, "put",
+                                put_then_grant)
+            env = StreamExecutionEnvironment(Configuration(
+                {"execution.micro-batch.size": 512}))
+            build(env, str(tmp_path / "o.jsonl"), total=2_000)
+            client = cluster.submit(env, "once")
+            grants[0].join(timeout=10)
+            assert not grants[0].is_alive() and recovered == []
+            assert started == [client.job_id]
         finally:
             cluster.shutdown()
 
