@@ -244,6 +244,10 @@ def load_slotmap() -> Optional[ctypes.CDLL]:
         lib.sm_lookup_or_insert.restype = i32
         lib.sm_lookup_or_insert.argtypes = [vp, i64, P(i64), P(i64), P(i32),
                                             P(u8)]
+        lib.sm_resolve_grouped.restype = i32
+        lib.sm_resolve_grouped.argtypes = [vp, i64, P(i64), P(i64), i64, i64,
+                                           i64, i64, P(i32), P(i32),
+                                           P(i64), P(i64)]
         lib.sm_erase.restype = i64
         lib.sm_erase.argtypes = [vp, i64, P(i64), P(i64), P(i32)]
         lib.sm_lookup.restype = None

@@ -31,6 +31,9 @@ KNOWN_SPAN_KINDS = (
     "prep.resolve",        # slice assignment + (key, slice) -> slot
                            # resolution on the host index (work: pairs
                            # newly given a slot)
+    "resolve.sweep",       # a batch whose slice ends and slots one native
+                           # sweep resolved (instant inside prep.resolve;
+                           # work: records)
     "prep.stage",          # input mapping, padding to the sticky bucket,
                            # shuffle staging into [P, B] blocks (work:
                            # bytes handed to the device, padding included)

@@ -578,6 +578,13 @@ class NativeSlotIndex(_NamespaceRegistry):
         self._h = self._lib.sm_create(self.capacity, max_cap)
         self._wrap_views()
         self._init_registry(track_namespaces)
+        # _resolve_grouped's outputs, kept from batch to batch (a fresh
+        # megabyte per batch costs more in page faults than the sweep's
+        # arithmetic): new slots grouped by namespace; [3, max_uniq]
+        # namespaces / records / new slots of each; the distinct count
+        self._sweep_new = np.empty(0, dtype=np.int32)
+        self._sweep_groups = np.empty(0, dtype=np.int64)
+        self._sweep_k = _ct.c_int64()
 
     def _wrap_views(self) -> None:
         import ctypes
@@ -609,6 +616,10 @@ class NativeSlotIndex(_NamespaceRegistry):
         keys = np.ascontiguousarray(key_ids, dtype=np.int64)
         nss = np.ascontiguousarray(namespaces, dtype=np.int64)
         n = len(keys)
+        if self._track_ns:
+            # width 0: the values are the namespaces; any number of
+            # distinct ones is taken
+            return self._resolve_grouped(keys, nss, 0, 0, 0, max(n, 1))[0]
         out = np.empty(n, dtype=np.int32)
         is_new = np.empty(n, dtype=np.uint8)
         old_cap = self.capacity
@@ -617,34 +628,95 @@ class NativeSlotIndex(_NamespaceRegistry):
             keys.ctypes.data_as(_I64P), nss.ctypes.data_as(_I64P),
             out.ctypes.data_as(_I32P), is_new.ctypes.data_as(_U8P))
         if rc < 0:
-            raise SlotTableFullError(
-                f"slot table full (capacity={self.capacity}) and not "
-                f"growable; {self.full_hint}")
+            raise self._full_error()
         if rc > 0:
-            self._wrap_views()
-            if self.on_grow is not None:
-                self.on_grow(old_cap, self.capacity)
-        new_mask = is_new.view(bool)
-        if not new_mask.any():
-            return out
-        if not self._track_ns:
-            self.pairs_inserted += int(np.count_nonzero(new_mask))
-        else:
-            new_slots = out[new_mask]
-            self.pairs_inserted += len(new_slots)
-            new_ns = nss[new_mask]
-            # group new slots by namespace: sort + split (O(n log n), not a
-            # per-namespace mask scan)
-            order = np.argsort(new_ns, kind="stable")
-            sorted_ns = new_ns[order]
-            sorted_slots = new_slots[order]
-            boundaries = np.nonzero(np.diff(sorted_ns))[0] + 1
-            chunks = np.split(sorted_slots, boundaries)
-            firsts = np.concatenate(([0], boundaries))
-            reg = self._ns_slots
-            for ns, chunk in zip(sorted_ns[firsts].tolist(), chunks):
-                reg.setdefault(ns, []).append(chunk)
+            self._regrown(old_cap)
+        self.pairs_inserted += int(np.count_nonzero(is_new))
         return out
+
+    #: distinct slice ends beyond which :meth:`resolve_slices` leaves a
+    #: batch to the caller's own path (timestamps wildly out of order)
+    MAX_SWEPT_SLICES = 1024
+
+    def resolve_slices(self, key_ids: np.ndarray, timestamps: np.ndarray,
+                       offset: int, slice_width: int, live_from: int
+                       ) -> Optional[Tuple[np.ndarray, np.ndarray,
+                                           np.ndarray]]:
+        """``lookup_or_insert`` of a batch under its records' slice ends,
+        straight from the timestamps: ``(slots, distinct slice ends
+        ascending, records per distinct slice)``. The slice end is the
+        assigner's (``ts - (ts - offset) mod slice_width + slice_width``).
+        None, with nothing changed, where a slice end lies below
+        ``live_from`` (a late record: the caller's own path drops and
+        counts it) or the batch holds more than ``MAX_SWEPT_SLICES``
+        distinct ones."""
+        return self._resolve_grouped(
+            np.ascontiguousarray(key_ids, dtype=np.int64),
+            np.ascontiguousarray(timestamps, dtype=np.int64),
+            int(offset), int(slice_width), int(live_from),
+            self.MAX_SWEPT_SLICES)
+
+    def _resolve_grouped(self, keys: np.ndarray, vals: np.ndarray,
+                         offset: int, width: int, live_from: int,
+                         max_uniq: int):
+        """One ``sm_resolve_grouped`` call (native/slotmap.cpp): every
+        record's slot, and the new slots appended to the registry as one
+        chunk per namespace, in record order, namespaces ascending. One
+        foreign call per batch (each is a GIL hand-over on the task
+        loop). ``slots`` is the caller's; the grouped new slots and the
+        per-namespace counts land in buffers the index keeps."""
+        n = len(keys)
+        if len(vals) != n:
+            raise ValueError(
+                f"{n} keys against {len(vals)} timestamps / namespaces")
+        slots = np.empty(n, dtype=np.int32)
+        if n > len(self._sweep_new):
+            self._sweep_new = np.empty(n, dtype=np.int32)
+        if 3 * max_uniq > len(self._sweep_groups):
+            self._sweep_groups = np.empty(3 * max_uniq, dtype=np.int64)
+        new, groups = self._sweep_new, self._sweep_groups
+        old_cap = self.capacity
+        rc = self._lib.sm_resolve_grouped(
+            self._h, n, keys.ctypes.data_as(_I64P),
+            vals.ctypes.data_as(_I64P), offset, width, live_from, max_uniq,
+            slots.ctypes.data_as(_I32P), new.ctypes.data_as(_I32P),
+            groups.ctypes.data_as(_I64P), _ct.byref(self._sweep_k))
+        if rc == -2:
+            return None
+        k = self._sweep_k.value
+        ends = groups[:k].copy()
+        records = groups[max_uniq:max_uniq + k].copy()
+        reg, track = self._ns_slots, self._track_ns
+        pos = 0
+        for ns, held, fresh in zip(
+                ends.tolist(), records.tolist(),
+                groups[2 * max_uniq:2 * max_uniq + k].tolist()):
+            if fresh:
+                self.pairs_inserted += fresh
+                if track:
+                    reg.setdefault(ns, []).append(
+                        new[pos:pos + fresh].copy())
+            pos += held
+        if rc < 0:
+            # what was inserted before the table filled is registered
+            # above, and a growth before it is passed on: index,
+            # registry and the owner's arrays stay level
+            if int(self._lib.sm_capacity(self._h)) != old_cap:
+                self._regrown(old_cap)
+            raise self._full_error()
+        if rc > 0:
+            self._regrown(old_cap)
+        return slots, ends, records
+
+    def _full_error(self) -> SlotTableFullError:
+        return SlotTableFullError(
+            f"slot table full (capacity={self.capacity}) and not "
+            f"growable; {self.full_hint}")
+
+    def _regrown(self, old_cap: int) -> None:
+        self._wrap_views()
+        if self.on_grow is not None:
+            self.on_grow(old_cap, self.capacity)
 
     def pane_ingest(self, key_ids: np.ndarray, timestamps: np.ndarray,
                     offset: int, width: int, max_uniq: int = 4096):
@@ -678,13 +750,9 @@ class NativeSlotIndex(_NamespaceRegistry):
         if rc == -2:
             return None
         if rc < 0:
-            raise SlotTableFullError(
-                f"slot table full (capacity={self.capacity}) and not "
-                f"growable; {self.full_hint}")
+            raise self._full_error()
         if rc > 0:
-            self._wrap_views()
-            if self.on_grow is not None:
-                self.on_grow(old_cap, self.capacity)
+            self._regrown(old_cap)
         new_mask = is_new.view(bool)
         if new_mask.any():
             # all pane-table entries live in namespace 0
@@ -1334,6 +1402,27 @@ class SlotTable:
             return
         slots = resolve(key_ids, namespaces)
         emit(slots, values)
+
+    def resolve_slices(self, key_ids: np.ndarray, timestamps: np.ndarray,
+                       offset: int, slice_width: int, live_from: int
+                       ) -> Optional[Tuple[np.ndarray, np.ndarray, int]]:
+        """``(slots, distinct slice ends, pairs newly given a slot)`` of
+        a batch in the index's one native sweep over keys and timestamps
+        (``NativeSlotIndex.resolve_slices``) — or None, with nothing
+        changed, where the batch has to take ``upsert``'s path under
+        slice ends the caller computes: the index is the Python one; the
+        table has a device-slot budget (the spill tiers make a batch's
+        namespaces resident before the insert); the batch holds a slice
+        end below ``live_from`` (a late record, dropped and counted
+        there) or too many distinct ones."""
+        sweep = getattr(self.index, "resolve_slices", None)
+        if sweep is None or self.max_device_slots:
+            return None
+        before = self.index.pairs_inserted
+        swept = sweep(key_ids, timestamps, offset, slice_width, live_from)
+        if swept is None:
+            return None
+        return swept[0], swept[1], self.index.pairs_inserted - before
 
     def _resolve(self, key_ids, namespaces, _pairs=None) -> np.ndarray:
         """``lookup_or_insert`` under a ``prep.resolve`` span whose work

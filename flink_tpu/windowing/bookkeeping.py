@@ -42,8 +42,35 @@ class SliceBookkeeper:
         self.watermark: int = _NEG_INF
         self.max_fired_end: int = _NEG_INF
         self.late_records_dropped = 0
+        # (watermark, oldest_live_slice_end() at it)
+        self._live_from = (_NEG_INF, _NEG_INF)
 
     # ---------------------------------------------------------------- arrivals
+
+    def oldest_live_slice_end(self) -> int:
+        """The smallest slice end :meth:`live_mask` keeps at the current
+        watermark: a batch whose oldest slice end is at least this drops
+        nothing, which is that method's early-out as a threshold — one
+        scalar a native sweep can test a batch against. A slice's last
+        window end never falls as the slice end grows, so the late slices
+        are exactly those below it. Reckoned once per watermark."""
+        wm = self.watermark
+        if wm <= _NEG_INF // 2:
+            return _NEG_INF
+        if self._live_from[0] != wm:
+            a, lateness = self.assigner, self.allowed_lateness
+            w = a.slice_width
+            # the slice of wm - lateness + 1 ends past it, so its own
+            # end alone keeps it live; one more than size // w slices
+            # below it even the longest reach (size - w past the slice
+            # end) is over
+            t = wm - lateness + 1
+            top = t - (t - a.offset) % w + w
+            ends = np.arange(top - (a.size // w + 1) * w, top + w, w,
+                             dtype=np.int64)
+            live = a.last_window_ends(ends) - 1 + lateness > wm
+            self._live_from = (wm, int(ends[np.argmax(live)]))
+        return self._live_from[1]
 
     def live_mask(self, slice_ends: np.ndarray) -> Optional[np.ndarray]:
         """Late-record filter: a record is dropped iff its slice is past

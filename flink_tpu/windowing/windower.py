@@ -135,6 +135,27 @@ class SliceSharedWindower:
                 values, valued = self._values_of(batch)
                 self.table.scatter_flat(flat, values, valued=valued)
                 return
+        sweep = getattr(self.table, "resolve_slices", None)
+        if sweep is not None:
+            # the table's one native sweep over keys and timestamps,
+            # where the table and the batch allow it (no late record
+            # among them); else the path below, with the same results
+            with flight.span("prep.resolve") as resolve:
+                swept = sweep(batch.key_ids, batch.timestamps,
+                              self.assigner.offset,
+                              self.assigner.slice_width,
+                              self.book.oldest_live_slice_end())
+                if swept is not None:
+                    slots, uniq, resolve.work = swept
+                    self.book.register_slices(uniq, uniq=uniq)
+                    flight.instant("resolve.sweep", work=len(batch))
+            if swept is not None:
+                values, valued = self._values_of(batch)
+                if valued:
+                    self.table.scatter_valued(slots, values)
+                else:
+                    self.table.scatter(slots, values)
+                return
         with flight.span("prep.resolve"):
             slice_ends = self.assigner.assign_slice_ends(batch.timestamps)
             live = self.book.live_mask(slice_ends)

@@ -14,11 +14,27 @@
 // Exposed as a plain C ABI for ctypes; all batch arguments are raw pointers
 // into NumPy buffers.
 
+#include <algorithm>
 #include <cstdint>
 #include <cstdlib>
 #include <cstring>
 
 namespace {
+
+// Scratch of the batch sweep (sm_resolve_grouped below), kept with the map
+// and reused from batch to batch: a 131k-record batch would otherwise
+// fault in a megabyte of fresh pages per call.
+struct SweepScratch {
+  int64_t rec_cap;   // records sinv holds
+  int32_t* sinv;     // [rec_cap] record -> distinct namespace (first-seen)
+  int64_t uniq_cap;  // distinct namespaces the arrays below hold
+  int64_t* val;      // [uniq_cap] the namespace
+  int64_t* count;    // [uniq_cap] records under it
+  int64_t* cursor;   // [uniq_cap] where its next new slot goes
+  int32_t* order;    // [uniq_cap] first-seen indexes by ascending namespace
+  int64_t tab_size;  // power of two, > 2 * distinct held
+  int32_t* tab;      // namespace -> first-seen index, -1 empty
+};
 
 struct SlotMap {
   int64_t capacity;      // slot array capacity (includes reserved slot 0)
@@ -32,6 +48,7 @@ struct SlotMap {
   uint8_t* slot_used;    // [capacity]
   int32_t* free_stack;   // [capacity]
   int64_t free_top;      // stack size
+  SweepScratch sweep;    // zeroed by sm_create's calloc, grown on demand
 };
 
 inline uint64_t mix_hash(uint64_t k, uint64_t n) {
@@ -78,6 +95,41 @@ int grow(SlotMap* m) {
   m->capacity = new_cap;
   build_buckets(m);
   return 0;
+}
+
+// The probe of one (key, ns) pair from its hash: the pair's slot, a free
+// one taken where the pair is new (*is_new), the table grown where none is
+// free (*grows counts them). -1: full at max_capacity.
+inline int32_t probe_or_insert(SlotMap* m, int64_t k, int64_t ns,
+                               uint64_t hash, int32_t* grows, bool* is_new) {
+  uint64_t mask = (uint64_t)m->bucket_count - 1;
+  uint64_t i = hash & mask;
+  for (;;) {
+    int32_t b = m->buckets[i];
+    if (b == -1) {
+      if (m->free_top == 0) {
+        if (grow(m) != 0) return -1;
+        ++*grows;
+        // re-probe against rebuilt buckets
+        mask = (uint64_t)m->bucket_count - 1;
+        i = hash & mask;
+        continue;
+      }
+      int32_t slot = m->free_stack[--m->free_top];
+      m->buckets[i] = slot;
+      m->slot_key[slot] = k;
+      m->slot_ns[slot] = ns;
+      m->slot_used[slot] = 1;
+      m->used++;
+      *is_new = true;
+      return slot;
+    }
+    if (m->slot_key[b] == k && m->slot_ns[b] == ns) {
+      *is_new = false;
+      return b;
+    }
+    i = (i + 1) & mask;
+  }
 }
 
 // The fire path's carried slot matrix (see sm_carry_advance below).
@@ -141,6 +193,47 @@ void carry_remove_row(SliceCarry* c, int64_t r) {
   memcpy(c->mat + r * c->k, c->mat + last * c->k, sizeof(int32_t) * c->k);
 }
 
+inline uint64_t sweep_hash(int64_t v) {
+  return (uint64_t)v * 0x9E3779B97F4A7C15ull >> 20;
+}
+
+// First-seen index of namespace v among the *k held so far, entering it
+// where it is new; -1 where that would pass max_uniq.
+inline int32_t sweep_index_of(SweepScratch* w, int64_t v, int64_t* k,
+                              int64_t max_uniq) {
+  uint64_t mask = (uint64_t)w->tab_size - 1;
+  uint64_t b = sweep_hash(v) & mask;
+  for (int32_t i; (i = w->tab[b]) >= 0; b = (b + 1) & mask)
+    if (w->val[i] == v) return i;
+  if (*k >= max_uniq) return -1;
+  if (*k == w->uniq_cap) {
+    int64_t cap = w->uniq_cap ? w->uniq_cap * 2 : 64;
+    w->val = (int64_t*)realloc(w->val, sizeof(int64_t) * cap);
+    w->count = (int64_t*)realloc(w->count, sizeof(int64_t) * cap);
+    w->cursor = (int64_t*)realloc(w->cursor, sizeof(int64_t) * cap);
+    w->order = (int32_t*)realloc(w->order, sizeof(int32_t) * cap);
+    w->uniq_cap = cap;
+  }
+  int32_t i = (int32_t)(*k)++;
+  w->val[i] = v;
+  w->count[i] = 0;
+  if (*k * 2 < w->tab_size) {
+    w->tab[b] = i;
+    return i;
+  }
+  // keep the table under half full: double it and enter everything again
+  w->tab_size *= 2;
+  w->tab = (int32_t*)realloc(w->tab, sizeof(int32_t) * w->tab_size);
+  memset(w->tab, 0xff, sizeof(int32_t) * w->tab_size);
+  mask = (uint64_t)w->tab_size - 1;
+  for (int32_t j = 0; j <= i; j++) {
+    b = sweep_hash(w->val[j]) & mask;
+    while (w->tab[b] >= 0) b = (b + 1) & mask;
+    w->tab[b] = j;
+  }
+  return i;
+}
+
 }  // namespace
 
 extern "C" {
@@ -170,6 +263,12 @@ void sm_destroy(void* h) {
   free(m->slot_ns);
   free(m->slot_used);
   free(m->free_stack);
+  free(m->sweep.sinv);
+  free(m->sweep.val);
+  free(m->sweep.count);
+  free(m->sweep.cursor);
+  free(m->sweep.order);
+  free(m->sweep.tab);
   free(m);
 }
 
@@ -212,41 +311,151 @@ int32_t sm_lookup_or_insert(void* h, int64_t n, const int64_t* keys,
         __builtin_prefetch(&m->slot_ns[b], 0, 1);
       }
     }
-  for (int64_t r = base; r < end; r++) {
-    int64_t k = keys[r], ns = nss[r];
-    uint64_t mask = (uint64_t)m->bucket_count - 1;
-    uint64_t i = hashes[r - base] & mask;
-    for (;;) {
-      int32_t b = m->buckets[i];
-      if (b == -1) {
-        // miss -> insert
-        if (m->free_top == 0) {
-          if (grow(m) != 0) return -1;
-          grows++;
-          // re-probe against rebuilt buckets
-          mask = (uint64_t)m->bucket_count - 1;
-          i = mix_hash((uint64_t)k, (uint64_t)ns) & mask;
-          continue;
-        }
-        int32_t slot = m->free_stack[--m->free_top];
-        m->buckets[i] = slot;
-        m->slot_key[slot] = k;
-        m->slot_ns[slot] = ns;
-        m->slot_used[slot] = 1;
-        m->used++;
-        out_slots[r] = slot;
-        if (out_is_new) out_is_new[r] = 1;
-        break;
-      } else if (m->slot_key[b] == k && m->slot_ns[b] == ns) {
-        out_slots[r] = b;
-        if (out_is_new) out_is_new[r] = 0;
-        break;
-      }
-      i = (i + 1) & mask;
+    for (int64_t r = base; r < end; r++) {
+      bool is_new;
+      int32_t slot = probe_or_insert(m, keys[r], nss[r], hashes[r - base],
+                                     &grows, &is_new);
+      if (slot < 0) return -1;
+      out_slots[r] = slot;
+      if (out_is_new) out_is_new[r] = is_new;
     }
   }
-  }
   return grows;
+}
+
+// One sweep that resolves a whole batch: (key, namespace) -> slot for
+// every record, and the slots newly given out grouped by namespace — what
+// the registry (flink_tpu/state/slot_table.py) appends, with no is_new
+// mask, sort or split behind the call.
+//
+// ``vals`` are timestamps where width > 0: a record's namespace is then
+// the end of its slice, ts - floormod(ts - offset, width) + width (the
+// assigner's rule; an in-order run stays in the slice of the record before
+// it, so a compare stands in for the division). Where width == 0 they are
+// the namespaces themselves.
+//
+// Pass A reads ``vals`` alone and changes nothing: each record's distinct
+// namespace (first-seen index, kept in scratch) and the records under
+// each. More than max_uniq distinct namespaces, or (width > 0) a slice end
+// below live_from — a late record, which the caller's own path drops and
+// counts — returns -2 with the table untouched. The counts give each
+// namespace its place in out_new, ascending by namespace, before the first
+// insert. Pass B is sm_lookup_or_insert's probe (same hash, prefetch,
+// growth), a new slot going to its namespace's cursor: new slots come out
+// grouped, in record order within a group.
+//
+// out_groups is [3, max_uniq] int64: the distinct namespaces ascending,
+// the records under each, the new slots of each. Namespace j's new slots
+// start at out_new[records of the namespaces before it]. *out_k = distinct
+// namespaces. Returns grows (>= 0) or -1 (table full at max_capacity;
+// out_groups and out_new then hold what was inserted before it, so the
+// caller's registry can stay level with the table).
+int32_t sm_resolve_grouped(void* h, int64_t n, const int64_t* keys,
+                           const int64_t* vals, int64_t offset,
+                           int64_t width, int64_t live_from,
+                           int64_t max_uniq, int32_t* out_slots,
+                           int32_t* out_new, int64_t* out_groups,
+                           int64_t* out_k) {
+  SlotMap* m = (SlotMap*)h;
+  SweepScratch* w = &m->sweep;
+  if (n > w->rec_cap) {
+    free(w->sinv);
+    w->sinv = (int32_t*)malloc(sizeof(int32_t) * n);
+    w->rec_cap = n;
+  }
+  if (!w->tab) {
+    w->tab_size = 64;
+    w->tab = (int32_t*)malloc(sizeof(int32_t) * w->tab_size);
+  }
+  memset(w->tab, 0xff, sizeof(int32_t) * w->tab_size);
+  int64_t k = 0;
+  int32_t* sinv = w->sinv;
+  // ---- pass A: distinct namespaces and their record counts
+  {
+    int32_t cur = -1;
+    int64_t run = 0;       // records of the open run, not yet counted
+    int64_t cur_val = 0;   // width > 0: the open slice's start
+    for (int64_t r = 0; r < n; r++) {
+      int64_t v = vals[r];
+      bool same = width > 0
+                      ? (uint64_t)v - (uint64_t)cur_val < (uint64_t)width
+                      : v == cur_val;
+      if (cur < 0 || !same) {
+        if (cur >= 0) w->count[cur] += run;
+        run = 0;
+        int64_t ns = v;
+        if (width > 0) {
+          int64_t rem = (v - offset) % width;
+          if (rem < 0) rem += width;
+          cur_val = v - rem;
+          ns = cur_val + width;
+          if (ns < live_from) return -2;
+        } else {
+          cur_val = v;
+        }
+        cur = sweep_index_of(w, ns, &k, max_uniq);
+        if (cur < 0) return -2;
+      }
+      sinv[r] = cur;
+      run++;
+    }
+    if (cur >= 0) w->count[cur] += run;
+  }
+  for (int64_t j = 0; j < k; j++) w->order[j] = (int32_t)j;
+  std::sort(w->order, w->order + k,
+            [w](int32_t a, int32_t b) { return w->val[a] < w->val[b]; });
+  int64_t* g_val = out_groups;
+  int64_t* g_records = out_groups + max_uniq;
+  int64_t* g_new = out_groups + 2 * max_uniq;
+  int64_t pos = 0;
+  for (int64_t j = 0; j < k; j++) {
+    int32_t u = w->order[j];
+    g_val[j] = w->val[u];
+    g_records[j] = w->count[u];
+    w->cursor[u] = pos;
+    pos += w->count[u];
+  }
+  *out_k = k;
+  // ---- pass B: the probe (sm_lookup_or_insert's, see there)
+  int32_t grows = 0;
+  bool full = false;
+  constexpr int64_t CHUNK = 256;
+  uint64_t hashes[CHUNK];
+  for (int64_t base = 0; base < n && !full; base += CHUNK) {
+    int64_t end = base + CHUNK < n ? base + CHUNK : n;
+    uint64_t pmask = (uint64_t)m->bucket_count - 1;
+    for (int64_t r = base; r < end; r++) {
+      uint64_t hh = mix_hash((uint64_t)keys[r], (uint64_t)w->val[sinv[r]]);
+      hashes[r - base] = hh;
+      __builtin_prefetch(&m->buckets[hh & pmask], 0, 1);
+    }
+    for (int64_t r = base; r < end; r++) {
+      int32_t b = m->buckets[hashes[r - base] & pmask];
+      if (b >= 0) {
+        __builtin_prefetch(&m->slot_key[b], 0, 1);
+        __builtin_prefetch(&m->slot_ns[b], 0, 1);
+      }
+    }
+    for (int64_t r = base; r < end; r++) {
+      int32_t u = sinv[r];
+      bool is_new;
+      int32_t slot = probe_or_insert(m, keys[r], w->val[u], hashes[r - base],
+                                     &grows, &is_new);
+      if (slot < 0) {
+        full = true;
+        break;
+      }
+      out_slots[r] = slot;
+      if (is_new) out_new[w->cursor[u]++] = slot;
+    }
+  }
+  pos = 0;
+  for (int64_t j = 0; j < k; j++) {
+    int32_t u = w->order[j];
+    g_new[j] = w->cursor[u] - pos;
+    pos += w->count[u];
+  }
+  return full ? -1 : grows;
 }
 
 // Read-only batch probe: out_slots[i] = slot id, or -1 if the pair is not
@@ -453,41 +662,17 @@ int32_t sm_pane_ingest(void* h, int64_t n, const int64_t* keys,
       }
       out_sinv[r] = se_idx[sb];
       // key -> column (lookup-or-insert, ns = 0)
-      int64_t k = keys[r];
-      uint64_t mask = (uint64_t)m->bucket_count - 1;
-      uint64_t i = hashes[r - base] & mask;
-      for (;;) {
-        int32_t b = m->buckets[i];
-        if (b == -1) {
-          if (m->free_top == 0) {
-            if (grow(m) != 0) {
-              free(se_key);
-              free(se_idx);
-              return -1;
-            }
-            grows++;
-            mask = (uint64_t)m->bucket_count - 1;
-            i = mix_hash((uint64_t)k, 0) & mask;
-            continue;
-          }
-          int32_t slot = m->free_stack[--m->free_top];
-          m->buckets[i] = slot;
-          m->slot_key[slot] = k;
-          m->slot_ns[slot] = 0;
-          m->slot_used[slot] = 1;
-          m->used++;
-          out_cols[r] = slot;
-          out_is_new[r] = 1;
-          if (slot > max_col) max_col = slot;
-          break;
-        } else if (m->slot_key[b] == k && m->slot_ns[b] == 0) {
-          out_cols[r] = b;
-          out_is_new[r] = 0;
-          if (b > max_col) max_col = b;
-          break;
-        }
-        i = (i + 1) & mask;
+      bool is_new;
+      int32_t col = probe_or_insert(m, keys[r], 0, hashes[r - base], &grows,
+                                    &is_new);
+      if (col < 0) {
+        free(se_key);
+        free(se_idx);
+        return -1;
       }
+      out_cols[r] = col;
+      out_is_new[r] = is_new;
+      if (col > max_col) max_col = col;
     }
   }
   free(se_key);

@@ -13,6 +13,7 @@ import pytest
 from flink_tpu import Configuration, StreamExecutionEnvironment
 from flink_tpu.connectors.sinks import CollectSink
 from flink_tpu.connectors.sources import DataGenSource
+from flink_tpu.native import slotmap_available
 from flink_tpu.observe import flight_recorder as flight
 from flink_tpu.runtime.watermarks import WatermarkStrategy
 from flink_tpu.windowing.aggregates import CountAggregate
@@ -58,9 +59,18 @@ def test_every_kind_is_recorded_with_the_count_of_its_boundary(q5):
     # ingest: one batch.ingest per micro-batch, stating its events
     assert kt["batch.ingest"]["count"] == BATCHES
     assert kt["batch.ingest"]["work"] == BATCH * BATCHES
-    # slots: slice planning in the windower + the slot lookup in the
-    # table; panes: one fused index build
-    per_batch = 2 if layout == "slots" else 1
+    # one resolve per batch: the slots layout's native sweep over keys
+    # and timestamps (every batch here is in order, so every batch takes
+    # it and says so), the panes layout's fused index build. Without the
+    # native index the slots layout plans slices in the windower and
+    # looks slots up in the table: two
+    swept = kt.get("resolve.sweep", {"count": 0, "work": 0})
+    if layout == "slots" and slotmap_available():
+        assert swept["count"] == BATCHES
+        assert swept["work"] == BATCH * BATCHES
+    else:
+        assert swept["count"] == 0
+    per_batch = 2 if layout == "slots" and not swept["count"] else 1
     assert kt["prep.resolve"]["count"] == per_batch * BATCHES
     assert kt["prep.stage"]["count"] == 2 * BATCHES
     assert kt["device.dispatch"]["count"] == BATCHES

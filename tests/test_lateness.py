@@ -2,6 +2,7 @@
 (reference: WindowOperator allowedLateness + cleanup timers)."""
 
 import numpy as np
+import pytest
 
 from flink_tpu.core.records import KEY_ID_FIELD, RecordBatch
 from flink_tpu.windowing.aggregates import SumAggregate
@@ -96,6 +97,84 @@ class TestAllowedLateness:
             want = [a.window_ends_for_slice(int(s))[-1] for s in ses]
             got = a.last_window_ends(ses).tolist()
             assert got == want, (size, slide)
+
+
+ASSIGNERS = {
+    "tumble": lambda: TumblingEventTimeWindows.of(100),
+    "tumble_offset": lambda: TumblingEventTimeWindows.of(100, 30),
+    "hop": lambda: SlidingEventTimeWindows.of(500, 100),
+    "hop_uneven": lambda: SlidingEventTimeWindows.of(1000, 300),
+    "hop_offset": lambda: SlidingEventTimeWindows.of(400, 200, 70),
+    "cumulate": lambda: CumulativeEventTimeWindows(max_size_ms=300,
+                                                   step_ms=100),
+}
+
+
+class TestOldestLiveSliceEnd:
+    """The late-record filter's early-out as a threshold (what the
+    batch sweep tests a batch's slice ends against, natively)."""
+
+    @pytest.mark.parametrize("lateness", [0, 50, 250])
+    @pytest.mark.parametrize("name", sorted(ASSIGNERS))
+    def test_is_where_live_mask_starts_keeping(self, name, lateness):
+        from flink_tpu.windowing.bookkeeping import SliceBookkeeper
+
+        a = ASSIGNERS[name]()
+        book = SliceBookkeeper(a, lateness)
+        w = a.slice_width
+        assert book.oldest_live_slice_end() < -(1 << 61)   # no watermark
+        for wm in [-2345, -1, 0, 99, 100, 129, 130, 777, 1000, 5003]:
+            book.watermark = wm
+            first = book.oldest_live_slice_end()
+            assert first == book.oldest_live_slice_end()    # kept per wm
+            assert (first - a.offset) % w == 0
+            ends = a.assign_slice_ends(
+                np.arange(wm - 3000, wm + 3000, 7, dtype=np.int64))
+            for se in np.unique(ends).tolist():
+                kept = book.live_mask(
+                    np.asarray([se], dtype=np.int64)) is None
+                assert kept == (se >= first), (wm, se, first)
+
+
+class TestLateBatchesKeepTheirPath:
+    """A batch holding a late record is not swept: it takes the path
+    that drops and counts late records, unchanged; the batches around it
+    are swept (where the native index is there)."""
+
+    @pytest.mark.parametrize("late", [0, 1, 3])
+    def test_late_records_dropped_and_counted_as_before(self, late):
+        from flink_tpu.native import slotmap_available
+        from flink_tpu.observe import flight_recorder as flight
+
+        w = SliceSharedWindower(SlidingEventTimeWindows.of(200, 100),
+                                SumAggregate("v"), capacity=1024,
+                                allowed_lateness=100)
+        rec = flight.recorder()
+        rec.clear()
+        w.process_batch(kb([1, 2], [1.0, 1.0], [10, 120]))
+        assert fired(w.on_watermark(450)) == {
+            (1, -100, 100): 1.0, (1, 0, 200): 1.0,
+            (2, 0, 200): 1.0, (2, 100, 300): 1.0}
+        # slice (0, 100] left retention at 199 + 100, (100, 200] at 399;
+        # (200, 300] is kept until 499
+        ts = [460, 470, 480] + [50, 150, 199][:late]
+        w.process_batch(kb([5] * len(ts), [1.0] * len(ts), ts))
+        assert w.late_records_dropped == late
+        w.process_batch(kb([5], [2.0], [250]))      # inside retention
+        assert w.late_records_dropped == late
+        # (window [100, 300) is past its own retention: not re-fired)
+        assert fired(w.on_watermark(10 ** 6)) == {
+            (5, 200, 400): 2.0,
+            (5, 300, 500): 3.0, (5, 400, 600): 3.0}
+        assert w.table.num_used == 0
+        sweeps = rec.kind_totals().get("resolve.sweep",
+                                       {"count": 0, "work": 0})
+        rec.clear()
+        if slotmap_available():
+            assert sweeps["count"] == (3 if late == 0 else 2)
+            assert sweeps["work"] == 3 + (3 if late == 0 else 0)
+        else:
+            assert sweeps["count"] == 0
 
 
 class TestSessionLateness:

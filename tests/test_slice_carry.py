@@ -384,3 +384,58 @@ def test_mesh_sink_rows_bit_identical(index_cls, monkeypatch, async_ok):
             capacity=1 << 12, allowed_lateness=200),
         False, monkeypatch, async_ok)
     assert [r[:2] for r in got] == [r[:2] for r in single]
+
+
+def test_window_after_swept_batches_carries_its_matrix(index_cls):
+    """Batches resolved by the native sweep append each slice's new slots
+    to the registry as the lookup did (a namespace's list only grows at
+    its end), so the fire after them resolves the slice that entered —
+    ``fire.shard``'s work — and not the window, and what it fires on
+    equals a rebuild."""
+    from flink_tpu.observe import flight_recorder as flight
+
+    w = SliceSharedWindower(SlidingEventTimeWindows.of(K * 100, 100),
+                            SumAggregate("v"), capacity=1 << 12)
+    assert type(w.table.index) is index_cls
+    index = w.table.index
+    seen = []
+    build = w.table.build_slice_matrix
+
+    def checked(ends):
+        keys, matrix, cells = build(ends)
+        assert as_rows(keys, matrix) == rebuilt(index, ends)
+        seen.append(cells)
+        return keys, matrix, cells
+
+    w.table.build_slice_matrix = checked
+    rng = np.random.default_rng(8)
+    rec = flight.recorder()
+    rec.clear()
+    entered = []
+    batches = 0
+    for s in range(12):
+        # two in-order batches per slice: the second appends to the
+        # slice's list a chunk of its own
+        before = index.pairs_inserted
+        for half in range(2):
+            n = 200
+            ts = np.sort(rng.integers(s * 100 + half * 50,
+                                      s * 100 + half * 50 + 50, n))
+            w.process_batch(kb(rng.integers(0, 150, n), np.ones(n), ts))
+            batches += 1
+        entered.append(index.pairs_inserted - before)
+        assert len(w.on_watermark(s * 100 + 99)) == 1
+    kt = rec.kind_totals()
+    rec.clear()
+    swept = kt.get("resolve.sweep", {"count": 0})["count"]
+    assert swept == (batches if index_cls is NativeSlotIndex else 0)
+    # window s holds slices s-K+1 .. s: each fire resolved exactly the
+    # pairs its newest slice was given, the first one included (from
+    # nothing, when that slice is all there is)
+    assert seen == entered and min(entered) > 100
+    assert kt["fire.shard"]["count"] == 12
+    assert kt["fire.shard"]["work"] == sum(entered) \
+        == kt["prep.resolve"]["work"]
+    live = sum(len(index.slots_for_namespace(ns))
+               for ns in index.namespaces)
+    assert seen[-1] < live / 3          # a rebuild would resolve these
