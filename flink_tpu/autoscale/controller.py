@@ -4,8 +4,8 @@ Two execution paths, one decision loop:
 
 - **live** — a mesh engine (``MeshWindowEngine`` / ``MeshSessionEngine``)
   migrates its key groups in place via ``engine.reshard(target)``: no
-  stop-and-redeploy, no checkpoint round-trip, handoff measured in the
-  tens of milliseconds (BENCHMARKS.md "rescale handoff" row).
+  stop-and-redeploy, no checkpoint round-trip (the handoff's time on
+  the chip is not measured: no benchmark cell rescales).
 - **cold** — a minicluster job redeploys at the new parallelism from its
   latest checkpoint via ``JobMaster.request_rescale(target)`` (the
   reactive-rescale path, reference: AdaptiveScheduler Executing ->

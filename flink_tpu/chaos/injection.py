@@ -8,8 +8,7 @@ in one process group here):
 - Code under test declares **named fault points**:
   ``chaos.fault_point("shuffle.bucket_send", shard=p)``. With no
   controller armed the call is a no-op costing one module-global load
-  and a ``None`` check — cheap enough for per-batch hot paths (the
-  tier-1 bench gate pins the disarmed overhead).
+  and a ``None`` check — cheap enough for per-batch hot paths.
 - A :class:`FaultPlan` maps point-name PATTERNS (fnmatch) to seeded
   schedules and fault kinds. Any run is exactly reproducible from
   ``(plan, seed)``: nth-hit schedules count matching hits, and the

@@ -528,9 +528,10 @@ class LocalExecutor:
                 from flink_tpu.metrics.core import quantile_sorted
 
                 # the `window` group: live fire-latency percentiles per
-                # stateful operator, fed from the SAME bounded reservoir
-                # the bench and the job result read — the latency tier's
-                # observable surface (KNOWN_METRIC_GROUPS discipline;
+                # stateful operator, fed from the operator's bounded
+                # reservoir (the autoscaler's fire_p99 reads it too) —
+                # the latency tier's observable surface
+                # (KNOWN_METRIC_GROUPS discipline;
                 # supersedes the old top-level windowFireLatencyP99Ms
                 # gauge, which had no consumers)
                 wg = g.add_group("window")
@@ -847,11 +848,6 @@ class LocalExecutor:
             raise
 
         elapsed = time.perf_counter() - t0
-        fire_latencies: List[float] = []
-        for node in nodes.values():
-            lat = getattr(node.operator, "fire_latencies_ms", None)
-            if lat:
-                fire_latencies.extend(lat)  # deque -> list copy
         metrics = {
             "records_emitted_by_sources": total_records,
             "runtime_s": elapsed,
@@ -866,16 +862,6 @@ class LocalExecutor:
                 for uid, n in nodes.items()
             },
         }
-        if fire_latencies:
-            from flink_tpu.metrics.core import quantile_sorted
-
-            fire_latencies.sort()
-            metrics["window_fire_latency_ms"] = {
-                "p50": quantile_sorted(fire_latencies, 0.5),
-                "p99": quantile_sorted(fire_latencies, 0.99),
-                "max": fire_latencies[-1],
-                "count": len(fire_latencies),
-            }
         if getattr(self, "fallback_reason", None):
             # surfaced in REST job status: the user asked for stage
             # parallelism but opted into single-slot fallback

@@ -894,7 +894,7 @@ class _SourceSubtask(threading.Thread):
                  max_parallelism: int, batch_size: int,
                  coordinator: "_Coordinator", source,
                  restore_position=None, batch_mode: bool = False,
-                 source_index: int = 0):
+                 source_index: int = 0, ckpt_every_n: int = 0):
         self.spec = spec
         self.source_index = source_index
         super().__init__(
@@ -916,6 +916,14 @@ class _SourceSubtask(threading.Thread):
         self.chain: Optional[_OperatorChain] = None
         self.records_polled = 0
         self.batches_polled = 0
+        #: execution.checkpointing.every-n-source-batches (0 = not the
+        #: trigger): the coordinator polls this subtask's batch count on
+        #: a wall clock, so the subtask itself holds at the N-th batch
+        #: since its last barrier until the next one is served —
+        #: otherwise a starved coordinator lets the source run any
+        #: number of batches past the trigger it calls deterministic
+        self.ckpt_every_n = ckpt_every_n
+        self._batches_at_barrier = 0
         #: position at exit — checkpoints after this subtask drains its
         #: split still record where it ended (restore must not replay it)
         self.final_position = None
@@ -949,6 +957,10 @@ class _SourceSubtask(threading.Thread):
         try:
             while not stopping:
                 stopping = self._serve_control()
+                if not stopping and self.ckpt_every_n and (
+                        self.batches_polled - self._batches_at_barrier
+                        >= self.ckpt_every_n):
+                    stopping = self._await_due_barrier()
                 if stopping:
                     break
                 if self.coordinator.cancelled.is_set():
@@ -996,6 +1008,18 @@ class _SourceSubtask(threading.Thread):
             snap.update(r.snapshot(graph, savepoint=savepoint))
         return snap
 
+    def _await_due_barrier(self) -> bool:
+        """Hold at the every-N-batches boundary until the coordinator's
+        barrier is in the control queue, then serve it. The coordinator
+        counts batches from its trigger and this subtask from its serve,
+        so the coordinator's count is never the smaller: a subtask that
+        is due here is due there too. Returns the stop flag."""
+        while self.control.empty():
+            if self.coordinator.cancelled.is_set():
+                return False
+            time.sleep(0.001)
+        return self._serve_control()
+
     def _serve_control(self) -> bool:
         """Returns True when the job should stop (stop-with-savepoint)."""
         stopping = False
@@ -1005,6 +1029,7 @@ class _SourceSubtask(threading.Thread):
             except _q.Empty:
                 return stopping
             barrier: Barrier = trigger
+            self._batches_at_barrier = self.batches_polled
             snap = {"position": self.source.snapshot_position(),
                     "operators": self.snapshot_operators(
                         self.graph,
@@ -1703,7 +1728,9 @@ class StageParallelExecutor:
                     max_par, batch_size, coordinator, src,
                     restore_position=per_src_pos.get(s),
                     batch_mode=batch_mode,
-                    source_index=i))
+                    source_index=i,
+                    ckpt_every_n=(ckpt_every_n
+                                  if storage is not None else 0)))
         shared_sinks: Dict[int, _SharedSink] = {}
         mesh_devices = cfg.get(DeploymentOptions.STAGE_MESH_DEVICES)
         memory_manager = None

@@ -255,8 +255,7 @@ class ServingOptions:
         "generation — snapshot isolation, zero contention with "
         "ingest. false = every lookup takes the legacy control-queue "
         "path, serialized behind the owning job's batch boundaries "
-        "(the pre-replica behavior; also the A/B lever the NOTES_r17 "
-        "measurements use). Plain LocalExecutor runs never arm a "
+        "(the pre-replica behavior). Plain LocalExecutor runs never arm a "
         "replica regardless — publishing costs a per-boundary "
         "metadata diff that only pays off when something reads it.")
     PUBLISH_INTERVAL_MS = ConfigOption(
@@ -266,9 +265,9 @@ class ServingOptions:
         "tightest staleness. > 0 batches boundaries under one publish: "
         "the per-boundary metadata diff is paid once per interval and "
         "the hot-row cache invalidates at a bounded rate (lookup "
-        "staleness stays <= the interval + one boundary). The serving "
-        "bench runs 25 ms; per-boundary costs only matter when "
-        "boundaries are much more frequent than readers need.")
+        "staleness stays <= the interval + one boundary). Per-boundary "
+        "costs only matter when boundaries are much more frequent than "
+        "readers need.")
     # NOTE: the worker-pool size and hot-row cache capacity are
     # CLUSTER-scoped (one serving plane serves every tenant), so they
     # are constructor parameters of ServingPlane / SessionCluster, not
@@ -466,9 +465,9 @@ class StateOptions:
         "slot table — the general engine: sessions, spill, mesh), "
         "'panes' (ring-of-slices x key-rows — fires are pure device "
         "reductions with no per-fire host->device transfer; aligned "
-        "windows on one device only), or 'auto' (currently resolves to "
-        "'slots'; flips to panes once hardware measurements land — "
-        "bench.py measures both).")
+        "windows on one device only; has not run on the chip), or "
+        "'auto' (the slot layout; ROADMAP.md queue 3 item 7's A/B "
+        "decides whether panes stay).")
     SPILL_DIR = ConfigOption(
         "state.spill.dir", default=None, type=str,
         description="Filesystem tier for spilled state (any core.fs "

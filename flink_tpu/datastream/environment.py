@@ -32,11 +32,6 @@ class StreamExecutionEnvironment:
     def __init__(self, config: Optional[Configuration] = None):
         self.config = config or Configuration()
         self._sinks: List[Transformation] = []
-        #: JobExecutionResult of the most recent execute(), None before
-        #: the first run — convenience wrappers (execute_and_collect,
-        #: SQL collect) discard the result; callers that still want the
-        #: job metrics (e.g. bench fire-latency percentiles) read this
-        self.last_execution_result = None
 
     def _effective_config(self) -> Configuration:
         """CLI `-D` dynamic properties override programmatic config —
@@ -188,10 +183,6 @@ class StreamExecutionEnvironment:
                               restore_from=restore_from,
                               restore_mode=restore_mode)
         self._sinks = []
-        #: kept for callers that run through a convenience wrapper
-        #: (execute_and_collect, SQL collect) and still want the job
-        #: metrics — e.g. the bench suite's fire-latency percentiles
-        self.last_execution_result = result
         return result
 
 

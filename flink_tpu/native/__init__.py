@@ -3,8 +3,8 @@
 Build happens on demand with g++ (no pip deps): the shared object is cached
 under ``native/build/`` next to a source-hash stamp, so editing a ``.cpp``
 always triggers a rebuild (mtime alone lies after checkouts/copies). Set
-``FLINK_TPU_NO_NATIVE=1`` (or ``FLINK_TPU_NATIVE=0``) to force the pure
-Python fallbacks (used in tests to cover both paths).
+``FLINK_TPU_NO_NATIVE=1`` to force the pure Python fallbacks (used in
+tests to cover both paths): it is the one switch.
 
 Every function fetched off a CDLL returned by :func:`load_native` must
 declare ``argtypes`` AND ``restype`` before its first call — a missing
@@ -55,13 +55,12 @@ _tried = False
 
 
 def native_disabled() -> bool:
-    return (os.environ.get("FLINK_TPU_NO_NATIVE") == "1"
-            or os.environ.get("FLINK_TPU_NATIVE") == "0")
+    return os.environ.get("FLINK_TPU_NO_NATIVE") == "1"
 
 
 #: count of LOUD degradations to a Python fallback plane (build
 #: failure, load failure, runtime sweep error) — 0 on a healthy deploy.
-#: Explicit opt-outs (FLINK_TPU_NO_NATIVE=1 etc.) do NOT count: only
+#: The explicit opt-out (FLINK_TPU_NO_NATIVE=1) does NOT count: only
 #: the cases where native was wanted and silently losing it would hide
 #: a throughput regression behind a green suite.
 _fallbacks = 0
@@ -138,7 +137,7 @@ def _source_hash(src: str) -> str:
 def load_native(src_basename: str, so_basename: str) -> Optional[ctypes.CDLL]:
     """Compile-on-demand ctypes loader shared by every native component
     (slotmap, sessions, codec, datagen). Returns the CDLL, or None when
-    disabled (FLINK_TPU_NO_NATIVE=1 / FLINK_TPU_NATIVE=0) or the
+    disabled (FLINK_TPU_NO_NATIVE=1) or the
     toolchain/compile is unavailable.
 
     Staleness: the cached ``.so`` is paired with a ``.srchash`` stamp

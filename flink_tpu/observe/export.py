@@ -3,8 +3,9 @@ event-time latency markers.
 
 Three consumers of the one span plane (:mod:`flink_tpu.observe.
 flight_recorder`), so the attribution the recorder captures is also
-what every surface shows — the bench breakdowns, the dashboard and a
-Perfetto timeline can never disagree about where the time went:
+what every surface shows — the benchmark's per-layer metrics, the
+dashboard and a Perfetto timeline can never disagree about where the
+time went:
 
 - :func:`chrome_trace` / :func:`write_chrome_trace` — the Chrome trace
   event JSON format (load the file at https://ui.perfetto.dev or
@@ -119,7 +120,7 @@ def write_chrome_trace(path: str,
 
 def validate_trace_schema(trace: Dict[str, Any],
                           known_kinds) -> List[str]:
-    """Schema check the trace smoke gates on: every duration/instant
+    """Schema check of an exported trace: every duration/instant
     event's name is a registered span kind, batch-lifecycle events
     carry batch attribution, and fire events carry watermark
     attribution. Returns a list of violations (empty = valid)."""

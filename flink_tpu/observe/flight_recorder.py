@@ -1,14 +1,14 @@
 """Flight recorder: always-on, bounded-overhead per-batch pipeline
 tracing.
 
-Every perf round so far rediscovered WHERE the time went through ad-hoc
-bench counters (``host_prep_fraction``, ``native_sweep_s``,
-``pipeline_wait_s``); the reference dedicates a whole layer to making
-that a standing capability (SURVEY/PAPER §5 — spans, flame graphs,
-latency markers, the webmonitor). This module is that layer for the
-micro-batch mesh engines: a process-global recorder the hot paths write
-into unconditionally, cheap enough to leave on (the tier-1 trace smoke
-gates recorder-on throughput at <=3% of recorder-off).
+WHERE the time went used to be rediscovered through ad-hoc counters;
+the reference dedicates a whole layer to making that a standing
+capability (SURVEY/PAPER §5 — spans, flame graphs, latency markers, the
+webmonitor). This module is that layer for the micro-batch engines: a
+process-global recorder the hot paths write into unconditionally, cheap
+enough to leave on (a span costs 3.96 us on the chip's host, PERF.md
+§6, PR 26; benchmark/ reads its per-kind totals for every per-layer
+metric).
 
 Design constraints, in order:
 
@@ -51,7 +51,7 @@ Design constraints, in order:
 Span kinds are a closed registry (:data:`flink_tpu.observe.
 KNOWN_SPAN_KINDS`): an unregistered kind raises at the call site, and
 flint's REG03 cross-checks every literal producer statically — the
-recorder, the exporter schema and the trace smoke cannot drift.
+recorder and the exporter schema cannot drift.
 
 Usage::
 
@@ -614,8 +614,8 @@ def enabled() -> bool:
 
 
 class disabled:
-    """Context manager suppressing recording (the trace smoke's A/B
-    lever; also usable to exclude a noisy region)."""
+    """Context manager suppressing recording (to exclude a noisy
+    region, or for a recorder-off control run)."""
 
     def __enter__(self):
         global _enabled

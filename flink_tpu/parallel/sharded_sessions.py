@@ -515,10 +515,12 @@ class MeshSessionEngine(MeshPagedSpillSupport):
                     fills=fills, pool=self._shuffle_pool,
                     traffic=self._exchange2_traffic)
             s1, s2 = self._exchange2_steps
-            with flight.span("exchange.stage1"), self._device_span():
+            with flight.span("exchange.stage1"), \
+                    flight.span("device.dispatch"):
                 put = jax.device_put((dst, *staged), self._sharding)
                 inter = s1(put[0], put[1], tuple(put[2:]), w1)
-            with flight.span("exchange.stage2"), self._device_span():
+            with flight.span("exchange.stage2"), \
+                    flight.span("device.dispatch"):
                 self.accs = s2(self.accs, inter[0], inter[1],
                                tuple(inter[2:]), w2)
             chaos.fault_point("shuffle.device_exchange", records=n)
@@ -527,7 +529,7 @@ class MeshSessionEngine(MeshPagedSpillSupport):
                 dst, staged, width = stage_device_exchange(
                     rec_shards, self.P, columns=columns, fills=fills,
                     pool=self._shuffle_pool)
-            with self._device_span():
+            with flight.span("device.dispatch"):
                 # ONE host->device hop: all flat columns in a single
                 # device_put, then the fused exchange+scatter program
                 put = jax.device_put((dst, *staged), self._sharding)
@@ -543,7 +545,7 @@ class MeshSessionEngine(MeshPagedSpillSupport):
                     pool=self._shuffle_pool)
             slot_block = blocked[0]
             value_blocks = blocked[1:]
-            with self._device_span():
+            with flight.span("device.dispatch"):
                 self.accs = self._scatter_step(
                     self.accs,
                     self._put_sharded(slot_block),
@@ -605,7 +607,7 @@ class MeshSessionEngine(MeshPagedSpillSupport):
         for p, (d_slots, s_slots) in enumerate(per_shard):
             dst_block[p, : len(d_slots)] = d_slots
             src_block[p, : len(s_slots)] = s_slots
-        with self._device_span():
+        with flight.span("device.dispatch"):
             self.accs = self._merge_step(
                 self.accs, self._put_sharded(dst_block),
                 self._put_sharded(src_block))
@@ -1581,5 +1583,4 @@ class MeshSessionEngine(MeshPagedSpillSupport):
         py = SessionIntervalSet(self.gap, self.allowed_lateness)
         py.restore(self.meta.snapshot())
         py.late_records_dropped = self.meta.late_records_dropped
-        py.native_sweep_s = self.meta.native_sweep_s
         self.meta = py

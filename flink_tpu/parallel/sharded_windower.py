@@ -67,28 +67,6 @@ from flink_tpu.tenancy.program_cache import PROGRAM_CACHE
 _FENCE_STEP = jax.jit(lambda a: a[:1, :1])
 
 
-class _DeviceSpan:
-    """One device-interaction block: a ``device.dispatch`` span of the
-    flight recorder (the ``with`` yields it, so a block can state its
-    ``work``: bytes put through the exchange) whose duration also lands
-    in the owner's ``device_inline_s`` (see
-    MeshSpillSupport._init_pipeline) — the bench breakdown and a
-    Perfetto trace read ONE measurement."""
-
-    __slots__ = ("_owner", "_span")
-
-    def __init__(self, owner) -> None:
-        self._owner = owner
-
-    def __enter__(self):
-        self._span = flight.span("device.dispatch", timed=True)
-        return self._span.__enter__()
-
-    def __exit__(self, *exc) -> None:
-        self._span.__exit__(*exc)
-        self._owner.device_inline_s += self._span.duration_s
-
-
 class MeshSpillSupport:
     """Per-shard spill tier shared by the mesh window and mesh session
     engines: LRU namespace eviction under a per-device slot budget, batched
@@ -227,20 +205,6 @@ class MeshSpillSupport:
         self._shuffle_pool = ShuffleBufferPool(
             generations=self._pipeline_depth)
         self._dispatch_fences = deque()
-        #: wall seconds the host spent BLOCKED on dispatch fences (the
-        #: in-flight device work the pipeline could not hide) — the
-        #: bench reads this to attribute fence waits to device time
-        #: instead of host prep; survives reshard like the counters
-        if not hasattr(self, "pipeline_wait_s"):
-            self.pipeline_wait_s = 0.0
-        #: wall seconds spent INSIDE device interactions on the ingest
-        #: path (H2D puts, the fused exchange / scatter / merge / put
-        #: dispatches, eviction gathers + their D2H reads). On an
-        #: async accelerator link these overlap host prep; on the CPU
-        #: backend they execute inline, so the bench subtracts them
-        #: from process_batch wall time to report genuine host prep.
-        if not hasattr(self, "device_inline_s"):
-            self.device_inline_s = 0.0
         #: monotonically increasing per-engine batch sequence — the
         #: flight recorder's batch_id attribution (survives reshard)
         if not hasattr(self, "_flight_batch"):
@@ -253,12 +217,6 @@ class MeshSpillSupport:
         context)."""
         self._flight_batch += 1
         return flight.ingest_span(self._flight_batch)
-
-    def _device_span(self) -> "_DeviceSpan":
-        """Context manager accumulating into ``device_inline_s`` —
-        a slotted object, not a per-call generator (this sits on the
-        per-batch path the host-prep gate measures)."""
-        return _DeviceSpan(self)
 
     # ------------------------------------------------------------- watchdog
 
@@ -548,7 +506,7 @@ class MeshSpillSupport:
         before this batch's staging buffers are (re)written."""
         if len(self._dispatch_fences) < self._pipeline_depth:
             return
-        with flight.span("device.fence_wait", timed=True) as wait, \
+        with flight.span("device.fence_wait"), \
                 self._wd_section("fence_drain"):
             while len(self._dispatch_fences) >= self._pipeline_depth:
                 # flint: disable=TRC01 -- the depth-bounded fence drain
@@ -556,7 +514,6 @@ class MeshSpillSupport:
                 # only when the host ran a full pipeline depth ahead of
                 # the device
                 self._dispatch_fences.popleft().block_until_ready()
-        self.pipeline_wait_s += wait.duration_s
 
     def _push_dispatch_fence(self) -> None:
         # chaos: a fence failure mid-dispatch-ahead — the batch's device
@@ -566,8 +523,9 @@ class MeshSpillSupport:
         chaos.fault_point("mesh.dispatch_fence",
                           in_flight=len(self._dispatch_fences))
         # fence creation dispatches a (tiny) device program — an inline
-        # device interaction, attributed as such for the host-prep gate
-        with self._device_span(), self._wd_section("dispatch_fence"):
+        # device interaction, attributed as such in the trace
+        with flight.span("device.dispatch"), \
+                self._wd_section("dispatch_fence"):
             self._dispatch_fences.append(self.make_fence())
 
     @property
@@ -1890,7 +1848,7 @@ class MeshPagedSpillSupport(MeshSpillSupport):
                 slot_block[p, :n] = rslots
                 for i in range(len(val_blocks)):
                     val_blocks[i][p, :n] = rvals[i]
-            with self._device_span():
+            with flight.span("device.dispatch"):
                 self.accs = self._put_step(
                     self.accs, self._put_sharded(slot_block),
                     tuple(self._put_sharded(v) for v in val_blocks))
@@ -1966,7 +1924,7 @@ class MeshPagedSpillSupport(MeshSpillSupport):
         block = np.zeros((self.P, G), dtype=np.int32)
         for p, chosen in cohorts.items():
             block[p, : len(chosen)] = chosen
-        with self._device_span():
+        with flight.span("device.dispatch"):
             gathered = self._gather_step(self.accs,
                                          self._put_sharded(block))
             # ONE batched D2H
@@ -1993,7 +1951,7 @@ class MeshPagedSpillSupport(MeshSpillSupport):
         rb = np.zeros((self.P, R), dtype=np.int32)
         for p, chosen in cohorts.items():
             rb[p, : len(chosen)] = chosen
-        with self._device_span():
+        with flight.span("device.dispatch"):
             self.accs = self._reset_step(self.accs,
                                          self._put_sharded(rb))
 
@@ -2327,7 +2285,7 @@ class MeshWindowEngine(MeshSpillSupport):
             resolve.work = self._pairs_inserted() - inserted
 
         step = self._valued_scatter_step if partial else self._scatter_step
-        with self._device_span():
+        with flight.span("device.dispatch"):
             self.accs = step(
                 self.accs,
                 self._put_sharded(slot_block),
@@ -2412,11 +2370,13 @@ class MeshWindowEngine(MeshSpillSupport):
             row = sum(c.nbytes for c in staged) // len(dst)
             topo = self.host_topology
             hosts, local = topo.num_hosts, topo.local_devices
-            with flight.span("exchange.stage1") as x1, self._device_span():
+            with flight.span("exchange.stage1") as x1, \
+                    flight.span("device.dispatch"):
                 put = jax.device_put((dst, *staged), self._sharding)
                 inter = s1(put[0], put[1], tuple(put[2:]), w1)
                 x1.work = self.P * local * w1 * row
-            with flight.span("exchange.stage2") as x2, self._device_span():
+            with flight.span("exchange.stage2") as x2, \
+                    flight.span("device.dispatch"):
                 self.accs = s2(self.accs, inter[0], inter[1],
                                tuple(inter[2:]), w2)
                 x2.work = self.P * hosts * w2 * row
@@ -2429,7 +2389,7 @@ class MeshWindowEngine(MeshSpillSupport):
                     pool=self._shuffle_pool,
                 )
                 stage.work = dst.nbytes + sum(c.nbytes for c in staged)
-            with self._device_span() as dispatch:
+            with flight.span("device.dispatch") as dispatch:
                 # ONE host->device hop for the whole batch: every flat
                 # column in a single device_put against the key-group
                 # sharding

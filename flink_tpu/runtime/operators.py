@@ -303,16 +303,13 @@ class WindowAggOperator(Operator):
             if self._managed_memory(ctx) is not None:
                 table_kwargs["memory"] = self._managed_memory(ctx)
             has_spill = bool(self.spill and any(self.spill.values()))
-            # 'auto' currently resolves to the slot layout: the pane
-            # layout's dense fires measure SLOWER on CPU, and its win case
-            # — removing the per-fire host->device slot matrix on the
-            # transfer-constrained TPU link — is not yet hardware-measured
-            # (bench.py measures both layouts and reports the better).
-            # Flip 'auto' here once the TPU numbers land. An explicit
-            # 'panes' is honored for aligned windows without spill; note
-            # its footprint is DENSE ([ring_rows, key_capacity] per leaf),
-            # so high-ratio sliding windows multiply HBM by the slice
-            # count.
+            # 'auto' is the slot layout: every benchmark cell runs it.
+            # The pane layout has not run on the chip; whether it stays
+            # is decided by the A/B in ROADMAP.md queue 3 item 7. An
+            # explicit 'panes' is honored for aligned windows without
+            # spill; its footprint is DENSE ([ring_rows, key_capacity]
+            # per leaf), so high-ratio sliding windows multiply HBM by
+            # the slice count.
             use_panes = self.window_layout == "panes"
             if use_panes and has_spill:
                 raise ValueError(

@@ -101,8 +101,8 @@ class MeshStalledError(RuntimeError):
 
 
 class _Section:
-    """One timed device interaction (slotted: sections sit on per-batch
-    paths the host-prep gate measures)."""
+    """One timed device interaction (slotted: sections sit on
+    per-batch paths)."""
 
     __slots__ = ("_wd", "_op", "_shard", "_t0")
 

@@ -4,7 +4,8 @@ Before this module, ~eight homes (SlotTable, PaneTable, the two mesh
 engines, the join side tables, the replica publisher, the two-level
 exchange, CEP) each hand-rolled their own gather / scatter / evict /
 snapshot program families — flint's TRC01 sweep once fixed the same
-bug class in five of them (NOTES_r9). This module is the ONE home:
+bug class (a host sync inside the traced path) in five of them. This
+module is the ONE home:
 every compiled state-plane program the engines dispatch is built here
 (or in a sibling stateplane module) and cached in the shared
 :data:`~flink_tpu.tenancy.program_cache.PROGRAM_CACHE` under a family

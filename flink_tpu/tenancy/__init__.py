@@ -24,7 +24,8 @@ hardware. Four pillars:
   never touch the device, and cache misses batch per sealed
   generation on sharded worker queues — one gather program + ONE
   ``jax.device_get`` per miss batch (the flint TRC01 discipline),
-  measured as the ``queryable_lookups_per_s`` bench row. The legacy
+  (lookups/s on the chip: not measured, no benchmark cell serves
+  lookups yet). The legacy
   control-queue coalescers remain for single-device engines and the
   cold-row (page tier) detour. Since r19 the hit path is NATIVE
   (:mod:`hot_cache_native` over ``native/hotcache.cpp``): a whole key

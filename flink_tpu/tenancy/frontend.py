@@ -1,9 +1,10 @@
 """Multi-process serving tier: frontend processes over the shm hot cache.
 
-NOTES_r19's ceiling analysis said it plainly: at 1.14M lookups/s the
-native probe is ~3% of one core — past ~1.3M/s the serving CLIENTS
-starve the publish loop, so the next factor needs more cores, not a
-faster probe. This module is that factor, split by role:
+The native probe is a small share of one core: past a point the
+serving CLIENTS, not the probe, starve the owner's publish loop, so the
+next factor needs more cores, not a faster probe (a CPU-box reading;
+lookups have no benchmark cell on the chip yet, ROADMAP.md queue 2
+B.10). This module is that factor, split by role:
 
 - the OWNER process keeps ingest + publish/prime exactly as today
   (``ServingPlane`` with a shm-backed ``NativeHotRowCache``,

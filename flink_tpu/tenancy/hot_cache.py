@@ -69,21 +69,18 @@ def make_hot_row_cache(max_entries: int = 1 << 18,
     shm-map — requesting ``shm_dir`` without the native plane raises
     rather than silently serving a frontendless cache.
 
-    ``FLINK_TPU_NATIVE_HOTCACHE=0`` forces the Python plane while other
-    native components stay on — the A/B knob the serving bench and the
-    NOTES_r19 walk use (the blanket ``FLINK_TPU_NO_NATIVE=1`` disables
-    everything native). Unavailability (no toolchain, build failure)
-    degrades LOUDLY via ``flink_tpu.native.note_fallback``."""
-    import os
-
+    ``FLINK_TPU_NO_NATIVE=1`` selects the Python plane (with every
+    other native component); a test that wants only this plane in
+    Python constructs :class:`HotRowCache` itself. Unavailability (no
+    toolchain, build failure) degrades LOUDLY via
+    ``flink_tpu.native.note_fallback``."""
     from flink_tpu.native import (
         hotcache_available,
         native_disabled,
         note_fallback,
     )
 
-    if (os.environ.get("FLINK_TPU_NATIVE_HOTCACHE") != "0"
-            and not native_disabled()):
+    if not native_disabled():
         if hotcache_available():
             try:
                 from flink_tpu.tenancy.hot_cache_native import (

@@ -5,7 +5,7 @@ paid a fresh gather + ``device_get`` against the LIVE state plane,
 serialized behind the owning job's batch boundaries (the control-queue
 detour — reads had to wait for the single-owner task loop because the
 live plane mutates under them). At serving QPS that serialization IS
-the latency: BENCHMARKS.md recorded p99 153 ms.
+the latency.
 
 This module decouples readers from ingest with a device-resident READ
 REPLICA of the hot slot rows:

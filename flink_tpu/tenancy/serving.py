@@ -68,8 +68,8 @@ def aggregate_lookup_stats(coalescers,
     tier's shm-header counters. Frontend-served probes fold into
     ``lookups_total`` (a frontend hit IS a served lookup that never
     reached a coalescer) and the per-counter sums ride along under
-    ``frontend_*``, so the bench breakdown derives from the real
-    counters, not wall-clock division."""
+    ``frontend_*``, so a breakdown derives from the real counters, not
+    wall-clock division."""
     lookups = 0
     batches = 0
     lat: List[float] = []
@@ -534,7 +534,7 @@ class ServingPlane:
         from flink_tpu.tenancy.hot_cache import make_hot_row_cache
 
         #: the native GIL-free probe table when available, else the
-        #: bit-identical Python LRU (FLINK_TPU_NATIVE_HOTCACHE=0 A/B)
+        #: bit-identical Python LRU
         self.hot_cache = make_hot_row_cache(cache_entries,
                                             shm_dir=shm_dir)
         self._workers: List[_ReplicaWorker] = []

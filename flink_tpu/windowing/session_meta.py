@@ -149,10 +149,6 @@ class SessionIntervalSet:
     def __init__(self, gap: int, allowed_lateness: int = 0):
         self.gap = int(gap)
         self.allowed_lateness = int(allowed_lateness)
-        #: time spent inside the native sweep calls (absorb + pop); the
-        #: pure-Python plane keeps it at 0.0 — bench tooling reports it
-        #: as its own host-prep line
-        self.native_sweep_s = 0.0
         #: keys with >= 2 live sessions: reference-shaped interval lists
         self._multi: Dict[int, List[Tuple[int, int, int]]] = {}
         self._reset_store()
@@ -838,9 +834,9 @@ def make_session_meta(gap: int,
     ``make_slot_index`` picks the state-plane index. Fires and snapshots
     are bit-identical across planes (test-pinned).
 
-    ``FLINK_TPU_NATIVE_SESSIONS=0`` forces the Python plane while the
-    native state-plane index stays on — the A/B knob bench and parity
-    tooling use (the blanket ``FLINK_TPU_NO_NATIVE=1`` disables both).
+    ``FLINK_TPU_NO_NATIVE=1`` selects the Python plane (with every
+    other native component); a test that wants only this plane in
+    Python constructs :class:`SessionIntervalSet` itself.
 
     Graceful degradation: when the native plane was NOT explicitly
     disabled but is unavailable (the ``.so`` failed to build — missing
@@ -849,16 +845,13 @@ def make_session_meta(gap: int,
     reason plus the ``flink_tpu.native.native_fallbacks()`` counter —
     a silent fallback would hide a 1.3x throughput regression behind a
     green suite."""
-    import os
-
     from flink_tpu.native import (
         native_disabled,
         note_fallback,
         sessions_available,
     )
 
-    if (os.environ.get("FLINK_TPU_NATIVE_SESSIONS") != "0"
-            and not native_disabled()):
+    if not native_disabled():
         if sessions_available():
             try:
                 from flink_tpu.windowing.session_native import (

@@ -24,7 +24,7 @@ One C sweep per batch replaces the numpy hot loop of
   for flat staging and ``free_slots(keys=, nss=)``.
 
 The pure-Python plane remains the bit-identical fallback
-(``FLINK_TPU_NO_NATIVE=1`` / ``FLINK_TPU_NATIVE=0`` / compiler absent);
+(``FLINK_TPU_NO_NATIVE=1`` / compiler absent);
 :func:`flink_tpu.windowing.session_meta.make_session_meta` selects per
 engine, the way ``make_slot_index`` already does for the state plane.
 """
@@ -32,7 +32,6 @@ engine, the way ``make_slot_index`` already does for the state plane.
 from __future__ import annotations
 
 import ctypes as _ct
-import time
 from typing import List, Optional, Tuple
 
 import numpy as np
@@ -330,13 +329,11 @@ class NativeSessionIntervalSet(SessionIntervalSet):
                         want_fresh: bool = True) -> AbsorbResult:
         # want_fresh is accepted for interface parity and ignored: the
         # sweep's flag column makes the fresh mask a free compare
-        t0 = time.perf_counter()
         (m, n_fast, order, rec_to_sess, sess_key, sess_start, sess_end,
          sess_sid, sess_slot, sess_row, sess_flag) = native_absorb(
             self._store, keys, ts, self.gap, self.allowed_lateness,
             self.max_fired_watermark, self._next_sid)
         self._next_sid += n_fast
-        self.native_sweep_s += time.perf_counter() - t0
         # slow path: multi-flavored sessions + disjoint seconds, exact
         # reference semantics in the base class, ascending (key, ts)
         slow = np.nonzero(sess_flag == _FLAG_SLOW)[0]
@@ -388,10 +385,8 @@ class NativeSessionIntervalSet(SessionIntervalSet):
             return self._EMPTY_POP_EX
         self._drain_fire_buf()  # buf -> one native chunk
         self._min_pending_end = 1 << 62
-        t0 = time.perf_counter()
         (keys, starts, ends, sids, slots), (rk, rs, re) = native_pop(
             self._store, watermark)
-        self.native_sweep_s += time.perf_counter() - t0
         self.max_fired_watermark = max(self.max_fired_watermark,
                                        watermark)
         if self._multi and len(rk):
@@ -453,7 +448,6 @@ class NativeSessionIntervalSet(SessionIntervalSet):
         fresh_s = np.empty(m, dtype=np.uint8)
         hint_s = np.empty(m, dtype=np.int32)
         row_s = np.empty(m, dtype=np.int32)
-        t0 = time.perf_counter()
         nl = int(self._lib.sx_shard_group(
             m, _i64p(res.sess_key), _i64p(res.sess_sid),
             res.fresh.view(np.uint8).ctypes.data_as(_U8P),
@@ -462,7 +456,6 @@ class NativeSessionIntervalSet(SessionIntervalSet):
             _i64p(shard), _i64p(counts), _i64p(sorted_idx),
             _i64p(key_s), _i64p(sid_s),
             fresh_s.ctypes.data_as(_U8P), _i32p(hint_s), _i32p(row_s)))
-        self.native_sweep_s += time.perf_counter() - t0
         if nl < 0:
             raise ValueError(
                 "session key routed outside the engine's key-group "
@@ -477,11 +470,9 @@ class NativeSessionIntervalSet(SessionIntervalSet):
         kg_first, kg_last = (key_group_range
                              if key_group_range is not None else (-1, -1))
         keys = np.ascontiguousarray(keys, dtype=np.int64)
-        t0 = time.perf_counter()
         mx = int(self._lib.sx_rec_shard_max(
             len(keys), _i64p(keys), int(P), int(maxp),
             int(kg_first), int(kg_last)))
-        self.native_sweep_s += time.perf_counter() - t0
         if mx < 0:
             raise ValueError(
                 "record key routed outside the engine's key-group "
@@ -501,12 +492,10 @@ class NativeSessionIntervalSet(SessionIntervalSet):
         rec_slots = np.empty(n, dtype=np.int32)
         rec_shards = np.empty(n, dtype=np.int64)
         slot_sorted = np.ascontiguousarray(slot_sorted, dtype=np.int32)
-        t0 = time.perf_counter()
         self._lib.sx_route(
             int(n), int(m), _i64p(order), _i64p(rec_to_sess),
             len(sorted_idx), _i64p(sorted_idx), _i32p(slot_sorted),
             _i64p(sess_shard), _i32p(rec_slots), _i64p(rec_shards))
-        self.native_sweep_s += time.perf_counter() - t0
         return rec_slots, rec_shards
 
     # --------------------------------------------------------- snapshot

@@ -10,7 +10,7 @@ mesh window engine and the async-fire/dispatch-ahead pipeline path, and
 
 The LAST test asserts every fault point in the CANONICAL inventory
 (``flink_tpu.chaos.KNOWN_FAULT_POINTS`` — one source of truth, shared
-with flint's REG01 registry check; NOTES_r7.md documents each row) was
+with flint's REG01 registry check) was
 injected at least once across this suite — the tier-1 guarantee that no
 injection site silently goes stale.
 """
@@ -1852,5 +1852,5 @@ class TestZZFaultPointReachability:
         assert not missing, (
             f"fault points never injected across the suite: {missing} "
             f"(reached: {REACHED}) — an injection site moved or a "
-            "schedule went stale; update chaos.KNOWN_FAULT_POINTS, "
-            "tests/test_chaos.py and NOTES_r7.md together")
+            "schedule went stale; update chaos.KNOWN_FAULT_POINTS "
+            "and tests/test_chaos.py together")

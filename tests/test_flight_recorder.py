@@ -533,3 +533,143 @@ class TestShardedCheckpointSpans:
         storage.write_checkpoint(
             1, "job", {(0, 7): {"table": {}}}, {(0, 7): 0})
         assert len(default_collector().spans("checkpoint")) == before + 1
+
+
+def _mesh_session_pass(mesh, host_topology=None, steps=6):
+    """A tiny steady pass of the mesh session engine, built here: fixed
+    batch shape, one watermark advance and harvest per batch, the
+    end-of-input flush last. Returns the rows fired."""
+    from flink_tpu.core.records import (
+        KEY_ID_FIELD,
+        TIMESTAMP_FIELD,
+        RecordBatch,
+    )
+    from flink_tpu.parallel.sharded_sessions import MeshSessionEngine
+    from flink_tpu.windowing.aggregates import SumAggregate
+
+    # a device-slot budget arms the paged layout, whose fire path is
+    # the one with per-shard attribution
+    eng = MeshSessionEngine(16_000, SumAggregate("v"), mesh,
+                            capacity_per_shard=1 << 14,
+                            max_device_slots=1 << 14,
+                            host_topology=host_topology)
+    rng = np.random.default_rng(5)
+    fired, t, n = 0, 0, 4096
+    for _ in range(steps):
+        ts = t + np.arange(n, dtype=np.int64) * 8
+        eng.process_batch(RecordBatch({
+            KEY_ID_FIELD: rng.integers(0, 20_000, n).astype(np.int64),
+            "v": np.ones(n, dtype=np.float32), TIMESTAMP_FIELD: ts}))
+        t = int(ts[-1]) + 1
+        for pf in eng.on_watermark(t - 16_000, async_ok=True):
+            fired += len(pf.harvest())
+    for pf in eng.on_watermark(1 << 60, async_ok=True):
+        fired += len(pf.harvest())
+    return fired
+
+
+class TestMeshSessionCapture:
+    """The capture of a steady pass of the mesh session engine: what the
+    exporters and the recorder's call sites must agree on, and that a
+    warm pass compiles nothing."""
+
+    @pytest.fixture(scope="class")
+    def capture(self):
+        from flink_tpu.parallel.mesh import make_mesh
+
+        flight.install_probes()
+        mesh = make_mesh(4)
+        rec = flight.recorder()
+        flight.set_job("capture-job")
+        _mesh_session_pass(mesh)  # warm: every shape compiles here
+        rec.clear()
+        fired = _mesh_session_pass(mesh)  # fresh engine, warm programs
+        totals = rec.kind_totals()
+        trace = chrome_trace(rec.snapshot(), anchor=rec.anchor)
+        rec.clear()
+        return fired, totals, trace
+
+    @pytest.mark.parametrize("claim", [
+        "schema", "lifecycle", "shard", "no_compile"])
+    def test_steady_pass(self, capture, claim):
+        fired, totals, trace = capture
+        events = [e for e in trace["traceEvents"] if e["ph"] != "M"]
+        assert fired > 0 and len(events) >= 50  # not a vacuous capture
+        if claim == "schema":
+            # every event a registered kind, batch.ingest with its
+            # batch, fire.dispatch with its watermark
+            assert validate_trace_schema(trace, KNOWN_SPAN_KINDS) == []
+            assert any(e["name"] == "batch.ingest" for e in events)
+            assert any(e["name"] == "fire.dispatch" for e in events)
+        elif claim == "lifecycle":
+            assert {"batch.ingest", "fire.dispatch", "fire.harvest",
+                    "device.dispatch"} <= set(totals)
+        elif claim == "shard":
+            shards = {e["args"]["shard"] for e in events
+                      if e["name"] == "fire.shard"}
+            assert shards and min(shards) >= 0
+        else:
+            assert totals.get("xla.compile", {}).get("count", 0) == 0
+
+    def test_two_level_exchange_stages_are_distinct_spans(self):
+        """With the (2 x P/2) topology armed the ICI route and the DCN
+        hop are two span kinds, each with time in it."""
+        from flink_tpu.parallel.mesh import HostTopology, make_mesh
+
+        rec = flight.recorder()
+        rec.clear()
+        _mesh_session_pass(make_mesh(4), HostTopology(2, 2), steps=3)
+        totals = rec.kind_totals()
+        for kind in ("exchange.stage1", "exchange.stage2"):
+            assert totals[kind]["count"] > 0
+            assert totals[kind]["total_s"] > 0
+
+
+class TestServingCapture:
+    def test_lookups_attribute_to_job_and_generation(self):
+        """A tenant's lookups leave serving.lookup spans naming the job
+        with the replica generation in the batch field, and boundary
+        publishes leave serving.replica_publish spans."""
+        from flink_tpu.connectors.sinks import CollectSink
+        from flink_tpu.connectors.sources import DataGenSource
+        from flink_tpu.core.config import Configuration
+        from flink_tpu.datastream.environment import (
+            StreamExecutionEnvironment,
+        )
+        from flink_tpu.runtime.watermarks import WatermarkStrategy
+        from flink_tpu.tenancy.session_cluster import SessionCluster
+        from flink_tpu.windowing.assigners import TumblingEventTimeWindows
+
+        rec = flight.recorder()
+        rec.clear()
+        env = StreamExecutionEnvironment(Configuration({
+            "execution.micro-batch.size": 4096,
+            "parallelism.default": 4,
+        }))
+        (env.add_source(
+            DataGenSource(total_records=32768, num_keys=128,
+                          events_per_second_of_eventtime=50_000, seed=7),
+            WatermarkStrategy.for_bounded_out_of_orderness(0))
+            .key_by("key")
+            .window(TumblingEventTimeWindows.of(60_000))
+            .sum("value").sink_to(CollectSink()))
+        cluster = SessionCluster(quantum_records=4096)
+        cluster.submit(env, "trace-job")
+        rounds = 0
+        while cluster.step_round() and rounds < 8:
+            rounds += 1
+            try:
+                # fresh keys each round miss the cache and reach the
+                # worker flush, which is where the span is
+                cluster.lookup_batch(
+                    "trace-job", "window_agg(SumAggregate)",
+                    list(range(16)) + list(range(rounds * 64,
+                                                 rounds * 64 + 32)))
+            except RuntimeError:
+                pass  # rounds before the first publish
+        cluster.run(timeout_s=120)
+        cluster.serving.shutdown_workers()
+        spans = rec.snapshot()
+        assert [s for s in spans if s.kind == "serving.replica_publish"]
+        assert [s for s in spans if s.kind == "serving.lookup"
+                and s.job == "trace-job" and s.batch_id >= 1]

@@ -942,3 +942,30 @@ class TestSlowLaneBookkeeping:
     def test_slow_marker_is_registered(self):
         src = (REPO_ROOT / "tests/conftest.py").read_text()
         assert '"markers"' in src and "slow:" in src
+
+    def test_gate_runs_what_exists(self):
+        """Every script and module tools/tier1.sh runs is a file of the
+        tree, and its pytest line is the driver's: the slow lane out,
+        6 xdist workers, one file per worker at a time."""
+        import re
+
+        tier1 = (REPO_ROOT / "tools/tier1.sh").read_text()
+        code = "\n".join(ln for ln in tier1.splitlines()
+                         if not ln.lstrip().startswith("#"))
+        scripts = re.findall(r"python3? +([\w./-]+\.py)\b", code)
+        modules = [m for m in re.findall(r"python3? +-m +([\w.]+)", code)
+                   if m != "pytest"]
+        assert scripts and modules, "the gate runs nothing"
+        for path in scripts:
+            assert (REPO_ROOT / path).is_file(), \
+                f"tier1.sh runs {path}, which is not in the tree"
+        for mod in modules:
+            base = REPO_ROOT / mod.replace(".", "/")
+            assert base.with_suffix(".py").is_file() or \
+                (base / "__main__.py").is_file(), \
+                f"tier1.sh runs -m {mod}, which is not in the tree"
+        i = code.index("-m pytest")
+        pytest_line = code[i:code.index("\n\n", i)]
+        for flag in ("-m 'not slow'", "-n 6", "--dist loadfile"):
+            assert flag in pytest_line, \
+                f"tier1.sh's pytest line lost {flag}"

@@ -361,15 +361,21 @@ class TestCrossGenerationFuzz:
 
 
 class TestFactory:
-    def test_knob_forces_python_plane(self, monkeypatch):
+    def test_removed_knob_is_ignored(self, monkeypatch):
+        monkeypatch.delenv("FLINK_TPU_NO_NATIVE", raising=False)
+        default = make_hot_row_cache(64)
         monkeypatch.setenv("FLINK_TPU_NATIVE_HOTCACHE", "0")
-        assert type(make_hot_row_cache(64)) is HotRowCache
+        knobbed = make_hot_row_cache(64)
+        assert type(knobbed) is type(default)
+        if hotcache_available():
+            assert type(knobbed) is not HotRowCache
+        _close(default)
+        _close(knobbed)
 
     @native
     def test_selects_native_when_available(self, monkeypatch):
         from flink_tpu.tenancy.hot_cache_native import NativeHotRowCache
 
-        monkeypatch.delenv("FLINK_TPU_NATIVE_HOTCACHE", raising=False)
         monkeypatch.delenv("FLINK_TPU_NO_NATIVE", raising=False)
         c = make_hot_row_cache(64)
         assert type(c) is NativeHotRowCache

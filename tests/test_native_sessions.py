@@ -265,7 +265,11 @@ class TestMetadataPlaneParity:
 
         assert isinstance(make_session_meta(GAP),
                           NativeSessionIntervalSet)
+        # the per-plane knob is gone: its name is ignored
         monkeypatch.setenv("FLINK_TPU_NATIVE_SESSIONS", "0")
+        assert isinstance(make_session_meta(GAP),
+                          NativeSessionIntervalSet)
+        monkeypatch.setenv("FLINK_TPU_NO_NATIVE", "1")
         meta = make_session_meta(GAP)
         assert isinstance(meta, SessionIntervalSet)
         assert not isinstance(meta, NativeSessionIntervalSet)
@@ -428,8 +432,10 @@ class TestSourceHashStamp:
     def test_disabled_env_returns_none(self, tmp_path, monkeypatch):
         import flink_tpu.native as native
 
+        # the removed alias is ignored: FLINK_TPU_NO_NATIVE is the switch
         monkeypatch.setenv("FLINK_TPU_NATIVE", "0")
-        assert native.load_native("slotmap.cpp", "_slotmap.so") is None
+        assert not native.native_disabled()
+        assert native.load_native("slotmap.cpp", "_slotmap.so") is not None
         monkeypatch.delenv("FLINK_TPU_NATIVE")
         monkeypatch.setenv("FLINK_TPU_NO_NATIVE", "1")
         assert native.load_native("slotmap.cpp", "_slotmap.so") is None

@@ -190,7 +190,7 @@ class TestShmArena:
     def test_shm_dir_without_native_plane_raises(self, monkeypatch):
         from flink_tpu.tenancy.hot_cache import make_hot_row_cache
 
-        monkeypatch.setenv("FLINK_TPU_NATIVE_HOTCACHE", "0")
+        monkeypatch.setenv("FLINK_TPU_NO_NATIVE", "1")
         with tempfile.TemporaryDirectory() as tmp:
             with pytest.raises(RuntimeError, match="shm_dir"):
                 make_hot_row_cache(shm_dir=os.path.join(tmp, "shm"))

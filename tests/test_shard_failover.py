@@ -621,7 +621,6 @@ class TestNativePlaneDegradation:
         native.reset_fallbacks_for_testing()
         monkeypatch.setattr(native, "sessions_available", lambda: False)
         monkeypatch.setattr(native, "native_disabled", lambda: False)
-        monkeypatch.delenv("FLINK_TPU_NATIVE_SESSIONS", raising=False)
         with pytest.warns(RuntimeWarning, match="degraded to Python"):
             meta = make_session_meta(GAP, 0)
         assert type(meta) is SessionIntervalSet

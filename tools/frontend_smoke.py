@@ -2,8 +2,7 @@
 (tier-1).
 
 The executable form of the frontend-tier acceptance criteria on a box
-of ANY core count — structural claims, not throughput (the throughput
-row is tools/bench_serving_mp.py, recorded in BENCHMARKS.md):
+of ANY core count — structural claims, not throughput:
 
 1. **Seqlock fuzz phase** — an owner process writes generation after
    generation into a shm-backed hot cache while TWO frontend reader

@@ -267,9 +267,7 @@ def main():
     from flink_tpu.native import hotcache_available
     from flink_tpu.observe import LockOrderViolation, LockSentinel
 
-    frontend_armed = (hotcache_available()
-                      and os.environ.get(
-                          "FLINK_TPU_NATIVE_HOTCACHE") != "0")
+    frontend_armed = hotcache_available()
     if not frontend_armed:
         print("LOCK SMOKE: native hotcache unavailable — frontend-pool "
               "leg SKIPPED (cluster/backend/cache gates still run)")

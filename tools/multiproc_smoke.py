@@ -23,7 +23,7 @@ Also emits the ``mesh_sessions_2proc`` bench numbers (aggregate ev/s +
 scaling vs the same-box 1-process run) — honest caveat: on a 1-core CI
 box two processes time-share one clock, so the aggregate measures
 pod-protocol overhead, not the pod speedup a multi-core/multi-host box
-shows (NOTES_r18.md).
+shows.
 
     JAX_PLATFORMS=cpu python tools/multiproc_smoke.py
     MP_SMOKE_RECORDS=$((1<<17)) ... # scale knobs
@@ -446,7 +446,7 @@ def main() -> int:
     scaling = ev_s_2p / ev_s_1p
     # the near-linear target (ROADMAP item 2) is gateable only where 2
     # processes get 2 clocks: a 1-core CI box time-shares them and
-    # measures protocol overhead, not pod speedup (NOTES_r18.md) — so
+    # measures protocol overhead, not pod speedup — so
     # the gate ARMS ITSELF when the affinity mask grants >= 2 CPUs
     # (1.4x default: two clocks minus the DCN/ICI protocol tax), and
     # stays env-overridable both ways (0 disarms, higher tightens)

@@ -20,9 +20,6 @@ host hot-row cache + sharded coalescer workers):
      default 350,000/s — raised from 216k when the r19 native fast
      path landed: GIL-free hot-row probe table + packed zero-copy
      batch lookups),
-   - the native hit path less than ``SERVING_SMOKE_MIN_HIT_RATIO``
-     (default 2x) cheaper per hit than the Python dict path
-     (microbenched via tools/bench_hotcache.py after the load phase),
    - the serving plane silently on the Python cache while
      ``SERVING_REQUIRE_NATIVE_HOTCACHE=1`` (tier1.sh exports it when
      the up-front native build succeeded — no vacuous green),
@@ -32,19 +29,15 @@ host hot-row cache + sharded coalescer workers):
      actually happen),
    - any quota violation, zero served lookups, empty job output, or a
      packed-vs-dict lookup mismatch (one materialized cross-check).
-   ``SERVING_SMOKE_PACKED=0`` forces the dict client path (the
-   PR-13-shaped control of the NOTES_r19 walk, gated at the pre-r19
-   216k floor); ``FLINK_TPU_NATIVE_HOTCACHE=0`` is the cache-plane
-   A/B knob.
+   ``SERVING_SMOKE_PACKED=0`` forces the dict client path (the control
+   for the packed lookups, gated at the pre-r19 216k floor).
 
-Prints a JSON line with ``queryable_lookups_per_s`` — `tools/bench_suite.py`
-runs this script at bench scale for the BENCHMARKS.md serving row.
+Prints a JSON line with ``queryable_lookups_per_s``.
 
     JAX_PLATFORMS=cpu python tools/serving_smoke.py
     SERVING_SMOKE_RECORDS=... SERVING_SMOKE_CLIENTS=... to scale.
     SERVING_SMOKE_REPLICA=0 measures the legacy live-plane path
-    (floor/hit-rate/generation gates auto-disable — the A/B lever the
-    NOTES_r17 walk uses).
+    (floor/hit-rate/generation gates auto-disable).
 """
 
 import json
@@ -67,8 +60,8 @@ CLIENTS = int(os.environ.get("SERVING_SMOKE_CLIENTS", 16))
 KEYS = int(os.environ.get("SERVING_SMOKE_KEYS", 4096))
 P99_BUDGET_MS = float(os.environ.get("SERVING_SMOKE_P99_BUDGET_MS", 25))
 #: packed (zero-copy) client lever — read early: the default floor
-#: keys on it (1 = the native fast path; 0 = the PR-13-shaped dict
-#: control of the NOTES_r19 walk, gated at the old floor)
+#: keys on it (1 = the native fast path; 0 = the dict control, gated
+#: at the old floor)
 PACKED = os.environ.get("SERVING_SMOKE_PACKED", "1") != "0"
 #: throughput floor, raised for the r19 native fast path (216k was
 #: 3x the pre-replica 72k row; the native hot-row table + packed
@@ -77,11 +70,6 @@ PACKED = os.environ.get("SERVING_SMOKE_PACKED", "1") != "0"
 MIN_LOOKUPS_PER_S = float(os.environ.get(
     "SERVING_SMOKE_MIN_LOOKUPS_PER_S",
     350_000 if PACKED else 216_000))
-#: per-hit-cost gate: the native hit path must stay at least this many
-#: times cheaper than the Python dict path on THIS box (microbenched
-#: via tools/bench_hotcache.py after the load phase; 0 disables)
-MIN_HIT_RATIO = float(os.environ.get(
-    "SERVING_SMOKE_MIN_HIT_RATIO", 2.0))
 #: exported by tier1.sh when the up-front native build succeeded: the
 #: smoke then FAILS if the serving plane silently fell back to the
 #: Python cache (no vacuous green on the native gates)
@@ -112,7 +100,7 @@ PUBLISH_INTERVAL_MS = int(os.environ.get(
 #: 350 ms (accepted). 0 disables.
 STALENESS_BUDGET_MS = float(os.environ.get(
     "SERVING_SMOKE_STALENESS_BUDGET_MS", 1000))
-#: per-optimization A/B levers (the NOTES_r17 measured walk): hot-row
+#: per-optimization A/B levers: hot-row
 #: cache capacity (0 = every lookup resolves on the replica) and the
 #: serving worker-pool size (1 = one drain loop for all shards)
 CACHE_ENTRIES = int(os.environ.get(
@@ -337,27 +325,6 @@ def main():
     if viol:
         print(f"FAIL: {viol} quota violations on job-2")
         ok = False
-    # per-hit-cost gate (after the load phase — it microbenches on the
-    # quiet box): the native hit path must beat the Python dict path
-    # by the floor ratio, or the fast path silently regressed
-    hit_ratio = None
-    if MIN_HIT_RATIO and native_cache:
-        from tools.bench_hotcache import measure_hit_cost
-
-        cost = measure_hit_cost(rounds=9)
-        if cost is None:
-            print("FAIL: native cache armed but the microbench found "
-                  "no native library")
-            ok = False
-        else:
-            hit_ratio = cost["ratio"]
-            if hit_ratio < MIN_HIT_RATIO:
-                print(f"FAIL: native hit path only {hit_ratio:.2f}x "
-                      f"cheaper than the Python dict path (floor "
-                      f"{MIN_HIT_RATIO:.1f}x; native "
-                      f"{cost['native_hit_ns']:.0f} ns vs python "
-                      f"{cost['python_hit_ns']:.0f} ns)")
-                ok = False
     for name, sink in (("job-2", s2), ("job-3", s3)):
         if len(sink.result()) == 0:
             print(f"FAIL: {name} produced no output")
@@ -387,7 +354,6 @@ def main():
           f"staleness_p99={staleness_p99:.1f}ms "
           f"compiles={s.compiles} quota_violations={viol} "
           f"native_cache={native_cache} "
-          f"hit_ratio={hit_ratio if hit_ratio is None else round(hit_ratio, 2)} "
           f"=> {'OK' if ok else 'FAIL'}")
     return 0 if ok else 1
 

@@ -1,11 +1,13 @@
 #!/usr/bin/env bash
-# Tier-1 gate — the ONE command builders and CI both run, pinned to the
-# exact ROADMAP.md verify invocation (JAX_PLATFORMS=cpu, timeout, marker
-# filter) plus a CPU bench smoke, so the gate never drifts between
-# environments.
+# Tier-1 gate: flint, the native build report, the driver's pytest
+# command (the one in /root/TESTS_LAST_RUN.json: 6 xdist workers,
+# --dist loadfile) and the functional smokes. No bench runs here: a
+# number from a CPU run says nothing about the chip, and speed is
+# measured by benchmark/run.py on the TPU (PERF.md). The CPU floors the
+# smokes still carry are a debt (ROADMAP.md queue 3 item 1).
 #
-#   bash tools/tier1.sh            # tests + bench smoke
-#   SKIP_BENCH_SMOKE=1 bash tools/tier1.sh   # tests only
+#   bash tools/tier1.sh                      # tests + smokes
+#   SKIP_BENCH_SMOKE=1 bash tools/tier1.sh   # flint + build + tests only
 
 set -u
 cd "$(dirname "$0")/.."
@@ -19,21 +21,13 @@ python -m tools.flint flink_tpu/ --fail-on-violation \
   --json flint_report.json || exit 1
 
 # Native libraries build UP FRONT and LOUDLY (slotmap, sessions, codec,
-# datagen): a missing compiler used to surface as a silent pure-Python
-# fallback mid-suite — now it is one explicit line, and when the build
-# succeeds the bench smoke REQUIRES the native session plane (no
-# vacuous green on the host-prep gate).
+# datagen, hotcache): a missing compiler used to surface as a silent
+# pure-Python fallback mid-suite — now it is one explicit line.
 native_status="$(python -c 'from flink_tpu.native import build_report; print(build_report())')"
 echo "$native_status"
-# the no-vacuous-green gate is keyed on the SESSIONS library
-# specifically — an unrelated codec/datagen build failure must not
-# silently disable the metadata-plane requirement
-if python -c 'import sys; from flink_tpu.native import sessions_available; sys.exit(0 if sessions_available() else 1)'; then
-  export BENCH_REQUIRE_NATIVE=1
-fi
-# same discipline for the serving fast path: when the HOTCACHE library
-# built, the serving smoke FAILS if the plane silently fell back to
-# the Python cache (its throughput/per-hit gates would go vacuous)
+# when the HOTCACHE library built, the serving smoke FAILS if the plane
+# silently fell back to the Python cache (its hit-rate and throughput
+# gates would go vacuous)
 if python -c 'import sys; from flink_tpu.native import hotcache_available; sys.exit(0 if hotcache_available() else 1)'; then
   export SERVING_REQUIRE_NATIVE_HOTCACHE=1
 fi
@@ -41,9 +35,9 @@ fi
 set -o pipefail
 log="${T1_LOG:-/tmp/_t1.$$.log}"   # unique per run: concurrent gates must not clobber
 rm -f "$log"
-timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
+timeout -k 10 1470 env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
   -m 'not slow' --continue-on-collection-errors -p no:cacheprovider \
-  -p no:xdist -p no:randomly 2>&1 | tee "$log"
+  -p xdist -n 6 --dist loadfile -p no:randomly 2>&1 | tee "$log"
 rc=${PIPESTATUS[0]}
 echo "DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' "$log" \
   | tr -cd . | wc -c)"
@@ -52,66 +46,6 @@ if [ "$rc" -ne 0 ]; then
 fi
 
 if [ "${SKIP_BENCH_SMOKE:-0}" != "1" ]; then
-  # CPU bench smoke: a reduced Q5 run must still emit its JSON line
-  # (catches import/config regressions the unit tests cannot)
-  BENCH_RECORDS=$((1 << 20)) BENCH_REPS=1 \
-    JAX_PLATFORMS=cpu timeout -k 10 600 python bench.py || exit 1
-
-  # Mesh-sessions smoke with two gates pinned:
-  # (1) page-rewrite amplification: FAILS if (rows_split_on_reload +
-  #     rows_compacted) / rows_reloaded exceeds the budget. The lazy
-  #     tombstone design's only rewrites are threshold compactions
-  #     (~0.2x measured); the old split-on-reload path sat at ~16x.
-  # (2) host-prep fraction (device-shuffle mode): FAILS if genuine
-  #     host work (sessionization + slot resolution + flat staging,
-  #     with fence blocks and inline device interactions attributed to
-  #     device time) exceeds the budget share of wall clock — the
-  #     regression class where exchange or metadata work silently
-  #     moves back onto the host. Budget 0.35 (tightened from 0.45
-  #     when the NATIVE metadata plane landed — sessionize/absorb/
-  #     slot-fold/pop run as one C sweep per batch, NOTES_r12) vs
-  #     ~0.34 measured on the 1-core CI host. BENCH_REQUIRE_NATIVE
-  #     (exported above when the up-front build succeeded) makes the
-  #     smoke FAIL rather than silently measure the pure-Python plane.
-  # (3) fire p99 (the latency tier, ROADMAP item 1): FAILS if the
-  #     MEDIAN of the reps' fire p99 (watermark advance -> results on
-  #     host, steady state — the end-of-input drain is excluded and
-  #     reported as final_drain_ms) exceeds the budget at the
-  #     mesh-sessions smoke shape, or if the smoke recorded < 10 fires
-  #     (vacuity guard — a shape that fires too rarely measures
-  #     nothing). Budget 140 ms vs ~90-120 measured with the 25 ms
-  #     fire deadline on the 1-core CI box; the legacy whole-batch
-  #     path (BENCH_MESH_FIRE_DEADLINE_MS=0) measures ~164 ms median
-  #     here, so a regression to full-harvest fires trips the gate.
-  # 2M records so the live session set genuinely exceeds the 512k
-  # device budget — below ~1M the tier never spills and the
-  # amplification gate would be vacuous. 3 reps: all gates read the
-  # MEDIAN rep (the bench's own methodology) — a single-rep gate at a
-  # tight budget tripped on scheduler noise, not regressions.
-  BENCH_MESH_SESSION_RECORDS=$((1 << 21)) \
-    BENCH_MESH_REPS=3 BENCH_MESH_AMP_BUDGET=0.5 \
-    BENCH_HOST_PREP_BUDGET=0.35 \
-    BENCH_FIRE_P99_BUDGET=140 BENCH_MESH_FIRE_DEADLINE_MS=25 \
-    JAX_PLATFORMS=cpu timeout -k 10 600 \
-    python tools/bench_mesh_sessions.py || exit 1
-
-  # Trace smoke: the flight recorder's gate at the SAME bench shape —
-  # (1) a captured Chrome/Perfetto trace must be schema-valid (every
-  #     event a registered KNOWN_SPAN_KINDS kind, batch + watermark +
-  #     per-shard attribution present),
-  # (2) the measured pass must record 0 steady-state XLA compiles
-  #     (the compile-correlation agrees with the recompile sentinel),
-  # (3) recorder overhead must stay under 3% of the pass's wall
-  #     clock, gated on a DIRECT measurement (live-microbenched
-  #     per-record cost x the pass's actual record count / wall;
-  #     ~0.05% measured), with the A/B on/off throughput ratio
-  #     sanity-bounded at 15% — scheduler noise on this 1-core box is
-  #     ~±10%, so a tight A/B gate would flake on noise, not
-  #     regressions. ~25 s on CPU.
-  TRACE_SMOKE_RECORDS=$((1 << 20)) \
-    JAX_PLATFORMS=cpu timeout -k 10 300 \
-    python tools/trace_smoke.py || exit 1
-
   # Chaos smoke: seeded crash-restore-verify (3 injected engine crashes
   # — incl. the device data plane dying after the fused exchange
   # dispatch — + 1 torn checkpoint write over ~12k events) — FAILS on
@@ -184,8 +118,8 @@ if [ "${SKIP_BENCH_SMOKE:-0}" != "1" ]; then
   # the survivor must restore ONLY the dead host's key-group ranges
   # from its checkpoint units, replay within the per-host bound, and
   # finish bit-identical. Also emits the mesh_sessions_2proc scaling
-  # numbers (gateable via MP_SMOKE_MIN_SCALING on multi-core boxes —
-  # this 1-core box time-shares the clock, NOTES_r18.md). ~2 min.
+  # numbers (gateable via MP_SMOKE_MIN_SCALING on multi-core boxes;
+  # two processes on one core time-share the clock). ~2 min.
   MP_SMOKE_RECORDS=$((1 << 16)) \
     timeout -k 10 600 python tools/multiproc_smoke.py || exit 1
 
@@ -212,9 +146,7 @@ if [ "${SKIP_BENCH_SMOKE:-0}" != "1" ]; then
   # on a per-job program-cache miss, on lookup p99 over 25 ms, on
   # throughput under 350k lookups/s (raised from 216k when the native
   # fast path landed; measured ~500-580k here at the 5 ms client
-  # pause, ~1.1M/s at the bench row's 2 ms point), on the native hit
-  # path being < 2x cheaper per hit than the Python dict path
-  # (tools/bench_hotcache.py microbench), on replica staleness p99
+  # pause), on replica staleness p99
   # over 1 s (a starved publish loop behind big lookup numbers is a
   # different product), on a packed-vs-dict result mismatch, on a
   # silent fallback to the Python cache while the native library
