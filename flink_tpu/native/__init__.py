@@ -227,7 +227,7 @@ def load_slotmap() -> Optional[ctypes.CDLL]:
         i64, i32, u8, vp = (c.c_int64, c.c_int32, c.c_uint8, c.c_void_p)
         P = c.POINTER
         lib.sm_create.restype = vp
-        lib.sm_create.argtypes = [i64, i64]
+        lib.sm_create.argtypes = [i64, i64, i32]
         lib.sm_destroy.restype = None
         lib.sm_destroy.argtypes = [vp]
         lib.sm_capacity.restype = i64
@@ -245,8 +245,8 @@ def load_slotmap() -> Optional[ctypes.CDLL]:
                                             P(u8)]
         lib.sm_resolve_grouped.restype = i32
         lib.sm_resolve_grouped.argtypes = [vp, i64, P(i64), P(i64), i64, i64,
-                                           i64, i64, P(i32), P(i32),
-                                           P(i64), P(i64)]
+                                           i64, i64, P(i32), P(i64),
+                                           P(i64)]
         lib.sm_erase.restype = i64
         lib.sm_erase.argtypes = [vp, i64, P(i64), P(i64), P(i32)]
         lib.sm_lookup.restype = None
@@ -258,8 +258,16 @@ def load_slotmap() -> Optional[ctypes.CDLL]:
         lib.sm_carry_destroy.restype = None
         lib.sm_carry_destroy.argtypes = [vp]
         lib.sm_carry_advance.restype = i64
-        lib.sm_carry_advance.argtypes = [vp, i64, i64, i64, P(i32), P(i64),
-                                         P(i32), P(i64), P(i64), P(i32)]
+        lib.sm_carry_advance.argtypes = [vp, vp, i64, P(i64), i64, P(i64),
+                                         P(i32), P(i64)]
+        lib.sm_drop_namespaces.restype = i64
+        lib.sm_drop_namespaces.argtypes = [vp, i64, P(i64), P(i32)]
+        lib.sm_namespace_count.restype = i64
+        lib.sm_namespace_count.argtypes = [vp]
+        lib.sm_namespaces.restype = None
+        lib.sm_namespaces.argtypes = [vp, P(i64)]
+        lib.sm_namespace_slots.restype = i64
+        lib.sm_namespace_slots.argtypes = [vp, i64, P(i32), i64]
         lib.sm_pane_ingest.restype = i32
         lib.sm_pane_ingest.argtypes = [vp, i64, P(i64), P(i64), i64, i64,
                                        i64, P(i32), P(u8), P(i32), P(i64),

@@ -54,6 +54,9 @@ KNOWN_SPAN_KINDS = (
     "slice.retire",        # expired slices' pairs erased from the host
                            # index + their device rows reset (work:
                            # pairs erased; page faults summed)
+    "retire.drop",         # pairs that left the host index with their
+                           # slice's whole table, no erase per pair
+                           # (instant inside slice.retire; work: pairs)
     "sink.write",          # one batch handed to the sink (work: rows)
     "op.process",          # executor: one operator's process_batch
     "op.watermark",        # executor: one operator's process_watermark

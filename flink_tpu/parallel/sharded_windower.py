@@ -2612,12 +2612,18 @@ class MeshWindowEngine(MeshSpillSupport):
         freed: List[Optional[np.ndarray]] = []
         self._freed_ns.append(np.asarray(list(ends), dtype=np.int64))
         self._drop_spilled(ends)
+        before = sum(idx.pairs_dropped for idx in self.indexes)
         for p in range(self.P):
             slots = self.indexes[p].free_namespaces(ends)
             freed.append(slots)
             if slots is not None:
                 self._dirty[p, slots] = False
                 f_max = max(f_max, len(slots))
+        dropped = sum(idx.pairs_dropped for idx in self.indexes) - before
+        if dropped:
+            # pairs that left with their slice's whole table (the native
+            # index's drop; none on the Python index)
+            flight.instant("retire.drop", work=dropped)
         if f_max == 0:
             return 0
         F = sticky_bucket(f_max, getattr(self, "_reset_bucket", 0))

@@ -6,9 +6,12 @@ families keyed by keyGroup+key+namespace:
 flink-state-backends/flink-statebackend-rocksdb/.../RocksDBKeyedStateBackend.java)
 with a split design natural to XLA's static-shape world:
 
-- **Host** (``HostSlotIndex``): a hash index ``(key_id, namespace) -> slot``
-  plus per-slot metadata (key id, namespace) in NumPy arrays, a free list,
-  and a namespace -> slots registry for O(fired) window expiry.
+- **Host** (``NativeSlotIndex``, ``native/slotmap.cpp``; ``HostSlotIndex``
+  is its pure-Python twin): an index ``(key_id, namespace) -> slot`` plus
+  per-slot metadata (key id, namespace) in NumPy arrays, a free list, and
+  each namespace's slots kept together for O(fired) window expiry — one
+  hash table per namespace where the owner frees by namespace, so a slice
+  leaves with its table.
 - **Device** (``SlotTable``): the accumulator leaves — flat ``[capacity]``
   jnp arrays updated by donated scatter kernels (see
   ``flink_tpu.windowing.aggregates``). The mesh-sharded variant
@@ -165,27 +168,112 @@ def resolve_slot_hints(index, key_ids: np.ndarray, namespaces: np.ndarray,
     return pre
 
 
-class _NamespaceRegistry:
-    """Shared namespace -> slots registry (O(namespaces), pure Python).
+class _DictSliceCarry:
+    """What :meth:`HostSlotIndex.slice_matrix` keeps of the matrix it
+    carries: the slices of the last call, each one's list object in the
+    registry and how much of that list the matrix holds; the matrix in
+    NumPy with a dict as the key -> row table (the native index has
+    ``sm_carry_advance``)."""
 
-    Mixed into both slot-index implementations so slice expiry and the
-    chunk-merge bookkeeping exist exactly once.
+    def __init__(self) -> None:
+        self.ends: List[int] = []
+        self.lists: List[Optional[List[np.ndarray]]] = []
+        self.consumed: List[int] = []
+        self._keys = np.empty(0, dtype=np.int64)
+        self._mat = np.zeros((0, 0), dtype=np.int32)
+        self._row_of: Dict[int, int] = {}
+
+    def advance(self, k: int, shift: int,
+                parts: List[Tuple[int, np.ndarray]], slot_key: np.ndarray
+                ) -> Tuple[np.ndarray, np.ndarray]:
+        """Drop the ``shift`` leftmost columns (all of them: start from
+        nothing), sweep out the rows left empty, then enter ``parts``:
+        (column, slots) runs whose keys are ``slot_key[slots]``."""
+        if shift >= k or self._mat.shape[1] != k:
+            keys = np.empty(0, dtype=np.int64)
+            mat = np.zeros((0, k), dtype=np.int32)
+            row_of: Dict[int, int] = {}
+        else:
+            keys, mat, row_of = self._keys, self._mat, self._row_of
+            if shift:
+                mat = np.concatenate(
+                    [mat[:, shift:],
+                     np.zeros((len(mat), shift), dtype=np.int32)], axis=1)
+                live = mat.any(axis=1)
+                if not live.all():
+                    keys, mat = keys[live], mat[live]
+                    row_of = dict(zip(keys.tolist(), range(len(keys))))
+        if parts:
+            slots = np.concatenate([s for _, s in parts])
+            cols = np.repeat([j for j, _ in parts],
+                             [len(s) for _, s in parts])
+            fresh: List[int] = []
+            rows = np.empty(len(slots), dtype=np.int64)
+            for i, key in enumerate(slot_key[slots].tolist()):
+                r = row_of.get(key)
+                if r is None:
+                    r = row_of[key] = len(row_of)
+                    fresh.append(key)
+                rows[i] = r
+            if fresh:
+                keys = np.concatenate(
+                    [keys, np.asarray(fresh, dtype=np.int64)])
+                mat = np.concatenate(
+                    [mat, np.zeros((len(fresh), k), dtype=np.int32)])
+            mat[rows, cols] = slots
+        self._keys, self._mat, self._row_of = keys, mat, row_of
+        return keys.copy(), mat.copy()
+
+
+class HostSlotIndex:
+    """Host half of the state table in pure Python: a dict
+    (key, ns) -> slot, per-slot metadata in NumPy arrays, a free list,
+    and a namespace -> slots registry (chunk lists, O(namespaces)) for
+    window expiry. The fallback where the native index
+    (:class:`NativeSlotIndex`) is unavailable, with identical results.
+
+    Capacity growth is signalled via ``on_grow(old, new)`` so the owner can
+    resize device arrays in lockstep.
     """
 
-    def _init_registry(self, track: bool = True) -> None:
+    def __init__(self, capacity: int,
+                 on_grow: Optional[Callable[[int, int], None]] = None,
+                 growable: bool = True,
+                 full_hint: str = "raise state.slot-table.capacity",
+                 max_capacity: int = 0,
+                 track_namespaces: bool = True) -> None:
+        self.capacity = max(int(capacity), 1024)
+        self.on_grow = on_grow
+        self.growable = growable
+        self.full_hint = full_hint
+        self.max_capacity = int(max_capacity or 0)
+        self._index: Dict[Tuple[int, int], int] = {}
+        self.slot_key = np.zeros(self.capacity, dtype=np.int64)
+        self.slot_ns = np.zeros(self.capacity, dtype=np.int64)
+        self.slot_used = np.zeros(self.capacity, dtype=bool)
+        self._free: List[int] = list(range(self.capacity - 1, 0, -1))
         self._ns_slots: Dict[int, List[np.ndarray]] = {}
         #: False = the owner frees by SLOT and never asks for a
         #: namespace's slot list — skip the per-namespace bookkeeping
         #: entirely (the session tables: one row per ns, millions of ns;
         #: registry upkeep was O(sessions) Python per batch)
-        self._track_ns = track
+        self._track_ns = track_namespaces
         #: (key, namespace) pairs ``lookup_or_insert`` has given a new
         #: slot so far (the table states one batch's growth as its
         #: ``prep.resolve`` span's work)
         self.pairs_inserted = 0
+        #: pairs that left with their namespace's whole table: none here,
+        #: this index erases pair by pair (see ``NativeSlotIndex``)
+        self.pairs_dropped = 0
         #: the last fired window's slot matrix, carried to the next fire
         #: (:meth:`slice_matrix`); made on the first fire
-        self._slice_carry = None
+        self._slice_carry: Optional[_DictSliceCarry] = None
+
+    @property
+    def num_used(self) -> int:
+        return int(self.slot_used.sum())
+
+    # ------------------------------------------- namespace -> slots registry
 
     @property
     def namespaces(self) -> List[int]:
@@ -224,7 +312,7 @@ class _NamespaceRegistry:
         k = len(ends)
         carry = self._slice_carry
         if carry is None:
-            carry = self._slice_carry = self._new_slice_carry()
+            carry = self._slice_carry = _DictSliceCarry()
         reg = self._ns_slots
         shift = k
         if len(carry.ends) == k:
@@ -290,101 +378,7 @@ class _NamespaceRegistry:
             else:
                 self._ns_slots.pop(int(ns), None)
 
-
-class _SliceCarry:
-    """What :meth:`_NamespaceRegistry.slice_matrix` keeps of the matrix it
-    carries: the slices of the last call, each one's list object in the
-    registry and how much of that list the matrix holds. The matrix
-    itself is the subclass's."""
-
-    def __init__(self) -> None:
-        self.ends: List[int] = []
-        self.lists: List[Optional[List[np.ndarray]]] = []
-        self.consumed: List[int] = []
-
-
-class _DictSliceCarry(_SliceCarry):
-    """The carried matrix in NumPy with a dict as the key -> row table
-    (``HostSlotIndex``; the native index has ``sm_carry_advance``)."""
-
-    def __init__(self) -> None:
-        super().__init__()
-        self._keys = np.empty(0, dtype=np.int64)
-        self._mat = np.zeros((0, 0), dtype=np.int32)
-        self._row_of: Dict[int, int] = {}
-
-    def advance(self, k: int, shift: int,
-                parts: List[Tuple[int, np.ndarray]], slot_key: np.ndarray
-                ) -> Tuple[np.ndarray, np.ndarray]:
-        """Drop the ``shift`` leftmost columns (all of them: start from
-        nothing), sweep out the rows left empty, then enter ``parts``:
-        (column, slots) runs whose keys are ``slot_key[slots]``."""
-        if shift >= k or self._mat.shape[1] != k:
-            keys = np.empty(0, dtype=np.int64)
-            mat = np.zeros((0, k), dtype=np.int32)
-            row_of: Dict[int, int] = {}
-        else:
-            keys, mat, row_of = self._keys, self._mat, self._row_of
-            if shift:
-                mat = np.concatenate(
-                    [mat[:, shift:],
-                     np.zeros((len(mat), shift), dtype=np.int32)], axis=1)
-                live = mat.any(axis=1)
-                if not live.all():
-                    keys, mat = keys[live], mat[live]
-                    row_of = dict(zip(keys.tolist(), range(len(keys))))
-        if parts:
-            slots = np.concatenate([s for _, s in parts])
-            cols = np.repeat([j for j, _ in parts],
-                             [len(s) for _, s in parts])
-            fresh: List[int] = []
-            rows = np.empty(len(slots), dtype=np.int64)
-            for i, key in enumerate(slot_key[slots].tolist()):
-                r = row_of.get(key)
-                if r is None:
-                    r = row_of[key] = len(row_of)
-                    fresh.append(key)
-                rows[i] = r
-            if fresh:
-                keys = np.concatenate(
-                    [keys, np.asarray(fresh, dtype=np.int64)])
-                mat = np.concatenate(
-                    [mat, np.zeros((len(fresh), k), dtype=np.int32)])
-            mat[rows, cols] = slots
-        self._keys, self._mat, self._row_of = keys, mat, row_of
-        return keys.copy(), mat.copy()
-
-
-class HostSlotIndex(_NamespaceRegistry):
-    """Host half of the state table: (key, ns) -> slot mapping + metadata.
-
-    Capacity growth is signalled via ``on_grow(old, new)`` so the owner can
-    resize device arrays in lockstep.
-    """
-
-    def __init__(self, capacity: int,
-                 on_grow: Optional[Callable[[int, int], None]] = None,
-                 growable: bool = True,
-                 full_hint: str = "raise state.slot-table.capacity",
-                 max_capacity: int = 0,
-                 track_namespaces: bool = True) -> None:
-        self.capacity = max(int(capacity), 1024)
-        self.on_grow = on_grow
-        self.growable = growable
-        self.full_hint = full_hint
-        self.max_capacity = int(max_capacity or 0)
-        self._index: Dict[Tuple[int, int], int] = {}
-        self.slot_key = np.zeros(self.capacity, dtype=np.int64)
-        self.slot_ns = np.zeros(self.capacity, dtype=np.int64)
-        self.slot_used = np.zeros(self.capacity, dtype=bool)
-        self._free: List[int] = list(range(self.capacity - 1, 0, -1))
-        self._init_registry(track_namespaces)
-
-    _new_slice_carry = _DictSliceCarry
-
-    @property
-    def num_used(self) -> int:
-        return int(self.slot_used.sum())
+    # ---------------------------------------------------- pairs <-> slots
 
     def lookup_or_insert(self, key_ids: np.ndarray,
                          namespaces: np.ndarray) -> np.ndarray:
@@ -513,49 +507,43 @@ _I32P = _ct.POINTER(_ct.c_int32)
 _U8P = _ct.POINTER(_ct.c_uint8)
 
 
-class _NativeSliceCarry(_SliceCarry):
-    """The carried matrix kept by ``native/slotmap.cpp``: keys, matrix and
-    a key -> row table that persists between fires. One foreign call per
-    fire (each one is a GIL hand-over on the task loop)."""
+class _NativeSliceCarry:
+    """Handle of the carried matrix ``native/slotmap.cpp`` keeps for
+    :meth:`NativeSlotIndex.slice_matrix` (keys, matrix, a key -> row table
+    and what it consumed of each slice's table), with what sizes the next
+    call's output: the rows it held and the index's ``pairs_inserted``
+    then."""
 
     def __init__(self, lib) -> None:
-        super().__init__()
         self._lib = lib
-        self._h = lib.sm_carry_create()
-        self._rows = 0
+        self.h = lib.sm_carry_create()
+        self.rows = 0
+        self.inserted = 0
 
     def __del__(self):  # pragma: no cover - finalizer
-        lib, h = getattr(self, "_lib", None), getattr(self, "_h", None)
+        lib, h = getattr(self, "_lib", None), getattr(self, "h", None)
         if lib is not None and h:
             lib.sm_carry_destroy(h)
-            self._h = None
-
-    def advance(self, k: int, shift: int,
-                parts: List[Tuple[int, np.ndarray]], slot_key: np.ndarray
-                ) -> Tuple[np.ndarray, np.ndarray]:
-        seg_col = np.asarray([j for j, _ in parts], dtype=np.int32)
-        seg_len = np.asarray([len(s) for _, s in parts], dtype=np.int64)
-        slots = np.concatenate(
-            [s for _, s in parts] or [np.empty(0, dtype=np.int32)]
-        ).astype(np.int32, copy=False)
-        # at most the rows held plus one new row per cell given
-        bound = (self._rows if shift < k else 0) + len(slots)
-        keys = np.empty(bound, dtype=np.int64)
-        matrix = np.empty((bound, k), dtype=np.int32)
-        self._rows = rows = self._lib.sm_carry_advance(
-            self._h, k, shift, len(parts),
-            seg_col.ctypes.data_as(_I32P), seg_len.ctypes.data_as(_I64P),
-            slots.ctypes.data_as(_I32P), slot_key.ctypes.data_as(_I64P),
-            keys.ctypes.data_as(_I64P), matrix.ctypes.data_as(_I32P))
-        return keys[:rows], matrix[:rows]
+            self.h = None
 
 
-class NativeSlotIndex(_NamespaceRegistry):
+class NativeSlotIndex:
     """C++-backed drop-in for HostSlotIndex (see native/slotmap.cpp).
 
-    The batch probe loop runs in native code; slot metadata lives in
-    C++-owned arrays exposed to NumPy zero-copy. The namespace -> slots
-    registry stays in Python (it is O(namespaces), not O(records)).
+    Slot metadata lives in C++-owned arrays exposed to NumPy zero-copy;
+    every batch entry is one foreign call (each one is a GIL hand-over on
+    the task loop). What the owner does chooses the map's form, once, here:
+
+    - ``track_namespaces=True`` (the owner frees by namespace: window
+      slices, keyed state, pane columns): one table per namespace, keyed
+      by the key alone, with the namespace's slots dense in insertion
+      order beside it — that table is the namespace registry. A retire
+      drops tables (``free_namespaces`` → ``sm_drop_namespaces``), the
+      fire's resolve reads a table's own arrays (``slice_matrix`` →
+      ``sm_carry_advance``), a probe touches its slice's table alone.
+    - ``track_namespaces=False`` (the owner frees by slot: the session
+      tables, one row per namespace): one flat (key, namespace) table; no
+      namespace is tracked, ``namespaces`` is empty.
     """
 
     def __init__(self, capacity: int,
@@ -575,20 +563,26 @@ class NativeSlotIndex(_NamespaceRegistry):
         self.max_capacity = int(max_capacity or 0)
         max_cap = (self.max_capacity or (1 << 28)) if growable \
             else self.capacity
-        self._h = self._lib.sm_create(self.capacity, max_cap)
+        self._track_ns = track_namespaces
+        self._h = self._lib.sm_create(self.capacity, max_cap,
+                                      int(track_namespaces))
         self._wrap_views()
-        self._init_registry(track_namespaces)
-        # _resolve_grouped's outputs, kept from batch to batch (a fresh
-        # megabyte per batch costs more in page faults than the sweep's
-        # arithmetic): new slots grouped by namespace; [3, max_uniq]
-        # namespaces / records / new slots of each; the distinct count
-        self._sweep_new = np.empty(0, dtype=np.int32)
+        #: (key, namespace) pairs given a new slot so far (as
+        #: ``HostSlotIndex.pairs_inserted``)
+        self.pairs_inserted = 0
+        #: pairs that left with their namespace's whole table
+        #: (``free_namespaces``): no hash, gather or shift per pair
+        self.pairs_dropped = 0
+        self._slice_carry: Optional[_NativeSliceCarry] = None
+        # _resolve_grouped's [3, max_uniq] namespaces / records / new
+        # pairs of each, kept from batch to batch (a fresh buffer per
+        # batch costs more in page faults than the sweep's arithmetic),
+        # and the distinct count
         self._sweep_groups = np.empty(0, dtype=np.int64)
         self._sweep_k = _ct.c_int64()
+        self._carry_cells = _ct.c_int64()
 
     def _wrap_views(self) -> None:
-        import ctypes
-
         cap = int(self._lib.sm_capacity(self._h))
         self.capacity = cap
         self.slot_key = np.ctypeslib.as_array(
@@ -597,6 +591,9 @@ class NativeSlotIndex(_NamespaceRegistry):
             self._lib.sm_slot_namespaces(self._h), shape=(cap,))
         self.slot_used = np.ctypeslib.as_array(
             self._lib.sm_slot_used(self._h), shape=(cap,)).view(bool)
+        # where the native side writes a namespace's slots (every slot
+        # the index could hold; pages are touched as far as written)
+        self._slots_out = np.empty(cap, dtype=np.int32)
 
     def __del__(self):  # pragma: no cover - finalizer
         lib, h = getattr(self, "_lib", None), getattr(self, "_h", None)
@@ -604,12 +601,72 @@ class NativeSlotIndex(_NamespaceRegistry):
             lib.sm_destroy(h)
             self._h = None
 
-    def _new_slice_carry(self) -> _NativeSliceCarry:
-        return _NativeSliceCarry(self._lib)
-
     @property
     def num_used(self) -> int:
         return int(self._lib.sm_used(self._h))
+
+    # ------------------------------------------------ a namespace's table
+
+    @property
+    def namespaces(self) -> List[int]:
+        """The live namespaces, in the order they were first given a
+        slot."""
+        out = np.empty(int(self._lib.sm_namespace_count(self._h)),
+                       dtype=np.int64)
+        self._lib.sm_namespaces(self._h, out.ctypes.data_as(_I64P))
+        return out.tolist()
+
+    def slots_for_namespace(self, ns: int) -> np.ndarray:
+        """The namespace's slots in the order they were given out (a
+        copy: the caller's)."""
+        out = self._slots_out
+        n = self._lib.sm_namespace_slots(
+            self._h, int(ns), out.ctypes.data_as(_I32P), len(out))
+        return out[:n].copy()
+
+    def slice_matrix(self, slice_ends
+                     ) -> Tuple[np.ndarray, np.ndarray, int]:
+        """As :meth:`HostSlotIndex.slice_matrix`, in one foreign call
+        (``sm_carry_advance``): the cells that entered are read from each
+        slice's own table, from where the carried matrix stopped; a table's
+        generation tells a namespace from a later one of the same name,
+        and any per-slot free starts the matrix from nothing."""
+        ends = np.ascontiguousarray(slice_ends, dtype=np.int64)
+        k = len(ends)
+        carry = self._slice_carry
+        if carry is None:
+            carry = self._slice_carry = _NativeSliceCarry(self._lib)
+        # the rows held plus one per pair inserted since covers an
+        # advance; a rebuild says how many it needs and is asked again
+        bound = carry.rows + self.pairs_inserted - carry.inserted
+        while True:
+            keys = np.empty(bound, dtype=np.int64)
+            matrix = np.empty((bound, k), dtype=np.int32)
+            rows = self._lib.sm_carry_advance(
+                carry.h, self._h, k, ends.ctypes.data_as(_I64P), bound,
+                keys.ctypes.data_as(_I64P), matrix.ctypes.data_as(_I32P),
+                _ct.byref(self._carry_cells))
+            if rows >= 0:
+                break
+            bound = -rows
+        carry.rows, carry.inserted = rows, self.pairs_inserted
+        return keys[:rows], matrix[:rows], self._carry_cells.value
+
+    def free_namespaces(self, namespaces: List[int]) -> Optional[np.ndarray]:
+        """Release all slots of the given namespaces: each one's table is
+        dropped whole, one foreign call for them all. Returns the freed
+        slots (None where the namespaces hold none)."""
+        nss = np.ascontiguousarray(namespaces, dtype=np.int64)
+        out = self._slots_out
+        n = self._lib.sm_drop_namespaces(
+            self._h, len(nss), nss.ctypes.data_as(_I64P),
+            out.ctypes.data_as(_I32P))
+        if not n:
+            return None
+        self.pairs_dropped += n
+        return out[:n].copy()
+
+    # ---------------------------------------------------- pairs <-> slots
 
     def lookup_or_insert(self, key_ids: np.ndarray,
                          namespaces: np.ndarray) -> np.ndarray:
@@ -627,10 +684,7 @@ class NativeSlotIndex(_NamespaceRegistry):
             self._h, n,
             keys.ctypes.data_as(_I64P), nss.ctypes.data_as(_I64P),
             out.ctypes.data_as(_I32P), is_new.ctypes.data_as(_U8P))
-        if rc < 0:
-            raise self._full_error()
-        if rc > 0:
-            self._regrown(old_cap)
+        self._settle(rc, old_cap)
         self.pairs_inserted += int(np.count_nonzero(is_new))
         return out
 
@@ -660,74 +714,58 @@ class NativeSlotIndex(_NamespaceRegistry):
                          offset: int, width: int, live_from: int,
                          max_uniq: int):
         """One ``sm_resolve_grouped`` call (native/slotmap.cpp): every
-        record's slot, and the new slots appended to the registry as one
-        chunk per namespace, in record order, namespaces ascending. One
-        foreign call per batch (each is a GIL hand-over on the task
-        loop). ``slots`` is the caller's; the grouped new slots and the
-        per-namespace counts land in buffers the index keeps."""
+        record's slot, a new pair appended to its namespace's table in
+        record order. One foreign call per batch (each is a GIL hand-over
+        on the task loop). ``slots`` is the caller's; the per-namespace
+        counts land in a buffer the index keeps."""
         n = len(keys)
         if len(vals) != n:
             raise ValueError(
                 f"{n} keys against {len(vals)} timestamps / namespaces")
         slots = np.empty(n, dtype=np.int32)
-        if n > len(self._sweep_new):
-            self._sweep_new = np.empty(n, dtype=np.int32)
         if 3 * max_uniq > len(self._sweep_groups):
             self._sweep_groups = np.empty(3 * max_uniq, dtype=np.int64)
-        new, groups = self._sweep_new, self._sweep_groups
+        groups = self._sweep_groups
         old_cap = self.capacity
         rc = self._lib.sm_resolve_grouped(
             self._h, n, keys.ctypes.data_as(_I64P),
             vals.ctypes.data_as(_I64P), offset, width, live_from, max_uniq,
-            slots.ctypes.data_as(_I32P), new.ctypes.data_as(_I32P),
-            groups.ctypes.data_as(_I64P), _ct.byref(self._sweep_k))
+            slots.ctypes.data_as(_I32P), groups.ctypes.data_as(_I64P),
+            _ct.byref(self._sweep_k))
         if rc == -2:
             return None
         k = self._sweep_k.value
         ends = groups[:k].copy()
         records = groups[max_uniq:max_uniq + k].copy()
-        reg, track = self._ns_slots, self._track_ns
-        pos = 0
-        for ns, held, fresh in zip(
-                ends.tolist(), records.tolist(),
-                groups[2 * max_uniq:2 * max_uniq + k].tolist()):
-            if fresh:
-                self.pairs_inserted += fresh
-                if track:
-                    reg.setdefault(ns, []).append(
-                        new[pos:pos + fresh].copy())
-            pos += held
-        if rc < 0:
-            # what was inserted before the table filled is registered
-            # above, and a growth before it is passed on: index,
-            # registry and the owner's arrays stay level
-            if int(self._lib.sm_capacity(self._h)) != old_cap:
-                self._regrown(old_cap)
-            raise self._full_error()
-        if rc > 0:
-            self._regrown(old_cap)
+        self.pairs_inserted += int(
+            groups[2 * max_uniq:2 * max_uniq + k].sum())
+        self._settle(rc, old_cap)
         return slots, ends, records
 
-    def _full_error(self) -> SlotTableFullError:
-        return SlotTableFullError(
-            f"slot table full (capacity={self.capacity}) and not "
-            f"growable; {self.full_hint}")
-
-    def _regrown(self, old_cap: int) -> None:
-        self._wrap_views()
-        if self.on_grow is not None:
-            self.on_grow(old_cap, self.capacity)
+    def _settle(self, rc: int, old_cap: int) -> None:
+        """After an inserting call returned ``rc``: a growth is passed on
+        to the owner — also one that happened before the index filled
+        (what was inserted until then stays, so index and the owner's
+        arrays stay level) — and a full index raises."""
+        if rc > 0 or (rc < 0 and int(self._lib.sm_capacity(self._h))
+                      != old_cap):
+            self._wrap_views()
+            if self.on_grow is not None:
+                self.on_grow(old_cap, self.capacity)
+        if rc < 0:
+            raise SlotTableFullError(
+                f"slot table full (capacity={self.capacity}) and not "
+                f"growable; {self.full_hint}")
 
     def pane_ingest(self, key_ids: np.ndarray, timestamps: np.ndarray,
                     offset: int, width: int, max_uniq: int = 4096):
         """Fused pane-table ingest (native/slotmap.cpp sm_pane_ingest):
         one native sweep computes slice ends, the key -> column probe
-        (namespace 0) and the distinct-slice-end plan that previously
-        took five separate numpy passes. Returns (cols, sinv, uniq,
-        max_col) or None when the batch has pathologically many distinct
-        slice ends (caller falls back to the unfused path)."""
-        import ctypes
-
+        (namespace 0: all pane-table entries live there) and the
+        distinct-slice-end plan that previously took five separate numpy
+        passes. Returns (cols, sinv, uniq, max_col) or None when the batch
+        has pathologically many distinct slice ends (caller falls back to
+        the unfused path)."""
         keys = np.ascontiguousarray(key_ids, dtype=np.int64)
         ts = np.ascontiguousarray(timestamps, dtype=np.int64)
         n = len(keys)
@@ -735,45 +773,31 @@ class NativeSlotIndex(_NamespaceRegistry):
         is_new = np.empty(n, dtype=np.uint8)
         sinv = np.empty(n, dtype=np.int32)
         uniq = np.empty(max_uniq, dtype=np.int64)
-        out_k = ctypes.c_int64()
-        out_max_col = ctypes.c_int64()
-        i64p = ctypes.POINTER(ctypes.c_int64)
-        i32p = ctypes.POINTER(ctypes.c_int32)
-        u8p = ctypes.POINTER(ctypes.c_uint8)
+        out_k = _ct.c_int64()
+        out_max_col = _ct.c_int64()
         old_cap = self.capacity
         rc = self._lib.sm_pane_ingest(
-            self._h, n, keys.ctypes.data_as(i64p), ts.ctypes.data_as(i64p),
+            self._h, n, keys.ctypes.data_as(_I64P), ts.ctypes.data_as(_I64P),
             int(offset), int(width), int(max_uniq),
-            cols.ctypes.data_as(i32p), is_new.ctypes.data_as(u8p),
-            sinv.ctypes.data_as(i32p), uniq.ctypes.data_as(i64p),
-            ctypes.byref(out_k), ctypes.byref(out_max_col))
+            cols.ctypes.data_as(_I32P), is_new.ctypes.data_as(_U8P),
+            sinv.ctypes.data_as(_I32P), uniq.ctypes.data_as(_I64P),
+            _ct.byref(out_k), _ct.byref(out_max_col))
         if rc == -2:
             return None
-        if rc < 0:
-            raise self._full_error()
-        if rc > 0:
-            self._regrown(old_cap)
-        new_mask = is_new.view(bool)
-        if new_mask.any():
-            # all pane-table entries live in namespace 0
-            self._ns_slots.setdefault(0, []).append(cols[new_mask])
+        self._settle(rc, old_cap)
         return cols, sinv, uniq[:out_k.value], int(out_max_col.value)
 
     def flat_fuse(self, cols: np.ndarray, sinv: np.ndarray,
                   rowmap: np.ndarray, capacity: int) -> np.ndarray:
         """flat[i] = rowmap[sinv[i]] * capacity + cols[i] as int32, in one
         native pass (sm_flat_fuse)."""
-        import ctypes
-
         n = len(cols)
         out = np.empty(n, dtype=np.int32)
         rowmap = np.ascontiguousarray(rowmap, dtype=np.int64)
-        i64p = ctypes.POINTER(ctypes.c_int64)
-        i32p = ctypes.POINTER(ctypes.c_int32)
         self._lib.sm_flat_fuse(
-            n, cols.ctypes.data_as(i32p), sinv.ctypes.data_as(i32p),
-            rowmap.ctypes.data_as(i64p), int(capacity),
-            out.ctypes.data_as(i32p))
+            n, cols.ctypes.data_as(_I32P), sinv.ctypes.data_as(_I32P),
+            rowmap.ctypes.data_as(_I64P), int(capacity),
+            out.ctypes.data_as(_I32P))
         return out
 
     def lookup(self, key_ids: np.ndarray,
@@ -803,30 +827,17 @@ class NativeSlotIndex(_NamespaceRegistry):
                             out.ctypes.data_as(_I32P))
         return out
 
-    def free_namespaces(self, namespaces: List[int]) -> Optional[np.ndarray]:
-        drained = self._registry_drain(namespaces)
-        if drained is None:
-            return None
-        slots = np.ascontiguousarray(drained, dtype=np.int32)
-        keys = np.ascontiguousarray(self.slot_key[slots])
-        nss = np.ascontiguousarray(self.slot_ns[slots])
-        out = np.empty(len(slots), dtype=np.int32)
-        n = self._lib.sm_erase(
-            self._h, len(slots),
-            keys.ctypes.data_as(_I64P), nss.ctypes.data_as(_I64P),
-            out.ctypes.data_as(_I32P))
-        return out[:n]
-
     def free_slots(self, slots: np.ndarray, keys=None, nss=None) -> None:
-        """Release individual slots (TTL expiry) via the native erase.
-        ``keys``/``nss`` let a caller that already holds the slots' pair
-        columns skip the per-slot metadata gathers."""
+        """Release individual slots (TTL expiry, paged eviction, fired
+        sessions) via the native erase: each pair leaves its namespace's
+        table, or the flat one. ``keys``/``nss`` let a caller that already
+        holds the slots' pair columns skip the per-slot metadata
+        gathers."""
         slots = np.ascontiguousarray(slots, dtype=np.int32)
         if not len(slots):
             return
         if nss is None:
             nss = self.slot_ns[slots]
-        self._registry_remove_slots(slots, nss)
         if keys is None:
             keys = self.slot_key[slots]
         keys = np.ascontiguousarray(keys, dtype=np.int64)
@@ -1850,8 +1861,15 @@ class SlotTable:
 
     def free_namespaces(self, namespaces: List[int]) -> int:
         """Release all slots of the given namespaces (windows fully
-        fired); returns how many (key, namespace) pairs were erased."""
+        fired); returns how many (key, namespace) pairs were erased. The
+        pairs that left with their namespace's whole table (the native
+        index's drop; none on the Python index) are stated as a
+        ``retire.drop`` instant."""
+        dropped = self.index.pairs_dropped
         slots = self.index.free_namespaces(namespaces)
+        if self.index.pairs_dropped > dropped:
+            flight.instant("retire.drop",
+                           work=self.index.pairs_dropped - dropped)
         self._freed_ns.extend(int(n) for n in namespaces)
         if self._paged:
             self._drop_spilled_sessions(

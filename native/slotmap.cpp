@@ -2,14 +2,27 @@
 // backend. This is the native half of the keyed-state hot path: the role the
 // reference delegates to RocksDB/ForSt via JNI (batch point lookups backing
 // StateExecutor.executeBatchRequests) is played here by an open-addressing
-// table that maps 128-bit (key_id, namespace) pairs to dense device slot ids
+// index that maps 128-bit (key_id, namespace) pairs to dense device slot ids
 // in one C call per micro-batch. No LSM is needed — persistence comes from
 // logical snapshots of the slot arrays (see flink_tpu/state/slot_table.py).
 //
-// Design: linear-probing buckets sized 2x slot capacity (load <= 0.5),
-// slot-id free list, slot 0 reserved as the identity slot, growth by
-// doubling with full rebuild (bounded amortized cost, mirrors the device
-// array growth in Python).
+// One of everything but the probe and the erase: slot ids, the per-slot
+// metadata (slot_key / slot_ns / slot_used), the free stack, slot 0 reserved
+// as the identity slot, growth by doubling (mirrors the device array growth
+// in Python), the batch sweep's pass over the namespaces. The pair -> slot
+// map itself has two forms, chosen at sm_create by what the owner does:
+//
+// - partitioned (the owner frees by namespace: window slices, keyed state):
+//   a directory namespace -> table and, per namespace, one open-addressing
+//   table keyed by the key alone with the key stored in the bucket beside
+//   the slot id, plus the namespace's keys[] / slots[] dense in insertion
+//   order. A namespace's pairs live together and leave together: the retire
+//   hands out slots[] and drops the table, the fire's resolve reads
+//   (keys[], slots[]) from where it stopped, a probe touches one table.
+// - flat (the owner frees by slot: session tables, one row per namespace,
+//   millions of namespaces): one table over (key, namespace) whose buckets
+//   hold slot ids and whose compares read slot_key / slot_ns, sized 2x the
+//   slot capacity and rebuilt on growth.
 //
 // Exposed as a plain C ABI for ctypes; all batch arguments are raw pointers
 // into NumPy buffers.
@@ -30,25 +43,76 @@ struct SweepScratch {
   int64_t uniq_cap;  // distinct namespaces the arrays below hold
   int64_t* val;      // [uniq_cap] the namespace
   int64_t* count;    // [uniq_cap] records under it
-  int64_t* cursor;   // [uniq_cap] where its next new slot goes
+  int64_t* fresh;    // [uniq_cap] pairs of it newly given a slot
   int32_t* order;    // [uniq_cap] first-seen indexes by ascending namespace
+  int32_t* table;    // [uniq_cap] partitioned: its table's index
   int64_t tab_size;  // power of two, > 2 * distinct held
   int32_t* tab;      // namespace -> first-seen index, -1 empty
 };
+
+// ---- the partitioned form: one table per namespace
+
+struct NsBucket {
+  int64_t key;
+  int32_t slot;  // 0 = empty (slot 0 is the reserved identity slot)
+  int32_t pad;
+};
+
+// Memory of one table: keys[cap] and slots[cap] in one block, the buckets
+// (2 * cap of them; none while cap <= TINY_CAP, a table that small is
+// scanned) in another.
+struct NsBlock {
+  int64_t cap;
+  int64_t* keys;
+  int32_t* slots;
+  int64_t nb;
+  NsBucket* buckets;
+};
+
+struct NsTable {
+  int64_t ns;
+  uint64_t gen;  // order of opening: a later table of the same name differs
+  int64_t n;     // pairs held: keys[0, n) / slots[0, n) in insertion order
+  NsBlock mem;
+  bool thinned;  // sm_erase took pairs out and has not closed the gaps yet
+};
+
+constexpr int64_t TINY_CAP = 8;   // pairs a table holds without buckets
+constexpr int64_t FIRST_CAP = 4;  // pairs a new table opens for
+constexpr int POOL_MAX = 4;       // dropped tables' memory kept for reuse
 
 struct SlotMap {
   int64_t capacity;      // slot array capacity (includes reserved slot 0)
   int64_t max_capacity;  // growth bound
   int64_t used;          // live entries
-  int64_t bucket_count;  // power of two, >= 2*capacity
-  int32_t* buckets;      // slot id, -1 empty (deletion is backward-shift,
-                         // so no tombstones ever exist)
   int64_t* slot_key;     // [capacity]
   int64_t* slot_ns;      // [capacity]
   uint8_t* slot_used;    // [capacity]
   int32_t* free_stack;   // [capacity]
   int64_t free_top;      // stack size
   SweepScratch sweep;    // zeroed by sm_create's calloc, grown on demand
+  bool partitioned;
+  // flat form
+  int64_t bucket_count;  // power of two, >= 2*capacity
+  int32_t* buckets;      // slot id, -1 empty (deletion is backward-shift,
+                         // so no tombstones ever exist)
+  // partitioned form (a flat map keeps the directory too, empty: every
+  // entry that asks for a namespace's table finds none)
+  NsTable* tabs;         // [tabs_cap] tables by index; live ones are in dir
+  int64_t tabs_cap;
+  int64_t tabs_top;      // indexes ever handed out
+  int32_t* tab_free;     // [tabs_cap] indexes of closed tables
+  int64_t tab_free_top;
+  int64_t tabs_live;
+  int32_t* dir;          // namespace -> table index, -1 empty (open hash,
+                         // backward-shift deletion)
+  int64_t dir_size;      // power of two, > 2 * tabs_live
+  uint64_t next_gen;
+  uint64_t thinned;      // times sm_erase closed gaps in some keys[]/slots[]
+  NsBlock pool[POOL_MAX];
+  int pool_n;
+  int32_t* thin_list;    // sm_erase scratch: tables it thinned
+  int64_t thin_cap;
 };
 
 inline uint64_t mix_hash(uint64_t k, uint64_t n) {
@@ -61,7 +125,25 @@ inline uint64_t mix_hash(uint64_t k, uint64_t n) {
   return x;
 }
 
-void build_buckets(SlotMap* m) {
+// Deletion from an open-addressing table without tombstones (Knuth 6.4
+// algorithm R, backward shift): the bucket at ``hole`` was vacated; every
+// follower of its chain whose home does not lie (cyclically) strictly
+// after the hole moves back into it. ``empty(j)`` ends the chain,
+// ``home(j)`` is bucket j's entry's first choice, ``move(to, from)`` moves
+// an entry. Returns the bucket left vacant, for the caller to mark empty.
+template <class Empty, class Home, class Move>
+inline uint64_t close_hole(uint64_t hole, uint64_t mask, Empty empty,
+                           Home home, Move move) {
+  for (uint64_t j = (hole + 1) & mask; !empty(j); j = (j + 1) & mask) {
+    if (((j - home(j)) & mask) >= ((j - hole) & mask)) {
+      move(hole, j);
+      hole = j;
+    }
+  }
+  return hole;
+}
+
+void flat_build_buckets(SlotMap* m) {
   int64_t want = m->capacity * 2;
   int64_t bc = 64;
   while (bc < want) bc <<= 1;
@@ -79,7 +161,9 @@ void build_buckets(SlotMap* m) {
   }
 }
 
-// returns 0 on success, -1 if at max capacity
+// Double the slot arrays. Returns 0 on success, -1 if at max capacity. The
+// per-namespace tables hold slot ids and are untouched by it; the flat
+// table is sized by the capacity and rebuilt.
 int grow(SlotMap* m) {
   if (m->capacity >= m->max_capacity) return -1;
   int64_t old_cap = m->capacity;
@@ -93,34 +177,51 @@ int grow(SlotMap* m) {
   for (int64_t s = new_cap - 1; s >= old_cap; s--)
     m->free_stack[m->free_top++] = (int32_t)s;
   m->capacity = new_cap;
-  build_buckets(m);
+  if (!m->partitioned) flat_build_buckets(m);
   return 0;
 }
 
-// The probe of one (key, ns) pair from its hash: the pair's slot, a free
-// one taken where the pair is new (*is_new), the table grown where none is
-// free (*grows counts them). -1: full at max_capacity.
-inline int32_t probe_or_insert(SlotMap* m, int64_t k, int64_t ns,
-                               uint64_t hash, int32_t* grows, bool* is_new) {
+// A free slot for a new pair, the slot arrays grown where none is left
+// (*grows counts them). -1: full at max_capacity, nothing changed.
+inline int32_t take_slot(SlotMap* m, int64_t k, int64_t ns, int32_t* grows) {
+  if (m->free_top == 0) {
+    if (grow(m) != 0) return -1;
+    ++*grows;
+  }
+  int32_t slot = m->free_stack[--m->free_top];
+  m->slot_key[slot] = k;
+  m->slot_ns[slot] = ns;
+  m->slot_used[slot] = 1;
+  m->used++;
+  return slot;
+}
+
+inline void release_slot(SlotMap* m, int32_t slot) {
+  m->slot_used[slot] = 0;
+  m->free_stack[m->free_top++] = slot;
+  m->used--;
+}
+
+// ---- flat form: the probe of one (key, ns) pair from its hash: the pair's
+// slot, a free one taken where the pair is new (*is_new). -1: full.
+inline int32_t flat_probe_or_insert(SlotMap* m, int64_t k, int64_t ns,
+                                    uint64_t hash, int32_t* grows,
+                                    bool* is_new) {
   uint64_t mask = (uint64_t)m->bucket_count - 1;
   uint64_t i = hash & mask;
   for (;;) {
     int32_t b = m->buckets[i];
     if (b == -1) {
-      if (m->free_top == 0) {
-        if (grow(m) != 0) return -1;
-        ++*grows;
-        // re-probe against rebuilt buckets
+      int32_t before = *grows;
+      int32_t slot = take_slot(m, k, ns, grows);
+      if (slot < 0) return -1;
+      if (*grows != before) {
+        // the buckets were rebuilt: find the pair's empty bucket again
         mask = (uint64_t)m->bucket_count - 1;
         i = hash & mask;
-        continue;
+        while (m->buckets[i] != -1) i = (i + 1) & mask;
       }
-      int32_t slot = m->free_stack[--m->free_top];
       m->buckets[i] = slot;
-      m->slot_key[slot] = k;
-      m->slot_ns[slot] = ns;
-      m->slot_used[slot] = 1;
-      m->used++;
       *is_new = true;
       return slot;
     }
@@ -130,6 +231,292 @@ inline int32_t probe_or_insert(SlotMap* m, int64_t k, int64_t ns,
     }
     i = (i + 1) & mask;
   }
+}
+
+inline int32_t flat_find(const SlotMap* m, int64_t k, int64_t ns,
+                         uint64_t hash) {
+  uint64_t mask = (uint64_t)m->bucket_count - 1;
+  for (uint64_t i = hash & mask;; i = (i + 1) & mask) {
+    int32_t b = m->buckets[i];
+    if (b == -1) return -1;
+    if (m->slot_key[b] == k && m->slot_ns[b] == ns) return b;
+  }
+}
+
+// Erase one pair: its slot, or -1 where the table does not hold it.
+inline int32_t flat_erase(SlotMap* m, int64_t k, int64_t ns, uint64_t hash) {
+  uint64_t mask = (uint64_t)m->bucket_count - 1;
+  uint64_t i = hash & mask;
+  for (;; i = (i + 1) & mask) {
+    int32_t b = m->buckets[i];
+    if (b == -1) return -1;
+    if (m->slot_key[b] == k && m->slot_ns[b] == ns) break;
+  }
+  int32_t slot = m->buckets[i];
+  int32_t* bk = m->buckets;
+  m->buckets[close_hole(
+      i, mask, [bk](uint64_t j) { return bk[j] == -1; },
+      [m, bk, mask](uint64_t j) {
+        return mix_hash((uint64_t)m->slot_key[bk[j]],
+                        (uint64_t)m->slot_ns[bk[j]]) & mask;
+      },
+      [bk](uint64_t to, uint64_t from) { bk[to] = bk[from]; })] = -1;
+  return slot;
+}
+
+// ---- partitioned form: the directory
+
+inline uint64_t dir_hash(int64_t ns) { return mix_hash((uint64_t)ns, 1); }
+
+// Index of the namespace's table, -1 where it has none.
+inline int32_t dir_find(const SlotMap* m, int64_t ns) {
+  uint64_t mask = (uint64_t)m->dir_size - 1;
+  for (uint64_t b = dir_hash(ns) & mask;; b = (b + 1) & mask) {
+    int32_t t = m->dir[b];
+    if (t < 0 || m->tabs[t].ns == ns) return t;
+  }
+}
+
+void dir_insert(SlotMap* m, int64_t ns, int32_t t) {
+  if ((m->tabs_live + 1) * 2 >= m->dir_size) {
+    m->dir_size *= 2;
+    m->dir = (int32_t*)realloc(m->dir, sizeof(int32_t) * m->dir_size);
+    memset(m->dir, 0xff, sizeof(int32_t) * m->dir_size);
+    uint64_t mask = (uint64_t)m->dir_size - 1;
+    // every live table but t (not entered yet; its slot in tabs is set)
+    for (int64_t i = 0; i < m->tabs_top; i++) {
+      if (m->tabs[i].n < 0 || i == t) continue;
+      uint64_t b = dir_hash(m->tabs[i].ns) & mask;
+      while (m->dir[b] >= 0) b = (b + 1) & mask;
+      m->dir[b] = (int32_t)i;
+    }
+  }
+  uint64_t mask = (uint64_t)m->dir_size - 1;
+  uint64_t b = dir_hash(ns) & mask;
+  while (m->dir[b] >= 0) b = (b + 1) & mask;
+  m->dir[b] = t;
+}
+
+void dir_remove(SlotMap* m, int64_t ns) {
+  uint64_t mask = (uint64_t)m->dir_size - 1;
+  uint64_t hole = dir_hash(ns) & mask;
+  while (m->tabs[m->dir[hole]].ns != ns) hole = (hole + 1) & mask;
+  int32_t* dir = m->dir;
+  dir[close_hole(
+      hole, mask, [dir](uint64_t j) { return dir[j] < 0; },
+      [m, dir, mask](uint64_t j) {
+        return dir_hash(m->tabs[dir[j]].ns) & mask;
+      },
+      [dir](uint64_t to, uint64_t from) { dir[to] = dir[from]; })] = -1;
+}
+
+// ---- partitioned form: one namespace's table
+
+inline uint64_t key_hash(int64_t k) { return mix_hash((uint64_t)k, 0); }
+
+NsBlock block_alloc(int64_t cap) {
+  NsBlock b;
+  b.cap = cap;
+  b.keys = (int64_t*)malloc((sizeof(int64_t) + sizeof(int32_t)) * cap);
+  b.slots = (int32_t*)(b.keys + cap);
+  b.nb = cap > TINY_CAP ? cap * 2 : 0;
+  b.buckets = b.nb ? (NsBucket*)calloc(b.nb, sizeof(NsBucket)) : nullptr;
+  return b;
+}
+
+inline void block_free(NsBlock* b) {
+  free(b->keys);
+  free(b->buckets);
+}
+
+// Enter (key, slot), known to be absent, into the buckets.
+inline void bucket_put(NsBlock* b, int64_t key, int32_t slot) {
+  uint64_t mask = (uint64_t)b->nb - 1;
+  uint64_t i = key_hash(key) & mask;
+  while (b->buckets[i].slot) i = (i + 1) & mask;
+  b->buckets[i].key = key;
+  b->buckets[i].slot = slot;
+}
+
+// Double a full table: the pairs move in their order, the buckets are
+// filled again from them.
+void table_grow(NsTable* t) {
+  NsBlock b = block_alloc(t->mem.cap * 2);
+  memcpy(b.keys, t->mem.keys, sizeof(int64_t) * t->n);
+  memcpy(b.slots, t->mem.slots, sizeof(int32_t) * t->n);
+  if (b.nb)
+    for (int64_t i = 0; i < t->n; i++) bucket_put(&b, b.keys[i], b.slots[i]);
+  block_free(&t->mem);
+  t->mem = b;
+}
+
+// A table for a namespace that has none: in the memory of a dropped one
+// where one is kept, at the size that one closed at — a window job's
+// slices are as large as the slice before them, and a table that is
+// neither grown nor paged in while a batch runs is what makes its probe
+// cheap. Else new and small, grown by doubling: a size is only ever taken
+// on credit of a table that left, so many small namespaces opened after a
+// big one was dropped cost what they hold.
+int32_t table_open(SlotMap* m, int64_t ns) {
+  int32_t ti;
+  if (m->tab_free_top) {
+    ti = m->tab_free[--m->tab_free_top];
+  } else {
+    if (m->tabs_top == m->tabs_cap) {
+      m->tabs_cap = m->tabs_cap ? m->tabs_cap * 2 : 16;
+      m->tabs = (NsTable*)realloc(m->tabs, sizeof(NsTable) * m->tabs_cap);
+      m->tab_free =
+          (int32_t*)realloc(m->tab_free, sizeof(int32_t) * m->tabs_cap);
+    }
+    ti = (int32_t)m->tabs_top++;
+  }
+  NsTable* t = &m->tabs[ti];
+  t->ns = ns;
+  t->gen = m->next_gen++;
+  t->n = 0;
+  t->thinned = false;
+  t->mem = m->pool_n ? m->pool[--m->pool_n] : block_alloc(FIRST_CAP);
+  dir_insert(m, ns, ti);
+  m->tabs_live++;
+  return ti;
+}
+
+// Empty a block's buckets for its next table: the ones its n keys sit in
+// where they are few against the buckets, else all of them.
+void block_clear(NsBlock* b, int64_t n) {
+  if (!b->nb) return;
+  if (n * 64 >= b->nb) {
+    memset(b->buckets, 0, sizeof(NsBucket) * b->nb);
+    return;
+  }
+  uint64_t mask = (uint64_t)b->nb - 1;
+  // find them all first (a bucket emptied early would cut a chain short),
+  // each key's place kept where the key was
+  for (int64_t i = 0; i < n; i++) {
+    uint64_t p = key_hash(b->keys[i]) & mask;
+    while (b->buckets[p].key != b->keys[i] || !b->buckets[p].slot)
+      p = (p + 1) & mask;
+    b->keys[i] = (int64_t)p;
+  }
+  for (int64_t i = 0; i < n; i++) b->buckets[b->keys[i]].slot = 0;
+}
+
+// The table leaves the directory; its slots are the caller's to release.
+void table_close(SlotMap* m, int32_t ti) {
+  NsTable* t = &m->tabs[ti];
+  dir_remove(m, t->ns);
+  if (t->mem.nb && m->pool_n < POOL_MAX) {
+    block_clear(&t->mem, t->n);
+    m->pool[m->pool_n++] = t->mem;
+  } else {
+    block_free(&t->mem);
+  }
+  t->n = -1;
+  m->tab_free[m->tab_free_top++] = ti;
+  m->tabs_live--;
+}
+
+// The probe of one key in its namespace's table from its hash: the pair's
+// slot, a free one taken where the pair is new (*is_new). -1: full.
+inline int32_t part_probe_or_insert(SlotMap* m, NsTable* t, int64_t k,
+                                    uint64_t hash, int32_t* grows,
+                                    bool* is_new) {
+  NsBlock* b = &t->mem;
+  *is_new = false;
+  NsBucket* empty = nullptr;  // where the key goes if it is new
+  if (b->nb) {
+    uint64_t mask = (uint64_t)b->nb - 1;
+    for (uint64_t i = hash & mask;; i = (i + 1) & mask) {
+      NsBucket* e = &b->buckets[i];
+      if (!e->slot) {
+        empty = e;
+        break;
+      }
+      if (e->key == k) return e->slot;
+    }
+  } else {
+    for (int64_t i = 0; i < t->n; i++)
+      if (b->keys[i] == k) return b->slots[i];
+  }
+  int32_t slot = take_slot(m, k, t->ns, grows);
+  if (slot < 0) return -1;
+  if (t->n == b->cap) {
+    table_grow(t);
+    empty = nullptr;
+  }
+  if (empty) {
+    empty->key = k;
+    empty->slot = slot;
+  } else if (b->nb) {
+    bucket_put(b, k, slot);
+  }
+  b->keys[t->n] = k;
+  b->slots[t->n] = slot;
+  t->n++;
+  *is_new = true;
+  return slot;
+}
+
+inline int32_t part_find(const NsTable* t, int64_t k, uint64_t hash) {
+  const NsBlock* b = &t->mem;
+  if (b->nb) {
+    uint64_t mask = (uint64_t)b->nb - 1;
+    for (uint64_t i = hash & mask;; i = (i + 1) & mask) {
+      const NsBucket* e = &b->buckets[i];
+      if (!e->slot) return -1;
+      if (e->key == k) return e->slot;
+    }
+  }
+  for (int64_t i = 0; i < t->n; i++)
+    if (b->keys[i] == k) return b->slots[i];
+  return -1;
+}
+
+inline void part_prefetch(const NsTable* t, uint64_t hash) {
+  if (t->mem.nb)
+    __builtin_prefetch(&t->mem.buckets[hash & ((uint64_t)t->mem.nb - 1)], 0,
+                       1);
+}
+
+// Take one key out of its table's buckets (backward shift inside the
+// table); its slot, or -1. keys[] / slots[] keep the pair until
+// table_close_gaps.
+inline int32_t part_erase(const SlotMap* m, NsTable* t, int64_t k,
+                          uint64_t hash) {
+  NsBlock* b = &t->mem;
+  if (!b->nb) {
+    // scanned table: a pair erased twice in one call is still in keys[]
+    for (int64_t i = 0; i < t->n; i++)
+      if (b->keys[i] == k && m->slot_used[b->slots[i]]) return b->slots[i];
+    return -1;
+  }
+  uint64_t mask = (uint64_t)b->nb - 1;
+  uint64_t i = hash & mask;
+  for (;; i = (i + 1) & mask) {
+    if (!b->buckets[i].slot) return -1;
+    if (b->buckets[i].key == k) break;
+  }
+  int32_t slot = b->buckets[i].slot;
+  NsBucket* bk = b->buckets;
+  bk[close_hole(
+      i, mask, [bk](uint64_t j) { return !bk[j].slot; },
+      [bk, mask](uint64_t j) { return key_hash(bk[j].key) & mask; },
+      [bk](uint64_t to, uint64_t from) { bk[to] = bk[from]; })].slot = 0;
+  return slot;
+}
+
+// keys[] / slots[] dense again after part_erase: the pairs whose slot was
+// released go, the others keep their order.
+void table_close_gaps(const SlotMap* m, NsTable* t) {
+  NsBlock* b = &t->mem;
+  int64_t kept = 0;
+  for (int64_t i = 0; i < t->n; i++) {
+    if (!m->slot_used[b->slots[i]]) continue;
+    b->keys[kept] = b->keys[i];
+    b->slots[kept++] = b->slots[i];
+  }
+  t->n = kept;
+  t->thinned = false;
 }
 
 // The fire path's carried slot matrix (see sm_carry_advance below).
@@ -142,6 +529,11 @@ struct SliceCarry {
   int64_t bucket_count;  // power of two, >= 2 * row_cap
   int32_t* buckets;      // key -> row, -1 empty (backward-shift deletion)
   int32_t* emptied;      // [row_cap] scratch: rows an advance left empty
+  // what the matrix holds of each column's namespace (the last call's)
+  int64_t* ends;         // [k] the namespace
+  uint64_t* gens;        // [k] its table's gen
+  int64_t* consumed;     // [k] pairs of its keys[] / slots[] entered
+  uint64_t thinned;      // the map's count of gap-closing erases then
 };
 
 void carry_grow(SliceCarry* c) {
@@ -176,16 +568,14 @@ inline uint64_t carry_bucket_of(const SliceCarry* c, int64_t key) {
 // row takes its place so the rows stay dense.
 void carry_remove_row(SliceCarry* c, int64_t r) {
   uint64_t mask = (uint64_t)c->bucket_count - 1;
-  uint64_t hole = carry_bucket_of(c, c->keys[r]);
-  for (uint64_t j = (hole + 1) & mask; c->buckets[j] != -1;
-       j = (j + 1) & mask) {
-    uint64_t home = mix_hash((uint64_t)c->keys[c->buckets[j]], 0) & mask;
-    if (((j - home) & mask) >= ((j - hole) & mask)) {
-      c->buckets[hole] = c->buckets[j];
-      hole = j;
-    }
-  }
-  c->buckets[hole] = -1;
+  int32_t* bk = c->buckets;
+  bk[close_hole(
+      carry_bucket_of(c, c->keys[r]), mask,
+      [bk](uint64_t j) { return bk[j] == -1; },
+      [c, bk, mask](uint64_t j) {
+        return mix_hash((uint64_t)c->keys[bk[j]], 0) & mask;
+      },
+      [bk](uint64_t to, uint64_t from) { bk[to] = bk[from]; })] = -1;
   int64_t last = --c->rows;
   if (r == last) return;
   c->buckets[carry_bucket_of(c, c->keys[last])] = (int32_t)r;
@@ -210,8 +600,9 @@ inline int32_t sweep_index_of(SweepScratch* w, int64_t v, int64_t* k,
     int64_t cap = w->uniq_cap ? w->uniq_cap * 2 : 64;
     w->val = (int64_t*)realloc(w->val, sizeof(int64_t) * cap);
     w->count = (int64_t*)realloc(w->count, sizeof(int64_t) * cap);
-    w->cursor = (int64_t*)realloc(w->cursor, sizeof(int64_t) * cap);
+    w->fresh = (int64_t*)realloc(w->fresh, sizeof(int64_t) * cap);
     w->order = (int32_t*)realloc(w->order, sizeof(int32_t) * cap);
+    w->table = (int32_t*)realloc(w->table, sizeof(int32_t) * cap);
     w->uniq_cap = cap;
   }
   int32_t i = (int32_t)(*k)++;
@@ -238,7 +629,8 @@ inline int32_t sweep_index_of(SweepScratch* w, int64_t v, int64_t* k,
 
 extern "C" {
 
-void* sm_create(int64_t initial_capacity, int64_t max_capacity) {
+void* sm_create(int64_t initial_capacity, int64_t max_capacity,
+                int32_t partitioned) {
   if (initial_capacity < 1024) initial_capacity = 1024;
   if (max_capacity < initial_capacity) max_capacity = initial_capacity;
   SlotMap* m = (SlotMap*)calloc(1, sizeof(SlotMap));
@@ -251,13 +643,23 @@ void* sm_create(int64_t initial_capacity, int64_t max_capacity) {
   m->free_top = 0;
   for (int64_t s = initial_capacity - 1; s >= 1; s--)
     m->free_stack[m->free_top++] = (int32_t)s;
-  m->buckets = nullptr;
-  build_buckets(m);
+  m->partitioned = partitioned != 0;
+  if (!m->partitioned) flat_build_buckets(m);
+  m->dir_size = 64;
+  m->dir = (int32_t*)malloc(sizeof(int32_t) * m->dir_size);
+  memset(m->dir, 0xff, sizeof(int32_t) * m->dir_size);
   return m;
 }
 
 void sm_destroy(void* h) {
   SlotMap* m = (SlotMap*)h;
+  for (int64_t i = 0; i < m->tabs_top; i++)
+    if (m->tabs[i].n >= 0) block_free(&m->tabs[i].mem);
+  for (int i = 0; i < m->pool_n; i++) block_free(&m->pool[i]);
+  free(m->tabs);
+  free(m->tab_free);
+  free(m->dir);
+  free(m->thin_list);
   free(m->buckets);
   free(m->slot_key);
   free(m->slot_ns);
@@ -266,8 +668,9 @@ void sm_destroy(void* h) {
   free(m->sweep.sinv);
   free(m->sweep.val);
   free(m->sweep.count);
-  free(m->sweep.cursor);
+  free(m->sweep.fresh);
   free(m->sweep.order);
+  free(m->sweep.table);
   free(m->sweep.tab);
   free(m);
 }
@@ -288,16 +691,49 @@ int32_t sm_lookup_or_insert(void* h, int64_t n, const int64_t* keys,
                             uint8_t* out_is_new) {
   SlotMap* m = (SlotMap*)h;
   int32_t grows = 0;
-  // Chunked software prefetch: the table spans far more than L2, so the
-  // bucket probe and the slot_key/slot_ns verify are each a likely cache
-  // miss. Hash a chunk up front, prefetch every home bucket line, then
-  // peek the (now warm) buckets to prefetch the slot rows. Inserts during
-  // processing only make earlier hints stale — hints are never required
-  // for correctness.
+  // Chunked software prefetch: the tables span far more than L2, so a
+  // probe's first bucket (and, flat, the slot_key/slot_ns verify behind
+  // it) is a likely cache miss. Hash a chunk up front, prefetch every home
+  // bucket line, then (flat) peek the now warm buckets to prefetch the
+  // slot rows. Inserts during processing only make earlier hints stale —
+  // hints are never required for correctness.
   constexpr int64_t CHUNK = 256;
   uint64_t hashes[CHUNK];
+  int32_t tis[CHUNK];
   for (int64_t base = 0; base < n; base += CHUNK) {
     int64_t end = base + CHUNK < n ? base + CHUNK : n;
+    bool is_new;
+    if (m->partitioned) {
+      // each record's table first (opened where its namespace has none:
+      // the table array may move, so indexes and not pointers)
+      int32_t ti = -1;
+      for (int64_t r = base; r < end; r++) {
+        if (ti < 0 || m->tabs[ti].ns != nss[r]) {
+          ti = dir_find(m, nss[r]);
+          if (ti < 0) ti = table_open(m, nss[r]);
+        }
+        tis[r - base] = ti;
+      }
+      for (int64_t r = base; r < end; r++) {
+        hashes[r - base] = key_hash(keys[r]);
+        part_prefetch(&m->tabs[tis[r - base]], hashes[r - base]);
+      }
+      int32_t slot = 0;
+      int64_t r = base;
+      for (; r < end && slot >= 0; r++) {
+        slot = part_probe_or_insert(m, &m->tabs[tis[r - base]], keys[r],
+                                    hashes[r - base], &grows, &is_new);
+        out_slots[r] = slot;
+        if (out_is_new) out_is_new[r] = is_new;
+      }
+      if (slot < 0) {
+        // full: the tables this chunk opened and nothing entered go again
+        for (r = base; r < end; r++)
+          if (m->tabs[tis[r - base]].n == 0) table_close(m, tis[r - base]);
+        return -1;
+      }
+      continue;
+    }
     uint64_t pmask = (uint64_t)m->bucket_count - 1;
     for (int64_t r = base; r < end; r++) {
       uint64_t hh = mix_hash((uint64_t)keys[r], (uint64_t)nss[r]);
@@ -312,9 +748,8 @@ int32_t sm_lookup_or_insert(void* h, int64_t n, const int64_t* keys,
       }
     }
     for (int64_t r = base; r < end; r++) {
-      bool is_new;
-      int32_t slot = probe_or_insert(m, keys[r], nss[r], hashes[r - base],
-                                     &grows, &is_new);
+      int32_t slot = flat_probe_or_insert(m, keys[r], nss[r],
+                                          hashes[r - base], &grows, &is_new);
       if (slot < 0) return -1;
       out_slots[r] = slot;
       if (out_is_new) out_is_new[r] = is_new;
@@ -324,9 +759,8 @@ int32_t sm_lookup_or_insert(void* h, int64_t n, const int64_t* keys,
 }
 
 // One sweep that resolves a whole batch: (key, namespace) -> slot for
-// every record, and the slots newly given out grouped by namespace — what
-// the registry (flink_tpu/state/slot_table.py) appends, with no is_new
-// mask, sort or split behind the call.
+// every record, and per distinct namespace the records under it and the
+// pairs newly given a slot.
 //
 // ``vals`` are timestamps where width > 0: a record's namespace is then
 // the end of its slice, ts - floormod(ts - offset, width) + width (the
@@ -338,24 +772,21 @@ int32_t sm_lookup_or_insert(void* h, int64_t n, const int64_t* keys,
 // namespace (first-seen index, kept in scratch) and the records under
 // each. More than max_uniq distinct namespaces, or (width > 0) a slice end
 // below live_from — a late record, which the caller's own path drops and
-// counts — returns -2 with the table untouched. The counts give each
-// namespace its place in out_new, ascending by namespace, before the first
-// insert. Pass B is sm_lookup_or_insert's probe (same hash, prefetch,
-// growth), a new slot going to its namespace's cursor: new slots come out
-// grouped, in record order within a group.
+// counts — returns -2 with the index untouched. Pass B is
+// sm_lookup_or_insert's probe (same hashes, prefetch, growth); partitioned,
+// each distinct namespace's table is found (or opened) once before it and
+// a record goes straight to its own slice's table, where its new pair is
+// appended: the namespace's slots stay in record order.
 //
 // out_groups is [3, max_uniq] int64: the distinct namespaces ascending,
-// the records under each, the new slots of each. Namespace j's new slots
-// start at out_new[records of the namespaces before it]. *out_k = distinct
-// namespaces. Returns grows (>= 0) or -1 (table full at max_capacity;
-// out_groups and out_new then hold what was inserted before it, so the
-// caller's registry can stay level with the table).
+// the records under each, the new pairs of each. *out_k = distinct
+// namespaces. Returns grows (>= 0) or -1 (full at max_capacity;
+// out_groups then counts what was inserted before it).
 int32_t sm_resolve_grouped(void* h, int64_t n, const int64_t* keys,
                            const int64_t* vals, int64_t offset,
                            int64_t width, int64_t live_from,
                            int64_t max_uniq, int32_t* out_slots,
-                           int32_t* out_new, int64_t* out_groups,
-                           int64_t* out_k) {
+                           int64_t* out_groups, int64_t* out_k) {
   SlotMap* m = (SlotMap*)h;
   SweepScratch* w = &m->sweep;
   if (n > w->rec_cap) {
@@ -401,59 +832,85 @@ int32_t sm_resolve_grouped(void* h, int64_t n, const int64_t* keys,
     }
     if (cur >= 0) w->count[cur] += run;
   }
-  for (int64_t j = 0; j < k; j++) w->order[j] = (int32_t)j;
+  for (int64_t j = 0; j < k; j++) {
+    w->order[j] = (int32_t)j;
+    w->fresh[j] = 0;
+  }
   std::sort(w->order, w->order + k,
             [w](int32_t a, int32_t b) { return w->val[a] < w->val[b]; });
-  int64_t* g_val = out_groups;
-  int64_t* g_records = out_groups + max_uniq;
-  int64_t* g_new = out_groups + 2 * max_uniq;
-  int64_t pos = 0;
-  for (int64_t j = 0; j < k; j++) {
-    int32_t u = w->order[j];
-    g_val[j] = w->val[u];
-    g_records[j] = w->count[u];
-    w->cursor[u] = pos;
-    pos += w->count[u];
-  }
   *out_k = k;
   // ---- pass B: the probe (sm_lookup_or_insert's, see there)
   int32_t grows = 0;
   bool full = false;
   constexpr int64_t CHUNK = 256;
   uint64_t hashes[CHUNK];
-  for (int64_t base = 0; base < n && !full; base += CHUNK) {
-    int64_t end = base + CHUNK < n ? base + CHUNK : n;
-    uint64_t pmask = (uint64_t)m->bucket_count - 1;
-    for (int64_t r = base; r < end; r++) {
-      uint64_t hh = mix_hash((uint64_t)keys[r], (uint64_t)w->val[sinv[r]]);
-      hashes[r - base] = hh;
-      __builtin_prefetch(&m->buckets[hh & pmask], 0, 1);
+  if (m->partitioned) {
+    // ascending, so that new tables open in the order of their names
+    for (int64_t j = 0; j < k; j++) {
+      int32_t u = w->order[j];
+      int32_t ti = dir_find(m, w->val[u]);
+      w->table[u] = ti >= 0 ? ti : table_open(m, w->val[u]);
     }
-    for (int64_t r = base; r < end; r++) {
-      int32_t b = m->buckets[hashes[r - base] & pmask];
-      if (b >= 0) {
-        __builtin_prefetch(&m->slot_key[b], 0, 1);
-        __builtin_prefetch(&m->slot_ns[b], 0, 1);
+    NsTable* tabs = m->tabs;  // no table is opened from here on
+    for (int64_t base = 0; base < n && !full; base += CHUNK) {
+      int64_t end = base + CHUNK < n ? base + CHUNK : n;
+      for (int64_t r = base; r < end; r++) {
+        hashes[r - base] = key_hash(keys[r]);
+        part_prefetch(&tabs[w->table[sinv[r]]], hashes[r - base]);
+      }
+      for (int64_t r = base; r < end; r++) {
+        int32_t u = sinv[r];
+        bool is_new;
+        int32_t slot = part_probe_or_insert(m, &tabs[w->table[u]], keys[r],
+                                            hashes[r - base], &grows,
+                                            &is_new);
+        if (slot < 0) {
+          full = true;
+          break;
+        }
+        out_slots[r] = slot;
+        w->fresh[u] += is_new;
       }
     }
-    for (int64_t r = base; r < end; r++) {
-      int32_t u = sinv[r];
-      bool is_new;
-      int32_t slot = probe_or_insert(m, keys[r], w->val[u], hashes[r - base],
-                                     &grows, &is_new);
-      if (slot < 0) {
-        full = true;
-        break;
+    if (full)
+      for (int64_t u = 0; u < k; u++)
+        if (tabs[w->table[u]].n == 0) table_close(m, w->table[u]);
+  } else {
+    for (int64_t base = 0; base < n && !full; base += CHUNK) {
+      int64_t end = base + CHUNK < n ? base + CHUNK : n;
+      uint64_t pmask = (uint64_t)m->bucket_count - 1;
+      for (int64_t r = base; r < end; r++) {
+        uint64_t hh = mix_hash((uint64_t)keys[r], (uint64_t)w->val[sinv[r]]);
+        hashes[r - base] = hh;
+        __builtin_prefetch(&m->buckets[hh & pmask], 0, 1);
       }
-      out_slots[r] = slot;
-      if (is_new) out_new[w->cursor[u]++] = slot;
+      for (int64_t r = base; r < end; r++) {
+        int32_t b = m->buckets[hashes[r - base] & pmask];
+        if (b >= 0) {
+          __builtin_prefetch(&m->slot_key[b], 0, 1);
+          __builtin_prefetch(&m->slot_ns[b], 0, 1);
+        }
+      }
+      for (int64_t r = base; r < end; r++) {
+        int32_t u = sinv[r];
+        bool is_new;
+        int32_t slot = flat_probe_or_insert(m, keys[r], w->val[u],
+                                            hashes[r - base], &grows,
+                                            &is_new);
+        if (slot < 0) {
+          full = true;
+          break;
+        }
+        out_slots[r] = slot;
+        w->fresh[u] += is_new;
+      }
     }
   }
-  pos = 0;
   for (int64_t j = 0; j < k; j++) {
     int32_t u = w->order[j];
-    g_new[j] = w->cursor[u] - pos;
-    pos += w->count[u];
+    out_groups[j] = w->val[u];
+    out_groups[max_uniq + j] = w->count[u];
+    out_groups[2 * max_uniq + j] = w->fresh[u];
   }
   return full ? -1 : grows;
 }
@@ -465,11 +922,27 @@ int32_t sm_resolve_grouped(void* h, int64_t n, const int64_t* keys,
 void sm_lookup(void* h, int64_t n, const int64_t* keys, const int64_t* nss,
                int32_t* out_slots) {
   SlotMap* m = (SlotMap*)h;
-  uint64_t mask = (uint64_t)m->bucket_count - 1;
   constexpr int64_t CHUNK = 256;
   uint64_t hashes[CHUNK];
+  int32_t tis[CHUNK];
   for (int64_t base = 0; base < n; base += CHUNK) {
     int64_t end = base + CHUNK < n ? base + CHUNK : n;
+    if (m->partitioned) {
+      int32_t ti = -1;
+      for (int64_t r = base; r < end; r++) {
+        if (ti < 0 || m->tabs[ti].ns != nss[r]) ti = dir_find(m, nss[r]);
+        tis[r - base] = ti;
+        hashes[r - base] = key_hash(keys[r]);
+        if (ti >= 0) part_prefetch(&m->tabs[ti], hashes[r - base]);
+      }
+      for (int64_t r = base; r < end; r++)
+        out_slots[r] = tis[r - base] < 0
+                           ? -1
+                           : part_find(&m->tabs[tis[r - base]], keys[r],
+                                       hashes[r - base]);
+      continue;
+    }
+    uint64_t mask = (uint64_t)m->bucket_count - 1;
     for (int64_t r = base; r < end; r++) {
       uint64_t hh = mix_hash((uint64_t)keys[r], (uint64_t)nss[r]);
       hashes[r - base] = hh;
@@ -482,20 +955,8 @@ void sm_lookup(void* h, int64_t n, const int64_t* keys, const int64_t* nss,
         __builtin_prefetch(&m->slot_ns[b], 0, 1);
       }
     }
-    for (int64_t r = base; r < end; r++) {
-      int64_t k = keys[r], ns = nss[r];
-      uint64_t i = hashes[r - base] & mask;
-      out_slots[r] = -1;
-      for (;;) {
-        int32_t b = m->buckets[i];
-        if (b == -1) break;
-        if (m->slot_key[b] == k && m->slot_ns[b] == ns) {
-          out_slots[r] = b;
-          break;
-        }
-        i = (i + 1) & mask;
-      }
-    }
+    for (int64_t r = base; r < end; r++)
+      out_slots[r] = flat_find(m, keys[r], nss[r], hashes[r - base]);
   }
 }
 
@@ -528,17 +989,48 @@ void sm_verify(void* h, int64_t n, const int64_t* keys, const int64_t* nss,
   }
 }
 
-// Erase pairs; writes freed slot ids to out_slots (only for pairs that were
-// present). Returns the number actually erased. Deletion is backward-shift
-// (Knuth 6.4 algorithm R): no tombstones, so probe chains stay short under
-// the insert/erase churn of session windows and slice expiry.
+// Erase pairs one by one (TTL expiry, paged eviction, fired sessions);
+// writes freed slot ids to out_slots (only for pairs that were present).
+// Returns the number actually erased. Partitioned: each key leaves its
+// namespace's table, then the tables touched close the gaps in their
+// keys[] / slots[] (one pass each) and a table left empty is dropped; the
+// count of such calls tells the carried fire matrix that what it consumed
+// of a namespace may have moved.
 int64_t sm_erase(void* h, int64_t n, const int64_t* keys, const int64_t* nss,
                  int32_t* out_slots) {
   SlotMap* m = (SlotMap*)h;
   int64_t erased = 0;
-  uint64_t mask = (uint64_t)m->bucket_count - 1;
   constexpr int64_t CHUNK = 256;
   uint64_t hashes[CHUNK];
+  if (m->partitioned) {
+    int64_t thinned = 0;
+    int32_t ti = -1;
+    for (int64_t r = 0; r < n; r++) {
+      if (ti < 0 || m->tabs[ti].ns != nss[r]) ti = dir_find(m, nss[r]);
+      if (ti < 0) continue;
+      NsTable* t = &m->tabs[ti];
+      int32_t slot = part_erase(m, t, keys[r], key_hash(keys[r]));
+      if (slot < 0) continue;
+      release_slot(m, slot);
+      out_slots[erased++] = slot;
+      if (t->thinned) continue;
+      t->thinned = true;
+      if (thinned == m->thin_cap) {
+        m->thin_cap = m->thin_cap ? m->thin_cap * 2 : 64;
+        m->thin_list =
+            (int32_t*)realloc(m->thin_list, sizeof(int32_t) * m->thin_cap);
+      }
+      m->thin_list[thinned++] = ti;
+    }
+    for (int64_t i = 0; i < thinned; i++) {
+      NsTable* t = &m->tabs[m->thin_list[i]];
+      table_close_gaps(m, t);
+      if (t->n == 0) table_close(m, m->thin_list[i]);
+    }
+    if (thinned) m->thinned++;
+    return erased;
+  }
+  uint64_t mask = (uint64_t)m->bucket_count - 1;
   for (int64_t base = 0; base < n; base += CHUNK) {
     int64_t end = base + CHUNK < n ? base + CHUNK : n;
     // chunked prefetch (same discipline as the probe paths): session
@@ -557,43 +1049,62 @@ int64_t sm_erase(void* h, int64_t n, const int64_t* keys, const int64_t* nss,
         __builtin_prefetch(&m->slot_ns[b], 0, 1);
       }
     }
-  for (int64_t r = base; r < end; r++) {
-    int64_t k = keys[r], ns = nss[r];
-    uint64_t i = hashes[r - base] & mask;
-    for (;;) {
-      int32_t b = m->buckets[i];
-      if (b == -1) break;  // not present
-      if (m->slot_key[b] == k && m->slot_ns[b] == ns) {
-        m->slot_used[b] = 0;
-        m->free_stack[m->free_top++] = b;
-        m->used--;
-        out_slots[erased++] = b;
-        // backward-shift: compact the probe chain following i
-        uint64_t hole = i;
-        uint64_t j = (i + 1) & mask;
-        while (m->buckets[j] != -1) {
-          int32_t c = m->buckets[j];
-          uint64_t home =
-              mix_hash((uint64_t)m->slot_key[c], (uint64_t)m->slot_ns[c]) &
-              mask;
-          // move c into the hole if its home position does not lie
-          // (cyclically) strictly after the hole
-          uint64_t dist_home = (j - home) & mask;
-          uint64_t dist_hole = (j - hole) & mask;
-          if (dist_home >= dist_hole) {
-            m->buckets[hole] = c;
-            hole = j;
-          }
-          j = (j + 1) & mask;
-        }
-        m->buckets[hole] = -1;
-        break;
-      }
-      i = (i + 1) & mask;
+    for (int64_t r = base; r < end; r++) {
+      int32_t slot = flat_erase(m, keys[r], nss[r], hashes[r - base]);
+      if (slot < 0) continue;
+      release_slot(m, slot);
+      out_slots[erased++] = slot;
     }
   }
-  }
   return erased;
+}
+
+// ---- a namespace as a whole (partitioned; a flat map holds no table and
+// answers 0 to each)
+
+// The retire: every pair of the namespaces given leaves with its table.
+// Their slots are written to out_slots (namespace by namespace as given,
+// insertion order within one) and released; each table goes to the pool.
+// No hash, no gather, no shift per pair. Returns the slots written.
+int64_t sm_drop_namespaces(void* h, int64_t n, const int64_t* nss,
+                           int32_t* out_slots) {
+  SlotMap* m = (SlotMap*)h;
+  int64_t total = 0;
+  for (int64_t i = 0; i < n; i++) {
+    int32_t ti = dir_find(m, nss[i]);
+    if (ti < 0) continue;
+    const NsTable* t = &m->tabs[ti];
+    memcpy(out_slots + total, t->mem.slots, sizeof(int32_t) * t->n);
+    for (int64_t j = 0; j < t->n; j++) release_slot(m, t->mem.slots[j]);
+    total += t->n;
+    table_close(m, ti);
+  }
+  return total;
+}
+
+int64_t sm_namespace_count(void* h) { return ((SlotMap*)h)->tabs_live; }
+
+// The live namespaces, in the order their tables were opened.
+void sm_namespaces(void* h, int64_t* out) {
+  SlotMap* m = (SlotMap*)h;
+  int64_t k = 0;
+  for (int64_t i = 0; i < m->tabs_top; i++)
+    if (m->tabs[i].n >= 0) out[k++] = i;
+  std::sort(out, out + k, [m](int64_t a, int64_t b) {
+    return m->tabs[a].gen < m->tabs[b].gen;
+  });
+  for (int64_t i = 0; i < k; i++) out[i] = m->tabs[out[i]].ns;
+}
+
+// A namespace's slots in insertion order: the count, and the first ``cap``
+// of them copied to out.
+int64_t sm_namespace_slots(void* h, int64_t ns, int32_t* out, int64_t cap) {
+  SlotMap* m = (SlotMap*)h;
+  int32_t ti = dir_find(m, ns);
+  if (ti < 0) return 0;
+  const NsTable* t = &m->tabs[ti];
+  memcpy(out, t->mem.slots, sizeof(int32_t) * (t->n < cap ? t->n : cap));
+  return t->n;
 }
 
 // Fused pane-table ingest, pass A — ONE sweep over the micro-batch doing
@@ -624,20 +1135,33 @@ int32_t sm_pane_ingest(void* h, int64_t n, const int64_t* keys,
   memset(se_idx, 0xff, sizeof(int32_t) * nb);
   int64_t k_count = 0;
   int64_t max_col = 0;
+  int32_t rc = 0;
+  // partitioned: the one table of namespace 0 (no other is opened here,
+  // so the pointer holds)
+  NsTable* t = nullptr;
+  if (m->partitioned && n) {
+    int32_t ti = dir_find(m, 0);
+    if (ti < 0) ti = table_open(m, 0);
+    t = &m->tabs[ti];
+  }
   constexpr int64_t CHUNK = 256;
   uint64_t hashes[CHUNK];
-  for (int64_t base = 0; base < n; base += CHUNK) {
+  for (int64_t base = 0; base < n && rc == 0; base += CHUNK) {
     int64_t end = base + CHUNK < n ? base + CHUNK : n;
     uint64_t pmask = (uint64_t)m->bucket_count - 1;
     for (int64_t r = base; r < end; r++) {
-      uint64_t hh = mix_hash((uint64_t)keys[r], 0);
+      uint64_t hh = key_hash(keys[r]);
       hashes[r - base] = hh;
-      __builtin_prefetch(&m->buckets[hh & pmask], 0, 1);
+      if (t)
+        part_prefetch(t, hh);
+      else
+        __builtin_prefetch(&m->buckets[hh & pmask], 0, 1);
     }
-    for (int64_t r = base; r < end; r++) {
-      int32_t b = m->buckets[hashes[r - base] & pmask];
-      if (b >= 0) __builtin_prefetch(&m->slot_key[b], 0, 1);
-    }
+    if (!t)
+      for (int64_t r = base; r < end; r++) {
+        int32_t b = m->buckets[hashes[r - base] & pmask];
+        if (b >= 0) __builtin_prefetch(&m->slot_key[b], 0, 1);
+      }
     for (int64_t r = base; r < end; r++) {
       // slice end (floor-mod)
       int64_t x = ts[r] - offset;
@@ -648,9 +1172,8 @@ int32_t sm_pane_ingest(void* h, int64_t n, const int64_t* keys,
       for (;;) {
         if (se_idx[sb] < 0) {
           if (k_count >= maxu) {
-            free(se_key);
-            free(se_idx);
-            return -2;
+            rc = -2;
+            break;
           }
           se_key[sb] = se;
           se_idx[sb] = (int32_t)k_count;
@@ -660,15 +1183,18 @@ int32_t sm_pane_ingest(void* h, int64_t n, const int64_t* keys,
         if (se_key[sb] == se) break;
         sb = (sb + 1) & (nb - 1);
       }
+      if (rc) break;
       out_sinv[r] = se_idx[sb];
       // key -> column (lookup-or-insert, ns = 0)
       bool is_new;
-      int32_t col = probe_or_insert(m, keys[r], 0, hashes[r - base], &grows,
-                                    &is_new);
+      int32_t col =
+          t ? part_probe_or_insert(m, t, keys[r], hashes[r - base], &grows,
+                                   &is_new)
+            : flat_probe_or_insert(m, keys[r], 0, hashes[r - base], &grows,
+                                   &is_new);
       if (col < 0) {
-        free(se_key);
-        free(se_idx);
-        return -1;
+        rc = -1;
+        break;
       }
       out_cols[r] = col;
       out_is_new[r] = is_new;
@@ -677,6 +1203,8 @@ int32_t sm_pane_ingest(void* h, int64_t n, const int64_t* keys,
   }
   free(se_key);
   free(se_idx);
+  if (t && t->n == 0) table_close(m, (int32_t)(t - m->tabs));
+  if (rc) return rc;
   *out_k = k_count;
   *out_max_col = max_col;
   return grows;
@@ -696,14 +1224,17 @@ void sm_flat_fuse(int64_t n, const int32_t* cols, const int32_t* sinv,
 // ---- the fire path's carried slot matrix -------------------------------
 //
 // A window's (keys, [rows, k] slot matrix) kept from one fire to the next
-// (flink_tpu/state/slot_table.py, _NamespaceRegistry.slice_matrix). A
-// sliding window shares k-1 of its k slices with the window before it, so
-// an advance drops the columns that left, probes only the cells that
-// entered into a key -> row table that persists between calls, and sweeps
-// out the rows whose last cell left. The same call from an empty carry is
-// the from-nothing rebuild. Rows stay dense (an emptied row is overwritten
-// by the last one), so row order is arbitrary; columns are the caller's
-// slice order.
+// for a partitioned map. A sliding window shares k-1 of its k slices with
+// the window before it, so an advance drops the columns that left, probes
+// only the cells that entered into a key -> row table that persists
+// between calls, and sweeps out the rows whose last cell left. The cells
+// that entered are read where they lie: a namespace's table holds its
+// (keys[], slots[]) in insertion order and only ever appends until it is
+// dropped, so the pairs past what the matrix consumed of a kept column,
+// and all of a new column's, are the cells — no gather through slot_key.
+// The same call from an empty carry is the from-nothing rebuild. Rows stay
+// dense (an emptied row is overwritten by the last one), so row order is
+// arbitrary; columns are the caller's slice order.
 
 void* sm_carry_create() {
   SliceCarry* c = (SliceCarry*)calloc(1, sizeof(SliceCarry));
@@ -719,27 +1250,55 @@ void sm_carry_destroy(void* h) {
   free(c->mat);
   free(c->buckets);
   free(c->emptied);
+  free(c->ends);
+  free(c->gens);
+  free(c->consumed);
   free(c);
 }
 
-// One fire's advance. ``shift`` columns leave on the left (shift >= k, or
-// another k than the carry holds: everything leaves — a rebuild). The
-// cells that entered come as ``n_seg`` runs of ``slots``: run s holds
-// seg_len[s] slots of column seg_col[s]; a cell's key is
-// slot_key[slot]. Writes the advanced (keys, matrix) to out_keys /
-// out_mat, which the caller sized for the carry's rows + the cells
-// given, and returns the rows written. The out arrays are the caller's:
-// the carry never touches them again.
-int64_t sm_carry_advance(void* h, int64_t k, int64_t shift, int64_t n_seg,
-                         const int32_t* seg_col, const int64_t* seg_len,
-                         const int32_t* slots, const int64_t* slot_key,
-                         int64_t* out_keys, int32_t* out_mat) {
+// One fire's advance to the window over the k namespaces ``ends``. Where
+// they are the last call's moved on by ``shift`` < k, and every kept
+// column the matrix holds cells of is still the same table (its gen: a
+// namespace dropped and made again is another) with nothing erased from
+// any table in between, the shift leftmost columns leave and only the
+// cells that entered are probed; anything else starts from nothing.
+// Writes the advanced (keys, matrix) to out_keys / out_mat and returns the
+// rows written, *out_cells = cells entered. The out arrays hold out_rows
+// rows; where that is fewer than the carry's rows + the cells entering,
+// nothing is changed and -(rows needed) is returned. The out arrays are
+// the caller's: the carry never touches them again.
+int64_t sm_carry_advance(void* h, void* map, int64_t k, const int64_t* ends,
+                         int64_t out_rows, int64_t* out_keys,
+                         int32_t* out_mat, int64_t* out_cells) {
   SliceCarry* c = (SliceCarry*)h;
+  const SlotMap* m = (const SlotMap*)map;
+  int64_t shift = k;
+  if (k == c->k && c->thinned == m->thinned)
+    for (int64_t s = 0; s < k && shift == k; s++)
+      if (!memcmp(c->ends + s, ends, sizeof(int64_t) * (k - s))) shift = s;
+  for (int64_t j = 0; j + shift < k; j++) {
+    if (!c->consumed[j + shift]) continue;
+    int32_t ti = dir_find(m, ends[j]);
+    if (ti < 0 || m->tabs[ti].gen != c->gens[j + shift]) shift = k;
+  }
+  int64_t cells = 0;
+  for (int64_t j = 0; j < k; j++) {
+    int32_t ti = dir_find(m, ends[j]);
+    if (ti >= 0)
+      cells += m->tabs[ti].n - (j + shift < k ? c->consumed[j + shift] : 0);
+  }
+  int64_t bound = (shift < k ? c->rows : 0) + cells;
+  if (bound > out_rows) return -bound;
+  *out_cells = cells;
   int64_t n_emptied = 0;
-  if (k != c->k || shift >= k) {
+  if (shift >= k) {
     if (k != c->k) {
       if (c->row_cap && k)
         c->mat = (int32_t*)realloc(c->mat, sizeof(int32_t) * c->row_cap * k);
+      c->ends = (int64_t*)realloc(c->ends, sizeof(int64_t) * (k ? k : 1));
+      c->gens = (uint64_t*)realloc(c->gens, sizeof(uint64_t) * (k ? k : 1));
+      c->consumed =
+          (int64_t*)realloc(c->consumed, sizeof(int64_t) * (k ? k : 1));
       c->k = k;
     }
     c->rows = 0;
@@ -762,20 +1321,23 @@ int64_t sm_carry_advance(void* h, int64_t k, int64_t shift, int64_t n_seg,
     }
   }
   constexpr int64_t CHUNK = 256;
-  int64_t ck[CHUNK];
   uint64_t hashes[CHUNK];
-  const int32_t* seg_slots = slots;
-  for (int64_t s = 0; s < n_seg; seg_slots += seg_len[s], s++) {
-    int64_t col = seg_col[s], n = seg_len[s];
+  for (int64_t col = 0; col < k; col++) {
+    int64_t seen = col + shift < k ? c->consumed[col + shift] : 0;
+    int32_t ti = dir_find(m, ends[col]);
+    c->ends[col] = ends[col];
+    c->gens[col] = ti >= 0 ? m->tabs[ti].gen : 0;
+    c->consumed[col] = ti >= 0 ? m->tabs[ti].n : 0;
+    if (ti < 0) continue;
+    const int64_t* seg_keys = m->tabs[ti].mem.keys + seen;
+    const int32_t* seg_slots = m->tabs[ti].mem.slots + seen;
+    int64_t n = m->tabs[ti].n - seen;
     for (int64_t base = 0; base < n; base += CHUNK) {
       int64_t end = base + CHUNK < n ? base + CHUNK : n;
-      // same prefetch discipline as the probe paths: slot_key is far
-      // larger than the caches, and every gather a likely miss
-      for (int64_t i = base; i < end; i++)
-        __builtin_prefetch(&slot_key[seg_slots[i]], 0, 1);
+      // same prefetch discipline as the probe paths: the key -> row
+      // table and the rows it names are each a likely miss
       for (int64_t i = base; i < end; i++) {
-        ck[i - base] = slot_key[seg_slots[i]];
-        hashes[i - base] = mix_hash((uint64_t)ck[i - base], 0);
+        hashes[i - base] = key_hash(seg_keys[i]);
         __builtin_prefetch(
             &c->buckets[hashes[i - base] & ((uint64_t)c->bucket_count - 1)],
             0, 1);
@@ -789,7 +1351,7 @@ int64_t sm_carry_advance(void* h, int64_t k, int64_t shift, int64_t n_seg,
         }
       }
       for (int64_t i = base; i < end; i++) {
-        int64_t key = ck[i - base];
+        int64_t key = seg_keys[i];
         uint64_t mask = (uint64_t)c->bucket_count - 1;
         uint64_t b = hashes[i - base] & mask;
         int32_t r;
@@ -815,6 +1377,7 @@ int64_t sm_carry_advance(void* h, int64_t k, int64_t shift, int64_t n_seg,
       }
     }
   }
+  c->thinned = m->thinned;
   // rows still empty go, highest first: whatever lies above the one
   // being judged is live, so the last row may fill its place
   for (int64_t i = 0; i < n_emptied; i++) {

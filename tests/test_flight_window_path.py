@@ -157,3 +157,66 @@ def test_the_span_budget_per_batch_and_per_fired_window(q5):
     spans = sum(1 for r in records
                 if not r.instant and r.kind != "xla.compile")
     assert spans <= 12 * BATCHES + 10 * windows
+
+
+# ------------------------------------------------- the retire's drop, stated
+
+
+def test_the_retire_says_which_pairs_left_with_their_whole_table(q5):
+    """On the native index a slice's pairs live in one table and leave
+    with it: every ``slice.retire`` of the slots layout holds one
+    ``retire.drop`` instant, and their work is the retire's — all of it,
+    no pair was erased one by one. The panes layout frees ring rows, not
+    namespaces, and says nothing."""
+    layout, kt, records, _, _ = q5
+    drops = [r for r in records if r.kind == "retire.drop"]
+    if layout != "slots" or not slotmap_available():
+        assert not drops and "retire.drop" not in kt
+        return
+    assert all(r.instant and r.parent == "slice.retire" for r in drops)
+    assert kt["retire.drop"]["count"] == kt["slice.retire"]["count"] \
+        == len(drops)
+    assert kt["retire.drop"]["work"] == kt["slice.retire"]["work"] \
+        == kt["prep.resolve"]["work"] > 0
+    # instant by instant inside its retire, with the retire's own count
+    retires = sorted((r for r in records if r.kind == "slice.retire"),
+                     key=lambda r: r.t0)
+    drops.sort(key=lambda r: r.t0)
+    for retire, drop in zip(retires, drops):
+        assert retire.t0 <= drop.t0 <= retire.t0 + retire.duration_s
+        assert drop.work == retire.work
+
+
+def test_no_drop_is_stated_where_pairs_are_erased_one_by_one(monkeypatch):
+    """The Python index erases pair by pair, and a session job frees by
+    slot from the flat table: neither records the kind."""
+    import flink_tpu.state.slot_table as slot_table_mod
+    from flink_tpu.state.slot_table import HostSlotIndex
+    from flink_tpu.windowing.assigners import EventTimeSessionWindows
+
+    with monkeypatch.context() as m:
+        m.setattr(slot_table_mod, "make_slot_index",
+                  lambda capacity, **kw: HostSlotIndex(capacity, **kw))
+        kt, _, rows, windows = run_q5("slots")
+    assert windows > 10 and len(rows) == windows * TOP_K
+    assert kt["slice.retire"]["work"] == kt["prep.resolve"]["work"] > 0
+    assert "retire.drop" not in kt and "resolve.sweep" not in kt
+
+    env = StreamExecutionEnvironment(Configuration({
+        "execution.micro-batch.size": BATCH}))
+    sink = CollectSink()
+    (env.add_source(
+        DataGenSource(total_records=BATCH * 4, num_keys=KEYS,
+                      events_per_second_of_eventtime=1_000),
+        WatermarkStrategy.for_bounded_out_of_orderness(0))
+        .key_by("key")
+        .window(EventTimeSessionWindows.with_gap(300))
+        .aggregate(CountAggregate())
+        .sink_to(sink))
+    rec = flight.recorder()
+    rec.clear()
+    env.execute("sessions")
+    kt = rec.kind_totals()
+    assert len(sink.rows()) > 100
+    assert kt["slice.retire"]["work"] > 0       # sessions fired and freed
+    assert "retire.drop" not in kt

@@ -69,7 +69,7 @@ class TestMeshPagedSpill:
         assert eng._paged
         for idx in eng.indexes:
             assert idx._track_ns is False
-            assert idx._ns_slots == {}
+            assert idx.namespaces == []
 
     def test_forced_eviction_matches_single_device_oracle(
             self, eight_device_mesh):
@@ -257,7 +257,7 @@ class TestMeshPagedSpill:
         for k in d_ref:
             assert d_got[k] == pytest.approx(d_ref[k], rel=1e-4), k
         for idx in eng.indexes:
-            assert idx._ns_slots == {}
+            assert idx.namespaces == []
         assert eng.spill_counters() == {
             "pages_evicted": 0, "pages_reloaded": 0, "rows_evicted": 0,
             "rows_reloaded": 0, "rows_split_on_reload": 0,

@@ -98,11 +98,13 @@ class TestNativeSlotIndex:
 
 # ------------------------------------------------- the one-sweep resolve
 #
-# ``sm_resolve_grouped`` against the path it replaced, kept here as the
-# oracle: slice ends by NumPy, ``sm_lookup_or_insert`` with its is_new
-# mask, and the new slots regrouped by a stable argsort of their
-# namespaces. Two native indexes fed the same pairs in the same order
-# hand out the same slot numbers, so everything compares exactly.
+# ``sm_resolve_grouped`` over the per-namespace tables against the path
+# it replaced, kept here as the oracle: slice ends by NumPy,
+# ``sm_lookup_or_insert`` into the flat table with its is_new mask, and the
+# new slots regrouped by a stable argsort of their namespaces into a
+# registry of chunk lists. The two forms share the free stack, so fed the
+# same pairs in the same order they hand out the same slot numbers, and
+# everything compares exactly.
 
 W, OFFSET = 100, 0
 
@@ -110,6 +112,15 @@ W, OFFSET = 100, 0
 def slice_ends(ts, width=W, offset=OFFSET):
     ts = np.asarray(ts, dtype=np.int64)
     return ts - np.remainder(ts - offset, width) + width
+
+
+def oracle_index(capacity):
+    """The parent's native index with tracking on: the flat (key,
+    namespace) table, and the namespace -> chunks registry it kept in
+    Python (here ``registry``, kept by the oracle's two functions)."""
+    idx = NativeSlotIndex(capacity, track_namespaces=False)
+    idx.registry = {}
+    return idx
 
 
 def oracle_lookup_or_insert(idx, keys, nss):
@@ -132,16 +143,30 @@ def oracle_lookup_or_insert(idx, keys, nss):
     idx.pairs_inserted += len(new_slots)
     order = np.argsort(new_ns, kind="stable")
     for ns in np.unique(new_ns).tolist():
-        idx._ns_slots.setdefault(ns, []).append(
+        idx.registry.setdefault(ns, []).append(
             new_slots[order][new_ns[order] == ns])
     return rc, out
 
 
+def oracle_free_namespaces(idx, namespaces):
+    """The parent's ``free_namespaces``: the registry drained, the pairs
+    erased one by one in the drained order."""
+    chunks = [c for ns in namespaces for c in idx.registry.pop(ns, [])]
+    if not chunks:
+        return None
+    slots = np.concatenate(chunks)
+    idx.free_slots(slots)
+    return slots
+
+
 def registry(idx):
-    """Namespaces in the registry's own order, each with its slots in
-    the order they were appended."""
-    return [(ns, np.concatenate(chunks).tolist())
-            for ns, chunks in idx._ns_slots.items()]
+    """Namespaces in the order they were first given a slot, each with
+    its slots in the order they were given out."""
+    if hasattr(idx, "registry"):
+        return [(ns, np.concatenate(chunks).tolist())
+                for ns, chunks in idx.registry.items()]
+    return [(ns, idx.slots_for_namespace(ns).tolist())
+            for ns in idx.namespaces]
 
 
 def _in_order(rng, n):
@@ -177,7 +202,7 @@ def test_sweep_equals_the_path_it_replaced(case):
     rng = np.random.default_rng(sorted(SWEEP_CASES).index(case))
     grows = []
     swept = NativeSlotIndex(1024, on_grow=lambda o, n: grows.append((o, n)))
-    plain = NativeSlotIndex(1024)
+    plain = oracle_index(1024)
     for step in range(12):
         n = int(rng.integers(1, 1500))
         ts = make_ts(rng, n).astype(np.int64)
@@ -220,7 +245,7 @@ def test_namespaces_entry_groups_natively_as_the_sort_did(case):
     any number of distinct namespaces, with frees in between."""
     make_ts, make_keys, width, offset = SWEEP_CASES[case]
     rng = np.random.default_rng(100 + sorted(SWEEP_CASES).index(case))
-    swept, plain = NativeSlotIndex(1024), NativeSlotIndex(1024)
+    swept, plain = NativeSlotIndex(1024), oracle_index(1024)
     for step in range(12):
         n = int(rng.integers(1, 1500))
         # slice ends, or (every third step) a namespace of its own for
@@ -235,8 +260,8 @@ def test_namespaces_entry_groups_natively_as_the_sort_did(case):
         assert swept.pairs_inserted == plain.pairs_inserted
         if step % 4 == 3:
             dead = [ns for ns, _ in registry(swept)[::2]]
-            freed, freed_plain = (i.free_namespaces(dead)
-                                  for i in (swept, plain))
+            freed = swept.free_namespaces(dead)
+            freed_plain = oracle_free_namespaces(plain, dead)
             np.testing.assert_array_equal(freed, freed_plain)
             assert registry(swept) == registry(plain)
     assert swept.num_used == plain.num_used
@@ -252,7 +277,7 @@ class TestSweepLeavesABatchAlone:
         ts = np.arange(n, dtype=np.int64) * W
         keys = np.arange(n, dtype=np.int64)
         assert idx.resolve_slices(keys, ts, 0, W, -(1 << 62)) is None
-        assert idx.num_used == 0 and not idx._ns_slots
+        assert idx.num_used == 0 and not idx.namespaces
         assert idx.pairs_inserted == 0
         # one fewer is taken
         got = idx.resolve_slices(keys[1:], ts[1:], 0, W, -(1 << 62))
@@ -266,7 +291,7 @@ class TestSweepLeavesABatchAlone:
         ts[late_at] = 4 * W + 99          # slice end 5 * W: late
         keys = np.arange(1000, dtype=np.int64)
         assert idx.resolve_slices(keys, ts, 0, W, 6 * W) is None
-        assert idx.num_used == 0 and not idx._ns_slots
+        assert idx.num_used == 0 and not idx.namespaces
         # at the threshold itself the slice is live
         assert idx.resolve_slices(keys, ts, 0, W, 5 * W) is not None
         assert idx.num_used == 1000
@@ -308,3 +333,344 @@ def test_table_full_leaves_index_and_registry_level(entry):
     idx.lookup_or_insert(np.arange(room, dtype=np.int64) + 10 ** 6,
                          np.full(room, 7 * W, dtype=np.int64))
     assert idx.num_used == 4095
+
+
+# ------------------------------------- the two native forms and the Python
+# index side by side
+#
+# One random walk drives the partitioned native index (a table per
+# namespace: what a window job's state sits on), the flat native index
+# (the session tables') and ``HostSlotIndex`` through everything an owner
+# does, and compares them after every step. Slot numbers are each index's
+# own business (the Python index allocates in sorted-pair order), so the
+# comparison is by (key, namespace): which pairs are live, which slot each
+# record was given, which namespaces exist and which keys each holds.
+
+
+def live_pairs(idx):
+    used = np.nonzero(idx.slot_used)[0]
+    pairs = set(zip(idx.slot_key[used].tolist(), idx.slot_ns[used].tolist()))
+    assert len(pairs) == len(used) == idx.num_used     # no pair twice
+    return pairs
+
+
+def check_level(part, flat, host):
+    """The three indexes hold the same pairs, and the two that track
+    namespaces the same namespaces with the same keys under each."""
+    want = live_pairs(host)
+    assert live_pairs(part) == want and live_pairs(flat) == want
+    by_ns = {}
+    for key, ns in want:
+        by_ns.setdefault(ns, []).append(key)
+    assert sorted(part.namespaces) == sorted(host.namespaces) \
+        == sorted(by_ns)
+    assert flat.namespaces == []
+    for ns, keys in by_ns.items():
+        for idx in (part, host):
+            slots = idx.slots_for_namespace(ns)
+            assert idx.slot_used[slots].all()
+            assert (idx.slot_ns[slots] == ns).all()
+            assert sorted(idx.slot_key[slots].tolist()) == sorted(keys)
+        assert len(flat.slots_for_namespace(ns)) == 0
+
+
+class ThreeIndexes:
+    def __init__(self, capacity=1024, max_capacity=0):
+        self.kw = dict(max_capacity=max_capacity)
+        self.grown = [0, 0, 0]
+        self.make(capacity)
+
+    def make(self, capacity):
+        def counting(i):
+            return lambda old, new: self.grown.__setitem__(i, new)
+
+        self.part = NativeSlotIndex(capacity, on_grow=counting(0), **self.kw)
+        self.flat = NativeSlotIndex(capacity, on_grow=counting(1),
+                                    track_namespaces=False, **self.kw)
+        self.host = HostSlotIndex(capacity, on_grow=counting(2), **self.kw)
+        self.all = (self.part, self.flat, self.host)
+
+    def insert(self, keys, nss):
+        keys = np.asarray(keys, dtype=np.int64)
+        nss = np.asarray(nss, dtype=np.int64)
+        for idx in self.all:
+            slots = idx.lookup_or_insert(keys, nss)
+            np.testing.assert_array_equal(idx.slot_key[slots], keys)
+            np.testing.assert_array_equal(idx.slot_ns[slots], nss)
+            assert idx.slot_used[slots].all()
+
+    def sweep(self, keys, ts, width, offset):
+        keys = np.asarray(keys, dtype=np.int64)
+        ends = slice_ends(ts, width, offset)
+        for idx in (self.part, self.flat):      # pass B of either form
+            slots, uniq, records = idx.resolve_slices(
+                keys, np.asarray(ts, dtype=np.int64), offset, width,
+                -(1 << 62))
+            np.testing.assert_array_equal(idx.slot_key[slots], keys)
+            np.testing.assert_array_equal(idx.slot_ns[slots], ends)
+            want_uniq, want_records = np.unique(ends, return_counts=True)
+            np.testing.assert_array_equal(uniq, want_uniq)
+            np.testing.assert_array_equal(records, want_records)
+        self.host.lookup_or_insert(keys, ends)
+
+    def lookup(self, keys, nss):
+        keys = np.asarray(keys, dtype=np.int64)
+        nss = np.asarray(nss, dtype=np.int64)
+        want = live_pairs(self.host)
+        present = np.array([(k, n) in want
+                            for k, n in zip(keys.tolist(), nss.tolist())],
+                           dtype=bool)
+        for idx in self.all:
+            slots = idx.lookup(keys, nss)
+            np.testing.assert_array_equal(slots >= 0, present)
+            hit = slots[present]
+            np.testing.assert_array_equal(idx.slot_key[hit], keys[present])
+            np.testing.assert_array_equal(idx.slot_ns[hit], nss[present])
+            # a hint is taken where it names the pair's slot, only there
+            hints = np.where(np.arange(len(keys)) % 2 == 0, slots,
+                             np.int32(1))
+            from flink_tpu.state.slot_table import verify_slot_hints
+            got = verify_slot_hints(idx, keys, nss, hints)
+            np.testing.assert_array_equal(
+                got, np.where(hints == slots, slots, -1))
+
+    def free_namespaces(self, namespaces):
+        for idx in (self.part, self.host):
+            held = sum(len(idx.slots_for_namespace(ns))
+                       for ns in set(namespaces))
+            freed = idx.free_namespaces(list(namespaces))
+            assert (0 if freed is None else len(freed)) == held
+            if freed is not None:
+                assert not idx.slot_used[freed].any()
+                assert len(set(freed.tolist())) == len(freed)
+        flat = self.flat
+        assert flat.free_namespaces(list(namespaces)) is None
+        used = np.nonzero(flat.slot_used)[0].astype(np.int32)
+        flat.free_slots(used[np.isin(flat.slot_ns[used], namespaces)])
+
+    def free_pairs(self, keys, nss, give_columns):
+        """Per-slot frees of the pairs that are live, each once; with
+        the pairs' columns handed along or left to the index's gather."""
+        keys = np.asarray(keys, dtype=np.int64)
+        nss = np.asarray(nss, dtype=np.int64)
+        for idx in self.all:
+            slots = idx.lookup(keys, nss)
+            slots, first = np.unique(slots, return_index=True)
+            first = first[slots >= 0]
+            slots = slots[slots >= 0]
+            if give_columns:
+                idx.free_slots(slots, keys=keys[first], nss=nss[first])
+            else:
+                idx.free_slots(slots)
+
+    def restore(self):
+        """Snapshot -> restore as ``SlotTable.restore`` does it: the
+        live pairs' columns into a new index."""
+        used = self.host.used_slots()
+        keys, nss = self.host.slot_key[used], self.host.slot_ns[used]
+        self.make(1024)
+        if len(keys):
+            self.insert(keys, nss)
+
+
+WALKS = {
+    # name: (namespaces drawn from, keys drawn from, records per step)
+    "a_handful_of_big_slices": (12, 3000, 1500),
+    "hundreds_of_small_namespaces": (400, 40, 600),
+    "one_namespace": (1, 5000, 800),
+    "names_dropped_and_made_again": (5, 800, 900),
+}
+
+
+@needs_native
+@pytest.mark.parametrize("walk", sorted(WALKS))
+def test_partitioned_flat_and_python_index_agree_after_every_step(walk):
+    n_ns, n_keys, per_step = WALKS[walk]
+    rng = np.random.default_rng(sorted(WALKS).index(walk) + 40)
+    three = ThreeIndexes()
+    width = 100
+
+    def some_pairs(n):
+        """Half live pairs, half anything."""
+        live = sorted(live_pairs(three.host))
+        keys = rng.integers(0, n_keys, n)
+        nss = rng.integers(0, n_ns, n) * width
+        if live:
+            pick = rng.integers(0, len(live), n // 2)
+            keys[:n // 2] = [live[i][0] for i in pick]
+            nss[:n // 2] = [live[i][1] for i in pick]
+        return keys, nss
+
+    ops = ["sweep", "insert", "lookup", "free_ns", "free_slots", "restore"]
+    done = {op: 0 for op in ops}
+    for step in range(60):
+        op = ops[step] if step < len(ops) else rng.choice(
+            ops, p=[0.3, 0.25, 0.1, 0.2, 0.1, 0.05])
+        done[op] += 1
+        n = int(rng.integers(1, per_step))
+        if op == "sweep":
+            lo = int(rng.integers(0, n_ns))
+            ts = rng.integers(lo * width - 37, (lo + 3) * width - 37, n)
+            three.sweep(rng.integers(0, n_keys, n), ts, width, -37)
+        elif op == "insert":
+            three.insert(rng.integers(0, n_keys, n),
+                         rng.integers(0, n_ns, n) * width)
+        elif op == "lookup":
+            three.lookup(*some_pairs(n))
+        elif op == "free_ns":
+            names = three.host.namespaces
+            dead = (rng.choice(names, size=max(1, len(names) // 3),
+                               replace=False).tolist() if names else [])
+            three.free_namespaces(dead + [10 ** 9])     # and an absent one
+        elif op == "free_slots":
+            keys, nss = some_pairs(max(2, n // 10))
+            three.free_pairs(keys, nss, give_columns=bool(step % 2))
+        else:
+            three.restore()
+        check_level(*three.all)
+    assert min(done.values()) > 0
+    if walk != "hundreds_of_small_namespaces":
+        assert three.part.capacity > 1024               # growth happened
+    assert three.part.capacity == three.flat.capacity \
+        == three.host.capacity == three.grown[0] == three.grown[1] \
+        == three.grown[2]
+
+
+@needs_native
+def test_table_full_then_on_in_all_three():
+    """Full at max_capacity in mid-batch: each index raises with every
+    slot taken. The two native forms took the same pairs (record order)
+    and the partitioned one's namespaces are level with its slots; the
+    Python index allocates in sorted-pair order and registers a batch's
+    new slots after its last insert (its owner makes headroom first), so
+    it is made anew from the pairs the native ones hold. Emptied by
+    namespace, the three are level again and serve on."""
+    three = ThreeIndexes(1024, max_capacity=2048)
+    rng = np.random.default_rng(3)
+    three.insert(rng.integers(0, 300, 900), rng.integers(0, 4, 900) * 100)
+    check_level(*three.all)
+    keys = np.arange(5000, dtype=np.int64) + 1000
+    nss = rng.integers(2, 9, 5000) * 100
+    for idx in three.all:
+        with pytest.raises(RuntimeError, match="slot table full"):
+            idx.lookup_or_insert(keys, nss)
+        assert idx.num_used == 2047 == len(live_pairs(idx))
+        assert idx.capacity == 2048 == len(idx.slot_used)
+    part, flat = three.part, three.flat
+    assert live_pairs(part) == live_pairs(flat)
+    held = np.concatenate([part.slots_for_namespace(ns)
+                           for ns in part.namespaces])
+    assert sorted(held.tolist()) == np.nonzero(part.slot_used)[0].tolist()
+    assert all(len(part.slots_for_namespace(ns))
+               for ns in part.namespaces)               # none left empty
+    used = part.used_slots()
+    three.host = HostSlotIndex(2048, max_capacity=2048)
+    three.host.lookup_or_insert(part.slot_key[used], part.slot_ns[used])
+    three.all = (part, flat, three.host)
+    check_level(*three.all)
+    three.free_namespaces(list(range(0, 900, 100)))
+    check_level(*three.all)
+    assert part.num_used == 0
+    three.insert(rng.integers(0, 300, 900), rng.integers(0, 4, 900) * 100)
+    check_level(*three.all)
+
+
+@needs_native
+def test_a_namespace_dropped_and_opened_again_under_its_name():
+    idx = NativeSlotIndex(1024)
+    first = idx.lookup_or_insert(np.arange(50, dtype=np.int64),
+                                 np.full(50, 7, dtype=np.int64))
+    assert idx.namespaces == [7]
+    np.testing.assert_array_equal(idx.free_namespaces([7]), first)
+    assert idx.namespaces == [] and idx.pairs_dropped == 50
+    assert (idx.lookup(np.arange(50, dtype=np.int64),
+                       np.full(50, 7, dtype=np.int64)) == -1).all()
+    # again, other keys: nothing of the first table shows through
+    again = idx.lookup_or_insert(np.arange(40, 70, dtype=np.int64),
+                                 np.full(30, 7, dtype=np.int64))
+    assert idx.namespaces == [7] and idx.num_used == 30
+    np.testing.assert_array_equal(idx.slots_for_namespace(7), again)
+    found = idx.lookup(np.arange(80, dtype=np.int64),
+                       np.full(80, 7, dtype=np.int64))
+    np.testing.assert_array_equal(found >= 0,
+                                  (np.arange(80) >= 40) & (np.arange(80) < 70))
+    assert idx.free_namespaces([7, 7, 8]).tolist() == again.tolist()
+    assert idx.free_namespaces([7]) is None and idx.num_used == 0
+
+
+def _rss_mb():
+    with open("/proc/self/statm") as f:
+        return int(f.read().split()[1]) * 4096 / 1e6
+
+
+@needs_native
+def test_a_hundred_thousand_small_namespaces_cost_little():
+    """One to three keys under each of 100,000 namespaces (session ids on
+    the mesh, 1 s slices over a day): the smallest table is tens of bytes
+    and the directory a hash, so memory and time stay small — also right
+    after a big namespace was dropped, whose size only the next table
+    opens at."""
+    import time
+
+    idx = NativeSlotIndex(1 << 12)
+    idx.lookup_or_insert(np.arange(200_000, dtype=np.int64),
+                         np.full(200_000, -5, dtype=np.int64))
+    assert len(idx.free_namespaces([-5])) == 200_000
+    rng = np.random.default_rng(12)
+    n_ns = 100_000
+    nss = np.repeat(np.arange(n_ns, dtype=np.int64), 3)
+    keys = np.tile(np.arange(3, dtype=np.int64), n_ns)
+    keep = rng.random(len(nss)) < 0.7
+    keep[::3] = True                       # at least one key each
+    nss, keys = nss[keep], keys[keep]
+    order = rng.permutation(len(nss))
+    before, t0 = _rss_mb(), time.perf_counter()
+    for part in np.array_split(order, 20):
+        slots = idx.lookup_or_insert(keys[part], nss[part])
+        assert (idx.slot_ns[slots] == nss[part]).all()
+    assert idx.num_used == len(nss)
+    assert sorted(idx.namespaces) == list(range(n_ns))
+    assert (idx.lookup(keys, nss) >= 0).all()
+    grown_mb = _rss_mb() - before
+    # by slot (the session engines' free), then by namespace
+    half = idx.lookup(keys[::2], nss[::2])
+    idx.free_slots(half)
+    assert idx.num_used == len(nss) - len(half)
+    left = np.unique(nss[1::2])
+    assert sorted(idx.namespaces) == left.tolist()
+    assert len(idx.free_namespaces(left.tolist())) == len(nss) - len(half)
+    assert idx.num_used == 0 and idx.namespaces == []
+    seconds = time.perf_counter() - t0
+    # the slot arrays at 262,144 slots are 6 MB; a table per namespace
+    # opened at the dropped one's size would be 100,000 x 5 MB
+    assert grown_mb < 120, grown_mb
+    assert seconds < 30, seconds
+
+
+@needs_native
+@pytest.mark.parametrize("then", ["larger", "smaller", "tiny"])
+def test_a_pooled_table_is_reused_at_another_size(then):
+    """A dropped table's memory serves the next namespace whatever that
+    one grows to: more pairs than it held (grown in place of the pooled
+    block), fewer, or a handful."""
+    idx = NativeSlotIndex(1 << 12)
+    host = HostSlotIndex(1 << 12)
+    sizes = {"larger": 9000, "smaller": 700, "tiny": 3}
+    rng = np.random.default_rng(7)
+    for ns, n in enumerate([2000, sizes[then], 2000, sizes[then]]):
+        keys = rng.integers(0, 1 << 40, n)
+        for i in (idx, host):
+            slots = i.lookup_or_insert(keys, np.full(n, ns, dtype=np.int64))
+            assert (i.slot_key[slots] == keys).all()
+        # a kept neighbour: its pairs are untouched by the reuse
+        for i in (idx, host):
+            i.lookup_or_insert(keys[:50], np.full(len(keys[:50]), 100 + ns))
+        assert live_pairs(idx) == live_pairs(host)
+        np.testing.assert_array_equal(
+            idx.slot_key[idx.slots_for_namespace(ns)],
+            keys[np.sort(np.unique(keys, return_index=True)[1])])
+        for i in (idx, host):
+            assert len(i.free_namespaces([ns])) == len(np.unique(keys))
+        assert (idx.lookup(keys, np.full(n, ns, dtype=np.int64)) == -1).all()
+        assert live_pairs(idx) == live_pairs(host)
+    assert sorted(idx.namespaces) == [100, 101, 102, 103]

@@ -1,5 +1,5 @@
-"""The fire path's carried slot matrix (``_NamespaceRegistry.slice_matrix``,
-state/slot_table.py; ``sm_carry_advance``, native/slotmap.cpp).
+"""The fire path's carried slot matrix (``slice_matrix`` of both index
+classes, state/slot_table.py; ``sm_carry_advance``, native/slotmap.cpp).
 
 A window's (keys, slot matrix) is kept after the fire and the next
 window's is made from it: the column of the slice that left is dropped,
@@ -22,7 +22,6 @@ from flink_tpu.state.slot_table import (
     HostSlotIndex,
     NativeSlotIndex,
     SlotTable,
-    _NamespaceRegistry,
 )
 from flink_tpu.windowing.aggregates import SumAggregate
 from flink_tpu.windowing.assigners import SlidingEventTimeWindows
@@ -266,6 +265,83 @@ def test_only_what_entered_is_resolved(index_cls):
     assert as_rows(keys, matrix) == rebuilt(index, ends)
 
 
+def _put(index, ns, keys):
+    index.lookup_or_insert(np.asarray(list(keys), dtype=np.int64),
+                           np.full(len(keys), ns, dtype=np.int64))
+
+
+def _live_cells(index, ends):
+    return sum(len(index.slots_for_namespace(e)) for e in ends)
+
+
+# what happens between two fires -> (the next window's slices, whether
+# the matrix may be carried on: the cells it resolves are then the ones
+# that entered, else every live cell of the window)
+BETWEEN_FIRES = {
+    "a_slide": (
+        lambda ix: (ix.free_namespaces([0]), _put(ix, K, range(40, 90))),
+        list(range(1, K + 1)), 50),
+    "a_late_batch_into_a_kept_slice": (
+        lambda ix: _put(ix, 2, [5, 6, 7, 50, 51]),
+        list(range(K)), 3),                 # 5, 6, 7 are new there
+    "a_kept_slice_dropped_and_made_again": (
+        lambda ix: (ix.free_namespaces([3]), _put(ix, 3, range(30, 60))),
+        list(range(K)), None),
+    "the_same_with_a_slide": (
+        lambda ix: (ix.free_namespaces([0, 3]), _put(ix, 3, [1, 2]),
+                    _put(ix, K, range(9))),
+        list(range(1, K + 1)), None),
+    "a_kept_slice_dropped_for_good": (
+        lambda ix: ix.free_namespaces([2]),
+        list(range(K)), None),
+    "a_per_slot_free": (
+        lambda ix: ix.free_slots(ix.slots_for_namespace(1)[10:20]),
+        list(range(K)), None),
+    "a_per_slot_free_in_a_slice_the_window_lacks": (
+        lambda ix: ix.free_slots(ix.slots_for_namespace(77)[:2]),
+        list(range(K)), None),
+    "a_per_slot_free_that_empties_its_slice": (
+        lambda ix: ix.free_slots(ix.slots_for_namespace(78)),
+        list(range(K)), None),
+    "nothing": (lambda ix: None, list(range(K)), 0),
+}
+
+
+@pytest.mark.parametrize("between", sorted(BETWEEN_FIRES))
+def test_matrix_equals_a_rebuild_whatever_happened_since(index_cls,
+                                                         between):
+    """Fire, let one thing happen to the index, fire again: the second
+    matrix is a rebuild's, and it was carried on exactly where that is
+    safe — the native index tells a table from a later one of its name
+    by its generation, the Python one a list from a later list."""
+    happen, ends, entered = BETWEEN_FIRES[between]
+    index = index_cls(1 << 12)
+    for ns in range(K):
+        _put(index, ns, range(ns * 10, ns * 10 + 100))
+    _put(index, 77, range(500, 520))
+    _put(index, 78, range(3))
+    first = list(range(K))
+    keys, matrix, cells = index.slice_matrix(first)
+    assert cells == 100 * K
+    assert as_rows(keys, matrix) == rebuilt(index, first)
+    handed = (keys.copy(), matrix.copy())
+    happen(index)
+    got_keys, got_matrix, cells = index.slice_matrix(ends)
+    assert as_rows(got_keys, got_matrix) == rebuilt(index, ends)
+    assert cells == (_live_cells(index, ends) if entered is None
+                     else entered)
+    # the first fire's arrays are its caller's
+    np.testing.assert_array_equal(keys, handed[0])
+    np.testing.assert_array_equal(matrix, handed[1])
+    # and on from there: a slide after whatever happened
+    index.free_namespaces([ends[0]])
+    _put(index, ends[-1] + 1, range(250, 300))
+    ends = ends[1:] + [ends[-1] + 1]
+    keys, matrix, cells = index.slice_matrix(ends)
+    assert as_rows(keys, matrix) == rebuilt(index, ends)
+    assert cells == 50
+
+
 def test_carry_grows_with_the_rows(index_cls):
     """More rows than the carry's first allocation, then many of them
     leaving at once (the key -> row table's deletions and its growth)."""
@@ -310,14 +386,12 @@ def stream(seed, steps=14):
 def run_engine(make, always_rebuild, monkeypatch, async_ok):
     """Sink rows of one run, as sorted tuples with the float's bits."""
     with monkeypatch.context() as m:
-        if always_rebuild:
-            carried = _NamespaceRegistry.slice_matrix
-
-            def from_nothing(self, slice_ends):
+        for cls in (HostSlotIndex, NativeSlotIndex) * always_rebuild:
+            def from_nothing(self, slice_ends, carried=cls.slice_matrix):
                 self._slice_carry = None
                 return carried(self, slice_ends)
 
-            m.setattr(_NamespaceRegistry, "slice_matrix", from_nothing)
+            m.setattr(cls, "slice_matrix", from_nothing)
         engine = make()
         fired, pending = [], []
         for keys, vals, ts, wm in stream(11):
