@@ -49,6 +49,12 @@ KNOWN_SPAN_KINDS = (
                            # into the slot matrix: the slices that
                            # entered where the last window's matrix was
                            # carried on, every live cell where it was not)
+    "carry.rows",          # rows of the window's slot matrix a fire was
+                           # handed (instant inside fire.shard; work:
+                           # rows)
+    "carry.removed",       # rows the carried matrix swept out in that
+                           # advance: keys whose last cell left with its
+                           # slice (instant inside fire.shard; work: rows)
     "fire.harvest",        # D2H materialization of fire/query results
                            # (work: bytes fetched)
     "slice.retire",        # expired slices' pairs erased from the host

@@ -185,10 +185,14 @@ class _DictSliceCarry:
 
     def advance(self, k: int, shift: int,
                 parts: List[Tuple[int, np.ndarray]], slot_key: np.ndarray
-                ) -> Tuple[np.ndarray, np.ndarray]:
+                ) -> Tuple[np.ndarray, np.ndarray, int]:
         """Drop the ``shift`` leftmost columns (all of them: start from
         nothing), sweep out the rows left empty, then enter ``parts``:
-        (column, slots) runs whose keys are ``slot_key[slots]``."""
+        (column, slots) runs whose keys are ``slot_key[slots]``. Returns
+        the (keys, matrix) and the rows that left: swept out and not
+        brought back by what entered (as ``sm_carry_advance`` counts
+        them; a matrix started from nothing sweeps none)."""
+        left: set = set()
         if shift >= k or self._mat.shape[1] != k:
             keys = np.empty(0, dtype=np.int64)
             mat = np.zeros((0, k), dtype=np.int32)
@@ -201,6 +205,7 @@ class _DictSliceCarry:
                      np.zeros((len(mat), shift), dtype=np.int32)], axis=1)
                 live = mat.any(axis=1)
                 if not live.all():
+                    left = set(keys[~live].tolist())
                     keys, mat = keys[live], mat[live]
                     row_of = dict(zip(keys.tolist(), range(len(keys))))
         if parts:
@@ -221,8 +226,9 @@ class _DictSliceCarry:
                 mat = np.concatenate(
                     [mat, np.zeros((len(fresh), k), dtype=np.int32)])
             mat[rows, cols] = slots
+            left.difference_update(fresh)
         self._keys, self._mat, self._row_of = keys, mat, row_of
-        return keys.copy(), mat.copy()
+        return keys.copy(), mat.copy(), len(left)
 
 
 class HostSlotIndex:
@@ -265,6 +271,9 @@ class HostSlotIndex:
         #: pairs that left with their namespace's whole table: none here,
         #: this index erases pair by pair (see ``NativeSlotIndex``)
         self.pairs_dropped = 0
+        #: rows the carried fire matrix swept out so far: keys whose last
+        #: cell left with its slice (:meth:`slice_matrix`)
+        self.carry_rows_removed = 0
         #: the last fired window's slot matrix, carried to the next fire
         #: (:meth:`slice_matrix`); made on the first fire
         self._slice_carry: Optional[_DictSliceCarry] = None
@@ -307,7 +316,18 @@ class HostSlotIndex:
         other slices, a kept namespace drained since (a re-made one is
         another list object), a per-slot free — resolves every cell, from
         nothing. Either way the result is what a rebuild gives, and the
-        arrays handed out are the caller's: no later call writes them."""
+        arrays handed out are the caller's: no later call writes them.
+
+        "Rows left empty go" is the part whose cost depends on the
+        stream: a key that holds no slot in any kept slice gives up its
+        row, so the key -> row table is rebuilt over the rows that stay
+        (the native carry deletes each such key and moves the last row
+        into its place). Under keys that live for the whole run that is
+        a few rows per fire; under keys that live in one slice every row
+        enters with one fire and leaves ``k`` fires later. The rows a
+        call returned and the rows it swept out are stated by the
+        owner's ``carry.rows`` / ``carry.removed`` instants (the latter
+        from ``carry_rows_removed``)."""
         ends = [int(se) for se in slice_ends]
         k = len(ends)
         carry = self._slice_carry
@@ -334,7 +354,9 @@ class HostSlotIndex:
             lists.append(reg.get(se))
             consumed.append(len(slots))
         carry.ends, carry.lists, carry.consumed = ends, lists, consumed
-        keys, matrix = carry.advance(k, shift, parts, self.slot_key)
+        keys, matrix, removed = carry.advance(k, shift, parts,
+                                              self.slot_key)
+        self.carry_rows_removed += removed
         return keys, matrix, sum(len(slots) for _, slots in parts)
 
     def _registry_drain(self, namespaces: List[int]) -> Optional[np.ndarray]:
@@ -573,6 +595,9 @@ class NativeSlotIndex:
         #: pairs that left with their namespace's whole table
         #: (``free_namespaces``): no hash, gather or shift per pair
         self.pairs_dropped = 0
+        #: rows the carried fire matrix swept out so far (as
+        #: ``HostSlotIndex.carry_rows_removed``)
+        self.carry_rows_removed = 0
         self._slice_carry: Optional[_NativeSliceCarry] = None
         # _resolve_grouped's [3, max_uniq] namespaces / records / new
         # pairs of each, kept from batch to batch (a fresh buffer per
@@ -581,6 +606,7 @@ class NativeSlotIndex:
         self._sweep_groups = np.empty(0, dtype=np.int64)
         self._sweep_k = _ct.c_int64()
         self._carry_cells = _ct.c_int64()
+        self._carry_removed = _ct.c_int64()
 
     def _wrap_views(self) -> None:
         cap = int(self._lib.sm_capacity(self._h))
@@ -630,7 +656,16 @@ class NativeSlotIndex:
         (``sm_carry_advance``): the cells that entered are read from each
         slice's own table, from where the carried matrix stopped; a table's
         generation tells a namespace from a later one of the same name,
-        and any per-slot free starts the matrix from nothing."""
+        and any per-slot free starts the matrix from nothing.
+
+        "Rows left empty go" costs, per row, a backward-shift delete in
+        the carry's key -> row table and the move of the last row into
+        the hole (``carry_remove_row``), and its key's return later a
+        fresh-row insert: a few hundred per fire where keys live for the
+        whole run, every row of the slice that left where a key lives in
+        one slice. ``carry_rows_removed`` counts them; the owner states
+        each call's rows and removals as ``carry.rows`` /
+        ``carry.removed`` instants."""
         ends = np.ascontiguousarray(slice_ends, dtype=np.int64)
         k = len(ends)
         carry = self._slice_carry
@@ -645,11 +680,13 @@ class NativeSlotIndex:
             rows = self._lib.sm_carry_advance(
                 carry.h, self._h, k, ends.ctypes.data_as(_I64P), bound,
                 keys.ctypes.data_as(_I64P), matrix.ctypes.data_as(_I32P),
-                _ct.byref(self._carry_cells))
+                _ct.byref(self._carry_cells),
+                _ct.byref(self._carry_removed))
             if rows >= 0:
                 break
             bound = -rows
         carry.rows, carry.inserted = rows, self.pairs_inserted
+        self.carry_rows_removed += self._carry_removed.value
         return keys[:rows], matrix[:rows], self._carry_cells.value
 
     def free_namespaces(self, namespaces: List[int]) -> Optional[np.ndarray]:
@@ -1741,8 +1778,14 @@ class SlotTable:
         the identity slot 0; (None, None, cells) where no key holds a
         slot. Shared by the device fire path and the hybrid (spill) fire
         path; the index carries the matrix from one window to the next
-        (``slice_matrix``)."""
+        (``slice_matrix``), and what that cost in rows is stated as two
+        instants: ``carry.rows`` (rows of the matrix returned) and
+        ``carry.removed`` (rows the advance swept out)."""
+        removed = self.index.carry_rows_removed
         keys, matrix, cells = self.index.slice_matrix(slice_ends)
+        flight.instant("carry.rows", work=len(keys))
+        flight.instant("carry.removed",
+                       work=self.index.carry_rows_removed - removed)
         if len(keys) == 0:
             return None, None, cells
         return keys, matrix, cells

@@ -1263,13 +1263,16 @@ void sm_carry_destroy(void* h) {
 // any table in between, the shift leftmost columns leave and only the
 // cells that entered are probed; anything else starts from nothing.
 // Writes the advanced (keys, matrix) to out_keys / out_mat and returns the
-// rows written, *out_cells = cells entered. The out arrays hold out_rows
+// rows written, *out_cells = cells entered, *out_removed = rows swept out
+// (their last cell left and nothing that entered brought their key back;
+// a matrix started from nothing sweeps none). The out arrays hold out_rows
 // rows; where that is fewer than the carry's rows + the cells entering,
 // nothing is changed and -(rows needed) is returned. The out arrays are
 // the caller's: the carry never touches them again.
 int64_t sm_carry_advance(void* h, void* map, int64_t k, const int64_t* ends,
                          int64_t out_rows, int64_t* out_keys,
-                         int32_t* out_mat, int64_t* out_cells) {
+                         int32_t* out_mat, int64_t* out_cells,
+                         int64_t* out_removed) {
   SliceCarry* c = (SliceCarry*)h;
   const SlotMap* m = (const SlotMap*)map;
   int64_t shift = k;
@@ -1290,6 +1293,7 @@ int64_t sm_carry_advance(void* h, void* map, int64_t k, const int64_t* ends,
   int64_t bound = (shift < k ? c->rows : 0) + cells;
   if (bound > out_rows) return -bound;
   *out_cells = cells;
+  *out_removed = 0;
   int64_t n_emptied = 0;
   if (shift >= k) {
     if (k != c->k) {
@@ -1384,7 +1388,10 @@ int64_t sm_carry_advance(void* h, void* map, int64_t k, const int64_t* ends,
     const int32_t* row = c->mat + (int64_t)c->emptied[i] * k;
     int32_t live = 0;
     for (int64_t j = 0; j < k; j++) live |= row[j];
-    if (!live) carry_remove_row(c, c->emptied[i]);
+    if (!live) {
+      carry_remove_row(c, c->emptied[i]);
+      ++*out_removed;
+    }
   }
   memcpy(out_keys, c->keys, sizeof(int64_t) * c->rows);
   memcpy(out_mat, c->mat, sizeof(int32_t) * c->rows * k);

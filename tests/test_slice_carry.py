@@ -359,6 +359,50 @@ def test_carry_grows_with_the_rows(index_cls):
         index.free_namespaces([w])
 
 
+@pytest.mark.parametrize("per_slice, spill_over", [
+    (1, 0), (300, 0), (300, 25), (5000, 110)])
+def test_full_turnover_every_row_enters_once_and_leaves(per_slice,
+                                                         spill_over):
+    """Keys that live in one slice (``spill_over`` of them also in the
+    next, as an auction in flight over a slide's edge) and never come
+    back: every row of the matrix enters with one fire and leaves K fires
+    later. The native carry and the Python one hold the rows of a rebuild
+    at every fire, count the same rows swept out, and leak none."""
+    if not slotmap_available():
+        pytest.skip("native slotmap unavailable")
+    native, plain = NativeSlotIndex(1 << 16), HostSlotIndex(1 << 16)
+    fresh = HostSlotIndex(1 << 16)      # fires from nothing every time
+    live = {}                           # slice -> its keys
+    removed_before = 0
+    for s in range(20):
+        keys = list(range(s * per_slice - (spill_over if s else 0),
+                          (s + 1) * per_slice))
+        live[s] = set(keys)
+        for index in (native, plain, fresh):
+            _put(index, s, keys)
+        ends = list(range(s - K + 1, s + 1))
+        want = rebuilt(fresh, ends)
+        fresh._slice_carry = None
+        assert as_rows(*fresh.slice_matrix(ends)[:2]) == want
+        for index in (native, plain):
+            got_keys, got_matrix, cells = index.slice_matrix(ends)
+            assert as_rows(got_keys, got_matrix) == want
+            assert cells == len(keys)
+            # no row leaks: the matrix holds the live keys, no more
+            assert len(got_keys) == len(set().union(
+                *(live[e] for e in ends if e in live)))
+        assert native.carry_rows_removed == plain.carry_rows_removed
+        # the rows that left: the keys of the slice that left which the
+        # slice after it does not hold
+        left = len(live[s - K] - live[s - K + 1]) if s >= K else 0
+        assert native.carry_rows_removed - removed_before == left
+        removed_before = native.carry_rows_removed
+        assert fresh.carry_rows_removed == 0    # nothing to sweep
+        for index in (native, plain, fresh):
+            index.free_namespaces([s - K - LATENESS + 1])
+    assert native.carry_rows_removed == 15 * per_slice - spill_over
+
+
 # ---------------------------------------------------------------- engines
 
 
