@@ -247,6 +247,10 @@ def load_slotmap() -> Optional[ctypes.CDLL]:
         lib.sm_resolve_grouped.argtypes = [vp, i64, P(i64), P(i64), i64, i64,
                                            i64, i64, P(i32), P(i64),
                                            P(i64)]
+        lib.sm_resolve_grouped_sharded.restype = i32
+        lib.sm_resolve_grouped_sharded.argtypes = [
+            P(vp), i64, i64, P(i64), P(i64), P(i32), i64, i64, i64, i64,
+            i64, P(u8), i64, P(i32), P(i32), P(i64), P(i64), P(i64)]
         lib.sm_erase.restype = i64
         lib.sm_erase.argtypes = [vp, i64, P(i64), P(i64), P(i32)]
         lib.sm_lookup.restype = None

@@ -43,11 +43,16 @@ from flink_tpu.parallel.mesh import KEY_AXIS, shard_map
 from flink_tpu.parallel.shuffle import (
     bucket_by_shard,
     build_exchange_scatter,
+    group_shard_table,
     shard_records,
     stage_device_exchange,
 )
 from flink_tpu.state.keygroups import assign_key_groups
-from flink_tpu.state.slot_table import HostSlotIndex, resolve_slot_hints
+from flink_tpu.state.slot_table import (
+    HostSlotIndex,
+    resolve_slices_sharded,
+    resolve_slot_hints,
+)
 from flink_tpu.windowing.aggregates import AggregateFunction
 from flink_tpu.windowing.assigners import WindowAssigner
 from flink_tpu.windowing.bookkeeping import SliceBookkeeper
@@ -101,6 +106,9 @@ class MeshSpillSupport:
     #: case — every routing site goes through _route so a rebalanced
     #: table threads the whole data plane without per-site branching)
     _assignment = None
+    #: (assignment, (P, max_parallelism, key_group_range), table) of the
+    #: last :meth:`_group_shard_table`
+    _group_shards = None
     #: hot-range rebalances applied (counterpart of reshards_completed)
     rebalances_completed: int = 0
     #: report dict of the most recent reassign_key_groups()
@@ -125,6 +133,19 @@ class MeshSpillSupport:
                 key_ids, self.max_parallelism).astype(np.int64)
         return shard_records(key_ids, self.P, self.max_parallelism,
                              self.key_group_range)
+
+    def _group_shard_table(self) -> np.ndarray:
+        """:meth:`_route` as the int32 key group -> shard table a native
+        sweep routes by (-1: a group this mesh does not own), built anew
+        when what ``_route`` reads has changed (a reshard, a rebalance)."""
+        assignment, rest = self._assignment, (
+            self.P, self.max_parallelism, self.key_group_range)
+        cached = self._group_shards
+        if cached is None or cached[0] is not assignment \
+                or cached[1] != rest:
+            cached = self._group_shards = (
+                assignment, rest, group_shard_table(*rest, assignment))
+        return cached[2]
 
     def _set_host_topology(self, topology) -> None:
         if topology is not None:
@@ -2203,6 +2224,34 @@ class MeshWindowEngine(MeshSpillSupport):
         # batch boundary: the engine is consistent at a known source
         # position — the one point the watchdog may declare a shard dead
         self._wd_boundary()
+        if (self.shuffle_mode == "device" and not self._spill_active
+                and self._replica is None):
+            # one native sweep over timestamps, keys and all the shards'
+            # indexes, where the engine and the batch allow it: nothing
+            # here wants a shard's records as one run (spill's residency
+            # and reserve and the replica's marks do), the indexes are
+            # native slice-partitioned ones and no record is late; else
+            # the path below, with the same results
+            with flight.span("prep.resolve") as resolve:
+                capacity = self.capacity
+                swept = resolve_slices_sharded(
+                    self.indexes, batch.key_ids, batch.timestamps,
+                    self._group_shard_table(), self.assigner.offset,
+                    self.assigner.slice_width,
+                    self.book.oldest_live_slice_end(), dirty=self._dirty)
+                if swept is not None:
+                    shards, rec_slots, uniq, _, resolve.work = swept
+                    if self.capacity != capacity:
+                        # an index grew inside the sweep: the slots past
+                        # the old capacity had no place in the old map
+                        self._dirty[shards, rec_slots] = True
+                    self.book.register_slices(uniq, uniq=uniq)
+                    flight.instant("resolve.sweep", work=n)
+            if swept is not None:
+                values, leaves, partial = self._values_of(batch)
+                self._dispatch_device(shards, rec_slots, values, leaves,
+                                      partial)
+                return
         key_ids = batch.key_ids
         slice_ends = self.assigner.assign_slice_ends(batch.timestamps)
         if self._spill_active and n > 1:
@@ -2224,21 +2273,7 @@ class MeshWindowEngine(MeshSpillSupport):
 
             # route to owning shard, bucket into [P, B] blocks
             shards = self._route(key_ids)
-        from flink_tpu.runtime.local_agg import (
-            is_partial_batch,
-            partial_leaf_values,
-        )
-
-        partial = is_partial_batch(batch)
-        if partial:
-            # locally pre-aggregated rows (two-phase agg): one explicit
-            # value per ACC leaf, folded with the valued scatter (the
-            # mesh form of SlotTable.upsert_valued)
-            values = partial_leaf_values(batch, self.agg)
-            leaves = self.agg.leaves
-        else:
-            values = self.agg.map_input(batch)
-            leaves = self.agg.input_leaves
+        values, leaves, partial = self._values_of(batch)
         if self.shuffle_mode == "device":
             self._process_batch_device(key_ids, slice_ends, shards,
                                        values, leaves, partial)
@@ -2293,6 +2328,21 @@ class MeshWindowEngine(MeshSpillSupport):
             )
         self._push_dispatch_fence()
 
+    def _values_of(self, batch: RecordBatch):
+        """``(values, their leaves, partial)`` of a batch for the
+        scatter."""
+        from flink_tpu.runtime.local_agg import (
+            is_partial_batch,
+            partial_leaf_values,
+        )
+
+        if is_partial_batch(batch):
+            # locally pre-aggregated rows (two-phase agg): one explicit
+            # value per ACC leaf, folded with the valued scatter (the
+            # mesh form of SlotTable.upsert_valued)
+            return partial_leaf_values(batch, self.agg), self.agg.leaves, True
+        return self.agg.map_input(batch), self.agg.input_leaves, False
+
     def _pairs_inserted(self) -> int:
         """(key, slice) pairs the shards' host indexes have given a slot
         so far (one batch's growth is its ``prep.resolve`` work)."""
@@ -2337,6 +2387,13 @@ class MeshWindowEngine(MeshSpillSupport):
             rec_slots = np.empty(n, dtype=np.int32)
             rec_slots[order] = slots_sorted
             resolve.work = self._pairs_inserted() - inserted
+        self._dispatch_device(shards, rec_slots, values, leaves, partial)
+
+    def _dispatch_device(self, shards, rec_slots, values, leaves,
+                         partial: bool) -> None:
+        """Stage a batch's per-record (shard, slot) and values and hand
+        them to the fused exchange+scatter program."""
+        n = len(rec_slots)
         # pipelining: claim a dispatch slot BEFORE rewriting the pooled
         # flat staging buffers (their previous consumer must have
         # finished — the same fence discipline as the host blocks)

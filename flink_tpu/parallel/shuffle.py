@@ -195,7 +195,20 @@ def shard_records(
     contiguous formula. Subsumes ``key_group_range`` (an assignment
     carries its own first/span).
     """
-    groups = assign_key_groups(key_ids, max_parallelism)
+    return shard_of_key_groups(
+        assign_key_groups(key_ids, max_parallelism), num_shards,
+        max_parallelism, key_group_range, assignment)
+
+
+def shard_of_key_groups(
+    groups: np.ndarray,
+    num_shards: int,
+    max_parallelism: int,
+    key_group_range=None,
+    assignment=None,
+) -> np.ndarray:
+    """Global key group -> owning shard: :func:`shard_records` past the
+    key's hash (its arguments, its three forms)."""
     if assignment is not None:
         return assignment.shard_of_groups(groups).astype(np.int64)
     if key_group_range is not None:
@@ -204,6 +217,29 @@ def shard_records(
         local_max = int(last) - int(first) + 1
         return ((local * num_shards) // local_max).astype(np.int64)
     return key_group_to_operator_index(groups, max_parallelism, num_shards)
+
+
+def group_shard_table(
+    num_shards: int,
+    max_parallelism: int,
+    key_group_range=None,
+    assignment=None,
+) -> np.ndarray:
+    """:func:`shard_of_key_groups` of every key group, as the int32
+    ``[max_parallelism]`` table a native sweep routes by
+    (``resolve_slices_sharded``); -1 for a group outside the range this
+    mesh owns."""
+    groups = np.arange(max_parallelism, dtype=np.int64)
+    if assignment is not None:
+        first, last = assignment.first, assignment.first + assignment.span - 1
+    else:
+        first, last = key_group_range or (0, max_parallelism - 1)
+    owned = (groups >= int(first)) & (groups <= int(last))
+    table = np.full(max_parallelism, -1, dtype=np.int32)
+    table[owned] = shard_of_key_groups(
+        groups[owned], num_shards, max_parallelism, key_group_range,
+        assignment)
+    return table
 
 
 # ---------------------------------------------------------------------------

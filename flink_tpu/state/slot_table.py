@@ -897,6 +897,74 @@ class NativeSlotIndex:
         return limit - 1 - self.num_used
 
 
+def resolve_slices_sharded(indexes: List, key_ids: np.ndarray,
+                           timestamps: np.ndarray, group_shard: np.ndarray,
+                           offset: int, slice_width: int, live_from: int,
+                           dirty: Optional[np.ndarray] = None):
+    """:meth:`NativeSlotIndex.resolve_slices` of one batch over a mesh's
+    shard indexes, in one foreign call (``sm_resolve_grouped_sharded``,
+    native/slotmap.cpp): a record goes straight to the index of its own
+    shard, ``group_shard[key group of its key]`` (int32 per key group, -1
+    where a group has no shard here). Each index is left as
+    ``lookup_or_insert`` of its own records, in record order, leaves it.
+    ``dirty``, a ``[len(indexes), capacity]`` bool map, gets every
+    record's slot marked in its shard's row; after a growth inside the
+    call the owner's ``on_grow`` has run and the marks are its to repeat.
+
+    ``(shards, slots, distinct slice ends ascending, records per distinct
+    slice, new pairs)``, shards and slots per record; None, with nothing
+    changed, where an index is not a native slice-partitioned one, a
+    slice end lies below ``live_from``, the batch holds more than
+    ``MAX_SWEPT_SLICES`` distinct ones or a key's group has no shard."""
+    if not all(type(idx) is NativeSlotIndex and idx._track_ns
+               for idx in indexes):
+        return None
+    first, shard_count = indexes[0], len(indexes)
+    keys = np.ascontiguousarray(key_ids, dtype=np.int64)
+    ts = np.ascontiguousarray(timestamps, dtype=np.int64)
+    group_shard = np.ascontiguousarray(group_shard, dtype=np.int32)
+    n = len(keys)
+    if len(ts) != n:
+        raise ValueError(f"{n} keys against {len(ts)} timestamps")
+    if dirty is not None and not (dirty.flags.c_contiguous
+                                  and dirty.dtype == np.bool_):
+        raise ValueError("dirty must be a C-contiguous bool map")
+    max_uniq = NativeSlotIndex.MAX_SWEPT_SLICES
+    if 3 * max_uniq > len(first._sweep_groups):
+        first._sweep_groups = np.empty(3 * max_uniq, dtype=np.int64)
+    groups = first._sweep_groups
+    shards = np.empty(n, dtype=np.int32)
+    slots = np.empty(n, dtype=np.int32)
+    per_shard = np.empty((2, shard_count), dtype=np.int64)
+    old_caps = [idx.capacity for idx in indexes]
+    rc = first._lib.sm_resolve_grouped_sharded(
+        (_ct.c_void_p * shard_count)(*[idx._h for idx in indexes]),
+        shard_count, n, keys.ctypes.data_as(_I64P), ts.ctypes.data_as(_I64P),
+        group_shard.ctypes.data_as(_I32P), len(group_shard), int(offset),
+        int(slice_width), int(live_from), max_uniq,
+        None if dirty is None else dirty.ctypes.data_as(_U8P),
+        0 if dirty is None else dirty.shape[1],
+        shards.ctypes.data_as(_I32P), slots.ctypes.data_as(_I32P),
+        groups.ctypes.data_as(_I64P), _ct.byref(first._sweep_k),
+        per_shard.ctypes.data_as(_I64P))
+    if rc == -2:
+        return None
+    k = first._sweep_k.value
+    ends, records = groups[:k].copy(), groups[max_uniq:max_uniq + k].copy()
+    full = None
+    for idx, fresh, grows, old_cap in zip(
+            indexes, per_shard[0].tolist(), per_shard[1].tolist(), old_caps):
+        idx.pairs_inserted += fresh
+        try:
+            idx._settle(grows, old_cap)
+        except SlotTableFullError as e:
+            # the others' growth reaches their owners first
+            full = e
+    if full is not None:
+        raise full
+    return shards, slots, ends, records, int(per_shard[0].sum())
+
+
 def make_slot_index(capacity: int, on_grow=None, growable: bool = True,
                     full_hint: str = "raise state.slot-table.capacity",
                     max_capacity: int = 0,

@@ -48,6 +48,11 @@ struct SweepScratch {
   int32_t* table;    // [uniq_cap] partitioned: its table's index
   int64_t tab_size;  // power of two, > 2 * distinct held
   int32_t* tab;      // namespace -> first-seen index, -1 empty
+  // the sweep over several maps (sm_resolve_grouped_sharded), kept with
+  // the first of them
+  int64_t cell_cap;  // (distinct namespace, shard) cells ``cell`` holds
+  int32_t* cell;     // [cell_cap] whether the cell holds a record, then
+                     // its table (-1: none)
 };
 
 // ---- the partitioned form: one table per namespace
@@ -625,6 +630,71 @@ inline int32_t sweep_index_of(SweepScratch* w, int64_t v, int64_t* k,
   return i;
 }
 
+// Pass A of the batch sweeps: reads ``vals`` alone and changes nothing but
+// the scratch. Each record's distinct namespace (first-seen index, in
+// w->sinv) and the records under each (w->count), the first-seen indexes
+// by ascending namespace in w->order, w->fresh zeroed; *k = distinct
+// namespaces. ``vals`` are timestamps where width > 0: a record's
+// namespace is then the end of its slice, ts - floormod(ts - offset,
+// width) + width (the assigner's rule; an in-order run stays in the slice
+// of the record before it, so a compare stands in for the division).
+// Where width == 0 they are the namespaces themselves. False where there
+// are more than max_uniq distinct namespaces, or (width > 0) a slice end
+// lies below live_from: a late record, which the caller's own path drops
+// and counts.
+inline bool sweep_pass_a(SweepScratch* w, int64_t n, const int64_t* vals,
+                         int64_t offset, int64_t width, int64_t live_from,
+                         int64_t max_uniq, int64_t* out_k) {
+  if (n > w->rec_cap) {
+    free(w->sinv);
+    w->sinv = (int32_t*)malloc(sizeof(int32_t) * n);
+    w->rec_cap = n;
+  }
+  if (!w->tab) {
+    w->tab_size = 64;
+    w->tab = (int32_t*)malloc(sizeof(int32_t) * w->tab_size);
+  }
+  memset(w->tab, 0xff, sizeof(int32_t) * w->tab_size);
+  int64_t k = 0;
+  int32_t* sinv = w->sinv;
+  int32_t cur = -1;
+  int64_t run = 0;       // records of the open run, not yet counted
+  int64_t cur_val = 0;   // width > 0: the open slice's start
+  for (int64_t r = 0; r < n; r++) {
+    int64_t v = vals[r];
+    bool same = width > 0
+                    ? (uint64_t)v - (uint64_t)cur_val < (uint64_t)width
+                    : v == cur_val;
+    if (cur < 0 || !same) {
+      if (cur >= 0) w->count[cur] += run;
+      run = 0;
+      int64_t ns = v;
+      if (width > 0) {
+        int64_t rem = (v - offset) % width;
+        if (rem < 0) rem += width;
+        cur_val = v - rem;
+        ns = cur_val + width;
+        if (ns < live_from) return false;
+      } else {
+        cur_val = v;
+      }
+      cur = sweep_index_of(w, ns, &k, max_uniq);
+      if (cur < 0) return false;
+    }
+    sinv[r] = cur;
+    run++;
+  }
+  if (cur >= 0) w->count[cur] += run;
+  for (int64_t j = 0; j < k; j++) {
+    w->order[j] = (int32_t)j;
+    w->fresh[j] = 0;
+  }
+  std::sort(w->order, w->order + k,
+            [w](int32_t a, int32_t b) { return w->val[a] < w->val[b]; });
+  *out_k = k;
+  return true;
+}
+
 }  // namespace
 
 extern "C" {
@@ -672,6 +742,7 @@ void sm_destroy(void* h) {
   free(m->sweep.order);
   free(m->sweep.table);
   free(m->sweep.tab);
+  free(m->sweep.cell);
   free(m);
 }
 
@@ -762,17 +833,9 @@ int32_t sm_lookup_or_insert(void* h, int64_t n, const int64_t* keys,
 // every record, and per distinct namespace the records under it and the
 // pairs newly given a slot.
 //
-// ``vals`` are timestamps where width > 0: a record's namespace is then
-// the end of its slice, ts - floormod(ts - offset, width) + width (the
-// assigner's rule; an in-order run stays in the slice of the record before
-// it, so a compare stands in for the division). Where width == 0 they are
-// the namespaces themselves.
-//
-// Pass A reads ``vals`` alone and changes nothing: each record's distinct
-// namespace (first-seen index, kept in scratch) and the records under
-// each. More than max_uniq distinct namespaces, or (width > 0) a slice end
-// below live_from — a late record, which the caller's own path drops and
-// counts — returns -2 with the index untouched. Pass B is
+// Pass A (sweep_pass_a above: what ``vals`` are, and when it gives a
+// batch up) changes nothing, and where it gives up the call returns -2
+// with the index untouched. Pass B is
 // sm_lookup_or_insert's probe (same hashes, prefetch, growth); partitioned,
 // each distinct namespace's table is found (or opened) once before it and
 // a record goes straight to its own slice's table, where its new pair is
@@ -789,56 +852,11 @@ int32_t sm_resolve_grouped(void* h, int64_t n, const int64_t* keys,
                            int64_t* out_groups, int64_t* out_k) {
   SlotMap* m = (SlotMap*)h;
   SweepScratch* w = &m->sweep;
-  if (n > w->rec_cap) {
-    free(w->sinv);
-    w->sinv = (int32_t*)malloc(sizeof(int32_t) * n);
-    w->rec_cap = n;
-  }
-  if (!w->tab) {
-    w->tab_size = 64;
-    w->tab = (int32_t*)malloc(sizeof(int32_t) * w->tab_size);
-  }
-  memset(w->tab, 0xff, sizeof(int32_t) * w->tab_size);
-  int64_t k = 0;
-  int32_t* sinv = w->sinv;
-  // ---- pass A: distinct namespaces and their record counts
-  {
-    int32_t cur = -1;
-    int64_t run = 0;       // records of the open run, not yet counted
-    int64_t cur_val = 0;   // width > 0: the open slice's start
-    for (int64_t r = 0; r < n; r++) {
-      int64_t v = vals[r];
-      bool same = width > 0
-                      ? (uint64_t)v - (uint64_t)cur_val < (uint64_t)width
-                      : v == cur_val;
-      if (cur < 0 || !same) {
-        if (cur >= 0) w->count[cur] += run;
-        run = 0;
-        int64_t ns = v;
-        if (width > 0) {
-          int64_t rem = (v - offset) % width;
-          if (rem < 0) rem += width;
-          cur_val = v - rem;
-          ns = cur_val + width;
-          if (ns < live_from) return -2;
-        } else {
-          cur_val = v;
-        }
-        cur = sweep_index_of(w, ns, &k, max_uniq);
-        if (cur < 0) return -2;
-      }
-      sinv[r] = cur;
-      run++;
-    }
-    if (cur >= 0) w->count[cur] += run;
-  }
-  for (int64_t j = 0; j < k; j++) {
-    w->order[j] = (int32_t)j;
-    w->fresh[j] = 0;
-  }
-  std::sort(w->order, w->order + k,
-            [w](int32_t a, int32_t b) { return w->val[a] < w->val[b]; });
+  int64_t k;
+  if (!sweep_pass_a(w, n, vals, offset, width, live_from, max_uniq, &k))
+    return -2;
   *out_k = k;
+  const int32_t* sinv = w->sinv;
   // ---- pass B: the probe (sm_lookup_or_insert's, see there)
   int32_t grows = 0;
   bool full = false;
@@ -913,6 +931,150 @@ int32_t sm_resolve_grouped(void* h, int64_t n, const int64_t* keys,
     out_groups[2 * max_uniq + j] = w->fresh[u];
   }
   return full ? -1 : grows;
+}
+
+// The same sweep over a batch whose records belong to P maps, one per
+// shard of a mesh: record r goes to handles[shard(r)], where shard(r) =
+// group_shard[murmur_fmix32(fold(key)) % max_parallelism] — the key-group
+// routing (state/keygroups.py assign_key_groups) through the caller's
+// group -> shard table, -1 where a group has no shard here. Each map sees
+// its own records in record order, only interleaved with the others', so
+// its tables, free stack and slot ids come out as from sm_resolve_grouped
+// over its records alone: a (namespace, shard) cell's table is found or
+// opened only where the cell holds a record, per shard in ascending order
+// of the namespaces.
+//
+// Nothing is changed before pass A and the routing are through: -2 where
+// pass A gives the batch up, a record's group has no shard, or a map is
+// not partitioned.
+//
+// out_shards / out_slots are per record, in record order. ``dirty``,
+// where given, is the owner's [P, dirty_stride] byte map of slots touched:
+// a record's slot is marked in its shard's row (a slot at or past the
+// stride, handed out by a growth inside this call, is the owner's to
+// mark once it has grown its map). out_groups is [2, max_uniq] int64: the
+// distinct namespaces ascending and the records under each. out_per_shard
+// is [2, P] int64: per shard its new pairs and its grows (-1: full at
+// max_capacity). Returns 0, or -1 where some shard was full: the
+// sweep stops at that record, and out_per_shard counts what was inserted
+// before it.
+int32_t sm_resolve_grouped_sharded(
+    void* const* handles, int64_t P, int64_t n, const int64_t* keys,
+    const int64_t* vals, const int32_t* group_shard, int64_t max_parallelism,
+    int64_t offset, int64_t width, int64_t live_from, int64_t max_uniq,
+    uint8_t* dirty, int64_t dirty_stride, int32_t* out_shards,
+    int32_t* out_slots, int64_t* out_groups, int64_t* out_k,
+    int64_t* out_per_shard) {
+  for (int64_t p = 0; p < P; p++)
+    if (!((SlotMap*)handles[p])->partitioned) return -2;
+  SweepScratch* w = &((SlotMap*)handles[0])->sweep;
+  int64_t k;
+  if (!sweep_pass_a(w, n, vals, offset, width, live_from, max_uniq, &k))
+    return -2;
+  const int32_t* sinv = w->sinv;
+  if (k * P > w->cell_cap) {
+    free(w->cell);
+    w->cell_cap = k * P;
+    w->cell = (int32_t*)malloc(sizeof(int32_t) * w->cell_cap);
+  }
+  int32_t* cell = w->cell;
+  memset(cell, 0, sizeof(int32_t) * k * P);
+  // ---- routing: each record's shard, the cells that hold a record. x % d
+  // for 32-bit x by two multiplications (Lemire's fastmod, exact)
+  const uint64_t d = (uint64_t)max_parallelism;
+  const uint64_t fast = UINT64_C(0xFFFFFFFFFFFFFFFF) / d + 1;
+  for (int64_t r = 0; r < n; r++) {
+    uint64_t key = (uint64_t)keys[r];
+    // the arithmetic shift of the signed key, as NumPy's k >> 32
+    uint32_t x = (uint32_t)(key ^ (uint64_t)(keys[r] >> 32));
+    x ^= x >> 16;
+    x *= 0x85EBCA6Bu;
+    x ^= x >> 13;
+    x *= 0xC2B2AE35u;
+    x ^= x >> 16;
+    uint64_t group =
+        (uint64_t)(((unsigned __int128)(fast * x) * d) >> 64);
+    int32_t s = group_shard[group];
+    if (s < 0 || s >= P) return -2;
+    out_shards[r] = s;
+    cell[(int64_t)sinv[r] * P + s] = 1;
+  }
+  *out_k = k;
+  int64_t* fresh = out_per_shard;
+  int64_t* grows = out_per_shard + P;
+  // ---- the cells' tables: per shard ascending, so that new tables open
+  // in the order of their names
+  for (int64_t p = 0; p < P; p++) {
+    SlotMap* m = (SlotMap*)handles[p];
+    fresh[p] = grows[p] = 0;
+    for (int64_t j = 0; j < k; j++) {
+      int32_t u = w->order[j];
+      int32_t* c = &cell[(int64_t)u * P + p];
+      if (!*c) {
+        *c = -1;
+        continue;
+      }
+      int32_t ti = dir_find(m, w->val[u]);
+      *c = ti >= 0 ? ti : table_open(m, w->val[u]);
+    }
+  }
+  // ---- pass B: the probe, each record in its own shard's map (no table
+  // is opened from here on; a map's growth moves its slot arrays alone)
+  bool full = false;
+  constexpr int64_t CHUNK = 256;
+  uint64_t hashes[CHUNK];
+  NsTable* tables[CHUNK];
+  for (int64_t base = 0; base < n && !full; base += CHUNK) {
+    int64_t end = base + CHUNK < n ? base + CHUNK : n;
+    for (int64_t r = base; r < end; r++) {
+      int32_t s = out_shards[r];
+      NsTable* t = &((SlotMap*)handles[s])
+                        ->tabs[cell[(int64_t)sinv[r] * P + s]];
+      tables[r - base] = t;
+      hashes[r - base] = key_hash(keys[r]);
+      part_prefetch(t, hashes[r - base]);
+    }
+    int64_t r = base;
+    for (; r < end; r++) {
+      int32_t s = out_shards[r];
+      bool is_new;
+      int32_t grew = 0;
+      int32_t slot = part_probe_or_insert((SlotMap*)handles[s],
+                                          tables[r - base], keys[r],
+                                          hashes[r - base], &grew, &is_new);
+      if (slot < 0) {
+        grows[s] = -1;
+        full = true;
+        break;
+      }
+      out_slots[r] = slot;
+      fresh[s] += is_new;
+      grows[s] += grew;
+      // the mark's cache line is asked for now and written after the
+      // chunk: the map is far larger than the cache, and a store that
+      // waits for its line holds the probes behind it back
+      if (dirty && slot < dirty_stride)
+        __builtin_prefetch(&dirty[s * dirty_stride + slot], 1, 1);
+    }
+    if (dirty)
+      for (int64_t q = base; q < r; q++)
+        if (out_slots[q] < dirty_stride)
+          dirty[out_shards[q] * dirty_stride + out_slots[q]] = 1;
+  }
+  if (full)
+    for (int64_t p = 0; p < P; p++) {
+      SlotMap* m = (SlotMap*)handles[p];
+      for (int64_t u = 0; u < k; u++) {
+        int32_t ti = cell[u * P + p];
+        if (ti >= 0 && m->tabs[ti].n == 0) table_close(m, ti);
+      }
+    }
+  for (int64_t j = 0; j < k; j++) {
+    int32_t u = w->order[j];
+    out_groups[j] = w->val[u];
+    out_groups[max_uniq + j] = w->count[u];
+  }
+  return full ? -1 : 0;
 }
 
 // Read-only batch probe: out_slots[i] = slot id, or -1 if the pair is not

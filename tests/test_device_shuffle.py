@@ -33,6 +33,7 @@ from flink_tpu.parallel.shuffle import (
 from flink_tpu.windowing.aggregates import SumAggregate
 from flink_tpu.windowing.sessions import SessionWindower
 
+from tests.test_native_slotmap import assert_shards_level
 from tests.test_sessions import keyed_batch
 
 GAP = 100
@@ -295,3 +296,183 @@ class TestDeviceModeEngines:
                 parallelism=min(8, len(jax.devices())),
                 shuffle_mode=mode))
             assert op.windower.shuffle_mode == mode
+
+
+# ------------------------------------------------ the sharded resolve sweep
+#
+# In device mode a batch's (key, slice) -> slot goes through ONE native
+# sweep over all the shards' indexes (``resolve_slices_sharded``) where the
+# engine and the batch allow it, else through the path it replaced
+# (argsort by destination, a lookup per shard, the scatter-back). Which
+# one ran is what the engine observed — spill, a replica, the index's
+# type, a late record — never a switch, so the other path is reached
+# here by those conditions.
+
+SWEEP_LATE_STEP = 4
+
+
+def _hop_engine(mesh, **kw):
+    from flink_tpu.parallel.sharded_windower import MeshWindowEngine
+    from flink_tpu.windowing.aggregates import CountAggregate
+    from flink_tpu.windowing.assigners import SlidingEventTimeWindows
+
+    kw.setdefault("capacity_per_shard", 1024)
+    return MeshWindowEngine(SlidingEventTimeWindows.of(500, 100),
+                            CountAggregate(), mesh, **kw)
+
+
+def _q5_shaped_stream(seed=41, steps=9, per_step=4000, num_keys=2500):
+    """HOP(500, 100) COUNT per key over in-order batches of 150 ms each,
+    half of the records on the hottest 1 % of the keys; the watermark
+    follows every batch, and the batch in the middle holds three records
+    of a slice whose every window has fired."""
+    rng = np.random.default_rng(seed)
+    out = []
+    for s in range(steps):
+        hot = rng.random(per_step) < 0.5
+        keys = np.where(hot, rng.integers(0, num_keys // 100, per_step),
+                        rng.integers(0, num_keys, per_step)).astype(np.int64)
+        ts = np.sort(rng.integers(s * 150, (s + 1) * 150, per_step))
+        if s == SWEEP_LATE_STEP:
+            ts[[7, per_step // 2, per_step - 1]] = 20
+        out.append((keys, ts.astype(np.int64), (s + 1) * 150 - 1))
+    return out
+
+
+def _drive(engine, stream):
+    """Rows fired (sorted), and per batch the recorder's sweep totals."""
+    from flink_tpu.observe import flight_recorder as flight
+
+    rec = flight.recorder()
+    rows, sweeps = [], []
+    for keys, ts, wm in stream:
+        rec.clear()
+        engine.process_batch(RecordBatch.from_pydict(
+            {KEY_ID_FIELD: keys}, timestamps=ts))
+        s = rec.kind_totals().get("resolve.sweep", {"count": 0, "work": 0})
+        sweeps.append((s["count"], s["work"]))
+        for b in engine.on_watermark(wm):
+            rows.extend((r[KEY_ID_FIELD], r["window_end"], r["count"])
+                        for r in b.to_rows())
+    rec.clear()
+    return sorted(rows), sweeps
+
+
+def _snapshot_rows(engine):
+    t = engine.snapshot(mode="savepoint")["table"]
+    cols = sorted(t)
+    return sorted(zip(*[np.asarray(t[c]).tolist() for c in cols]))
+
+
+@pytest.fixture(scope="module")
+def four_device_mesh():
+    from flink_tpu.parallel.mesh import make_mesh
+
+    return make_mesh(4)
+
+
+@pytest.fixture(scope="module")
+def swept_run(four_device_mesh):
+    """The engine every batch of whose stream may take the sweep, driven
+    up to the end-of-input flush; shared by the comparisons below."""
+    stream = _q5_shaped_stream()
+    engine = _hop_engine(four_device_mesh)
+    rows, sweeps = _drive(engine, stream)
+    return engine, stream, rows, sweeps
+
+
+def test_swept_batches_say_so_and_the_late_batch_does_not(swept_run):
+    engine, stream, rows, sweeps = swept_run
+    for step, ((keys, _, _), got) in enumerate(zip(stream, sweeps)):
+        want = (0, 0) if step == SWEEP_LATE_STEP else (1, len(keys))
+        assert got == want, step
+    assert engine.late_records_dropped == 3
+    assert len(rows) > 5000
+    # the sweep met an index growing under it (1,024 slots a shard)
+    assert engine.capacity > 1024
+
+
+@pytest.mark.parametrize("forced_by", [
+    "an_armed_replica", "a_python_index", "spill", "host_shuffle"])
+def test_old_path_by_what_the_engine_observes_gives_the_same(
+        swept_run, four_device_mesh, monkeypatch, forced_by):
+    """An engine kept off the sweep by spill, an armed replica, a Python
+    index or host shuffle fires the same rows, drops the same late
+    records and holds the same logical state; where the indexes are the
+    same native ones (the replica) the same slots too: ``_dirty`` and
+    every slot's metadata bit for bit."""
+    from flink_tpu.state import slot_table
+
+    engine, stream, rows, _ = swept_run
+    kw = {}
+    if forced_by == "a_python_index":
+        import flink_tpu.native as native
+
+        monkeypatch.setattr(native, "slotmap_available", lambda: False)
+    elif forced_by == "spill":
+        kw = dict(max_device_slots=1 << 14)
+    elif forced_by == "host_shuffle":
+        kw = dict(shuffle_mode="host")
+    old = _hop_engine(four_device_mesh, **kw)
+    if forced_by == "an_armed_replica":
+        old.arm_replica()
+    if forced_by == "a_python_index":
+        assert type(old.indexes[0]) is slot_table.HostSlotIndex
+    else:
+        assert type(old.indexes[0]) is slot_table.NativeSlotIndex
+    old_rows, old_sweeps = _drive(old, stream)
+    assert old_sweeps == [(0, 0)] * len(stream)
+    assert old_rows == rows
+    assert old.late_records_dropped == engine.late_records_dropped == 3
+    assert _snapshot_rows(old) == _snapshot_rows(engine)
+    if forced_by == "an_armed_replica":
+        assert old.capacity == engine.capacity
+        np.testing.assert_array_equal(old._dirty, engine._dirty)
+        assert_shards_level(old.indexes, engine.indexes)
+        for mode in ("delta", "full"):
+            sa, sb = old.snapshot(mode), engine.snapshot(mode)
+            assert sorted(sa["table"]) == sorted(sb["table"])
+            for col in sa["table"]:
+                np.testing.assert_array_equal(sa["table"][col],
+                                              sb["table"][col])
+
+
+@pytest.mark.parametrize("form", ["formula", "range", "assignment"])
+def test_sweep_routing_table_is_the_engines_route(four_device_mesh, form):
+    """The key group -> shard table the sweep routes by against
+    ``_route``, THE engine routing decision, under its three forms; a
+    rebalance builds it anew."""
+    from flink_tpu.state.keygroups import (
+        KeyGroupAssignment,
+        assign_key_groups,
+    )
+
+    group_range = (32, 95) if form == "range" else None
+    engine = _hop_engine(four_device_mesh, key_group_range=group_range)
+    keys = np.random.default_rng(2).integers(-10 ** 12, 10 ** 12, 20_000)
+    groups = assign_key_groups(keys, engine.max_parallelism)
+    if group_range is not None:
+        mine = (groups >= 32) & (groups <= 95)
+        keys, groups = keys[mine], groups[mine]
+    table = engine._group_shard_table()
+    assert table is engine._group_shard_table()         # kept
+    np.testing.assert_array_equal(table[groups], engine._route(keys))
+    if form == "assignment":
+        engine.reassign_key_groups(KeyGroupAssignment.contiguous(
+            4, engine.max_parallelism).move(np.arange(5, 128, 9), 3))
+        moved = engine._group_shard_table()
+        assert (moved != table).any()
+        np.testing.assert_array_equal(moved[groups], engine._route(keys))
+        assert len(np.unique(engine._route(keys))) == 4
+    first, last = group_range or (0, engine.max_parallelism - 1)
+    every = np.arange(engine.max_parallelism)
+    assert ((table >= 0) == ((every >= first) & (every <= last))).all()
+    assert table.max() == 3
+    # and the engine's sweep sends every record where _route does
+    ts = np.sort(np.random.default_rng(3).integers(0, 300, len(keys)))
+    engine.process_batch(RecordBatch.from_pydict(
+        {KEY_ID_FIELD: keys}, timestamps=ts.astype(np.int64)))
+    shard_of = engine._route(keys)
+    for p, idx in enumerate(engine.indexes):
+        held = np.unique(idx.slot_key[idx.used_slots()])
+        np.testing.assert_array_equal(held, np.unique(keys[shard_of == p]))

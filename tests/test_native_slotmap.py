@@ -674,3 +674,275 @@ def test_a_pooled_table_is_reused_at_another_size(then):
         assert (idx.lookup(keys, np.full(n, ns, dtype=np.int64)) == -1).all()
         assert live_pairs(idx) == live_pairs(host)
     assert sorted(idx.namespaces) == [100, 101, 102, 103]
+
+
+# ------------------------- the sweep over a mesh's shard indexes at once
+#
+# ``resolve_slices_sharded`` (``sm_resolve_grouped_sharded``) against the
+# path it replaced in ``MeshWindowEngine``, kept here as the oracle: slice
+# ends and routing by NumPy, a stable argsort of the destinations, one
+# ``lookup_or_insert`` per shard on its contiguous run, the scatter-back.
+# Each shard's index sees its own records in record order either way, so
+# slot numbers, tables, free stacks and counters compare exactly.
+
+MP = 128            # max_parallelism
+
+
+def _routing(form, shards):
+    """``(key_group_range, assignment)`` of a routing form."""
+    from flink_tpu.state.keygroups import KeyGroupAssignment
+
+    if form == "formula":
+        return None, None
+    if form == "range":
+        return (40, 103), None
+    moved = np.arange(3, MP, 7)          # every seventh group to the last
+    return None, KeyGroupAssignment.contiguous(shards, MP).move(
+        moved, shards - 1)
+
+
+def _owned_keys(rng, n, group_range, high=5000):
+    """Keys whose key group the mesh owns (a sub-range mesh is sent no
+    others)."""
+    from flink_tpu.state.keygroups import assign_key_groups
+
+    keys = rng.integers(-high, high, 4 * n).astype(np.int64)
+    if group_range is not None:
+        groups = assign_key_groups(keys, MP)
+        keys = keys[(groups >= group_range[0]) & (groups <= group_range[1])]
+    return keys[:n]
+
+
+def old_mesh_resolve(indexes, keys, ts, group_range, assignment, dirty):
+    """``MeshWindowEngine._process_batch_device``'s resolve before the
+    sweep: ``(shards, slots per record)``."""
+    from flink_tpu.parallel.shuffle import shard_records
+
+    ends = slice_ends(ts)
+    shards = shard_records(keys, len(indexes), MP, group_range, assignment)
+    order = np.argsort(shards, kind="stable")
+    offsets = np.zeros(len(indexes) + 1, dtype=np.int64)
+    np.cumsum(np.bincount(shards, minlength=len(indexes)), out=offsets[1:])
+    s_keys, s_ns = keys[order], ends[order]
+    slots_sorted = np.empty(len(keys), dtype=np.int32)
+    for p, idx in enumerate(indexes):
+        a, b = int(offsets[p]), int(offsets[p + 1])
+        if a == b:
+            continue
+        slots_sorted[a:b] = idx.lookup_or_insert(s_keys[a:b], s_ns[a:b])
+        dirty[p, slots_sorted[a:b]] = True
+    slots = np.empty(len(keys), dtype=np.int32)
+    slots[order] = slots_sorted
+    return shards, slots
+
+
+def assert_shards_level(swept, plain):
+    for a, b in zip(swept, plain):
+        assert registry(a) == registry(b)
+        assert a.pairs_inserted == b.pairs_inserted
+        assert a.num_used == b.num_used and a.capacity == b.capacity
+        np.testing.assert_array_equal(a.slot_used, b.slot_used)
+        used = np.nonzero(a.slot_used)[0]
+        np.testing.assert_array_equal(a.slot_key[used], b.slot_key[used])
+        np.testing.assert_array_equal(a.slot_ns[used], b.slot_ns[used])
+
+
+@needs_native
+@pytest.mark.parametrize("form", ["formula", "range", "assignment"])
+@pytest.mark.parametrize("shards", [1, 2, 4])
+def test_sharded_sweep_equals_argsort_and_per_shard_lookups(shards, form):
+    from flink_tpu.parallel.shuffle import group_shard_table
+    from flink_tpu.state.slot_table import resolve_slices_sharded
+
+    group_range, assignment = _routing(form, shards)
+    table = group_shard_table(shards, MP, group_range, assignment)
+    assert table.dtype == np.int32 and len(table) == MP
+    rng = np.random.default_rng(10 * shards + len(form))
+    swept = [NativeSlotIndex(1024) for _ in range(shards)]
+    plain = [NativeSlotIndex(1024) for _ in range(shards)]
+    cap = 1 << 15
+    dirty, dirty_plain = (np.zeros((shards, cap), dtype=bool)
+                          for _ in range(2))
+    for step in range(14):
+        n = int(rng.integers(1, 2500))
+        keys = _owned_keys(rng, n, group_range)
+        # slices step * W .. : mostly in order, every third batch shuffled
+        ts = rng.integers(step * W, (step + 4) * W, len(keys))
+        ts = (ts if step % 3 == 2 else np.sort(ts)).astype(np.int64)
+        got = resolve_slices_sharded(swept, keys, ts, table, 0, W,
+                                     -(1 << 62), dirty=dirty)
+        assert got is not None
+        rec_shards, slots, uniq, records, new_pairs = got
+        before = sum(i.pairs_inserted for i in plain)
+        want_shards, want = old_mesh_resolve(
+            plain, keys, ts, group_range, assignment, dirty_plain)
+        np.testing.assert_array_equal(rec_shards, want_shards)
+        np.testing.assert_array_equal(slots, want)
+        assert rec_shards.dtype == slots.dtype == np.int32
+        want_uniq, want_records = np.unique(slice_ends(ts),
+                                            return_counts=True)
+        np.testing.assert_array_equal(uniq, want_uniq)
+        np.testing.assert_array_equal(records, want_records)
+        assert new_pairs == sum(i.pairs_inserted for i in plain) - before
+        assert_shards_level(swept, plain)
+        np.testing.assert_array_equal(dirty, dirty_plain)
+        # the fire's carried matrix reads the tables' own arrays: the
+        # same rows from either set
+        window = [(step + j) * W + W for j in range(3)]
+        for a, b in zip(swept, plain):
+            for x, y in zip(a.slice_matrix(window), b.slice_matrix(window)):
+                np.testing.assert_array_equal(x, y)
+        if step % 4 == 3:
+            dead = [ns for ns in uniq.tolist() if ns <= (step - 1) * W + W]
+            dead += [(step - 2) * W]
+            for a, b in zip(swept, plain):
+                freed, freed_plain = (i.free_namespaces(dead)
+                                      for i in (a, b))
+                assert (freed is None) == (freed_plain is None)
+                if freed is not None:
+                    np.testing.assert_array_equal(freed, freed_plain)
+            assert_shards_level(swept, plain)
+    assert all(i.capacity > 1024 for i in swept) or shards == 4
+
+
+def _four_shards(capacity=1 << 12, **kw):
+    from flink_tpu.parallel.shuffle import group_shard_table
+
+    return ([NativeSlotIndex(capacity, **kw) for _ in range(4)],
+            group_shard_table(4, MP))
+
+
+def _untouched(indexes):
+    return all(i.num_used == 0 and not i.namespaces
+               and i.pairs_inserted == 0 for i in indexes)
+
+
+@needs_native
+@pytest.mark.parametrize("why", [
+    "late_first", "late_middle", "late_last", "too_many_slices",
+    "a_group_without_a_shard", "a_python_index", "a_flat_index"])
+def test_sharded_sweep_leaves_a_batch_alone(why):
+    """"Not swept" is None with every index and the dirty map untouched;
+    the batch's neighbour that lacks the cause is taken."""
+    from flink_tpu.parallel.shuffle import group_shard_table
+    from flink_tpu.state.slot_table import resolve_slices_sharded
+
+    indexes, table = _four_shards(1 << 14)
+    n = 1000
+    keys = np.arange(n, dtype=np.int64)
+    ts = np.full(n, 5 * W + 3, dtype=np.int64)
+    live_from = 6 * W
+    taken = dict(keys=keys, ts=ts + W, table=table, indexes=indexes)
+    left = dict(taken)
+    if why.startswith("late"):
+        late_at = {"late_first": 0, "late_middle": 617, "late_last": n - 1}
+        left["ts"] = ts + W
+        left["ts"][late_at[why]] = 4 * W + 99      # slice end 5 * W
+    elif why == "too_many_slices":
+        n = NativeSlotIndex.MAX_SWEPT_SLICES + 1
+        keys = np.arange(n, dtype=np.int64)
+        left.update(keys=keys, ts=(np.arange(n) + 6) * W)
+        taken.update(keys=keys[1:], ts=left["ts"][1:])
+    elif why == "a_group_without_a_shard":
+        # a sub-range mesh handed a key of a group it does not own
+        owned = group_shard_table(4, MP, (0, 63))
+        assert (owned[64:] == -1).all() and (owned[:64] >= 0).all()
+        left["table"] = owned
+        taken.update(table=owned, keys=_owned_keys(
+            np.random.default_rng(3), n, (0, 63)))
+    elif why == "a_python_index":
+        left["indexes"] = indexes[:3] + [HostSlotIndex(1 << 14)]
+    else:
+        left["indexes"] = indexes[:3] + [
+            NativeSlotIndex(1 << 14, track_namespaces=False)]
+    dirty = np.zeros((4, 1 << 14), dtype=bool)
+    assert resolve_slices_sharded(
+        left["indexes"], left["keys"], left["ts"], left["table"], 0, W,
+        live_from, dirty=dirty) is None
+    assert _untouched(left["indexes"]) and not dirty.any()
+    got = resolve_slices_sharded(
+        taken["indexes"], taken["keys"], taken["ts"], taken["table"], 0, W,
+        live_from, dirty=dirty)
+    assert got is not None
+    distinct = len(np.unique(taken["keys"]))     # one slice: pairs = keys
+    assert sum(i.num_used for i in indexes) == distinct == dirty.sum()
+
+
+@needs_native
+def test_sharded_sweep_growth_of_one_shard_settles_that_shard():
+    """One shard's index doubles in mid-sweep: its owner hears of it, its
+    views are re-wrapped, the others stay as they were — and the slots
+    past the old capacity are the owner's to mark."""
+    from flink_tpu.state.keygroups import assign_key_groups
+    from flink_tpu.state.slot_table import resolve_slices_sharded
+
+    grows = []
+    indexes, table = _four_shards(1024)
+    for p, idx in enumerate(indexes):
+        idx.on_grow = lambda old, new, p=p: grows.append((p, old, new))
+    keys = np.arange(40_000, dtype=np.int64)
+    shard_of = table[assign_key_groups(keys, MP)]
+    # 1,500 keys of shard 2 (past its 1,023 free slots), 200 of the others
+    keys = np.concatenate([keys[shard_of == 2][:1500]]
+                          + [keys[shard_of == p][:200] for p in (0, 1, 3)])
+    keys = np.random.default_rng(5).permutation(keys)
+    ts = np.sort(np.random.default_rng(6).integers(0, 3 * W, len(keys)))
+    dirty = np.zeros((4, 1024), dtype=bool)
+    shards, slots, _, _, new_pairs = resolve_slices_sharded(
+        indexes, keys, ts, table, 0, W, -(1 << 62), dirty=dirty)
+    assert grows == [(2, 1024, 2048)]
+    assert [i.capacity for i in indexes] == [1024, 1024, 2048, 1024]
+    assert len(indexes[2].slot_key) == 2048
+    assert new_pairs == len(keys) == sum(i.num_used for i in indexes)
+    for p, idx in enumerate(indexes):
+        mine = shards == p
+        np.testing.assert_array_equal(idx.slot_key[slots[mine]], keys[mine])
+        in_map = slots[mine][slots[mine] < 1024]
+        assert dirty[p].sum() == len(in_map) and dirty[p, in_map].all()
+    assert (slots[shards == 2] >= 1024).sum() == 1500 - 1023
+
+
+@needs_native
+def test_sharded_sweep_full_shard_raises_with_the_others_level():
+    """A shard full at max_capacity in mid-sweep raises as
+    ``lookup_or_insert`` does; what every shard was given before it is in
+    its tables and counters, and a growth on the way reached its owner."""
+    from flink_tpu.state.keygroups import assign_key_groups
+    from flink_tpu.state.slot_table import (
+        SlotTableFullError,
+        resolve_slices_sharded,
+    )
+
+    grows = []
+    indexes, table = _four_shards(1024, max_capacity=2048)
+    for p, idx in enumerate(indexes):
+        idx.on_grow = lambda old, new, p=p: grows.append((p, old, new))
+    keys = np.arange(60_000, dtype=np.int64)
+    shard_of = table[assign_key_groups(keys, MP)]
+    keys = np.concatenate([keys[shard_of == 1][:3000]]
+                          + [keys[shard_of == p][:300] for p in (0, 2, 3)])
+    keys = np.random.default_rng(8).permutation(keys)
+    ts = np.random.default_rng(9).integers(0, 3 * W, len(keys))
+    dirty = np.zeros((4, 2048), dtype=bool)
+    with pytest.raises(SlotTableFullError, match="slot table full"):
+        resolve_slices_sharded(indexes, keys, ts.astype(np.int64), table,
+                               0, W, -(1 << 62), dirty=dirty)
+    assert grows == [(1, 1024, 2048)]
+    # every key is new here: the marks are the slots given out
+    for p, idx in enumerate(indexes):
+        np.testing.assert_array_equal(dirty[p, :idx.capacity], idx.slot_used)
+    assert indexes[1].num_used == 2047 == indexes[1].pairs_inserted
+    assert len(indexes[1].slot_key) == 2048
+    for idx in indexes:
+        assert idx.num_used == idx.pairs_inserted
+        held = [s for _, slots in registry(idx) for s in slots]
+        assert sorted(held) == np.nonzero(idx.slot_used)[0].tolist()
+        for ns, slots in registry(idx):
+            assert slots and (idx.slot_ns[slots] == ns).all()
+    # the others stopped where the full one did: fewer than their 300
+    assert all(0 < indexes[p].num_used < 300 for p in (0, 2, 3))
+    # and the tables still serve
+    indexes[1].free_namespaces([W])
+    assert indexes[1].num_used < 2047
+    assert resolve_slices_sharded(indexes, keys[:50], ts[:50].astype(
+        np.int64), table, 0, W, -(1 << 62)) is not None
