@@ -34,6 +34,11 @@ KNOWN_SPAN_KINDS = (
     "resolve.sweep",       # a batch whose slice ends and slots one native
                            # sweep resolved (instant inside prep.resolve;
                            # work: records)
+    "late.records",        # a batch that holds records behind a window
+                           # that has fired, inside the allowed lateness
+                           # (instant inside prep.resolve; work: the
+                           # batch's records older than the newest fired
+                           # window's end; absent from an in-order job)
     "prep.stage",          # input mapping, padding to the sticky bucket,
                            # shuffle staging into [P, B] blocks (work:
                            # bytes handed to the device, padding included)
@@ -55,6 +60,10 @@ KNOWN_SPAN_KINDS = (
     "carry.removed",       # rows the carried matrix swept out in that
                            # advance: keys whose last cell left with its
                            # slice (instant inside fire.shard; work: rows)
+    "fire.late",           # a fire of a window whose end is at or under
+                           # the newest fired window's: a late re-firing
+                           # under allowed lateness (instant inside
+                           # fire.dispatch; work: 1)
     "fire.harvest",        # D2H materialization of fire/query results
                            # (work: bytes fetched)
     "slice.retire",        # expired slices' pairs erased from the host

@@ -96,15 +96,20 @@ class SliceBookkeeper:
         return live
 
     def register_slices(self, slice_ends: np.ndarray,
-                        uniq: Optional[np.ndarray] = None) -> None:
+                        uniq: Optional[np.ndarray] = None) -> bool:
         """Track new slices and (re-)schedule their windows.
 
         A window is scheduled iff it can still produce output:
         w - 1 + lateness > watermark. For an already-fired window inside the
         lateness allowance this is a late re-firing. ``uniq`` lets the
         caller supply the already-computed distinct slice ends (see
-        WindowAssigner.slice_plan) instead of re-sorting the batch."""
+        WindowAssigner.slice_plan) instead of re-sorting the batch.
+
+        True where a window at or under ``max_fired_end`` stands scheduled
+        for one of these slices: the batch holds records behind a window
+        that has fired (never on an in-order stream)."""
         lateness = self.allowed_lateness
+        late = False
         if uniq is None:
             uniq = np.unique(slice_ends)
         for se in uniq.tolist():
@@ -121,10 +126,12 @@ class SliceBookkeeper:
             if ends is None:
                 continue
             for w in ends:
-                if (w - 1 + lateness > self.watermark
-                        and w not in self._pending_set):
-                    self._pending_set.add(w)
-                    heapq.heappush(self._pending, w)
+                if w - 1 + lateness > self.watermark:
+                    if w not in self._pending_set:
+                        self._pending_set.add(w)
+                        heapq.heappush(self._pending, w)
+                    late = late or w <= self.max_fired_end
+        return late
 
     # -------------------------------------------------------------------- fire
 

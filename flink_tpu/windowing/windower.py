@@ -147,7 +147,7 @@ class SliceSharedWindower:
                               self.book.oldest_live_slice_end())
                 if swept is not None:
                     slots, uniq, resolve.work = swept
-                    self.book.register_slices(uniq, uniq=uniq)
+                    self._register(uniq, batch.timestamps)
                     flight.instant("resolve.sweep", work=len(batch))
             if swept is not None:
                 values, valued = self._values_of(batch)
@@ -168,7 +168,7 @@ class SliceSharedWindower:
             # shared by the bookkeeper AND the state table so neither
             # re-sorts the batch
             plan = self.assigner.slice_plan(slice_ends)
-            self.book.register_slices(slice_ends, uniq=plan[0])
+            self._register(plan[0], batch.timestamps)
         accepts_plan = getattr(self.table, "accepts_slice_plan", False)
         kw = {"slice_plan": plan} if accepts_plan else {}
         values, valued = self._values_of(batch)
@@ -176,6 +176,18 @@ class SliceSharedWindower:
             self.table.upsert_valued(batch.key_ids, slice_ends, values, **kw)
         else:
             self.table.upsert(batch.key_ids, slice_ends, values, **kw)
+
+    def _register(self, uniq: np.ndarray, timestamps: np.ndarray) -> None:
+        """The batch's distinct slice ends to the bookkeeper and, where
+        one of them scheduled a window that has fired, the batch's
+        records behind the newest fired window's end as a
+        ``late.records`` instant: a window's end is its last slice's, so
+        those are the records in slices that hold a fired window. One
+        pass over the timestamps, taken by a batch with a late record
+        and by no other."""
+        if self.book.register_slices(uniq, uniq=uniq):
+            flight.instant("late.records", work=int(np.count_nonzero(
+                timestamps < self.book.max_fired_end)))
 
     def _register_fused(self, uniq: np.ndarray, sinv: np.ndarray) -> None:
         """Bookkeeping for the fused ingest path. Late records are NOT
@@ -211,6 +223,8 @@ class SliceSharedWindower:
                 w_end = self.book.next_window(watermark)
                 if w_end is None:
                     break
+                if w_end <= self.book.max_fired_end:
+                    flight.instant("fire.late", work=1)
                 batch = self._fire_window(w_end, async_ok=async_ok)
                 if batch is not None and (not hasattr(batch, "__len__")
                                           or len(batch) > 0):

@@ -474,10 +474,19 @@ def test_single_device_sink_rows_bit_identical(index_cls, monkeypatch,
             SlidingEventTimeWindows.of(500, 100), SumAggregate("v"),
             capacity=1 << 12, allowed_lateness=200)
 
+    from flink_tpu.observe import flight_recorder as flight
+
     want = run_engine(make, True, monkeypatch, async_ok)
+    flight.recorder().clear()
     got = run_engine(make, False, monkeypatch, async_ok)
     assert len(got) > 1000
     assert got == want
+    # the stream does step back: windows at or under the newest fired one
+    # fire again on a carried matrix, for records the engine counted late
+    late = flight.recorder().kind_totals()
+    windows = len({row[0] for row in got})
+    assert late["fire.late"]["work"] >= windows
+    assert late["late.records"]["work"] > late["fire.late"]["work"]
 
 
 @pytest.mark.parametrize("async_ok", [False, True])
