@@ -263,7 +263,7 @@ def load_slotmap() -> Optional[ctypes.CDLL]:
         lib.sm_carry_destroy.argtypes = [vp]
         lib.sm_carry_advance.restype = i64
         lib.sm_carry_advance.argtypes = [vp, vp, i64, P(i64), i64, P(i64),
-                                         P(i32), P(i64), P(i64)]
+                                         P(i32), P(i64), P(i64), P(i64)]
         lib.sm_drop_namespaces.restype = i64
         lib.sm_drop_namespaces.argtypes = [vp, i64, P(i64), P(i32)]
         lib.sm_namespace_count.restype = i64

@@ -64,6 +64,9 @@ KNOWN_SPAN_KINDS = (
                            # the newest fired window's: a late re-firing
                            # under allowed lateness (instant inside
                            # fire.dispatch; work: 1)
+    "fire.gather",         # the slot matrix one fire program was handed,
+                           # padded (instant inside fire.dispatch; work:
+                           # padded rows x columns, the cells it gathers)
     "fire.harvest",        # D2H materialization of fire/query results
                            # (work: bytes fetched)
     "slice.retire",        # expired slices' pairs erased from the host

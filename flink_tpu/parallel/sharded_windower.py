@@ -2522,7 +2522,6 @@ class MeshWindowEngine(MeshSpillSupport):
             # the host, so there is nothing to defer: stays synchronous
             # inside an async on_watermark.
             return self._fire_window_hybrid(window_end, slice_ends)
-        k = len(slice_ends)
         per_shard_mats: List[np.ndarray] = []
         per_shard_keys: List[np.ndarray] = []
         w_max = 0
@@ -2539,9 +2538,12 @@ class MeshWindowEngine(MeshSpillSupport):
             return None
         W = sticky_bucket(w_max, getattr(self, "_fire_bucket", 0), minimum=64)
         self._fire_bucket = W
-        sm = np.zeros((self.P, W, k), dtype=np.int32)
+        # each shard's matrix is as wide as its fullest row needs
+        # (``slice_matrix``): the block takes the widest
+        width = max(mat.shape[1] for mat in per_shard_mats)
+        sm = np.zeros((self.P, W, width), dtype=np.int32)
         for p, mat in enumerate(per_shard_mats):
-            sm[p, : len(mat)] = mat
+            sm[p, : len(mat), : mat.shape[1]] = mat
         self._fire_matrix_bytes += sm.nbytes
         fire_out = self._fire_step(self.accs, self._put_sharded(sm))
         names = sorted(fire_out.keys())
@@ -2590,7 +2592,6 @@ class MeshWindowEngine(MeshSpillSupport):
                             slice_ends) -> Optional[RecordBatch]:
         from flink_tpu.ops.segment_ops import HOST_COMBINE
 
-        k = len(slice_ends)
         leaves = self.agg.leaves
         # device part: per-shard slot matrices over RESIDENT slices (the
         # index only knows resident namespaces), merged raw on device
@@ -2608,9 +2609,10 @@ class MeshWindowEngine(MeshSpillSupport):
             W = sticky_bucket(w_max, getattr(self, "_fire_bucket", 0),
                               minimum=64)
             self._fire_bucket = W
-            sm = np.zeros((self.P, W, k), dtype=np.int32)
+            width = max(mat.shape[1] for mat in per_shard_mats)
+            sm = np.zeros((self.P, W, width), dtype=np.int32)
             for p, mat in enumerate(per_shard_mats):
-                sm[p, : len(mat)] = mat
+                sm[p, : len(mat), : mat.shape[1]] = mat
             merged = self._merge_step(self.accs, self._put_sharded(sm))
             merged_host = self._harvest_get(merged)  # ONE batched D2H
             for p in range(self.P):

@@ -168,6 +168,30 @@ def resolve_slot_hints(index, key_ids: np.ndarray, namespaces: np.ndarray,
     return pre
 
 
+def fire_matrix_width(k: int, fullest: int) -> int:
+    """Columns of the slot matrix a fire is handed where the fullest row
+    of a ``k``-slice window holds ``fullest`` live cells: the next power
+    of two, at least 2 (a stream's first one-slice windows then take the
+    two-column program too, not one of their own) and at most ``k`` — 2,
+    4 or 5 for ``k = 5``. Decided per fire from the matrix alone
+    (``fire_width`` in native/slotmap.cpp is the same rule)."""
+    return min(k, max(2, pad_bucket_size(fullest, minimum=1)))
+
+
+def pack_slot_matrix(matrix: np.ndarray) -> np.ndarray:
+    """``matrix`` as the fire gets it: each row's live cells in its first
+    columns in the order of their slices, zeros behind them, cut to
+    :func:`fire_matrix_width` of the fullest row; ``matrix`` itself where
+    that is all its columns (``carry_copy_out`` in native/slotmap.cpp)."""
+    rows, k = matrix.shape
+    live = matrix != 0
+    width = fire_matrix_width(k, int(live.sum(axis=1).max(initial=0)))
+    if width >= k:
+        return matrix
+    order = np.argsort(~live, axis=1, kind="stable")[:, :width]
+    return np.take_along_axis(matrix, order, axis=1)
+
+
 class _DictSliceCarry:
     """What :meth:`HostSlotIndex.slice_matrix` keeps of the matrix it
     carries: the slices of the last call, each one's list object in the
@@ -189,9 +213,11 @@ class _DictSliceCarry:
         """Drop the ``shift`` leftmost columns (all of them: start from
         nothing), sweep out the rows left empty, then enter ``parts``:
         (column, slots) runs whose keys are ``slot_key[slots]``. Returns
-        the (keys, matrix) and the rows that left: swept out and not
-        brought back by what entered (as ``sm_carry_advance`` counts
-        them; a matrix started from nothing sweeps none)."""
+        the keys, the matrix as the fire gets it (packed and cut:
+        :func:`pack_slot_matrix`; the carry's own keeps a column per
+        slice, the shift depends on it) and the rows that left: swept out
+        and not brought back by what entered (as ``sm_carry_advance``
+        counts them; a matrix started from nothing sweeps none)."""
         left: set = set()
         if shift >= k or self._mat.shape[1] != k:
             keys = np.empty(0, dtype=np.int64)
@@ -228,7 +254,9 @@ class _DictSliceCarry:
             mat[rows, cols] = slots
             left.difference_update(fresh)
         self._keys, self._mat, self._row_of = keys, mat, row_of
-        return keys.copy(), mat.copy(), len(left)
+        packed = pack_slot_matrix(mat)
+        return keys.copy(), (mat.copy() if packed is mat else packed), \
+            len(left)
 
 
 class HostSlotIndex:
@@ -301,10 +329,23 @@ class HostSlotIndex:
 
     def slice_matrix(self, slice_ends
                      ) -> Tuple[np.ndarray, np.ndarray, int]:
-        """``(keys, [rows, k] slot matrix, cells resolved)`` over the
-        slices of a window, in the order given: one row per key that
-        holds a slot in any of them, absent (key, slice) cells at the
-        identity slot 0. Row order is arbitrary.
+        """``(keys, [rows, width] slot matrix, cells resolved)`` over
+        the ``k`` slices of a window: one row per key that holds a slot
+        in any of them, the row's live slots in its first columns and the
+        identity slot 0 behind them. Row order is arbitrary, and **column
+        order is not the slices'**: a row's live cells stand left of
+        every zero (in the order of their slices) and the columns past
+        what the fullest row needs are left off — ``width`` is
+        :func:`fire_matrix_width` of the fullest row, 2 where every key
+        lives in one or two of the slices, ``k`` where some key lives in
+        all of them (the matrix then goes out a column per slice, as it
+        is kept). That is sound because all a fire does with a row is
+        merge its cells with a commutative reduction (``MERGE_FN``: sum,
+        max, min) whose identity slot 0 holds: the same cells in other
+        columns, and fewer identities, reduce to the same value — bit for
+        bit for integers, max and min; a float sum adds the same live
+        values in the same order (a zero adds exactly) but XLA may pair
+        them differently over another width.
 
         The matrix is carried from one call to the next. Where the
         slices asked for are the last call's moved on by some slices (or
@@ -607,6 +648,7 @@ class NativeSlotIndex:
         self._sweep_k = _ct.c_int64()
         self._carry_cells = _ct.c_int64()
         self._carry_removed = _ct.c_int64()
+        self._carry_width = _ct.c_int64()
 
     def _wrap_views(self) -> None:
         cap = int(self._lib.sm_capacity(self._h))
@@ -656,7 +698,12 @@ class NativeSlotIndex:
         (``sm_carry_advance``): the cells that entered are read from each
         slice's own table, from where the carried matrix stopped; a table's
         generation tells a namespace from a later one of the same name,
-        and any per-slot free starts the matrix from nothing.
+        and any per-slot free starts the matrix from nothing. The copy
+        handed out is packed and cut as there (``carry_copy_out``: live
+        cells left of every zero, ``width`` columns, **column order not
+        the slices'** — sound for the fire's commutative merges with the
+        identity at slot 0); the carry's own matrix keeps a column per
+        slice.
 
         "Rows left empty go" costs, per row, a backward-shift delete in
         the carry's key -> row table and the move of the last row into
@@ -676,18 +723,21 @@ class NativeSlotIndex:
         bound = carry.rows + self.pairs_inserted - carry.inserted
         while True:
             keys = np.empty(bound, dtype=np.int64)
-            matrix = np.empty((bound, k), dtype=np.int32)
+            matrix = np.empty(bound * k, dtype=np.int32)
             rows = self._lib.sm_carry_advance(
                 carry.h, self._h, k, ends.ctypes.data_as(_I64P), bound,
                 keys.ctypes.data_as(_I64P), matrix.ctypes.data_as(_I32P),
                 _ct.byref(self._carry_cells),
-                _ct.byref(self._carry_removed))
+                _ct.byref(self._carry_removed),
+                _ct.byref(self._carry_width))
             if rows >= 0:
                 break
             bound = -rows
         carry.rows, carry.inserted = rows, self.pairs_inserted
         self.carry_rows_removed += self._carry_removed.value
-        return keys[:rows], matrix[:rows], self._carry_cells.value
+        width = self._carry_width.value
+        return (keys[:rows], matrix[:rows * width].reshape(rows, width),
+                self._carry_cells.value)
 
     def free_namespaces(self, namespaces: List[int]) -> Optional[np.ndarray]:
         """Release all slots of the given namespaces: each one's table is
@@ -1771,14 +1821,19 @@ class SlotTable:
                 for name, col in jax.device_get(out).items()}
 
     def _pad_fire_matrix(self, slot_matrix: np.ndarray) -> np.ndarray:
-        """Sticky-bucket zero-pad shared by every fire dispatch (sync and
-        async): one padding policy, one compiled-shape family."""
+        """Sticky-bucket zero-pad shared by every fire dispatch (sync,
+        async and hybrid): one padding policy, one compiled-shape family
+        per width of the matrix (``slice_matrix`` cuts it to its fullest
+        row). The cells the program will gather — padded rows times
+        columns, what its device time is proportional to — are stated as
+        a ``fire.gather`` instant."""
         w, k = slot_matrix.shape
         wp = sticky_bucket(w, self._fire_bucket, minimum=64)
         self._fire_bucket = wp
         padded = np.zeros((wp, k), dtype=np.int32)
         padded[:w] = slot_matrix
         self.fire_matrix_bytes += padded.nbytes
+        flight.instant("fire.gather", work=padded.size)
         return padded
 
     def fire_projected(self, slot_matrix: np.ndarray, keys: np.ndarray,
@@ -1841,13 +1896,17 @@ class SlotTable:
     def build_slice_matrix(self, slice_ends: List[int]
                            ) -> Tuple[Optional[np.ndarray],
                                       Optional[np.ndarray], int]:
-        """(keys, [num_keys, k] slot matrix, cells resolved) for the
-        resident slices of a window — missing (key, slice) cells point at
-        the identity slot 0; (None, None, cells) where no key holds a
-        slot. Shared by the device fire path and the hybrid (spill) fire
-        path; the index carries the matrix from one window to the next
-        (``slice_matrix``), and what that cost in rows is stated as two
-        instants: ``carry.rows`` (rows of the matrix returned) and
+        """(keys, [num_keys, width] slot matrix, cells resolved) for the
+        resident slices of a window; (None, None, cells) where no key
+        holds a slot. A row holds its key's live slots in its first
+        columns and the identity slot 0 behind them, and the matrix is as
+        wide as its fullest row needs (``slice_matrix`` of the index):
+        **column order is not the slices'**, which no fire can tell —
+        every merge it knows is commutative and slot 0 holds each leaf's
+        identity. Shared by the device fire path and the hybrid (spill)
+        fire path; the index carries the matrix from one window to the
+        next, and what that cost in rows is stated as two instants:
+        ``carry.rows`` (rows of the matrix returned) and
         ``carry.removed`` (rows the advance swept out)."""
         removed = self.index.carry_rows_removed
         keys, matrix, cells = self.index.slice_matrix(slice_ends)
@@ -1877,11 +1936,8 @@ class SlotTable:
         # device part
         keys, matrix, _ = self.build_slice_matrix(resident)
         if keys is not None:
-            wp = sticky_bucket(len(keys), self._fire_bucket, minimum=64)
-            self._fire_bucket = wp
-            padded = np.zeros((wp, matrix.shape[1]), dtype=np.int32)
-            padded[:len(keys)] = matrix
-            merged = self.agg._merge_jit(self.accs, jnp.asarray(padded))
+            merged = self.agg._merge_jit(
+                self.accs, jnp.asarray(self._pad_fire_matrix(matrix)))
             key_chunks.append(keys)
             for i, m in enumerate(jax.device_get(merged)):
                 leaf_chunks[i].append(m[:len(keys)])

@@ -539,6 +539,7 @@ struct SliceCarry {
   uint64_t* gens;        // [k] its table's gen
   int64_t* consumed;     // [k] pairs of its keys[] / slots[] entered
   uint64_t thinned;      // the map's count of gap-closing erases then
+  int64_t width;         // columns the last copy-out wrote (tried first)
 };
 
 void carry_grow(SliceCarry* c) {
@@ -586,6 +587,100 @@ void carry_remove_row(SliceCarry* c, int64_t r) {
   c->buckets[carry_bucket_of(c, c->keys[last])] = (int32_t)r;
   c->keys[r] = c->keys[last];
   memcpy(c->mat + r * c->k, c->mat + last * c->k, sizeof(int32_t) * c->k);
+}
+
+// Columns of the matrix a fire is handed where its fullest row holds
+// ``fullest`` live cells of k: the next power of two, at least 2 (so a
+// stream's first one-slice windows take the two-column program too) and
+// at most k. The Python index has the same rule (``fire_matrix_width``,
+// state/slot_table.py); a test holds the two equal.
+inline int64_t fire_width(int64_t k, int64_t fullest) {
+  int64_t w = 2;
+  while (w < fullest) w <<= 1;
+  return w < k ? w : k;
+}
+
+// Live cells of each row of ``mat`` ([rows, k]) packed to the row's left
+// in ``out`` at ``width`` < k columns to the row, in the order of their
+// columns, zeros behind them; returns the fullest row's count, at once
+// where a row holds more than ``width`` (what is written is then of no
+// use). No branch on a cell: each is stored where the next live one goes
+// and stays if it is live; the store past a full row lands in the next
+// row's place, or in the k - width columns ``out`` holds beyond the last.
+// K, W: k and width where the compiler is to know them (0: it is not).
+template <int K, int W>
+int64_t carry_pack_rows(const int32_t* __restrict mat, int64_t rows,
+                        int64_t k_, int64_t width_,
+                        int32_t* __restrict out) {
+  const int64_t k = K ? K : k_, width = W ? W : width_;
+  int64_t fullest = 0;
+  for (int64_t r = 0; r < rows && fullest <= width; r++) {
+    const int32_t* row = mat + r * k;
+    int32_t* dst = out + r * width;
+    for (int64_t j = 0; j < width; j++) dst[j] = 0;
+    int64_t n = 0;
+    for (int64_t j = 0; j < k; j++) {
+      const int32_t slot = row[j];
+      dst[n] = slot;
+      n += slot != 0;
+    }
+    if (n > fullest) fullest = n;
+  }
+  return fullest;
+}
+
+// The same for any (k, width): windows of up to eight slices get the loop
+// unrolled for theirs — 0.28 ms for 149,000 rows of 5 where the
+// runtime-sized loop takes 0.95 and a memcpy of them 0.22 (one core of
+// the build sandbox) — any other the runtime-sized one.
+int64_t carry_pack(const int32_t* mat, int64_t rows, int64_t k,
+                   int64_t width, int32_t* out) {
+  switch (k * 16 + width) {  // width < k is 2 or 4 here: no two cases meet
+#define PACK(K, W)     \
+  case K * 16 + W:     \
+    return carry_pack_rows<K, W>(mat, rows, k, width, out);
+    PACK(3, 2) PACK(4, 2) PACK(5, 2) PACK(6, 2) PACK(7, 2) PACK(8, 2)
+    PACK(5, 4) PACK(6, 4) PACK(7, 4) PACK(8, 4)
+#undef PACK
+    default:
+      return carry_pack_rows<0, 0>(mat, rows, k, width, out);
+  }
+}
+
+// The carry's matrix as the fire gets it: each row's live cells in its
+// first columns, in the order of their slices, zeros behind them, in
+// ``out`` at fire_width(k, fullest row) columns to the row; returns that
+// width. The fire merges a row's cells with a commutative reduction and
+// slot 0 holds the identity, so which column a cell stands in means
+// nothing to it, and a column no row fills is not gathered at all. Where
+// that width is k (a row holding all k cells is met: among the first few
+// under keys that live all run) the matrix goes out as it is. The width
+// the last call found is tried first, so a steady stream is packed in
+// one pass; whatever it was, what is written depends on the matrix alone.
+int64_t carry_copy_out(SliceCarry* c, int32_t* out) {
+  const int64_t k = c->k, rows = c->rows;
+  int64_t full = 1;  // the fewest live cells under which a row takes k
+  while (fire_width(k, full) < k) full++;
+  int64_t width = c->width > 0 && c->width < k ? c->width : k;
+  for (;;) {
+    int64_t fullest = 0;
+    if (width < k) {
+      fullest = carry_pack(c->mat, rows, k, width, out);
+    } else {
+      for (int64_t r = 0; r < rows && fullest < full; r++) {
+        const int32_t* row = c->mat + r * k;
+        int64_t n = 0;
+        for (int64_t j = 0; j < k; j++) n += row[j] != 0;
+        if (n > fullest) fullest = n;
+      }
+    }
+    const int64_t want = fire_width(k, fullest);
+    if (want == width) break;
+    width = want;
+  }
+  if (width >= k) memcpy(out, c->mat, sizeof(int32_t) * rows * k);
+  c->width = width;
+  return width;
 }
 
 inline uint64_t sweep_hash(int64_t v) {
@@ -1396,7 +1491,8 @@ void sm_flat_fuse(int64_t n, const int32_t* cols, const int32_t* sinv,
 // and all of a new column's, are the cells — no gather through slot_key.
 // The same call from an empty carry is the from-nothing rebuild. Rows stay
 // dense (an emptied row is overwritten by the last one), so row order is
-// arbitrary; columns are the caller's slice order.
+// arbitrary; the carry's own columns are the caller's slice order (the
+// shift depends on it), the matrix handed out is packed (carry_copy_out).
 
 void* sm_carry_create() {
   SliceCarry* c = (SliceCarry*)calloc(1, sizeof(SliceCarry));
@@ -1424,17 +1520,20 @@ void sm_carry_destroy(void* h) {
 // namespace dropped and made again is another) with nothing erased from
 // any table in between, the shift leftmost columns leave and only the
 // cells that entered are probed; anything else starts from nothing.
-// Writes the advanced (keys, matrix) to out_keys / out_mat and returns the
-// rows written, *out_cells = cells entered, *out_removed = rows swept out
-// (their last cell left and nothing that entered brought their key back;
-// a matrix started from nothing sweeps none). The out arrays hold out_rows
-// rows; where that is fewer than the carry's rows + the cells entering,
-// nothing is changed and -(rows needed) is returned. The out arrays are
-// the caller's: the carry never touches them again.
+// Writes the advanced keys to out_keys and the matrix, as the fire gets it
+// (carry_copy_out above: live cells packed to the left of each row, cut
+// to *out_width columns, rows contiguous at that width), to out_mat, and
+// returns the rows written, *out_cells = cells entered, *out_removed =
+// rows swept out (their last cell left and nothing that entered brought
+// their key back; a matrix started from nothing sweeps none). The out
+// arrays hold out_rows rows of k columns; where that is fewer than the
+// carry's rows + the cells entering, nothing is changed and -(rows
+// needed) is returned. The out arrays are the caller's: the carry never
+// touches them again.
 int64_t sm_carry_advance(void* h, void* map, int64_t k, const int64_t* ends,
                          int64_t out_rows, int64_t* out_keys,
                          int32_t* out_mat, int64_t* out_cells,
-                         int64_t* out_removed) {
+                         int64_t* out_removed, int64_t* out_width) {
   SliceCarry* c = (SliceCarry*)h;
   const SlotMap* m = (const SlotMap*)map;
   int64_t shift = k;
@@ -1556,7 +1655,7 @@ int64_t sm_carry_advance(void* h, void* map, int64_t k, const int64_t* ends,
     }
   }
   memcpy(out_keys, c->keys, sizeof(int64_t) * c->rows);
-  memcpy(out_mat, c->mat, sizeof(int32_t) * c->rows * k);
+  *out_width = carry_copy_out(c, out_mat);
   return c->rows;
 }
 

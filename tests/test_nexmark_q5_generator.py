@@ -13,6 +13,7 @@ from benchmark.harness import manifest, runner
 from benchmark.harness.traffic import TimedSource
 from benchmark.jobs import q5_generator as q5g
 from benchmark.jobs._hash import splitmix64
+from tests.test_fire_width import assert_gathers
 
 MAN = manifest.manifest()
 CONFIG = manifest.config(MAN, "nexmark-q5-generator")
@@ -334,6 +335,7 @@ def test_the_fire_spans_count_cells_rows_and_removals():
     sink, _, log = run_job(cfg, seed, 180_000)
     totals = rec.kind_totals()
     records = [r for r in rec.snapshot() if r.kind == "carry.rows"]
+    gathered = [r for r in rec.snapshot() if r.kind == "fire.gather"]
     rec.clear()
     assert "xla.compile" not in totals
     fires = len(np.unique(sink.result()["window_end"]))
@@ -355,3 +357,9 @@ def test_the_fire_spans_count_cells_rows_and_removals():
     assert totals["carry.removed"]["work"] \
         == len(np.unique(stream)) - per_window[-1]
     assert fires == log.events // 23_000 + 4
+    # an auction lives in one slice, or two where it straddles a boundary:
+    # every fire is handed two columns of the window's five
+    assert totals["fire.gather"]["count"] == fires
+    for r, n in zip(gathered, per_window):
+        assert_gathers(r.work, n, 2)
+        assert r.instant and r.parent == "fire.dispatch"

@@ -16,6 +16,7 @@ from benchmark.jobs import q5_generator as q5g
 from benchmark.jobs import q5_late
 from benchmark.jobs._hash import splitmix64
 from flink_tpu.observe import flight_recorder as flight
+from tests.test_fire_width import assert_gathers
 
 MAN = manifest.manifest()
 CONFIG = manifest.config(MAN, "nexmark-q5-late")
@@ -94,6 +95,15 @@ def test_the_last_emission_of_every_window_equals_the_reference(seed, batch):
     assert work["late.records"] == late_records_by_hand(seed, o, log)
     assert 0.03 < work["late.records"] / log.events < 0.07
     assert work["resolve.sweep"] == log.events      # every batch swept
+    # an auction is bid on for 1,702 bids, a late bid keeps its own
+    # dateTime: no key lives in more than two of a window's five slices,
+    # so every fire, late ones too, is handed two columns
+    records = flight.recorder().snapshot()
+    gathered = [r.work for r in records if r.kind == "fire.gather"]
+    rows = [r.work for r in records if r.kind == "carry.rows"]
+    assert len(gathered) == len(rows) == fires
+    for cells, n in zip(gathered, rows):
+        assert_gathers(cells, n, 2)
 
 
 def test_without_its_allowed_lateness_the_job_is_not_correct():

@@ -10,6 +10,10 @@ re-fires of older windows, drained and re-made namespaces, per-slot
 frees, spill eviction and restore; rows that went empty must vanish; what
 was handed out must never be written again; and the engines' sink rows
 must be bit-identical to a run that rebuilds on every fire.
+
+The matrix handed out is packed: a row's live slots stand left of every
+zero and the columns past what the fullest row needs are left off, so
+rows are compared as multisets of live slots.
 """
 
 import numpy as np
@@ -22,6 +26,7 @@ from flink_tpu.state.slot_table import (
     HostSlotIndex,
     NativeSlotIndex,
     SlotTable,
+    fire_matrix_width,
 )
 from flink_tpu.windowing.aggregates import SumAggregate
 from flink_tpu.windowing.assigners import SlidingEventTimeWindows
@@ -48,20 +53,33 @@ def index_cls(request, monkeypatch):
 
 
 def rebuilt(index, ends):
-    """The window's rows made from nothing, the plain way: one dict."""
+    """The window's rows made from nothing, the plain way: one dict of
+    key -> its live slots (a row is a multiset of them: sorted)."""
     rows = {}
-    for j, ns in enumerate(ends):
+    for ns in ends:
         for slot in index.slots_for_namespace(ns).tolist():
-            rows.setdefault(int(index.slot_key[slot]), [0] * len(ends))[j] \
-                = slot
-    return {(key, *row) for key, row in rows.items()}
+            rows.setdefault(int(index.slot_key[slot]), []).append(slot)
+    return {(key, *sorted(row)) for key, row in rows.items()}
 
 
 def as_rows(keys, matrix):
+    """The matrix's rows as (key, its live slots sorted); holds that live
+    cells stand left of every zero and that the matrix is as wide as its
+    fullest row needs (a full-width matrix may be in slice order)."""
     if keys is None:
         return set()
     assert matrix.shape[0] == len(keys)
-    return {(int(key), *(int(s) for s in row))
+    live = matrix != 0
+    if len(keys):
+        fullest = int(live.sum(axis=1).max())
+        # no wider than that row needs (the rule maps its own result
+        # to itself, whatever number of slices the window has)
+        assert matrix.shape[1] == fire_matrix_width(matrix.shape[1],
+                                                    fullest)
+        if fullest < matrix.shape[1]:
+            assert (live[:, :-1] >= live[:, 1:]).all(), \
+                "a live cell right of a zero"
+    return {(int(key), *sorted(int(s) for s in row if s))
             for key, row in zip(keys, matrix)}
 
 
@@ -107,12 +125,13 @@ class Driver:
         pairs = set()
         for key, *row in got:
             assert any(row), "an all-identity row"
-            for j, slot in enumerate(row):
-                if slot:
-                    assert index.slot_used[slot]
-                    assert index.slot_key[slot] == key
-                    assert index.slot_ns[slot] == resident[j]
-                    pairs.add((key, resident[j]))
+            for slot in row:
+                assert index.slot_used[slot]
+                assert index.slot_key[slot] == key
+                assert index.slot_ns[slot] in resident
+                pairs.add((key, int(index.slot_ns[slot])))
+            assert len({int(index.slot_ns[slot]) for slot in row}) \
+                == len(row), "two cells of one slice in a row"
         assert pairs == {p for p in self.model if p[1] in resident}
         assert len(got) == (0 if keys is None else len(keys))
         live_cells = sum(len(index.slots_for_namespace(e))
@@ -254,7 +273,8 @@ def test_only_what_entered_is_resolved(index_cls):
     assert as_rows(keys, matrix) == rebuilt(index, ends)
     # another number of slices (the hybrid fire's resident subset)
     keys, matrix, cells = index.slice_matrix(ends[1:])
-    assert matrix.shape[1] == K - 1
+    assert matrix.shape[1] == fire_matrix_width(
+        K - 1, int((matrix != 0).sum(axis=1).max()))
     assert as_rows(keys, matrix) == rebuilt(index, ends[1:])
     # nothing live, and no slice at all (every slice of a window spilled)
     keys, matrix, cells = index.slice_matrix([90, 91])
