@@ -167,7 +167,7 @@ class SavepointRequest(_ControlRequest):
 class _SourcePump:
     """Bounded-prefetch source reader: a thread that polls one source,
     assigns timestamps and watermarks, and hands (batch, watermark,
-    position) entries to the task loop through a bounded queue.
+    position, stamp) entries to the task loop through a bounded queue.
 
     The queue bound IS the backpressure (credit-based flow control,
     reference: RemoteInputChannel.java:114 unannouncedCredit — here a
@@ -176,6 +176,14 @@ class _SourcePump:
     exactly the consumed prefix — prefetched-but-unprocessed batches are
     re-read after restore (reference: source offsets ride the same barrier
     as operator state).
+
+    The hand-over is where either side waits for the other, and each
+    says so in the flight recorder on its own thread: the pump at a full
+    queue (``source.wait_loop``), the loop at an empty one
+    (``loop.wait_source``), and per batch the time between
+    ``poll_batch``'s return — the entry's stamp — and the loop taking it
+    (``source.queue_wait``). The stamp then is the origin of everything
+    that batch and its watermark cause (``flight.set_origin``).
 
     The pump owns the source object while running (single-owner
     discipline); the task loop touches the source only after ``stop()``.
@@ -194,6 +202,8 @@ class _SourcePump:
         self._stop = threading.Event()    # stop reading new batches
         self._abort = threading.Event()   # discard mode: puts give up
         self.error: Optional[BaseException] = None
+        #: batches the task loop has taken (a batch's sequence)
+        self.taken = 0
         self._thread = threading.Thread(
             target=self._run, name=f"source-pump-{transformation.name}",
             daemon=True)
@@ -208,12 +218,22 @@ class _SourcePump:
         # consumes
         import queue as _q
 
-        while not self._abort.is_set():
-            try:
-                self.queue.put(item, timeout=0.05)
-                return True
-            except _q.Full:
-                continue
+        if self._abort.is_set():
+            return False
+        try:
+            self.queue.put_nowait(item)
+            return True
+        except _q.Full:
+            pass
+        # the loop has not taken what it was handed: back-pressured
+        with flight.span("source.wait_loop") as span:
+            span.work = 1
+            while not self._abort.is_set():
+                try:
+                    self.queue.put(item, timeout=0.05)
+                    return True
+                except _q.Full:
+                    continue
         return False
 
     def _run(self) -> None:
@@ -224,32 +244,57 @@ class _SourcePump:
                 # batch_size is re-read each poll: the adaptive controller
                 # on the task loop may resize it (benign cross-thread read)
                 batch = src.poll_batch(self.batch_size)
+                stamp = time.perf_counter()
                 if batch is None:
-                    self._put((self._EOS, None, src.snapshot_position()))
+                    self._put((self._EOS, None, src.snapshot_position(),
+                               stamp))
                     return
                 if len(batch) == 0:
                     continue
                 batch = strategy.assign_timestamps(batch)
                 wm = self.wm_gen.on_batch(batch)
                 pos = src.snapshot_position()
-                if not self._put((batch, wm, pos)):
+                if not self._put((batch, wm, pos, stamp)):
                     return
         except BaseException as e:  # noqa: BLE001 - surfaced to task loop
             self.error = e
-            self._put((self._EOS, None, None))
+            self._put((self._EOS, None, None, time.perf_counter()))
+
+    def _take(self, entry):
+        """The task loop takes ``entry``: how long it lay since the source
+        handed it over, and its stamp as the origin of what follows."""
+        batch, wm, _, stamp = entry
+        if batch is not self._EOS:
+            self.taken += 1
+            now = time.perf_counter()
+            # under the watermark the batch brought: the one that fires
+            # what this batch closes
+            flight.instant("source.queue_wait", batch=self.taken,
+                           watermark=flight.WM_NONE if wm is None
+                           else int(wm),
+                           t0=now, duration_s=now - stamp,
+                           work=self.queue.qsize())
+        flight.set_origin(stamp)
+        return entry
 
     def poll(self, timeout: float = 0.0):
         """One queue entry or None. Raises the pump's error, if any."""
         import queue as _q
 
         try:
-            entry = self.queue.get(timeout=timeout) if timeout \
-                else self.queue.get_nowait()
+            entry = self.queue.get_nowait()
         except _q.Empty:
-            return None
+            if not timeout:
+                return None
+            # idle for want of input; a batch found waiting says nothing
+            with flight.span("loop.wait_source"):
+                try:
+                    entry = self.queue.get(timeout=timeout)
+                except _q.Empty:
+                    return None
         if entry[0] is self._EOS and self.error is not None:
             raise self.error
-        return entry
+        return self._take(entry)
 
     def stop_filling(self) -> None:
         """Stop reading new batches; already-queued entries stay consumable
@@ -263,7 +308,7 @@ class _SourcePump:
 
         while self._thread.is_alive() or not self.queue.empty():
             try:
-                yield self.queue.get(timeout=0.05)
+                yield self._take(self.queue.get(timeout=0.05))
             except _q.Empty:
                 continue
 
@@ -686,7 +731,7 @@ class LocalExecutor:
                             timeout=0.002 if not progressed else 0.0)
                         if entry is None:
                             continue
-                        batch, wm, pos = entry
+                        batch, wm, pos, _ = entry
                         if batch is _SourcePump._EOS:
                             active.discard(t.uid)
                             if pos is not None:
@@ -696,6 +741,9 @@ class LocalExecutor:
                             continue
                     else:
                         batch = t.source.poll_batch(batch_size)
+                        # no pump, no queue: the batch (or the end of
+                        # input) is taken as the source hands it over
+                        flight.set_origin(time.perf_counter())
                         if batch is None:
                             active.discard(t.uid)
                             self._emit_watermark(node, MAX_WATERMARK)
@@ -796,7 +844,8 @@ class LocalExecutor:
                         break
                 if not progressed and active and not pumps \
                         and not cooperative:
-                    time.sleep(0.001)
+                    with flight.span("loop.wait_source"):
+                        time.sleep(0.001)
                 yield step_records
             else:
                 suppress_final_drain = False
@@ -1076,7 +1125,7 @@ class LocalExecutor:
                         p = pumps.get(t.uid)
                         if p is not None:
                             p.stop_filling()
-                            for batch, wm, pos in p.consume_remaining():
+                            for batch, wm, pos, _ in p.consume_remaining():
                                 if pos is not None:
                                     source_positions[t.uid] = pos
                                 if batch is _SourcePump._EOS:
@@ -1251,7 +1300,10 @@ class LocalExecutor:
             outs = node.operator.process_watermark(advanced)
         node.busy_s += span.duration_s
         for out in outs:
+            # a synchronous fire: its rows leave with the batch whose
+            # watermark released them
             self._forward(node, out)
+            flight.window_emit(int(advanced))
         if node.operator.has_pending_output():
             # async fires in flight: the watermark must not overtake the
             # results it covers — hold it here; _drain_pending releases it
@@ -1273,8 +1325,12 @@ class LocalExecutor:
                 if op is None:
                     continue
                 if op.has_pending_output():
+                    # each harvest put its fire's (watermark, origin)
+                    # back into the ambient context before yielding:
+                    # the forward and the emission carry that window's
                     for out in op.poll_pending_output(wait=wait):
                         self._forward(node, out)
+                        flight.window_emit()
                 if node.held_wm is not None and not op.has_pending_output():
                     wm = node.held_wm
                     node.held_wm = None

@@ -80,6 +80,36 @@ KNOWN_SPAN_KINDS = (
     "op.watermark",        # executor: one operator's process_watermark
     "emit",                # executor: one output left its operator
                            # (instant — durations belong to op.process)
+    # the waiting between the work: each hand-over between two threads
+    # or between host and device, timed on the side that waits
+    "loop.wait_source",    # the task loop blocked at an empty source
+                           # queue (Flink's idleTime; a turn that finds a
+                           # batch waiting records nothing)
+    "source.wait_loop",    # the source pump blocked at a full queue: the
+                           # source back-pressured by the loop (Flink's
+                           # backPressuredTime; work: 1)
+    "source.queue_wait",   # a batch polled and not yet taken by the loop:
+                           # watermark assignment, the put and the time
+                           # in the queue (externally timed, on the task
+                           # loop, one per batch taken; work: entries
+                           # still queued behind it; batch: its sequence;
+                           # watermark: the one the batch brought)
+    "fire.in_flight",      # a dispatched fire out of the host's hands:
+                           # dispatch -> the start of its harvest (device
+                           # queue, fire program, D2H, the wait to be
+                           # polled; externally timed, one per harvest)
+    "fire.poll_gap",       # of that, the last look that found the fire
+                           # not ready -> the one that found it ready:
+                           # an upper bound on how long the result lay
+                           # landed (externally timed; 0 where the first
+                           # look found it ready, the whole wait where
+                           # the harvest blocks)
+    "window.emit",         # rows a watermark released have left through
+                           # the chain: the origin (poll_batch's return)
+                           # of the batch whose watermark dispatched the
+                           # fire -> the forward's return (externally
+                           # timed, one per fire that produced rows;
+                           # watermark: the fire's)
     # control plane
     "checkpoint.write",
     "checkpoint.restore",

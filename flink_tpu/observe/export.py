@@ -37,6 +37,11 @@ from flink_tpu.observe.flight_recorder import FlightRecorder, SpanRecord
 HOST_TID_BASE = 1000
 
 
+#: the records of one fire, which share its watermark as identifier
+_FIRE_RECORDS = ("fire.dispatch", "fire.in_flight", "fire.harvest",
+                 "window.emit")
+
+
 def _sanitize(kind: str) -> str:
     return kind.replace(".", "_")
 
@@ -122,8 +127,10 @@ def validate_trace_schema(trace: Dict[str, Any],
                           known_kinds) -> List[str]:
     """Schema check of an exported trace: every duration/instant
     event's name is a registered span kind, batch-lifecycle events
-    carry batch attribution, and fire events carry watermark
-    attribution. Returns a list of violations (empty = valid)."""
+    carry batch attribution, and the records of a fire — its dispatch,
+    its time in flight, its harvest and its emission — carry the
+    watermark that fired it. Returns a list of violations (empty =
+    valid)."""
     known = set(known_kinds)
     problems: List[str] = []
     for ev in trace.get("traceEvents", []):
@@ -137,8 +144,8 @@ def validate_trace_schema(trace: Dict[str, Any],
         args = ev.get("args", {})
         if name == "batch.ingest" and args.get("batch", -1) < 0:
             problems.append("batch.ingest without batch attribution")
-        if name == "fire.dispatch" and "watermark" not in args:
-            problems.append("fire.dispatch without watermark")
+        if name in _FIRE_RECORDS and "watermark" not in args:
+            problems.append(f"{name} without watermark")
         if ph == "X" and ev.get("dur", 0) < 0:
             problems.append(f"negative duration on {name!r}")
     return problems

@@ -25,7 +25,19 @@ Design constraints, in order:
   inherited from an ambient per-thread context (``set_job`` /
   ``set_batch`` / ``set_watermark``) so the executor names the job
   once, the engine names the batch once, and a harvest three layers
-  down still lands attributed.
+  down still lands attributed. The context also holds the *origin* of
+  the work in hand (``set_origin``: the clock reading at which the
+  source handed over the batch the task loop is processing); a
+  dispatched fire takes ``(watermark, origin)`` with it
+  (:func:`fire_context`) and its harvest, turns later, puts both back,
+  so every record of one result window — harvest, downstream
+  operators, sink, ``window.emit`` — carries that window's watermark.
+- **Waiting is recorded where it is waited.** Each hand-over between
+  two threads or between host and device has a kind of its own, timed
+  on the waiting side: ``loop.wait_source`` / ``source.wait_loop`` /
+  ``source.queue_wait`` at the source queue, ``fire.in_flight`` /
+  ``fire.poll_gap`` between a fire's dispatch and its harvest,
+  ``window.emit`` from the closing batch's origin to the sink's return.
 - **One timeline.** Durations (batch lifecycle, fires, harvests,
   checkpoints, serving lookups) and instants (XLA backend compiles,
   D2H materializations, watchdog deadline misses, armed chaos
@@ -96,7 +108,7 @@ _enabled = os.environ.get("FLINK_TPU_FLIGHT_RECORDER", "1") != "0"
 #: (control-plane spans and instants stay in the recorder only)
 _MIRRORED = frozenset(
     ("op", "batch", "prep", "session", "device", "exchange", "fire", "slice",
-     "sink"))
+     "sink", "loop", "source"))
 #: ``resource.RUSAGE_THREAD`` (Linux); absent elsewhere, where
 #: ``faults=True`` then counts nothing
 _RUSAGE_THREAD = getattr(resource, "RUSAGE_THREAD", None)
@@ -275,6 +287,9 @@ class _ThreadRing:
         self.ctx_job = -1
         self.ctx_batch = -1
         self.ctx_wm = WM_NONE
+        #: perf_counter reading at which the source handed over the
+        #: batch in hand (0.0: none)
+        self.ctx_origin = 0.0
         #: innermost span open on this thread (the spans chain through
         #: ``_outer``: the stack is the contexts themselves)
         self.open: Optional[_SpanCtx] = None
@@ -372,11 +387,15 @@ class FlightRecorder:
     def instant(self, kind: str, shard: int = -1, batch: int = -1,
                 watermark: int = WM_NONE, job: Optional[str] = None,
                 t0: Optional[float] = None,
-                duration_s: float = 0.0, work: int = 0) -> None:
+                duration_s: float = 0.0, work: int = 0,
+                timed: bool = False) -> None:
         """Record an instant event (or a short externally-timed span,
         e.g. an XLA compile whose duration arrives via monitoring:
         pass ``duration_s`` and it lands as ``[now - d, now]``, a child
-        of the span open on this thread, clipped to it)."""
+        of the span open on this thread, clipped to it). ``timed``
+        says the record is a measurement whatever it reads: a
+        ``duration_s`` of 0 then is a sample of 0 in the kind's
+        aggregates and quantiles, not an instant."""
         if not _enabled:
             return
         ring = self._ring()
@@ -389,7 +408,7 @@ class FlightRecorder:
                 outer._child += max(
                     min(duration_s, now - outer._t0), 0.0)
         ring.write(
-            self._kind_id[kind], 0 if duration_s > 0.0 else 1,
+            self._kind_id[kind], 0 if duration_s > 0.0 or timed else 1,
             now - duration_s, now,
             self.job_id(job) if job is not None else ring.ctx_job,
             shard,
@@ -421,6 +440,9 @@ class FlightRecorder:
 
     def set_watermark(self, wm: int) -> None:
         self._ring().ctx_wm = int(wm)
+
+    def set_origin(self, t: float) -> None:
+        self._ring().ctx_origin = t
 
     # ------------------------------------------------------------- reading
 
@@ -571,12 +593,12 @@ def span(kind: str, shard: int = -1, batch: int = -1,
 def instant(kind: str, shard: int = -1, batch: int = -1,
             watermark: int = WM_NONE, job: Optional[str] = None,
             t0: Optional[float] = None, duration_s: float = 0.0,
-            work: int = 0) -> None:
+            work: int = 0, timed: bool = False) -> None:
     if not _enabled:
         return
     recorder().instant(kind, shard=shard, batch=batch,
                        watermark=watermark, job=job, t0=t0,
-                       duration_s=duration_s, work=work)
+                       duration_s=duration_s, work=work, timed=timed)
 
 
 def set_job(name: Optional[str]) -> None:
@@ -592,6 +614,46 @@ def set_batch(batch_id: int) -> None:
 def set_watermark(wm: int) -> None:
     if _enabled:
         recorder().set_watermark(wm)
+
+
+def set_origin(t: float) -> None:
+    """Note the origin of the work in hand: the ``perf_counter``
+    reading at which the source handed over the batch the thread is
+    about to process."""
+    if _enabled:
+        recorder().set_origin(t)
+
+
+def fire_context():
+    """``(watermark, origin)`` of the thread's ambient context: what a
+    fire takes with it at dispatch, for its harvest to put back."""
+    if not _enabled:
+        return WM_NONE, 0.0
+    ring = recorder()._ring()
+    return ring.ctx_wm, ring.ctx_origin
+
+
+def set_fire_context(watermark: int, origin: float) -> None:
+    """Put a fire's ``(watermark, origin)`` back into the thread's
+    ambient context: its harvest and what that forwards carry them."""
+    if _enabled:
+        ring = recorder()._ring()
+        ring.ctx_wm = watermark
+        ring.ctx_origin = origin
+
+
+def window_emit(watermark: int = WM_NONE) -> None:
+    """THE emission contract: rows a watermark released have left
+    through the chain — record ``window.emit``, the ambient origin ->
+    now, under ``watermark`` (default: the ambient one, which a harvest
+    set to its fire's). Nothing where no origin is known."""
+    if not _enabled:
+        return
+    origin = recorder()._ring().ctx_origin
+    if origin > 0.0:
+        now = time.perf_counter()
+        instant("window.emit", watermark=watermark, t0=now,
+                duration_s=now - origin)
 
 
 def ingest_span(seq: int):

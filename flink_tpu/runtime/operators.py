@@ -248,9 +248,10 @@ class WindowAggOperator(Operator):
         #: dispatched-but-unharvested fires (FIFO; see poll_pending_output)
         self._pending = deque()
         self._async_fires = False
-        #: bound on in-flight fires: beyond it the oldest is harvested
-        #: synchronously (backpressure — pending results are small, but a
-        #: catch-up burst firing hundreds of windows must not hoard buffers)
+        #: bound on in-flight fires: past it the next poll harvests the
+        #: oldest without waiting for them to land (backpressure — pending
+        #: results are small, but a catch-up burst firing hundreds of
+        #: windows must not hoard buffers)
         self._max_pending = 32
         #: per-batch dispatch fences bounding how far the host runs ahead
         #: of the device queue — keeps fire kernels (and their latency)
@@ -480,28 +481,55 @@ class WindowAggOperator(Operator):
             # sample per fire-to-harvest span
             self.fire_latencies_ms.append((_time.perf_counter() - t0) * 1e3)
             self.fires_total += 1
-        while len(self._pending) > self._max_pending:
-            outs.extend(self._harvest_one())
         return outs
 
     def has_pending_output(self) -> bool:
         return bool(self._pending)
 
     def poll_pending_output(self, wait: bool = False):
-        outs = []
-        while self._pending:
-            if not wait and not self._pending[0].ready():
-                break
-            outs.extend(self._harvest_one())
-        return outs
+        """Harvest the fires that have landed, oldest first, yielding each
+        one's result with that fire's ``(watermark, origin)`` in the
+        flight recorder's ambient context: the caller forwards it before
+        it asks for the next. With ``wait``, and past the bound on
+        pending fires, the harvest waits for its fire."""
+        import time as _time
 
-    def _harvest_one(self) -> List[RecordBatch]:
+        while self._pending:
+            block = wait or len(self._pending) > self._max_pending
+            if not block and not self._pending[0].ready():
+                # the device runs its programs in order: what was
+                # dispatched behind a fire that has not landed has not
+                now = _time.perf_counter()
+                for pf in self._pending:
+                    pf.unready_at = now
+                return
+            yield from self._harvest_one(block)
+
+    def _harvest_one(self, block: bool = False) -> List[RecordBatch]:
+        """Pop and harvest the oldest pending fire — the one place a
+        ``PendingFire`` of any engine leaves the queue, and so the one
+        place the wait between its dispatch and its harvest is recorded:
+        ``fire.in_flight`` (dispatch -> here) and, of it, ``fire.poll_gap``
+        (the last look that found it not ready -> here)."""
         import time as _time
 
         pf = self._pending.popleft()
+        waited_from = pf.unready_at
+        if block:
+            if not waited_from:
+                waited_from = _time.perf_counter()
+            pf.wait_ready()
+        flight.set_fire_context(pf.watermark, pf.origin)
+        t_start = _time.perf_counter()
+        flight.instant("fire.in_flight", t0=t_start,
+                       duration_s=t_start - pf.dispatched_at)
+        flight.instant("fire.poll_gap", t0=t_start, timed=True,
+                       duration_s=t_start - waited_from
+                       if waited_from else 0.0)
         batch = pf.harvest()
         # fire latency = watermark advance (dispatch) -> results on host,
-        # the same span the synchronous path measures
+        # the same span the synchronous path measures: fire.in_flight
+        # plus the harvest, off the same dispatch stamp
         self.fire_latencies_ms.append(
             (_time.perf_counter() - pf.dispatched_at) * 1e3)
         self.fires_total += 1

@@ -21,6 +21,7 @@ emitted downstream (see LocalExecutor._drain_pending).
 
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Callable, List, Optional, Sequence
 
@@ -30,18 +31,30 @@ import numpy as np
 class PendingFire:
     """A dispatched-but-unharvested fire: device output buffers (async host
     copies already in flight) plus a host-side finisher that assembles the
-    final result batch once the bytes land."""
+    final result batch once the bytes land. It takes with it the flight
+    recorder's ``(watermark, origin)`` of the thread that dispatched it —
+    the watermark advance that fired the window and the hand-over time of
+    the batch that caused it — so the harvest, turns later, attributes
+    itself and what it forwards to this window and not to whatever the
+    loop processed last."""
 
-    __slots__ = ("arrays", "build", "dispatched_at", "watchdog")
+    __slots__ = ("arrays", "build", "dispatched_at", "watchdog",
+                 "watermark", "origin", "unready_at")
 
     def __init__(self, arrays: Sequence,
                  build: Callable[[List[np.ndarray]], object],
                  watchdog=None):
+        from flink_tpu.observe import flight_recorder as flight
+
         self.arrays = list(arrays)
         self.build = build
         #: optional DeviceWatchdog: the harvest is a deadline-tracked
         #: section (a fire whose D2H never lands is a dead device)
         self.watchdog = watchdog
+        self.watermark, self.origin = flight.fire_context()
+        #: the last time a poll found this fire (or one dispatched before
+        #: it) not ready; 0.0 until a poll has
+        self.unready_at = 0.0
         self.dispatched_at = time.perf_counter()
         for a in self.arrays:
             copy = getattr(a, "copy_to_host_async", None)
@@ -50,8 +63,28 @@ class PendingFire:
 
     def ready(self) -> bool:
         """True when every output buffer's computation has finished (the
-        async host copy then only waits for its DMA)."""
-        return all(a.is_ready() for a in self.arrays)
+        async host copy then only waits for its DMA). A host array is
+        ready as it stands."""
+        for a in self.arrays:
+            is_ready = getattr(a, "is_ready", None)
+            if is_ready is not None and not is_ready():
+                return False
+        return True
+
+    def wait_ready(self) -> None:
+        """Block until ``ready()`` would say yes: what the blocking
+        harvests (a drain, a checkpoint cut, the bound on pending fires)
+        wait for before they start — ahead of the harvest, so that the
+        wait is timed as waiting (``fire.in_flight`` / ``fire.poll_gap``)
+        and not as the harvest's work — under the harvest's own
+        deadline."""
+        section = contextlib.nullcontext() if self.watchdog is None \
+            else self.watchdog.section("pending_harvest")
+        with section:
+            for a in self.arrays:
+                block = getattr(a, "block_until_ready", None)
+                if block is not None:
+                    block()
 
     def harvest(self) -> Optional[object]:
         """Materialize host values and build the result (blocks only on
@@ -69,7 +102,7 @@ class PendingFire:
         # D2H results never land (link loss mid-coalesced-harvest)
         chaos.fault_point("harvest.pending_fire",
                           arrays=len(self.arrays))
-        with flight.span("fire.harvest") as span:
+        with flight.span("fire.harvest", watermark=self.watermark) as span:
             if self.watchdog is not None:
                 with self.watchdog.section("pending_harvest"):
                     host = jax.device_get(self.arrays)

@@ -152,11 +152,27 @@ def test_the_span_budget_per_batch_and_per_fired_window(q5):
     assert sum(kt[k]["count"] for k in ingest_side) <= 8 * BATCHES
     assert sum(kt[k]["count"] for k in fire_side if k in kt) \
         <= 4 * windows + 2 * BATCHES
-    # everything the operator thread times (compiles aside: a warm
-    # process has none): at most 12 spans per batch and 10 per fire
-    spans = sum(1 for r in records
-                if not r.instant and r.kind != "xla.compile")
+    # the waits (PR 37): per batch one queue wait and at most one wait of
+    # the pump, per harvested fire its time in flight, its poll gap and
+    # its emission. The loop's waits are bounded by the time it stood
+    # idle: one that ends with an entry (a batch or the end of input)
+    # comes at most once per entry, every other one lasted its whole 2 ms
+    assert kt["source.queue_wait"]["count"] == BATCHES
+    assert kt.get("source.wait_loop", {"count": 0})["count"] <= BATCHES + 1
+    for kind in ("fire.in_flight", "fire.poll_gap", "window.emit"):
+        assert kt[kind]["count"] == windows, kind
+    idle = kt.get("loop.wait_source", {"count": 0, "total_s": 0.0})
+    assert idle["count"] <= BATCHES + 1 + idle["total_s"] / 0.002
+    # everything the job's threads time (compiles aside: a warm process
+    # has none): at most 12 spans per batch and 10 per fire of work, and
+    # of waiting 3 records more per batch and 3 more per fire
+    waits = ("loop.wait_source", "source.wait_loop", "source.queue_wait",
+             "fire.in_flight", "fire.poll_gap", "window.emit")
+    spans = sum(1 for r in records if not r.instant
+                and r.kind != "xla.compile" and r.kind not in waits)
     assert spans <= 12 * BATCHES + 10 * windows
+    waited = sum(1 for r in records if r.kind in waits)
+    assert waited - idle["count"] <= 2 * BATCHES + 1 + 3 * windows
 
 
 # ------------------------------------------------- the retire's drop, stated
