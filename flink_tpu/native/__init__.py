@@ -348,13 +348,17 @@ def load_sessions() -> Optional[ctypes.CDLL]:
         lib.sx_multi_remove.argtypes = [vp, i64]
         lib.sx_multi_count.restype = i64
         lib.sx_multi_count.argtypes = [vp]
-        lib.sx_absorb.restype = i64
-        lib.sx_absorb.argtypes = [vp, i64, P(i64), P(i64),  # n, keys, ts
-                                  i64, i64, i64, i64,  # gap, late, mfw, sid
-                                  P(i64), P(i64),      # order, rec_to_sess
-                                  P(i64), P(i64), P(i64), P(i64),  # k/s/e/sid
-                                  P(i32), P(i32), P(u8),  # slot/row/flags
-                                  P(i64)]              # out n_fast
+        for absorb in (lib.sx_absorb, lib.sx_absorb_sorted):
+            absorb.restype = i64
+            absorb.argtypes = [vp, i64, P(i64), P(i64),  # n, keys, ts
+                               i64, i64, i64, i64,  # gap, late, mfw, sid
+                               P(i64), P(i64),      # order, rec_to_sess
+                               P(i32),              # rec_sess
+                               P(i64), P(i64), P(i64), P(i64),  # k/s/e/sid
+                               P(i32), P(i32), P(u8),  # slot/row/flags
+                               P(i64)]              # out[5] scalars
+        lib.sx_sorted_maps.restype = None
+        lib.sx_sorted_maps.argtypes = [i64, i64, P(i32), P(i64), P(i64)]
         lib.sx_fold.restype = None
         lib.sx_fold.argtypes = [vp, i64, P(i64), P(i64), P(i32)]
         lib.sx_fold_rows.restype = None
@@ -377,7 +381,7 @@ def load_sessions() -> Optional[ctypes.CDLL]:
                                        P(i64), P(i64), P(u8), P(i32),
                                        P(i32)]
         lib.sx_route.restype = None
-        lib.sx_route.argtypes = [i64, i64, P(i64), P(i64), i64, P(i64),
+        lib.sx_route.argtypes = [i64, i64, P(i32), i64, P(i64),
                                  P(i32), P(i64), P(i32), P(i64)]
         lib.sx_rec_shard_max.restype = i64
         lib.sx_rec_shard_max.argtypes = [i64, P(i64), i64, i64, i64, i64]

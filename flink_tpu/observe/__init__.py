@@ -24,6 +24,10 @@ KNOWN_SPAN_KINDS = (
     "batch.ingest",        # one engine process_batch (host prep + dispatch)
     "prep.meta_sweep",     # session-metadata absorb (native C or Python;
                            # work: sessions the sweep opened)
+    "sweep.grouped",       # a batch the native sweep grouped by key in
+                           # one hash pass, no sort: no key's timestamps
+                           # stepped backwards in it (instant inside
+                           # prep.meta_sweep; work: records)
     "session.merge",       # sessions whose accumulators a batch merged
                            # into another's: the merge kernel and the
                            # absorbed rows' free (work: sessions absorbed;

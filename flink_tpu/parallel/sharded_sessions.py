@@ -361,15 +361,13 @@ class MeshSessionEngine(MeshPagedSpillSupport):
                 res = self.meta.absorb_batch_ex(keys, ts,
                                                 want_fresh=self._paged)
         sess_key, sess_sid = res.sess_key, res.sess_sid
-        rec_to_sess, order, groups = res.rec_to_sess, res.order, res.groups
+        rec_sess, groups = res.rec_sess, res.groups
         for g in groups:
             self._run_merge_group(g)
 
         live_sess = sess_sid >= 0
         if not live_sess.all():
-            starts_pos = np.nonzero(
-                np.diff(rec_to_sess, prepend=-1) > 0)[0]
-            sess_counts = np.diff(np.append(starts_pos, n))
+            sess_counts = np.bincount(rec_sess, minlength=len(sess_key))
             self.meta.late_records_dropped += int(
                 sess_counts[~live_sess].sum())
 
@@ -474,21 +472,18 @@ class MeshSessionEngine(MeshPagedSpillSupport):
         # one C pass on the native plane, numpy otherwise
         rt = getattr(self.meta, "route_records", None)
         if rt is not None:
-            rec_slots, rec_shards = rt(n, order, rec_to_sess, m,
-                                       sorted_idx, slot_sorted,
-                                       sess_shard)
+            rec_slots, rec_shards = rt(rec_sess, m, sorted_idx,
+                                       slot_sorted, sess_shard)
         else:
             if slot_of_sess is None:
                 slot_of_sess = np.zeros(m, dtype=np.int32)
                 slot_of_sess[sorted_idx] = slot_sorted
-            rec_slots = np.empty(n, dtype=np.int32)
-            rec_slots[order] = slot_of_sess[rec_to_sess]
-            rec_shards = np.empty(n, dtype=sess_shard.dtype)
-            rec_shards[order] = sess_shard[rec_to_sess]
+            rec_slots = slot_of_sess[rec_sess]
+            rec_shards = sess_shard[rec_sess]
         if self._hot_keys:
             rec_slots, rec_shards = self._salt_hot_records(
-                keys, ts, sess_key, sess_sid, rec_to_sess, order,
-                rec_slots, rec_shards)
+                keys, ts, sess_key, sess_sid, rec_sess, rec_slots,
+                rec_shards)
         values = self.agg.map_input(batch)
         in_leaves = self.agg.input_leaves
         # pipelining: claim a dispatch slot BEFORE rewriting the pooled
@@ -706,19 +701,18 @@ class MeshSessionEngine(MeshPagedSpillSupport):
                            count=len(self._hot_keys))
 
     def _salt_hot_records(self, keys, ts, sess_key, sess_sid,
-                          rec_to_sess, order, rec_slots, rec_shards):
+                          rec_sess, rec_slots, rec_shards):
         """Ingest diversion: re-point hot keys' records at their salted
         sub-rows. The salt is derived from the record TIMESTAMP
         (splitmix64 mod n_salts) so a replay salts identically — no
         RNG, no per-batch state."""
         hot = self._hot_key_array()
         hot_sess = np.isin(sess_key, hot) & (sess_sid >= 0)
-        j = np.nonzero(hot_sess[rec_to_sess])[0]
-        if not len(j):
+        ridx = np.nonzero(hot_sess[rec_sess])[0]
+        if not len(ridx):
             return rec_slots, rec_shards
-        ridx = order[j]  # original record positions (session-sorted -> raw)
         rk = keys[ridx]
-        rs = sess_sid[rec_to_sess[j]]
+        rs = sess_sid[rec_sess[ridx]]
         nsalts = np.zeros(len(ridx), dtype=np.uint64)
         for hk, hv in self._hot_keys.items():
             nsalts[rk == hk] = np.uint64(hv)

@@ -47,10 +47,18 @@ class NativePlaneError(RuntimeError):
     batch (see MeshSessionEngine._meta_fallback)."""
 
 
-@dataclasses.dataclass
 class AbsorbResult:
     """One absorbed batch, engine-facing: the classic absorb_batch tuple
     plus the per-session columns the state-plane resolve consumes.
+
+    A record's session is held one of two ways, and the other follows
+    on first use: ``rec_sess[i]``, the session of record ``i`` in
+    arrival order (what the native plane's grouped pass yields), or the
+    stable (key, ts) permutation ``order`` with its session column
+    ``rec_to_sess`` (what a sort yields). ``rec_slots =
+    slot_of_sess[rec_sess]`` is one gather; ``rec_slots[order] =
+    slot_of_sess[rec_to_sess]`` says the same with a gather and a
+    scatter.
 
     ``fresh``: sessions CREATED by this absorb that cannot be resident
     or paged in the state plane (skip the hash probe AND the page
@@ -59,19 +67,67 @@ class AbsorbResult:
     metadata before trusting it, so a stale fold costs a fallback
     probe, never a wrong row."""
 
-    sess_key: np.ndarray
-    sess_sid: np.ndarray
-    rec_to_sess: np.ndarray
-    order: np.ndarray
-    groups: List["MergeGroup"]
-    #: None when the caller opted out (want_fresh=False — only the
-    #: paged resolve reads it)
-    fresh: Optional[np.ndarray]
-    slot_hint: Optional[np.ndarray] = None
-    #: native plane: each fast-path session's metadata row, -1 for
-    #: slow/stale sessions — lets note_slots fold by direct array
-    #: scatter instead of a hash pass
-    meta_row: Optional[np.ndarray] = None
+    def __init__(self, sess_key: np.ndarray, sess_sid: np.ndarray,
+                 rec_to_sess: Optional[np.ndarray],
+                 order: Optional[np.ndarray],
+                 groups: List["MergeGroup"],
+                 fresh: Optional[np.ndarray],
+                 slot_hint: Optional[np.ndarray] = None,
+                 meta_row: Optional[np.ndarray] = None,
+                 rec_sess: Optional[np.ndarray] = None,
+                 n_stale: Optional[int] = None) -> None:
+        self.sess_key = sess_key
+        self.sess_sid = sess_sid
+        self._rec_to_sess = rec_to_sess
+        self._order = order
+        self._rec_sess = rec_sess
+        self.groups = groups
+        #: None when the caller opted out (want_fresh=False — only the
+        #: paged resolve reads it)
+        self.fresh = fresh
+        self.slot_hint = slot_hint
+        #: native plane: each fast-path session's metadata row, -1 for
+        #: slow/stale sessions — lets note_slots fold by direct array
+        #: scatter instead of a hash pass
+        self.meta_row = meta_row
+        self._n_stale = n_stale
+
+    @property
+    def n_stale(self) -> int:
+        """Sessions stale on arrival (``sess_sid`` -1), whose records
+        are dropped: counted by the sweep where it can, else here."""
+        if self._n_stale is None:
+            self._n_stale = int(np.count_nonzero(self.sess_sid < 0))
+        return self._n_stale
+
+    @property
+    def rec_sess(self) -> np.ndarray:
+        if self._rec_sess is None:
+            rec_sess = np.empty(len(self._order), dtype=np.int32)
+            rec_sess[self._order] = self._rec_to_sess
+            self._rec_sess = rec_sess
+        return self._rec_sess
+
+    @property
+    def order(self) -> np.ndarray:
+        if self._order is None:
+            self._order, self._rec_to_sess = self._sorted_maps()
+        return self._order
+
+    @property
+    def rec_to_sess(self) -> np.ndarray:
+        if self._rec_to_sess is None:
+            self._order, self._rec_to_sess = self._sorted_maps()
+        return self._rec_to_sess
+
+    def _sorted_maps(self) -> Tuple[np.ndarray, np.ndarray]:
+        """``(order, rec_to_sess)`` from ``rec_sess``. Sessions stand in
+        (key, start) order, so a stable sort of the records by session
+        is the stable (key, ts) permutation wherever each key's records
+        arrived in timestamp order — the only batches whose result
+        comes without the permutation."""
+        order = np.argsort(self._rec_sess, kind="stable")
+        return order, self._rec_sess[order].astype(np.int64)
 
 
 @dataclasses.dataclass

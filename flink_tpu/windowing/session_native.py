@@ -4,9 +4,12 @@
 One C sweep per batch replaces the numpy hot loop of
 :class:`flink_tpu.windowing.session_meta.SessionIntervalSet`:
 
-- **absorb**: stable (key, ts) sort + sessionize + interval-index
-  probe/extend/create + sid allocation + fire-candidate pushes run in
-  ONE native pass over the batch columns (``sx_absorb``). The slow path
+- **absorb**: sessionize + interval-index probe/extend/create + sid
+  allocation + fire-candidate pushes run in ONE native call over the
+  batch columns (``sx_absorb``). A batch whose keys' timestamps never
+  step backwards — any in-order stream — is grouped by key in one hash
+  pass and its few sessions ranked; only a disordered batch pays the
+  stable (key, ts) sort. The slow path
   (keys holding >= 2 live sessions, disjoint second sessions) stays in
   Python with exact reference semantics — the sweep flags those
   sessions and the base class's ``_merge_session`` handles them against
@@ -32,10 +35,11 @@ engine, the way ``make_slot_index`` already does for the state plane.
 from __future__ import annotations
 
 import ctypes as _ct
-from typing import List, Optional, Tuple
+from typing import List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
+from flink_tpu.observe import flight_recorder as flight
 from flink_tpu.windowing.session_meta import (
     AbsorbResult,
     NativePlaneError,
@@ -149,23 +153,63 @@ class _NativeSessionStore:
             self._lib.sx_erase_rows(self._h, len(slots), _i32p(slots))
 
 
-def native_absorb(store: _NativeSessionStore, keys: np.ndarray,
-                  ts: np.ndarray, gap: int, lateness: int,
-                  max_fired_wm: int, next_sid: int):
-    """The raw fused-sweep call: one ``sx_absorb`` per (engine, batch).
+class _GroupedAbsorbResult(AbsorbResult):
+    """An absorb whose batch took the grouped pass: ``rec_sess`` is in
+    hand, ``order`` / ``rec_to_sess`` are made by one native counting
+    pass when someone reads them (``absorb_batch``; neither engine's
+    hot path does) — bit for bit what the sort would have given."""
 
-    Returns ``(m, n_fast, order, rec_to_sess, sess_key, sess_start,
-    sess_end, sess_sid, sess_slot, sess_row, sess_flag)`` with the
-    per-session arrays trimmed to the ``m`` batch-local sessions.
-    ``sess_row`` is each fast-path session's metadata row — the fold
-    writeback is a direct array scatter instead of a hash pass. Rooted
-    in flint's HOT_MODULE_ROOTS — this is a per-batch hot entry point.
-    """
+    def _sorted_maps(self) -> Tuple[np.ndarray, np.ndarray]:
+        from flink_tpu.native import load_sessions
+
+        rec_sess = self._rec_sess
+        n = len(rec_sess)
+        order = np.empty(n, dtype=np.int64)
+        rec_to_sess = np.empty(n, dtype=np.int64)
+        load_sessions().sx_sorted_maps(
+            n, len(self.sess_key), _i32p(rec_sess), _i64p(order),
+            _i64p(rec_to_sess))
+        return order, rec_to_sess
+
+
+class Sweep(NamedTuple):
+    """What one ``sx_absorb`` hands back: the per-session arrays trimmed
+    to the ``m`` batch-local sessions, ascending by (key, start), the
+    records' maps, and the counts the caller would else take over the
+    flag column (each a NumPy call, and a GIL hand-over, of its own)."""
+
+    m: int
+    n_fast: int            # sids the sweep allocated, from next_sid on
+    n_slow: int            # sessions left to the Python merge path
+    n_stale: int           # sessions stale on arrival (sid -1)
+    order: Optional[np.ndarray]        # None after a grouped batch
+    rec_to_sess: Optional[np.ndarray]  # None after a grouped batch
+    rec_sess: np.ndarray   # record i's session, arrival order (int32)
+    sess_key: np.ndarray
+    sess_start: np.ndarray
+    sess_end: np.ndarray
+    sess_sid: np.ndarray
+    sess_slot: np.ndarray  # folded device slot, -1 unknown
+    sess_row: np.ndarray   # metadata row of a fast-path session, else -1
+    sess_flag: np.ndarray
+
+
+#: ``sx_absorb``'s ``out`` array (native/sessions.cpp, OUT_*)
+_OUT_N_FAST, _OUT_GROUPED, _OUT_N_SLOW, _OUT_N_STALE, _OUT_CAPACITY = \
+    range(5)
+
+
+def _absorb_call(entry, store: _NativeSessionStore, keys: np.ndarray,
+                 ts: np.ndarray, gap: int, lateness: int,
+                 max_fired_wm: int, next_sid: int) -> Sweep:
+    """One call of ``sx_absorb`` (or of its sorted twin, which only the
+    parity tests name) with the batch's buffers marshalled."""
     n = len(keys)
     keys = np.ascontiguousarray(keys, dtype=np.int64)
     ts = np.ascontiguousarray(ts, dtype=np.int64)
     order = np.empty(n, dtype=np.int64)
     rec_to_sess = np.empty(n, dtype=np.int64)
+    rec_sess = np.empty(n, dtype=np.int32)
     sess_key = np.empty(n, dtype=np.int64)
     sess_start = np.empty(n, dtype=np.int64)
     sess_end = np.empty(n, dtype=np.int64)
@@ -173,22 +217,46 @@ def native_absorb(store: _NativeSessionStore, keys: np.ndarray,
     sess_slot = np.empty(n, dtype=np.int32)
     sess_row = np.empty(n, dtype=np.int32)
     sess_flag = np.empty(n, dtype=np.uint8)
-    n_fast = _ct.c_int64()
-    m = store._lib.sx_absorb(
+    out = (_ct.c_int64 * 5)()
+    m = entry(
         store._h, n, _i64p(keys), _i64p(ts),
         int(gap), int(lateness), int(max_fired_wm), int(next_sid),
-        _i64p(order), _i64p(rec_to_sess),
+        _i64p(order), _i64p(rec_to_sess), _i32p(rec_sess),
         _i64p(sess_key), _i64p(sess_start), _i64p(sess_end),
         _i64p(sess_sid), _i32p(sess_slot), _i32p(sess_row),
-        sess_flag.ctypes.data_as(_U8P), _ct.byref(n_fast))
+        sess_flag.ctypes.data_as(_U8P), out)
     if m < 0:
         raise NativePlaneError(
             "native session store full during absorb — raise its max "
             "capacity")
-    store._maybe_rewrap()
-    return (int(m), int(n_fast.value), order, rec_to_sess,
-            sess_key[:m], sess_start[:m], sess_end[:m], sess_sid[:m],
-            sess_slot[:m], sess_row[:m], sess_flag[:m])
+    if out[_OUT_CAPACITY] != store.capacity:
+        store._maybe_rewrap()
+    if out[_OUT_GROUPED]:
+        # the grouped pass wrote neither: they are made on demand
+        order = rec_to_sess = None
+    return Sweep(int(m), out[_OUT_N_FAST], out[_OUT_N_SLOW],
+                 out[_OUT_N_STALE], order, rec_to_sess, rec_sess,
+                 sess_key[:m], sess_start[:m], sess_end[:m], sess_sid[:m],
+                 sess_slot[:m], sess_row[:m], sess_flag[:m])
+
+
+def native_absorb(store: _NativeSessionStore, keys: np.ndarray,
+                  ts: np.ndarray, gap: int, lateness: int,
+                  max_fired_wm: int, next_sid: int) -> Sweep:
+    """The raw fused-sweep call: one ``sx_absorb`` per (engine, batch).
+
+    A batch in which no key's timestamps step backwards is grouped by
+    key in one hash pass and never sorted: ``order`` and ``rec_to_sess``
+    then come back ``None`` (``sx_sorted_maps`` makes them from
+    ``rec_sess`` for whoever needs the stable (key, ts) permutation).
+    Any other batch is radix-sorted and scanned, and both come filled.
+    The sweep decides from the batch alone. ``sess_row`` is each
+    fast-path session's metadata row — the fold writeback is a direct
+    array scatter instead of a hash pass. Rooted in flint's
+    HOT_MODULE_ROOTS — this is a per-batch hot entry point.
+    """
+    return _absorb_call(store._lib.sx_absorb, store, keys, ts, gap,
+                        lateness, max_fired_wm, next_sid)
 
 
 def native_pop(store: _NativeSessionStore, watermark: int):
@@ -327,32 +395,35 @@ class NativeSessionIntervalSet(SessionIntervalSet):
 
     def absorb_batch_ex(self, keys: np.ndarray, ts: np.ndarray,
                         want_fresh: bool = True) -> AbsorbResult:
-        # want_fresh is accepted for interface parity and ignored: the
-        # sweep's flag column makes the fresh mask a free compare
-        (m, n_fast, order, rec_to_sess, sess_key, sess_start, sess_end,
-         sess_sid, sess_slot, sess_row, sess_flag) = native_absorb(
+        sw = native_absorb(
             self._store, keys, ts, self.gap, self.allowed_lateness,
             self.max_fired_watermark, self._next_sid)
-        self._next_sid += n_fast
-        # slow path: multi-flavored sessions + disjoint seconds, exact
-        # reference semantics in the base class, ascending (key, ts)
-        slow = np.nonzero(sess_flag == _FLAG_SLOW)[0]
-        if len(slow):
+        self._next_sid += sw.n_fast
+        sess_key, sess_sid = sw.sess_key, sw.sess_sid
+        grouped = sw.order is None
+        if grouped:
+            flight.instant("sweep.grouped", work=len(sw.rec_sess))
+        groups = []
+        if sw.n_slow:
+            # slow path: multi-flavored sessions + disjoint seconds,
+            # exact reference semantics in the base class, ascending
+            # (key, ts)
             self._groups, self._cur = [], None
             self._cur_dst, self._cur_src = set(), set()
-            for j in slow:
+            for j in np.nonzero(sw.sess_flag == _FLAG_SLOW)[0]:
                 sess_sid[j] = self._merge_session(
-                    int(sess_key[j]), int(sess_start[j]),
-                    int(sess_end[j]))
+                    int(sess_key[j]), int(sw.sess_start[j]),
+                    int(sw.sess_end[j]))
             groups = self._groups
             if self._cur is not None and len(self._cur):
                 groups.append(self._cur)
             self._groups, self._cur = [], None
-        else:
-            groups = []
-        return AbsorbResult(sess_key, sess_sid, rec_to_sess, order,
-                            groups, sess_flag == _FLAG_FRESH, sess_slot,
-                            sess_row)
+        return (_GroupedAbsorbResult if grouped else AbsorbResult)(
+            sess_key, sess_sid, sw.rec_to_sess, sw.order, groups,
+            sw.sess_flag == _FLAG_FRESH if want_fresh else None,
+            sw.sess_slot, sw.sess_row, sw.rec_sess,
+            # a session the merge path resolved may have come out stale
+            n_stale=None if sw.n_slow else sw.n_stale)
 
     def absorb_batch(self, keys: np.ndarray, ts: np.ndarray):
         r = self.absorb_batch_ex(keys, ts)
@@ -448,9 +519,12 @@ class NativeSessionIntervalSet(SessionIntervalSet):
         fresh_s = np.empty(m, dtype=np.uint8)
         hint_s = np.empty(m, dtype=np.int32)
         row_s = np.empty(m, dtype=np.int32)
+        # only the paged resolve asks for (and reads) the fresh mask
+        fresh = (res.fresh if res.fresh is not None
+                 else np.zeros(m, dtype=bool))
         nl = int(self._lib.sx_shard_group(
             m, _i64p(res.sess_key), _i64p(res.sess_sid),
-            res.fresh.view(np.uint8).ctypes.data_as(_U8P),
+            fresh.view(np.uint8).ctypes.data_as(_U8P),
             _i32p(res.slot_hint), _i32p(res.meta_row),
             int(P), int(maxp), int(kg_first), int(kg_last),
             _i64p(shard), _i64p(counts), _i64p(sorted_idx),
@@ -479,21 +553,21 @@ class NativeSessionIntervalSet(SessionIntervalSet):
                 "range — upstream routing bug")
         return mx
 
-    def route_records(self, n: int, order: np.ndarray,
-                      rec_to_sess: np.ndarray, m: int,
+    def route_records(self, rec_sess: np.ndarray, m: int,
                       sorted_idx: np.ndarray, slot_sorted: np.ndarray,
                       sess_shard: np.ndarray
                       ) -> Tuple[np.ndarray, np.ndarray]:
         """Record routing in one C pass (sx_route):
-        ``rec[order[i]] = per_session[rec_to_sess[i]]`` for the slot and
-        shard columns, with the resolved slots arriving as the
+        ``rec[i] = per_session[rec_sess[i]]`` for the slot and shard
+        columns, with the resolved slots arriving as the
         (sorted_idx, slot_sorted) pairs the per-shard resolve
         produced."""
+        n = len(rec_sess)
         rec_slots = np.empty(n, dtype=np.int32)
         rec_shards = np.empty(n, dtype=np.int64)
         slot_sorted = np.ascontiguousarray(slot_sorted, dtype=np.int32)
         self._lib.sx_route(
-            int(n), int(m), _i64p(order), _i64p(rec_to_sess),
+            n, int(m), _i32p(rec_sess),
             len(sorted_idx), _i64p(sorted_idx), _i32p(slot_sorted),
             _i64p(sess_shard), _i32p(rec_slots), _i64p(rec_shards))
         return rec_slots, rec_shards

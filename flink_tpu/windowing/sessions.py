@@ -11,8 +11,10 @@ split:
   into :class:`flink_tpu.windowing.session_meta.SessionIntervalSet`, shared
   with the mesh-sharded engine.
 - **Device**: one accumulator slot per live session. Batch-local
-  sessionization is vectorized (lexsort + gap scan); record values scatter
-  straight into their final session slot; merging two sessions is a batched
+  sessionization is one sweep (grouped by key in a hash pass, or sorted
+  and gap-scanned where a key's timestamps step backwards); record
+  values scatter straight into their final session slot; merging two
+  sessions is a batched
   ``acc.at[dst].op(acc[src])`` scatter (duplicate dst allowed — scatter
   reduces), then the absorbed slots reset to identity.
 
@@ -147,7 +149,6 @@ class SessionWindower:
             self._ingest(batch)
 
     def _ingest(self, batch: RecordBatch) -> None:
-        n = len(batch)
         ts = np.asarray(batch.timestamps, dtype=np.int64)
         keys = np.asarray(batch.key_ids, dtype=np.int64)
 
@@ -156,43 +157,48 @@ class SessionWindower:
             res = self.meta.absorb_batch_ex(keys, ts, want_fresh=False)
             sweep.work = self.meta.sid_watermark - opened
         sess_key, sess_sid = res.sess_key, res.sess_sid
-        rec_to_sess, order = res.rec_to_sess, res.order
+        rec_sess = res.rec_sess
         with flight.span("session.merge") as merge:
             for g in res.groups:
                 self._run_merge_group(g)
                 merge.work += len(g.absorbed_sids)
 
-        live_sess = sess_sid >= 0
-        if not live_sess.all():
-            # stale-on-arrival sessions: route their records to slot 0
-            starts_pos = np.nonzero(
-                np.diff(rec_to_sess, prepend=-1) > 0)[0]
-            sess_counts = np.diff(np.append(starts_pos, n))
+        live = None
+        if res.n_stale:
+            # stale-on-arrival sessions: their records go to slot 0
+            live = sess_sid >= 0
+            sess_counts = np.bincount(rec_sess, minlength=len(sess_key))
             self.meta.late_records_dropped += int(
-                sess_counts[~live_sess].sum())
-        # ONE vectorized lookup for all session slots, then scatter
-        # records; the native metadata plane's folded slots skip the
+                sess_counts[~live].sum())
+        # ONE vectorized lookup for all session slots, then one gather
+        # per record; the native metadata plane's folded slots skip the
         # state-table hash probe for sessions whose fold is still valid
         with flight.span("prep.resolve") as resolve:
-            m = len(sess_key)
-            slot_of_sess = np.zeros(m, dtype=np.int32)
-            if live_sess.any():
-                inserted = self.table.index.pairs_inserted
-                slot_of_sess[live_sess] = self.table.lookup_or_insert(
-                    sess_key[live_sess], sess_sid[live_sess],
-                    hints=(None if res.slot_hint is None
-                           else res.slot_hint[live_sess]))
-                resolve.work = self.table.index.pairs_inserted - inserted
-                self.meta.note_slots(sess_key[live_sess],
-                                     sess_sid[live_sess],
-                                     slot_of_sess[live_sess],
-                                     rows=(None if res.meta_row is None
-                                           else res.meta_row[live_sess]))
-            rec_slots = np.empty(n, dtype=np.int32)
-            rec_slots[order] = slot_of_sess[rec_to_sess]
+            inserted = self.table.index.pairs_inserted
+            if live is None:
+                slot_of_sess = self._resolve_sessions(
+                    sess_key, sess_sid, res.slot_hint, res.meta_row)
+            else:
+                slot_of_sess = np.zeros(len(sess_key), dtype=np.int32)
+                if live.any():
+                    slot_of_sess[live] = self._resolve_sessions(
+                        sess_key[live], sess_sid[live],
+                        None if res.slot_hint is None
+                        else res.slot_hint[live],
+                        None if res.meta_row is None
+                        else res.meta_row[live])
+            resolve.work = self.table.index.pairs_inserted - inserted
+            rec_slots = slot_of_sess.take(rec_sess)
         with flight.span("prep.stage"):
             values = self.agg.map_input(batch)
         self.table.scatter(rec_slots, values)
+
+    def _resolve_sessions(self, keys, sids, hints, rows) -> np.ndarray:
+        """Slots of live sessions, folded back into their metadata rows
+        for the next batch's resolve."""
+        slots = self.table.lookup_or_insert(keys, sids, hints=hints)
+        self.meta.note_slots(keys, sids, slots, rows=rows)
+        return slots
 
     def _run_merge_group(self, g: MergeGroup) -> None:
         """Resolve a chain-free merge group's slots and move accumulators

@@ -61,6 +61,13 @@ struct Chunk {
   int64_t lo = 0, hi = 0;
 };
 
+// one bucket of the grouped pass's batch-local table (16 bytes)
+struct GroupEntry {
+  int64_t key;
+  uint32_t stamp;  // the batch that wrote it; any other reads as empty
+  int32_t sess;    // the key's newest local session
+};
+
 struct SessionSet {
   // ------------------------------------------------------- singles store
   int64_t capacity = 0;      // row capacity (row 0 is a normal row here)
@@ -93,6 +100,17 @@ struct SessionSet {
   std::vector<int64_t> si0, si1;
   std::vector<int64_t> fa_e, fa_k, fa_s, fb_e, fb_k, fb_s;
   std::vector<int32_t> fa_r, fb_r;
+  // --------------------------------------------- grouped-pass scratch
+  // batch-local key -> the key's current local session; an entry is
+  // live only while its stamp is the batch's, so the table is never
+  // cleared and keeps its size from batch to batch
+  std::vector<GroupEntry> g_tab;
+  uint32_t g_stamp = 0;
+  // local sessions in the order they opened: key, first ts, last ts
+  std::vector<int64_t> l_key, l_start, l_last;
+  std::vector<uint64_t> gv0, gv1;   // rank: biased keys
+  std::vector<int32_t> gi0, gi1;    // rank: local session numbers
+  std::vector<int32_t> l_rank;      // local session -> its rank
 };
 
 // ------------------------------------------------------------- row hash
@@ -332,6 +350,207 @@ void sort_order(SessionSet* m, const int64_t* keys, const int64_t* ts,
   }
 }
 
+// ------------------------------------------------- sessionize one batch
+//
+// Two ways to the same arrays. Both yield the batch-local sessions in
+// ascending (key, start) order — sess_key / sess_start / sess_end — and
+// rec_sess[i], the session of record i.
+
+// SORTED: stable (key, ts) argsort, then a gap scan over the sorted
+// stream. Takes any batch; also yields the permutation itself (order)
+// and its session column (rec_to_sess).
+int64_t sessionize_sorted(SessionSet* m, const int64_t* keys,
+                          const int64_t* ts, int64_t n, int64_t gap,
+                          int64_t* order, int64_t* rec_to_sess,
+                          int32_t* rec_sess, int64_t* sess_key,
+                          int64_t* sess_start, int64_t* sess_end) {
+  sort_order(m, keys, ts, n, order);
+  int64_t ms = 0;
+  int64_t prev_key = 0, prev_ts = 0;
+  for (int64_t i = 0; i < n; i++) {
+    int64_t at = order[i];
+    int64_t k = keys[at];
+    int64_t t = ts[at];
+    if (i == 0 || k != prev_key || t - prev_ts > gap) {
+      sess_key[ms] = k;
+      sess_start[ms] = t;
+      ms++;
+    }
+    sess_end[ms - 1] = t + gap;
+    rec_to_sess[i] = ms - 1;
+    rec_sess[at] = (int32_t)(ms - 1);
+    prev_key = k;
+    prev_ts = t;
+  }
+  return ms;
+}
+
+inline uint64_t group_home(int64_t key, int shift) {
+  // Fibonacci hashing: the product's top bits
+  return ((uint64_t)key * 0x9E3779B97F4A7C15ull) >> shift;
+}
+
+// fourfold, as the slot map's tables grow; the batch's live entries
+// move over, everything else is left behind
+void group_grow(SessionSet* m) {
+  std::vector<GroupEntry> old;
+  old.swap(m->g_tab);
+  m->g_tab.assign(old.size() * 4, GroupEntry{0, 0, 0});
+  uint64_t mask = m->g_tab.size() - 1;
+  int shift = 64 - __builtin_ctzll(m->g_tab.size());
+  for (const GroupEntry& e : old) {
+    if (e.stamp != m->g_stamp) continue;
+    uint64_t b = group_home(e.key, shift);
+    while (m->g_tab[b].stamp == m->g_stamp) b = (b + 1) & mask;
+    m->g_tab[b] = e;
+  }
+}
+
+// Stable rank of the local sessions by key (LSD radix over the keys
+// less their minimum, digits of at most 12 bits). A key's sessions
+// opened in ascending start order, so stable by key IS (key, start).
+// Writes the session arrays in rank order and l_rank[local] = rank.
+void rank_local_sessions(SessionSet* m, int64_t ml, int64_t gap,
+                         int64_t* sess_key, int64_t* sess_start,
+                         int64_t* sess_end) {
+  const int64_t* lk = m->l_key.data();
+  int64_t kmin = lk[0], kmax = lk[0];
+  bool ascending = true;
+  for (int64_t j = 1; j < ml; j++) {
+    if (lk[j] < lk[j - 1]) ascending = false;
+    if (lk[j] < kmin) kmin = lk[j];
+    if (lk[j] > kmax) kmax = lk[j];
+  }
+  m->l_rank.resize(ml);
+  m->gi0.resize(ml);
+  int32_t* idx = m->gi0.data();
+  for (int64_t j = 0; j < ml; j++) idx[j] = (int32_t)j;
+  if (!ascending) {
+    uint64_t span = (uint64_t)kmax - (uint64_t)kmin;
+    int bits = 64 - __builtin_clzll(span);  // span > 0 here
+    int passes = (bits + 11) / 12;
+    int digit = (bits + passes - 1) / passes;
+    uint64_t dmask = ((uint64_t)1 << digit) - 1;
+    m->gv0.resize(ml);
+    m->gv1.resize(ml);
+    m->gi1.resize(ml);
+    uint64_t* a = m->gv0.data();
+    uint64_t* b = m->gv1.data();
+    int32_t* ib = m->gi1.data();
+    for (int64_t j = 0; j < ml; j++) a[j] = (uint64_t)lk[j] - (uint64_t)kmin;
+    int32_t count[1 << 12];
+    for (int pass = 0; pass < passes; pass++) {
+      int shift = pass * digit;
+      memset(count, 0, sizeof(int32_t) << digit);
+      for (int64_t j = 0; j < ml; j++) count[(a[j] >> shift) & dmask]++;
+      int32_t total = 0;
+      for (uint64_t d = 0; d <= dmask; d++) {
+        int32_t c = count[d];
+        count[d] = total;
+        total += c;
+      }
+      for (int64_t j = 0; j < ml; j++) {
+        int32_t pos = count[(a[j] >> shift) & dmask]++;
+        b[pos] = a[j];
+        ib[pos] = idx[j];
+      }
+      std::swap(a, b);
+      std::swap(idx, ib);
+    }
+  }
+  const int64_t* ls = m->l_start.data();
+  const int64_t* ll = m->l_last.data();
+  int32_t* rank = m->l_rank.data();
+  for (int64_t r = 0; r < ml; r++) {
+    int32_t j = idx[r];
+    sess_key[r] = lk[j];
+    sess_start[r] = ls[j];
+    sess_end[r] = ll[j] + gap;
+    rank[j] = (int32_t)r;
+  }
+}
+
+// GROUPED: one pass over the batch in arrival order against a
+// batch-local table key -> the key's newest local session. Valid while
+// no key's timestamps step backwards inside the batch — then a key's
+// records stand in stable (key, ts) order as they arrived, and the gap
+// rule applied record by record opens exactly the sessions the sorted
+// scan opens. The pass observes the precondition itself: the first
+// record below its key's last one ends it with -1 and nothing written
+// that the sorted form does not overwrite. Else the session count.
+int64_t sessionize_grouped(SessionSet* m, const int64_t* keys,
+                           const int64_t* ts, int64_t n, int64_t gap,
+                           int32_t* rec_sess, int64_t* sess_key,
+                           int64_t* sess_start, int64_t* sess_end) {
+  if (n > INT32_MAX) return -1;
+  if (m->g_tab.empty()) m->g_tab.assign(1024, GroupEntry{0, 0, 0});
+  if (++m->g_stamp == 0) {  // the stamp came round: forget every entry
+    for (GroupEntry& e : m->g_tab) e.stamp = 0;
+    m->g_stamp = 1;
+  }
+  if ((int64_t)m->l_key.size() < n) {
+    m->l_key.resize(n);
+    m->l_start.resize(n);
+    m->l_last.resize(n);
+  }
+  const uint32_t stamp = m->g_stamp;
+  GroupEntry* tab = m->g_tab.data();
+  uint64_t size = m->g_tab.size();
+  uint64_t mask = size - 1;
+  int shift = 64 - __builtin_ctzll(size);
+  int64_t* lk = m->l_key.data();
+  int64_t* ls = m->l_start.data();
+  int64_t* ll = m->l_last.data();
+  uint64_t n_keys = 0;
+  int32_t ml = 0;
+  for (int64_t i = 0; i < n; i++) {
+    const int64_t k = keys[i];
+    const int64_t t = ts[i];
+    uint64_t b = group_home(k, shift);
+    for (;;) {
+      GroupEntry& e = tab[b];
+      if (e.stamp != stamp) {  // a key the batch has not met
+        if ((n_keys + 1) * 2 > size) {
+          group_grow(m);
+          tab = m->g_tab.data();
+          size = m->g_tab.size();
+          mask = size - 1;
+          shift = 64 - __builtin_ctzll(size);
+          b = group_home(k, shift);
+          continue;
+        }
+        n_keys++;
+        e.key = k;
+        e.stamp = stamp;
+      } else if (e.key != k) {
+        b = (b + 1) & mask;
+        continue;
+      } else {
+        const int32_t s = e.sess;
+        const int64_t last = ll[s];
+        if (t < last) return -1;  // a step backwards: sort instead
+        if (t - last <= gap) {
+          ll[s] = t;
+          rec_sess[i] = s;
+          break;
+        }
+      }
+      // open the key's next local session
+      e.sess = ml;
+      lk[ml] = k;
+      ls[ml] = t;
+      ll[ml] = t;
+      rec_sess[i] = ml;
+      ml++;
+      break;
+    }
+  }
+  rank_local_sessions(m, ml, gap, sess_key, sess_start, sess_end);
+  const int32_t* rank = m->l_rank.data();
+  for (int64_t i = 0; i < n; i++) rec_sess[i] = rank[rec_sess[i]];
+  return ml;
+}
+
 }  // namespace
 
 extern "C" {
@@ -514,14 +733,22 @@ void sx_push_chunk(void* h, int64_t n, const int64_t* ends,
 
 int64_t sx_min_pending(void* h) { return ((SessionSet*)h)->min_pending; }
 
-// The fused absorb sweep — ONE pass over the batch columns doing what
-// the Python plane does in ~a dozen vectorized numpy passes:
+// The fused absorb sweep — ONE foreign call per batch doing what the
+// Python plane does in ~a dozen vectorized numpy passes:
 //
-//   1. stable (key, ts) argsort (radix when the span packs, mirroring
-//      the Python packed-argsort condition — the permutation is
-//      identical either way);
-//   2. sessionize: gap scan over the sorted stream -> batch-local
-//      sessions with (key, min_ts, max_ts + gap);
+//   1. sessionize: the batch-local sessions (key, min_ts, max_ts + gap)
+//      in ascending (key, start) order and each record's session
+//      (rec_sess, arrival order). GROUPED — one hash pass over the batch
+//      as it arrived, then a rank of the sessions, not the records —
+//      wherever no key's timestamps step backwards inside the batch,
+//      which the pass itself observes (sessionize_grouped);
+//   2. else SORTED, as the Python plane does it: stable (key, ts)
+//      argsort (radix when the span packs, mirroring the Python
+//      packed-argsort condition — the permutation is identical either
+//      way) and a gap scan over the sorted stream. Only this form fills
+//      ``order`` / ``rec_to_sess``; after a grouped batch they follow
+//      from rec_sess by one counting pass (sx_sorted_maps), bit for bit
+//      the sort's, for whoever asks. ``out[OUT_GROUPED]`` says which ran;
 //   3. classify + apply per session, ascending:
 //        FRESH    sole local session, key unknown, not stale: insert a
 //                 store row, allocate sid (contiguous block from
@@ -539,36 +766,40 @@ int64_t sx_min_pending(void* h) { return ((SessionSet*)h)->min_pending; }
 // Fire candidates land as two chunks (FRESH then EXTENDED) in exactly
 // the Python plane's push order, so pop order stays bit-identical.
 // Returns the session count m, or -1 when the store hit max capacity.
-int64_t sx_absorb(void* h, int64_t n, const int64_t* keys, const int64_t* ts,
-                  int64_t gap, int64_t lateness, int64_t max_fired_wm,
-                  int64_t next_sid, int64_t* order, int64_t* rec_to_sess,
-                  int64_t* sess_key, int64_t* sess_start, int64_t* sess_end,
-                  int64_t* sess_sid, int32_t* sess_slot, int32_t* sess_row,
-                  uint8_t* sess_flag, int64_t* out_n_fast) {
-  SessionSet* m = (SessionSet*)h;
-  *out_n_fast = 0;
+// ``out`` takes the scalars the caller would else count over the flag
+// column or ask for in a call of its own (each of those is a GIL
+// hand-over on the task loop): sids allocated, whether the batch was
+// grouped, SLOW and STALE sessions, the store's row capacity after.
+enum { OUT_N_FAST, OUT_GROUPED, OUT_N_SLOW, OUT_N_STALE, OUT_CAPACITY };
+
+static int64_t absorb(SessionSet* m, bool try_grouped, int64_t n,
+                      const int64_t* keys, const int64_t* ts, int64_t gap,
+                      int64_t lateness, int64_t max_fired_wm,
+                      int64_t next_sid, int64_t* order,
+                      int64_t* rec_to_sess, int32_t* rec_sess,
+                      int64_t* sess_key, int64_t* sess_start,
+                      int64_t* sess_end, int64_t* sess_sid,
+                      int32_t* sess_slot, int32_t* sess_row,
+                      uint8_t* sess_flag, int64_t* out) {
+  out[OUT_N_FAST] = out[OUT_GROUPED] = out[OUT_N_SLOW] = 0;
+  out[OUT_N_STALE] = 0;
+  out[OUT_CAPACITY] = m->capacity;
   if (n == 0) return 0;
-  sort_order(m, keys, ts, n, order);
-  // sessionize the sorted stream
-  int64_t ms = 0;
-  int64_t prev_key = 0, prev_ts = 0;
-  for (int64_t i = 0; i < n; i++) {
-    int64_t k = keys[order[i]];
-    int64_t t = ts[order[i]];
-    if (i == 0 || k != prev_key || t - prev_ts > gap) {
-      sess_key[ms] = k;
-      sess_start[ms] = t;
-      ms++;
-    }
-    sess_end[ms - 1] = t + gap;
-    rec_to_sess[i] = ms - 1;
-    prev_key = k;
-    prev_ts = t;
+  int64_t ms = -1;
+  if (try_grouped) {
+    ms = sessionize_grouped(m, keys, ts, n, gap, rec_sess, sess_key,
+                            sess_start, sess_end);
+  }
+  if (ms >= 0) {
+    out[OUT_GROUPED] = 1;
+  } else {
+    ms = sessionize_sorted(m, keys, ts, n, gap, order, rec_to_sess,
+                           rec_sess, sess_key, sess_start, sess_end);
   }
   const bool have_wm = max_fired_wm > kNegInf / 2;
   m->fa_e.clear(); m->fa_k.clear(); m->fa_s.clear(); m->fa_r.clear();
   m->fb_e.clear(); m->fb_k.clear(); m->fb_s.clear(); m->fb_r.clear();
-  int64_t n_fast = 0;
+  int64_t n_fast = 0, n_slow = 0, n_stale = 0;
   // chunked software prefetch (the slotmap discipline): the store spans
   // far more than L2 at high cardinality, so the bucket probe and the
   // row verify are each a likely miss. Hash a chunk of session keys up
@@ -636,12 +867,14 @@ int64_t sx_absorb(void* h, int64_t n, const int64_t* keys, const int64_t* ts,
         }
         sess_flag[j] = 2;  // SLOW: disjoint second session of the key
         sess_sid[j] = 0;
+        n_slow++;
         continue;
       }
       if (!multi_contains(m, k)) {
         if (have_wm && sess_end[j] - 1 + lateness <= max_fired_wm) {
           sess_flag[j] = 3;  // STALE on arrival (never stored)
           sess_sid[j] = -1;
+          n_stale++;
           continue;
         }
         int64_t sid = next_sid + n_fast;
@@ -664,6 +897,7 @@ int64_t sx_absorb(void* h, int64_t n, const int64_t* keys, const int64_t* ts,
     }
     sess_flag[j] = 2;  // SLOW: the Python merge path fills the sid
     sess_sid[j] = 0;
+    n_slow++;
   }
   }
   // fire-candidate chunks in the Python plane's push order: the FRESH
@@ -672,8 +906,59 @@ int64_t sx_absorb(void* h, int64_t n, const int64_t* keys, const int64_t* ts,
              m->fa_r.data(), (int64_t)m->fa_e.size());
   push_chunk(m, m->fb_e.data(), m->fb_k.data(), m->fb_s.data(),
              m->fb_r.data(), (int64_t)m->fb_e.size());
-  *out_n_fast = n_fast;
+  out[OUT_N_FAST] = n_fast;
+  out[OUT_N_SLOW] = n_slow;
+  out[OUT_N_STALE] = n_stale;
+  out[OUT_CAPACITY] = m->capacity;
   return ms;
+}
+
+int64_t sx_absorb(void* h, int64_t n, const int64_t* keys, const int64_t* ts,
+                  int64_t gap, int64_t lateness, int64_t max_fired_wm,
+                  int64_t next_sid, int64_t* order, int64_t* rec_to_sess,
+                  int32_t* rec_sess, int64_t* sess_key, int64_t* sess_start,
+                  int64_t* sess_end, int64_t* sess_sid, int32_t* sess_slot,
+                  int32_t* sess_row, uint8_t* sess_flag, int64_t* out) {
+  return absorb((SessionSet*)h, true, n, keys, ts, gap, lateness,
+                max_fired_wm, next_sid, order, rec_to_sess, rec_sess,
+                sess_key, sess_start, sess_end, sess_sid, sess_slot,
+                sess_row, sess_flag, out);
+}
+
+// The same sweep held to the sorted form whatever the batch: what the
+// parity tests compare the grouped pass with. Nothing else calls it.
+int64_t sx_absorb_sorted(void* h, int64_t n, const int64_t* keys,
+                         const int64_t* ts, int64_t gap, int64_t lateness,
+                         int64_t max_fired_wm, int64_t next_sid,
+                         int64_t* order, int64_t* rec_to_sess,
+                         int32_t* rec_sess, int64_t* sess_key,
+                         int64_t* sess_start, int64_t* sess_end,
+                         int64_t* sess_sid, int32_t* sess_slot,
+                         int32_t* sess_row, uint8_t* sess_flag,
+                         int64_t* out) {
+  return absorb((SessionSet*)h, false, n, keys, ts, gap, lateness,
+                max_fired_wm, next_sid, order, rec_to_sess, rec_sess,
+                sess_key, sess_start, sess_end, sess_sid, sess_slot,
+                sess_row, sess_flag, out);
+}
+
+// ``order`` / ``rec_to_sess`` of a grouped batch, from rec_sess alone:
+// the sessions stand in (key, start) order and a key's records arrived
+// in timestamp order, so a record's place in the stable (key, ts)
+// permutation is its session's first place plus the records of that
+// session before it — one counting pass.
+void sx_sorted_maps(int64_t n, int64_t m, const int32_t* rec_sess,
+                    int64_t* order, int64_t* rec_to_sess) {
+  static thread_local std::vector<int64_t> cursor;
+  cursor.assign(m + 1, 0);
+  for (int64_t i = 0; i < n; i++) cursor[rec_sess[i] + 1]++;
+  for (int64_t j = 0; j < m; j++) cursor[j + 1] += cursor[j];
+  for (int64_t i = 0; i < n; i++) {
+    int64_t j = rec_sess[i];
+    int64_t pos = cursor[j]++;
+    order[pos] = i;
+    rec_to_sess[pos] = j;
+  }
 }
 
 // The chunk-bounded watermark cut + validate + remove, in one sweep:
@@ -920,26 +1205,23 @@ int64_t sx_rec_shard_max(int64_t n, const int64_t* keys, int64_t P,
   return mx;
 }
 
-// Record routing: scatter each record's session slot and shard through
-// the sort order — rec[order[i]] = per_session[rec_to_sess[i]] — with
-// the resolved slots arriving as (sorted_idx, slot_sorted) pairs from
-// the per-shard resolve. One pass in C for what took a slot scatter
-// plus two gather+scatter round trips in numpy.
-void sx_route(int64_t n, int64_t m, const int64_t* order,
-              const int64_t* rec_to_sess, int64_t n_live,
-              const int64_t* sorted_idx, const int32_t* slot_sorted,
-              const int64_t* sess_shard, int32_t* out_rec_slots,
-              int64_t* out_rec_shards) {
+// Record routing: each record takes its session's slot and shard —
+// rec[i] = per_session[rec_sess[i]], one sequential pass — with the
+// resolved slots arriving as (sorted_idx, slot_sorted) pairs from the
+// per-shard resolve.
+void sx_route(int64_t n, int64_t m, const int32_t* rec_sess,
+              int64_t n_live, const int64_t* sorted_idx,
+              const int32_t* slot_sorted, const int64_t* sess_shard,
+              int32_t* out_rec_slots, int64_t* out_rec_shards) {
   static thread_local std::vector<int32_t> slot_of;
   slot_of.resize(m);
   std::fill(slot_of.begin(), slot_of.end(), 0);
   for (int64_t i = 0; i < n_live; i++)
     slot_of[sorted_idx[i]] = slot_sorted[i];
   for (int64_t i = 0; i < n; i++) {
-    int64_t j = rec_to_sess[i];
-    int64_t dst = order[i];
-    out_rec_slots[dst] = slot_of[j];
-    out_rec_shards[dst] = sess_shard[j];
+    int64_t j = rec_sess[i];
+    out_rec_slots[i] = slot_of[j];
+    out_rec_shards[i] = sess_shard[j];
   }
 }
 

@@ -308,6 +308,298 @@ class TestMetadataPlaneParity:
         assert a.spill_counters()["rows_evicted"] > 0
 
 
+# ------------------------------------------- grouped pass against the sort
+
+#: shapes of the batch under test; each keeps every key's timestamps
+#: from stepping backwards, so the grouped pass must carry all of them
+GROUPED_SHAPES = ("hot_key", "all_distinct", "one_key", "equal_pairs",
+                  "gap_edges", "key_twice", "wide_keys")
+GROUPED_SIZES = (1, 2, 1000, 131072)
+
+
+def _grouped_batch(shape, n, rng, t0):
+    """``(keys, ts)`` of ``n`` records from event time ``t0`` on,
+    arrival order = event-time order (ties included)."""
+    if shape == "hot_key":
+        # three records in four name one key, the rest 1,000 others
+        keys = np.where(rng.random(n) < 0.75, 7,
+                        rng.integers(1000, 2000, n))
+        ts = t0 + np.sort(rng.integers(0, 3 * GAP, n))
+    elif shape == "all_distinct":
+        keys = rng.permutation(10 * n + 10)[:n]
+        ts = t0 + np.sort(rng.integers(0, 3 * GAP, n))
+    elif shape == "one_key":
+        keys = np.full(n, 42)
+        ts = t0 + np.cumsum(rng.integers(0, GAP // 2, n))
+    elif shape == "equal_pairs":
+        # few keys, few instants: many records share (key, ts)
+        keys = rng.integers(0, 5, n)
+        ts = t0 + np.sort(rng.integers(0, 8, n))
+    elif shape == "wide_keys":
+        # negative keys and the whole int64 range: the rank's bias and
+        # its six digit passes, the sort's comparison form
+        pool = np.concatenate([
+            rng.integers(-(1 << 62), 1 << 62, 40) * 2,
+            [np.iinfo(np.int64).min, np.iinfo(np.int64).max, -1, 0]])
+        keys = pool[rng.integers(0, len(pool), n)]
+        ts = t0 + np.sort(rng.integers(0, 3 * GAP, n))
+    elif shape == "gap_edges":
+        # a key's records exactly GAP and GAP + 1 apart: the first
+        # shares the session (the intervals touch), the second does not
+        keys = rng.integers(0, 3, n)
+        ts = t0 + np.cumsum(rng.choice([0, GAP, GAP + 1], n))
+    else:  # key_twice
+        # every key comes back after more than the gap: two local
+        # sessions of one key, the SLOW class
+        half = max(n // 2, 1)
+        keys = np.concatenate([np.arange(half), np.arange(n - half)])
+        ts = t0 + np.concatenate([
+            rng.integers(0, 5, half).cumsum() % GAP,
+            2 * GAP + 1 + rng.integers(0, 5, n - half).cumsum() % GAP])
+        ts = np.sort(ts)
+    return keys.astype(np.int64), ts.astype(np.int64)
+
+
+def _raw_sweep(entry, meta, keys, ts):
+    """One raw sweep through ``entry`` against ``meta``'s store, every
+    output named, ``order`` / ``rec_to_sess`` made by the counting pass
+    where the grouped pass left them out."""
+    from flink_tpu.windowing.session_native import (
+        _GroupedAbsorbResult,
+        _absorb_call,
+    )
+
+    out = _absorb_call(
+        entry, meta._store, keys, ts, meta.gap, meta.allowed_lateness,
+        meta.max_fired_watermark, meta._next_sid)._asdict()
+    out["grouped"] = out["order"] is None
+    if out["grouped"]:
+        lazy = _GroupedAbsorbResult(
+            out["sess_key"], out["sess_sid"], None, None, [], None,
+            rec_sess=out["rec_sess"])
+        out["order"], out["rec_to_sess"] = lazy.order, lazy.rec_to_sess
+    return out
+
+
+def _history(meta_a, meta_b, rng):
+    """The same three batches and one pop through both planes, so the
+    batch under test meets stored singles (EXTENDED), multi-session
+    keys (SLOW) and a fired watermark (STALE under lateness)."""
+    for step in range(3):
+        n = 600
+        keys = rng.integers(0, 1500, n).astype(np.int64)
+        ts = (step * 150 + rng.integers(0, 400, n)).astype(np.int64)
+        ra = meta_a.absorb_batch_ex(keys, ts)
+        rb = meta_b.absorb_batch_ex(keys, ts)
+        np.testing.assert_array_equal(ra.sess_sid, rb.sess_sid)
+    pa, pb = meta_a.pop_fired_ex(250), meta_b.pop_fired_ex(250)
+    np.testing.assert_array_equal(pa.sids, pb.sids)
+
+
+@needs_native
+@pytest.mark.parametrize("n", GROUPED_SIZES)
+@pytest.mark.parametrize("shape", GROUPED_SHAPES)
+def test_grouped_pass_equals_sorted_path_bit_for_bit(shape, n):
+    """The hash pass + session rank + counting pass against the radix
+    argsort + gap scan, on two stores with the same history: every
+    output array of the sweep equal, dtype and all."""
+    _, nat_cls = _planes()
+    rng = np.random.default_rng(len(shape) * 1000 + n)
+    a, b = nat_cls(GAP, 10), nat_cls(GAP, 10)
+    _history(a, b, rng)
+    keys, ts = _grouped_batch(shape, n, rng, t0=300)
+    got = _raw_sweep(a._lib.sx_absorb, a, keys, ts)
+    want = _raw_sweep(b._lib.sx_absorb_sorted, b, keys, ts)
+    assert got.pop("grouped") and not want.pop("grouped")
+    assert list(got) == list(want)
+    for name in got:
+        x, y = got[name], want[name]
+        assert np.asarray(x).dtype == np.asarray(y).dtype, name
+        np.testing.assert_array_equal(x, y, err_msg=name)
+    # the maps say the same thing three ways
+    np.testing.assert_array_equal(
+        got["rec_sess"][got["order"]], got["rec_to_sess"])
+    from flink_tpu.windowing.session_meta import AbsorbResult
+
+    plain = AbsorbResult(got["sess_key"], got["sess_sid"], None, None, [],
+                         None, rec_sess=got["rec_sess"])
+    np.testing.assert_array_equal(plain.order, got["order"])
+    np.testing.assert_array_equal(plain.rec_to_sess, got["rec_to_sess"])
+    assert plain.rec_to_sess.dtype == got["rec_to_sess"].dtype
+    # the counts the sweep hands back are the flag column's
+    assert got["n_slow"] == np.count_nonzero(got["sess_flag"] == 2)
+    assert got["n_stale"] == np.count_nonzero(got["sess_flag"] == 3) \
+        == np.count_nonzero(got["sess_sid"] < 0)
+    assert got["n_fast"] == np.count_nonzero(got["sess_flag"] == 0)
+    if shape == "key_twice" and n >= 4:
+        assert got["n_slow"] > 0  # SLOW was met
+    # both stores took the same rows and fire candidates
+    assert a.snapshot() == b.snapshot()
+    pa, pb = a.pop_fired_ex(1 << 60), b.pop_fired_ex(1 << 60)
+    for name in ("keys", "starts", "ends", "sids", "slot_hint"):
+        np.testing.assert_array_equal(getattr(pa, name),
+                                      getattr(pb, name), err_msg=name)
+
+
+@needs_native
+def test_grouped_pass_fuzz_over_a_stream():
+    """Sixty in-order batches of mixed shapes through both forms of the
+    sweep with the Python slow path behind them (merges, multi-session
+    keys, pops in between): results, stores and fires stay equal and
+    every batch says it was grouped."""
+    from flink_tpu.observe import flight_recorder as flight
+    from flink_tpu.windowing import session_native
+
+    py_cls, nat_cls = _planes()
+    rng = np.random.default_rng(11)
+    grouped, sorted_, py = nat_cls(GAP, 10), nat_cls(GAP, 10), py_cls(GAP, 10)
+    plain = session_native.native_absorb
+
+    def pick(store, *args):
+        if store is sorted_._store:
+            return session_native._absorb_call(
+                store._lib.sx_absorb_sorted, store, *args)
+        return plain(store, *args)
+
+    rec = flight.recorder()
+    rec.clear()
+    records = 0
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(session_native, "native_absorb", pick)
+        t0 = 0
+        for step in range(60):
+            shape = GROUPED_SHAPES[step % len(GROUPED_SHAPES)]
+            n = int(rng.integers(1, 500))
+            keys, ts = _grouped_batch(shape, n, rng, t0)
+            if shape == "all_distinct":
+                keys %= 40  # collide with what the store holds
+            t0 = int(ts[-1]) - GAP // 2
+            rg = grouped.absorb_batch_ex(keys, ts)
+            rs = sorted_.absorb_batch_ex(keys, ts)
+            rp = py.absorb_batch_ex(keys, ts)
+            records += n
+            assert type(rg) is session_native._GroupedAbsorbResult
+            assert type(rs) is session_native.AbsorbResult
+            for name in ("sess_key", "sess_sid", "rec_sess",
+                         "rec_to_sess", "order", "fresh", "slot_hint",
+                         "meta_row"):
+                np.testing.assert_array_equal(
+                    getattr(rg, name), getattr(rs, name), err_msg=name)
+            for name in ("sess_key", "sess_sid", "rec_sess",
+                         "rec_to_sess", "order"):
+                np.testing.assert_array_equal(
+                    getattr(rg, name), getattr(rp, name), err_msg=name)
+            assert [g.sids_dst for g in rg.groups] == \
+                [g.sids_dst for g in rs.groups] == \
+                [g.sids_dst for g in rp.groups]
+            if step % 4 == 3:
+                wm = t0 - GAP
+                fired = [m.pop_fired_ex(wm) for m in (grouped, sorted_, py)]
+                for name in ("keys", "starts", "ends", "sids"):
+                    np.testing.assert_array_equal(
+                        getattr(fired[0], name), getattr(fired[1], name))
+                    np.testing.assert_array_equal(
+                        getattr(fired[0], name), getattr(fired[2], name))
+    assert grouped.snapshot() == sorted_.snapshot() == py.snapshot()
+    # one instant per batch of the grouped store, none from the other two
+    said = rec.kind_totals()["sweep.grouped"]
+    rec.clear()
+    assert said["count"] == 60 and said["work"] == records
+
+
+@needs_native
+@pytest.mark.parametrize("where", ["first_pair", "middle", "last_pair"])
+def test_a_backward_step_inside_a_key_takes_the_sort(where):
+    """One key's timestamps step backwards once: the sweep sorts (no
+    ``sweep.grouped`` instant, ``order`` in hand) and matches the Python
+    plane; the same batch with the pair swapped back is grouped."""
+    from flink_tpu.observe import flight_recorder as flight
+    from flink_tpu.windowing.session_native import _GroupedAbsorbResult
+
+    py_cls, nat_cls = _planes()
+    rng = np.random.default_rng(5)
+    n = 2000
+    keys, ts = _grouped_batch("hot_key", n, rng, t0=0)
+    hot = np.nonzero(keys == 7)[0]
+    i, j = {"first_pair": hot[:2], "middle": hot[len(hot) // 2:][:2],
+            "last_pair": hot[-2:]}[where]
+    ts[j:] += 1  # make the pair strictly ordered, then swap it
+    back = ts.copy()
+    back[[i, j]] = back[[j, i]]
+    rec = flight.recorder()
+    for stream, want_grouped in ((back, False), (ts, True)):
+        py, nat = py_cls(GAP, 0), nat_cls(GAP, 0)
+        rec.clear()
+        rp = py.absorb_batch_ex(keys, stream)
+        rn = nat.absorb_batch_ex(keys, stream)
+        said = rec.kind_totals().get("sweep.grouped")
+        rec.clear()
+        assert isinstance(rn, _GroupedAbsorbResult) == want_grouped
+        assert (said is not None) == want_grouped
+        if want_grouped:
+            assert said == {**said, "count": 1, "work": n}
+        for name in ("sess_key", "sess_sid", "rec_to_sess", "order",
+                     "rec_sess"):
+            np.testing.assert_array_equal(
+                getattr(rp, name), getattr(rn, name), err_msg=name)
+        assert py.snapshot() == nat.snapshot()
+
+
+@needs_native
+@pytest.mark.parametrize("plane", ["native", "python"])
+def test_ingest_record_slots_equal_the_old_expression(plane):
+    """``rec_slots = slot_of_sess[rec_sess]`` against what ``_ingest``
+    computed before: ``rec_slots[order] = slot_of_sess[rec_to_sess]``,
+    batch by batch, stale sessions (slot 0) included."""
+    from flink_tpu.windowing.aggregates import SumAggregate
+    from flink_tpu.windowing.sessions import SessionWindower
+
+    py_cls, nat_cls = _planes()
+    w = SessionWindower(GAP, SumAggregate("v"), capacity=4096)
+    w.meta = (nat_cls if plane == "native" else py_cls)(GAP, 0)
+    seen = {}
+    absorb, note, scatter = (w.meta.absorb_batch_ex, w.meta.note_slots,
+                             w.table.scatter)
+
+    def absorb_spy(keys, ts, **kw):
+        seen["res"] = absorb(keys, ts, **kw)
+        seen["slot_of_sess"] = np.zeros(len(seen["res"].sess_key),
+                                        dtype=np.int32)
+        return seen["res"]
+
+    def note_spy(keys, sids, slots, rows=None):
+        live = seen["res"].sess_sid >= 0
+        seen["slot_of_sess"][live] = slots
+        return note(keys, sids, slots, rows=rows)
+
+    def scatter_spy(rec_slots, values):
+        res = seen["res"]
+        old = np.empty(len(rec_slots), dtype=np.int32)
+        old[res.order] = seen["slot_of_sess"][res.rec_to_sess]
+        assert rec_slots.dtype == old.dtype
+        np.testing.assert_array_equal(rec_slots, old)
+        seen["checked"] = seen.get("checked", 0) + 1
+        return scatter(rec_slots, values)
+
+    w.meta.absorb_batch_ex = absorb_spy
+    w.meta.note_slots = note_spy
+    w.table.scatter = scatter_spy
+    rng = np.random.default_rng(9)
+    dropped = 0
+    for step in range(12):
+        # in-order batches (grouped on the native plane) and, every
+        # third, a disordered one with stale records behind the fires
+        batch = _traffic(step if step % 3 else max(step - 4, 0), rng,
+                         n=1500, num_keys=400)
+        if step % 3:
+            o = np.argsort(batch.timestamps, kind="stable")
+            batch = batch.take(o)
+        w.process_batch(batch)
+        w.on_watermark(step * 70)
+        dropped = w.late_records_dropped
+    assert seen["checked"] == 12 and dropped > 0
+
+
 # ------------------------------------------------------ chaos coverage
 
 
