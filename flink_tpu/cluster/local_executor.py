@@ -538,7 +538,9 @@ class LocalExecutor:
                                           or None),
                                       watchdog=watchdog,
                                       pane_preagg=self.config.get(
-                                          LatencyOptions.PANE_PREAGG))
+                                          LatencyOptions.PANE_PREAGG),
+                                      incremental_checkpoints=self.config.get(
+                                          CheckpointOptions.INCREMENTAL))
                 op.open(ctx)
             nodes[t.uid] = node
             g = job_group.add_group(f"{t.name}#{t.uid}")
@@ -800,37 +802,54 @@ class LocalExecutor:
                         # before the cut — the bookkeeper already marked
                         # those windows fired, so a snapshot without them
                         # would lose results on restore
-                        self._drain_pending(nodes, wait=True)
-                        with flight.span("checkpoint.write"), traces.span(
+                        # the stall, in its three parts: the loop takes
+                        # no batch and launches no fire until the last
+                        with flight.span("checkpoint.drain") as drain:
+                            drain.work = sum(
+                                n.operator.pending_output_count()
+                                for n in nodes.values()
+                                if n.operator is not None)
+                            self._drain_pending(nodes, wait=True)
+                        with traces.span(
                                 "checkpoint",
                                 f"checkpoint-{checkpoint_count}") as sp:
-                            snap = self.snapshot_all(graph, nodes,
-                                                     source_positions,
-                                                     delta=use_delta)
-                            extra = ({"incremental": True,
-                                      "base": last_written_id}
-                                     if use_delta else None)
-                            new_dir = storage.write_checkpoint(
-                                checkpoint_count, job_name, snap,
-                                extra=extra)
-                            sp.set_attribute("checkpointId", checkpoint_count)
-                            sp.set_attribute("incremental", use_delta)
-                            sp.set_attribute("stateSizeBytes", sum(
-                                e.stat().st_size
-                                for e in os.scandir(new_dir) if e.is_file()))
-                        last_written_id = checkpoint_count
-                        since_full = since_full + 1 if use_delta else 1
-                        if claimed is not None:
-                            claimed.on_checkpoint_complete(new_dir)
-                        # checkpoint durable -> two-phase sinks publish
-                        # (reference: notifyCheckpointComplete -> commit)
-                        for node in nodes.values():
-                            op = node.operator
-                            if op is not None and hasattr(
-                                    op, "notify_checkpoint_complete"):
-                                op.notify_checkpoint_complete(
-                                    checkpoint_count)
-                        storage.retain(self._retained())
+                            # the tables state the bytes they fetch as
+                            # this span's work (flight.add_work)
+                            with flight.span("checkpoint.snapshot"):
+                                snap = self.snapshot_all(graph, nodes,
+                                                         source_positions,
+                                                         delta=use_delta)
+                            with flight.span("checkpoint.write") as write:
+                                extra = ({"incremental": True,
+                                          "base": last_written_id}
+                                         if use_delta else None)
+                                new_dir = storage.write_checkpoint(
+                                    checkpoint_count, job_name, snap,
+                                    extra=extra)
+                                sp.set_attribute("checkpointId",
+                                                 checkpoint_count)
+                                sp.set_attribute("incremental", use_delta)
+                                write.work = sum(
+                                    e.stat().st_size
+                                    for e in os.scandir(new_dir)
+                                    if e.is_file())
+                                sp.set_attribute("stateSizeBytes",
+                                                 write.work)
+                                last_written_id = checkpoint_count
+                                since_full = (since_full + 1 if use_delta
+                                              else 1)
+                                if claimed is not None:
+                                    claimed.on_checkpoint_complete(new_dir)
+                                # checkpoint durable -> two-phase sinks
+                                # publish (reference:
+                                # notifyCheckpointComplete -> commit)
+                                for node in nodes.values():
+                                    op = node.operator
+                                    if op is not None and hasattr(
+                                            op, "notify_checkpoint_complete"):
+                                        op.notify_checkpoint_complete(
+                                            checkpoint_count)
+                                storage.retain(self._retained())
                         last_ckpt = time.time() * 1000
                         batches_since_ckpt = 0
                 if control_queue is not None:

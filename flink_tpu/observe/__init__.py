@@ -115,7 +115,26 @@ KNOWN_SPAN_KINDS = (
                            # timed, one per fire that produced rows;
                            # watermark: the fire's)
     # control plane
-    "checkpoint.write",
+    # a checkpoint on the task loop is three spans in a row; nothing is
+    # ingested, dispatched or fired while they run
+    "checkpoint.drain",    # every in-flight fire waited for and its rows
+                           # forwarded to the sinks before the cut (work:
+                           # fires it waited for)
+    "checkpoint.snapshot", # every operator's state and the source
+                           # positions taken to host arrays (work: bytes
+                           # fetched from the device)
+    "checkpoint.rows",     # rows of keyed state one table's snapshot
+                           # holds: the used rows of a full one, the
+                           # dirty and used rows of a delta (instant
+                           # inside checkpoint.snapshot; work: rows)
+    "checkpoint.tombstones",  # what one table's delta says was freed
+                           # since the last snapshot: namespaces and
+                           # (key, namespace) pairs (instant inside
+                           # checkpoint.snapshot; work: their count;
+                           # absent from a full snapshot)
+    "checkpoint.write",    # the snapshot serialized, compressed, renamed
+                           # into place, older checkpoints retired (work:
+                           # bytes on disk)
     "checkpoint.restore",
     "failover.replay",     # partial-failover bounded replay of one range
     "reshard.handoff",     # live key-group migration between mesh sizes

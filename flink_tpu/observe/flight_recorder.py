@@ -105,10 +105,11 @@ _enabled = os.environ.get("FLINK_TPU_FLIGHT_RECORDER", "1") != "0"
 
 #: first components of the kinds mirrored into a profiler session as
 #: ``flink.<kind>`` rows: the batch / fire lifecycle on the task loop
-#: (control-plane spans and instants stay in the recorder only)
+#: and the checkpoint that stops it (the other control-plane spans and
+#: the instants stay in the recorder only)
 _MIRRORED = frozenset(
     ("op", "batch", "prep", "session", "device", "exchange", "fire", "slice",
-     "sink", "loop", "source"))
+     "sink", "loop", "source", "checkpoint"))
 #: ``resource.RUSAGE_THREAD`` (Linux); absent elsewhere, where
 #: ``faults=True`` then counts nothing
 _RUSAGE_THREAD = getattr(resource, "RUSAGE_THREAD", None)
@@ -599,6 +600,22 @@ def instant(kind: str, shard: int = -1, batch: int = -1,
     recorder().instant(kind, shard=shard, batch=batch,
                        watermark=watermark, job=job, t0=t0,
                        duration_s=duration_s, work=work, timed=timed)
+
+
+def add_work(kind: str, n: int) -> None:
+    """Add ``n`` to the work of the nearest span of ``kind`` open on
+    this thread; nothing where none is. For a callee that knows what
+    was done (bytes a snapshot fetched) under a span its caller owns."""
+    if not _enabled:
+        return
+    rec = recorder()
+    kid = rec._kind_id[kind]
+    ctx = rec._ring().open
+    while ctx is not None:
+        if ctx._kind == kid:
+            ctx.work += n
+            return
+        ctx = ctx._outer
 
 
 def set_job(name: Optional[str]) -> None:

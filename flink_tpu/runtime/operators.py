@@ -71,6 +71,10 @@ class Operator:
     def poll_pending_output(self, wait: bool = False) -> List[RecordBatch]:
         return []
 
+    def pending_output_count(self) -> int:
+        """Fires dispatched and not yet harvested."""
+        return 0
+
     # checkpointing
     def snapshot_state(self) -> Optional[Dict[str, Any]]:
         return None
@@ -107,7 +111,8 @@ class OperatorContext:
                  async_fires: bool = False, max_dispatch_ahead: int = 4,
                  mesh=None, key_group_range=None, memory_manager=None,
                  shuffle_mode: str = "device", watchdog=None,
-                 pane_preagg: bool = True, host_topology=None):
+                 pane_preagg: bool = True, host_topology=None,
+                 incremental_checkpoints: bool = True):
         self.operator_index = operator_index
         self.parallelism = parallelism
         self.max_parallelism = max_parallelism
@@ -144,6 +149,11 @@ class OperatorContext:
         #: latency-tier knob (latency.fire-deadline-ms) lives on the
         #: EXECUTOR, which owns the batch loop and the autoscale policy.
         self.pane_preagg = pane_preagg
+        #: whether a delta snapshot can be asked of this job's operators
+        #: (execution.checkpointing.incremental): a keyed table keeps
+        #: the tombstones of what it frees only while one can. On by
+        #: default: a host that does not say gets the tombstones
+        self.incremental_checkpoints = incremental_checkpoints
 
 
 class MapOperator(Operator):
@@ -343,6 +353,7 @@ class WindowAggOperator(Operator):
                     spill=table_kwargs,
                     fire_projector=self.fire_projector)
         self._resolve_async_fires(ctx)
+        self._resolve_tombstones(ctx)
 
     def _managed_memory(self, ctx):
         """(MemoryManager, unique owner) for device-state accounting, or
@@ -377,6 +388,16 @@ class WindowAggOperator(Operator):
         if placement is not None:
             kwargs["device"] = placement
         return kwargs, placement
+
+    def _resolve_tombstones(self, ctx) -> None:
+        """A job that takes no delta checkpoint
+        (execution.checkpointing.incremental off) keeps no tombstones of
+        what its keyed table frees: nothing would ever read or clear
+        them."""
+        table = getattr(self.windower, "table", None)
+        if hasattr(table, "keep_tombstones"):
+            table.keep_tombstones(
+                getattr(ctx, "incremental_checkpoints", True))
 
     def _resolve_async_fires(self, ctx) -> None:
         """Deferred fire harvesting needs both an engine that can dispatch
@@ -485,6 +506,9 @@ class WindowAggOperator(Operator):
 
     def has_pending_output(self) -> bool:
         return bool(self._pending)
+
+    def pending_output_count(self) -> int:
+        return len(self._pending)
 
     def poll_pending_output(self, wait: bool = False):
         """Harvest the fires that have landed, oldest first, yielding each
@@ -817,6 +841,7 @@ class SessionWindowAggOperator(WindowAggOperator):
                 allowed_lateness=self.allowed_lateness,
                 spill=table_kwargs)
         self._resolve_async_fires(ctx)
+        self._resolve_tombstones(ctx)
 
     def arm_serving_replica(self, publish_interval_ms: float = 0.0):
         """Session form: the adapter composes {session_end -> columns}

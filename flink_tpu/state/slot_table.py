@@ -1264,10 +1264,14 @@ class SlotTable:
         # RocksIncrementalSnapshotStrategy — here a host bitmap of slots
         # touched since the last snapshot + the namespaces freed since)
         self._dirty = np.zeros(self.index.capacity, dtype=bool)
-        self._freed_ns: List[int] = []
+        #: namespaces freed since the last snapshot, as int64 chunks, one
+        #: per free; kept only while a delta can still be asked for
+        #: (keep_tombstones): nothing else reads or clears them
+        self._freed_ns: List[np.ndarray] = []
         #: per-(key, ns) tombstones from TTL expiry (free_slots) — the
         #: entry-granular analog of _freed_ns for incremental snapshots
         self._freed_pairs: List[Tuple[np.ndarray, np.ndarray]] = []
+        self._keep_tombstones = True
         self._gather_bucket = 0
         #: bytes of padded fire slot matrices handed to the device so
         #: far (the windower states the growth over one watermark
@@ -1964,6 +1968,22 @@ class SlotTable:
         return uniq, {name: np.asarray(col)
                       for name, col in finished.items()}
 
+    def keep_tombstones(self, keep: bool) -> None:
+        """Whether a delta snapshot can still be asked of this table. A
+        job that takes none (execution.checkpointing.incremental off)
+        says so once, and the frees of a run that lasts for days leave
+        nothing behind; ``snapshot_delta`` then refuses."""
+        self._keep_tombstones = bool(keep)
+        if not keep:
+            self._freed_ns.clear()
+            self._freed_pairs.clear()
+
+    def _tombstone(self, namespaces) -> None:
+        if self._keep_tombstones and len(namespaces):
+            # a copy: the caller's array may be a view of a buffer it
+            # writes again
+            self._freed_ns.append(np.array(namespaces, dtype=np.int64))
+
     def mark_dirty(self, slots: np.ndarray) -> None:
         """For external kernels that mutate ``accs`` directly (e.g. session
         merges): keep incremental snapshots correct."""
@@ -1974,7 +1994,7 @@ class SlotTable:
         were already neutralized by a caller-owned kernel (session merges).
         Still records tombstones for incremental snapshots."""
         slots = self.index.free_namespaces(namespaces)
-        self._freed_ns.extend(int(n) for n in namespaces)
+        self._tombstone(namespaces)
         if slots is not None:
             self._dirty[slots] = False
         return slots
@@ -1985,8 +2005,7 @@ class SlotTable:
         caller (session merge path) already holds the absorbed rows'
         slots; device values were neutralized by its merge kernel."""
         slots = np.asarray(slots, dtype=np.int32)
-        self._freed_ns.extend(np.asarray(namespaces,
-                                         dtype=np.int64).tolist())
+        self._tombstone(namespaces)
         self.index.free_slots(slots)
         self._dirty[slots] = False
 
@@ -1998,7 +2017,7 @@ class SlotTable:
         if not len(slots):
             return
         nss = np.asarray(namespaces, dtype=np.int64)
-        self._freed_ns.extend(nss.tolist())
+        self._tombstone(nss)
         self._drop_spilled_sessions(nss)
         self.index.free_slots(slots)
         self._dirty[slots] = False
@@ -2017,8 +2036,9 @@ class SlotTable:
         slots = np.asarray(slots, dtype=np.int32)
         if not len(slots):
             return
-        self._freed_pairs.append((self.index.slot_key[slots].copy(),
-                                  self.index.slot_ns[slots].copy()))
+        if self._keep_tombstones:
+            self._freed_pairs.append((self.index.slot_key[slots].copy(),
+                                      self.index.slot_ns[slots].copy()))
         self.index.free_slots(slots)
         self._dirty[slots] = False
         size = sticky_bucket(len(slots), self._reset_bucket)
@@ -2037,7 +2057,7 @@ class SlotTable:
         if self.index.pairs_dropped > dropped:
             flight.instant("retire.drop",
                            work=self.index.pairs_dropped - dropped)
-        self._freed_ns.extend(int(n) for n in namespaces)
+        self._tombstone(namespaces)
         if self._paged:
             self._drop_spilled_sessions(
                 np.asarray(namespaces, dtype=np.int64))
@@ -2227,6 +2247,8 @@ class SlotTable:
         """
         used = self.index.used_slots()
         accs_host = jax.device_get(list(self.accs))  # ONE batched D2H
+        flight.add_work("checkpoint.snapshot",
+                        sum(a.nbytes for a in accs_host))
         key_ids = self.index.slot_key[used]
         out = {
             "key_id": key_ids,
@@ -2267,6 +2289,7 @@ class SlotTable:
             out[f"leaf_{i}"] = np.concatenate(leaf_chunks[i])
         out["key_group"] = assign_key_groups(out["key_id"],
                                              self.max_parallelism)
+        flight.instant("checkpoint.rows", work=len(out["key_id"]))
         if reset_dirty:
             self._dirty[:] = False
             self._freed_ns.clear()
@@ -2280,16 +2303,24 @@ class SlotTable:
         on top of the last full snapshot
         (reference: RocksIncrementalSnapshotStrategy — upload only new SSTs;
         here: transfer only dirty slots off the device)."""
+        if not self._keep_tombstones:
+            raise RuntimeError(
+                "a delta snapshot of a table that keeps no tombstones "
+                "(keep_tombstones(False): the job said it takes no "
+                "incremental checkpoint) would resurrect freed rows")
         dirty_used = np.nonzero(self._dirty & self.index.slot_used)[0] \
             .astype(np.int32)
-        freed = np.asarray(sorted(set(self._freed_ns)), dtype=np.int64)
+        freed = (np.unique(np.concatenate(self._freed_ns))
+                 if self._freed_ns else np.empty(0, dtype=np.int64))
         n = len(dirty_used)
         if n:
             size = sticky_bucket(n, self._gather_bucket)
             self._gather_bucket = size
-            gathered = self.agg._gather_jit(
-                self.accs, jnp.asarray(pad_i32(dirty_used, size, fill=0)))
-            leaves = [g[:n] for g in jax.device_get(gathered)]
+            gathered = jax.device_get(self.agg._gather_jit(
+                self.accs, jnp.asarray(pad_i32(dirty_used, size, fill=0))))
+            flight.add_work("checkpoint.snapshot",
+                            sum(g.nbytes for g in gathered))
+            leaves = [g[:n] for g in gathered]
         else:
             leaves = [np.empty(0, dtype=l.dtype) for l in self.agg.leaves]
         key_ids = self.index.slot_key[dirty_used]
@@ -2339,6 +2370,9 @@ class SlotTable:
             "tombstone_namespace": tomb_n,
             **{f"leaf_{i}": leaves[i] for i in range(len(leaves))},
         }
+        flight.instant("checkpoint.rows", work=len(key_ids))
+        flight.instant("checkpoint.tombstones",
+                       work=len(freed) + len(tomb_k))
         self._dirty[:] = False
         self._freed_ns.clear()
         self._freed_pairs.clear()
@@ -2416,4 +2450,5 @@ class SlotTable:
         # restored state IS the new incremental base
         self._dirty[:] = False
         self._freed_ns.clear()
+        self._freed_pairs.clear()
         self.spill.clear_dirty()

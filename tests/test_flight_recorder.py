@@ -339,7 +339,9 @@ class TestSelfTime:
                 with flight.span("batch.ingest"):
                     with flight.span("prep.resolve"):
                         time.sleep(0.001)
-            with flight.span("checkpoint.write"):
+            with flight.span("checkpoint.write"):   # it stops the loop
+                pass
+            with flight.span("reshard.handoff"):    # control plane: not
                 pass
         finally:
             jax.profiler.stop_trace()
@@ -356,7 +358,9 @@ class TestSelfTime:
                         rows.setdefault(ev.name, []).append(
                             (ev.start_ns, ev.start_ns + ev.duration_ns))
         assert sorted(rows) == ["bench.process_batch",
-                                "flink.batch.ingest", "flink.prep.resolve"]
+                                "flink.batch.ingest",
+                                "flink.checkpoint.write",
+                                "flink.prep.resolve"]
         (bench,), (ingest,), (resolve,) = (
             rows["bench.process_batch"], rows["flink.batch.ingest"],
             rows["flink.prep.resolve"])
