@@ -107,6 +107,25 @@ def _split_state(state: Dict[str, Any]):
     return arrays, {"meta": meta}
 
 
+class SplitStates(dict):
+    """operator uid -> ``(flat arrays, pickled host metadata)``: a snapshot
+    already split as :func:`write_snapshot_dir` writes it. Made by
+    :func:`split_states` on the task loop, so that what a writer thread is
+    handed refers to nothing the loop goes on mutating: the metadata is
+    bytes, and the arrays are the snapshot's own (``snapshot_state`` copies
+    them off live state)."""
+
+
+def split_states(operator_states: Dict[str, Dict[str, Any]]) -> SplitStates:
+    """Each operator's state split into its arrays and its metadata, the
+    metadata pickled — the bytes ``write_snapshot_dir`` writes."""
+    out = SplitStates()
+    for uid, state in operator_states.items():
+        arrays, meta = _split_state(state)
+        out[uid] = (arrays, pickle.dumps(meta))
+    return out
+
+
 def _set_path(d: Dict[str, Any], dotted: str, value: Any) -> None:
     parts = dotted.split(".")
     cur = d
@@ -126,9 +145,13 @@ def _merge(dst: Dict[str, Any], src: Dict[str, Any]) -> None:
 def write_snapshot_dir(final_dir: str, checkpoint_id: int, job_name: str,
                        operator_states: Dict[str, Dict[str, Any]],
                        extra: Optional[Dict[str, Any]] = None,
-                       compress: bool = True) -> str:
+                       compress: bool = True,
+                       timestamp_ms: Optional[int] = None) -> str:
     """Write a self-contained snapshot directory (tmp + atomic rename).
 
+    ``operator_states`` may be :class:`SplitStates` already (a checkpoint
+    persisted off the task loop); ``timestamp_ms`` stamps the manifest
+    (default: now) — such a checkpoint is stamped with its cut's time.
     An existing target is replaced only if it is itself a snapshot directory
     (manifest.json present) or empty — never an arbitrary user directory.
     """
@@ -146,23 +169,25 @@ def write_snapshot_dir(final_dir: str, checkpoint_id: int, job_name: str,
         prefix=f".snap-{checkpoint_id}-", dir=parent)
     try:
         uids = []
-        for uid, state in operator_states.items():
+        split = (operator_states if isinstance(operator_states, SplitStates)
+                 else split_states(operator_states))
+        for uid, (arrays, meta) in split.items():
             uids.append(uid)
-            arrays, meta = _split_state(state)
             if arrays:
                 # compressed by default (reference compresses state with
                 # lz4/snappy, root pom.xml:168,225); np.load autodetects
                 save = np.savez_compressed if compress else np.savez
                 save(os.path.join(tmp_dir, f"op-{uid}.npz"), **arrays)
             with open(os.path.join(tmp_dir, f"op-{uid}.meta.pkl"), "wb") as f:
-                pickle.dump(meta, f)
+                f.write(meta)
         file_crcs = {
             name: _file_crc32(os.path.join(tmp_dir, name))
             for name in sorted(os.listdir(tmp_dir))
         }
         manifest = CheckpointMetadata(
             checkpoint_id=checkpoint_id,
-            timestamp_ms=int(time.time() * 1000),
+            timestamp_ms=(int(time.time() * 1000) if timestamp_ms is None
+                          else timestamp_ms),
             job_name=job_name,
             operator_states=uids,
             extra=extra or {},
@@ -494,10 +519,12 @@ class CheckpointStorage:
 
     def write_checkpoint(self, checkpoint_id: int, job_name: str,
                          operator_states: Dict[str, Dict[str, Any]],
-                         extra: Optional[Dict[str, Any]] = None) -> str:
+                         extra: Optional[Dict[str, Any]] = None,
+                         timestamp_ms: Optional[int] = None) -> str:
         return write_snapshot_dir(self._dir(checkpoint_id), checkpoint_id,
                                   job_name, operator_states, extra,
-                                  compress=self.compress)
+                                  compress=self.compress,
+                                  timestamp_ms=timestamp_ms)
 
     # ------------------------------------------------------------------- read
 

@@ -16,6 +16,7 @@ boundaries: the executor simply snapshots all operators between pushes
 
 from __future__ import annotations
 
+import math
 import os
 import time
 from typing import Any, Dict, List, Optional
@@ -327,6 +328,120 @@ class _SourcePump:
         self._thread.join(timeout=5)
 
 
+class _CheckpointWriter:
+    """The asynchronous part of a checkpoint (reference:
+    ``SubtaskCheckpointCoordinatorImpl`` hands the barrier's snapshot to an
+    ``AsyncCheckpointRunnable`` and the task goes on processing records).
+
+    The task loop takes the cut — the drain, the snapshot, its state made
+    the writer's own (``split_states``: the snapshot's arrays, its metadata
+    pickled) — and hands it to :meth:`submit`. ONE writer thread, started
+    at the first hand-off, persists it (``checkpoint.persist``: the
+    ``.npz`` files with zlib, the CRCs, the manifest, the atomic rename,
+    then ``retain``) while the loop takes batches. At most one persist is
+    in flight. The loop's :meth:`complete` ends it on the loop's own
+    thread — at the top of the turn after it finished, or waiting for it
+    where it must be on disk first (a due cut, a savepoint, the end of
+    input, a failure) — and only then is the checkpoint complete: the
+    next delta's base, the point the interval counts from,
+    ``notify_checkpoint_complete`` delivered (two-phase sinks commit what
+    a durable checkpoint sealed). A persist that raised raises there, as
+    a failed synchronous write did."""
+
+    def __init__(self, storage, job_name: str, retained: int,
+                 base_id: Optional[int], nodes, claimed, traces):
+        self.storage = storage
+        self.job_name = job_name
+        self.retained = retained
+        self.nodes = nodes
+        #: the restored artifact a CLAIM restore deletes once subsumed
+        self.claimed = claimed
+        self.traces = traces
+        #: the newest completed checkpoint, which a delta may name as base
+        self.base_id = base_id
+        #: completed checkpoints since, and with, the last full one
+        self.since_full = 0
+        #: wall ms at which the last checkpoint completed
+        self.completed_ms = time.time() * 1000
+        #: (checkpoint id, delta?, cut wall ms, future) of the persist in
+        #: flight
+        self.pending = None
+        self._pool = None
+
+    def submit(self, checkpoint_id: int, states, extra, delta: bool,
+               cut_ms: float) -> None:
+        if self._pool is None:
+            from concurrent.futures import ThreadPoolExecutor
+
+            self._pool = ThreadPoolExecutor(
+                1, thread_name_prefix="checkpoint-writer")
+        self.pending = (checkpoint_id, delta, cut_ms, self._pool.submit(
+            self._persist, checkpoint_id, states, extra, cut_ms))
+
+    def _persist(self, checkpoint_id: int, states, extra, cut_ms: float):
+        flight.set_job(self.job_name)
+        with flight.span("checkpoint.persist") as persist:
+            # stamped with the cut's time: what the sinks held when it
+            # was taken was written before it
+            new_dir = self.storage.write_checkpoint(
+                checkpoint_id, self.job_name, states, extra=extra,
+                timestamp_ms=math.ceil(cut_ms))
+            size = sum(e.stat().st_size for e in os.scandir(new_dir)
+                       if e.is_file())
+            persist.work = size
+            self.storage.retain(self.retained)
+        return new_dir, size
+
+    def done(self) -> bool:
+        return self.pending is not None and self.pending[3].done()
+
+    def settle(self) -> None:
+        """Complete the persist in flight, if any (waiting for it)."""
+        if self.pending is not None:
+            self.complete()
+
+    def complete(self) -> None:
+        """Complete the persist in flight on the task loop's thread,
+        waiting for it first if it has not finished."""
+        from flink_tpu.metrics.traces import Span
+
+        checkpoint_id, delta, cut_ms, future = self.pending
+        self.pending = None
+        name = f"checkpoint-{checkpoint_id}"
+        with flight.span("checkpoint.write") as write:
+            if not future.done():
+                flight.instant("checkpoint.persist_wait", work=1)
+            try:
+                new_dir, size = future.result()
+            except BaseException as e:
+                self.traces.add(Span("checkpoint", name, cut_ms,
+                                     time.time() * 1000, {"error": repr(e)}))
+                raise
+            write.work = size
+            self.base_id = checkpoint_id
+            self.since_full = self.since_full + 1 if delta else 1
+            if self.claimed is not None:
+                self.claimed.on_checkpoint_complete(new_dir)
+            # checkpoint durable -> two-phase sinks publish (reference:
+            # notifyCheckpointComplete -> commit)
+            for node in self.nodes.values():
+                op = node.operator
+                if op is not None and hasattr(
+                        op, "notify_checkpoint_complete"):
+                    op.notify_checkpoint_complete(checkpoint_id)
+        self.completed_ms = time.time() * 1000
+        self.traces.add(Span("checkpoint", name, cut_ms, self.completed_ms,
+                             {"checkpointId": checkpoint_id,
+                              "incremental": delta, "stateSizeBytes": size}))
+
+    def close(self) -> None:
+        """Join the writer thread (a job that took no checkpoint has
+        none)."""
+        if self._pool is not None:
+            self._pool.shutdown(wait=True)
+            self._pool = None
+
+
 class JobHandle:
     """Setup artifacts of one stepwise job run — the first value yielded
     by :meth:`LocalExecutor.run_stepwise`. The tenancy session cluster
@@ -449,7 +564,10 @@ class LocalExecutor:
                         "batch mode rejects unbounded sources)")
         storage = None
         if ckpt_dir and (ckpt_interval or ckpt_every_n):
-            from flink_tpu.checkpoint.storage import CheckpointStorage
+            from flink_tpu.checkpoint.storage import (
+                CheckpointStorage,
+                split_states,
+            )
 
             storage = CheckpointStorage(
                 ckpt_dir,
@@ -664,18 +782,19 @@ class LocalExecutor:
 
         t0 = time.perf_counter()
         total_records = 0
-        last_ckpt = time.time() * 1000
         batches_since_ckpt = 0
         incremental = self.config.get(CheckpointOptions.INCREMENTAL)
         full_every = max(self.config.get(CheckpointOptions.FULL_EVERY), 1)
-        # deltas may build on a restored checkpoint only when it lives in
-        # the job's own checkpoint root (its chain stays intact under
-        # retain()); savepoints / foreign artifacts are not valid bases
-        last_written_id = None
-        since_full = 0
-        if restore_from is not None and storage is not None and \
-                restored_in_root:
-            last_written_id = restored_id
+        writer = None
+        if storage is not None:
+            # deltas may build on a restored checkpoint only when it lives
+            # in the job's own checkpoint root (its chain stays intact
+            # under retain()); savepoints / foreign artifacts are not
+            # valid bases
+            writer = _CheckpointWriter(
+                storage, job_name, self._retained(),
+                (restored_id if restore_from is not None
+                 and restored_in_root else None), nodes, claimed, traces)
 
         active = {t.uid for t, _ in sources}
         # host/device overlap: pump threads poll + timestamp the NEXT
@@ -717,6 +836,8 @@ class LocalExecutor:
                 # harvest landed async fires + release held watermarks
                 # (cheap is_ready() polls when nothing is pending)
                 self._drain_pending(nodes)
+                if writer is not None and writer.done():
+                    writer.complete()
                 if autoscale is not None:
                     autoscale.tick()
                 if pt_nodes:
@@ -789,74 +910,61 @@ class LocalExecutor:
                             for p in pumps.values():
                                 p.batch_size = new_size
                 if storage is not None:
+                    # the interval counts from the END of the last
+                    # checkpoint: its completion, after its persist
                     due = (ckpt_every_n
                            and batches_since_ckpt >= ckpt_every_n) or (
                         not ckpt_every_n and ckpt_interval
-                        and time.time() * 1000 - last_ckpt >= ckpt_interval)
+                        and writer.pending is None
+                        and time.time() * 1000 - writer.completed_ms
+                        >= ckpt_interval)
                     if due:
+                        # at most one persist in flight
+                        writer.settle()
                         checkpoint_count += 1
-                        use_delta = (incremental and last_written_id
-                                     is not None
-                                     and since_full < full_every)
+                        use_delta = (incremental
+                                     and writer.base_id is not None
+                                     and writer.since_full < full_every)
                         # in-flight fire results must reach their sinks
                         # before the cut — the bookkeeper already marked
                         # those windows fired, so a snapshot without them
                         # would lose results on restore
-                        # the stall, in its three parts: the loop takes
-                        # no batch and launches no fire until the last
+                        # the stall at the cut, in its two parts: the loop
+                        # takes no batch and launches no fire until the
+                        # snapshot is handed to the writer
                         with flight.span("checkpoint.drain") as drain:
                             drain.work = sum(
                                 n.operator.pending_output_count()
                                 for n in nodes.values()
                                 if n.operator is not None)
                             self._drain_pending(nodes, wait=True)
-                        with traces.span(
-                                "checkpoint",
-                                f"checkpoint-{checkpoint_count}") as sp:
-                            # the tables state the bytes they fetch as
-                            # this span's work (flight.add_work)
-                            with flight.span("checkpoint.snapshot"):
-                                snap = self.snapshot_all(graph, nodes,
-                                                         source_positions,
-                                                         delta=use_delta)
-                            with flight.span("checkpoint.write") as write:
-                                extra = ({"incremental": True,
-                                          "base": last_written_id}
-                                         if use_delta else None)
-                                new_dir = storage.write_checkpoint(
-                                    checkpoint_count, job_name, snap,
-                                    extra=extra)
-                                sp.set_attribute("checkpointId",
-                                                 checkpoint_count)
-                                sp.set_attribute("incremental", use_delta)
-                                write.work = sum(
-                                    e.stat().st_size
-                                    for e in os.scandir(new_dir)
-                                    if e.is_file())
-                                sp.set_attribute("stateSizeBytes",
-                                                 write.work)
-                                last_written_id = checkpoint_count
-                                since_full = (since_full + 1 if use_delta
-                                              else 1)
-                                if claimed is not None:
-                                    claimed.on_checkpoint_complete(new_dir)
-                                # checkpoint durable -> two-phase sinks
-                                # publish (reference:
-                                # notifyCheckpointComplete -> commit)
-                                for node in nodes.values():
-                                    op = node.operator
-                                    if op is not None and hasattr(
-                                            op, "notify_checkpoint_complete"):
-                                        op.notify_checkpoint_complete(
-                                            checkpoint_count)
-                                storage.retain(self._retained())
-                        last_ckpt = time.time() * 1000
+                        cut_ms = time.time() * 1000
+                        # the tables state the bytes they fetch as this
+                        # span's work (flight.add_work)
+                        with flight.span("checkpoint.snapshot"):
+                            snap = self.snapshot_all(graph, nodes,
+                                                     source_positions,
+                                                     delta=use_delta)
+                            # the writer's own from here: the tables'
+                            # arrays are copies, and the metadata that
+                            # still refers to live objects is pickled
+                            states = split_states(snap)
+                            flight.instant("checkpoint.offloop", work=1)
+                        writer.submit(
+                            checkpoint_count, states,
+                            {"incremental": True, "base": writer.base_id}
+                            if use_delta else None, use_delta, cut_ms)
                         batches_since_ckpt = 0
+                        # the manifest's stamp is the cut's, rounded up: no
+                        # row reaches a sink before the clock has passed it
+                        time.sleep(max(math.ceil(cut_ms)
+                                       - time.time() * 1000, 0) / 1000)
                 if control_queue is not None:
                     stopped = self._serve_control(
                         control_queue, graph, nodes, sources, active,
                         job_name, checkpoint_count, traces,
-                        source_positions, pumps)
+                        source_positions, pumps,
+                        settle=None if writer is None else writer.settle)
                     if stopped is not None:
                         suppress_final_drain = not stopped.drain
                         savepoint_path = stopped.result_path
@@ -869,6 +977,10 @@ class LocalExecutor:
             else:
                 suppress_final_drain = False
                 savepoint_path = None
+            if writer is not None:
+                # every checkpoint on disk before the job ends
+                writer.settle()
+                writer.close()
 
             # drain/close in topological order (skipped for
             # stop-with-savepoint without --drain: state was saved, in-flight
@@ -895,6 +1007,14 @@ class LocalExecutor:
         except BaseException:
             # failure/cancel path: release resources without emitting
             # (reference: Task.doRun finally -> cancel + releaseResources)
+            if writer is not None:
+                # no writer outlives the job: a cut taken before the
+                # failure completes as it would have, the failure wins
+                try:
+                    writer.settle()
+                except Exception:
+                    pass
+                writer.close()
             for p in pumps.values():
                 try:
                     p.stop()
@@ -1068,9 +1188,11 @@ class LocalExecutor:
 
     def _serve_control(self, control_queue, graph, nodes, sources, active,
                        job_name: str, checkpoint_id: int, traces,
-                       source_positions, pumps):
+                       source_positions, pumps, settle=None):
         """Serve pending SavepointRequests at a batch boundary. Returns the
-        request if it asked the job to stop, else None."""
+        request if it asked the job to stop, else None. ``settle`` completes
+        the checkpoint persist in flight, if any, before a savepoint: its
+        failure fails the job, not the request."""
         import queue as _queue
 
         from flink_tpu.checkpoint.savepoint import write_savepoint
@@ -1127,6 +1249,8 @@ class LocalExecutor:
                 except BaseException as e:  # noqa: BLE001
                     req.finish(None, e)
                 continue
+            if settle is not None:
+                settle()
             try:
                 # fail fast on a bad target BEFORE any irreversible action
                 # (closing sources / draining): a savepoint that cannot be

@@ -115,14 +115,19 @@ KNOWN_SPAN_KINDS = (
                            # timed, one per fire that produced rows;
                            # watermark: the fire's)
     # control plane
-    # a checkpoint on the task loop is three spans in a row; nothing is
-    # ingested, dispatched or fired while they run
+    # a checkpoint stops the task loop for three spans: the drain and the
+    # snapshot at the cut, the write when its persist has finished;
+    # nothing is ingested, dispatched or fired while they run. Between
+    # them the loop runs on and the writer thread persists the cut
     "checkpoint.drain",    # every in-flight fire waited for and its rows
                            # forwarded to the sinks before the cut (work:
                            # fires it waited for)
     "checkpoint.snapshot", # every operator's state and the source
-                           # positions taken to host arrays (work: bytes
-                           # fetched from the device)
+                           # positions taken to host arrays and made the
+                           # writer's own: arrays split off, metadata
+                           # pickled (work: bytes fetched from the device)
+    "checkpoint.offloop",  # a cut handed to the writer thread (instant
+                           # inside checkpoint.snapshot; work: 1)
     "checkpoint.rows",     # rows of keyed state one table's snapshot
                            # holds: the used rows of a full one, the
                            # dirty and used rows of a delta (instant
@@ -132,8 +137,19 @@ KNOWN_SPAN_KINDS = (
                            # (key, namespace) pairs (instant inside
                            # checkpoint.snapshot; work: their count;
                            # absent from a full snapshot)
-    "checkpoint.write",    # the snapshot serialized, compressed, renamed
-                           # into place, older checkpoints retired (work:
+    "checkpoint.persist",  # the writer thread: the cut's arrays
+                           # compressed into .npz files, CRCs, manifest,
+                           # the atomic rename, older checkpoints retired
+                           # (work: bytes on disk)
+    "checkpoint.persist_wait",  # the task loop found the persist in
+                           # flight unfinished where it must be complete:
+                           # a cut due, a savepoint, the end of input, a
+                           # failure (instant inside checkpoint.write;
+                           # work: 1)
+    "checkpoint.write",    # the task loop completes a persisted
+                           # checkpoint: waits for it where it must,
+                           # makes it the next delta's base, notifies the
+                           # operators (two-phase sinks commit) (work:
                            # bytes on disk)
     "checkpoint.restore",
     "failover.replay",     # partial-failover bounded replay of one range

@@ -278,7 +278,7 @@ def test_the_control_doubles_a_batch_and_every_window_that_holds_it_shows(
 # ------------------------------------ (4) what a checkpoint says of itself
 
 
-def test_each_checkpoint_records_three_spans_and_its_rows(built):
+def test_each_checkpoint_records_its_spans_and_its_rows(built):
     from flink_tpu.checkpoint.storage import read_snapshot_dir
     from flink_tpu.observe.export import chrome_trace, validate_trace_schema
 
@@ -289,8 +289,10 @@ def test_each_checkpoint_records_three_spans_and_its_rows(built):
     kt = rec.kind_totals()
     records = rec.snapshot()
     for kind in ("checkpoint.drain", "checkpoint.snapshot",
-                 "checkpoint.write", "checkpoint.rows"):
+                 "checkpoint.persist", "checkpoint.write",
+                 "checkpoint.rows", "checkpoint.offloop"):
         assert kt[kind]["count"] == 7, kind
+    assert kt["checkpoint.offloop"]["work"] == 7     # every cut off the loop
     assert kt["checkpoint.tombstones"]["count"] == 4    # the deltas
     dirs = checkpoint_dirs(built()["root"])
     tables = [read_snapshot_dir(dirs[i])["2:window_agg(CountAggregate)"]
@@ -301,6 +303,7 @@ def test_each_checkpoint_records_three_spans_and_its_rows(built):
         len(t["freed_namespaces"]) for t in tables
         if "freed_namespaces" in t) > 0
     assert kt["checkpoint.write"]["work"] == job.disk_bytes(built()["root"])
+    assert kt["checkpoint.persist"]["work"] == kt["checkpoint.write"]["work"]
     # a full snapshot fetches the whole accumulator array (int32 counts),
     # a delta its dirty rows padded to their bucket
     capacity = cfg["options"]["state.slot-table.capacity"]
@@ -309,18 +312,25 @@ def test_each_checkpoint_records_three_spans_and_its_rows(built):
     assert fetched[-3:] == [4 * capacity] * 3
     assert all(0 < w < 4 * capacity and w % 4096 == 0 for w in fetched[:4])
     assert kt["checkpoint.drain"]["work"] >= 1      # fires it waited for
-    # the instants lie inside their snapshot span; the three spans follow
-    # each other and nothing else runs on the loop between them
+    # the instants lie inside their snapshot span; the snapshot follows
+    # the drain on the loop, the writer thread persists it after, and the
+    # loop completes it (the write) once the persist has ended
     spans = {kind: sorted((r for r in records if r.kind == kind),
                           key=lambda r: r.t0)
              for kind in ("checkpoint.drain", "checkpoint.snapshot",
-                          "checkpoint.write", "checkpoint.rows")}
-    for drain, snap, write, counted in zip(*spans.values()):
+                          "checkpoint.persist", "checkpoint.write",
+                          "checkpoint.rows", "checkpoint.offloop")}
+    for drain, snap, persist, write, counted, offloop in zip(
+            *spans.values()):
         assert drain.t0 + drain.duration_s <= snap.t0
-        assert snap.t0 <= counted.t0 <= snap.t0 + snap.duration_s
-        assert snap.t0 + snap.duration_s <= write.t0
-        assert write.t0 - drain.t0 - drain.duration_s - snap.duration_s \
-            < 0.05
+        assert snap.t0 - drain.t0 - drain.duration_s < 0.05
+        for instant in (counted, offloop):
+            assert snap.t0 <= instant.t0 <= snap.t0 + snap.duration_s
+        assert snap.t0 + snap.duration_s <= persist.t0
+        assert persist.t0 + persist.duration_s <= write.t0 \
+            + write.duration_s
+        assert drain.thread == snap.thread == write.thread \
+            != persist.thread
     assert validate_trace_schema(
         chrome_trace(records, anchor=rec.anchor), KNOWN_SPAN_KINDS) == []
     rec.clear()
