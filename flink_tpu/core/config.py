@@ -352,17 +352,24 @@ class DeploymentOptions:
         "factorization (tests/CI). Engines whose mesh size the count "
         "does not divide keep the flat exchange.")
     JOIN_MODE = ConfigOption(
-        "join.mode", default="host", type=str,
-        description="Execution plane for the DataStream interval join "
-        "(KeyedStream.interval_join().between() — INNER): 'host' "
-        "(default) buffers sides as columnar batches in host numpy "
-        "(runtime/join_operators.py — also the semantics oracle); "
-        "'device' runs the join over dual keyed slot tables on the "
-        "mesh: both inputs ride the keyBy data plane co-partitioned "
-        "by key group, and a banded segment-intersection program "
-        "gathers/intersects/emits each batch's candidates "
-        "(flink_tpu/joins/). Outer joins and the SQL planner's join "
-        "operators stay on the host path regardless of this option.")
+        "join.mode", default="device", type=str,
+        description="Execution plane for the two DataStream joins (INNER): "
+        "the window join (DataStream.join().where().equal_to()"
+        ".window().apply()) and the interval join (KeyedStream."
+        "interval_join().between()). 'device' (default) runs them on the "
+        "device engines of flink_tpu/joins/: the window join keeps each "
+        "side's rows as int32 words in pages per window slice and finds "
+        "a window's pairs with one sort-and-expand program when the "
+        "watermark passes its end; the interval join keeps dual keyed "
+        "row tables and probes each batch with a banded "
+        "segment-intersection program. 'host' buffers both sides as "
+        "columnar batches in host numpy (runtime/join_operators.py — "
+        "also the semantics oracle). A window join the device engine "
+        "cannot run — a session or processing-time window, a spill "
+        "budget, a string column — runs on the host operator, with a "
+        "warning, and is counted (joins.operators.fallbacks()). Outer "
+        "joins and the SQL planner's join operators stay on the host "
+        "path regardless of this option.")
     CEP_MODE = ConfigOption(
         "cep.mode", default="host", type=str,
         description="Execution plane for CEP pattern matching "

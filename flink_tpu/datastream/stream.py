@@ -252,19 +252,42 @@ class WindowedJoin:
 
     def apply(self, suffixes=("_l", "_r"), name: str = "window_join"
               ) -> DataStream:
+        """The INNER join's pairs per window. ``join.mode=device`` (the
+        default) runs it on the device window-join engine
+        (flink_tpu/joins/); ``host``, and a shape the engine cannot run
+        (counted, with a warning), on the host operator."""
+        from flink_tpu.core.config import DeploymentOptions
+        from flink_tpu.joins.operators import (
+            DeviceWindowJoinOperator,
+            device_window_join_refusal,
+            fall_back,
+        )
         from flink_tpu.runtime.join_operators import WindowJoinOperator
 
         j = self.joined
+        env = j.left.env
         left_keyed = j.left.key_by(j.left_key).transformation
         right_keyed = j.right.key_by(j.right_key).transformation
         assigner = self.assigner
         key_fields = (j.left_key, j.right_key)
+        device = env.config.get(DeploymentOptions.JOIN_MODE) == "device"
+        if device:
+            refusal = device_window_join_refusal(
+                assigner, env.state_spill_options["max_device_slots"])
+            if refusal is not None:
+                fall_back(refusal)
+                device = False
+        if device:
+            capacity = env.state_slot_capacity
+            factory = lambda: DeviceWindowJoinOperator(  # noqa: E731
+                assigner, suffixes, key_fields, capacity=capacity)
+        else:
+            factory = lambda: WindowJoinOperator(  # noqa: E731
+                assigner, suffixes, key_fields=key_fields)
         t = Transformation(
-            name=name, kind="two_input",
-            operator_factory=lambda: WindowJoinOperator(
-                assigner, suffixes, key_fields=key_fields),
+            name=name, kind="two_input", operator_factory=factory,
             inputs=[left_keyed, right_keyed], keyed=True)
-        return DataStream(j.left.env, t)
+        return DataStream(env, t)
 
 
 class ConnectedStreams:

@@ -37,7 +37,7 @@ eviction and mid-stream ``reshard()``).
 from __future__ import annotations
 
 import time
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -1506,3 +1506,534 @@ class MeshTemporalJoinEngine(JoinEngineBase):
             unit["pending"] = {k: np.asarray(v)[mask]
                                for k, v in pend.items()}
         return units
+
+
+class UnsupportedJoinColumn(TypeError):
+    """A column the window-join engine cannot carry as int32 words
+    (strings, objects): the operator falls back to the host join."""
+
+
+def fine_bucket(n: int, cached: int = 0, minimum: int = 256) -> int:
+    """``n`` rounded up to its three leading bits (at most a quarter of
+    padding, where a power of two may double it), or ``cached`` where
+    that covers ``n`` within ``sticky_bucket``'s fourfold: a steady
+    stream, and the smaller batches that end it, keep one compiled
+    shape."""
+    n = max(int(n), int(minimum))
+    q = 1 << max(n.bit_length() - 3, 0)
+    need = -(-n // q) * q
+    if need <= cached <= 4 * need:
+        return cached
+    return need
+
+
+class _Column(NamedTuple):
+    """How one input column rides the device: ``words`` int32 planes (1
+    or 2 by its width; 0 for the key column of an integer key, which is
+    the key itself)."""
+
+    name: str
+    dtype: np.dtype
+    words: int
+
+
+#: the key id: two words, the first two planes of a side
+_KEY = _Column(KEY_ID_FIELD, np.dtype(np.int64), 2)
+
+
+def _column_of(name: str, dtype: np.dtype, key_field: Optional[str]
+               ) -> _Column:
+    if name == KEY_ID_FIELD:
+        return _KEY
+    if name == key_field and dtype.kind in "iu":
+        # hash_keys_to_i64 passes integers through: the key IS the column
+        return _Column(name, dtype, 0)
+    if dtype.kind not in "iufb" or dtype.itemsize not in (1, 2, 4, 8):
+        raise UnsupportedJoinColumn(
+            f"column {name!r} of dtype {dtype} cannot ride the device "
+            "window join (fixed-width numbers and booleans only)")
+    return _Column(name, dtype, 2 if dtype.itemsize == 8 else 1)
+
+
+def _to_words(values: np.ndarray, col: _Column) -> List[np.ndarray]:
+    v = np.ascontiguousarray(values, dtype=col.dtype)
+    if col.words == 2:
+        w = v.view(np.int32).reshape(-1, 2)
+        return [w[:, 0], w[:, 1]]
+    if col.dtype.itemsize == 4:
+        return [v.view(np.int32)]
+    return [v.astype(np.int32)]
+
+
+def _from_words(words: List[np.ndarray], dtype: np.dtype) -> np.ndarray:
+    if len(words) == 2:
+        both = np.empty((len(words[0]), 2), dtype=np.int32)
+        both[:, 0], both[:, 1] = words
+        return both.view(dtype).reshape(-1)
+    if dtype.itemsize == 4:
+        return words[0].view(dtype)
+    return words[0].astype(dtype)
+
+
+class _Fire:
+    """One dispatched window fire, until its harvest: what a re-run
+    needs, and the slices that leave with it."""
+
+    __slots__ = ("window_end", "pages", "out_rows", "retire", "done")
+
+    def __init__(self, window_end: int, pages, out_rows: int) -> None:
+        self.window_end = window_end
+        self.pages = pages
+        self.out_rows = out_rows
+        #: (side, detached slice) to release once harvested
+        self.retire: List[Tuple[int, list]] = []
+        self.done = False
+
+
+class MeshWindowJoinEngine(JoinEngineBase):
+    """INNER equi-join of two keyed streams per event-time window
+    (``JoinedStreams.where().equal_to().window().apply()``, reference:
+    JoinedStreams.java / CoGroupedStreams.java) on the device.
+
+    Semantics are the host ``WindowJoinOperator``'s: every pair of a
+    window once, when the watermark passes its end, stamped ``end - 1``
+    and carrying ``window_start`` / ``window_end``; records behind a
+    fired window's retention dropped and counted (``SliceBookkeeper``).
+
+    - **Ingest** (``process_batch``; the side is ``input_side``, which
+      the operator sets before each call): the batch's slices, its key
+      groups' shards, slots from each (shard, slice)'s pages
+      (:class:`~flink_tpu.joins.side_table.WindowPages`), and one scatter
+      of its int32 words into the side's planes — host work in the
+      batch's rows only: no live row is read, merged or sorted.
+    - **Fire** (``on_watermark``): one program over the pages of the
+      window's slices finds every pair (``kernels.window_pairs``). The
+      host sizes the output from the window's row counts (and the last
+      fire's pairs) without waiting for the device; the pair count comes
+      back with the pairs, and a window with more pairs than its output
+      holds runs again, at its harvest, wide enough.
+    - **Retire**: an expired slice leaves the index as its page list; its
+      pages go back to the free list when the last fire that reads them
+      has been harvested.
+    """
+
+    kind = "window"
+    supports_async_fires = True
+
+    def __init__(self, assigner, key_fields=(None, None),
+                 suffixes: Tuple[str, str] = ("_l", "_r"), **kw) -> None:
+        super().__init__(suffixes=suffixes, **kw)
+        if self.backend != "device" or self.max_device_slots:
+            raise ValueError(
+                "the window join runs on the device backend without a "
+                "spill budget; the host WindowJoinOperator is the other "
+                "path")
+        from flink_tpu.joins.side_table import WindowPages
+        from flink_tpu.windowing.bookkeeping import SliceBookkeeper
+
+        self.assigner = assigner
+        self.key_fields = tuple(key_fields)
+        self.book = SliceBookkeeper(assigner)
+        # a slice takes whole pages: about a thousandth of the capacity
+        # each, so that many small slices fit as well as a few large ones
+        self.page_rows = int(min(max(
+            1 << max((self.capacity // 1024).bit_length() - 1, 0), 128),
+            1 << 14))
+        pages = -(-self.capacity // self.page_rows)
+        self.capacity = pages * self.page_rows
+        self.index = [WindowPages(self.P, pages, self.page_rows)
+                      for _ in SIDE_NAMES]
+        self.columns: List[Optional[List[_Column]]] = [None, None]
+        #: the side ``process_batch`` ingests into
+        self.input_side = 0
+        self._ingest_bucket = [0, 0]
+        self._page_bucket = [0, 0]
+        self._out_bucket = 0
+        self._last_pairs = 0
+        #: the newest fire not yet harvested: slices that expire while
+        #: it is in flight leave with it
+        self._in_flight: Optional[_Fire] = None
+
+    # ------------------------------------------------------------ columns
+
+    @property
+    def accs(self) -> tuple:
+        """Every word plane of both sides: the state on the device."""
+        return tuple(a for planes in self._planes if planes
+                     for a in planes)
+
+    @property
+    def late_records_dropped(self) -> int:
+        return self.book.late_records_dropped
+
+    def _columns(self, side: int, batch: RecordBatch) -> List[_Column]:
+        cols = self.columns[side]
+        names = [n for n in batch.names() if n != TIMESTAMP_FIELD]
+        if cols is None:
+            # the planes hold the key's two words first, then each value
+            # column's in the batch's order
+            key_field = self.key_fields[side]
+            cols = [_column_of(n, np.asarray(batch[n]).dtype, key_field)
+                    for n in names]
+            self.columns[side] = cols
+            self._planes[side] = self._zeros(
+                sum(c.words for c in cols), self.capacity)
+        elif set(names) != {c.name for c in cols}:
+            raise RuntimeError(
+                f"{SIDE_NAMES[side]} join input changed columns "
+                f"mid-stream: {sorted(c.name for c in cols)} -> "
+                f"{sorted(names)}")
+        return cols
+
+    def _zeros(self, n_words: int, capacity: int) -> tuple:
+        import jax.numpy as jnp
+
+        return tuple(jax.device_put(jnp.zeros((self.P, capacity),
+                                              jnp.int32), self._sharding)
+                     for _ in range(n_words))
+
+    # ------------------------------------------------------------- ingest
+
+    def process_batch(self, batch: RecordBatch) -> None:
+        side = self.input_side
+        if len(batch) == 0:
+            return
+        from flink_tpu.observe import flight_recorder as flight
+
+        with self._flight_ingest():
+            cols = self._columns(side, batch)
+            with flight.span("join.index") as s:
+                ends = self.assigner.assign_slice_ends(batch.timestamps)
+                live = self.book.live_mask(ends)
+                if live is not None:
+                    ends, batch = ends[live], batch.filter(live)
+                if len(batch) == 0:
+                    return
+                if ends[0] == ends[-1] and (ends == ends[0]).all():
+                    # one slice: the batch of an in-order stream but the
+                    # one that crosses a slice's end
+                    uniq, slice_of = ends[:1], None
+                else:
+                    uniq, slice_of = np.unique(ends, return_inverse=True)
+                self.book.register_slices(ends, uniq)
+                shards = (self._shards_of(batch.key_ids) if self.P > 1
+                          else None)
+                slots = self._allocate(side, len(batch), shards, uniq,
+                                       slice_of)
+                s.work = len(batch)
+            with flight.span("device.dispatch"):
+                self._put(side, shards, slots, batch, cols)
+
+    def _allocate(self, side: int, n: int, shards, uniq, slice_of
+                  ) -> np.ndarray:
+        """Slots for the batch's ``n`` rows: grouped by (shard, slice),
+        each group from its slice's pages, in row order within the group.
+        ``uniq`` are the batch's slice ends, ``slice_of`` each row's place
+        in them (None: one slice)."""
+        index = self.index[side]
+        if shards is None and slice_of is None:
+            groups = [(0, int(uniq[0]), None)]
+        else:
+            if slice_of is None:
+                slice_of = np.zeros(n, dtype=np.int64)
+            group_of = slice_of if shards is None else \
+                shards.astype(np.int64) * len(uniq) + slice_of
+            gids, inverse = np.unique(group_of, return_inverse=True)
+            order = np.argsort(inverse, kind="stable")
+            bounds = np.searchsorted(inverse[order],
+                                     np.arange(len(gids) + 1))
+            groups = []
+            for g in range(len(gids)):
+                rows = order[bounds[g]:bounds[g + 1]]
+                p = 0 if shards is None else int(shards[rows[0]])
+                groups.append((p, int(uniq[slice_of[rows[0]]]), rows))
+        demand: Dict[int, Dict[int, int]] = {}
+        for p, se, rows in groups:
+            demand.setdefault(p, {})[se] = n if rows is None else len(rows)
+        short = max(index.pages_short(p, d) for p, d in demand.items())
+        if short:
+            self._grow(index.num_pages + max(short, index.num_pages))
+        if groups[0][2] is None:
+            return index.insert(0, groups[0][1], n)
+        slots = np.empty(n, dtype=np.int32)
+        for p, se, rows in groups:
+            slots[rows] = index.insert(p, se, len(rows))
+        return slots
+
+    def _grow(self, num_pages: int) -> None:
+        """Widen both sides' planes to ``num_pages`` pages a shard (on the
+        device; rare: capacity is sized for the live rows)."""
+        import jax.numpy as jnp
+
+        capacity = num_pages * self.page_rows
+        for side in range(len(SIDE_NAMES)):
+            self.index[side].grow(num_pages)
+            if self._planes[side] is not None:
+                self._planes[side] = tuple(
+                    jax.device_put(jnp.pad(pl, ((0, 0), (0, capacity
+                                                         - self.capacity))),
+                                   self._sharding)
+                    for pl in self._planes[side])
+        self.capacity = capacity
+
+    def _put(self, side: int, shards, slots, batch, cols) -> None:
+        """One scatter of the batch's words to their slots: a ``[P, 1 +
+        words, B]`` block, slot first, up in one ``device_put``."""
+        from flink_tpu.joins.kernels import build_window_put
+
+        words = _to_words(batch[KEY_ID_FIELD], _KEY) + [
+            w for c in cols if c.words and c.name != KEY_ID_FIELD
+            for w in _to_words(batch[c.name], c)]
+        n = len(slots)
+        counts = [n] if shards is None else \
+            np.bincount(shards, minlength=self.P).tolist()
+        b = self._ingest_bucket[side] = fine_bucket(
+            max(counts), self._ingest_bucket[side])
+        # lanes past a shard's rows: the slot ``capacity``, which the
+        # scatter drops, and words it never reads
+        block = np.empty((self.P, 1 + len(words), b), dtype=np.int32)
+        block[:, 0, :] = self.capacity
+        if shards is None:
+            block[0, 0, :n] = slots
+            for w, v in enumerate(words):
+                block[0, 1 + w, :n] = v
+        else:
+            rows = np.stack([slots] + words)[:, np.argsort(shards,
+                                                            kind="stable")]
+            at = 0
+            for p, c in enumerate(counts):
+                block[p, :, :c] = rows[:, at:at + c]
+                at += c
+        prog = build_window_put(self.mesh, len(words))
+        with self._wd_section("join_ingest"):
+            self._planes[side] = prog(
+                self._planes[side], jax.device_put(block, self._sharding))
+
+    def make_fence(self):
+        """A tiny program behind everything dispatched so far: ready once
+        the device has consumed every staged batch."""
+        planes = self._planes[0] or self._planes[1]
+        return _FENCE_STEP(planes[0]) if planes else None
+
+    # --------------------------------------------------------------- fire
+
+    def on_watermark(self, watermark: int, async_ok: bool = False) -> list:
+        """Fire every window the watermark passed (a ``PendingFire`` each
+        where ``async_ok``, else its rows), then retire expired slices."""
+        out = []
+        with self._flight_fire(watermark):
+            while True:
+                w_end = self.book.next_window(watermark)
+                if w_end is None:
+                    break
+                fired = self._fire(w_end, async_ok)
+                self.book.mark_fired(w_end)
+                if fired is not None and (async_ok or len(fired)):
+                    out.append(fired)
+            expired = self.book.expired_slices(watermark)
+            if expired:
+                detached = [(side, self.index[side].detach(se))
+                            for se in expired
+                            for side in range(len(SIDE_NAMES))]
+                holder = self._in_flight
+                if holder is not None and not holder.done:
+                    holder.retire.extend(detached)
+                else:
+                    self._retire(detached)
+        return out
+
+    def _retire(self, detached) -> None:
+        from flink_tpu.observe import flight_recorder as flight
+
+        with flight.span("join.retire") as s:
+            s.work = sum(self.index[side].release(d)
+                         for side, d in detached)
+
+    def _fire(self, window_end: int, async_ok: bool):
+        from flink_tpu.observe import flight_recorder as flight
+        from flink_tpu.runtime.pending import PendingFire
+
+        with flight.span("join.match") as s:
+            slices = self.assigner.slice_ends_for_window(window_end)
+            windows = [[self.index[side].window(p, slices)
+                        for p in range(self.P)]
+                       for side in range(len(SIDE_NAMES))]
+            rows = [[sum(f) for _, f in w] for w in windows]
+            flight.instant("join.rows", work=sum(map(sum, rows)))
+            if not (sum(rows[0]) and sum(rows[1])) or None in self.columns:
+                return None
+            pages = [self._page_block(side, windows[side])
+                     for side in range(len(SIDE_NAMES))]
+            sized = max(max(max(a, b) for a, b in zip(*rows)),
+                        self._last_pairs)
+            out_rows = self._out_bucket = fine_bucket(sized,
+                                                      self._out_bucket)
+            fire = _Fire(window_end, pages, out_rows)
+            arrays = self._match(pages, out_rows)
+            s.work = out_rows
+            self._in_flight = fire
+            pf = PendingFire(arrays, lambda host, f=fire: self._build(f, host),
+                             watchdog=self._watchdog)
+        return pf if async_ok else pf.harvest()
+
+    def _page_block(self, side: int, window) -> tuple:
+        """The window's ``[P, pages]`` page ids and rows per page, on the
+        device (pages past a shard's own: page 0, no rows)."""
+        width = max(len(pg) for pg, _ in window)
+        b = self._page_bucket[side] = fine_bucket(
+            width, self._page_bucket[side], minimum=8)
+        ids = np.zeros((self.P, b), dtype=np.int32)
+        fill = np.zeros((self.P, b), dtype=np.int32)
+        for p, (pg, fl) in enumerate(window):
+            ids[p, :len(pg)] = pg
+            fill[p, :len(fl)] = fl
+        return tuple(jax.device_put((ids, fill), self._sharding))
+
+    def _match(self, pages, out_rows: int) -> list:
+        from flink_tpu.joins.kernels import build_window_match
+
+        prog = build_window_match(self.mesh, len(self._planes[0]),
+                                  len(self._planes[1]), self.page_rows)
+        with self._wd_section("join_match"):
+            return list(prog(self._planes[0], self._planes[1],
+                             pages[0][0], pages[0][1],
+                             pages[1][0], pages[1][1], out_rows))
+
+    def _build(self, fire: _Fire, host: List[np.ndarray]
+               ) -> Optional[RecordBatch]:
+        """The harvested fire's rows (the harvest's ``build``): re-run
+        wider where a shard found more pairs than the output holds, then
+        retire what waited for this fire."""
+        from flink_tpu.observe import flight_recorder as flight
+
+        pairs = host[0].astype(np.int64)
+        if float(host[1].max()) >= _PAIRS_LIMIT:
+            raise RuntimeError(
+                f"window {fire.window_end}: a shard holds about "
+                f"{float(host[1].max()):.0f} pairs, past the device window "
+                f"join's {_PAIRS_LIMIT} (the match counts in int32)")
+        if int(pairs.max()) > fire.out_rows:
+            # the window holds more pairs than its rows sized: once more,
+            # wide enough (its pages are still taken: they leave with it)
+            fire.out_rows = self._out_bucket = fine_bucket(
+                int(pairs.max()), self._out_bucket)
+            host = [np.asarray(a) for a in self._harvest_get(
+                self._match(fire.pages, fire.out_rows), "join_rematch")]
+            pairs = host[0].astype(np.int64)
+        self._last_pairs = int(pairs.max())
+        flight.instant("join.pairs", work=int(pairs.sum()))
+        cols = [np.concatenate([h[p, :pairs[p]] for p in range(self.P)])
+                if self.P > 1 else h[0, :pairs[0]] for h in host[2:]]
+        fire.done = True
+        if fire.retire:
+            self._retire(fire.retire)
+            fire.retire = []
+        if not len(cols[0]):
+            return None
+        return self._output(fire.window_end, cols)
+
+    def _output(self, window_end: int, words: List[np.ndarray]
+                ) -> RecordBatch:
+        """The host ``WindowJoinOperator``'s columns, names and all, from
+        the fire's words."""
+        from flink_tpu.runtime.join_operators import merged_names
+        from flink_tpu.windowing.windower import (
+            WINDOW_END_FIELD,
+            WINDOW_START_FIELD,
+        )
+
+        key = _from_words(words[:2], np.dtype(np.int64))
+        n_left = len(self._planes[0]) - 2
+        values = [self._columns_of_words(0, key, words[2:2 + n_left]),
+                  self._columns_of_words(1, key, words[2 + n_left:])]
+        left_names = [c.name for c in self.columns[0]]
+        right_names = [c.name for c in self.columns[1]]
+        if self.key_fields[0] is not None \
+                and self.key_fields[0] == self.key_fields[1]:
+            # one join key column, unsuffixed, as the host operator has it
+            right_names.remove(self.key_fields[1])
+        cols = {}
+        for side, src, name in merged_names(left_names, right_names,
+                                            self.suffixes):
+            cols[name] = values[side][src]
+        m = len(key)
+        cols[WINDOW_START_FIELD] = np.full(
+            m, self.assigner.window_start(window_end), dtype=np.int64)
+        cols[WINDOW_END_FIELD] = np.full(m, window_end, dtype=np.int64)
+        cols[TIMESTAMP_FIELD] = np.full(m, window_end - 1, dtype=np.int64)
+        return RecordBatch(cols)
+
+    # ---------------------------------------------------------- snapshots
+
+    def export_slices(self) -> Dict[int, Tuple[list, list]]:
+        """Every live slice's rows, as the host ``WindowJoinOperator``
+        buffers them: ``{slice_end: ([left columns], [right columns])}``
+        (one dict of columns per side that holds rows; a row's timestamp
+        comes back as its slice's last millisecond, the only thing of it
+        the join reads)."""
+        planes = [None if p is None else self._harvest_get(
+            list(p), "snapshot_harvest") for p in self._planes]
+        out: Dict[int, Tuple[list, list]] = {}
+        for side in range(len(SIDE_NAMES)):
+            if planes[side] is None:
+                continue
+            index = self.index[side]
+            for se in index.live_slices():
+                parts = []
+                for p in range(self.P):
+                    pg, fl = index.window(p, [se])
+                    if not pg:
+                        continue
+                    slots = np.concatenate([
+                        np.arange(g * self.page_rows, g * self.page_rows + f)
+                        for g, f in zip(pg, fl)])
+                    parts.append([np.asarray(h[p])[slots]
+                                  for h in planes[side]])
+                words = [np.concatenate(ws) for ws in zip(*parts)]
+                cols = self._columns_of_words(
+                    side, _from_words(words[:2], np.dtype(np.int64)),
+                    words[2:])
+                cols[TIMESTAMP_FIELD] = np.full(len(words[0]), se - 1,
+                                                dtype=np.int64)
+                out.setdefault(se, ([], []))[side].append(cols)
+        return out
+
+    def _columns_of_words(self, side: int, key: np.ndarray, words
+                          ) -> Dict[str, np.ndarray]:
+        """One side's input columns from the key and that side's value
+        words, in the order its planes hold them."""
+        cols, at = {}, 0
+        for c in self.columns[side]:
+            if c.name == KEY_ID_FIELD:
+                cols[c.name] = key
+            elif c.words == 0:
+                cols[c.name] = key.astype(c.dtype, copy=False)
+            else:
+                cols[c.name] = _from_words(words[at:at + c.words], c.dtype)
+                at += c.words
+        return cols
+
+    def snapshot(self, mode: str = "full") -> Dict[str, object]:
+        """The host ``WindowJoinOperator``'s snapshot, field for field:
+        either path restores the other's."""
+        return {"book": self.book.snapshot(), "buf": self.export_slices()}
+
+    def restore(self, snap: Dict[str, object], key_group_filter=None
+                ) -> None:
+        from flink_tpu.runtime.join_operators import _filter_by_key_groups
+
+        self.book.restore(snap["book"])
+        for se, sides in sorted(snap.get("buf", {}).items()):
+            for side, parts in enumerate(sides):
+                for cols in parts:
+                    cols = {k: np.asarray(v) for k, v in cols.items()}
+                    if key_group_filter is not None:
+                        cols = _filter_by_key_groups(
+                            cols, key_group_filter, self.max_parallelism)
+                    self.input_side = side
+                    self.process_batch(RecordBatch(cols))
+        self.input_side = 0
+
+
+#: pairs one shard's fire may hold: the match counts them in int32
+_PAIRS_LIMIT = 1 << 30

@@ -22,6 +22,17 @@ shapes handled one level down by jit + the ``pad_bucket_size`` /
   ``parallel/shuffle.build_exchange_scatter``.
 - **join-gather**: plane rows at ``[P, G]`` slot blocks (eviction
   cohorts, snapshots, reshard lifts) — ONE batched D2H per harvest.
+- **window-join-put** / **window-join-match**: the window join's two
+  programs (:class:`~flink_tpu.joins.engine.MeshWindowJoinEngine`). A
+  side's rows are int32 WORDS — the int64 key as two, each value column
+  as one or two by its width — in ``[P, capacity]`` planes cut into
+  pages; the put scatters a batch's words to the slots its slices' pages
+  gave it. The match runs at a window's fire over the pages of its
+  slices: it gathers both sides' words, sorts them together by (key,
+  side, row), counts each right row's partners in its key's run, and
+  expands the pairs into ``out_rows``-wide output columns — every pair,
+  many-to-many, exact on all 64 bits of the key. The host only sizes the
+  output; the pair count comes back with it.
 - **join-banded-probe**: the banded segment-intersection step. The host
   metadata (sorted ``(key, ts)`` per shard — int64 lives on the host,
   the x32 device plane never sees a key) resolves each probe's band
@@ -218,3 +229,137 @@ def _build_banded_probe(mesh: Mesh, n_cols: int):
         )(*planes, sorted_slots, lo, cnt)
 
     return probe
+
+
+#: bit that sorts a padding row of a window behind every real row of its
+#: key (a window's rows, both sides together, stay under it)
+_PAD_BIT = 1 << 30
+
+
+def build_window_put(mesh: Mesh, n_words: int):
+    """``planes[w][p, slot] = block[p, 1 + w]`` for the slots in
+    ``block[p, 0]``: one batch of one side into its word planes. Lanes
+    past a shard's rows carry the slot ``capacity``, which the scatter
+    drops."""
+    return PROGRAM_CACHE.get_or_build(
+        "window-join-put", (_mesh_key(mesh), int(n_words)),
+        lambda: _build_window_put(mesh, int(n_words)))
+
+
+def _build_window_put(mesh: Mesh, n_words: int):
+    @partial(jax.jit, donate_argnums=(0,))
+    def put(planes, block):
+        def local(*args):
+            b = args[n_words][0]
+            return tuple(pl.at[0, b[0]].set(b[1 + w], mode="drop")
+                         for w, pl in enumerate(args[:n_words]))
+
+        return shard_map(
+            local, mesh=mesh,
+            in_specs=(P(KEY_AXIS),) * (n_words + 1),
+            out_specs=(P(KEY_AXIS),) * n_words,
+        )(*planes, block)
+
+    return put
+
+
+def build_window_match(mesh: Mesh, left_words: int, right_words: int,
+                       page_rows: int):
+    """The window join's fire: see the module docstring. Takes both
+    sides' word planes, each side's ``[P, pages]`` page ids and rows per
+    page (0 past the window's pages) and the static ``out_rows``;
+    returns the pairs per shard ``[P]`` (twice: int32 and a float sum)
+    and, per output column ``[P, out_rows]``: the key's two words, the left row's value words, the
+    right row's value words (the right key is the left's)."""
+    key = (_mesh_key(mesh), int(left_words), int(right_words),
+           int(page_rows))
+    return PROGRAM_CACHE.get_or_build(
+        "window-join-match", key,
+        lambda: _build_window_match(mesh, int(left_words),
+                                    int(right_words), int(page_rows)))
+
+
+def _window_rows(planes, pages, fill, page_rows: int):
+    """A window's words, page after page, and which lanes hold a row."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (pages.shape[0], page_rows),
+                                    1)
+    valid = (lane < fill[:, None]).reshape(-1)
+    words = [pl[0].reshape(-1, page_rows)[pages].reshape(-1)
+             for pl in planes]
+    return words, valid
+
+
+def window_pairs(left, left_valid, right, right_valid, out_rows: int):
+    """Every (left, right) pair of equal keys, for the words of one
+    window. ``left`` / ``right``: word columns, the key's two first;
+    returns ``[pairs]`` and ``out_rows``-long columns: the key's two
+    words, then the left's value words, then the right's (lanes past
+    ``pairs`` hold anything).
+
+    One sort of both sides by (key, position) with the left's positions
+    first, so each key's run holds its left rows and then its right
+    rows; a right row's partners are its run's left rows, counted by a
+    prefix sum. The pairs are laid out right row after right row, each
+    one's partners in a block; an output lane finds its right row as the
+    last block start at or before it (a scatter and a running maximum)
+    and its left row as the run's start plus its place in the block."""
+    n_left = left[0].shape[0]
+    n = n_left + right[0].shape[0]
+    pos = jnp.arange(n, dtype=jnp.int32)
+    valid = jnp.concatenate([left_valid, right_valid])
+    k0 = jnp.concatenate([left[0], right[0]])
+    k1 = jnp.concatenate([left[1], right[1]])
+    k1s, k0s, order = jax.lax.sort(
+        (k1, k0, jnp.where(valid, pos, pos | _PAD_BIT)), num_keys=3)
+    new_run = jnp.concatenate([
+        jnp.ones((1,), bool), (k1s[1:] != k1s[:-1]) | (k0s[1:] != k0s[:-1])])
+    run_start = jax.lax.cummax(jnp.where(new_run, pos, 0))
+    is_left = order < n_left
+    is_right = (order >= n_left) & (order < _PAD_BIT)
+    lefts_upto = jnp.cumsum(is_left.astype(jnp.int32))
+    lefts_before_run = lefts_upto[run_start] \
+        - is_left[run_start].astype(jnp.int32)
+    partners = jnp.where(is_right, lefts_upto - lefts_before_run, 0)
+    block_end = jnp.cumsum(partners)
+    block_start = block_end - partners
+    pairs = block_end[-1]
+    lane = jnp.arange(out_rows, dtype=jnp.int32)
+    starts = jnp.full((out_rows,), -1, jnp.int32).at[
+        jnp.where(partners > 0, block_start, out_rows)].set(
+            pos, mode="drop")
+    owner = jnp.maximum(jax.lax.cummax(starts), 0)
+    left_at = jnp.clip(run_start[owner] + lane - block_start[owner],
+                       0, n - 1)
+    left_row = jnp.clip(order[left_at], 0, n_left - 1)
+    right_row = jnp.clip(order[owner] - n_left, 0, n - n_left - 1)
+    # the count in int32 wraps past 2**31 pairs; its float sum says so
+    approx = jnp.sum(partners.astype(jnp.float32))
+    return ([pairs, approx, k0s[left_at], k1s[left_at]]
+            + [w[left_row] for w in left[2:]]
+            + [w[right_row] for w in right[2:]])
+
+
+def _build_window_match(mesh: Mesh, left_words: int, right_words: int,
+                        page_rows: int):
+    n_planes = left_words + right_words
+    n_out = 4 + (left_words - 2) + (right_words - 2)
+
+    @partial(jax.jit, static_argnums=(6,))
+    def match(left_planes, right_planes, left_pages, left_fill,
+              right_pages, right_fill, out_rows):
+        def local(*args):
+            lp, rp = args[:left_words], args[left_words:n_planes]
+            lpg, lfl, rpg, rfl = (a[0] for a in args[n_planes:])
+            left, lvalid = _window_rows(lp, lpg, lfl, page_rows)
+            right, rvalid = _window_rows(rp, rpg, rfl, page_rows)
+            return tuple(o[None] for o in window_pairs(
+                left, lvalid, right, rvalid, int(out_rows)))
+
+        return shard_map(
+            local, mesh=mesh,
+            in_specs=(P(KEY_AXIS),) * (n_planes + 4),
+            out_specs=(P(KEY_AXIS),) * n_out,
+        )(*left_planes, *right_planes, left_pages, left_fill,
+          right_pages, right_fill)
+
+    return match

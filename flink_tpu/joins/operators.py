@@ -8,10 +8,10 @@ configured keyBy data plane (``shuffle.mode``), attaches the job
 watchdog, and speaks the checkpoint protocol
 (``snapshot_state``/``restore_state(key_group_filter=...)``).
 
-Selected by ``join.mode=device`` (``DeploymentOptions.JOIN_MODE``);
-the default host
-operators (``runtime/join_operators.py``) remain both the fallback and
-the semantics oracle.
+Selected by ``join.mode=device`` (``DeploymentOptions.JOIN_MODE``, the
+default); the host operators (``runtime/join_operators.py``) are
+``join.mode=host``, the fallback for a shape the device cannot run
+(counted: :func:`fallbacks`) and the semantics oracle.
 """
 
 from __future__ import annotations
@@ -24,8 +24,46 @@ from flink_tpu.core.records import RecordBatch
 from flink_tpu.joins.engine import (
     MeshIntervalJoinEngine,
     MeshTemporalJoinEngine,
+    MeshWindowJoinEngine,
+    UnsupportedJoinColumn,
 )
-from flink_tpu.runtime.operators import Operator
+from flink_tpu.runtime.operators import AsyncFireOperator, Operator
+
+#: window joins that asked for the device and run on the host operator
+_fallbacks = 0
+
+
+def fallbacks() -> int:
+    """Window joins of this process that fell back to the host
+    operator: a shape the device engine cannot run."""
+    return _fallbacks
+
+
+def fall_back(reason: str) -> None:
+    """Say loudly that a window join leaves the device, and count it."""
+    import warnings
+
+    global _fallbacks
+    _fallbacks += 1
+    warnings.warn(f"window join on the host operator: {reason}",
+                  RuntimeWarning, stacklevel=3)
+
+
+def device_window_join_refusal(assigner, max_device_slots: int
+                               ) -> Optional[str]:
+    """Why the device window join cannot run this shape, or None: it
+    takes the event-time windows the slice bookkeeping knows (tumbling,
+    sliding, cumulative), and no spill budget."""
+    from flink_tpu.windowing.assigners import WindowAssigner
+
+    if not isinstance(assigner, WindowAssigner) \
+            or getattr(assigner, "is_merging", False):
+        return f"{type(assigner).__name__} is not a sliced window"
+    if getattr(assigner, "is_processing_time", False):
+        return f"{type(assigner).__name__} is a processing-time window"
+    if max_device_slots:
+        return "state.spill.max-device-slots is set: the join has no spill"
+    return None
 
 
 def _engine_kwargs(ctx, capacity: int, max_device_slots: int,
@@ -169,3 +207,103 @@ class DeviceTemporalJoinOperator(Operator):
 
     def spill_counters(self):
         return self.engine.spill_counters()
+
+
+class DeviceWindowJoinOperator(AsyncFireOperator):
+    """INNER window join on the device (:class:`~flink_tpu.joins.engine.
+    MeshWindowJoinEngine`), with the stream contract of the host
+    ``WindowJoinOperator``. Like ``WindowAggOperator``, ``windower`` is
+    the engine, ``process_batch`` / ``on_watermark`` are what it is
+    driven by, fires come back as ``PendingFire`` results where the
+    executor harvests asynchronously, and the snapshot is the host
+    operator's, so either restores the other's. A column the device
+    cannot carry (strings) moves the join to the host operator, state
+    and all, at the batch that brings it (counted: :func:`fallbacks`)."""
+
+    name = "device_window_join"
+
+    def __init__(self, assigner, suffixes=("_l", "_r"),
+                 key_fields=(None, None), capacity: int = 1 << 16) -> None:
+        super().__init__()
+        self.assigner = assigner
+        self.suffixes = tuple(suffixes)
+        self.key_fields = tuple(key_fields)
+        self._capacity = int(capacity)
+        self.windower: Optional[MeshWindowJoinEngine] = None
+        #: the host operator, once the join has left the device
+        self.host = None
+        self._ctx = None
+
+    def open(self, ctx) -> None:
+        self._ctx = ctx
+        self.windower = MeshWindowJoinEngine(
+            self.assigner, self.key_fields, self.suffixes,
+            **_engine_kwargs(ctx, self._capacity, 0, None))
+        self._async_fires = bool(getattr(ctx, "async_fires", False))
+        self._max_dispatch_ahead = int(
+            getattr(ctx, "max_dispatch_ahead", self._max_dispatch_ahead))
+        wd = getattr(ctx, "watchdog", None)
+        if wd is not None:
+            self.windower.attach_watchdog(wd)
+
+    @property
+    def late_records_dropped(self) -> int:
+        return (self.host or self.windower).book.late_records_dropped
+
+    def process_batch(self, batch, input_index=0) -> List[RecordBatch]:
+        if self.host is not None:
+            return self.host.process_batch(batch, input_index)
+        self.windower.input_side = input_index
+        try:
+            self.windower.process_batch(batch)
+        except UnsupportedJoinColumn as e:
+            self._to_host(str(e))
+            return self.host.process_batch(batch, input_index)
+        if self._async_fires:
+            self._push_fence(self.windower.make_fence())
+        return []
+
+    def process_watermark(self, watermark, input_index=0
+                          ) -> List[RecordBatch]:
+        if self.host is not None:
+            return self.host.process_watermark(watermark, input_index)
+        from flink_tpu.runtime.pending import PendingFire
+
+        outs = []
+        for f in self.windower.on_watermark(int(watermark),
+                                            async_ok=self._async_fires):
+            if isinstance(f, PendingFire):
+                self._pending.append(f)
+            else:
+                outs.append(f)
+        return outs
+
+    def _to_host(self, reason: str) -> None:
+        """The join leaves the device: its state, exported, restores a
+        host operator that takes over from here."""
+        from flink_tpu.runtime.join_operators import WindowJoinOperator
+
+        self._check_no_pending()
+        host = WindowJoinOperator(self.assigner, self.suffixes,
+                                  key_fields=self.key_fields)
+        host.open(self._ctx)
+        host.restore_state(self.windower.snapshot())
+        self.host, self.windower = host, None
+        self._fences.clear()
+        fall_back(reason)
+
+    def snapshot_state(self):
+        if self.host is not None:
+            return self.host.snapshot_state()
+        self._check_no_pending()
+        return self.windower.snapshot()
+
+    def restore_state(self, state, key_group_filter=None):
+        if self.host is not None:
+            return self.host.restore_state(state, key_group_filter)
+        return self.windower.restore(state,
+                                     key_group_filter=key_group_filter)
+
+    def dispose(self) -> None:
+        self._pending.clear()
+        self._fences.clear()

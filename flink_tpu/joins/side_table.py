@@ -442,3 +442,116 @@ def _rid_positions(sorted_source: np.ndarray, queries: np.ndarray
     pos = np.minimum(np.searchsorted(srt, queries), len(srt) - 1)
     found = srt[pos] == queries
     return found, order[pos[found]]
+
+
+class _SlicePages:
+    """The pages one slice's rows fill on one shard, in arrival order."""
+
+    __slots__ = ("pages", "rows")
+
+    def __init__(self) -> None:
+        self.pages: List[int] = []
+        self.rows = 0
+
+
+class WindowPages:
+    """The index of one side of a window join: which slots hold which
+    slice's rows, per shard.
+
+    A shard's plane is cut into pages of ``page_rows`` slots. A slice owns
+    a list of pages that its rows fill in arrival order, so the rows of a
+    slice are the first ``rows`` slots of its pages and need no key order
+    on the host: the fire sorts a window's keys on the device. A batch
+    takes its slots from the open page of each (shard, slice) it touches
+    and from the free list — work in the batch's rows and the pages it
+    opens, never in the rows already live (``touched`` counts it) — and a
+    fired slice leaves as its page list, whole (``detach`` then
+    ``release``). Compare the slot map's ``sm_drop_namespaces``."""
+
+    def __init__(self, num_shards: int, num_pages: int,
+                 page_rows: int) -> None:
+        self.P = int(num_shards)
+        self.page_rows = int(page_rows)
+        self.num_pages = 0
+        self._free: List[List[int]] = [[] for _ in range(self.P)]
+        self.slices: List[Dict[int, _SlicePages]] = [
+            {} for _ in range(self.P)]
+        #: slots and pages ``insert`` read or wrote, summed: a batch adds
+        #: its rows and the pages it reached, whatever is live already
+        self.touched = 0
+        self.grow(num_pages)
+
+    def grow(self, num_pages: int) -> None:
+        """Pages ``num_pages`` in all per shard: the new ones go on top
+        of every free list."""
+        for free in self._free:
+            free.extend(range(num_pages - 1, self.num_pages - 1, -1))
+        self.num_pages = int(num_pages)
+
+    def pages_short(self, shard: int, demand: Dict[int, int]) -> int:
+        """Pages shard ``shard`` lacks to take ``demand`` (slice end ->
+        rows) — 0 when its free list suffices."""
+        pg = self.page_rows
+        need = 0
+        for se, n in demand.items():
+            sp = self.slices[shard].get(se)
+            have, rows = (len(sp.pages), sp.rows) if sp else (0, 0)
+            need += -(-(rows + n) // pg) - have
+        return max(need - len(self._free[shard]), 0)
+
+    def insert(self, shard: int, slice_end: int, n: int) -> np.ndarray:
+        """Slots for ``n`` new rows of ``slice_end`` on ``shard``, in
+        order (the caller made sure the free list holds the pages)."""
+        pg = self.page_rows
+        sp = self.slices[shard].get(slice_end)
+        if sp is None:
+            sp = self.slices[shard][slice_end] = _SlicePages()
+        first, last = sp.rows // pg, (sp.rows + n - 1) // pg
+        free = self._free[shard]
+        while len(sp.pages) <= last:
+            sp.pages.append(free.pop())
+        pos = np.arange(sp.rows, sp.rows + n, dtype=np.int64)
+        pages = np.asarray(sp.pages[first:last + 1], dtype=np.int64)
+        slots = (pages[pos // pg - first] * pg + pos % pg).astype(np.int32)
+        sp.rows += n
+        self.touched += n + (last - first + 1)
+        return slots
+
+    def window(self, shard: int, slice_ends: Sequence[int]
+               ) -> Tuple[List[int], List[int]]:
+        """``(pages, rows in each)`` of the window whose slices are
+        ``slice_ends`` on ``shard``."""
+        pg = self.page_rows
+        pages: List[int] = []
+        fill: List[int] = []
+        for se in slice_ends:
+            sp = self.slices[shard].get(se)
+            if sp is None or not sp.rows:
+                continue
+            pages.extend(sp.pages)
+            fill.extend([pg] * (len(sp.pages) - 1))
+            fill.append(sp.rows - (len(sp.pages) - 1) * pg)
+        return pages, fill
+
+    def detach(self, slice_end: int) -> List[Tuple[int, List[int], int]]:
+        """Take ``slice_end`` out of the index: ``(shard, pages, rows)``
+        per shard that held it. Its pages stay taken until ``release``
+        (a fire in flight may still read them)."""
+        out = []
+        for p in range(self.P):
+            sp = self.slices[p].pop(slice_end, None)
+            if sp is not None:
+                out.append((p, sp.pages, sp.rows))
+        return out
+
+    def release(self, detached) -> int:
+        """Return detached slices' pages to the free lists; the rows they
+        held."""
+        rows = 0
+        for p, pages, n in detached:
+            self._free[p].extend(reversed(pages))
+            rows += n
+        return rows
+
+    def live_slices(self) -> List[int]:
+        return sorted({se for s in self.slices for se in s})

@@ -79,6 +79,22 @@ KNOWN_SPAN_KINDS = (
     "retire.drop",         # pairs that left the host index with their
                            # slice's whole table, no erase per pair
                            # (instant inside slice.retire; work: pairs)
+    "join.index",          # the window join's per-batch host work: the
+                           # batch's slices and shards, its slots from its
+                           # slices' pages (inside batch.ingest; work:
+                           # rows inserted)
+    "join.match",          # the window join's fire on the host: the
+                           # window's pages handed to the match program,
+                           # the output sized (inside fire.dispatch; work:
+                           # output rows sized, at least the pairs)
+    "join.rows",           # rows of both sides in a fired window (instant
+                           # inside join.match; work: rows)
+    "join.pairs",          # pairs a window join's fire emitted (instant
+                           # inside fire.harvest, where the count first
+                           # reaches the host; work: pairs)
+    "join.retire",         # fired slices' pages back to the free lists,
+                           # once the last fire reading them is harvested
+                           # (work: rows dropped)
     "sink.write",          # one batch handed to the sink (work: rows)
     "op.process",          # executor: one operator's process_batch
     "op.watermark",        # executor: one operator's process_watermark

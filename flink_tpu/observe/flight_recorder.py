@@ -109,7 +109,7 @@ _enabled = os.environ.get("FLINK_TPU_FLIGHT_RECORDER", "1") != "0"
 #: the instants stay in the recorder only)
 _MIRRORED = frozenset(
     ("op", "batch", "prep", "session", "device", "exchange", "fire", "slice",
-     "sink", "loop", "source", "checkpoint"))
+     "sink", "loop", "source", "checkpoint", "join"))
 #: ``resource.RUSAGE_THREAD`` (Linux); absent elsewhere, where
 #: ``faults=True`` then counts nothing
 _RUSAGE_THREAD = getattr(resource, "RUSAGE_THREAD", None)

@@ -7,10 +7,14 @@ both sides as window state, join on fire) and interval join
 buffers, relative time bounds, watermark-driven cleanup).
 
 Batched re-design: sides are buffered as columnar batches per *slice* on the
-host (joins are data movement, not arithmetic — NumPy's sort-join is the
-right tool; the device stays busy with the aggregation operators). Window
-lifecycle (pending windows, retention, cleanup) reuses SliceBookkeeper.
-Equality join uses a vectorized sort + searchsorted matcher.
+host. Window lifecycle (pending windows, retention, cleanup) reuses
+SliceBookkeeper. Equality join uses a vectorized sort + searchsorted matcher.
+
+These are the ``join.mode=host`` path: the DataStream window and interval
+joins run on the device engines of ``flink_tpu/joins/`` by default, and
+fall back here for a shape those cannot run. They stay the semantics
+oracle the device engines are tested against, and the SQL planner's
+joins run here.
 """
 
 from __future__ import annotations
@@ -65,25 +69,27 @@ def _filter_by_key_groups(cols: Dict[str, np.ndarray], key_group_filter,
     return {k: np.asarray(v)[keep] for k, v in cols.items()}
 
 
+def merged_names(left_names, right_names, suffixes=("_l", "_r")
+                 ) -> List[Tuple[int, str, str]]:
+    """``(side, input column, output column)`` of a joined row, in order:
+    a name on both sides takes its side's suffix, the key id stays one
+    unsuffixed column (the left's), everything else passes through."""
+    left, right = set(left_names), set(right_names)
+    out = [(0, k, k + suffixes[0] if k in right and k != KEY_ID_FIELD
+            else k) for k in left_names]
+    out += [(1, k, k + suffixes[1] if k in left else k)
+            for k in right_names if not (k in left and k == KEY_ID_FIELD)]
+    return out
+
+
 def _merge_columns(left: RecordBatch, right: RecordBatch,
                    l_idx: np.ndarray, r_idx: np.ndarray,
                    suffixes=("_l", "_r")) -> Dict[str, np.ndarray]:
-    cols: Dict[str, np.ndarray] = {}
-    lcols = {k: v[l_idx] for k, v in left.columns.items()}
-    rcols = {k: v[r_idx] for k, v in right.columns.items()}
-    for k, v in lcols.items():
-        if k in rcols and k not in (KEY_ID_FIELD,):
-            cols[k + suffixes[0]] = v
-        else:
-            cols[k] = v
-    for k, v in rcols.items():
-        if k in lcols:
-            if k == KEY_ID_FIELD:
-                continue
-            cols[k + suffixes[1]] = v
-        else:
-            cols[k] = v
-    return cols
+    sides = (left, right)
+    at = (l_idx, r_idx)
+    return {out: sides[side].columns[k][at[side]]
+            for side, k, out in merged_names(left.names(), right.names(),
+                                             suffixes)}
 
 
 class WindowJoinOperator(Operator):

@@ -208,42 +208,15 @@ class KeyByOperator(Operator):
         return [batch.with_column(KEY_ID_FIELD, key_ids)]
 
 
-class WindowAggOperator(Operator):
-    """keyBy -> window -> aggregate on the TPU slot table.
+class AsyncFireOperator(Operator):
+    """An operator whose engine dispatches window fires as
+    :class:`~flink_tpu.runtime.pending.PendingFire` results: the queue of
+    fires in flight, the per-batch dispatch fences that bound how far the
+    host runs ahead of the device, and the one harvest every such fire
+    leaves through (``_harvest_one``). The window aggregates and the
+    device window join share it."""
 
-    reference semantics: WindowOperator.java / WindowAggOperator.java (see
-    flink_tpu.windowing.windower docstring for the mapping).
-    """
-
-    name = "window_agg"
-
-    def __init__(self, assigner: WindowAssigner, agg: AggregateFunction,
-                 key_field: str, capacity: int = 1 << 16,
-                 allowed_lateness: int = 0, spill: dict = None,
-                 fire_projector=None, window_layout: str = "auto",
-                 state_backend: str = "tpu-slot-table"):
-        self.window_layout = window_layout
-        self.state_backend = state_backend
-        self.assigner = assigner
-        self.agg = agg
-        self.key_field = key_field
-        self.capacity = capacity
-        self.allowed_lateness = allowed_lateness
-        self.spill = spill
-        self.fire_projector = fire_projector
-        #: processing-time assigner: records are stamped with wall-clock
-        #: arrival time; fires come from on_processing_time ticks
-        #: (reference: WindowOperator.onProcessingTime:497)
-        self.uses_processing_time = bool(
-            getattr(assigner, "is_processing_time", False))
-        self.windower: Optional[SliceSharedWindower] = None
-        self._key_values: Dict[int, Any] = {}  # key_id -> original key value
-        #: sorted-array mirror of _key_values for vectorized lookups on the
-        #: fire path (np.searchsorted instead of a per-key Python loop);
-        #: rebuilt lazily whenever the dict has grown
-        self._kv_ids: np.ndarray = np.empty(0, np.int64)
-        self._kv_vals: np.ndarray = np.empty(0, object)
-        self._keys_hashed = False
+    def __init__(self):
         #: wall-clock ms from watermark advance to fired results on host
         #: (the p99 window-fire latency metric; reference measures this at
         #: WindowOperator.emitWindowContents). Bounded reservoir — a
@@ -268,6 +241,133 @@ class WindowAggOperator(Operator):
         #: from queueing behind an unbounded scatter backlog
         self._fences = deque()
         self._max_dispatch_ahead = 4  # overridden from ctx in open()
+
+    def _push_fence(self, fence) -> None:
+        """Keep one batch's fence; past the dispatch-ahead bound wait for
+        the oldest."""
+        if fence is not None:
+            self._fences.append(fence)
+            if len(self._fences) > self._max_dispatch_ahead:
+                self._await_fences()
+
+    def _await_fences(self) -> None:
+        """The task loop's dispatch-ahead backpressure point: the host
+        waits for the device here, and only past the bound."""
+        with flight.span("device.fence_wait"):
+            while len(self._fences) > self._max_dispatch_ahead:
+                # flint: disable=TRC01 -- the depth-bounded fence drain
+                # IS the backpressure point (blocks only past the bound)
+                self._fences.popleft().block_until_ready()
+
+    def has_pending_output(self) -> bool:
+        return bool(self._pending)
+
+    def pending_output_count(self) -> int:
+        return len(self._pending)
+
+    def poll_pending_output(self, wait: bool = False):
+        """Harvest the fires that have landed, oldest first, yielding each
+        one's result with that fire's ``(watermark, origin)`` in the
+        flight recorder's ambient context: the caller forwards it before
+        it asks for the next. With ``wait``, and past the bound on
+        pending fires, the harvest waits for its fire."""
+        import time as _time
+
+        while self._pending:
+            block = wait or len(self._pending) > self._max_pending
+            if not block and not self._pending[0].ready():
+                # the device runs its programs in order: what was
+                # dispatched behind a fire that has not landed has not
+                now = _time.perf_counter()
+                for pf in self._pending:
+                    pf.unready_at = now
+                return
+            yield from self._harvest_one(block)
+
+    def _harvest_one(self, block: bool = False) -> List[RecordBatch]:
+        """Pop and harvest the oldest pending fire — the one place a
+        ``PendingFire`` of any engine leaves the queue, and so the one
+        place the wait between its dispatch and its harvest is recorded:
+        ``fire.in_flight`` (dispatch -> here) and, of it, ``fire.poll_gap``
+        (the last look that found it not ready -> here)."""
+        import time as _time
+
+        pf = self._pending.popleft()
+        waited_from = pf.unready_at
+        if block:
+            if not waited_from:
+                waited_from = _time.perf_counter()
+            pf.wait_ready()
+        flight.set_fire_context(pf.watermark, pf.origin)
+        t_start = _time.perf_counter()
+        flight.instant("fire.in_flight", t0=t_start,
+                       duration_s=t_start - pf.dispatched_at)
+        flight.instant("fire.poll_gap", t0=t_start, timed=True,
+                       duration_s=t_start - waited_from
+                       if waited_from else 0.0)
+        batch = pf.harvest()
+        # fire latency = watermark advance (dispatch) -> results on host,
+        # the same span the synchronous path measures: fire.in_flight
+        # plus the harvest, off the same dispatch stamp
+        self.fire_latencies_ms.append(
+            (_time.perf_counter() - pf.dispatched_at) * 1e3)
+        self.fires_total += 1
+        if batch is None or len(batch) == 0:
+            return []
+        return [self._emit_harvested(batch)]
+
+    def _emit_harvested(self, batch: RecordBatch) -> RecordBatch:
+        """What a harvested fire's batch becomes on its way out."""
+        return batch
+
+    def _check_no_pending(self) -> None:
+        # the hosting executor must drain (and forward) in-flight fires
+        # before a snapshot — silently dropping them here would lose fired
+        # windows that the bookkeeper already marked fired
+        if self._pending:
+            raise RuntimeError(
+                "snapshot with in-flight async fires; the executor must "
+                "drain pending outputs (poll_pending_output(wait=True)) "
+                "before snapshotting")
+
+
+class WindowAggOperator(AsyncFireOperator):
+    """keyBy -> window -> aggregate on the TPU slot table.
+
+    reference semantics: WindowOperator.java / WindowAggOperator.java (see
+    flink_tpu.windowing.windower docstring for the mapping).
+    """
+
+    name = "window_agg"
+
+    def __init__(self, assigner: WindowAssigner, agg: AggregateFunction,
+                 key_field: str, capacity: int = 1 << 16,
+                 allowed_lateness: int = 0, spill: dict = None,
+                 fire_projector=None, window_layout: str = "auto",
+                 state_backend: str = "tpu-slot-table"):
+        super().__init__()
+        self.window_layout = window_layout
+        self.state_backend = state_backend
+        self.assigner = assigner
+        self.agg = agg
+        self.key_field = key_field
+        self.capacity = capacity
+        self.allowed_lateness = allowed_lateness
+        self.spill = spill
+        self.fire_projector = fire_projector
+        #: processing-time assigner: records are stamped with wall-clock
+        #: arrival time; fires come from on_processing_time ticks
+        #: (reference: WindowOperator.onProcessingTime:497)
+        self.uses_processing_time = bool(
+            getattr(assigner, "is_processing_time", False))
+        self.windower: Optional[SliceSharedWindower] = None
+        self._key_values: Dict[int, Any] = {}  # key_id -> original key value
+        #: sorted-array mirror of _key_values for vectorized lookups on the
+        #: fire path (np.searchsorted instead of a per-key Python loop);
+        #: rebuilt lazily whenever the dict has grown
+        self._kv_ids: np.ndarray = np.empty(0, np.int64)
+        self._kv_vals: np.ndarray = np.empty(0, object)
+        self._keys_hashed = False
 
     def open(self, ctx):
         if ctx.parallelism > 1:
@@ -457,21 +557,9 @@ class WindowAggOperator(Operator):
                 table = getattr(self.windower, "table", None)
                 fence_src = getattr(table, "make_fence", None) \
                     if table is not None else None
-            fence = fence_src() if fence_src is not None else None
-            if fence is not None:
-                self._fences.append(fence)
-                if len(self._fences) > self._max_dispatch_ahead:
-                    self._await_fences()
+            self._push_fence(fence_src() if fence_src is not None
+                             else None)
         return []
-
-    def _await_fences(self) -> None:
-        """The task loop's dispatch-ahead backpressure point: the host
-        waits for the device here, and only past the bound."""
-        with flight.span("device.fence_wait"):
-            while len(self._fences) > self._max_dispatch_ahead:
-                # flint: disable=TRC01 -- the depth-bounded fence drain
-                # IS the backpressure point (blocks only past the bound)
-                self._fences.popleft().block_until_ready()
 
     def process_watermark(self, watermark, input_index=0):
         from flink_tpu.runtime.elements import MAX_WATERMARK
@@ -504,63 +592,6 @@ class WindowAggOperator(Operator):
             self.fires_total += 1
         return outs
 
-    def has_pending_output(self) -> bool:
-        return bool(self._pending)
-
-    def pending_output_count(self) -> int:
-        return len(self._pending)
-
-    def poll_pending_output(self, wait: bool = False):
-        """Harvest the fires that have landed, oldest first, yielding each
-        one's result with that fire's ``(watermark, origin)`` in the
-        flight recorder's ambient context: the caller forwards it before
-        it asks for the next. With ``wait``, and past the bound on
-        pending fires, the harvest waits for its fire."""
-        import time as _time
-
-        while self._pending:
-            block = wait or len(self._pending) > self._max_pending
-            if not block and not self._pending[0].ready():
-                # the device runs its programs in order: what was
-                # dispatched behind a fire that has not landed has not
-                now = _time.perf_counter()
-                for pf in self._pending:
-                    pf.unready_at = now
-                return
-            yield from self._harvest_one(block)
-
-    def _harvest_one(self, block: bool = False) -> List[RecordBatch]:
-        """Pop and harvest the oldest pending fire — the one place a
-        ``PendingFire`` of any engine leaves the queue, and so the one
-        place the wait between its dispatch and its harvest is recorded:
-        ``fire.in_flight`` (dispatch -> here) and, of it, ``fire.poll_gap``
-        (the last look that found it not ready -> here)."""
-        import time as _time
-
-        pf = self._pending.popleft()
-        waited_from = pf.unready_at
-        if block:
-            if not waited_from:
-                waited_from = _time.perf_counter()
-            pf.wait_ready()
-        flight.set_fire_context(pf.watermark, pf.origin)
-        t_start = _time.perf_counter()
-        flight.instant("fire.in_flight", t0=t_start,
-                       duration_s=t_start - pf.dispatched_at)
-        flight.instant("fire.poll_gap", t0=t_start, timed=True,
-                       duration_s=t_start - waited_from
-                       if waited_from else 0.0)
-        batch = pf.harvest()
-        # fire latency = watermark advance (dispatch) -> results on host,
-        # the same span the synchronous path measures: fire.in_flight
-        # plus the harvest, off the same dispatch stamp
-        self.fire_latencies_ms.append(
-            (_time.perf_counter() - pf.dispatched_at) * 1e3)
-        self.fires_total += 1
-        if batch is None or len(batch) == 0:
-            return []
-        return [self._reattach_keys(batch)]
-
     def on_processing_time(self, now_ms: int):
         if not self.uses_processing_time:
             return []
@@ -580,6 +611,9 @@ class WindowAggOperator(Operator):
             vals = np.empty(len(ids), object)
             vals[:] = list(self._key_values.values())
             self._kv_vals = vals[order]
+
+    def _emit_harvested(self, batch: RecordBatch) -> RecordBatch:
+        return self._reattach_keys(batch)
 
     def _reattach_keys(self, batch: RecordBatch) -> RecordBatch:
         kid = batch.key_ids
@@ -613,16 +647,6 @@ class WindowAggOperator(Operator):
             release = getattr(table, "release_memory", None)
         if release is not None:
             release()
-
-    def _check_no_pending(self) -> None:
-        # the hosting executor must drain (and forward) in-flight fires
-        # before a snapshot — silently dropping them here would lose fired
-        # windows that the bookkeeper already marked fired
-        if self._pending:
-            raise RuntimeError(
-                "snapshot with in-flight async fires; the executor must "
-                "drain pending outputs (poll_pending_output(wait=True)) "
-                "before snapshotting")
 
     def snapshot_state(self):
         self._check_no_pending()

@@ -66,6 +66,8 @@ KNOWN_PROGRAM_FAMILIES = (
     "join-exchange-put",
     "join-gather",
     "join-banded-probe",
+    "window-join-put",
+    "window-join-match",
     "join-exchange2-stage1",
     "join-exchange2-stage2",
     "cep-advance",

@@ -1,0 +1,364 @@
+"""The device window join (``MeshWindowJoinEngine`` behind
+``DeviceWindowJoinOperator``) against the host ``WindowJoinOperator`` and a
+plain NumPy join, on seeded random inputs: every pair of a window, once,
+whatever the keys, the windows or the order the two sides arrive in."""
+
+import warnings
+
+import numpy as np
+import pytest
+
+from flink_tpu import Configuration, StreamExecutionEnvironment
+from flink_tpu.connectors.sinks import CollectSink
+from flink_tpu.connectors.sources import Source
+from flink_tpu.core.records import KEY_ID_FIELD, TIMESTAMP_FIELD, RecordBatch
+from flink_tpu.joins import operators as join_ops
+from flink_tpu.joins.engine import MeshWindowJoinEngine, fine_bucket
+from flink_tpu.runtime.join_operators import WindowJoinOperator
+from flink_tpu.runtime.operators import OperatorContext
+from flink_tpu.runtime.watermarks import WatermarkStrategy
+from flink_tpu.windowing.assigners import (
+    SlidingEventTimeWindows,
+    TumblingEventTimeWindows,
+    TumblingProcessingTimeWindows,
+)
+
+#: two keys that share their low 32 bits: a join on 32 bits would pair them
+WIDE = (1 << 33) + 5, (1 << 40) + 5
+
+
+class Rows(Source):
+    """A bounded source handing out fixed batches in order."""
+
+    bounded = True
+
+    def __init__(self, batches):
+        self.batches = list(batches)
+
+    def poll_batch(self, max_records):
+        return self.batches.pop(0) if self.batches else None
+
+
+def side(keys, ts, value_name, values):
+    return RecordBatch.from_pydict(
+        {"k": np.asarray(keys, dtype=np.int64),
+         value_name: np.asarray(values)}, timestamps=np.asarray(ts))
+
+
+def numpy_join(left, right, window_of):
+    """Every (left, right) pair with equal keys in one window:
+    ``(window_end, key, left value, right value)``, sorted."""
+    out = []
+    for lk, lt, lv in zip(*left):
+        for rk, rt, rv in zip(*right):
+            for w in window_of(lt) & window_of(rt):
+                if lk == rk:
+                    out.append((w, int(lk), lv, rv))
+    return sorted(out)
+
+
+def tumbling(size):
+    return lambda t: {(int(t) // size + 1) * size}
+
+
+def sliding(size, slide):
+    def of(t):
+        last = (int(t) // slide + 1) * slide
+        return {e for e in range(last, last + size, slide) if e - size <= t}
+    return of
+
+
+def run(batches, assigner, mode, micro_batch=64, delay=0, parallelism=1):
+    """The two streams through ``env.execute()`` under ``join.mode``;
+    the sink's rows as ``(window_end, k, lv, rv)``, sorted, and the
+    sink."""
+    env = StreamExecutionEnvironment(Configuration({
+        "join.mode": mode, "execution.micro-batch.size": micro_batch,
+        "state.slot-table.capacity": 1 << 12,
+        "parallelism.default": parallelism}))
+    sink = CollectSink()
+    streams = [env.from_source(
+        Rows(b), WatermarkStrategy.for_bounded_out_of_orderness(delay))
+        for b in batches]
+    (streams[0].join(streams[1]).where("k").equal_to("k")
+     .window(assigner).apply().sink_to(sink))
+    env.execute("wj-" + mode)
+    rows = []
+    for b in sink.batches:
+        rows.extend(zip(b["window_end"].tolist(), b["k"].tolist(),
+                        b["lv"].tolist(), b["rv"].tolist()))
+    return sorted(rows), sink
+
+
+def random_sides(seed, n_left, n_right, keys, t_max, hot=None):
+    rng = np.random.default_rng(seed)
+    lk = rng.integers(0, keys, n_left)
+    rk = rng.integers(0, keys, n_right)
+    if hot is not None:
+        lk[rng.random(n_left) < 0.3] = hot
+        rk[rng.random(n_right) < 0.5] = hot
+    lt = np.sort(rng.integers(0, t_max, n_left))
+    rt = np.sort(rng.integers(0, t_max, n_right))
+    lv = rng.integers(0, 1 << 30, n_left).astype(np.int32)
+    rv = rng.random(n_right)                      # float64: two words
+    return (lk, lt, lv.tolist()), (rk, rt, rv.tolist())
+
+
+def batches_of(rows, name, size):
+    k, t, v = rows
+    return [side(k[a:a + size], t[a:a + size], name, v[a:a + size])
+            for a in range(0, len(k), size)]
+
+
+CASES = {
+    # many-to-many keys and one hot key with hundreds of matches a window
+    "hot_many_to_many": dict(seed=1, n_left=600, n_right=900, keys=20,
+                             t_max=5000, hot=7,
+                             assigner=TumblingEventTimeWindows.of(1000),
+                             windows=tumbling(1000)),
+    "sliding": dict(seed=2, n_left=400, n_right=500, keys=15, t_max=4000,
+                    assigner=SlidingEventTimeWindows.of(1000, 250),
+                    windows=sliding(1000, 250)),
+    "sparse_keys": dict(seed=3, n_left=300, n_right=300, keys=400,
+                        t_max=3000, assigner=TumblingEventTimeWindows.of(500),
+                        windows=tumbling(500)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_device_equals_host_and_numpy(case):
+    c = dict(CASES[case])
+    assigner, window_of = c.pop("assigner"), c.pop("windows")
+    left, right = random_sides(**c)
+    want = numpy_join(left, right, window_of)
+    host, _ = run([batches_of(left, "lv", 97), batches_of(right, "rv", 61)],
+                  assigner, "host")
+    device, _ = run([batches_of(left, "lv", 97),
+                     batches_of(right, "rv", 61)], assigner, "device")
+    assert len(want) > 0
+    assert device == host == want
+
+
+def test_the_join_over_four_shards_equals_numpy():
+    """Key groups spread over four shards: each shard's pages, slots and
+    pairs are its own, the output their union."""
+    c = dict(CASES["hot_many_to_many"])
+    assigner, window_of = c.pop("assigner"), c.pop("windows")
+    left, right = random_sides(**c)
+    got, _ = run([batches_of(left, "lv", 97), batches_of(right, "rv", 61)],
+                 assigner, "device", parallelism=4)
+    assert got == numpy_join(left, right, window_of)
+
+
+def test_keys_that_share_their_low_32_bits_do_not_pair():
+    left = ([WIDE[0], WIDE[1], 5], [10, 20, 30], [1, 2, 3])
+    right = ([WIDE[0], 5, WIDE[1], WIDE[1]], [15, 25, 35, 45],
+             [0.5, 0.25, 0.125, 0.0625])
+    want = numpy_join(left, right, tumbling(100))
+    got, _ = run([[side(*left[:2], "lv", left[2])],
+                  [side(*right[:2], "rv", right[2])]],
+                 TumblingEventTimeWindows.of(100), "device")
+    assert got == want
+    assert sorted(r[1] for r in got) == [5, WIDE[0], WIDE[1], WIDE[1]]
+
+
+def test_a_side_empty_in_a_window_and_rows_at_the_window_edges():
+    # window [0, 100): left only; [100, 200): both, right at 199 (its last
+    # millisecond) and left at 100 (its first); 200 is the next window's
+    left = ([1, 1, 2], [50, 100, 200], [10, 11, 12])
+    right = ([1, 1, 2], [199, 200, 300], [0.5, 0.75, 1.0])
+    want = numpy_join(left, right, tumbling(100))
+    got, sink = run([[side(*left[:2], "lv", left[2])],
+                     [side(*right[:2], "rv", right[2])]],
+                    TumblingEventTimeWindows.of(100), "device")
+    assert got == want == [(200, 1, 11, 0.5)]
+    (b,) = sink.batches
+    assert b[TIMESTAMP_FIELD].tolist() == [199]
+    assert b["window_start"].tolist() == [100]
+
+
+def _empty(name, dtype):
+    return RecordBatch.from_pydict(
+        {"k": np.zeros(0, np.int64), name: np.zeros(0, dtype)},
+        timestamps=np.zeros(0, np.int64))
+
+
+@pytest.mark.parametrize("first", ["left", "right"])
+def test_either_side_may_arrive_first(first):
+    left, right = random_sides(seed=4, n_left=200, n_right=200, keys=10,
+                               t_max=2000)
+    want = numpy_join(left, right, tumbling(400))
+    lb, rb = batches_of(left, "lv", 200), batches_of(right, "rv", 200)
+    # one input's first batch is empty: the other's opens every window
+    if first == "left":
+        batches = [lb, [_empty("rv", np.float64)] + rb]
+    else:
+        batches = [[_empty("lv", np.int32)] + lb, rb]
+    got, _ = run(batches, TumblingEventTimeWindows.of(400), "device",
+                 micro_batch=512)
+    assert got == want and len(want) > 0
+
+
+def test_the_end_of_input_flush_fires_every_open_window():
+    """No watermark passes the last windows: the final one does."""
+    left, right = random_sides(seed=5, n_left=150, n_right=150, keys=6,
+                               t_max=3000)
+    want = numpy_join(left, right, tumbling(1000))
+    got, _ = run([batches_of(left, "lv", 150), batches_of(right, "rv", 150)],
+                 TumblingEventTimeWindows.of(1000), "device",
+                 delay=10_000)
+    assert {w for w, *_ in got} == {1000, 2000, 3000}
+    assert got == want
+
+
+def test_a_fallback_shape_is_counted_and_runs_on_the_host():
+    left, right = random_sides(seed=6, n_left=50, n_right=50, keys=5,
+                               t_max=100)
+    before = join_ops.fallbacks()
+    env = StreamExecutionEnvironment()
+    a = env.from_source(Rows(batches_of(left, "lv", 50)))
+    b = env.from_source(Rows(batches_of(right, "rv", 50)))
+    with pytest.warns(RuntimeWarning, match="processing-time"):
+        t = (a.join(b).where("k").equal_to("k")
+             .window(TumblingProcessingTimeWindows.of(1000)).apply()
+             .transformation)
+    assert join_ops.fallbacks() == before + 1
+    assert isinstance(t.operator_factory(), WindowJoinOperator)
+
+
+def test_a_string_column_moves_the_join_to_the_host_state_and_all():
+    """The left's rows are on the device when the right's first rows
+    bring a column the device cannot carry: they go with the join."""
+    left = side([1, 2, 1], [10, 20, 30], "lv", [1, 2, 3])
+    right = RecordBatch.from_pydict(
+        {"k": np.array([1, 2], np.int64),
+         "rv": np.array(["x", "y"], dtype=object)},
+        timestamps=np.array([40, 50], np.int64))
+    before = join_ops.fallbacks()
+    env = StreamExecutionEnvironment(Configuration(
+        {"execution.micro-batch.size": 8}))
+    sink = CollectSink()
+    a = env.from_source(Rows([left]),
+                        WatermarkStrategy.for_bounded_out_of_orderness(0))
+    b = env.from_source(Rows([_empty("rv", object), right]),
+                        WatermarkStrategy.for_bounded_out_of_orderness(0))
+    (a.join(b).where("k").equal_to("k")
+     .window(TumblingEventTimeWindows.of(100)).apply().sink_to(sink))
+    with pytest.warns(RuntimeWarning, match="cannot ride the device"):
+        env.execute("to-host")
+    assert join_ops.fallbacks() == before + 1
+    got = sorted((k, lv, rv) for s in sink.batches
+                 for k, lv, rv in zip(s["k"].tolist(), s["lv"].tolist(),
+                                      s["rv"].tolist()))
+    assert got == [(1, 1, "x"), (1, 3, "x"), (2, 2, "y")]
+
+
+# ---------------------------------------------------------------- engine
+
+
+def engine(capacity=1 << 12, size=1000):
+    return MeshWindowJoinEngine(TumblingEventTimeWindows.of(size),
+                                ("k", "k"), capacity_per_shard=capacity)
+
+
+def keyed(keys, ts, name, values):
+    b = side(keys, ts, name, values)
+    return b.with_column(KEY_ID_FIELD, b["k"])
+
+
+def test_a_batch_touches_only_its_own_rows_in_the_index():
+    """The index work of a batch is its rows and the pages they reach,
+    whatever is live already: eight times the rows live, the same work."""
+    e = engine(capacity=1 << 16)
+    per_batch = []
+    rng = np.random.default_rng(7)
+    for i in range(16):
+        n = 1000
+        before = e.index[0].touched
+        e.input_side = 0
+        e.process_batch(keyed(rng.integers(0, 50, n), np.full(n, 10 + i),
+                              "lv", np.arange(n, dtype=np.int32)))
+        per_batch.append(e.index[0].touched - before)
+    assert e.index[0].slices[0][1000].rows == 16_000
+    pages = -(-1000 // e.page_rows) + 1
+    assert all(1000 < w <= 1000 + pages for w in per_batch), per_batch
+    assert max(per_batch[8:]) <= max(per_batch[:2])
+
+
+def test_a_fired_window_leaves_as_its_pages():
+    e = engine()
+    for s, (name, v) in enumerate((("lv", np.int32), ("rv", np.float64))):
+        e.input_side = s
+        e.process_batch(keyed([1, 2, 3], [5, 6, 7], name,
+                              np.arange(3).astype(v)))
+    free = [len(i._free[0]) for i in e.index]
+    (out,) = e.on_watermark(2000)
+    assert len(out) == 3
+    assert [len(i._free[0]) for i in e.index] == [f + 1 for f in free]
+    assert e.index[0].live_slices() == [] == e.index[1].live_slices()
+
+
+def test_more_pairs_than_sized_run_again_wider():
+    """A window's output is sized from its rows; a key with more pairs
+    than rows takes a second, wider run at the harvest."""
+    e = engine()
+    n = 300
+    e.input_side = 0
+    e.process_batch(keyed(np.full(n, 9), np.full(n, 5), "lv",
+                          np.arange(n, dtype=np.int32)))
+    e.input_side = 1
+    e.process_batch(keyed(np.full(n, 9), np.full(n, 6), "rv",
+                          np.arange(n, dtype=np.float64)))
+    (out,) = e.on_watermark(1000)
+    assert len(out) == n * n
+    assert sorted(zip(out["lv"].tolist(), out["rv"].tolist())) == sorted(
+        (i, float(j)) for i in range(n) for j in range(n))
+
+
+def test_the_snapshot_is_the_host_operators_and_restores_either_way():
+    left, right = random_sides(seed=8, n_left=120, n_right=120, keys=8,
+                               t_max=1900)
+    e = engine()
+    for s, rows, name in ((0, left, "lv"), (1, right, "rv")):
+        e.input_side = s
+        for b in batches_of(rows, name, 40):
+            e.process_batch(b.with_column(KEY_ID_FIELD, b["k"]))
+    snap = e.snapshot()
+    host = WindowJoinOperator(TumblingEventTimeWindows.of(1000),
+                              key_fields=("k", "k"))
+    host.open(OperatorContext())
+    host.restore_state(snap)
+    again = engine()
+    again.restore(snap)
+
+    def rows(batches):
+        return sorted(r for b in batches for r in zip(
+            *(b[c].tolist() for c in ("window_end", "k", "lv", "rv"))))
+
+    want = numpy_join(left, right, tumbling(1000))
+    assert rows(host.process_watermark(5000)) == want
+    assert rows(again.on_watermark(5000)) == want
+
+
+def test_fine_buckets_pad_by_at_most_a_quarter_and_stick():
+    assert fine_bucket(6_000_000) == 6_291_456
+    assert fine_bucket(123, minimum=8) == 128
+    assert fine_bucket(367, minimum=8) == 384
+    assert fine_bucket(17_500, cached=32_768) == 32_768   # a tail batch
+    assert fine_bucket(200_000, cached=32_768) == 229_376
+    for n in range(300, 5000, 37):
+        assert n <= fine_bucket(n) <= n + n // 4 + 1
+
+
+def test_late_rows_behind_a_fired_window_are_dropped_and_counted():
+    e = engine()
+    e.input_side = 0
+    e.process_batch(keyed([1], [5], "lv", np.array([1], np.int32)))
+    e.input_side = 1
+    e.process_batch(keyed([1], [6], "rv", np.array([0.5])))
+    assert len(e.on_watermark(1500)[0]) == 1
+    e.process_batch(keyed([1, 1], [7, 1600], "rv", np.array([0.1, 0.2])))
+    assert e.late_records_dropped == 1
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        assert e.on_watermark(3000) == []     # no left row in [1000, 2000)
