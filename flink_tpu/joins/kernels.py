@@ -29,10 +29,10 @@ shapes handled one level down by jit + the ``pad_bucket_size`` /
   pages; the put scatters a batch's words to the slots its slices' pages
   gave it. The match runs at a window's fire over the pages of its
   slices: it gathers both sides' words, sorts them together by (key,
-  side, row), counts each right row's partners in its key's run, and
-  expands the pairs into ``out_rows``-wide output columns — every pair,
-  many-to-many, exact on all 64 bits of the key. The host only sizes the
-  output; the pair count comes back with it.
+  side) with their value words, counts each right row's partners in its
+  key's run, and expands the pairs into ``out_rows``-wide output columns
+  — every pair, many-to-many, exact on all 64 bits of the key. The host
+  only sizes the output; the pair count comes back with it.
 - **join-banded-probe**: the banded segment-intersection step. The host
   metadata (sorted ``(key, ts)`` per shard — int64 lives on the host,
   the x32 device plane never sees a key) resolves each probe's band
@@ -231,11 +231,6 @@ def _build_banded_probe(mesh: Mesh, n_cols: int):
     return probe
 
 
-#: bit that sorts a padding row of a window behind every real row of its
-#: key (a window's rows, both sides together, stay under it)
-_PAD_BIT = 1 << 30
-
-
 def build_window_put(mesh: Mesh, n_words: int):
     """``planes[w][p, slot] = block[p, 1 + w]`` for the slots in
     ``block[p, 0]``: one batch of one side into its word planes. Lanes
@@ -289,6 +284,11 @@ def _window_rows(planes, pages, fill, page_rows: int):
     return words, valid
 
 
+#: a row's side, the sort's last key: within a key's run the left rows
+#: come first, then the right rows, then padding lanes
+_LEFT, _RIGHT, _PAD = 0, 1, 2
+
+
 def window_pairs(left, left_valid, right, right_valid, out_rows: int):
     """Every (left, right) pair of equal keys, for the words of one
     window. ``left`` / ``right``: word columns, the key's two first;
@@ -296,47 +296,59 @@ def window_pairs(left, left_valid, right, right_valid, out_rows: int):
     words, then the left's value words, then the right's (lanes past
     ``pairs`` hold anything).
 
-    One sort of both sides by (key, position) with the left's positions
-    first, so each key's run holds its left rows and then its right
-    rows; a right row's partners are its run's left rows, counted by a
-    prefix sum. The pairs are laid out right row after right row, each
-    one's partners in a block; an output lane finds its right row as the
-    last block start at or before it (a scatter and a running maximum)
-    and its left row as the run's start plus its place in the block."""
-    n_left = left[0].shape[0]
-    n = n_left + right[0].shape[0]
+    One sort of both sides by (key, side) that carries each row's value
+    words (a left row's and a right row's share columns), so each key's
+    run holds its left rows, then its right rows, then padding; a right
+    row's partners are its run's left rows, counted by a prefix sum less
+    the count before the run (a running maximum: the count only grows).
+    The pairs are laid out right row after right row, each one's
+    partners in a block; an output lane finds its right row as the last
+    block start at or before it (a scatter and a running maximum) and
+    its left row as the run's start plus its place in the block. After
+    the sort every random read is indexed by those two vectors: two
+    gathers, one per vector."""
+    n_left, n_right = left[0].shape[0], right[0].shape[0]
+    n = n_left + n_right
+    n_lv, n_rv = len(left) - 2, len(right) - 2
     pos = jnp.arange(n, dtype=jnp.int32)
-    valid = jnp.concatenate([left_valid, right_valid])
+    side = jnp.concatenate([jnp.where(left_valid, _LEFT, _PAD),
+                            jnp.where(right_valid, _RIGHT, _PAD)])
     k0 = jnp.concatenate([left[0], right[0]])
     k1 = jnp.concatenate([left[1], right[1]])
-    k1s, k0s, order = jax.lax.sort(
-        (k1, k0, jnp.where(valid, pos, pos | _PAD_BIT)), num_keys=3)
+    zero_l = jnp.zeros((n_left,), jnp.int32)
+    zero_r = jnp.zeros((n_right,), jnp.int32)
+    words = [jnp.concatenate([left[2 + j] if j < n_lv else zero_l,
+                              right[2 + j] if j < n_rv else zero_r])
+             for j in range(max(n_lv, n_rv))]
+    k1s, k0s, sides, *words = jax.lax.sort(
+        (k1, k0, side.astype(jnp.int32), *words), num_keys=3,
+        is_stable=False)
     new_run = jnp.concatenate([
         jnp.ones((1,), bool), (k1s[1:] != k1s[:-1]) | (k0s[1:] != k0s[:-1])])
     run_start = jax.lax.cummax(jnp.where(new_run, pos, 0))
-    is_left = order < n_left
-    is_right = (order >= n_left) & (order < _PAD_BIT)
-    lefts_upto = jnp.cumsum(is_left.astype(jnp.int32))
-    lefts_before_run = lefts_upto[run_start] \
-        - is_left[run_start].astype(jnp.int32)
-    partners = jnp.where(is_right, lefts_upto - lefts_before_run, 0)
+    is_left = (sides == _LEFT).astype(jnp.int32)
+    lefts_upto = jnp.cumsum(is_left)
+    lefts_before_run = jax.lax.cummax(
+        jnp.where(new_run, lefts_upto - is_left, 0))
+    partners = jnp.where(sides == _RIGHT, lefts_upto - lefts_before_run, 0)
     block_end = jnp.cumsum(partners)
     block_start = block_end - partners
     pairs = block_end[-1]
     lane = jnp.arange(out_rows, dtype=jnp.int32)
-    starts = jnp.full((out_rows,), -1, jnp.int32).at[
-        jnp.where(partners > 0, block_start, out_rows)].set(
-            pos, mode="drop")
+    # the block starts rise with the rows (so the scatter needs no sort of
+    # its own); a row without partners shares its start with the next
+    # block's owner, which comes after it and so outweighs it
+    starts = jnp.full((out_rows,), -1, jnp.int32).at[block_start].max(
+        pos, indices_are_sorted=True, mode="drop")
     owner = jnp.maximum(jax.lax.cummax(starts), 0)
-    left_at = jnp.clip(run_start[owner] + lane - block_start[owner],
-                       0, n - 1)
-    left_row = jnp.clip(order[left_at], 0, n_left - 1)
-    right_row = jnp.clip(order[owner] - n_left, 0, n - n_left - 1)
+    # what one index reads is stacked and read by one gather: on the chip
+    # a [c, n] gather costs about what one [n] gather does
+    by_owner = jnp.stack([run_start - block_start] + words[:n_rv])[:, owner]
+    left_at = jnp.clip(by_owner[0] + lane, 0, n - 1)
+    by_left = jnp.stack([k0s, k1s] + words[:n_lv])[:, left_at]
     # the count in int32 wraps past 2**31 pairs; its float sum says so
     approx = jnp.sum(partners.astype(jnp.float32))
-    return ([pairs, approx, k0s[left_at], k1s[left_at]]
-            + [w[left_row] for w in left[2:]]
-            + [w[right_row] for w in right[2:]])
+    return [pairs, approx] + list(by_left) + list(by_owner[1:])
 
 
 def _build_window_match(mesh: Mesh, left_words: int, right_words: int,

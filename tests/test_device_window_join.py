@@ -350,6 +350,213 @@ def test_fine_buckets_pad_by_at_most_a_quarter_and_stick():
         assert n <= fine_bucket(n) <= n + n // 4 + 1
 
 
+# ---------------------------------------------------------------- kernel
+
+I32 = np.iinfo(np.int32)
+
+
+def words(rng, n, n_values, keys):
+    """A side's int32 word columns: the key's two words from the ``[n,
+    2]`` array ``keys``, then ``n_values`` random value words."""
+    keys = np.asarray(keys, dtype=np.int32).reshape(n, 2)
+    return [keys[:, 0], keys[:, 1]] + [
+        rng.integers(I32.min, I32.max, n, dtype=np.int32, endpoint=True)
+        for _ in range(n_values)]
+
+
+def key_pairs(low, high):
+    return np.stack([low, high], axis=1)
+
+
+def brute_join(left, lvalid, right, rvalid):
+    """Every valid (left, right) row pair whose two key words are equal,
+    as sorted ``(key words, left value words, right value words)``."""
+    eq = ((left[0][:, None] == right[0][None, :])
+          & (left[1][:, None] == right[1][None, :])
+          & lvalid[:, None] & rvalid[None, :])
+    i, j = np.nonzero(eq)
+    return sorted(zip(*(c.tolist() for c in (
+        [left[0][i], left[1][i]] + [w[i] for w in left[2:]]
+        + [w[j] for w in right[2:]]))))
+
+
+def unique_left_keys(rng):
+    """The Q8 shape: a person's id once, three in four auctions on a hot
+    seller of its stride, the rest on recent or not yet made ids."""
+    ids = 1000 + np.arange(400)
+    epoch = rng.integers(0, 400, 1200)
+    seller = np.where(rng.random(1200) < 0.75, (epoch // 100) * 100,
+                      epoch - 50 + rng.integers(0, 60, 1200)) + 1000
+    return (words(rng, 400, 1, key_pairs(ids, 0 * ids)), 1,
+            words(rng, 1200, 1, key_pairs(seller, 0 * seller)))
+
+
+def many_to_many(rng):
+    """Both sides draw from 25 keys; left 1 value word, right 2 (an int32
+    and a float64 column)."""
+    lk, rk = rng.integers(0, 25, 300), rng.integers(0, 25, 450)
+    return (words(rng, 300, 1, key_pairs(lk, lk * 7)), 1,
+            words(rng, 450, 2, key_pairs(rk, rk * 7)))
+
+
+def one_hot_key(rng):
+    """Key 7 holds 6 left rows and 500 right rows: every one of those
+    left rows has 500 partners."""
+    lk = np.r_[np.full(6, 7), rng.integers(100, 200, 200)]
+    rk = np.r_[np.full(500, 7), rng.integers(100, 200, 300)]
+    return (words(rng, len(lk), 1, key_pairs(lk, 0 * lk)), 1,
+            words(rng, len(rk), 1, key_pairs(rk, 0 * rk)))
+
+
+def padding_between_rows(rng):
+    """Pages of 16 lanes, each filled part way, and every padding lane
+    holding a real key's words: padding sorts inside real keys' runs."""
+    def side(pages, n_values):
+        fill = rng.integers(0, 17, pages)
+        valid = (np.arange(16)[None, :] < fill[:, None]).reshape(-1)
+        k = rng.integers(0, 12, pages * 16)
+        return words(rng, pages * 16, n_values, key_pairs(k, -k)), valid
+    (left, lvalid), (right, rvalid) = side(20, 1), side(30, 2)
+    return left, lvalid, right, rvalid
+
+
+def extreme_words(rng):
+    """Key words at the ends of int32 and around zero, in every pairing."""
+    ends = np.array([I32.min, I32.min + 1, -1, 0, 1, I32.max - 1, I32.max])
+    lk = rng.choice(ends, (300, 2))
+    rk = rng.choice(ends, (400, 2))
+    return words(rng, 300, 1, lk), 1, words(rng, 400, 1, rk)
+
+
+def shared_low_word(rng):
+    """Keys that share their low word (or their high one) and differ in
+    the other: a join on one word would pair them."""
+    lk = key_pairs(np.full(200, 5), rng.integers(0, 8, 200))
+    rk = key_pairs(np.full(300, 5), rng.integers(4, 12, 300))
+    lk[::3, 0], lk[::3, 1] = rng.integers(0, 8, len(lk[::3])), 9
+    rk[::4, 0], rk[::4, 1] = rng.integers(4, 12, len(rk[::4])), 9
+    return words(rng, 200, 1, lk), 1, words(rng, 300, 2, rk)
+
+
+def no_partner(rng):
+    """Even keys on the left, odd on the right: not one pair."""
+    lk, rk = 2 * rng.integers(0, 50, 200), 2 * rng.integers(0, 50, 300) + 1
+    return (words(rng, 200, 1, key_pairs(lk, 0 * lk)), 1,
+            words(rng, 300, 1, key_pairs(rk, 0 * rk)))
+
+
+def left_wider(rng):
+    """Two value words on the left, none on the right: the sort's value
+    columns are the wider side's."""
+    lk, rk = rng.integers(0, 30, 250), rng.integers(0, 30, 250)
+    return (words(rng, 250, 2, key_pairs(lk, lk)), 1,
+            words(rng, 250, 0, key_pairs(rk, rk)))
+
+
+KERNEL_CASES = {f.__name__: f for f in (
+    unique_left_keys, many_to_many, one_hot_key, padding_between_rows,
+    extreme_words, shared_low_word, no_partner, left_wider)}
+
+
+def kernel_case(name, seed=11):
+    """``(left, left valid, right, right valid)`` of a case; a side
+    given its valid mask as 1 is valid all through and gets 37 padding
+    lanes of random words appended."""
+    rng = np.random.default_rng(seed)
+    left, lvalid, right, *rvalid = KERNEL_CASES[name](rng)
+    rvalid = rvalid[0] if rvalid else 1
+    sides = []
+    for cols, valid in ((left, lvalid), (right, rvalid)):
+        if isinstance(valid, int):
+            pad = words(rng, 37, len(cols) - 2, rng.integers(
+                I32.min, I32.max, (37, 2), dtype=np.int32, endpoint=True))
+            valid = np.r_[np.ones(len(cols[0]), bool), np.zeros(37, bool)]
+            cols = [np.r_[c, p] for c, p in zip(cols, pad)]
+        sides += [cols, valid]
+    return sides
+
+
+def run_window_pairs(left, lvalid, right, rvalid, out_rows):
+    import jax
+
+    from flink_tpu.joins.kernels import window_pairs
+
+    pairs, approx, *cols = jax.jit(window_pairs, static_argnums=4)(
+        left, lvalid, right, rvalid, out_rows)
+    cols = [np.asarray(c) for c in cols]
+    return int(pairs), float(approx), cols
+
+
+@pytest.mark.parametrize("short", [False, True], ids=["sized", "short"])
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_window_pairs_equal_a_brute_force_join(case, short):
+    """The kernel's pairs as a multiset, and its count, against every
+    pair of equal keys found by comparing each row with each. ``short``:
+    an output narrower than the pairs still counts them all (the count
+    the harvest re-runs on) and holds a part of them."""
+    left, lvalid, right, rvalid = kernel_case(case)
+    want = brute_join(left, lvalid, right, rvalid)
+    out_rows = max(len(want) // 3, 1) if short else len(want) + 29
+    pairs, approx, cols = run_window_pairs(left, lvalid, right, rvalid,
+                                           out_rows)
+    assert pairs == len(want) and approx == len(want)
+    assert len(cols) == 2 + (len(left) - 2) + (len(right) - 2)
+    got = list(zip(*(c[:min(pairs, out_rows)].tolist() for c in cols)))
+    if short:
+        from collections import Counter
+        assert not Counter(got) - Counter(want)
+    else:
+        assert sorted(got) == want
+    if case == "no_partner":
+        assert pairs == 0
+    if case == "one_hot_key":
+        assert pairs >= 6 * 500
+
+
+def test_window_pairs_equal_the_q8_reference_on_a_window_of_the_generator():
+    """One NEXmark Q8 window at a sixteenth of the benchmark cell's rows
+    (500,000 persons and auctions), words from the benchmark's own
+    generator, through the kernel: the (id, name, reserve) multiset is
+    the plain reference's, and every row of the window finds its
+    place."""
+    import json
+    import os
+
+    from benchmark.jobs import q8
+    from flink_tpu.joins.engine import _KEY, _to_words
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "nexmark-q8-new-users.json")) as f:
+        o = dict(json.load(f)["job_options"])
+    o["event_rate"] //= 16
+    n = q8.boundary_events(o)
+    assert n == 500_000
+    seed = 2_147_542_123
+    cols, _ = q8.make_generator(seed, o)(0, n)
+    person = cols["kind"] == 0
+
+    def side(key, value):
+        # laid out as a fire finds them: real rows, then padding lanes
+        width = fine_bucket(len(key))
+        out = [np.zeros(width, np.int32) for _ in range(3)]
+        for w, c in zip(out, _to_words(key, _KEY) + [value]):
+            w[:len(key)] = c
+        return out, np.arange(width) < len(key)
+
+    left, lvalid = side(cols["id"][person], cols["name"][person])
+    right, rvalid = side(cols["seller"][~person], cols["reserve"][~person])
+    want = q8.reference_rows(seed, n, o)
+    pairs, _, (k0, k1, name, reserve) = run_window_pairs(
+        left, lvalid, right, rvalid, fine_bucket(int(person.size)))
+    assert pairs == len(want["id"]) >= q8.pairs_floor_per_window(o)
+    key = np.stack([k0[:pairs], k1[:pairs]], axis=1).view(np.int64)[:, 0]
+    got = sorted(zip(key.tolist(), name[:pairs].tolist(),
+                     reserve[:pairs].tolist()))
+    assert got == sorted(zip(want["id"].tolist(), want["name"].tolist(),
+                             want["reserve"].tolist()))
+
+
 def test_late_rows_behind_a_fired_window_are_dropped_and_counted():
     e = engine()
     e.input_side = 0
