@@ -1579,7 +1579,8 @@ class _Fire:
     """One dispatched window fire, until its harvest: what a re-run
     needs, and the slices that leave with it."""
 
-    __slots__ = ("window_end", "pages", "out_rows", "retire", "done")
+    __slots__ = ("window_end", "pages", "out_rows", "retire", "done",
+                 "pending")
 
     def __init__(self, window_end: int, pages, out_rows: int) -> None:
         self.window_end = window_end
@@ -1588,6 +1589,71 @@ class _Fire:
         #: (side, detached slice) to release once harvested
         self.retire: List[Tuple[int, list]] = []
         self.done = False
+        #: the ``PendingFire`` of an asynchronous fire until its harvest
+        self.pending = None
+
+
+#: a staged chunk's lanes per shard, in the side's ingest buckets
+_CHUNK_BUCKETS = 8
+#: batches a side stages before its chunks go up behind the match
+_STAGE_BATCHES = 64
+
+
+class _Stage:
+    """One side's rows on their way to the device: ``[P, 1 + words,
+    lanes]`` blocks (slot first) that each shard fills from the front,
+    batch after batch, one put a block. A batch's own put is one block of
+    its ingest bucket; the rows staged while a match runs fill blocks of
+    ``_CHUNK_BUCKETS`` buckets, fixed when the stage opens, so a flush is
+    a few puts of one compiled shape however many batches were staged."""
+
+    __slots__ = ("width", "lanes", "blocks", "fill", "batches")
+
+    def __init__(self, shards: int, width: int, lanes: int) -> None:
+        self.width = width
+        self.lanes = lanes
+        self.blocks: List[np.ndarray] = []
+        self.fill = [0] * shards
+        self.batches = 0
+
+    def add(self, slots, words, shards, counts) -> None:
+        """A batch's slots and words; ``counts`` its rows per shard,
+        ``shards`` each row's (None: one shard)."""
+        cols = [slots] + words
+        if shards is not None:
+            order = np.argsort(shards, kind="stable")
+            cols = [c[order] for c in cols]
+        at = 0
+        for p, c in enumerate(counts):
+            if c:
+                self._write(p, [col[at:at + c] for col in cols])
+            at += c
+        self.batches += 1
+
+    def _write(self, p: int, cols) -> None:
+        n, done = len(cols[0]), 0
+        while done < n:
+            blk, off = divmod(self.fill[p], self.lanes)
+            if blk == len(self.blocks):
+                self.blocks.append(np.empty(
+                    (len(self.fill), self.width, self.lanes), np.int32))
+            take = min(n - done, self.lanes - off)
+            block = self.blocks[blk]
+            for w, col in enumerate(cols):
+                block[p, w, off:off + take] = col[done:done + take]
+            done += take
+            self.fill[p] += take
+
+    def padded(self, drop_slot: int) -> List[np.ndarray]:
+        """The blocks, each shard's lanes past its rows given the slot
+        ``drop_slot``, which the scatter drops (and words it never
+        reads): written now, at the put, because a ``_grow`` makes the
+        old capacity a live slot."""
+        for i, block in enumerate(self.blocks):
+            for p, f in enumerate(self.fill):
+                block[p, 0, min(max(f - i * self.lanes, 0),
+                                self.lanes):] = drop_slot
+        return self.blocks
 
 
 class MeshWindowJoinEngine(JoinEngineBase):
@@ -1605,7 +1671,15 @@ class MeshWindowJoinEngine(JoinEngineBase):
       groups' shards, slots from each (shard, slice)'s pages
       (:class:`~flink_tpu.joins.side_table.WindowPages`), and one scatter
       of its int32 words into the side's planes — host work in the
-      batch's rows only: no live row is read, merged or sorted.
+      batch's rows only: no live row is read, merged or sorted. While an
+      asynchronous fire of this engine runs on the device, a batch is
+      indexed as ever but its rows wait in a host stage per side
+      (:class:`_Stage`, counted by the instant ``join.staged``) instead of
+      queueing a put behind the match; ``flush`` sends each side's stage
+      as a few puts of one fixed width — when the match lands (the
+      operator's poll, before the harvest), before any match is
+      dispatched, before the planes or the index are read or grown, and
+      at ``_STAGE_BATCHES`` staged batches.
     - **Fire** (``on_watermark``): one program over the pages of the
       window's slices finds every pair (``kernels.window_pairs``). The
       host sizes the output from the window's row counts (and the last
@@ -1653,6 +1727,10 @@ class MeshWindowJoinEngine(JoinEngineBase):
         #: the newest fire not yet harvested: slices that expire while
         #: it is in flight leave with it
         self._in_flight: Optional[_Fire] = None
+        #: per side, the rows staged while a match runs (None: none)
+        self._stage: List[Optional[_Stage]] = [None, None]
+        #: asynchronous fires dispatched and not yet harvested
+        self._fires_out = 0
 
     # ------------------------------------------------------------ columns
 
@@ -1694,10 +1772,14 @@ class MeshWindowJoinEngine(JoinEngineBase):
 
     # ------------------------------------------------------------- ingest
 
-    def process_batch(self, batch: RecordBatch) -> None:
+    def process_batch(self, batch: RecordBatch) -> bool:
+        """Index the batch and put its rows up — or, while a match of
+        this engine runs, stage them. True where something was dispatched
+        (the batch's put or a flush): a fence then has work to stand
+        behind."""
         side = self.input_side
         if len(batch) == 0:
-            return
+            return False
         from flink_tpu.observe import flight_recorder as flight
 
         with self._flight_ingest():
@@ -1708,7 +1790,7 @@ class MeshWindowJoinEngine(JoinEngineBase):
                 if live is not None:
                     ends, batch = ends[live], batch.filter(live)
                 if len(batch) == 0:
-                    return
+                    return False
                 if ends[0] == ends[-1] and (ends == ends[0]).all():
                     # one slice: the batch of an in-order stream but the
                     # one that crosses a slice's end
@@ -1721,8 +1803,30 @@ class MeshWindowJoinEngine(JoinEngineBase):
                 slots = self._allocate(side, len(batch), shards, uniq,
                                        slice_of)
                 s.work = len(batch)
+                staging = self.match_running()
+                if staging:
+                    flight.instant("join.staged", work=len(batch))
+            if staging:
+                return self._stage_rows(side, shards, slots, batch, cols)
+            # rows staged behind a match that has landed go first
+            self.flush()
             with flight.span("device.dispatch"):
                 self._put(side, shards, slots, batch, cols)
+        return True
+
+    def match_running(self) -> bool:
+        """Whether the one asynchronous fire of this engine not yet
+        harvested is still on the device: a put dispatched now would wait
+        behind it. False while two are out: the device is then a window
+        behind, and the batches' puts and fences hold the loop back."""
+        fire = self._in_flight
+        return (self._fires_out == 1 and fire.pending is not None
+                and not fire.pending.ready())
+
+    @property
+    def staged(self) -> bool:
+        """Whether rows wait in the host stage."""
+        return self._stage != [None, None]
 
     def _allocate(self, side: int, n: int, shards, uniq, slice_of
                   ) -> np.ndarray:
@@ -1765,6 +1869,7 @@ class MeshWindowJoinEngine(JoinEngineBase):
         device; rare: capacity is sized for the live rows)."""
         import jax.numpy as jnp
 
+        self.flush()
         capacity = num_pages * self.page_rows
         for side in range(len(SIDE_NAMES)):
             self.index[side].grow(num_pages)
@@ -1776,38 +1881,61 @@ class MeshWindowJoinEngine(JoinEngineBase):
                     for pl in self._planes[side])
         self.capacity = capacity
 
-    def _put(self, side: int, shards, slots, batch, cols) -> None:
-        """One scatter of the batch's words to their slots: a ``[P, 1 +
-        words, B]`` block, slot first, up in one ``device_put``."""
-        from flink_tpu.joins.kernels import build_window_put
-
+    def _words(self, side: int, shards, batch, cols):
+        """The batch's int32 word columns (the key's two first), its rows
+        per shard, and the side's sticky ingest bucket for them."""
         words = _to_words(batch[KEY_ID_FIELD], _KEY) + [
             w for c in cols if c.words and c.name != KEY_ID_FIELD
             for w in _to_words(batch[c.name], c)]
-        n = len(slots)
-        counts = [n] if shards is None else \
+        counts = [len(batch)] if shards is None else \
             np.bincount(shards, minlength=self.P).tolist()
         b = self._ingest_bucket[side] = fine_bucket(
             max(counts), self._ingest_bucket[side])
-        # lanes past a shard's rows: the slot ``capacity``, which the
-        # scatter drops, and words it never reads
-        block = np.empty((self.P, 1 + len(words), b), dtype=np.int32)
-        block[:, 0, :] = self.capacity
-        if shards is None:
-            block[0, 0, :n] = slots
-            for w, v in enumerate(words):
-                block[0, 1 + w, :n] = v
-        else:
-            rows = np.stack([slots] + words)[:, np.argsort(shards,
-                                                            kind="stable")]
-            at = 0
-            for p, c in enumerate(counts):
-                block[p, :, :c] = rows[:, at:at + c]
-                at += c
-        prog = build_window_put(self.mesh, len(words))
+        return words, counts, b
+
+    def _put(self, side: int, shards, slots, batch, cols) -> None:
+        """One scatter of the batch's words to their slots: a ``[P, 1 +
+        words, B]`` block, slot first, up in one ``device_put``."""
+        words, counts, b = self._words(side, shards, batch, cols)
+        block = _Stage(self.P, 1 + len(words), b)
+        block.add(slots, words, shards, counts)
+        self._put_stage(side, block)
+
+    def _stage_rows(self, side: int, shards, slots, batch, cols) -> bool:
+        """The batch's rows into its side's stage, whose chunks are
+        ``_CHUNK_BUCKETS`` ingest buckets wide; at ``_STAGE_BATCHES``
+        staged batches every stage goes up. True where it did."""
+        words, counts, b = self._words(side, shards, batch, cols)
+        stage = self._stage[side]
+        if stage is None:
+            stage = self._stage[side] = _Stage(self.P, 1 + len(words),
+                                               _CHUNK_BUCKETS * b)
+        stage.add(slots, words, shards, counts)
+        return stage.batches >= _STAGE_BATCHES and self.flush()
+
+    def _put_stage(self, side: int, stage: _Stage) -> None:
+        from flink_tpu.joins.kernels import build_window_put
+
+        prog = build_window_put(self.mesh, stage.width - 1)
         with self._wd_section("join_ingest"):
-            self._planes[side] = prog(
-                self._planes[side], jax.device_put(block, self._sharding))
+            for block in stage.padded(self.capacity):
+                self._planes[side] = prog(
+                    self._planes[side],
+                    jax.device_put(block, self._sharding))
+
+    def flush(self) -> bool:
+        """Every staged row up, a chunk a put, both sides. True where any
+        row was staged."""
+        if not self.staged:
+            return False
+        from flink_tpu.observe import flight_recorder as flight
+
+        stages, self._stage = self._stage, [None, None]
+        with flight.span("device.dispatch"):
+            for side, stage in enumerate(stages):
+                if stage is not None:
+                    self._put_stage(side, stage)
+        return True
 
     def make_fence(self):
         """A tiny program behind everything dispatched so far: ready once
@@ -1853,6 +1981,8 @@ class MeshWindowJoinEngine(JoinEngineBase):
         from flink_tpu.observe import flight_recorder as flight
         from flink_tpu.runtime.pending import PendingFire
 
+        # the window's rows on the device before its match reads them
+        self.flush()
         with flight.span("join.match") as s:
             slices = self.assigner.slice_ends_for_window(window_end)
             windows = [[self.index[side].window(p, slices)
@@ -1874,7 +2004,11 @@ class MeshWindowJoinEngine(JoinEngineBase):
             self._in_flight = fire
             pf = PendingFire(arrays, lambda host, f=fire: self._build(f, host),
                              watchdog=self._watchdog)
-        return pf if async_ok else pf.harvest()
+        if not async_ok:
+            return pf.harvest()
+        fire.pending = pf
+        self._fires_out += 1
+        return pf
 
     def _page_block(self, side: int, window) -> tuple:
         """The window's ``[P, pages]`` page ids and rows per page, on the
@@ -1925,6 +2059,9 @@ class MeshWindowJoinEngine(JoinEngineBase):
         cols = [np.concatenate([h[p, :pairs[p]] for p in range(self.P)])
                 if self.P > 1 else h[0, :pairs[0]] for h in host[2:]]
         fire.done = True
+        if fire.pending is not None:
+            fire.pending = None
+            self._fires_out -= 1
         if fire.retire:
             self._retire(fire.retire)
             fire.retire = []
@@ -1971,6 +2108,7 @@ class MeshWindowJoinEngine(JoinEngineBase):
         (one dict of columns per side that holds rows; a row's timestamp
         comes back as its slice's last millisecond, the only thing of it
         the join reads)."""
+        self.flush()
         planes = [None if p is None else self._harvest_get(
             list(p), "snapshot_harvest") for p in self._planes]
         out: Dict[int, Tuple[list, list]] = {}
@@ -2022,6 +2160,7 @@ class MeshWindowJoinEngine(JoinEngineBase):
                 ) -> None:
         from flink_tpu.runtime.join_operators import _filter_by_key_groups
 
+        self.flush()
         self.book.restore(snap["book"])
         for se, sides in sorted(snap.get("buf", {}).items()):
             for side, parts in enumerate(sides):

@@ -255,13 +255,26 @@ class DeviceWindowJoinOperator(AsyncFireOperator):
             return self.host.process_batch(batch, input_index)
         self.windower.input_side = input_index
         try:
-            self.windower.process_batch(batch)
+            dispatched = self.windower.process_batch(batch)
         except UnsupportedJoinColumn as e:
             self._to_host(str(e))
             return self.host.process_batch(batch, input_index)
-        if self._async_fires:
+        if dispatched and self._async_fires:
             self._push_fence(self.windower.make_fence())
         return []
+
+    def poll_pending_output(self, wait: bool = False):
+        """The base class's harvests, after the rows the engine staged
+        while its match ran go up: they run on the device while the host
+        builds the fire's columns. One fence stands behind them."""
+        w = self.windower
+        if w is not None and w.staged and (wait or not w.match_running()):
+            self._flush()
+        return super().poll_pending_output(wait)
+
+    def _flush(self) -> None:
+        if self.windower.flush() and self._async_fires:
+            self._push_fence(self.windower.make_fence())
 
     def process_watermark(self, watermark, input_index=0
                           ) -> List[RecordBatch]:
@@ -303,6 +316,11 @@ class DeviceWindowJoinOperator(AsyncFireOperator):
             return self.host.restore_state(state, key_group_filter)
         return self.windower.restore(state,
                                      key_group_filter=key_group_filter)
+
+    def close(self) -> List[RecordBatch]:
+        if self.windower is not None:
+            self._flush()
+        return []
 
     def dispose(self) -> None:
         self._pending.clear()

@@ -83,6 +83,9 @@ KNOWN_SPAN_KINDS = (
                            # batch's slices and shards, its slots from its
                            # slices' pages (inside batch.ingest; work:
                            # rows inserted)
+    "join.staged",         # rows of a batch indexed while the join's
+                           # match ran and held on the host, not put
+                           # (instant inside join.index; work: rows)
     "join.match",          # the window join's fire on the host: the
                            # window's pages handed to the match program,
                            # the output sized (inside fire.dispatch; work:

@@ -340,6 +340,252 @@ def test_the_snapshot_is_the_host_operators_and_restores_either_way():
     assert rows(again.on_watermark(5000)) == want
 
 
+# ------------------------------------------- rows staged behind a match
+
+
+class HeldFires:
+    """A test double on ``PendingFire.ready``: every fire reads not ready
+    while ``held``; with ``looks``, a fire reads not ready for its first
+    ``looks`` looks and then as it is."""
+
+    def __init__(self, monkeypatch, looks=None):
+        from flink_tpu.runtime.pending import PendingFire
+
+        real = PendingFire.ready
+        self.held = looks is None
+        seen = {}
+
+        def ready(pf):
+            seen[pf] = seen.get(pf, 0) + 1
+            if self.held or seen[pf] <= (looks or 0):
+                return False
+            return real(pf)
+
+        monkeypatch.setattr(PendingFire, "ready", ready)
+
+
+def pairs_of(batches):
+    return sorted(r for b in batches if b is not None for r in zip(
+        *(b[c].tolist() for c in ("window_end", "k", "lv", "rv"))))
+
+
+def tumbling_join(left, right, size):
+    """``numpy_join`` for tumbling windows, by (window, key) groups: fast
+    enough for thousands of rows a side."""
+    from collections import defaultdict
+
+    lefts = defaultdict(list)
+    for k, t, v in zip(*left):
+        lefts[(int(t) // size + 1) * size, int(k)].append(v)
+    return sorted((w, k, lv, rv) for k, t, rv in zip(*right)
+                  for w in [(int(t) // size + 1) * size]
+                  for lv in lefts.get((w, int(k)), ()))
+
+
+def next_window(seed, n_left, n_right, keys):
+    """Random rows of the window [1000, 2000)."""
+    left, right = random_sides(seed, n_left, n_right, keys, t_max=1000)
+    return tuple((k, t + 1000, v) for k, t, v in (left, right))
+
+
+def stage(e, left, right, size=250):
+    for s, rows, name in ((0, left, "lv"), (1, right, "rv")):
+        e.input_side = s
+        for b in batches_of(rows, name, size):
+            assert e.process_batch(b.with_column(KEY_ID_FIELD, b["k"])) \
+                is False
+    e.input_side = 0
+
+
+#: the first window's pairs, as ``staged_engine`` ingests it
+FIRST = [(1000, 1, 0, 0.0), (1000, 2, 1, 1.0), (1000, 3, 2, 2.0)]
+
+
+def staged_engine(monkeypatch, capacity=1 << 12):
+    """An engine whose first window is fired asynchronously and held not
+    ready: what it ingests next waits in its stage."""
+    hold = HeldFires(monkeypatch)
+    e = engine(capacity)
+    for s, (name, v) in enumerate((("lv", np.int32), ("rv", np.float64))):
+        e.input_side = s
+        assert e.process_batch(keyed([1, 2, 3], [5, 6, 7], name,
+                                     np.arange(3).astype(v)))
+    (pf,) = e.on_watermark(1000, async_ok=True)
+    assert e.match_running() and not e.staged
+    return e, pf, hold
+
+
+def test_rows_staged_over_several_chunks_pair_as_numpy(monkeypatch):
+    e, pf, hold = staged_engine(monkeypatch)
+    left, right = next_window(21, 3000, 2500, keys=300)
+    stage(e, left, right)
+    # 8 buckets of 256 lanes a chunk: 3,000 and 2,500 rows take two each
+    assert [(len(s.blocks), s.lanes, s.fill) for s in e._stage] == [
+        (2, 2048, [3000]), (2, 2048, [2500])]
+    hold.held = False
+    assert pairs_of([pf.harvest()]) == FIRST
+    want = tumbling_join(left, right, 1000)
+    assert len(want) > 10_000
+    assert pairs_of(e.on_watermark(2000)) == want
+    assert not e.staged
+
+
+def test_a_grow_while_rows_are_staged_keeps_the_old_capacity_slot(
+        monkeypatch):
+    """Two pages a side; the first window holds one. 100 staged rows take
+    the other, then 200 more grow the planes to four pages: the stage
+    goes up first, its padding lanes on the slot 256 that the old planes
+    drop, and the row that takes slot 256 after the grow keeps it."""
+    e, pf, hold = staged_engine(monkeypatch, capacity=256)
+    assert (e.capacity, e.page_rows) == (256, 128)
+    keys = np.arange(300) * 3 + 10
+    left = (keys, np.full(300, 1500), np.arange(300, dtype=np.int32))
+    right = (keys[::2], np.full(150, 1600), np.arange(150) / 4)
+    e.input_side = 0
+    assert e.process_batch(keyed(keys[:100], left[1][:100], "lv",
+                                 left[2][:100])) is False
+    assert e.staged and e.capacity == 256
+    assert e.process_batch(keyed(keys[100:], left[1][100:], "lv",
+                                 left[2][100:])) is False
+    # the stage went up before the grow; the 200 rows are staged anew
+    assert e.capacity == 512 and e._stage[0].fill == [200]
+    e.input_side = 1
+    assert e.process_batch(keyed(*right[:2], "rv", right[2])) is False
+    hold.held = False
+    assert e.flush() and not e.staged
+    # slot 256 opens the first new page: the 200's 29th row (100 + 28
+    # rows fill the last old page)
+    k0 = np.asarray(e._planes[0][0])[0]
+    assert k0[256] == keys[128] and (k0[384:428] == keys[256:]).all()
+    assert pairs_of([pf.harvest()]) == FIRST
+    assert pairs_of(e.on_watermark(2000)) == numpy_join(
+        left, right, tumbling(1000))
+
+
+def test_a_snapshot_while_rows_are_staged_holds_them(monkeypatch):
+    e, pf, hold = staged_engine(monkeypatch)
+    left, right = next_window(22, 400, 500, keys=30)
+    stage(e, left, right, size=97)
+    snap = e.snapshot()
+    assert not e.staged
+    (sides,) = snap["buf"].values()
+    assert [sum(len(c["k"]) for c in parts) for parts in sides] == [400,
+                                                                    500]
+    again = engine()
+    again.restore(snap)
+    hold.held = False
+    assert pairs_of([pf.harvest()]) == FIRST
+    want = tumbling_join(left, right, 1000)
+    assert pairs_of(again.on_watermark(2000)) == want
+    assert pairs_of(e.on_watermark(2000)) == want
+
+
+def test_a_fire_dispatched_while_rows_are_staged_sees_them(monkeypatch):
+    e, pf, hold = staged_engine(monkeypatch)
+    left, right = next_window(23, 400, 500, keys=30)
+    stage(e, left, right, size=61)
+    (pf2,) = e.on_watermark(2000, async_ok=True)
+    assert not e.staged
+    # two fires out: the device is a window behind, and a batch goes up
+    e.input_side = 0
+    assert e.process_batch(keyed([5], [2500], "lv", np.array([9], np.int32)))
+    assert not e.staged and not e.match_running()
+    assert pairs_of([pf.harvest()]) == FIRST
+    # one out again, and held: the next batch is staged
+    assert e.match_running()
+    assert e.process_batch(keyed([6], [2600], "lv",
+                                 np.array([8], np.int32))) is False
+    hold.held = False
+    assert pairs_of([pf2.harvest()]) == tumbling_join(left, right, 1000)
+    assert e.staged and not e.match_running()
+
+
+def test_synchronous_fires_stage_nothing(monkeypatch):
+    from flink_tpu.observe import flight_recorder as flight
+
+    HeldFires(monkeypatch)
+    rec = flight.recorder()
+    rec.clear()
+    e = engine()
+    for s, (name, v) in enumerate((("lv", np.int32), ("rv", np.float64))):
+        e.input_side = s
+        e.process_batch(keyed([1, 2, 3], [5, 6, 7], name,
+                              np.arange(3).astype(v)))
+    assert pairs_of(e.on_watermark(1000)) == FIRST
+    left, right = next_window(24, 200, 200, keys=20)
+    for s, rows, name in ((0, left, "lv"), (1, right, "rv")):
+        e.input_side = s
+        for b in batches_of(rows, name, 50):
+            assert e.process_batch(b.with_column(KEY_ID_FIELD, b["k"]))
+            assert not e.match_running() and not e.staged
+    assert "join.staged" not in rec.kind_totals()
+    # and a job whose executor fires synchronously
+    c = dict(CASES["hot_many_to_many"])
+    assigner, window_of = c.pop("assigner"), c.pop("windows")
+    left, right = random_sides(**c)
+    env = StreamExecutionEnvironment(Configuration({
+        "execution.window.async-fires": False,
+        "execution.micro-batch.size": 64,
+        "state.slot-table.capacity": 1 << 12}))
+    sink = CollectSink()
+    a, b = (env.from_source(Rows(bs), WatermarkStrategy
+                            .for_bounded_out_of_orderness(0))
+            for bs in (batches_of(left, "lv", 97),
+                       batches_of(right, "rv", 61)))
+    a.join(b).where("k").equal_to("k").window(assigner).apply() \
+        .sink_to(sink)
+    env.execute("wj-sync")
+    assert pairs_of(sink.batches) == numpy_join(left, right, window_of)
+    assert "join.staged" not in rec.kind_totals()
+    rec.clear()
+
+
+def test_join_staged_counts_exactly_the_rows_staged(monkeypatch):
+    from flink_tpu.observe import flight_recorder as flight
+
+    rec = flight.recorder()
+    e, pf, hold = staged_engine(monkeypatch)
+    rec.clear()
+    left, right = next_window(25, 300, 200, keys=20)
+    stage(e, left, right, size=70)
+    # a batch with three rows behind the fired window: they are dropped,
+    # not staged
+    e.input_side = 1
+    assert e.process_batch(keyed([1, 2, 3, 4], [900, 950, 999, 1999], "rv",
+                                 np.arange(4) / 2)) is False
+    kt = rec.kind_totals()["join.staged"]
+    assert kt["work"] == sum(sum(s.fill) for s in e._stage) == 501
+    assert kt["count"] == 5 + 3 + 1
+    assert e.late_records_dropped == 3
+    hold.held = False
+    pf.harvest()
+    e.flush()
+    assert "join.staged" in rec.kind_totals()
+    assert rec.kind_totals()["join.staged"]["work"] == 501
+    rec.clear()
+
+
+@pytest.mark.parametrize("parallelism", [1, 4])
+def test_a_job_whose_fires_land_late_stages_and_equals_numpy(
+        monkeypatch, parallelism):
+    """Each fire reads not ready for its first four looks: the batches
+    that arrive meanwhile are staged, and go up at the poll that finds
+    the fire landed, before its harvest."""
+    from flink_tpu.observe import flight_recorder as flight
+
+    HeldFires(monkeypatch, looks=4)
+    rec = flight.recorder()
+    rec.clear()
+    c = dict(CASES["hot_many_to_many"])
+    assigner, window_of = c.pop("assigner"), c.pop("windows")
+    left, right = random_sides(**c)
+    got, _ = run([batches_of(left, "lv", 97), batches_of(right, "rv", 61)],
+                 assigner, "device", parallelism=parallelism)
+    assert got == numpy_join(left, right, window_of)
+    assert rec.kind_totals()["join.staged"]["work"] > 0
+    rec.clear()
+
+
 def test_fine_buckets_pad_by_at_most_a_quarter_and_stick():
     assert fine_bucket(6_000_000) == 6_291_456
     assert fine_bucket(123, minimum=8) == 128

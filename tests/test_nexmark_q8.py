@@ -131,6 +131,83 @@ def test_a_tiny_run_equals_its_reference():
     rec.clear()
 
 
+def test_a_tiny_run_with_rows_staged_behind_each_match_is_correct(
+        monkeypatch):
+    """Each fire reads not ready for its first three looks (a test double
+    on ``PendingFire.ready``), so the batches that arrive behind it are
+    staged on the host whatever the CPU's speed: the run is exact, every
+    staged row was indexed, and the stage took rows."""
+    from flink_tpu.runtime.pending import PendingFire
+
+    real, looks = PendingFire.ready, {}
+
+    def ready(pf):
+        looks[pf] = looks.get(pf, 0) + 1
+        return looks[pf] > 3 and real(pf)
+
+    monkeypatch.setattr(PendingFire, "ready", ready)
+    rec = flight.recorder()
+    rec.clear()
+    v = verdict(config())
+    assert v["numbers"]["rows_wrong"]["value"] == 0 and v["failed"] == 0
+    kt = rec.kind_totals()
+    assert 0 < kt["join.staged"]["work"] < kt["join.index"]["work"]
+    rec.clear()
+
+
+def test_a_window_aggregate_pushes_and_awaits_one_fence_per_batch(
+        monkeypatch):
+    """The aggregates' side of ``AsyncFireOperator`` is the join's to
+    leave alone: one fence pushed per batch, one wait per push past the
+    dispatch-ahead bound, and no hook of the join's on their path."""
+    from flink_tpu import Configuration, StreamExecutionEnvironment
+    from flink_tpu.connectors.sinks import CollectSink
+    from flink_tpu.connectors.sources import DataGenSource
+    from flink_tpu.joins.operators import DeviceWindowJoinOperator
+    from flink_tpu.runtime.operators import AsyncFireOperator, \
+        WindowAggOperator
+    from flink_tpu.runtime.watermarks import WatermarkStrategy
+
+    for name in ("_push_fence", "_await_fences", "poll_pending_output",
+                 "_harvest_one"):
+        assert getattr(WindowAggOperator, name) \
+            is getattr(AsyncFireOperator, name), name
+    assert DeviceWindowJoinOperator.poll_pending_output \
+        is not AsyncFireOperator.poll_pending_output
+    counts = {"push": 0, "await": 0, "batches": 0}
+    push, wait = AsyncFireOperator._push_fence, \
+        AsyncFireOperator._await_fences
+    process = WindowAggOperator.process_batch
+
+    def counted_push(self, fence):
+        counts["push"] += fence is not None
+        push(self, fence)
+
+    def counted_await(self):
+        counts["await"] += 1
+        wait(self)
+
+    def counted_process(self, batch, input_index=0):
+        counts["batches"] += 1
+        return process(self, batch, input_index)
+
+    monkeypatch.setattr(AsyncFireOperator, "_push_fence", counted_push)
+    monkeypatch.setattr(AsyncFireOperator, "_await_fences", counted_await)
+    monkeypatch.setattr(WindowAggOperator, "process_batch", counted_process)
+    env = StreamExecutionEnvironment(Configuration(
+        {"execution.micro-batch.size": 8192}))
+    sink = CollectSink()
+    env.add_source(DataGenSource(total_records=100_000, num_keys=500,
+                                 events_per_second_of_eventtime=100_000),
+                   WatermarkStrategy.for_bounded_out_of_orderness(0)) \
+        .key_by("key").window(TumblingEventTimeWindows.of(200)) \
+        .sum("value").sink_to(sink)
+    env.execute("fences")
+    assert counts["batches"] == 13 and len(sink.rows()) > 0
+    assert counts["push"] == counts["batches"]
+    assert counts["await"] == counts["push"] - 4
+
+
 def test_host_mode_runs_the_numpy_operator_to_the_same_rows():
     from benchmark.harness.traffic import StampingSink
     from flink_tpu import Configuration, StreamExecutionEnvironment
