@@ -19,7 +19,7 @@ under ``SKIP_PAST_LAST_EVENT`` the winning match is the lowest set bit,
 under ``NO_SKIP`` multiple completions on one event emit in bit order
 with the virtual-start completion (bit ``Q``) last.  Bit-identity with
 the host ``CepOperator`` — values AND emission order — is the contract
-``tools/cep_smoke.py`` gates.
+``tests/test_mesh_cep.py`` gates.
 
 Event references ride ``ring`` planes: the last ``R = Σ max_i − 1``
 processed event sequence numbers per key, shifted one step per event —
@@ -35,8 +35,8 @@ Program families, all cached in the shared tenancy
 
 - **cep-advance**: keyed on ``(device ids, compiled pattern layout)`` —
   two engines running the same pattern shape on the same mesh share the
-  executable (the multi-tenant zero-recompile contract; gated by the
-  CEP phase of ``tools/recompile_smoke.py``).  One ``lax.scan`` over
+  executable (the multi-tenant zero-recompile contract; gated by
+  ``tests/test_stateplane.py``'s CEP tier walk).  One ``lax.scan`` over
   the due-event axis, transitions unrolled over the ``Q`` settled
   states inside.
 - **cep-prune**: the watermark ``within``-expiry scatter

@@ -15,8 +15,8 @@ ingest scatter (fused keyBy exchange under ``shuffle.mode=device``),
 the NFA advance, one eviction gather under budget pressure and the
 within-expiry prune — all shared through the tenancy ``PROGRAM_CACHE``
 and shape-bounded by the ``pad_bucket_size`` / ``sticky_bucket`` tier
-discipline, so steady state compiles nothing (the CEP phase of
-``tools/recompile_smoke.py``).
+discipline, so steady state compiles nothing (``tests/test_mesh_cep.py``
+and the CEP tier walk of ``tests/test_stateplane.py``).
 
 It rides the existing machinery end-to-end, the way ``joins/`` does:
 ``stage_device_exchange`` staging with the double-buffer fence
@@ -32,7 +32,7 @@ matches are queryable state like any aggregate.
 
 ``backend="host"`` wraps the reference :class:`CepOperator` — the
 bit-identity oracle (values AND emission order) for every pattern the
-device path accepts, gated by ``tools/cep_smoke.py``. Patterns outside
+device path accepts, gated by ``tests/test_mesh_cep.py``. Patterns outside
 the bounded-partial class raise :class:`UnsupportedCepPattern` at
 construction; callers fall back LOUDLY (``record_host_fallback``).
 
@@ -175,7 +175,7 @@ class MeshCepEngine:
     :class:`~flink_tpu.cep.kernels.DevicePatternLayout` (raises
     :class:`~flink_tpu.cep.kernels.UnsupportedCepPattern` otherwise);
     ``backend="host"`` wraps the reference operator behind the same
-    interface — the oracle both the smoke and the chaos harness pin
+    interface — the oracle both the tests and the chaos harness pin
     the device path against, bit for bit."""
 
     def __init__(self, pattern: Pattern,

@@ -69,7 +69,7 @@ class ChaosReport:
     #: declared dead, key-group ranges restored from their checkpoint
     #: units, and records re-absorbed to rebuild those ranges — the
     #: bounded-replay claim is ``records_replayed <= events/shards +
-    #: padding``, gated in tools/chaos_smoke.py
+    #: padding``, gated in tests/test_shard_failover.py
     shards_lost: int = 0
     shard_restores: int = 0
     records_replayed: int = 0

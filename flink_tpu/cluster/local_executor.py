@@ -539,13 +539,6 @@ class LocalExecutor:
 
         batch_size = self.config.get(BatchOptions.BATCH_SIZE)
         max_parallelism = self.config.get(CoreOptions.MAX_PARALLELISM)
-        # stateplane.backend.<family>=pallas|xla: applied (and validated
-        # LOUDLY — unknown family/backend fails at submit, not mid-run)
-        # before any engine builds a program; backend selection is
-        # process-global, like the program cache the keys live in
-        from flink_tpu.stateplane import configure_backends
-
-        configure_backends(self.config)
         ckpt_interval = self.config.get(CheckpointOptions.INTERVAL_MS)
         ckpt_every_n = self.config.get(CheckpointOptions.EVERY_N_BATCHES)
         ckpt_dir = self.config.get(StateOptions.CHECKPOINT_DIR)
@@ -1214,7 +1207,7 @@ class LocalExecutor:
         # under sustained lookup load, clients re-submit while a served
         # request's device read releases the GIL — an unbounded drain
         # would keep serving forever and starve the data path (observed
-        # as a livelock in the serving smoke's batched mode)
+        # as a livelock under batched lookup load)
         budget = max(control_queue.qsize(), 1)
         while budget > 0:
             budget -= 1

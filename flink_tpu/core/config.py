@@ -88,20 +88,6 @@ class Configuration:
 
     # -- raw access ---------------------------------------------------------
 
-    def keys(self):
-        """Every key visible through this configuration — own layer
-        plus the fallback chain, own layer first on duplicates. The
-        scan surface for prefix-keyed option namespaces (e.g.
-        ``stateplane.backend.<family>``): a consumer that only probes
-        the names it knows would silently ignore a typo'd key."""
-        out = dict.fromkeys(self._data)
-        fb = self._fallback
-        while fb is not None:
-            for k in fb._data:
-                out.setdefault(k)
-            fb = fb._fallback
-        return list(out)
-
     def get_raw(self, key: str, default: Any = None) -> Any:
         found, value = self._lookup(key)
         return value if found else default
@@ -132,6 +118,8 @@ class Configuration:
         return c
 
     def keys(self) -> List[str]:
+        """Every key visible through this configuration: its own layer
+        plus the fallback chain."""
         return list(self.to_dict().keys())
 
     def __iter__(self) -> Iterator[str]:

@@ -9,7 +9,8 @@ programs — the ingest put/exchange, the banded probe, and (under
 budget pressure) one eviction gather — all cached in the shared
 ``PROGRAM_CACHE`` and shape-bounded by the ``pad_bucket_size`` /
 ``sticky_bucket`` tier discipline, so steady state compiles nothing
-(gated by the join phase of ``tools/recompile_smoke.py``).
+(gated by ``tests/test_device_joins.py`` and the join tier walk of
+``tests/test_stateplane.py``).
 
 - :class:`MeshIntervalJoinEngine` — keyed interval join (left row at
   ``t`` matches right rows in ``[t+lower, t+upper]``,

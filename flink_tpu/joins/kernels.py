@@ -14,7 +14,7 @@ shapes handled one level down by jit + the ``pad_bucket_size`` /
 - **join-exchange-put**: the device-mode ingest: flat staged columns go
   up in ONE ``device_put``, and a single program segment-sorts each
   shard's chunk into per-destination buckets (the stateplane
-  ``exchange-rank`` combinator, xla or pallas backend —
+  rank-within-destination combinator —
   stream order preserved per destination, same as the host path),
   ``all_to_all``-exchanges them over the mesh axis and scatters the
   received rows into the plane — keyBy exchange + state write as one
@@ -60,7 +60,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from flink_tpu.parallel.mesh import KEY_AXIS, shard_map
-from flink_tpu.stateplane.backends import backend_of
 from flink_tpu.stateplane.rank import exchange_rank_flat
 from flink_tpu.tenancy.program_cache import PROGRAM_CACHE
 
@@ -102,17 +101,14 @@ def build_join_exchange_put(mesh: Mesh, dtypes: Tuple[str, ...]):
     chunk into per-destination buckets, ``all_to_all`` them over the
     mesh axis, scatter the received (slot, values) rows into the plane
     — one compiled program from staged columns to state write."""
-    rank_backend = backend_of("exchange-rank")
-    key = (_mesh_key(mesh), tuple(dtypes), rank_backend)
+    key = (_mesh_key(mesh), tuple(dtypes))
     return PROGRAM_CACHE.get_or_build(
         "join-exchange-put", key,
-        lambda: _build_join_exchange_put(mesh, len(dtypes), rank_backend))
+        lambda: _build_join_exchange_put(mesh, len(dtypes)))
 
 
-def _build_join_exchange_put(mesh: Mesh, n_cols: int,
-                             rank_backend: str = "xla"):
+def _build_join_exchange_put(mesh: Mesh, n_cols: int):
     num_shards = int(mesh.devices.size)
-    sm_kwargs = {"check_vma": False} if rank_backend == "pallas" else {}
 
     def _exchange(block):
         if num_shards == 1:
@@ -132,7 +128,7 @@ def _build_join_exchange_put(mesh: Mesh, n_cols: int,
             # rank within destination preserves stream order per
             # destination — the same (source, rank) flattening the
             # host bucketing produces (see build_exchange_scatter)
-            flat = exchange_rank_flat(d, num_shards, W, rank_backend)
+            flat = exchange_rank_flat(d, num_shards, W)
             recv_s = _exchange(
                 jnp.zeros((num_shards * W,), jnp.int32)
                 .at[flat].set(s, mode="drop")
@@ -152,7 +148,6 @@ def _build_join_exchange_put(mesh: Mesh, n_cols: int,
             local, mesh=mesh,
             in_specs=(P(KEY_AXIS),) * (2 * n_cols + 2),
             out_specs=(P(KEY_AXIS),) * n_cols,
-            **sm_kwargs,
         )(*planes, dst, slots, *values)
 
     return exchange_put

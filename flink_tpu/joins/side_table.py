@@ -173,7 +173,7 @@ class JoinSideTable:
         self.pmaps: List[PagedSpillMap] = [PagedSpillMap()
                                            for _ in range(self.P)]
         #: probe rows answered from page storage (the no-vacuous-spill
-        #: gate in tools/join_smoke.py reads this)
+        #: guard in tests/test_device_joins.py reads this)
         self.cold_rows_served = 0
 
     # ------------------------------------------------------------ accounting

@@ -4,16 +4,16 @@ The static side of the concurrency contract lives in
 ``tools/flint/rules_conc.py`` (LCK01..LCK03, SHM01). This module is the
 runtime complement: the package's hot classes create their locks
 through :func:`named_lock`, and a :class:`LockSentinel` — installed
-only by tests and ``tools/lock_smoke.py`` — observes every acquisition
+only by tests (``tests/test_lock_sentinel.py``) — observes every acquisition
 through those wrappers:
 
 - the **acquisition-order graph** (edge ``A -> B`` whenever a thread
   acquires B while holding A), with a first-witness site per edge;
   an observed cycle raises :class:`LockOrderViolation` in the
   acquiring thread AND is recorded, so a cycle in a daemon thread
-  still fails the smoke's final :meth:`LockSentinel.check`;
+  still fails the test's final :meth:`LockSentinel.check`;
 - per-lock **hold and contention** accounting (acquisitions, contended
-  acquires, total wait, total/max hold) that the smoke gates on — a
+  acquires, total wait, total/max hold) that the tests gate on — a
   lock held across a slow path shows up as a hold-time budget failure
   before it shows up as tail latency.
 

@@ -59,14 +59,13 @@ from flink_tpu.parallel.mesh import (
     pod_mesh_view,
     shard_map,
 )
-from flink_tpu.stateplane.backends import backend_of
 from flink_tpu.stateplane.rank import exchange_rank_flat
 from flink_tpu.tenancy.program_cache import PROGRAM_CACHE
 
 
 class ExchangeTraffic:
     """Per-engine two-level traffic accounting: how many records stayed
-    on ICI vs genuinely crossed the DCN (the smoke's vacuity guard and
+    on ICI vs genuinely crossed the DCN (the tests' vacuity guard and
     the NOTES scaling-walk split read these)."""
 
     __slots__ = ("rows_intra_host", "rows_cross_host", "batches")
@@ -223,13 +222,11 @@ def _mesh_key(mesh) -> Tuple[int, ...]:
     return tuple(d.id for d in mesh.devices.flat)
 
 
-def _stage1_route(mesh2, H: int, L: int, fill_specs,
-                  rank_backend: str = "xla"):
+def _stage1_route(mesh2, H: int, L: int, fill_specs):
     """Stage 1: route (dst, slot, values...) by destination LOCAL index
     over the intra-host axis. Returns per-column received buckets
     flattened ``[L * W1]`` in (source-local, rank) order."""
     num_shards = H * L
-    sm_kwargs = {"check_vma": False} if rank_backend == "pallas" else {}
 
     def _xc_local(block):
         if L == 1:
@@ -247,7 +244,7 @@ def _stage1_route(mesh2, H: int, L: int, fill_specs,
             vals = args[2:]
             dl = jnp.where(d < num_shards,
                            jax.lax.rem(d, L), L)
-            flat = exchange_rank_flat(dl, L, W1, rank_backend)
+            flat = exchange_rank_flat(dl, L, W1)
             outs = []
             # the destination shard rides the exchange (stage 2 needs
             # the host part); empty lanes carry the padding sentinel
@@ -272,19 +269,17 @@ def _stage1_route(mesh2, H: int, L: int, fill_specs,
             local, mesh=mesh2,
             in_specs=(spec,) * (2 + n_vals),
             out_specs=(spec,) * (2 + n_vals),
-            **sm_kwargs,
         )(dst, slots, *values)
 
     return stage1
 
 
-def _stage2_rank(d2, H: int, L: int, num_shards: int, W2: int,
-                 rank_backend: str = "xla"):
+def _stage2_rank(d2, H: int, L: int, num_shards: int, W2: int):
     """Shared stage-2 bucketing: destination-host rank-within-
-    destination (the stateplane exchange-rank combinator) over the
+    destination (the stateplane rank combinator) over the
     stage-1 receive order."""
     dh = jnp.where(d2 < num_shards, d2 // L, H)
-    return exchange_rank_flat(dh, H, W2, rank_backend)
+    return exchange_rank_flat(dh, H, W2)
 
 
 def build_exchange2_steps(mesh, topology: HostTopology, agg,
@@ -295,19 +290,15 @@ def build_exchange2_steps(mesh, topology: HostTopology, agg,
     the same per-slot stream-order guarantee as
     ``build_exchange_scatter`` — bit-identical output, two dispatches.
     """
-    rank_backend = backend_of("exchange-rank")
     key = (_mesh_key(mesh), topology.num_hosts,
-           topology.local_devices, agg.cache_key(), bool(valued),
-           rank_backend)
+           topology.local_devices, agg.cache_key(), bool(valued))
     return (
         PROGRAM_CACHE.get_or_build(
             "exchange2-stage1", key,
-            lambda: _build_fold_stage1(mesh, topology, agg, valued,
-                                       rank_backend)),
+            lambda: _build_fold_stage1(mesh, topology, agg, valued)),
         PROGRAM_CACHE.get_or_build(
             "exchange2-stage2", key,
-            lambda: _build_fold_stage2(mesh, topology, agg, valued,
-                                       rank_backend)),
+            lambda: _build_fold_stage2(mesh, topology, agg, valued)),
     )
 
 
@@ -320,24 +311,21 @@ def _exchanged_leaves(agg, valued: bool):
     return [l for l in agg.leaves if l.const is None]
 
 
-def _build_fold_stage1(mesh, topology: HostTopology, agg, valued: bool,
-                       rank_backend: str = "xla"):
+def _build_fold_stage1(mesh, topology: HostTopology, agg, valued: bool):
     H, L = topology.num_hosts, topology.local_devices
     mesh2 = pod_mesh_view(mesh, topology)
     fill_specs = tuple((np.dtype(l.dtype).str, l.identity)
                        for l in _exchanged_leaves(agg, valued))
-    return _stage1_route(mesh2, H, L, fill_specs, rank_backend)
+    return _stage1_route(mesh2, H, L, fill_specs)
 
 
-def _build_fold_stage2(mesh, topology: HostTopology, agg, valued: bool,
-                       rank_backend: str = "xla"):
+def _build_fold_stage2(mesh, topology: HostTopology, agg, valued: bool):
     H, L = topology.num_hosts, topology.local_devices
     num_shards = H * L
     mesh2 = pod_mesh_view(mesh, topology)
     leaves = agg.leaves
     methods = tuple(SCATTER_METHOD[l.reduce] for l in leaves)
     n_leaves = len(leaves)
-    sm_kwargs = {"check_vma": False} if rank_backend == "pallas" else {}
 
     def _xc_hosts(block):
         if H == 1:
@@ -354,7 +342,7 @@ def _build_fold_stage2(mesh, topology: HostTopology, agg, valued: bool,
             d2 = args[n_leaves]          # [L*W1] destination shard
             s2 = args[n_leaves + 1]      # [L*W1] destination slot
             vals_l = iter(args[n_leaves + 2:])
-            flat = _stage2_rank(d2, H, L, num_shards, W2, rank_backend)
+            flat = _stage2_rank(d2, H, L, num_shards, W2)
             recv_s = _xc_hosts(
                 jnp.zeros((H * W2,), jnp.int32)
                 .at[flat].set(s2, mode="drop")
@@ -382,7 +370,6 @@ def _build_fold_stage2(mesh, topology: HostTopology, agg, valued: bool,
             local, mesh=mesh2,
             in_specs=(spec,) * (n_leaves + 2 + n_vals),
             out_specs=(spec,) * n_leaves,
-            **sm_kwargs,
         )(*accs, dst2, slots2, *vals2)
 
     return stage2
@@ -395,36 +382,30 @@ def build_join_exchange2_steps(mesh, topology: HostTopology,
     the host axis and writes the received rows into the side table's
     plane (``.set`` — last write in stream order wins, identical to the
     flat join exchange)."""
-    rank_backend = backend_of("exchange-rank")
     key = (_mesh_key(mesh), topology.num_hosts,
-           topology.local_devices, tuple(dtypes), rank_backend)
+           topology.local_devices, tuple(dtypes))
     return (
         PROGRAM_CACHE.get_or_build(
             "join-exchange2-stage1", key,
-            lambda: _build_join_stage1(mesh, topology, dtypes,
-                                       rank_backend)),
+            lambda: _build_join_stage1(mesh, topology, dtypes)),
         PROGRAM_CACHE.get_or_build(
             "join-exchange2-stage2", key,
-            lambda: _build_join_stage2(mesh, topology, dtypes,
-                                       rank_backend)),
+            lambda: _build_join_stage2(mesh, topology, dtypes)),
     )
 
 
-def _build_join_stage1(mesh, topology: HostTopology, dtypes,
-                       rank_backend: str = "xla"):
+def _build_join_stage1(mesh, topology: HostTopology, dtypes):
     H, L = topology.num_hosts, topology.local_devices
     mesh2 = pod_mesh_view(mesh, topology)
     fill_specs = tuple((np.dtype(dt).str, 0) for dt in dtypes)
-    return _stage1_route(mesh2, H, L, fill_specs, rank_backend)
+    return _stage1_route(mesh2, H, L, fill_specs)
 
 
-def _build_join_stage2(mesh, topology: HostTopology, dtypes,
-                       rank_backend: str = "xla"):
+def _build_join_stage2(mesh, topology: HostTopology, dtypes):
     H, L = topology.num_hosts, topology.local_devices
     num_shards = H * L
     mesh2 = pod_mesh_view(mesh, topology)
     n_cols = len(dtypes)
-    sm_kwargs = {"check_vma": False} if rank_backend == "pallas" else {}
 
     def _xc_hosts(block):
         if H == 1:
@@ -441,7 +422,7 @@ def _build_join_stage2(mesh, topology: HostTopology, dtypes,
             d2 = args[n_cols]
             s2 = args[n_cols + 1]
             vs = args[n_cols + 2:]
-            flat = _stage2_rank(d2, H, L, num_shards, W2, rank_backend)
+            flat = _stage2_rank(d2, H, L, num_shards, W2)
             recv_s = _xc_hosts(
                 jnp.zeros((H * W2,), jnp.int32)
                 .at[flat].set(s2, mode="drop")
@@ -462,7 +443,6 @@ def _build_join_stage2(mesh, topology: HostTopology, dtypes,
             local, mesh=mesh2,
             in_specs=(spec,) * (2 * n_cols + 2),
             out_specs=(spec,) * n_cols,
-            **sm_kwargs,
         )(*planes, dst2, slots2, *vals2)
 
     return stage2

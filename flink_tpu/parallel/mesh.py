@@ -14,7 +14,7 @@ Pod scale (ROADMAP item 2): the same key-group axis can SPAN PROCESSES —
 (``parallel/exchange2.py``) programs against. On CPU the same shape runs
 as N processes x M virtual devices (``jax.distributed.initialize`` + the
 gloo cross-process collectives — :func:`initialize_distributed`), which
-is how the multi-process smoke and chaos scenarios exercise the pod data
+is how the two-process tests and chaos scenarios exercise the pod data
 plane without a pod.
 """
 

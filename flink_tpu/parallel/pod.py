@@ -19,7 +19,8 @@ Host-granular planes fall out of the process split: each process keeps
 its own session-metadata plane, spill tier and per-range checkpoint
 units (its engine's ``snapshot_sharded`` — PR 9's shard units), so a
 lost process is "restore k units, replay one contiguous range"
-(``tools/multiproc_smoke.py`` drives exactly that scenario).
+(``tests/test_host_failover.py``'s ``TestTwoRealProcesses`` drives
+exactly that scenario).
 
 CPU bring-up: ``mesh.initialize_distributed`` enables the gloo
 cross-process collectives; N processes x M virtual devices then run
@@ -180,7 +181,7 @@ class PodDataPlane:
             (tuple(d.id for d in self.mesh.devices.flat),),
             lambda: _build_agree(self.mesh))
         #: rows that genuinely crossed a process boundary vs stayed
-        #: home (the smoke's vacuity guard)
+        #: home (the two-process test's vacuity guard)
         self.rows_cross_host = 0
         self.rows_intra_host = 0
         self.batches = 0

@@ -99,7 +99,7 @@ class MeshSpillSupport:
     #: fast path — every engine on a single-process mesh is unchanged
     host_topology = None
     #: intra- vs cross-host row accounting for the two-level exchange
-    #: (smoke vacuity guard + the NOTES traffic split)
+    #: (the tests' vacuity guard + the NOTES traffic split)
     _exchange2_traffic = None
     #: live non-contiguous shard->key-group assignment installed by
     #: reassign_key_groups(); None = the contiguous formula (the common

@@ -45,7 +45,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from flink_tpu.chaos import injection as chaos
 from flink_tpu.ops.segment_ops import SCATTER_METHOD, pad_bucket_size
 from flink_tpu.parallel.mesh import KEY_AXIS, shard_map
-from flink_tpu.stateplane.backends import backend_of
 from flink_tpu.stateplane.rank import exchange_rank_flat
 from flink_tpu.tenancy.program_cache import PROGRAM_CACHE
 from flink_tpu.state.keygroups import (
@@ -362,24 +361,18 @@ def build_exchange_scatter(mesh: Mesh, agg, valued: bool = False):
     engines share the executable (the multi-tenant zero-recompile
     contract), shapes one level down via jit + the pad_bucket_size
     tiers."""
-    rank_backend = backend_of("exchange-rank")
     key = (tuple(d.id for d in mesh.devices.flat), agg.cache_key(),
-           bool(valued), rank_backend)
+           bool(valued))
     return PROGRAM_CACHE.get_or_build(
         "exchange-scatter", key,
-        lambda: _build_exchange_scatter(mesh, agg, valued, rank_backend))
+        lambda: _build_exchange_scatter(mesh, agg, valued))
 
 
-def _build_exchange_scatter(mesh: Mesh, agg, valued: bool,
-                            rank_backend: str = "xla"):
+def _build_exchange_scatter(mesh: Mesh, agg, valued: bool):
     leaves = agg.leaves
     methods = tuple(SCATTER_METHOD[l.reduce] for l in leaves)
     n_leaves = len(leaves)
     num_shards = int(mesh.devices.size)
-    # pallas_call has no shard_map replication rule — disable the check
-    # for the pallas-ranked build only (the xla build stays byte-
-    # identical in behavior to the pre-stateplane program)
-    sm_kwargs = {"check_vma": False} if rank_backend == "pallas" else {}
 
     def _exchange(block):
         # [P, W] local block, dim0 = destination shard -> [P, W] with
@@ -409,7 +402,7 @@ def _build_exchange_scatter(mesh: Mesh, agg, valued: bool,
             # to the batch's densest pair, so the rank < W guard only
             # bounds a miscount to a drop (-> oracle divergence)
             # instead of silent row corruption.
-            flat = exchange_rank_flat(d, num_shards, W, rank_backend)
+            flat = exchange_rank_flat(d, num_shards, W)
             recv_s = _exchange(
                 jnp.zeros((num_shards * W,), jnp.int32)
                 .at[flat].set(s, mode="drop")
@@ -437,7 +430,6 @@ def _build_exchange_scatter(mesh: Mesh, agg, valued: bool,
             local, mesh=mesh,
             in_specs=(P(KEY_AXIS),) * (n_leaves + 2 + n_vals),
             out_specs=(P(KEY_AXIS),) * n_leaves,
-            **sm_kwargs,
         )(*accs, dst, slots, *values)
 
     return exchange_scatter

@@ -3,21 +3,14 @@
 One home for the compiled device programs every engine dispatches
 (ROADMAP item 5): the canonical flat program families
 (:mod:`~flink_tpu.stateplane.families`), the pane-ring delta-harvest
-bundle (:mod:`~flink_tpu.stateplane.pane`), the exchange-rank
-combinator with its first Pallas backend
-(:mod:`~flink_tpu.stateplane.rank`), and the pluggable per-family
-backend hook (:mod:`~flink_tpu.stateplane.backends`). Engines —
+bundle (:mod:`~flink_tpu.stateplane.pane`) and the rank-within-
+destination combinator the exchanges share
+(:mod:`~flink_tpu.stateplane.rank`). Engines —
 SlotTable, PaneTable, the mesh engines, the joins — are thin policies
 over these builders; flint REG04 pins every PROGRAM_CACHE kind to
 :data:`KNOWN_PROGRAM_FAMILIES`.
 """
 
-from flink_tpu.stateplane.backends import (
-    backend_of,
-    backend_scope,
-    configure_backends,
-    set_backend,
-)
 from flink_tpu.stateplane.families import (
     KNOWN_PROGRAM_FAMILIES,
     flat_fence,
@@ -34,18 +27,12 @@ from flink_tpu.stateplane.families import (
 )
 from flink_tpu.stateplane.pane import pane_programs
 from flink_tpu.stateplane.rank import (
-    build_exchange_rank,
     exchange_rank_flat,
-    pallas_rank,
     xla_rank,
 )
 
 __all__ = [
     "KNOWN_PROGRAM_FAMILIES",
-    "backend_of",
-    "backend_scope",
-    "build_exchange_rank",
-    "configure_backends",
     "exchange_rank_flat",
     "flat_fence",
     "flat_gather",
@@ -58,8 +45,6 @@ __all__ = [
     "flat_segment_fire",
     "flat_segment_fire_projected",
     "flat_segment_merge",
-    "pallas_rank",
     "pane_programs",
-    "set_backend",
     "xla_rank",
 ]

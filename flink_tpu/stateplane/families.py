@@ -51,7 +51,6 @@ KNOWN_PROGRAM_FAMILIES = (
     "evict-cohort",     # cohort put/reset (spill reload, eviction clear)
     "snapshot-lift",    # snapshot ordering fence / row lift
     "delta-harvest",    # pane-ring partial scatter + fire-row harvest
-    "exchange-rank",    # rank-within-destination (xla | pallas backends)
     # -- composite per-engine programs (fused from canonical pieces) --
     "mesh-steps",
     "session-merge",

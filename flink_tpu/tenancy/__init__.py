@@ -8,7 +8,7 @@ hardware. Four pillars:
 - **Shared compiled-program cache** (:mod:`program_cache`): step/fire/
   evict/harvest XLA programs keyed on (kind, layout, device ids) —
   job K+1 reuses job K's executables, zero per-job steady-state
-  compiles (sentinel-gated in ``tools/serving_smoke.py``).
+  compiles (sentinel-gated in ``tests/test_tenancy.py``).
 - **Per-job state-plane quotas** (:mod:`quotas`): each job's engines get
   a bounded slice of resident [P, cap] rows with per-job spill
   directories; over-quota jobs spill their OWN cold rows — never

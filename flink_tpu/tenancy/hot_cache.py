@@ -117,7 +117,7 @@ class HotRowCache:
         self._entries: "OrderedDict[tuple, Tuple[int, Any]]" = \
             OrderedDict()
         #: counters read (under the lock) by the serving gauges and the
-        #: smoke's vacuity gate
+        #: serving tests' vacuity gate
         self.hits = 0
         self.misses = 0
         self.evictions = 0

@@ -16,10 +16,9 @@ family once per job and erase the tenancy win.
 This module is that cache's single home. It wraps the raw program dict
 (previously ``sharded_windower._STEP_CACHE``) with per-job hit/miss
 attribution so the tenancy layer can PROVE sharing: after job A warms
-the cluster, job B's stats must show ``misses == 0`` (the serving smoke
-and the recompile smoke both gate on the stronger runtime signal — the
-recompile sentinel — and read these stats for the diagnosis when it
-trips).
+the cluster, job B's stats must show ``misses == 0`` (the steady-state
+tests gate on the stronger runtime signal too — the recompile sentinel
+— and read these stats for the diagnosis when it trips).
 
 No engine imports here: the cache must be importable from the lowest
 layers (parallel/, state/) without cycles.

@@ -16,7 +16,7 @@ Tenant isolation on the state plane has two halves:
    budget sheds its own cold rows through
    ``MeshSpillSupport.enforce_resident_budget``; a job STILL over
    budget after shedding (no spill tier, tier full, every row pinned)
-   counts a ``quota_violations`` — the serving smoke fails on any.
+   counts a ``quota_violations`` — the serving tests fail on any.
 
 reference: fine-grained resource management (slot sharing groups with
 explicit resource profiles) — here the scarce resource is HBM-resident
@@ -128,7 +128,7 @@ class QuotaLedger:
             # STILL over after shedding — whether because an engine has
             # no tier, its tier is full, or every row is pinned: the
             # budget is being violated and the gauge (and the serving
-            # smoke's gate) must say so
+            # tests' gate) must say so
             self.quota_violations += 1
         self.rows_shed += shed
         return shed

@@ -207,7 +207,7 @@ class ReplicaPlane:
         #: cache must drop this operator's entries — a rolled-back
         #: value would otherwise serve stale forever
         self.on_rebuild = None
-        # ---- counters (read by serving gauges / the smoke's gates)
+        # ---- counters (read by serving gauges / the serving tests)
         self.publishes = 0
         self.rows_published = 0
         self.rows_freed = 0

@@ -969,8 +969,9 @@ class ServingPlane:
         return self.hot_cache.hit_rate()
 
     def replica_generations(self) -> int:
-        """Total sealed generations across bound replicas (the smoke's
-        publish-vacuity gate reads this; snapshot — see staleness)."""
+        """Total sealed generations across bound replicas (the serving
+        tests' publish-vacuity guard reads this; snapshot — see
+        staleness)."""
         return sum(ad.plane.generation()
                    for ad in list(self._replicas.values()))
 

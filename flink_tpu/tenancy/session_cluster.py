@@ -15,7 +15,7 @@ What each quantum pays / observes:
 
 - the job's program-cache traffic is attributed to it
   (:mod:`program_cache`) — job K+1 on a warm cluster must show zero
-  misses AND zero XLA compiles (gated by ``tools/serving_smoke.py``);
+  misses AND zero XLA compiles (gated in ``tests/test_tenancy.py``);
 - the job's quota ledger enforces its resident-row budget
   (:mod:`quotas`) — over-budget jobs shed their own cold rows;
 - the serving plane's coalesced lookup batches land on the job's

@@ -626,6 +626,55 @@ class TestAutoscaleController:
         assert eng.P == 8
         assert ctl.live_handoffs == 1  # converged once, then steady
 
+    def test_load_ramp_scales_up_and_back_down_live(self):
+        """A low -> high -> low load ramp through the DS2 policy: the
+        busy fraction is scripted off the ramp (load / peak), so at the
+        peak the target is ceil(2 * 1.0 / 0.5) = 4 and at the trough
+        it clamps to min 2. The engine must rescale 2 -> 4 -> 2 LIVE,
+        mid-stream, and finish oracle-identical."""
+        phases = [1500] * 3 + [6000] * 4 + [1500] * 4
+        rng = np.random.default_rng(23)
+        steps = []
+        for s, n in enumerate(phases):
+            steps.append((
+                rng.integers(0, 6000, n).astype(np.int64),
+                rng.random(n).astype(np.float32),
+                rng.integers(s * 80, s * 80 + 60, n).astype(np.int64),
+                (s - 1) * 80))
+        clk = FakeClock()
+        eng = _session_engine(make_mesh(2), max_device_slots=1024)
+        cum = {"records": 0.0, "busy_ms": 0.0}
+        ctl = AutoscaleController(
+            ScalingPolicy(utilization_target=0.5, hysteresis=0.25,
+                          cooldown_s=2.0, min_shards=2, max_shards=4,
+                          clock=clk),
+            sample_fn=lambda: SignalSample(
+                records_total=cum["records"],
+                busy_ms_total=cum["busy_ms"],
+                shard_resident_rows=eng.shard_resident_rows()),
+            engine=eng, interval_s=0.0, clock=clk)
+        fired = []
+        for keys, vals, ts, wm in steps:
+            cum["records"] += len(keys)
+            cum["busy_ms"] += min(len(keys) / 6000.0, 1.0) * 1000.0
+            clk.advance(1.0)
+            ctl.tick()
+            eng.process_batch(keyed_batch(keys, vals, ts))
+            fired.extend(eng.on_watermark(wm))
+        fired.extend(eng.on_watermark(1 << 60))
+        got = {}
+        for b in fired:
+            for r in b.to_rows():
+                got[(r[KEY_ID_FIELD], r["window_start"],
+                     r["window_end"])] = r["sum_v"]
+        path = [(e.source, e.target) for e in ctl.events]
+        assert (2, 4) in path and (4, 2) in path, path
+        assert ctl.live_handoffs >= 2
+        assert all(e.mode == "live" for e in ctl.events)
+        oracle = SessionWindower(GAP, SumAggregate("v"),
+                                 capacity=1 << 15)
+        _assert_equal(got, _run(oracle, steps))
+
 
 # ---------------------------------------------------------------------------
 # skew guard surface: refusal counter + gauges + rebalancer hand-off

@@ -514,6 +514,36 @@ class TestDeviceExchangeFaults:
         assert r.faults_injected.get("shuffle.device_exchange", 0) == 1
         _note_reached(r.faults_injected)
 
+    def test_three_crashes_and_torn_checkpoint_exactly_once(
+            self, eight_device_mesh, tmp_path):
+        """The device data plane (the engine default) under three engine
+        crashes — a dispatch fence with batches in flight, a page reload
+        broken past its retry budget, the data plane dying after the
+        fused exchange+scatter dispatch — and one torn checkpoint write:
+        committed output equals the fault-free oracle, every planned
+        fault was injected, and the torn checkpoint was skipped."""
+        plan = FaultPlan(rules=[
+            FaultRule(pattern="mesh.dispatch_fence", nth=5, kind="raise"),
+            FaultRule(pattern="spill.page_reload", nth=3, kind="raise"),
+            FaultRule(pattern="shuffle.device_exchange", nth=10,
+                      kind="raise"),
+            FaultRule(pattern="checkpoint.write.torn", nth=2,
+                      kind="drop"),
+        ])
+        r = run_crash_restore_verify(
+            _make_session_engine(eight_device_mesh,
+                                 shuffle_mode="device"),
+            _make_session_oracle(),
+            _session_steps(), plan, seed=7,
+            ckpt_root=str(tmp_path / "ckpt"), checkpoint_every=2)
+        assert not r.diverged and r.windows > 0
+        for point in ("mesh.dispatch_fence", "spill.page_reload",
+                      "shuffle.device_exchange", "checkpoint.write.torn"):
+            assert r.faults_injected.get(point, 0) >= 1, point
+        assert r.crashes == 3
+        assert r.corrupt_checkpoints_skipped >= 1
+        _note_reached(r.faults_injected)
+
 
 # -------------------------------------------------------- restart satellites
 

@@ -8,7 +8,8 @@ aggregate as ONE XLA program. These tests pin the contract the fused
 path must honor:
 
 - staging shapes walk the ``pad_bucket_size`` tiers (bounded program
-  shapes — the recompile smoke gates the runtime signal),
+  shapes — the steady-state cases of ``tests/test_stateplane.py`` gate
+  the runtime signal),
 - output BIT-IDENTICAL to the explicit host fallback
   (``bucket_by_shard`` + sharded device_put) and to the single-device
   oracle, under forced paged eviction,

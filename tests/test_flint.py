@@ -955,7 +955,7 @@ class TestSlowLaneBookkeeping:
         scripts = re.findall(r"python3? +([\w./-]+\.py)\b", code)
         modules = [m for m in re.findall(r"python3? +-m +([\w.]+)", code)
                    if m != "pytest"]
-        assert scripts and modules, "the gate runs nothing"
+        assert modules, "the gate runs nothing"
         for path in scripts:
             assert (REPO_ROOT / path).is_file(), \
                 f"tier1.sh runs {path}, which is not in the tree"

@@ -4,14 +4,21 @@ failover — a lost HOST (one process's contiguous slice of shards) means
 "lose k shard units, restore k units, replay one contiguous range",
 bit-identical to the fault-free oracle and seed-deterministic.
 
-Runs single-process on virtual topologies (2x2 / 2x4 over CPU devices);
-tools/multiproc_smoke.py drives the same protocol across REAL process
-boundaries (kill 1 of 2 processes) — these tests keep the escalation
-ladder and the evacuation/restore/replay machinery in plain tier-1.
+Most cases run single-process on virtual topologies (2x2 / 2x4 over
+CPU devices); ``TestTwoRealProcesses`` drives the same protocol across
+REAL process boundaries (kill 1 of 2 processes, each child running
+``tests/pod_process.py``).
 """
+
+import os
+import socket
+import subprocess
+import sys
 
 import numpy as np
 import pytest
+
+from tests import pod_process
 
 from flink_tpu.chaos.harness import run_shard_loss_verify
 from flink_tpu.chaos.injection import FaultPlan, FaultRule
@@ -25,6 +32,7 @@ from flink_tpu.runtime.watchdog import (
 from flink_tpu.windowing.aggregates import SumAggregate
 
 GAP = 100
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _steps(n_steps=8, per_step=800, num_keys=3000, seed=17):
@@ -263,3 +271,112 @@ class TestHostLossVerify:
             ckpt_root=str(tmp_path / "c"), checkpoint_every=4)
         assert not report.diverged
         assert report.hosts_lost == 1
+
+
+# ------------------------------------------------ two real processes
+
+
+def _free_port() -> int:
+    s = socket.socket()
+    s.bind(("127.0.0.1", 0))
+    port = s.getsockname()[1]
+    s.close()
+    return port
+
+
+def _children(runs, timeout=300):
+    """Start ``tests/pod_process.py`` once per argument list (JAX on
+    the CPU, four virtual devices each) and wait for all of them."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
+                        f"{pod_process.LOCAL}")
+    env["PYTHONPATH"] = (REPO + os.pathsep
+                         + env.get("PYTHONPATH", "")).rstrip(os.pathsep)
+    procs = [subprocess.Popen(
+        [sys.executable, os.path.join(REPO, "tests", "pod_process.py"),
+         *args], cwd=REPO, env=env,
+        stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
+        for args in runs]
+    try:
+        for p in procs:
+            out, _ = p.communicate(timeout=timeout)
+            assert p.returncode == 0, out.decode()[-3000:]
+    finally:
+        for p in procs:
+            if p.poll() is None:
+                p.kill()
+                p.wait()
+
+
+def _pod(tmp, tag, kill_at=0):
+    port = _free_port()
+    ckpt = tmp / f"ckpt-{tag}"
+    ckpt.mkdir()
+    outs = [str(tmp / f"{tag}-{pid}.json")
+            for pid in range(pod_process.HOSTS)]
+    _children([["pod", "--out", outs[pid], "--pid", str(pid),
+                "--port", str(port), "--ckpt", str(ckpt),
+                "--kill-at", str(kill_at)]
+               for pid in range(pod_process.HOSTS)])
+    return [pod_process.load_committed(o) for o in outs]
+
+
+def _merge(parts):
+    merged = {}
+    for part in parts:
+        for k, v in part.items():
+            assert merged.get(k, v) == v, f"conflicting cell {k}"
+            merged[k] = v
+    return merged
+
+
+class TestTwoRealProcesses:
+    """The protocol above across REAL process boundaries: two processes
+    (``jax.distributed`` + gloo CPU collectives), each owning half the
+    key-group space with its own metadata plane and checkpoint units,
+    exchange records over the DCN axis on the device; then process 1
+    is killed mid-stream. Each leg's measured rep runs fresh engines on
+    a warm program cache."""
+
+    @pytest.fixture(scope="class")
+    def single(self, tmp_path_factory):
+        out = str(tmp_path_factory.mktemp("single") / "single.json")
+        _children([["single", "--out", out]])
+        return pod_process.load_committed(out)
+
+    @pytest.fixture(scope="class")
+    def pod(self, tmp_path_factory):
+        return _pod(tmp_path_factory.mktemp("pod"), "pod")
+
+    @pytest.fixture(scope="class")
+    def chaos(self, tmp_path_factory):
+        kill_at = max(pod_process.n_batches() * 2 // 3,
+                      pod_process.CKPT_EVERY + 1)
+        assert kill_at < pod_process.n_batches() - 1
+        return _pod(tmp_path_factory.mktemp("chaos"), "chaos", kill_at)
+
+    def test_one_process_steady_state_compiles_nothing(self, single):
+        oracle, meta = single
+        assert len(oracle) > 0
+        assert meta["compiles_measured"] == 0
+
+    def test_two_processes_bit_identical_to_one(self, single, pod):
+        assert _merge([part for part, _ in pod]) == single[0]
+
+    def test_rows_cross_the_process_boundary_on_device(self, pod):
+        assert sum(meta["cross_rows"] for _, meta in pod) > 0
+
+    def test_two_process_steady_state_compiles_nothing(self, pod):
+        assert [meta["compiles_measured"] for _, meta in pod] == [0, 0]
+
+    def test_killed_process_range_restored_and_replay_bounded(
+            self, single, chaos):
+        """The survivor restores ONLY the dead host's ranges from its
+        checkpoint units, replays at most the per-host share of the
+        stream, and the union of both committed outputs is
+        bit-identical to the one-process run."""
+        (survivor, s_meta), (dead, _) = chaos
+        assert _merge([dead, survivor]) == single[0]
+        assert s_meta["restored_units"] >= 1
+        assert 0 < s_meta["replayed"] <= pod_process.RECORDS // 2

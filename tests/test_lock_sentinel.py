@@ -1,13 +1,12 @@
-"""LockSentinel + named_lock (flink_tpu/observe/lock_sentinel) and the
-r24 thread-safety fix of the state-plane backend registry.
+"""LockSentinel + named_lock (flink_tpu/observe/lock_sentinel).
 
 Covers: the acquisition-order graph (cycle raised AND recorded, clean
 orders pass), reentrant re-acquisition recording no edge, the same-name
 two-instance nesting hazard, hold-budget and contention accounting, the
-no-sentinel fast path, and the backend registry's compare-and-restore
-scope exit under a concurrent ``set_backend`` (the lost-override race
-LCK01/LCK03 flagged before the fix)."""
+no-sentinel fast path, and one sentinel over real lock traffic: a
+two-job cluster under lookup load and a program-cache build race."""
 
+import sys
 import threading
 import time
 
@@ -158,105 +157,181 @@ class TestLockSentinel:
             assert current_sentinel() is s2
 
 
-class TestBackendRegistryThreadSafety:
-    """The r24 satellite: set_backend/backend_scope/configure_backends
-    share one module lock, and a scope exit must not clobber overrides
-    it did not install."""
 
-    def setup_method(self):
-        from flink_tpu.stateplane import backends
+# ------------------------------------------------- under real lock traffic
 
-        backends.set_backend("exchange-rank", "xla")
 
-    teardown_method = setup_method
+def _churn(fn, threads=4):
+    """Run ``fn(i)`` on a few threads under a tiny GIL switch interval:
+    the default 5 ms quantum lets a microsecond critical section finish
+    unpreempted, so contention a small box can produce never shows."""
+    prev = sys.getswitchinterval()
+    sys.setswitchinterval(5e-5)
+    try:
+        ts = [threading.Thread(target=fn, args=(i,), daemon=True)
+              for i in range(threads)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(prev)
 
-    def test_overlapping_scopes_leak_no_override(self):
-        """Two threads' scopes overlap, exiting in ENTER order. The
-        naive read/set/restore exit re-installed the second scope's
-        stale 'prev' (= the first scope's override) after BOTH scopes
-        closed; compare-and-restore leaves the default."""
-        from flink_tpu.stateplane.backends import (
-            backend_of,
-            backend_scope,
+
+class TestSentinelUnderLoad:
+    """ONE sentinel observes the hot multi-threaded surfaces — the
+    runtime complement of flint's LCK rules."""
+
+    #: lock families that must show acquisitions: each tuple lists
+    #: alternatives (the cache plane is the Python LRU's lock or the
+    #: native writer lock, depending on the build)
+    EXPECTED_FAMILIES = [
+        ("tenancy.program_cache",),
+        ("tenancy.hot_rows", "tenancy.native_cache"),
+        ("serving.coalescer", "serving.worker", "serving.workers",
+         "serving.pool"),
+    ]
+
+    def test_two_job_cluster_with_lookup_clients(self, tmp_path):
+        """Two jobs on a session cluster while client threads hammer
+        batched lookups (and, with the native hot cache, a two-process
+        frontend pool serves half of them): no lock-order cycle, locks
+        really contended, and every expected family acquired through
+        its NamedLock (a hot class reverting to a bare primitive would
+        vanish from the accounting)."""
+        import numpy as np
+
+        from flink_tpu.connectors.sinks import CollectSink
+        from flink_tpu.connectors.sources import DataGenSource
+        from flink_tpu.core.config import Configuration
+        from flink_tpu.datastream.environment import (
+            StreamExecutionEnvironment,
         )
+        from flink_tpu.native import hotcache_available
+        from flink_tpu.runtime.watermarks import WatermarkStrategy
+        from flink_tpu.tenancy.session_cluster import SessionCluster
+        from flink_tpu.windowing.assigners import TumblingEventTimeWindows
 
-        t1_in, t1_go, t1_out = (threading.Event() for _ in range(3))
-        t2_in, t2_go = threading.Event(), threading.Event()
+        keys, operator = 2048, "window_agg(SumAggregate)"
 
-        def first():
-            with backend_scope("exchange-rank", "pallas"):
-                t1_in.set()
-                t1_go.wait(5)
-            t1_out.set()
+        def pipeline(sink):
+            env = StreamExecutionEnvironment(Configuration({
+                "execution.micro-batch.size": 4096,
+                "parallelism.default": 4,
+                "serving.replica": True,
+                "serving.replica.publish-interval-ms": 25,
+            }))
+            (env.add_source(
+                DataGenSource(total_records=40_000, num_keys=keys,
+                              events_per_second_of_eventtime=50_000,
+                              seed=13),
+                WatermarkStrategy.for_bounded_out_of_orderness(0))
+                .key_by("key")
+                .window(TumblingEventTimeWindows.of(60_000))
+                .sum("value").sink_to(sink))
+            return env
 
-        def second():
-            t1_in.wait(5)
-            with backend_scope("exchange-rank", "pallas"):
-                t2_in.set()
-                t2_go.wait(5)
+        frontends = hotcache_available()
+        sentinel = LockSentinel()
+        errors = []
+        stop = threading.Event()
+        with sentinel:
+            cluster = SessionCluster(
+                quantum_records=8192, serving_workers=2,
+                serving_shm_dir=(str(tmp_path / "shm") if frontends
+                                 else None))
+            s1, s2 = CollectSink(), CollectSink()
+            cluster.submit(pipeline(s1), "job-1")
+            cluster.submit(pipeline(s2), "job-2")
+            pool = None
+            if frontends:
+                from flink_tpu.tenancy.frontend import FrontendPool
 
-        a = threading.Thread(target=first, daemon=True)
-        b = threading.Thread(target=second, daemon=True)
-        a.start()
-        b.start()
-        t2_in.wait(5)       # both scopes open
-        t1_go.set()         # first exits while second is still open
-        t1_out.wait(5)
-        t2_go.set()         # second exits last
-        a.join(5)
-        b.join(5)
-        assert backend_of("exchange-rank") == "xla"
+                pool = FrontendPool(cluster.serving, n_frontends=2)
+                pool.wait_ready()
 
-    def test_concurrent_set_backend_survives_scope_exit(self):
-        """A set_backend racing a scope's exit wins: the exit re-checks
-        that the installed override is still its own before restoring."""
-        from flink_tpu.stateplane.backends import (
-            backend_of,
-            backend_scope,
-            set_backend,
-        )
+            def client(i):
+                rng = np.random.default_rng(300 + i)
+                job = "job-1" if i % 2 == 0 else "job-2"
+                while not stop.is_set():
+                    ks = rng.integers(0, keys, 128).tolist()
+                    try:
+                        if pool is not None and i % 2 == 1:
+                            pool.lookup_batch(job, operator, ks)
+                        else:
+                            cluster.lookup_batch(job, operator, ks)
+                    except (RuntimeError, TimeoutError) as e:
+                        if not any(m in str(e) for m in (
+                                "is not serving", "already terminated",
+                                "shut down")):
+                            errors.append(f"client {i}: {e!r}")
+                        return
+                    time.sleep(0.002)
 
-        entered, release = threading.Event(), threading.Event()
+            threads = [threading.Thread(target=client, args=(i,),
+                                        daemon=True) for i in range(8)]
+            for t in threads:
+                t.start()
+            try:
+                cluster.run(timeout_s=600)
+            finally:
+                stop.set()
+                for t in threads:
+                    t.join(timeout=10)
+                if pool is not None:
+                    live = len(pool.live_frontends())
+                    pool.close()
+                    cluster.serving.hot_cache.close()
+        assert errors == []
+        assert len(s1.result()) + len(s2.result()) > 0
+        sentinel.check()  # no lock-order cycle was observed
+        locks = sentinel.report()["locks"]
+        # vacuity: the order graph of an uncontended run proves nothing
+        assert len(sentinel.contended_locks()) >= (2 if frontends else 1)
+        for family in self.EXPECTED_FAMILIES:
+            assert sum(locks.get(n, {}).get("acquisitions", 0)
+                       for n in family) > 0, family
+        if pool is not None:
+            assert live == 2
+            assert locks.get("frontend.pipe", {}).get(
+                "acquisitions", 0) > 0
 
-        def scoped():
-            with backend_scope("exchange-rank", "pallas"):
-                entered.set()
-                release.wait(5)
+    def test_program_cache_once_latch_under_churn(self):
+        """Threads race ``get_or_build`` on a fresh cache: exactly one
+        build per key, every caller gets that key's program, and the
+        once-latch's release boundaries run under the sentinel (through
+        the cache's NamedLock) without an order cycle."""
+        from flink_tpu.tenancy.program_cache import SharedProgramCache
 
-        t = threading.Thread(target=scoped, daemon=True)
-        t.start()
-        entered.wait(5)
-        set_backend("exchange-rank", "xla")  # mid-scope override
-        release.set()
-        t.join(5)
-        # the exit saw the override was no longer its own and did NOT
-        # re-install its stale prev
-        assert backend_of("exchange-rank") == "xla"
+        cache = SharedProgramCache()
+        n_keys = 100
+        built = {"n": 0}
+        built_mu = threading.Lock()
+        errors = []
 
-    def test_set_backend_churn_is_consistent(self):
-        """Two threads hammer set_backend; every read must be a valid
-        backend and the final state deterministic."""
-        from flink_tpu.stateplane.backends import (
-            backend_of,
-            set_backend,
-        )
-
-        bad = []
+        def builder_for(key):
+            def build():
+                time.sleep(0.001)  # long enough for waiters to park
+                with built_mu:
+                    built["n"] += 1
+                return ("program", key)
+            return build
 
         def churn(i):
-            for _ in range(300):
-                set_backend("exchange-rank",
-                            "pallas" if i % 2 == 0 else "xla")
-                got = backend_of("exchange-rank")
-                if got not in ("xla", "pallas"):
-                    bad.append(got)
+            try:
+                for k in range(n_keys):
+                    got = cache.get_or_build("churn", k, builder_for(k))
+                    if got != ("program", k):
+                        errors.append(f"thread {i}: {got!r} for {k}")
+                        return
+            except Exception as e:  # noqa: BLE001 - reported below
+                errors.append(f"thread {i}: {e!r}")
 
-        threads = [threading.Thread(target=churn, args=(i,),
-                                    daemon=True) for i in range(2)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(10)
-        assert bad == []
-        set_backend("exchange-rank", "xla")
-        assert backend_of("exchange-rank") == "xla"
+        sentinel = LockSentinel()
+        with sentinel:
+            _churn(churn)
+        assert errors == []
+        assert built["n"] == n_keys
+        sentinel.check()
+        locks = sentinel.report()["locks"]
+        assert locks["tenancy.program_cache"]["acquisitions"] > 0

@@ -18,7 +18,10 @@ The contract under test, in order of importance:
    ``snapshot_sharded`` units merge through ``merge_unit_snapshots``
    into a DIFFERENT shard count and replay identically.
 4. SERVING: the matched-pattern store answers ``query_match_batch``
-   and the replica-plane adapter returns the same rows.
+   and the replica-plane adapter returns the same rows, in process and
+   through the multi-process frontend tier.
+5. STEADY STATE: a fresh engine on the warm program cache compiles
+   nothing.
 """
 
 import tempfile
@@ -146,6 +149,31 @@ class TestBitIdentity:
         assert rows_of(got) == want
         assert sc["rows_evicted"] > 0
         assert sc["rows_reloaded"] > 0
+
+    @pytest.mark.parametrize("skip", [Skip.SKIP_PAST_LAST_EVENT,
+                                      Skip.NO_SKIP])
+    def test_seq3_dense_keys_on_eight_shards(self, skip):
+        """The same 3-stage sequence with 256-record batches over 40
+        dense keys on an 8-shard mesh: many keys complete per batch."""
+        pat = seq3(skip)
+        steps = gen_steps(7, n_steps=12, n_keys=40, batch=256)
+        want = host_rows(pat, steps)
+        got, _ = run(device(pat, shards=8), steps)
+        assert want, "vacuous: oracle emitted nothing"
+        assert rows_of(got) == want
+
+    def test_forced_eviction_on_eight_shards(self):
+        """The always-alive pattern over 20,000 keys on 8 shards of 256
+        slots: evict AND reload both run, output bit-identical."""
+        pat = churn_pattern()
+        steps = gen_steps(11, n_steps=12, n_keys=20_000, batch=256)
+        want = host_rows(pat, steps)
+        with tempfile.TemporaryDirectory() as td:
+            got, eng = run(device(pat, shards=8, spill_dir=td), steps)
+            sc = eng.spill_counters()
+        assert want
+        assert rows_of(got) == want
+        assert sc["rows_evicted"] > 0 and sc["rows_reloaded"] > 0
 
     def test_late_rows_dropped_like_oracle(self):
         pat = seq3()
@@ -289,3 +317,71 @@ class TestMatchStore:
         steps = gen_steps(5, n_steps=4, n_keys=20, batch=64)
         run(eng, steps)
         assert eng.matches_emitted >= 0
+
+
+class TestSteadyState:
+    def test_fresh_engine_compiles_nothing_on_warm_cache(self):
+        """After one device pass warmed the shared program cache, a
+        FRESH engine replaying the same stream compiles ZERO XLA
+        programs (the cep-advance / cep-prune program families)."""
+        from flink_tpu.observe import RecompileSentinel
+
+        pat = seq3()
+        steps = gen_steps(7, n_steps=12, n_keys=40, batch=256)
+        warm, _ = run(device(pat, shards=8), steps)
+        assert rows_of(warm), "vacuous: nothing matched"
+        with RecompileSentinel(max_compiles=0,
+                               max_transfers=len(steps) * 64,
+                               label="cep steady state"):
+            got, _ = run(device(pat, shards=8), steps)
+        assert rows_of(got) == rows_of(warm)
+
+
+class TestMatchStoreFrontends:
+    def test_frontend_pool_serves_match_rows_from_shm(self):
+        """Matched-pattern lookups through the multi-process serving
+        tier: the owner primes the shm hot cache through
+        ``CepMatchServingAdapter``, two frontend processes probe it
+        over shared memory, and every decoded row set equals the live
+        ``query_match_batch`` probe. The second round must be served
+        from shared memory (a packing regression would turn every
+        probe into an owner crossing)."""
+        import os
+        import queue
+
+        from flink_tpu.cep.mesh_engine import CepMatchServingAdapter
+        from flink_tpu.native import hotcache_available
+        from flink_tpu.tenancy.frontend import FrontendPool
+        from flink_tpu.tenancy.serving import ServingPlane
+
+        if not hotcache_available():
+            pytest.skip("native hotcache unavailable")
+        pat = seq3()
+        steps = gen_steps(7, n_steps=12, n_keys=40, batch=256)
+        with tempfile.TemporaryDirectory() as td:
+            plane = ServingPlane(shm_dir=os.path.join(td, "hc"))
+            try:
+                engine = device(pat, shards=8)
+                adapter = engine.arm_match_replica(serving=True)
+                assert isinstance(adapter, CepMatchServingAdapter)
+                plane.bind_job("cep", queue.Queue())
+                plane.bind_replica("cep", "matches", adapter)
+                run(engine, steps)
+                qkeys = np.arange(40, dtype=np.int64)
+                live = engine.query_match_batch(qkeys)
+                assert sum(len(r) for r in live) > 0
+                with FrontendPool(plane, n_frontends=2) as pool:
+                    pool.wait_ready()
+                    # round 1 fills the shm table through the miss
+                    # path; round 2 is served out of process
+                    pool.lookup_batch("cep", "matches", qkeys.tolist())
+                    got = pool.lookup_batch("cep", "matches",
+                                            qkeys.tolist())
+                    stats = pool.fe_stats()
+                for i in range(40):
+                    assert CepMatchServingAdapter.match_rows(got[i]) \
+                        == live[i], i
+                assert sum(r.get("probes_hit", r.get("hits", 0))
+                           for r in stats) > 0
+            finally:
+                plane.shutdown_workers()

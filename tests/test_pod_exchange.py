@@ -4,7 +4,8 @@ two-level ICI/DCN exchange (parallel/exchange2.py).
 Everything here runs SINGLE-process on a 2x4 (and 2x2) VIRTUAL topology
 over 8 CPU devices — the exchange programs only see the (hosts, local)
 factorization, so plain tier-1 exercises the exact program family the
-multi-process smoke dispatches across real process boundaries. The
+two-process pod run (``tests/test_host_failover.py``) dispatches across
+real process boundaries. The
 contracts pinned:
 
 - ``make_mesh`` REFUSES to silently truncate (`num_devices` beyond the
@@ -429,7 +430,7 @@ class TestOperatorWiring:
 
 class TestPodDataPlane:
     """The DCN record router (parallel/pod.py) in its single-process
-    tier-1 mode: same program family the multi-process smoke dispatches
+    tier-1 mode: same program family the two-process pod run dispatches
     across real process boundaries."""
 
     def test_routes_to_owner_in_stream_order(self):

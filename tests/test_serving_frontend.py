@@ -16,6 +16,8 @@ The contracts under test:
   row — every hit matches the deterministic value scheme of exactly
   one generation (verified against a dict-oracle formula, not
   wall-clock luck);
+- frontends over a LIVE ingesting job serve hits out of shared memory
+  and agree with the owner's own lookup path;
 - lookup routing follows ``host_of_key_group`` under the LIVE
   key-group assignment, reassembling results in input order.
 """
@@ -396,6 +398,118 @@ class TestCrossProcessSeqlock:
                 assert rows[1]["probes"] > 0 and rows[2]["probes"] > 0
             finally:
                 cache.close()
+
+
+# ------------------------------------------- frontends over a live job
+
+
+@native
+class TestFrontendsServeALiveJob:
+    RECORDS, KEYS, LOOKUP_BATCH = 60_000, 2048, 128
+
+    def test_lookups_hit_shm_and_match_the_owner_under_ingest(self):
+        """A session cluster ingests a real job with the shm serving
+        tier armed while two frontend processes serve lookups (hits
+        inside the frontend, misses crossing to the owner's replica
+        path) and background clients keep them busy.
+
+        The frontends are made ready BEFORE the job runs: a spawned
+        frontend pays its interpreter and import boot first, and a job
+        this size finishes sooner than that, so lookups queued at start
+        would reach the owner only after it stopped serving. The test
+        steps the cluster itself and looks up between rounds, when no
+        publish can land: a batch's first pass fills the shm table
+        through the miss path, the second is served from shared
+        memory, and both equal the owner's own lookup."""
+        import threading
+
+        from flink_tpu.connectors.sinks import CollectSink
+        from flink_tpu.connectors.sources import DataGenSource
+        from flink_tpu.core.config import Configuration
+        from flink_tpu.datastream.environment import (
+            StreamExecutionEnvironment,
+        )
+        from flink_tpu.runtime.watermarks import WatermarkStrategy
+        from flink_tpu.tenancy.frontend import FrontendPool
+        from flink_tpu.tenancy.session_cluster import SessionCluster
+        from flink_tpu.windowing.assigners import TumblingEventTimeWindows
+
+        env = StreamExecutionEnvironment(Configuration({
+            "execution.micro-batch.size": 4096,
+            "parallelism.default": 4,
+            "serving.replica": True,
+            "serving.replica.publish-interval-ms": 25,
+        }))
+        sink = CollectSink()
+        (env.add_source(
+            DataGenSource(total_records=self.RECORDS,
+                          num_keys=self.KEYS,
+                          events_per_second_of_eventtime=50_000,
+                          seed=13),
+            WatermarkStrategy.for_bounded_out_of_orderness(0))
+            .key_by("key")
+            .window(TumblingEventTimeWindows.of(60_000))
+            .sum("value").sink_to(sink))
+        operator = "window_agg(SumAggregate)"
+        stop = threading.Event()
+        errors = []
+
+        with tempfile.TemporaryDirectory() as tmp:
+            cluster = SessionCluster(
+                quantum_records=8192,
+                serving_shm_dir=os.path.join(tmp, "serving-shm"))
+            cluster.submit(env, "job-1")
+            pool = FrontendPool(cluster.serving, n_frontends=2)
+
+            def client(i):
+                rng = np.random.default_rng(500 + i)
+                while not stop.is_set():
+                    ks = rng.integers(0, self.KEYS,
+                                      self.LOOKUP_BATCH).tolist()
+                    try:
+                        pool.lookup_batch("job-1", operator, ks)
+                    except (RuntimeError, TimeoutError) as e:
+                        if "is not serving" not in str(e):
+                            errors.append(f"client {i}: {e!r}")
+                        return
+                    time.sleep(0.005)
+
+            threads = [threading.Thread(target=client, args=(i,),
+                                        daemon=True) for i in range(2)]
+            rng = np.random.default_rng(7)
+            checked = 0
+            try:
+                pool.wait_ready()
+                for t in threads:
+                    t.start()
+                while cluster.step_round():
+                    if cluster.serving.replica_generations() == 0:
+                        continue  # nothing sealed to serve yet
+                    ks = rng.integers(0, self.KEYS,
+                                      self.LOOKUP_BATCH).tolist()
+                    first = pool.lookup_batch("job-1", operator, ks)
+                    again = pool.lookup_batch("job-1", operator, ks)
+                    assert first == again
+                    assert again == cluster.lookup_batch(
+                        "job-1", operator, ks)
+                    checked += 1
+            finally:
+                stop.set()
+                for t in threads:
+                    t.join(timeout=10)
+                cluster.serving.shutdown_workers()
+                fe_rows = cluster.serving.hot_cache.fe_stats(2)
+                live = len(pool.live_frontends())
+                pool.close()
+                cluster.serving.hot_cache.close()
+
+        assert errors == []
+        assert checked > 0, "the job ended before anything was served"
+        assert sum(r["hits"] for r in fe_rows) > 0, \
+            "frontends never served a shm hit"
+        assert sum(r["miss_crossings"] for r in fe_rows) > 0
+        assert live == 2
+        assert len(sink.result()) > 0
 
 
 # ------------------------------------------------------------- routing

@@ -1,23 +1,23 @@
 """The shared state-plane kernel library (flink_tpu/stateplane).
 
-Three contracts:
+Four contracts:
 
 - **One library, one cache entry per (family, key)**: every engine's
   device programs come from the ``families`` builders, keyed on WHAT
   they compute — two owners with the same plane layout share the
   executable object (the multi-tenant zero-recompile contract, now
   enforced at the library boundary).
-- **Backend hook honesty**: ``stateplane.backend.<family>`` resolves
-  per family, rejects unknown families/backends, and refuses a pallas
-  request for a family with no pallas implementation (a config typo
-  must not vacuously pass an A/B experiment).
-- **Golden bit identity**: the Pallas exchange-rank kernel equals the
-  XLA one-hot-cumsum EXACTLY on random shapes (ranks AND the
-  downstream fold/scatter order), and ported engines driven through
+- **Exchange rank**: the rank-within-destination combinator equals a
+  sequential counting pass EXACTLY on random shapes, out-of-range
+  lanes included.
+- **Golden bit identity**: ported engines driven through
   forced paged eviction plus a live mid-stream reshard pin their
   fires (including emission order), snapshots (including row order),
   deltas and spill counters — run-to-run and against the host data
   plane.
+- **Steady state**: after a warmup pass, fresh engines and a second
+  job on the warm cache compile nothing, across the mesh engines, the
+  flat and two-level exchanges, the interval join and CEP.
 """
 
 import numpy as np
@@ -25,17 +25,12 @@ import pytest
 
 from flink_tpu.stateplane import (
     KNOWN_PROGRAM_FAMILIES,
-    backend_of,
-    backend_scope,
-    build_exchange_rank,
-    configure_backends,
     exchange_rank_flat,
     flat_fence,
     flat_gather,
     flat_put,
     flat_scatter_combine,
     flat_segment_fire,
-    set_backend,
     xla_rank,
 )
 from flink_tpu.windowing.aggregates import AvgAggregate, SumAggregate
@@ -85,186 +80,45 @@ class TestProgramFamilies:
         pytest.fail("KNOWN_PROGRAM_FAMILIES literal not found")
 
 
-# -------------------------------------------------------------- backends
+# ------------------------------------------------------------------ rank
 
 
-class TestBackendHook:
-    def test_default_is_xla(self):
-        assert backend_of("exchange-rank") == "xla"
-        assert backend_of("gather") == "xla"
-
-    def test_scope_restores(self):
-        with backend_scope("exchange-rank", "pallas"):
-            assert backend_of("exchange-rank") == "pallas"
-        assert backend_of("exchange-rank") == "xla"
-
-    def test_unknown_family_rejected(self):
-        with pytest.raises(ValueError, match="unknown program family"):
-            set_backend("exchange-rnak", "pallas")
-
-    def test_unknown_backend_rejected(self):
-        with pytest.raises(ValueError, match="unknown backend"):
-            set_backend("exchange-rank", "triton")
-
-    def test_pallas_for_incapable_family_rejected(self):
-        """No silent xla fallback: a family without a pallas
-        implementation refuses the override outright."""
-        with pytest.raises(ValueError, match="no pallas implementation"):
-            set_backend("gather", "pallas")
-
-    def test_config_hook_applies_and_restores(self):
-        from flink_tpu.core.config import Configuration
-
-        conf = Configuration(
-            {"stateplane.backend.exchange-rank": "pallas"})
-        try:
-            applied = configure_backends(conf)
-            assert applied == {"exchange-rank": "pallas"}
-            assert backend_of("exchange-rank") == "pallas"
-        finally:
-            set_backend("exchange-rank", "xla")
-
-    def test_config_hook_rejects_typo_family(self):
-        from flink_tpu.core.config import Configuration
-
-        conf = Configuration({"stateplane.backend.gather": "pallas"})
-        with pytest.raises(ValueError):
-            configure_backends(conf)
-
-    def test_config_hook_scans_keys_not_known_names(self):
-        """A typo'd FAMILY in the config key must raise, not be
-        silently skipped — the hook scans the key space for the
-        prefix (including fallback layers)."""
-        from flink_tpu.core.config import Configuration
-
-        conf = Configuration({"stateplane.backend.gahter": "xla"})
-        with pytest.raises(ValueError, match="unknown program family"):
-            configure_backends(conf)
-        layered = Configuration({"unrelated.key": 1}).with_fallback(
-            Configuration({"stateplane.backend.exchange-rnak": "xla"}))
-        with pytest.raises(ValueError, match="unknown program family"):
-            configure_backends(layered)
-
-    def test_executor_applies_backend_config_at_submit(self):
-        """A job Configuration's stateplane.backend.* keys take effect
-        through the executor — and an invalid one fails the job at
-        SUBMIT, before any batch runs."""
-        from flink_tpu.connectors.sinks import CollectSink
-        from flink_tpu.connectors.sources import DataGenSource
-        from flink_tpu.core.config import Configuration
-        from flink_tpu.datastream.environment import (
-            StreamExecutionEnvironment,
-        )
-        from flink_tpu.runtime.watermarks import WatermarkStrategy
-        from flink_tpu.windowing.assigners import (
-            TumblingEventTimeWindows,
-        )
-
-        def job(conf):
-            env = StreamExecutionEnvironment(conf)
-            sink = CollectSink()
-            (env.add_source(
-                DataGenSource(total_records=2000, num_keys=16,
-                              events_per_second_of_eventtime=2000),
-                WatermarkStrategy.for_bounded_out_of_orderness(0))
-             .key_by("key")
-             .window(TumblingEventTimeWindows.of(1000))
-             .count()
-             .sink_to(sink))
-            env.execute()
-            return sink
-
-        try:
-            sink = job(Configuration(
-                {"stateplane.backend.exchange-rank": "xla"}))
-            assert len(sink.rows()) > 0
-            assert backend_of("exchange-rank") == "xla"
-            with pytest.raises(ValueError, match="unknown program"):
-                job(Configuration(
-                    {"stateplane.backend.gahter": "xla"}))
-        finally:
-            set_backend("exchange-rank", "xla")
+def _rank_reference(d, num_dests):
+    """rank(i) = #{j < i : 0 <= d_j < D and d_j == clip(d_i, 0, D-1)}:
+    the count of prior in-range records at the lane's clipped bucket."""
+    counts = [0] * num_dests
+    out = []
+    for x in d.tolist():
+        b = min(max(x, 0), num_dests - 1)
+        out.append(counts[b])
+        if 0 <= x < num_dests:
+            counts[b] += 1
+    return np.asarray(out, dtype=np.int32)
 
 
-# ---------------------------------------------------- rank kernel parity
-
-
-class TestPallasRankParity:
-    def test_random_shapes_bit_identical(self):
+class TestExchangeRank:
+    def test_random_shapes_match_reference(self):
         """Property test: over random (num_dests, length, width) the
-        Pallas counting sort equals the XLA one-hot-cumsum EXACTLY —
-        ranks and the flattened (dest, rank) scatter positions,
-        including the out-of-range destinations staging pads with and
-        bucket-overflow lanes."""
-        from flink_tpu.stateplane.rank import pallas_rank
-
+        one-hot-cumsum rank equals a sequential counting pass EXACTLY,
+        including the out-of-range destinations staging pads with."""
         rng = np.random.default_rng(19)
         for _ in range(25):
             D = int(rng.integers(1, 17))
             n = int(rng.integers(1, 500))
-            W = int(rng.integers(1, 64))
             d = rng.integers(-2, D + 3, size=n).astype(np.int32)
             np.testing.assert_array_equal(
-                np.asarray(pallas_rank(d, D)),
-                np.asarray(xla_rank(d, D)))
-            np.testing.assert_array_equal(
-                np.asarray(exchange_rank_flat(d, D, W, "pallas")),
-                np.asarray(exchange_rank_flat(d, D, W, "xla")))
+                np.asarray(xla_rank(d, D)), _rank_reference(d, D))
 
-    def test_cached_program_parity_and_distinct_keys(self):
-        """The cached exchange-rank programs agree across backends and
-        occupy DISTINCT cache entries (cache-key honesty: a backend
-        swap is a new key, never a silent retrace)."""
-        d = np.asarray([3, 0, 1, 0, 7, 3, 3, -1, 0], dtype=np.int32)
-        px = build_exchange_rank(8, "xla")
-        pp = build_exchange_rank(8, "pallas")
-        assert px is not pp
+    def test_flat_offsets_drop_out_of_range_and_overflow(self):
+        """``exchange_rank_flat`` places in-range lanes at
+        ``d * W + rank`` and sends out-of-range destinations and
+        bucket-overflow lanes to the sentinel ``D * W``."""
+        d = np.asarray([3, 0, 1, 0, 7, 3, 3, -1, 0, 8], dtype=np.int32)
+        D, W = 8, 2
+        flat = np.asarray(exchange_rank_flat(d, D, W))
+        sentinel = D * W
         np.testing.assert_array_equal(
-            np.asarray(px(d, 4)), np.asarray(pp(d, 4)))
-
-    def test_downstream_fold_order_identical(self, eight_device_mesh):
-        """The full fused exchange+scatter program under the pallas
-        rank backend equals the xla-backed one bit-for-bit — same
-        bucket positions means same scatter order means identical
-        state planes (the fold-order half of the A/B gate)."""
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from flink_tpu.parallel.mesh import KEY_AXIS
-        from flink_tpu.parallel.shuffle import (
-            build_exchange_scatter,
-            stage_device_exchange,
-        )
-
-        mesh = eight_device_mesh
-        agg = SumAggregate("v")
-        sharding = NamedSharding(mesh, P(KEY_AXIS))
-        cap = 2048
-        rng = np.random.default_rng(5)
-        n = 4000
-        shards = rng.integers(0, 8, n).astype(np.int64)
-        slots = rng.integers(1, cap, n).astype(np.int32)
-        vals = rng.integers(0, 100, n).astype(np.float32)
-        dst, staged, width = stage_device_exchange(
-            shards, 8, [slots, vals], fills=[0, 0.0])
-        put = jax.device_put((dst, *staged), sharding)
-
-        def run():
-            accs = tuple(
-                jax.device_put(jnp.full((8, cap), l.identity,
-                                        dtype=l.dtype), sharding)
-                for l in agg.leaves)
-            step = build_exchange_scatter(mesh, agg, valued=False)
-            return jax.device_get(list(step(
-                accs, put[0], put[1], tuple(put[2:]), width)))
-
-        base = run()
-        with backend_scope("exchange-rank", "pallas"):
-            swapped = run()
-        for b, s in zip(base, swapped):
-            np.testing.assert_array_equal(np.asarray(b),
-                                          np.asarray(s))
+            flat, [6, 0, 2, 1, 14, 7] + [sentinel] * 4)
 
 
 # ------------------------------------------------------- golden identity
@@ -419,16 +273,340 @@ class TestGoldenBitIdentity:
         v_dev, v_host = vals_of(dev), vals_of(host)
         assert len(v_dev) > 0 and v_dev == v_host
 
-    def test_session_fires_identical_under_pallas_rank(
-            self, eight_device_mesh):
-        """The engine-level half of the Pallas A/B gate: a device-mode
-        session run with the pallas exchange-rank backend emits
-        bit-identical fires IN ORDER vs the xla backend."""
-        steps = _stream(seed=59, n_steps=4)
-        base, _ = _drive(self._session_engine(eight_device_mesh),
-                         steps)
-        with backend_scope("exchange-rank", "pallas"):
-            swapped, _ = _drive(
-                self._session_engine(eight_device_mesh), steps)
-        assert len(_fire_rows(base)) > 0
-        assert _fire_rows(base) == _fire_rows(swapped)
+
+# ------------------------------------------------- steady-state compiles
+#
+# One warmup pass compiles a program family at its padded tier shapes;
+# then a FRESH engine (or job) replays the same shapes under the
+# recompile sentinel and must compile NOTHING. Fresh engines make the
+# claim strict: a program keyed on anything unstable (engine identity,
+# a per-instance lambda, a device object instead of its id, a raw batch
+# length instead of its tier) recompiles here. Spill is armed below the
+# live set, and asserted to engage, so the evict/reload programs are
+# part of what is guarded.
+
+SS_GAP_MS = 16_000
+SS_WINDOW_MS = 5_000
+SS_KEYS = 50_000
+SS_BATCH = 8_192
+SS_RECORDS = 1 << 16
+#: records per ms of event time: slow event time keeps the live set of
+#: each open window or session gap above the per-shard slot budget
+SS_RECORDS_PER_MS = 4
+#: per-shard slot budgets below the live set; the window engine's floor
+#: is one slice's per-shard key set (a batch's touched namespaces are
+#: protected from eviction)
+SS_SESSION_SLOTS, SS_WINDOW_SLOTS = 2048, 4096
+#: batch-size walks over the same pad tiers with shifted lengths: the
+#: warm engine walks both lists, the fresh one replays the second
+TIER_WALK_WARM = (8192, 4096, 2048, 6000, 3000, 1900)
+TIER_WALK_RUN = (8000, 3500, 2200, 7000, 2600, 1800)
+JOIN_WALK_WARM = (4096, 2048, 1024, 3000, 1500, 900)
+JOIN_WALK_RUN = (4000, 2200, 1100, 2800, 1300, 1000)
+CEP_WALK_WARM = (512, 256, 128, 384, 192, 96)
+CEP_WALK_RUN = (448, 288, 144, 336, 224, 112)
+
+
+def _ss_batches(rep):
+    """One rep's stream: identical shape every rep, event time shifted
+    by whole windows so watermarks advance and windows close (a
+    non-aligned shift would move the fire tiers)."""
+    from flink_tpu.core.records import (
+        KEY_ID_FIELD,
+        TIMESTAMP_FIELD,
+        RecordBatch,
+    )
+
+    stride = SS_RECORDS // SS_RECORDS_PER_MS + 10 * SS_GAP_MS
+    stride += -stride % SS_WINDOW_MS
+    rng = np.random.default_rng(7)
+    produced = 0
+    while produced < SS_RECORDS:
+        b = min(SS_BATCH, SS_RECORDS - produced)
+        ts = rep * stride + (produced + np.arange(b, dtype=np.int64)) \
+            // SS_RECORDS_PER_MS
+        yield RecordBatch({
+            KEY_ID_FIELD: rng.integers(0, SS_KEYS, b).astype(np.int64),
+            "v": np.ones(b, dtype=np.float32),
+            TIMESTAMP_FIELD: ts,
+        }), int(ts[-1])
+        produced += b
+
+
+def _ss_drive(engines, rep, serve_keys=None):
+    """The rep's stream through every engine in turn (jobs interleaved
+    batch by batch), with a batched lookup per engine per batch when
+    ``serve_keys`` is given. Returns the number of rows fired."""
+    fired = 0
+    last = 0
+    for rb, last in _ss_batches(rep):
+        for eng in engines:
+            eng.process_batch(rb)
+            fired += sum(len(b)
+                         for b in eng.on_watermark(last - SS_GAP_MS))
+            if serve_keys is not None:
+                eng.query_batch(serve_keys)
+    for eng in engines:
+        fired += sum(len(b) for b in
+                     eng.on_watermark(last + 100 * SS_GAP_MS))
+    return fired
+
+
+def _ss_drive_sized(engine, sizes, offset):
+    """One batch per entry of ``sizes``, event time advancing so
+    sessions fire, then a flush. Returns rows fired."""
+    from flink_tpu.core.records import (
+        KEY_ID_FIELD,
+        TIMESTAMP_FIELD,
+        RecordBatch,
+    )
+
+    rng = np.random.default_rng(11)
+    fired = 0
+    t = offset
+    for b in sizes:
+        ts = t + np.arange(b, dtype=np.int64) // SS_RECORDS_PER_MS
+        engine.process_batch(RecordBatch({
+            KEY_ID_FIELD: rng.integers(0, SS_KEYS, b).astype(np.int64),
+            "v": np.ones(b, dtype=np.float32),
+            TIMESTAMP_FIELD: ts,
+        }))
+        t = int(ts[-1]) + 1
+        fired += sum(len(x) for x in engine.on_watermark(t - SS_GAP_MS))
+    fired += sum(len(x)
+                 for x in engine.on_watermark(t + 100 * SS_GAP_MS))
+    return fired
+
+
+def _ss_sessions(mesh, **kw):
+    from flink_tpu.parallel.sharded_sessions import MeshSessionEngine
+
+    return MeshSessionEngine(SS_GAP_MS, SumAggregate("v"), mesh,
+                             capacity_per_shard=SS_SESSION_SLOTS,
+                             max_device_slots=SS_SESSION_SLOTS, **kw)
+
+
+def _ss_windows(mesh):
+    from flink_tpu.parallel.sharded_windower import MeshWindowEngine
+    from flink_tpu.windowing.assigners import TumblingEventTimeWindows
+
+    return MeshWindowEngine(TumblingEventTimeWindows.of(SS_WINDOW_MS),
+                            SumAggregate("v"), mesh,
+                            capacity_per_shard=SS_WINDOW_SLOTS,
+                            max_device_slots=SS_WINDOW_SLOTS)
+
+
+def _ss_tier_walk(make):
+    """Warm one engine over both size lists, then replay the shifted
+    list on a fresh engine under the sentinel. Returns the fresh
+    engine."""
+    from flink_tpu.observe import RecompileSentinel
+
+    warm = make()
+    assert _ss_drive_sized(warm, TIER_WALK_WARM, offset=0) \
+        + _ss_drive_sized(warm, TIER_WALK_RUN, offset=1 << 22) > 0
+    engine = make()
+    with RecompileSentinel(max_compiles=0,
+                           max_transfers=max(len(TIER_WALK_RUN) * 8, 64),
+                           label="tier walk"):
+        fired = _ss_drive_sized(engine, TIER_WALK_RUN, offset=1 << 23)
+    assert fired > 0, "vacuous: nothing fired"
+    return engine
+
+
+def _ss_join_drive(engine, sizes, offset):
+    """Two-sided interval-join stream, one left and one right batch per
+    entry of ``sizes``, with a lagging watermark so the band stays
+    populated and the spill tier engages. Returns rows matched."""
+    from flink_tpu.core.records import (
+        KEY_ID_FIELD,
+        TIMESTAMP_FIELD,
+        RecordBatch,
+    )
+
+    rng = np.random.default_rng(17)
+    matches = 0
+    t = offset
+    for b in sizes:
+        for side, name in ((0, "v"), (1, "w")):
+            ts = t + np.arange(b, dtype=np.int64) // SS_RECORDS_PER_MS
+            out = engine.process_batch(RecordBatch({
+                KEY_ID_FIELD: rng.integers(0, SS_KEYS,
+                                           b).astype(np.int64),
+                name: np.ones(b, dtype=np.float32),
+                TIMESTAMP_FIELD: ts,
+            }), side)
+            matches += sum(len(x) for x in out)
+        t = int(ts[-1]) + 1
+        engine.on_watermark(t - 3000)
+    return matches
+
+
+def _ss_cep_drive(engine, sizes, offset, n_keys, rng):
+    """One keyed batch and one trailing-watermark fire per entry of
+    ``sizes``. Returns (rows matched, event time reached)."""
+    from flink_tpu.core.records import RecordBatch
+
+    matches = 0
+    t = offset
+    for n in sizes:
+        keys = rng.integers(0, n_keys, n).astype(np.int64)
+        ts = t + np.sort(rng.integers(0, 30, size=n)).astype(np.int64)
+        t += 25
+        engine.process_batch(RecordBatch.from_pydict(
+            {"k": keys, "v": rng.integers(0, 9, n).astype(np.int64),
+             "__key_id__": keys}, timestamps=ts))
+        matches += sum(len(b) for b in engine.on_watermark(t - 5))
+    return matches, t
+
+
+class TestSteadyStateCompiles:
+    @pytest.mark.parametrize("engine", ["mesh-sessions", "mesh-windows"])
+    def test_fresh_engines_compile_nothing(self, eight_device_mesh,
+                                           engine):
+        """Two measured reps, each on a FRESH engine: the step programs
+        hit across engine rebuilds (restarts, rescales), not just across
+        batches. Each watermark advance harvests one batched read, so
+        the transfer budget also catches an unbatched per-leaf read."""
+        from flink_tpu.observe import RecompileSentinel
+
+        make = {"mesh-sessions": _ss_sessions,
+                "mesh-windows": _ss_windows}[engine]
+        assert _ss_drive([make(eight_device_mesh)], rep=0) > 0
+        for rep in (1, 2):
+            eng = make(eight_device_mesh)
+            with RecompileSentinel(
+                    max_compiles=0,
+                    max_transfers=(SS_RECORDS // SS_BATCH) * 8,
+                    label=f"{engine} rep {rep}"):
+                fired = _ss_drive([eng], rep)
+            assert fired > 0, "vacuous: nothing fired"
+            assert eng.spill_counters()["rows_evicted"] > 0, \
+                "spill never engaged: evict/reload not covered"
+
+    def test_device_shuffle_tier_walk(self, eight_device_mesh):
+        """The fused exchange's program shapes are (chunk tier, bucket
+        width tier): a fresh engine on shifted batch sizes over the same
+        tier lattice compiles nothing."""
+        def make():
+            eng = _ss_sessions(eight_device_mesh)
+            assert eng.shuffle_mode == "device"
+            return eng
+
+        _ss_tier_walk(make)
+
+    def test_two_level_exchange_tier_walk(self, eight_device_mesh):
+        """A virtual (2, 4) topology arms the two-level exchange's
+        stage-1/stage-2 pair: its (chunk, W1, W2) tiers hold on a fresh
+        engine, and rows really crossed the host axis."""
+        from flink_tpu.parallel.mesh import HostTopology
+
+        topo = HostTopology(2, 4)
+
+        def make():
+            eng = _ss_sessions(eight_device_mesh, host_topology=topo)
+            assert eng._two_level_active()
+            return eng
+
+        engine = _ss_tier_walk(make)
+        assert engine.exchange2_traffic()["rows_cross_host"] > 0
+
+    def test_interval_join_tier_walk(self, eight_device_mesh):
+        """The banded-probe / ingest-exchange / eviction-gather family:
+        a fresh interval-join engine on shifted sizes compiles nothing,
+        with rows evicted and cold band candidates served from pages."""
+        from flink_tpu.joins import MeshIntervalJoinEngine
+        from flink_tpu.observe import RecompileSentinel
+
+        def make():
+            # band as deep as the pruning horizon: probes reach past the
+            # resident rows into the paged tier
+            return MeshIntervalJoinEngine(
+                -2500, 2500, mesh=eight_device_mesh,
+                capacity_per_shard=SS_SESSION_SLOTS // 4,
+                max_device_slots=SS_SESSION_SLOTS // 4)
+
+        warm = make()
+        assert _ss_join_drive(warm, JOIN_WALK_WARM, offset=0) \
+            + _ss_join_drive(warm, JOIN_WALK_RUN, offset=1 << 22) > 0
+        engine = make()
+        with RecompileSentinel(max_compiles=0,
+                               max_transfers=len(JOIN_WALK_RUN) * 16,
+                               label="join tier walk"):
+            matches = _ss_join_drive(engine, JOIN_WALK_RUN,
+                                     offset=1 << 23)
+        assert matches > 0, "vacuous: nothing matched"
+        sc = engine.spill_counters()
+        assert sc["rows_evicted"] > 0 and sc["cold_rows_served"] > 0
+
+    def test_cep_tier_walk(self, eight_device_mesh, tmp_path):
+        """Both CEP device program families — a within-window sequence
+        (advance + within-prune) and an always-alive pattern (advance +
+        evict/restore, keys far beyond the slot budget) — compile
+        nothing on fresh engines over shifted batch sizes."""
+        from flink_tpu.cep.mesh_engine import MeshCepEngine
+        from flink_tpu.cep.pattern import AfterMatchSkipStrategy, Pattern
+        from flink_tpu.observe import RecompileSentinel
+
+        skip = AfterMatchSkipStrategy.SKIP_PAST_LAST_EVENT
+        within_pat = (Pattern.begin("a", skip=skip)
+                      .where(lambda b: np.asarray(b["v"]) % 3 == 0)
+                      .next("b")
+                      .where(lambda b: np.asarray(b["v"]) % 3 == 1)
+                      .within(50))
+        churn_pat = (Pattern.begin("a", skip=skip)
+                     .next("b")
+                     .where(lambda b: np.asarray(b["v"]) == 7))
+
+        def make(pat, spill_dir=None):
+            return MeshCepEngine(pat, key_field="k",
+                                 mesh=eight_device_mesh,
+                                 capacity_per_shard=256,
+                                 spill_dir=spill_dir)
+
+        rng = np.random.default_rng(19)
+        spill = str(tmp_path)
+        w_within = make(within_pat)
+        warm, t = _ss_cep_drive(w_within, CEP_WALK_WARM, 0, 64, rng)
+        warm += _ss_cep_drive(w_within, CEP_WALK_RUN, t, 64, rng)[0]
+        assert warm > 0
+        w_churn = make(churn_pat, spill_dir=spill)
+        _, t = _ss_cep_drive(w_churn, CEP_WALK_WARM, 0, 20_000, rng)
+        _ss_cep_drive(w_churn, CEP_WALK_RUN, t, 20_000, rng)
+
+        within = make(within_pat)
+        churn = make(churn_pat, spill_dir=spill)
+        with RecompileSentinel(max_compiles=0,
+                               max_transfers=len(CEP_WALK_RUN) * 6 * 64,
+                               label="cep tier walk"):
+            m, _ = _ss_cep_drive(within, CEP_WALK_RUN, 0, 64, rng)
+            # two passes: the churn engine's live key set must outgrow
+            # the 8 x 256 slot budget so evict/restore run here too
+            _, t2 = _ss_cep_drive(churn, CEP_WALK_RUN, 0, 20_000, rng)
+            cm = _ss_cep_drive(churn, CEP_WALK_RUN, t2, 20_000, rng)[0]
+        assert m > 0 and cm > 0, "vacuous: nothing matched"
+        assert churn.spill_counters()["rows_evicted"] > 0
+
+    def test_second_job_on_warm_cluster(self, eight_device_mesh):
+        """The tenancy contract: after job A warmed the ingest, fire,
+        evict and serving programs, a SECOND job's two fresh engines,
+        interleaved batch by batch with batched lookups, compile
+        nothing and miss the program cache nowhere."""
+        from flink_tpu.observe import RecompileSentinel
+        from flink_tpu.tenancy.program_cache import PROGRAM_CACHE
+
+        serve_keys = np.arange(0, SS_KEYS, SS_KEYS // 16, dtype=np.int64)
+        with PROGRAM_CACHE.job_scope("steady-warm"):
+            assert _ss_drive([_ss_sessions(eight_device_mesh)], rep=0,
+                             serve_keys=serve_keys) > 0
+        PROGRAM_CACHE.reset_stats()
+        with PROGRAM_CACHE.job_scope("steady-job2"):
+            with RecompileSentinel(
+                    max_compiles=0,
+                    max_transfers=(SS_RECORDS // SS_BATCH) * 24,
+                    label="2 jobs on warm cluster"):
+                fired = _ss_drive([_ss_sessions(eight_device_mesh),
+                                   _ss_sessions(eight_device_mesh)],
+                                  rep=1, serve_keys=serve_keys)
+        assert fired > 0, "vacuous: nothing fired"
+        assert PROGRAM_CACHE.stats_for("steady-job2")["misses"] == 0

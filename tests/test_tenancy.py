@@ -2,7 +2,8 @@
 
 The tenancy claims, executed:
 - job K+1 on a warm cluster compiles NOTHING (shared program cache,
-  sentinel-verified);
+  sentinel-verified), also while serving batched lookups through the
+  read-replica plane and its hot-row cache;
 - two jobs with IDENTICAL key spaces on one mesh are bit-identical to
   each running alone — windows, sessions, paged spill with forced
   eviction (cross-job state isolation is structural);
@@ -857,6 +858,132 @@ class TestSessionCluster:
         assert int(eng_a.P) == 4
         assert int(eng_b.P) == 1
         assert _rows(sa) == solo and _rows(sb) == solo
+
+
+# ------------------------------------------- serving on a warm cluster
+
+
+class TestServingOnWarmCluster:
+    """Two FRESH jobs on a fresh cluster, after a first job warmed the
+    shared program cache and the replica tier lattice, ingest under
+    batched lookup load through the read-replica plane."""
+
+    RECORDS, KEYS, QUOTA_ROWS, LOOKUP_BATCH = 100_000, 4096, 8192, 256
+    OPERATOR = "window_agg(SumAggregate)"
+
+    def _pipeline(self, sink):
+        cfg = {
+            "execution.micro-batch.size": 4096,
+            "parallelism.default": 4,
+            # deadline splitting bounds each ingest dispatch, so a miss
+            # batch never waits out a full-batch program
+            "latency.fire-deadline-ms": 25,
+            "serving.replica": True,
+            "serving.replica.publish-interval-ms": 25,
+            # the spill tier sized to the quota's per-shard slice, so
+            # the quota has somewhere to shed
+            "state.slot-table.max-device-slots": TenantQuota(
+                max_resident_rows=self.QUOTA_ROWS).per_shard_slots(4),
+        }
+        env = StreamExecutionEnvironment(Configuration(cfg))
+        (env.add_source(
+            DataGenSource(total_records=self.RECORDS, num_keys=self.KEYS,
+                          events_per_second_of_eventtime=50_000,
+                          seed=13),
+            WatermarkStrategy.for_bounded_out_of_orderness(0))
+            .key_by("key")
+            .window(TumblingEventTimeWindows.of(60_000))
+            .sum("value").sink_to(sink))
+        return env
+
+    def _serve(self, cluster, jobs, n_clients):
+        """Step the cluster to completion with ``n_clients`` background
+        threads hammering packed lookups, and between rounds (when no
+        publish can land) look one batch up twice per job: the second
+        pass must equal the dict path. Returns (client errors, checks
+        made, most replica generations seen)."""
+        stop = threading.Event()
+        errors = []
+
+        def client(i):
+            rng = np.random.default_rng(100 + i)
+            while not stop.is_set():
+                ks = rng.integers(0, self.KEYS, self.LOOKUP_BATCH).tolist()
+                try:
+                    cluster.lookup_batch_packed(jobs[i % len(jobs)],
+                                                self.OPERATOR, ks)
+                except RuntimeError as e:
+                    if "is not serving" not in str(e) and \
+                            "shut down" not in str(e):
+                        errors.append(f"client {i}: {e!r}")
+                    return
+                time.sleep(0.005)
+
+        threads = [threading.Thread(target=client, args=(i,),
+                                    daemon=True)
+                   for i in range(n_clients)]
+        rng = np.random.default_rng(3)
+        checked = gens = 0
+        for t in threads:
+            t.start()
+        try:
+            while cluster.step_round():
+                g = cluster.serving.replica_generations()
+                gens = max(gens, g)
+                if g == 0:
+                    continue
+                for job in jobs:
+                    if cluster.jobs[job].finished:
+                        continue
+                    ks = rng.integers(0, self.KEYS,
+                                      self.LOOKUP_BATCH).tolist()
+                    cluster.lookup_batch_packed(job, self.OPERATOR, ks)
+                    packed = cluster.lookup_batch_packed(
+                        job, self.OPERATOR, ks)
+                    assert packed.to_dicts() == cluster.lookup_batch(
+                        job, self.OPERATOR, ks)
+                    checked += 1
+            cluster.serving.shutdown_workers()
+        finally:
+            stop.set()
+            for t in threads:
+                t.join(timeout=10)
+        return errors, checked, gens
+
+    def test_two_fresh_jobs_serve_lookups_without_compiles(self):
+        from flink_tpu.native import hotcache_available
+        from flink_tpu.observe import RecompileSentinel
+        from flink_tpu.tenancy.hot_cache import HotRowCache
+
+        # job-1 warms ingest, fire, serving and replica programs
+        warm = SessionCluster(quantum_records=8192, serving_workers=2)
+        warm.submit(self._pipeline(CollectSink()), "job-1")
+        errors, _, _ = self._serve(warm, ["job-1"], 2)
+        assert errors == []
+
+        PROGRAM_CACHE.reset_stats()
+        cluster = SessionCluster(quantum_records=8192, serving_workers=2)
+        s2, s3 = CollectSink(), CollectSink()
+        cluster.submit(self._pipeline(s2), "job-2",
+                       quota=TenantQuota(max_resident_rows=self.QUOTA_ROWS))
+        cluster.submit(self._pipeline(s3), "job-3")
+        with RecompileSentinel(max_compiles=0,
+                               label="two jobs on a warm cluster"):
+            errors, checked, gens = self._serve(
+                cluster, ["job-2", "job-3"], 4)
+
+        assert errors == []
+        assert checked > 0, "the jobs ended before anything was served"
+        for job in ("job-2", "job-3"):
+            assert PROGRAM_CACHE.stats_for(job)["misses"] == 0, job
+        metrics = cluster.serving.metrics()
+        assert metrics["lookups_total"] > 0
+        assert metrics["hot_row_hit_rate"] > 0.0
+        assert gens >= 2, "boundary publishes never happened"
+        assert cluster.jobs["job-2"].ledger.quota_violations == 0
+        assert len(s2.result()) > 0 and len(s3.result()) > 0
+        if hotcache_available():
+            assert not isinstance(cluster.serving.hot_cache, HotRowCache)
 
 
 # ----------------------------------------------------------------- serving

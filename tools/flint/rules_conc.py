@@ -27,8 +27,9 @@ pure AST over the package source, never importing it.
   function, guarded state read under one acquisition of a lock and
   written under a SEPARATE acquisition of the same lock: whatever the
   first block learned is stale by the second. Calls into same-scope
-  helpers that take the lock count as acquisitions (that is exactly
-  the ``backend_scope`` read/restore shape).
+  helpers that take the lock count as acquisitions (a scoped override
+  that reads the old value under one acquisition and restores it under
+  another is exactly this shape).
 - **SHM01 attached-handle write discipline** — scopes that attach to
   the shm hot-cache arena (``hc_attach``) are read-side by contract;
   calling any symbol in the ``HOTCACHE_WRITER_SYMBOLS`` registry
