@@ -435,6 +435,15 @@ class StateOptions:
     SLOT_CAPACITY = ConfigOption(
         "state.slot-table.capacity", default=1 << 20, type=int,
         description="Fixed slot capacity per keyed window state (XLA static shape).")
+    JOIN_CAPACITY = ConfigOption(
+        "state.join.capacity", default=1 << 16, type=int,
+        description="Rows the device regular join (SQL INNER JOIN with no "
+        "window or time bound) holds, both sides together, before a side "
+        "doubles its plane: split between the sides in proportion to the "
+        "rows each brings first. Every row is held for the job's life, so "
+        "this counts rows, where state.slot-table.capacity counts a "
+        "GROUP BY's keys; a plane that doubles is a new shape, compiled "
+        "anew.")
     CHECKPOINT_DIR = ConfigOption(
         "state.checkpoints.dir", default=None, type=str,
         description="Directory for checkpoint snapshots.")

@@ -79,13 +79,98 @@ def _suffixed_names(left_names: Sequence[str],
     return lmap, rmap
 
 
-class JoinEngineBase:
-    """Shared machinery of the two-input engines: the dual side tables,
-    the data-plane staging, eviction, probing, checkpoints, partial
-    restore, live reshard and the watchdog plumbing."""
+class JoinEngineCore:
+    """What every two-input engine has: its devices (``backend="host"``:
+    none, ``num_shards`` host shards), its capacity and key-group bound,
+    the watchdog plumbing, and the flight recorder's ingest and fire
+    spans and harvest."""
 
-    #: subclasses set: ("interval", lower, upper) or ("temporal",)
+    #: subclasses set the engine's kind ("interval", "regular", ...)
     kind: str = ""
+
+    def __init__(self, mesh=None, num_shards: int = 1,
+                 capacity_per_shard: int = 1 << 16,
+                 max_parallelism: int = 128,
+                 backend: str = "device",
+                 suffixes: Tuple[str, str] = ("_l", "_r")) -> None:
+        if backend not in ("device", "host"):
+            raise ValueError(
+                f"backend must be 'device' or 'host', got {backend!r}")
+        self.backend = backend
+        self.mesh = None
+        if backend == "device":
+            if mesh is None:
+                from flink_tpu.parallel.mesh import make_mesh
+
+                mesh = make_mesh(num_shards)
+            self.mesh = mesh
+            self.P = int(mesh.devices.size)
+        else:
+            self.P = int(num_shards)
+        self.capacity = max(int(capacity_per_shard), 256)
+        self.max_parallelism = int(max_parallelism)
+        if self.max_parallelism < self.P:
+            raise ValueError(
+                f"max_parallelism {max_parallelism} < shard count "
+                f"{self.P}")
+        self.suffixes = tuple(suffixes)
+        #: flight-recorder batch sequence (the join engines' analog of
+        #: MeshSpillSupport._flight_batch)
+        self._flight_batch = 0
+
+    # ------------------------------------------------------------- watchdog
+
+    _watchdog = None
+
+    def attach_watchdog(self, wd) -> None:
+        self._watchdog = wd
+        if wd is not None and self.mesh is not None:
+            wd.rebind(self.P, [d.id for d in self.mesh.devices.flat])
+            wd.set_topology(getattr(self, "host_topology", None))
+
+    def _wd_section(self, op: str, shard: int = -1):
+        wd = self._watchdog
+        if wd is None:
+            from flink_tpu.runtime.watchdog import NULL_SECTION
+
+            return NULL_SECTION
+        return wd.section(op, shard)
+
+    def _wd_boundary(self) -> None:
+        wd = self._watchdog
+        if wd is not None:
+            wd.boundary_probe()
+
+    def _harvest_get(self, tree, op: str = "join_probe_harvest"):
+        """ONE batched D2H per harvest point (the TRC01 discipline)."""
+        import jax
+
+        from flink_tpu.observe import flight_recorder as flight
+
+        with flight.span("fire.harvest"), self._wd_section(op):
+            return jax.device_get(tree)
+
+    def _flight_ingest(self):
+        """Open the ``batch.ingest`` flight span for one
+        ``process_batch`` (the one contract, shared with the mesh
+        engines — see flight_recorder.ingest_span)."""
+        from flink_tpu.observe import flight_recorder as flight
+
+        self._flight_batch += 1
+        return flight.ingest_span(self._flight_batch)
+
+    def _flight_fire(self, watermark: int):
+        """Open the ``fire.dispatch`` flight span for one
+        ``on_watermark`` (see flight_recorder.fire_span)."""
+        from flink_tpu.observe import flight_recorder as flight
+
+        return flight.fire_span(watermark)
+
+
+class JoinEngineBase(JoinEngineCore):
+    """Shared machinery of the engines over side tables: the dual side
+    tables, the data-plane staging, eviction, probing, checkpoints,
+    partial restore, live reshard and the side replicas."""
 
     def __init__(self, mesh=None, num_shards: int = 1,
                  capacity_per_shard: int = 1 << 16,
@@ -98,48 +183,26 @@ class JoinEngineBase:
                  shuffle_mode: str = "device",
                  host_topology=None,
                  suffixes: Tuple[str, str] = ("_l", "_r")) -> None:
-        if backend not in ("device", "host"):
-            raise ValueError(
-                f"backend must be 'device' or 'host', got {backend!r}")
         if shuffle_mode not in ("device", "host"):
             raise ValueError(
                 f"shuffle_mode must be 'device' or 'host', got "
                 f"{shuffle_mode!r}")
-        self.backend = backend
+        super().__init__(mesh, num_shards, capacity_per_shard,
+                         max_parallelism, backend, suffixes)
         self.shuffle_mode = shuffle_mode
-        self.mesh = None
-        if backend == "device":
-            if mesh is None:
-                from flink_tpu.parallel.mesh import make_mesh
-
-                mesh = make_mesh(num_shards)
-            self.mesh = mesh
-            self.P = int(mesh.devices.size)
-        else:
-            self.P = int(num_shards)
-        self.capacity = max(int(capacity_per_shard), 256)
         self.max_device_slots = int(max_device_slots or 0)
         if self.max_device_slots:
             self.capacity = min(self.capacity,
                                 max(self.max_device_slots, 256))
-        self.max_parallelism = int(max_parallelism)
-        if self.max_parallelism < self.P:
-            raise ValueError(
-                f"max_parallelism {max_parallelism} < shard count "
-                f"{self.P}")
         self.key_group_range = key_group_range
         self.spill_dir = spill_dir
         self.spill_host_max_bytes = int(spill_host_max_bytes or 0)
-        self.suffixes = tuple(suffixes)
         #: per-side state (created lazily at the side's first batch —
         #: the value schema is observed, like the table-runtime's
         #: late-bound row types)
         self.sides: List[Optional[JoinSideTable]] = [None, None]
         self._planes: List[Optional[tuple]] = [None, None]
         self._next_rid = 1
-        #: flight-recorder batch sequence (the join engines' analog of
-        #: MeshSpillSupport._flight_batch)
-        self._flight_batch = 0
         # sticky compile-shape tiers (per side where shapes differ)
         self._put_bucket = [0, 0]
         self._mirror_bucket = [0, 0]
@@ -179,54 +242,6 @@ class JoinEngineBase:
         from flink_tpu.parallel.exchange2 import ExchangeTraffic
 
         return ExchangeTraffic.dict_of(self._exchange2_traffic)
-
-    # ------------------------------------------------------------- watchdog
-
-    _watchdog = None
-
-    def attach_watchdog(self, wd) -> None:
-        self._watchdog = wd
-        if wd is not None and self.mesh is not None:
-            wd.rebind(self.P, [d.id for d in self.mesh.devices.flat])
-            wd.set_topology(self.host_topology)
-
-    def _wd_section(self, op: str, shard: int = -1):
-        wd = self._watchdog
-        if wd is None:
-            from flink_tpu.runtime.watchdog import NULL_SECTION
-
-            return NULL_SECTION
-        return wd.section(op, shard)
-
-    def _wd_boundary(self) -> None:
-        wd = self._watchdog
-        if wd is not None:
-            wd.boundary_probe()
-
-    def _harvest_get(self, tree, op: str = "join_probe_harvest"):
-        """ONE batched D2H per harvest point (the TRC01 discipline)."""
-        import jax
-
-        from flink_tpu.observe import flight_recorder as flight
-
-        with flight.span("fire.harvest"), self._wd_section(op):
-            return jax.device_get(tree)
-
-    def _flight_ingest(self):
-        """Open the ``batch.ingest`` flight span for one
-        ``process_batch`` (the one contract, shared with the mesh
-        engines — see flight_recorder.ingest_span)."""
-        from flink_tpu.observe import flight_recorder as flight
-
-        self._flight_batch += 1
-        return flight.ingest_span(self._flight_batch)
-
-    def _flight_fire(self, watermark: int):
-        """Open the ``fire.dispatch`` flight span for one
-        ``on_watermark`` (see flight_recorder.fire_span)."""
-        from flink_tpu.observe import flight_recorder as flight
-
-        return flight.fire_span(watermark)
 
     # ----------------------------------------------------------- data plane
 
@@ -2177,3 +2192,497 @@ class MeshWindowJoinEngine(JoinEngineBase):
 
 #: pairs one shard's fire may hold: the match counts them in int32
 _PAIRS_LIMIT = 1 << 30
+
+
+class MeshRegularJoinEngine(JoinEngineCore):
+    """INNER equi-join of two streams with no window and no time bound —
+    SQL's regular join (``FROM a, b WHERE a.k = b.k AND ...``, reference:
+    StreamingJoinOperator with ``table.exec.state.ttl`` unset) — on one
+    device. Every row of both sides is held for the job's life: nothing
+    retires, and no row is dropped for being behind the watermark.
+
+    - **Index**: per side a key directory on the host (the native slot
+      index: key id -> a dense key number) with each key's chain head and
+      length; each held row's ``next`` word links it to the previous row
+      of its key. A batch's rows take the side's next free slots, so the
+      host work of a batch is in its own rows (a stable sort by key to
+      link them): no held row is read, sorted or merged.
+    - **Probe** (``join.probe``): a batch of one side looks its keys up
+      in the other side's directory and hands the heads and lengths of
+      their chains to one program (``kernels.build_regular_probe``; a
+      batch whose every key is held once walks nothing) that walks them,
+      evaluates the ``residual`` — comparisons between
+      columns of either side and literals — over every candidate and
+      compacts the pairs that pass. Only they leave the device. The
+      batch then joins its own side (``join.insert``): a pair is emitted
+      by whichever row arrives second.
+    - **Emission**: the probes dispatched since the last call are handed
+      out by ``on_watermark`` as one ``PendingFire``; the regular join
+      waits for no watermark, and its operator calls ``on_watermark``
+      once both inputs have brought a batch, at a watermark and at the
+      end, and harvests at once.
+    - **Columns**: a side holds the columns its pairs carry out
+      (``keep``, None: all) and those the residual reads, each as one or
+      two int32 words; its timestamp is the column ``ts_fields`` names or
+      a column of its own. The probe side's columns come from its batch
+      on the host.
+    - **Capacity**: ``capacity_per_shard`` rows for both sides together,
+      split in proportion to the rows each side brought before both had
+      brought any (those wait on the host: until both sides have rows no
+      pair exists); a side that fills doubles its plane.
+
+    One device only: the operator runs a wider mesh on the host operator
+    (counted, ``joins.operators.fallbacks``)."""
+
+    kind = "regular"
+
+    def __init__(self, suffixes: Tuple[str, str] = ("_l", "_r"),
+                 residual: Sequence[tuple] = (),
+                 keep: Sequence = (None, None),
+                 ts_fields: Sequence = (None, None), mesh=None,
+                 capacity_per_shard: int = 1 << 16,
+                 max_parallelism: int = 128) -> None:
+        super().__init__(mesh, 1, capacity_per_shard, max_parallelism,
+                         "device", suffixes)
+        if self.P != 1:
+            raise ValueError(
+                "the regular join runs on one device; the host "
+                "IntervalJoinOperator is the other path")
+        from flink_tpu.state.slot_table import make_slot_index
+
+        self.residual = tuple(residual)
+        self.keep = tuple(None if k is None else frozenset(k) for k in keep)
+        self.ts_fields = tuple(ts_fields)
+        self.device = self.mesh.devices.flat[0]
+        #: the side ``process_batch`` takes its batch into
+        self.input_side = 0
+        #: each side's input columns in batch order (the key id included,
+        #: the timestamp not), and its held ones: ``(name, dtype, first
+        #: word, words)``
+        self.names: List[Optional[List[str]]] = [None, None]
+        self.held: List[Optional[list]] = [None, None]
+        self.planes: List = [None, None]
+        self.rows = [0, 0]
+        self.keydir = [make_slot_index(1 << 16, track_namespaces=False)
+                       for _ in SIDE_NAMES]
+        self.head = [np.zeros(0, dtype=np.int32) for _ in SIDE_NAMES]
+        self.count = [np.zeros(0, dtype=np.int64) for _ in SIDE_NAMES]
+        #: each held row's dense key number (what an export needs)
+        self.row_key = [np.zeros(0, dtype=np.int32) for _ in SIDE_NAMES]
+        #: batches waiting on the host until both sides have brought rows
+        self._staged: List[List[RecordBatch]] = [[], []]
+        #: the column each side's timestamp is read from
+        self._ts_column: List[Optional[str]] = [None, None]
+        self._fires: List = []
+        self._insert_bucket = [0, 0]
+        self._probe_bucket = [0, 0]
+        self._out_bucket = [0, 0]
+
+    @property
+    def accs(self) -> tuple:
+        """Both sides' planes: the state on the device."""
+        return tuple(p for p in self.planes if p is not None)
+
+    def shard_resident_rows(self) -> List[int]:
+        """The rows held, both sides (one shard)."""
+        return [sum(self.rows) + sum(len(b) for side in self._staged
+                                     for b in side)]
+
+    # ------------------------------------------------------------ ingest
+
+    def process_batch(self, batch: RecordBatch) -> bool:
+        """Probe the other side's held rows with the batch, then hold it.
+        True where something was dispatched."""
+        side = self.input_side
+        if len(batch) == 0:
+            return False
+        with self._flight_ingest():
+            if self.planes[0] is None:
+                if not self._staged[1 - side]:
+                    self._staged[side].append(batch)
+                    return False
+                self._allocate(side, batch)
+                other = 1 - side
+                for b in self._staged[other]:
+                    self._insert(other, b)
+                self._staged = [[], []]
+            self._probe(side, batch)
+            self._insert(side, batch)
+        return True
+
+    def _schema(self, side: int, batch: RecordBatch) -> None:
+        """The side's held columns from its first batch."""
+        names = [n for n in batch.names() if n != TIMESTAMP_FIELD]
+        want = set(c[2] for conj in self.residual for c in conj[1:]
+                   if c[0] == "col" and c[1] == side)
+        keep = self.keep[side]
+        want |= set(names) if keep is None else set(keep)
+        want.discard(KEY_ID_FIELD)
+        ts = self.ts_fields[side]
+        if ts not in names:
+            ts = TIMESTAMP_FIELD
+        want.add(ts)
+        held, first = [], 0
+        for n in names + [TIMESTAMP_FIELD]:
+            if n not in want:
+                continue
+            dt = np.dtype(np.int64) if n == TIMESTAMP_FIELD else \
+                np.asarray(batch[n]).dtype
+            col = _column_of(n, dt, None)
+            held.append((n, dt.str, first, col.words))
+            first += col.words
+        missing = want - {h[0] for h in held}
+        if missing:
+            raise UnsupportedJoinColumn(
+                f"{SIDE_NAMES[side]} join input has no column "
+                f"{sorted(missing)} the join reads")
+        self.names[side] = names
+        self.held[side] = held
+        self._ts_column[side] = ts
+
+    def _check_residual(self) -> None:
+        """Every residual comparison over operands of one kind."""
+        kinds = {}
+        for side in range(2):
+            for n, dt, _, _ in self.held[side]:
+                kinds[(side, n)] = np.dtype(dt).kind
+        for op, *operands in self.residual:
+            seen = set()
+            for o in operands:
+                if o[0] == "lit":
+                    seen.add("f" if isinstance(o[1], float) else "i")
+                else:
+                    k = kinds[(o[1], o[2])]
+                    if k == "f" and np.dtype(dict(
+                            (h[0], h[1]) for h in self.held[o[1]])[o[2]]
+                            ).itemsize != 4:
+                        raise UnsupportedJoinColumn(
+                            f"residual column {o[2]!r} is a float64: the "
+                            "device compares float32 only")
+                    seen.add("f" if k == "f" else "i")
+            if len(seen) > 1:
+                raise UnsupportedJoinColumn(
+                    f"residual {op} compares an integer with a float")
+
+    def _allocate(self, side: int, batch: RecordBatch) -> None:
+        """Both sides' planes, once both have brought rows: the capacity
+        split in proportion to the rows each brought."""
+        import jax.numpy as jnp
+
+        other = 1 - side
+        first = {side: [batch], other: self._staged[other]}
+        for s in (0, 1):
+            self._schema(s, first[s][0])
+        self._check_residual()
+        rows = [sum(len(b) for b in first[s]) for s in (0, 1)]
+        for s in (0, 1):
+            share = self.capacity * rows[s] // max(sum(rows), 1)
+            cap = max(share, 4 * rows[s], 1024)
+            cap = -(-cap // 1024) * 1024
+            words = sum(h[3] for h in self.held[s]) + 1
+            self.planes[s] = jax.device_put(
+                jnp.zeros((words, cap), jnp.int32), self.device)
+
+    @staticmethod
+    def _words(columns, batch: RecordBatch, rows=slice(None)
+               ) -> List[np.ndarray]:
+        """The int32 words of ``columns`` (``(name, dtype, first word,
+        words)``) of the batch's ``rows``."""
+        out = []
+        for n, dt, _, words in columns:
+            v = batch.timestamps if n == TIMESTAMP_FIELD else batch[n]
+            out += _to_words(np.asarray(v)[rows],
+                             _Column(n, np.dtype(dt), words))
+        return out
+
+    def _insert(self, side: int, batch: RecordBatch) -> None:
+        from flink_tpu.joins.kernels import build_regular_insert
+        from flink_tpu.observe import flight_recorder as flight
+
+        with flight.span("join.insert") as span:
+            self._check_columns(side, batch)
+            n = len(batch)
+            kid = self.keydir[side].lookup_or_insert(
+                batch.key_ids, np.zeros(n, dtype=np.int64))
+            self._grow_keys(side, int(kid.max()) + 1)
+            base = self.rows[side]
+            b = self._insert_bucket[side] = fine_bucket(
+                n, self._insert_bucket[side])
+            if base + b > self.planes[side].shape[1]:
+                self._grow(side, base + b)
+            slots = np.arange(base, base + n, dtype=np.int32)
+            # link each row to the previous row of its key: in the batch
+            # where there is one, else the key's chain head
+            order = np.argsort(kid, kind="stable")
+            sk, so = kid[order], slots[order]
+            starts = np.r_[True, sk[1:] != sk[:-1]]
+            ends = np.r_[starts[1:], True]
+            prev = np.r_[np.int32(-1), so[:-1]]
+            prev[starts] = self.head[side][sk[starts]]
+            link = np.empty(n, dtype=np.int32)
+            link[order] = prev
+            self.head[side][sk[ends]] = so[ends]
+            self.count[side][sk[starts]] += np.diff(
+                np.r_[np.flatnonzero(starts), n])
+            if len(self.row_key[side]) < base + n:
+                grown = np.zeros(max(2 * len(self.row_key[side]), base + n),
+                                 dtype=np.int32)
+                grown[:base] = self.row_key[side][:base]
+                self.row_key[side] = grown
+            self.row_key[side][base:base + n] = kid
+            words = self._words(self.held[side], batch) + [link]
+            block = np.zeros((len(words), b), dtype=np.int32)
+            for w, col in enumerate(words):
+                block[w, :n] = col
+            with flight.span("device.dispatch"), \
+                    self._wd_section("join_ingest"):
+                self.planes[side] = build_regular_insert(len(words))(
+                    self.planes[side], jax.device_put(block, self.device),
+                    np.int32(base))
+            self.rows[side] = base + n
+            span.work = n
+            flight.instant("join.held_rows", shard=side, work=n)
+            flight.instant("join.held_bytes", shard=side,
+                           work=4 * n * len(words))
+
+    def _check_columns(self, side: int, batch: RecordBatch) -> None:
+        names = [n for n in batch.names() if n != TIMESTAMP_FIELD]
+        if set(names) != set(self.names[side]):
+            raise RuntimeError(
+                f"{SIDE_NAMES[side]} join input changed columns "
+                f"mid-stream: {sorted(self.names[side])} -> {sorted(names)}")
+
+    def _grow_keys(self, side: int, n: int) -> None:
+        if n <= len(self.head[side]):
+            return
+        size = max(n, 2 * len(self.head[side]), 1024)
+        head = np.full(size, -1, dtype=np.int32)
+        head[:len(self.head[side])] = self.head[side]
+        count = np.zeros(size, dtype=np.int64)
+        count[:len(self.count[side])] = self.count[side]
+        self.head[side], self.count[side] = head, count
+
+    def _grow(self, side: int, need: int) -> None:
+        """Double the side's plane (on the device) until ``need`` slots
+        fit: a shape the side's programs have not seen, so compiled anew."""
+        import jax.numpy as jnp
+
+        cap = self.planes[side].shape[1]
+        new = cap
+        while new < need:
+            new *= 2
+        self.planes[side] = jnp.pad(self.planes[side],
+                                    ((0, 0), (0, new - cap)))
+
+    # ------------------------------------------------------------- probe
+
+    def _probe(self, side: int, batch: RecordBatch) -> None:
+        from flink_tpu.joins.kernels import build_regular_probe
+        from flink_tpu.observe import flight_recorder as flight
+        from flink_tpu.runtime.pending import PendingFire
+
+        other = 1 - side
+        if not self.rows[other]:
+            return
+        with flight.span("join.probe") as span:
+            n = len(batch)
+            kid = self.keydir[other].lookup(batch.key_ids,
+                                            np.zeros(n, dtype=np.int64))
+            hit = kid >= 0
+            cnt = np.zeros(n, dtype=np.int64)
+            cnt[hit] = self.count[other][kid[hit]]
+            rows = np.flatnonzero(cnt)
+            if not len(rows):
+                return
+            cnt = cnt[rows]
+            total = int(cnt.sum())
+            if total >= 1 << 31:
+                raise RuntimeError(
+                    f"a batch of {n} rows has {total} candidate pairs, past "
+                    "the regular join's int32 count: a smaller micro-batch")
+            single = total == len(rows)
+            r = self._probe_bucket[side] = fine_bucket(
+                len(rows), self._probe_bucket[side])
+            t = r if single else fine_bucket(total, self._out_bucket[side])
+            if not single:
+                self._out_bucket[side] = t
+            start = np.zeros(r, dtype=np.int32)
+            start[:len(rows)] = self.head[other][kid[rows]]
+            counts = np.zeros(r, dtype=np.int32)
+            counts[:len(rows)] = cnt
+            stored, probe = self._residual_layout(side)
+            words = self._words(probe, batch, rows)
+            pw = np.zeros((max(len(words), 1), r), dtype=np.int32)
+            for w, col in enumerate(words):
+                pw[w, :len(rows)] = col
+            out_words, out_names = self._out_layout(other)
+            prog = build_regular_probe(stored, probe, self._spec(side),
+                                       out_words, single)
+            with flight.span("device.dispatch"), \
+                    self._wd_section("join_probe"):
+                arrays = prog(self.planes[other],
+                              jax.device_put(start, self.device),
+                              jax.device_put(counts, self.device),
+                              jax.device_put(pw, self.device),
+                              np.int32(int(cnt.max())), t)
+            span.work = total
+            self._fires.append(PendingFire(
+                arrays, lambda host, b=batch, rw=rows, s=side,
+                names=out_names: self._pairs(s, b, rw, names, host),
+                watchdog=self._watchdog))
+
+    def _spec(self, side: int) -> tuple:
+        """The residual with each column operand named by where the probe
+        of a ``side`` batch finds it."""
+        def where(o):
+            if o[0] == "lit":
+                return o
+            return ("col", "probe" if o[1] == side else "stored", o[2])
+
+        return tuple((op, where(a), where(b)) for op, a, b in self.residual)
+
+    def _residual_layout(self, side: int):
+        """``(stored columns, probe columns)`` the residual reads, for a
+        probe by a ``side`` batch: the stored side's at their words in
+        its plane, the probe side's at their words in ``probe_words``."""
+        other = 1 - side
+        refs = {(o[1], o[2]) for conj in self.residual for o in conj[1:]
+                if o[0] == "col"}
+        stored = tuple(h for h in self.held[other] if (other, h[0]) in refs)
+        probe, first = [], 0
+        for n, dt, _, w in self.held[side]:
+            if (side, n) in refs:
+                probe.append((n, dt, first, w))
+                first += w
+        return stored, tuple(probe)
+
+    def _out_layout(self, other: int):
+        """The stored side's plane rows a pair carries out, and per
+        column ``(name, dtype, its rows in the output)``: the columns its
+        pairs keep, and its timestamp."""
+        keep = self.keep[other]
+        ts = self._ts_column[other]
+        rows, names = [], []
+        # the timestamp first: the rows that carry the answers come last
+        for n, dt, first, w in sorted(self.held[other],
+                                      key=lambda h: h[0] != ts):
+            if n == ts or n != TIMESTAMP_FIELD and (keep is None
+                                                    or n in keep):
+                names.append((n, dt, tuple(range(len(rows),
+                                                 len(rows) + w))))
+                rows += range(first, first + w)
+        return tuple(rows), tuple(names)
+
+    def _pairs(self, side: int, batch: RecordBatch, rows: np.ndarray,
+               out_names, host) -> Optional[RecordBatch]:
+        """A probe's harvested pairs as the host join's rows: the probe
+        side's columns from its batch, the stored side's from the words."""
+        from flink_tpu.observe import flight_recorder as flight
+        from flink_tpu.runtime.join_operators import merged_names
+
+        n = int(host[0])
+        flight.instant("join.pairs", work=n)
+        if not n:
+            return None
+        at = rows[np.asarray(host[1])[:n]]
+        other = 1 - side
+        stored = {name: _from_words([np.asarray(host[2 + i])[:n]
+                                     for i in idx], np.dtype(dt))
+                  for name, dt, idx in out_names}
+        values = {side: {}, other: stored}
+        keep = self.keep[side]
+        for name in self.names[side]:
+            if name != KEY_ID_FIELD and (keep is None or name in keep):
+                values[side][name] = np.asarray(batch[name])[at]
+        cols = {}
+        for s, src, name in merged_names(self.names[0], self.names[1],
+                                         self.suffixes):
+            if src == KEY_ID_FIELD:
+                cols[name] = batch.key_ids[at]
+            elif src in values[s] and src != TIMESTAMP_FIELD \
+                    and (self.keep[s] is None or src in self.keep[s]):
+                cols[name] = values[s][src]
+        cols[TIMESTAMP_FIELD] = np.maximum(
+            batch.timestamps[at], stored[self._ts_column[other]])
+        return RecordBatch(cols)
+
+    def on_watermark(self, watermark: int) -> list:
+        """Every probe dispatched since the last call, as one
+        ``PendingFire`` (one harvest) whose rows are theirs in order: the
+        regular join emits whatever the watermark, when its operator
+        asks."""
+        from flink_tpu.runtime.pending import PendingFire
+
+        fires, self._fires = self._fires, []
+        if len(fires) < 2:
+            return fires
+        cuts = np.cumsum([0] + [len(f.arrays) for f in fires])
+
+        def build(host):
+            parts = [f.build(host[a:b])
+                     for f, a, b in zip(fires, cuts[:-1], cuts[1:])]
+            parts = [p for p in parts if p is not None and len(p)]
+            return RecordBatch.concat(parts) if parts else None
+
+        return [PendingFire([a for f in fires for a in f.arrays], build,
+                            watchdog=self._watchdog)]
+
+    # --------------------------------------------------------- snapshots
+
+    def export_rows(self) -> Tuple[list, list]:
+        """Every held row of each side as the host ``IntervalJoinOperator``
+        buffers them (one dict of columns per side that holds rows): the
+        key id, the held columns and the timestamp."""
+        out = ([], [])
+        for side in range(len(SIDE_NAMES)):
+            if self._staged[side]:
+                out[side].extend(dict(b.columns) for b in self._staged[side])
+            n = self.rows[side]
+            if not n:
+                continue
+            plane = np.asarray(self._harvest_get(
+                self.planes[side][:, :n], "snapshot_harvest"))
+            cols = {KEY_ID_FIELD: self.keydir[side].slot_key[
+                self.row_key[side][:n]].copy()}
+            for name, dt, first, w in self.held[side]:
+                v = _from_words(list(plane[first:first + w]), np.dtype(dt))
+                cols[name] = v
+            if TIMESTAMP_FIELD not in cols:
+                cols[TIMESTAMP_FIELD] = cols[self._ts_column[side]].astype(
+                    np.int64)
+            out[side].append(cols)
+        return out
+
+    def snapshot(self, mode: str = "full") -> Dict[str, object]:
+        """The host ``IntervalJoinOperator``'s snapshot: either path
+        restores the other's."""
+        left, right = self.export_rows()
+        return {"left": left, "right": right}
+
+    def restore(self, snap: Dict[str, object], key_group_filter=None
+                ) -> None:
+        from flink_tpu.runtime.join_operators import _filter_by_key_groups
+
+        for side, key in enumerate(("left", "right")):
+            for cols in snap.get(key, []):
+                cols = {k: np.asarray(v) for k, v in cols.items()}
+                if key_group_filter is not None:
+                    cols = _filter_by_key_groups(
+                        cols, key_group_filter, self.max_parallelism)
+                if not len(cols[KEY_ID_FIELD]):
+                    continue
+                batch = RecordBatch(cols)
+                if self.planes[0] is None:
+                    self._staged[side].append(batch)
+                else:
+                    self._insert(side, batch)
+        if self.planes[0] is None and all(self._staged):
+            # both sides restored rows: hold them without probing (their
+            # pairs were emitted before the snapshot)
+            first = self._staged[1].pop()
+            self._allocate(1, first)
+            for side in (0, 1):
+                for b in self._staged[side]:
+                    self._insert(side, b)
+            self._insert(1, first)
+            self._staged = [[], []]

@@ -57,6 +57,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from flink_tpu.parallel.mesh import KEY_AXIS, shard_map
@@ -370,3 +371,157 @@ def _build_window_match(mesh: Mesh, left_words: int, right_words: int,
           right_pages, right_fill)
 
     return match
+
+
+# ------------------------------------------------------------ regular join
+#
+# The regular join's side is ONE ``[words + 1, capacity]`` int32 plane on
+# one device: each held column's words (one or two by its width), then
+# the ``next`` row, which chains each row to the previous row of its key
+# (-1 ends a chain). A batch's rows take the next free slots, so the
+# insert is one ``dynamic_update_slice``; the host keeps each key's
+# chain head and length.
+
+
+def build_regular_insert(n_rows: int):
+    """``plane[:, base:base + B] = block``: a batch's words and chain
+    links into the side's next free slots (lanes past the batch's rows
+    write slots no row holds yet)."""
+    return PROGRAM_CACHE.get_or_build(
+        "regular-join-insert", int(n_rows),
+        lambda: _build_regular_insert())
+
+
+def _build_regular_insert():
+    @partial(jax.jit, donate_argnums=(0,))
+    def regular_join_insert(plane, block, base):
+        return jax.lax.dynamic_update_slice(
+            plane, block, (jnp.int32(0), base))
+
+    return regular_join_insert
+
+
+def build_regular_probe(stored, probe, residual, out_words, single):
+    """The regular join's probe of a batch against the other side's held
+    rows. ``stored`` / ``probe``: ``(name, dtype, first word, words)`` of
+    the columns the residual reads on each side (the stored side's words
+    are rows of its plane, the probe side's rows of ``probe_words``);
+    ``residual``: conjuncts ``(op, lhs, rhs)`` over ``("col", "stored" |
+    "probe", name)`` and ``("lit", value)`` operands; ``out_words``: the
+    stored plane's rows the pairs carry out; ``single``: every probe row
+    has exactly one candidate (a key held once), so lane ``i`` is row
+    ``i`` and nothing is walked.
+
+    For the ``R`` probe rows that have a chain on the other side (its
+    head ``start`` and length ``cnt``), each row's lanes follow from
+    ``cnt``'s prefix sum; a lane walks its row's chain to its candidate,
+    a link per step (``steps`` is the longest chain). One gather reads
+    every word of every candidate — a random read costs about the same
+    for one word as for all of a slot's, and the plane's layout keeps a
+    slot's words in one column — the residual is evaluated over the
+    lanes, and one scatter of lane numbers and one gather compact the
+    lanes that pass to the front. Returns the pairs, the probe row of
+    each and the stored side's ``out_words`` of each (``out_rows`` lanes;
+    those past the pairs hold anything)."""
+    key = (tuple(stored), tuple(probe), tuple(residual), tuple(out_words),
+           bool(single))
+    return PROGRAM_CACHE.get_or_build(
+        "regular-join-probe", key,
+        lambda: _build_regular_probe(tuple(stored), tuple(probe),
+                                     tuple(residual), tuple(out_words),
+                                     bool(single)))
+
+
+_SIGN32 = -(1 << 31)
+
+
+def _words64(v: int):
+    """The signed high and low int32 words of a 64-bit integer."""
+    v &= (1 << 64) - 1
+    hi, lo = v >> 32, v & 0xFFFFFFFF
+    return hi - (1 << 32) * (hi >> 31), lo - (1 << 32) * (lo >> 31)
+
+
+def _operand(ref, cols):
+    """A residual operand as a comparable tuple: ``(high word, low word
+    flipped)`` for an integer (signed 64-bit order), ``(value,)`` for a
+    float32."""
+    if ref[0] == "lit":
+        v = ref[1]
+        if isinstance(v, float):
+            return (jnp.float32(v),)
+        hi, lo = _words64(int(v))
+        return (jnp.int32(hi), jnp.int32(lo ^ _SIGN32))
+    _, where, name = ref
+    dt, words = cols[(where, name)]
+    if dt.kind == "f":
+        return (jax.lax.bitcast_convert_type(words[0], jnp.float32),)
+    if len(words) == 2:
+        lo, hi = words
+    elif dt.kind == "u":
+        lo, hi = words[0], jnp.zeros_like(words[0])
+    else:
+        lo, hi = words[0], jnp.right_shift(words[0], 31)
+    return (hi, jnp.bitwise_xor(lo, jnp.int32(_SIGN32)))
+
+
+def _compare(op, a, b):
+    if len(a) == 1:
+        x, y = a[0], b[0]
+        return {"<": x < y, "<=": x <= y, ">": x > y, ">=": x >= y,
+                "=": x == y, "<>": x != y}[op]
+    (ah, al), (bh, bl) = a, b
+    eq = (ah == bh) & (al == bl)
+    lt = (ah < bh) | ((ah == bh) & (al < bl))
+    return {"<": lt, "<=": lt | eq, ">": ~(lt | eq), ">=": ~lt,
+            "=": eq, "<>": ~eq}[op]
+
+
+def _build_regular_probe(stored, probe, residual, out_words, single):
+    @partial(jax.jit, static_argnums=(5,))
+    def regular_join_probe(plane, start, cnt, probe_words, steps, out_rows):
+        r = start.shape[0]
+        lane = jnp.arange(out_rows, dtype=jnp.int32)
+        if single:
+            row, cand = lane, start
+        else:
+            link_row = plane.shape[0] - 1
+            off = jnp.cumsum(cnt) - cnt
+            # a row's first lane holds the row (the last of the rows that
+            # share it, which are the ones before it with no candidate)
+            first = jnp.full((out_rows,), -1, jnp.int32).at[off].max(
+                jnp.arange(r, dtype=jnp.int32), mode="drop")
+            row = jnp.maximum(jax.lax.cummax(first), 0)
+            hop = lane - off[row]
+
+            def link(i, ptr):
+                return jnp.where(
+                    hop > i, plane.at[link_row, ptr].get(mode="clip"), ptr)
+
+            cand = jax.lax.fori_loop(0, steps - 1, link, start[row])
+        words = plane.at[:, cand].get(mode="clip")
+        keep = lane < jnp.sum(cnt)
+        if residual:
+            mine = probe_words if single else \
+                probe_words.at[:, row].get(mode="clip")
+            cols = {}
+            for name, dt, first, n in stored:
+                cols[("stored", name)] = (np.dtype(dt), [
+                    words[first + j] for j in range(n)])
+            for name, dt, first, n in probe:
+                cols[("probe", name)] = (np.dtype(dt), [
+                    mine[first + j] for j in range(n)])
+            for op, lhs, rhs in residual:
+                keep &= _compare(op, _operand(lhs, cols),
+                                 _operand(rhs, cols))
+        at = jnp.where(keep, jnp.cumsum(keep.astype(jnp.int32)) - 1,
+                       out_rows)
+        src = jnp.zeros((out_rows,), jnp.int32).at[at].set(lane, mode="drop")
+        picked = jnp.concatenate(
+            [row[None]] + ([words[jnp.asarray(out_words, jnp.int32)]]
+                           if out_words else []))
+        picked = picked.at[:, src].get(mode="clip")
+        return (jnp.sum(keep.astype(jnp.int32)),) + tuple(
+            picked[j] for j in range(picked.shape[0]))
+
+    return regular_join_probe

@@ -23,29 +23,31 @@ import numpy as np
 from flink_tpu.core.records import RecordBatch
 from flink_tpu.joins.engine import (
     MeshIntervalJoinEngine,
+    MeshRegularJoinEngine,
     MeshTemporalJoinEngine,
     MeshWindowJoinEngine,
     UnsupportedJoinColumn,
 )
 from flink_tpu.runtime.operators import AsyncFireOperator, Operator
 
-#: window joins that asked for the device and run on the host operator
+#: window and regular joins that asked for the device and run on the
+#: host operator
 _fallbacks = 0
 
 
 def fallbacks() -> int:
-    """Window joins of this process that fell back to the host
-    operator: a shape the device engine cannot run."""
+    """Window and regular joins of this process that fell back to the
+    host operator: a shape the device engine cannot run."""
     return _fallbacks
 
 
-def fall_back(reason: str) -> None:
-    """Say loudly that a window join leaves the device, and count it."""
+def fall_back(reason: str, what: str = "window join") -> None:
+    """Say loudly that a join leaves the device, and count it."""
     import warnings
 
     global _fallbacks
     _fallbacks += 1
-    warnings.warn(f"window join on the host operator: {reason}",
+    warnings.warn(f"{what} on the host operator: {reason}",
                   RuntimeWarning, stacklevel=3)
 
 
@@ -325,3 +327,121 @@ class DeviceWindowJoinOperator(AsyncFireOperator):
     def dispose(self) -> None:
         self._pending.clear()
         self._fences.clear()
+
+
+class DeviceRegularJoinOperator(Operator):
+    """SQL's regular INNER join on the device
+    (:class:`~flink_tpu.joins.engine.MeshRegularJoinEngine`), with the
+    stream contract of the host ``IntervalJoinOperator`` without bounds
+    plus its residual filter: a pair is found when its second row
+    arrives, every row is held for the job's life. ``windower`` is the
+    engine; once both inputs have brought a batch since the last time, at
+    a watermark and at the end, the operator takes the engine's probes
+    (``on_watermark``) and harvests them: the pairs of a source batch
+    that feeds both sides leave together, before the next batch. A shape the engine cannot run — a mesh wider than one device, a
+    column it cannot carry, a residual it cannot compare — moves the join
+    to ``host_factory()``'s operator, state and all, whose pairs
+    ``residual_filter`` (a batch -> row mask) then filters (counted:
+    :func:`fallbacks`)."""
+
+    name = "device_regular_join"
+
+    def __init__(self, suffixes=("_l", "_r"), residual=(),
+                 keep=(None, None), ts_fields=(None, None),
+                 capacity: int = 1 << 16, host_factory=None,
+                 residual_filter=None) -> None:
+        self.suffixes = tuple(suffixes)
+        self.residual = tuple(residual)
+        self.keep = tuple(keep)
+        self.ts_fields = tuple(ts_fields)
+        self._capacity = int(capacity)
+        self._host_factory = host_factory
+        self._residual_filter = residual_filter
+        self.windower: Optional[MeshRegularJoinEngine] = None
+        #: the host operator, once the join has left the device
+        self.host = None
+        self._ctx = None
+        self._watermark = 0
+        #: the inputs that brought a batch since the last emission
+        self._brought = set()
+
+    def open(self, ctx) -> None:
+        self._ctx = ctx
+        if max(getattr(ctx, "parallelism", 1), 1) > 1:
+            self._to_host("the regular join runs on one device")
+            return
+        from flink_tpu.parallel.mesh import make_mesh
+
+        self.windower = MeshRegularJoinEngine(
+            self.suffixes, residual=self.residual,
+            keep=self.keep, ts_fields=self.ts_fields,
+            mesh=getattr(ctx, "mesh", None) or make_mesh(1),
+            capacity_per_shard=self._capacity,
+            max_parallelism=getattr(ctx, "max_parallelism", 128))
+        wd = getattr(ctx, "watchdog", None)
+        if wd is not None:
+            self.windower.attach_watchdog(wd)
+
+    def process_batch(self, batch, input_index=0) -> List[RecordBatch]:
+        if self.host is None:
+            self.windower.input_side = input_index
+            try:
+                self.windower.process_batch(batch)
+                self._brought.add(input_index)
+                return self._emit() if len(self._brought) == 2 else []
+            except UnsupportedJoinColumn as e:
+                self._to_host(str(e))
+        return self._filtered(self.host.process_batch(batch, input_index))
+
+    def _emit(self) -> List[RecordBatch]:
+        self._brought.clear()
+        out = []
+        for fire in self.windower.on_watermark(self._watermark):
+            rows = fire.harvest()
+            if rows is not None and len(rows):
+                out.append(rows)
+        return out
+
+    def _filtered(self, outs) -> List[RecordBatch]:
+        if self._residual_filter is None:
+            return outs
+        out = []
+        for b in outs:
+            b = b.filter(np.asarray(self._residual_filter(b), dtype=bool))
+            if len(b):
+                out.append(b)
+        return out
+
+    def process_watermark(self, watermark, input_index=0
+                          ) -> List[RecordBatch]:
+        if self.host is not None:
+            return self._filtered(
+                self.host.process_watermark(watermark, input_index))
+        self._watermark = int(watermark)
+        return self._emit()
+
+    def _to_host(self, reason: str) -> None:
+        """The join leaves the device: its held rows restore the host
+        operator, which takes over from here."""
+        host = self._host_factory()
+        host.open(self._ctx)
+        if self.windower is not None:
+            host.restore_state(self.windower.snapshot())
+        self.host, self.windower = host, None
+        fall_back(reason, "regular join")
+
+    def snapshot_state(self):
+        if self.host is not None:
+            return self.host.snapshot_state()
+        return self.windower.snapshot()
+
+    def restore_state(self, state, key_group_filter=None):
+        if self.host is not None:
+            return self.host.restore_state(state, key_group_filter)
+        return self.windower.restore(state,
+                                     key_group_filter=key_group_filter)
+
+    def close(self) -> List[RecordBatch]:
+        if self.host is not None:
+            return self._filtered(self.host.close())
+        return self._emit()

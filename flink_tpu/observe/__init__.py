@@ -92,12 +92,40 @@ KNOWN_SPAN_KINDS = (
                            # output rows sized, at least the pairs)
     "join.rows",           # rows of both sides in a fired window (instant
                            # inside join.match; work: rows)
-    "join.pairs",          # pairs a window join's fire emitted (instant
-                           # inside fire.harvest, where the count first
-                           # reaches the host; work: pairs)
+    "join.pairs",          # pairs a window join's fire emitted, or the
+                           # pairs a regular join's probe found that its
+                           # residual kept (instant inside fire.harvest,
+                           # where the count first reaches the host;
+                           # work: pairs)
+    "join.probe",          # the regular join's probe of a batch against
+                           # the other side's held rows: the batch's
+                           # keys looked up on the host, the probe
+                           # program dispatched (inside batch.ingest;
+                           # work: candidate pairs, before the residual)
+    "join.insert",         # the regular join's insert of a batch into
+                           # its side: the batch's keys, their chains and
+                           # the put of its rows (inside batch.ingest;
+                           # work: rows)
+    "join.held_rows",      # rows a regular join's batch added to the
+                           # rows it holds for the job's life (instant
+                           # inside join.insert; work: rows; shard: the
+                           # side)
+    "join.held_bytes",     # device bytes those rows hold (instant inside
+                           # join.insert; work: bytes; shard: the side)
     "join.retire",         # fired slices' pages back to the free lists,
                            # once the last fire reading them is harvested
                            # (work: rows dropped)
+    "agg.fold",            # an unwindowed GROUP BY's batch folded into
+                           # its table and the touched keys' new values
+                           # read back (work: rows)
+    "agg.retract",         # retraction rows (UPDATE_BEFORE / DELETE) a
+                           # GROUP BY folded with their negative sign
+                           # (instant inside agg.fold; work: rows)
+    "agg.emit",            # a GROUP BY's changelog built on the host from
+                           # the touched keys' new and last emitted values
+                           # (work: rows)
+    "agg.changelog",       # rows of that changelog (instant after
+                           # agg.emit; work: rows)
     "sink.write",          # one batch handed to the sink (work: rows)
     "op.process",          # executor: one operator's process_batch
     "op.watermark",        # executor: one operator's process_watermark

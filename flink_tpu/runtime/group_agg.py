@@ -22,7 +22,23 @@ row. Retraction INPUT (a second-level aggregate over an updating stream) is
 consumed by folding each row's contribution with its changelog sign in one
 signed scatter; this requires every accumulator leaf to be additive
 (``AggregateFunction.retractable`` — COUNT/SUM/AVG yes, MAX/MIN no, like
-the reference's retractable agg function family).
+the reference's retractable agg function family). As the reference's
+GroupAggFunction does without state retention, a key whose new value
+equals the one it last emitted emits nothing.
+
+An aggregate whose accumulator takes its input's type (SQL's ``MAX(x)``,
+``AVG(x)``: ``dtype=None``) is bound to the first batch's column types,
+and the table is made then. An exact 64-bit one
+(:class:`~flink_tpu.windowing.aggregates.Int64Aggregate`: BIGINT MAX /
+MIN / AVG) folds per-slot partials the host sums, maxes or mins in int64
+through one device program (``stateplane.families.wide_fold``), which
+returns the parts' new words: the host finishes them (``finish_host``).
+A narrow leaf the device would carry at half its width is refused.
+
+Flight recorder: ``agg.fold`` (the batch into the table and the touched
+keys' new values back; work: rows), ``agg.retract`` (retraction rows it
+folded; work: rows), ``agg.emit`` (the changelog built on the host) and
+``agg.changelog`` (rows emitted; work: rows).
 """
 
 from __future__ import annotations
@@ -42,9 +58,13 @@ from flink_tpu.core.records import (
     RecordBatch,
     rowkind_signs,
 )
+from flink_tpu.observe import flight_recorder as flight
 from flink_tpu.runtime.operators import Operator
 from flink_tpu.state.slot_table import SlotTable
-from flink_tpu.windowing.aggregates import AggregateFunction
+from flink_tpu.windowing.aggregates import (
+    AggregateFunction,
+    device_truncates,
+)
 
 _GLOBAL_NS = 0
 
@@ -56,9 +76,15 @@ class GroupAggOperator(Operator):
                  capacity: int = 1 << 16,
                  emit_on_watermark_only: bool = False,
                  generate_update_before: bool = True,
-                 ttl_ms: Optional[int] = None, clock=None):
+                 ttl_ms: Optional[int] = None, clock=None,
+                 key_columns=()):
         self.agg = agg
         self.key_field = key_field
+        #: the columns a composite key was combined from (``key_field``
+        #: then holds their hash): each key's values are kept per slot,
+        #: checked against every row that brings the key, and emitted
+        self.key_columns = tuple(key_columns)
+        self._key_cols: Dict[str, np.ndarray] = {}
         self.capacity = capacity
         #: True = suppress per-batch emission, emit one deduped changelog
         #: per watermark advance (MiniBatch-style emission)
@@ -78,6 +104,10 @@ class GroupAggOperator(Operator):
         self._clock = clock or default_clock
         self._sweep_gate = SweepGate(ttl_ms) if ttl_ms else None
         self.table: Optional[SlotTable] = None
+        self._ctx = None
+        #: the input columns' types the aggregate was bound to
+        self._input_dtypes: Dict[str, str] = {}
+        self._fold_bucket = 0
         self._key_values: Dict[int, Any] = {}
         self._keys_hashed = False
         self._dirty: set = set()
@@ -90,10 +120,30 @@ class GroupAggOperator(Operator):
         self._last_update = np.zeros(0, dtype=np.int64)
 
     def open(self, ctx):
-        mm = getattr(ctx, "memory_manager", None)
+        self._ctx = ctx
+        if self.agg.leaves is not None:
+            self._make_table()
+
+    def _bind(self, dtypes: Dict[str, np.dtype]) -> None:
+        """The aggregate bound to the input's column types, and its
+        table (once, at the first batch or restore)."""
+        self._input_dtypes = {n: np.dtype(d).str for n, d in dtypes.items()}
+        self.agg = self.agg.bind(dtypes)
+        self._make_table()
+
+    def _make_table(self) -> None:
+        if not self.agg.wide:
+            for leaf in self.agg.leaves:
+                if device_truncates(leaf.dtype):
+                    raise TypeError(
+                        f"{type(self.agg).__name__} leaf {leaf.name!r} is "
+                        f"{leaf.dtype}: the device (JAX without x64) "
+                        "would carry it at half its width; an exact "
+                        "64-bit aggregate is Int64Aggregate")
+        mm = getattr(self._ctx, "memory_manager", None)
         self.table = SlotTable(
             self.agg, capacity=self.capacity,
-            max_parallelism=ctx.max_parallelism,
+            max_parallelism=getattr(self._ctx, "max_parallelism", 128),
             memory=(mm, f"{self.name}#{id(self):x}") if mm else None)
 
     def dispose(self):
@@ -115,10 +165,29 @@ class GroupAggOperator(Operator):
         stamps = np.full(size, -1, dtype=np.int64)
         stamps[: len(self._last_update)] = self._last_update
         self._last_update = stamps
-        for name, arr in self._last_emitted.items():
-            g = np.zeros(size, dtype=arr.dtype)
-            g[: len(arr)] = arr
-            self._last_emitted[name] = g
+        for store in (self._last_emitted, self._key_cols):
+            for name, arr in store.items():
+                g = np.zeros(size, dtype=arr.dtype)
+                g[: len(arr)] = arr
+                store[name] = g
+
+    def _carry_keys(self, slots: np.ndarray, batch: RecordBatch) -> None:
+        """Each new key's column values kept at its slot; every row's
+        checked against its key's (two keys whose hash collides would
+        share a slot: that is refused, never folded together)."""
+        new = self._row_counts[slots] == 0
+        new &= ~self._emitted_mask[slots]
+        for name in self.key_columns:
+            v = np.asarray(batch[name])
+            store = self._key_cols.get(name)
+            if store is None:
+                store = self._key_cols[name] = np.zeros(
+                    len(self._row_counts), dtype=v.dtype)
+            store[slots[new]] = v[new]
+            if not np.array_equal(store[slots], v):
+                raise RuntimeError(
+                    f"two GROUP BY keys of ({', '.join(self.key_columns)}) "
+                    "hash to one key id: their groups would fold together")
 
     # --------------------------------------------------------------- TTL
 
@@ -155,46 +224,145 @@ class GroupAggOperator(Operator):
                       ) -> List[RecordBatch]:
         if len(batch) == 0:
             return []
+        if self.table is None:
+            self._bind({n: np.asarray(batch[n]).dtype
+                        for n in batch.names()})
         if batch.has_timestamps:
             self._max_ts = max(self._max_ts, int(batch.timestamps.max()))
-        if self.key_field in batch.columns:
-            keys = batch[self.key_field]
-            if keys.dtype.kind not in "iu":
-                self._keys_hashed = True
-                kid = batch.key_ids
-                uniq, first = np.unique(kid, return_index=True)
-                for i, j in zip(uniq.tolist(), first.tolist()):
-                    self._key_values.setdefault(i, keys[j])
-        namespaces = np.full(len(batch), _GLOBAL_NS, dtype=np.int64)
-        slots = self.table.lookup_or_insert(batch.key_ids, namespaces)
-        kinds = batch.row_kinds
-        signs = None if kinds is None else rowkind_signs(np.asarray(kinds))
-        if signs is None or not (signs < 0).any():
-            # append-only input (possibly an all-INSERT changelog) — the
-            # plain scatter path works for every aggregate, incl. MAX/MIN
-            self.table.scatter(slots, self.agg.map_input(batch))
-            signs = None
-        else:
-            if not self.agg.retractable:
-                raise ValueError(
-                    "aggregate over an updating (retraction) input requires "
-                    "retractable accumulators (COUNT/SUM/AVG); "
-                    f"{type(self.agg).__name__} has MAX/MIN-style leaves "
-                    "(reference: GroupAggFunction requires retract() for "
-                    "update streams)")
-            self.table.scatter_signed(
-                slots, self.agg.map_input_signed(batch, signs))
-        self._ensure_host_capacity(int(slots.max()) + 1)
-        np.add.at(self._row_counts, slots,
-                  1 if signs is None else signs.astype(np.int64))
+        with flight.span("agg.fold") as fold:
+            if self.key_field in batch.columns:
+                keys = batch[self.key_field]
+                if keys.dtype.kind not in "iu":
+                    self._keys_hashed = True
+                    kid = batch.key_ids
+                    uniq, first = np.unique(kid, return_index=True)
+                    for i, j in zip(uniq.tolist(), first.tolist()):
+                        self._key_values.setdefault(i, keys[j])
+            namespaces = np.full(len(batch), _GLOBAL_NS, dtype=np.int64)
+            slots = self.table.lookup_or_insert(batch.key_ids, namespaces)
+            self._ensure_host_capacity(int(slots.max()) + 1)
+            if self.key_columns:
+                self._carry_keys(slots, batch)
+            kinds = batch.row_kinds
+            signs = None if kinds is None else rowkind_signs(
+                np.asarray(kinds))
+            if signs is not None and not (signs < 0).any():
+                # an all-INSERT changelog folds as append-only input
+                signs = None
+            if signs is not None:
+                if not self.agg.retractable:
+                    raise ValueError(
+                        "aggregate over an updating (retraction) input "
+                        "requires retractable accumulators (COUNT/SUM/AVG); "
+                        f"{type(self.agg).__name__} has MAX/MIN-style "
+                        "leaves (reference: GroupAggFunction requires "
+                        "retract() for update streams)")
+                flight.instant("agg.retract", work=int((signs < 0).sum()))
+            emit = not self.emit_on_watermark_only
+            if self.agg.wide:
+                uniq, results = self._fold_wide(slots, batch, signs)
+            else:
+                if signs is None:
+                    # append-only input: the plain scatter path works for
+                    # every aggregate, incl. MAX/MIN
+                    self.table.scatter(slots, self.agg.map_input(batch))
+                else:
+                    self.table.scatter_signed(
+                        slots, self.agg.map_input_signed(batch, signs))
+                uniq = np.unique(slots)
+                results = self._values(uniq) if emit else None
+            np.add.at(self._row_counts, slots,
+                      1 if signs is None else signs.astype(np.int64))
+            fold.work = len(batch)
         if self.ttl_ms:
             self._last_update[slots] = self._clock()
             self._maybe_sweep_ttl()
-        if self.emit_on_watermark_only:
-            self._dirty.update(np.unique(slots).tolist())
+        if not emit:
+            self._dirty.update(uniq.tolist())
             return []
-        out = self._emit_slots(np.unique(slots))
+        out = self._emit_slots(uniq, results)
         return [out] if out is not None else []
+
+    def _fold_wide(self, slots: np.ndarray, batch: RecordBatch, signs
+                   ) -> tuple:
+        """The exact 64-bit fold: per distinct slot, each part's partial
+        summed, maxed or mined on the host in int64, combined with the
+        table's words by one device program, whose new words come back
+        (one harvest) and are finished on the host. Returns the distinct
+        slots and their result columns."""
+        from flink_tpu.ops.segment_ops import sticky_bucket
+        from flink_tpu.runtime.pending import PendingFire
+        from flink_tpu.stateplane.families import wide_fold
+
+        order = np.argsort(slots, kind="stable")
+        ordered = slots[order]
+        starts = np.flatnonzero(np.r_[True, ordered[1:] != ordered[:-1]])
+        uniq = ordered[starts]
+        layout = tuple((r, dt) for _, r, dt in self.agg.parts)
+        size = sticky_bucket(len(uniq), self._fold_bucket)
+        self._fold_bucket = size
+        n = len(uniq)
+        pad_slots = np.full(size, self.table.capacity, dtype=np.int32)
+        pad_slots[:n] = uniq
+        fresh = np.zeros(size, dtype=bool)
+        fresh[:n] = self._row_counts[uniq] == 0
+        words = []
+        for (r, dt), v in zip(layout, self.agg.part_values(batch)):
+            dt = np.dtype(dt)
+            wide = dt.kind in "iu" and dt.itemsize == 8
+            v = np.asarray(v, dtype=np.int64 if wide or dt.kind in "iu"
+                           else np.float64)
+            if signs is not None:
+                v = v * signs.astype(v.dtype)
+            fn = {"sum": np.add, "max": np.maximum, "min": np.minimum}[r]
+            part = np.zeros(size, dtype=np.int64 if wide else dt)
+            part[:n] = fn.reduceat(v[order], starts)
+            if wide:
+                w = part.view(np.int32).reshape(-1, 2)
+                words += [np.ascontiguousarray(w[:, 0]),
+                          np.ascontiguousarray(w[:, 1])]
+            else:
+                words.append(part)
+        with flight.span("device.dispatch"):
+            self.table.accs, out = wide_fold(layout)(
+                self.table.accs, pad_slots, fresh, tuple(words))
+        self.table.mark_dirty(uniq)
+        fire = PendingFire(list(out), lambda host: self.agg.finish_host(
+            self._parts_of_words(host, n)))
+        return uniq, fire.harvest()
+
+    def _parts_of_words(self, host, n: int) -> List[np.ndarray]:
+        """Each part's values from its words: a 64-bit part's low and
+        high int32 words joined into int64."""
+        parts, at = [], 0
+        for _, _, dt in self.agg.parts:
+            if np.dtype(dt).itemsize == 8:
+                both = np.empty((n, 2), dtype=np.int32)
+                both[:, 0] = np.asarray(host[at])[:n]
+                both[:, 1] = np.asarray(host[at + 1])[:n]
+                parts.append(both.view(np.int64).reshape(-1))
+                at += 2
+            else:
+                parts.append(np.asarray(host[at])[:n])
+                at += 1
+        return parts
+
+    def _values(self, slots: np.ndarray) -> Dict[str, np.ndarray]:
+        """The finished result columns of ``slots``, read from the
+        device."""
+        if not self.agg.wide:
+            return self.table.fire(slots[:, None].astype(np.int32))
+        import jax
+
+        from flink_tpu.ops.segment_ops import sticky_bucket
+
+        size = sticky_bucket(len(slots), self._fold_bucket)
+        self._fold_bucket = size
+        pad = np.zeros(size, dtype=np.int32)
+        pad[:len(slots)] = slots
+        host = jax.device_get(self.agg._gather_jit(self.table.accs, pad))
+        return self.agg.finish_host(self._parts_of_words(
+            [np.asarray(h) for h in host], len(slots)))
 
     def process_watermark(self, watermark, input_index=0):
         if not self.emit_on_watermark_only or not self._dirty:
@@ -202,16 +370,26 @@ class GroupAggOperator(Operator):
             return []
         slots = np.fromiter(self._dirty, dtype=np.int64)
         self._dirty.clear()
-        out = self._emit_slots(slots)
+        out = self._emit_slots(slots, self._values(slots))
         self._maybe_sweep_ttl()
         return [out] if out is not None else []
 
     # --------------------------------------------------------------- emission
 
-    def _emit_slots(self, slots: np.ndarray) -> Optional[RecordBatch]:
+    def _emit_slots(self, slots: np.ndarray,
+                    results: Dict[str, np.ndarray]) -> Optional[RecordBatch]:
+        """The changelog of ``slots`` whose new values are ``results``."""
         if len(slots) == 0:
             return None
-        results = self.table.fire(slots[:, None].astype(np.int32))
+        with flight.span("agg.emit") as span:
+            out = self._changelog(slots, results)
+            span.work = 0 if out is None else len(out)
+        if out is not None:
+            flight.instant("agg.changelog", work=len(out))
+        return out
+
+    def _changelog(self, slots: np.ndarray,
+                   results: Dict[str, np.ndarray]) -> Optional[RecordBatch]:
         self._ensure_host_capacity(int(slots.max()) + 1)
         counts = self._row_counts[slots]
         live = counts > 0
@@ -239,6 +417,14 @@ class GroupAggOperator(Operator):
             })
 
         upd = live & was_emitted
+        if not self.ttl_ms:
+            # an unchanged value emits nothing (the reference's
+            # GroupAggFunction without state retention)
+            changed = np.zeros(len(slots), dtype=bool)
+            for name in results:
+                changed |= (self._last_emitted[name][slots]
+                            != np.asarray(results[name]))
+            upd &= changed
         if self.generate_update_before:
             _segment(upd, ROWKIND_UPDATE_BEFORE, from_prev=True)
         _segment(~live & was_emitted, ROWKIND_DELETE, from_prev=True)
@@ -266,6 +452,8 @@ class GroupAggOperator(Operator):
             TIMESTAMP_FIELD: np.full(len(all_slots), self._max_ts,
                                      dtype=np.int64),
         }
+        for name, store in self._key_cols.items():
+            cols[name] = store[all_slots]
         for name in segments[0]:
             cols[name] = np.concatenate([s[name] for s in segments])
         return RecordBatch(cols)
@@ -293,6 +481,7 @@ class GroupAggOperator(Operator):
             "dirty": dirty,
             "last": {n: a[interesting]
                      for n, a in self._last_emitted.items()},
+            "keys": {n: a[interesting] for n, a in self._key_cols.items()},
         }
         if self.ttl_ms:
             # stamps travel logically so restore resumes each key's
@@ -300,6 +489,7 @@ class GroupAggOperator(Operator):
             # original timestamps)
             cl["ttl_last_update"] = self._last_update[interesting]
         return {
+            "input_dtypes": dict(self._input_dtypes),
             "key_values": dict(self._key_values),
             "keys_hashed": self._keys_hashed,
             "max_ts": self._max_ts,
@@ -307,15 +497,21 @@ class GroupAggOperator(Operator):
         }
 
     def snapshot_state(self):
+        if self.table is None:
+            return {"table": None}     # no batch yet: nothing bound
         return {"table": self.table.snapshot(), **self._host_state()}
 
     def snapshot_state_delta(self):
         """Incremental: dirty rows + tombstones only (see
         SlotTable.snapshot_delta)."""
+        if self.table is None:
+            return {"table": None}
         return {"table": self.table.snapshot_delta(), **self._host_state()}
 
     def snapshot_state_savepoint(self):
         """Full state without resetting the incremental base."""
+        if self.table is None:
+            return {"table": None}
         return {"table": self.table.snapshot(reset_dirty=False),
                 **self._host_state()}
 
@@ -327,6 +523,11 @@ class GroupAggOperator(Operator):
         return self.table.query(key_id, namespace)
 
     def restore_state(self, state, key_group_filter=None):
+        if state.get("table") is None:
+            return
+        if self.table is None:
+            self._bind({n: np.dtype(d) for n, d in
+                        state.get("input_dtypes", {}).items()})
         self.table.restore(state["table"],
                            key_group_filter=key_group_filter)
         self._key_values = dict(state.get("key_values", {}))
@@ -373,8 +574,11 @@ class GroupAggOperator(Operator):
                 key_ids[keep], counts[keep], emitted[keep], dirty[keep])
             cl_last = {n: np.asarray(a)[keep]
                        for n, a in cl.get("last", {}).items()}
+            cl_keys = {n: np.asarray(a)[keep]
+                       for n, a in cl.get("keys", {}).items()}
         else:
             cl_last = {n: np.asarray(a) for n, a in cl.get("last", {}).items()}
+            cl_keys = {n: np.asarray(a) for n, a in cl.get("keys", {}).items()}
         if len(key_ids) == 0:
             return
         # re-key the logical changelog onto this instance's slot layout
@@ -389,7 +593,9 @@ class GroupAggOperator(Operator):
             if key_group_filter is not None:
                 stamps = stamps[keep]
             self._last_update[slots] = stamps
-        for n, a in cl_last.items():
-            arr = np.zeros(len(self._row_counts), dtype=a.dtype)
-            arr[slots] = a
-            self._last_emitted[n] = arr
+        for store, saved in ((self._last_emitted, cl_last),
+                             (self._key_cols, cl_keys)):
+            for n, a in saved.items():
+                arr = np.zeros(len(self._row_counts), dtype=a.dtype)
+                arr[slots] = a
+                store[n] = arr

@@ -36,6 +36,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from flink_tpu.ops.segment_ops import MERGE_FN, SCATTER_METHOD
 from flink_tpu.tenancy.program_cache import PROGRAM_CACHE
@@ -51,6 +52,7 @@ KNOWN_PROGRAM_FAMILIES = (
     "evict-cohort",     # cohort put/reset (spill reload, eviction clear)
     "snapshot-lift",    # snapshot ordering fence / row lift
     "delta-harvest",    # pane-ring partial scatter + fire-row harvest
+    "wide-fold",        # host partials combined into two-word int64 parts
     # -- composite per-engine programs (fused from canonical pieces) --
     "mesh-steps",
     "session-merge",
@@ -67,6 +69,8 @@ KNOWN_PROGRAM_FAMILIES = (
     "join-banded-probe",
     "window-join-put",
     "window-join-match",
+    "regular-join-insert",
+    "regular-join-probe",
     "join-exchange2-stage1",
     "join-exchange2-stage2",
     "cep-advance",
@@ -262,6 +266,73 @@ def _build_merge_pairs(leaves):
         return tuple(out)
 
     return merge
+
+
+# ------------------------------------------------------------------ wide-fold
+
+
+_SIGN = -(1 << 31)
+
+
+def _unsigned(w):
+    """An int32 word flipped so that signed order is its unsigned order."""
+    return jnp.bitwise_xor(w, jnp.int32(_SIGN))
+
+
+def wide_fold(layout: Tuple[Tuple[str, str], ...]):
+    """``(accs, slots, fresh, deltas) -> (accs, words)`` for a table that
+    holds an exact 64-bit part (``windowing.aggregates.Int64Aggregate``).
+    ``layout`` is each part's ``(reduce, dtype)``: a 64-bit part is two
+    int32 leaves, its low word then its high word, a 32-bit one one leaf.
+    ``deltas`` holds the batch's per-slot partials the same way,
+    ``slots`` the distinct slots they belong to (lanes past them hold the
+    capacity, which the gathers clip and the scatters drop), ``fresh``
+    the slots that hold nothing yet. A 64-bit ``sum`` adds with the low
+    word's carry, a 64-bit ``max`` or ``min`` compares the high words
+    signed and the low words unsigned. ``words`` are the parts' new words
+    at ``slots``: what the operator emits."""
+    layout = tuple((str(r), np.dtype(d).str) for r, d in layout)
+    for r, _ in layout:
+        if r not in ("sum", "max", "min"):
+            raise ValueError(f"unknown reduce {r!r}")
+    return PROGRAM_CACHE.get_or_build(
+        "wide-fold", layout, lambda: _build_wide_fold(layout))
+
+
+def _combine64(r, alo, ahi, dlo, dhi):
+    if r == "sum":
+        nlo = alo + dlo
+        carry = (_unsigned(nlo) < _unsigned(alo)).astype(jnp.int32)
+        return nlo, ahi + dhi + carry
+    above = (dhi > ahi) | ((dhi == ahi) & (_unsigned(dlo) > _unsigned(alo)))
+    below = (dhi < ahi) | ((dhi == ahi) & (_unsigned(dlo) < _unsigned(alo)))
+    take = above if r == "max" else below
+    return jnp.where(take, dlo, alo), jnp.where(take, dhi, ahi)
+
+
+def _combine32(r, a, d):
+    return {"sum": jnp.add, "max": jnp.maximum, "min": jnp.minimum}[r](a, d)
+
+
+def _build_wide_fold(layout):
+    @partial(jax.jit, donate_argnums=(0,))
+    def group_agg_fold64(accs, slots, fresh, deltas):
+        new_accs, words = [], []
+        at = 0
+        for r, dt in layout:
+            n = 2 if np.dtype(dt).itemsize == 8 else 1
+            old = [accs[at + j].at[slots].get(mode="clip") for j in range(n)]
+            delta = list(deltas[at:at + n])
+            new = (_combine64(r, *old, *delta) if n == 2
+                   else (_combine32(r, old[0], delta[0]),))
+            for j in range(n):
+                w = jnp.where(fresh, delta[j], new[j])
+                new_accs.append(accs[at + j].at[slots].set(w, mode="drop"))
+                words.append(w)
+            at += n
+        return tuple(new_accs), tuple(words)
+
+    return group_agg_fold64
 
 
 # --------------------------------------------------------------------- gather

@@ -111,7 +111,7 @@ class Planner:
             source = self._plan_table_ref(window.table)
             source.alias = window.alias or source.alias
         else:
-            source = self._plan_table_ref(stmt.table)
+            source = self._plan_table_ref(stmt.table, _columns_used(stmt))
 
         aliases = self._collect_aliases(stmt.table)
         resolve = lambda e: self._resolve(e, source.columns, aliases)  # noqa: E731
@@ -195,7 +195,10 @@ class Planner:
 
     # ------------------------------------------------------- FROM clause
 
-    def _plan_table_ref(self, ref: ast.TableRef) -> PlannedTable:
+    def _plan_table_ref(self, ref: ast.TableRef, needs=None
+                        ) -> PlannedTable:
+        """``needs``: the ``(qualifier, name)`` columns the enclosing
+        SELECT reads (None: all of them), which a join keeps."""
         from flink_tpu.table.fluent import _InlineTable
 
         if isinstance(ref, _InlineTable):
@@ -242,7 +245,7 @@ class Planner:
         if isinstance(ref, ast.MLPredictTVF):
             return self._plan_ml_predict(ref)
         if isinstance(ref, ast.Join):
-            return self._plan_join(ref)
+            return self._plan_join(ref, needs)
         if isinstance(ref, ast.MatchRecognize):
             return self._plan_match_recognize(ref)
         raise PlanError(f"unsupported table ref {ref!r}")
@@ -631,10 +634,12 @@ class Planner:
                 pre_cols[field] = a.arg
             cls = {"SUM": SumAggregate, "MIN": MinAggregate,
                    "MAX": MaxAggregate, "AVG": AvgAggregate}[a.func]
-            if cls is AvgAggregate:
-                agg_fns.append(AvgAggregate(field, output=out_name))
-            else:
-                agg_fns.append(cls(field, output=out_name))
+            # an unwindowed GROUP BY's SUM / MAX / MIN / AVG take their
+            # input's type when the operator sees it (BIGINT exact, a
+            # DOUBLE refused); a window aggregate keeps its float32 leaves
+            agg_fns.append(cls(field, output=out_name,
+                               dtype=None if window is None
+                               else np.float32))
 
         if pre_cols:
             def add_cols(batch, pre_cols=pre_cols):
@@ -660,7 +665,14 @@ class Planner:
             key_field = GROUP_KEY_FIELD
 
             def add_tuple_key(batch, fields=tuple(key_fields)):
-                vals = list(zip(*[batch[f].tolist() for f in fields]))
+                cols = [np.asarray(batch[f]) for f in fields]
+                if window is None and all(c.dtype.kind in "iub"
+                                          for c in cols):
+                    # integer keys: one int64 hash of them, the columns
+                    # kept per key by the GROUP BY operator
+                    return batch.with_column(GROUP_KEY_FIELD,
+                                             _combine_int_keys(cols))
+                vals = list(zip(*[c.tolist() for c in cols]))
                 arr = np.empty(len(batch), dtype=object)
                 arr[:] = vals
                 return batch.with_column(GROUP_KEY_FIELD, arr)
@@ -697,12 +709,14 @@ class Planner:
             # reference's table.exec.state.ttl is likewise stream-only)
             ttl = None if batch_final else (self.env.config.get(
                 StateOptions.TABLE_EXEC_STATE_TTL) or None)
+            carried = tuple(key_fields) if key_field == GROUP_KEY_FIELD \
+                else ()
             t = Transformation(
                 name="sql_group_agg", kind="one_input",
                 operator_factory=lambda: GroupAggOperator(
                     multi, key_field, capacity=capacity,
                     emit_on_watermark_only=batch_final,
-                    ttl_ms=ttl),
+                    ttl_ms=ttl, key_columns=carried),
                 inputs=[keyed.transformation], keyed=True,
                 key_field=key_field)
             agged = DataStream(self.env, t)
@@ -713,6 +727,9 @@ class Planner:
         if key_field == GROUP_KEY_FIELD:
             def split_key(batch, fields=tuple(key_fields)):
                 tuples = batch[GROUP_KEY_FIELD]
+                if tuples.dtype != object:
+                    # an integer key's columns came with the rows
+                    return batch.drop(GROUP_KEY_FIELD)
                 for j, f in enumerate(fields):
                     batch = batch.with_column(
                         f, np.array([t[j] for t in tuples], dtype=object))
@@ -948,9 +965,13 @@ class Planner:
 
     # --------------------------------------------------------------- joins
 
-    def _plan_join(self, join: ast.Join) -> PlannedTable:
+    def _plan_join(self, join: ast.Join, needs=None) -> PlannedTable:
         if join.temporal is not None:
             return self._plan_temporal_join(join)
+        if join.condition is None:
+            raise PlanError("a join needs a condition: FROM a, b takes its "
+                            "equality from WHERE (a cross join is not "
+                            "supported)")
         if join.kind not in ("INNER", "LEFT"):
             raise PlanError(f"{join.kind} JOIN is not supported yet")
         left = self._plan_table_ref(join.left)
@@ -1008,13 +1029,118 @@ class Planner:
         # schema exactly, including the synthetic join-key column both
         # sides carry after _key_for_join
         pad_cols = tuple(right.columns) + (GROUP_KEY_FIELD,)
+
+        def host_join(pad_cols=pad_cols):
+            return IntervalJoinOperator(
+                lower, upper, suffixes=("_l", "_r"),
+                left_outer=left_outer, right_columns=list(pad_cols))
+
+        if time_bounds is None and not left_outer:
+            device = self._device_regular_join(
+                left, right, l_aliases, r_aliases, residual, needs,
+                host_join)
+            if device is not None:
+                return self._lower_keyed_join(
+                    left, right, l_aliases, r_aliases, equi, residual,
+                    device, "sql_device_join", residual_in_operator=True)
         return self._lower_keyed_join(
             left, right, l_aliases, r_aliases, equi, residual,
-            lambda pad_cols=pad_cols: IntervalJoinOperator(
-                lower, upper, suffixes=("_l", "_r"),
-                left_outer=left_outer,
-                right_columns=list(pad_cols)),
-            "sql_join")
+            lambda res_filter: host_join(), "sql_join")
+
+    def _device_regular_join(self, left, right, l_aliases, r_aliases,
+                             residual, needs, host_join):
+        """The operator factory of the device regular join for an INNER
+        join with no time bound (``join.mode=device``, the default), or
+        None where the join stays on the host: ``join.mode=host``, or a
+        residual the device cannot compare (counted, with a warning)."""
+        from flink_tpu.core.config import DeploymentOptions, StateOptions
+        from flink_tpu.joins.operators import (
+            DeviceRegularJoinOperator,
+            fall_back,
+        )
+
+        config = self.env.config
+        if config.get(DeploymentOptions.JOIN_MODE) != "device":
+            return None
+        spec = []
+        for c in residual:
+            parts = self._device_comparisons(c, left, right, l_aliases,
+                                             r_aliases)
+            if parts is None:
+                from flink_tpu.table.explain import render_expr
+
+                fall_back(f"residual {render_expr(c)} is no comparison of "
+                          "columns and number literals", "regular join")
+                return None
+            spec.extend(parts)
+        keep = self._join_keeps(needs, left, right, l_aliases, r_aliases)
+        capacity = config.get(StateOptions.JOIN_CAPACITY)
+        ts_fields = (left.time_field, right.time_field)
+
+        def factory(res_filter, spec=tuple(spec)):
+            return DeviceRegularJoinOperator(
+                ("_l", "_r"), residual=spec, keep=keep, ts_fields=ts_fields,
+                capacity=capacity, host_factory=host_join,
+                residual_filter=res_filter)
+
+        return factory
+
+    def _device_comparisons(self, c: Expr, left, right, l_aliases,
+                            r_aliases) -> Optional[list]:
+        """A residual conjunct as the device join's comparisons ``(op,
+        lhs, rhs)`` over ``("col", side, name)`` / ``("lit", number)``
+        operands, or None where it is something else."""
+        def operand(e):
+            if isinstance(e, Literal) and not isinstance(e.value, bool) \
+                    and isinstance(e.value, (int, float)):
+                return ("lit", e.value)
+            if not isinstance(e, Column):
+                return None
+            side = self._side_of(e, left, right, l_aliases, r_aliases)
+            if side is None:
+                return None
+            t, al = (left, l_aliases) if side == "l" else (right, r_aliases)
+            col = self._strip(e, t, al)
+            if not isinstance(col, Column) or col.name not in t.columns:
+                return None
+            return ("col", 0 if side == "l" else 1, col.name)
+
+        if isinstance(c, Between):
+            parts = [(">=", c.value, c.low), ("<=", c.value, c.high)]
+        elif isinstance(c, BinaryOp) and c.op in _COMPARISONS:
+            parts = [(_COMPARISONS[c.op], c.left, c.right)]
+        else:
+            return None
+        out = []
+        for op, a, b in parts:
+            oa, ob = operand(a), operand(b)
+            if oa is None or ob is None:
+                return None
+            out.append((op, oa, ob))
+        return out
+
+    def _join_keeps(self, needs, left, right, l_aliases, r_aliases):
+        """Per side the input columns the enclosing SELECT reads of the
+        join (None: every column)."""
+        if needs is None:
+            return (None, None)
+        keep = (set(), set())
+        sides = ((left, l_aliases, "_l"), (right, r_aliases, "_r"))
+        for table, name in needs:
+            if table is not None:
+                at = [i for i, (_, al, _) in enumerate(sides)
+                      if table in al]
+                if not at:
+                    return (None, None)
+                keep[at[0]].add(name)
+                continue
+            for i, (t, _, suffix) in enumerate(sides):
+                if name in t.columns:
+                    keep[i].add(name)
+                elif name.endswith(suffix) and \
+                        name[:-len(suffix)] in t.columns:
+                    keep[i].add(name[:-len(suffix)])
+        return tuple(frozenset(k) for k in keep)
 
     def _plan_lookup_join(self, join: ast.Join) -> PlannedTable:
         """JOIN dim FOR SYSTEM_TIME AS OF o.rowtime ON o.k = dim.k where
@@ -1107,17 +1233,17 @@ class Planner:
                           l_aliases, r_aliases,
                           equi: List[Tuple[Expr, Expr]],
                           residual: List[Expr], op_factory,
-                          name: str) -> PlannedTable:
+                          name: str,
+                          residual_in_operator: bool = False
+                          ) -> PlannedTable:
         """Shared two-input keyed-join lowering: key both sides on the
         equi columns, wire the operator, suffix colliding output
-        columns, and apply non-equi conjuncts as a post-filter."""
+        columns, and apply non-equi conjuncts as a post-filter — or,
+        with ``residual_in_operator``, hand the filter to
+        ``op_factory(filter)``, whose operator applies the conjuncts
+        itself."""
         l_stream = self._key_for_join(left, [l for l, _ in equi])
         r_stream = self._key_for_join(right, [r for _, r in equi])
-        t = Transformation(
-            name=name, kind="two_input", operator_factory=op_factory,
-            inputs=[l_stream.transformation, r_stream.transformation],
-            keyed=True)
-        joined = DataStream(self.env, t)
 
         out_cols: List[str] = []
         for c in left.columns:
@@ -1125,6 +1251,7 @@ class Planner:
         for c in right.columns:
             out_cols.append(c + "_r" if c in left.columns else c)
 
+        res_filter = None
         if residual:
             # on an alias collision (self-join without aliases) the left
             # mapping wins, matching the historical behavior
@@ -1138,6 +1265,14 @@ class Planner:
                     mask &= np.asarray(e.eval(batch)).astype(bool)
                 return mask
 
+        t = Transformation(
+            name=name, kind="two_input",
+            operator_factory=lambda: op_factory(
+                res_filter if residual_in_operator else None),
+            inputs=[l_stream.transformation, r_stream.transformation],
+            keyed=True)
+        joined = DataStream(self.env, t)
+        if res_filter is not None and not residual_in_operator:
             joined = joined.filter(res_filter, name=f"{name}_residual")
         return PlannedTable(joined, out_cols, None, None)
 
@@ -1194,7 +1329,7 @@ class Planner:
                 "version key")
         return self._lower_keyed_join(
             left, right, l_aliases, r_aliases, equi, residual,
-            lambda: TemporalJoinOperator(suffixes=("_l", "_r")),
+            lambda res_filter: TemporalJoinOperator(suffixes=("_l", "_r")),
             "sql_temporal_join")
 
     def _side_of(self, expr: Expr, left: PlannedTable, right: PlannedTable,
@@ -1332,6 +1467,41 @@ class Planner:
                                  for o in stmt.order_by]
             planned.limit = stmt.limit
         return planned
+
+
+#: SQL comparison -> the device join's residual operator
+_COMPARISONS = {"<": "<", "<=": "<=", ">": ">", ">=": ">=", "=": "=",
+                "<>": "<>", "!=": "<>"}
+
+
+def _combine_int_keys(cols) -> np.ndarray:
+    """One int64 per row from integer key columns: a splitmix64 chain over
+    their values (the GROUP BY operator refuses two keys that collide)."""
+    from flink_tpu.state.keygroups import _splitmix64
+
+    h = np.zeros(len(cols[0]), dtype=np.uint64)
+    with np.errstate(over="ignore"):
+        for c in cols:
+            h = _splitmix64(h ^ c.astype(np.int64).view(np.uint64))
+    return h.view(np.int64)
+
+
+def _columns_used(stmt) -> Optional[set]:
+    """The ``(qualifier, name)`` columns a SELECT reads, or None where it
+    reads every column (``*``)."""
+    if not isinstance(stmt, ast.SelectStmt):
+        return None
+    exprs = [i.expr for i in stmt.items] + list(stmt.group_by) + [
+        o.expr for o in stmt.order_by] + [
+        e for e in (stmt.where, stmt.having) if e is not None]
+    used = set()
+    for e in exprs:
+        for node in e.walk():
+            if isinstance(node, Star):
+                return None
+            if isinstance(node, Column):
+                used.add((node.table, node.name))
+    return used
 
 
 def _window_assigner(tvf: ast.WindowTVF):

@@ -67,6 +67,7 @@ class Join:
     left: "TableRef"
     right: "TableRef"
     kind: str            # INNER | LEFT
+    #: None only while a comma join (``FROM a, b``) waits for its WHERE
     condition: Expr
     #: FOR SYSTEM_TIME AS OF <left rowtime> — an event-time TEMPORAL
     #: join against the right side's versions (reference:
@@ -486,6 +487,11 @@ class Parser:
         self.expect_kw("FROM")
         table = self._table_ref()
         where = self.parse_expr() if self.accept_kw("WHERE") else None
+        if isinstance(table, Join) and table.condition is None:
+            # FROM a A, b B WHERE ...: the comma join is the INNER join
+            # whose condition is the WHERE clause
+            table = dataclasses.replace(table, condition=where)
+            where = None
         group_by: List[Expr] = []
         if self.accept_kw("GROUP"):
             self.expect_kw("BY")
@@ -530,6 +536,14 @@ class Parser:
 
     def _table_ref(self) -> TableRef:
         left = self._table_primary()
+        if self.accept_op(","):
+            # a comma join: its condition is the WHERE clause (parse_select)
+            right = self._table_primary()
+            if (self.peek().kind == "op" and self.peek().value == ",") \
+                    or self.at_kw("JOIN", "INNER", "LEFT"):
+                raise SqlParseError(
+                    "a comma join takes two tables and no further join")
+            return Join(left, right, "INNER", None)
         while True:
             kind = None
             if self.accept_kw("JOIN"):

@@ -78,6 +78,33 @@ class AggregateFunction:
     leaves: Tuple[AccLeaf, ...] = ()
     #: names of the emitted result columns
     output_names: Tuple[str, ...] = ("result",)
+    #: True for an exact 64-bit aggregate (:class:`Int64Aggregate`): its
+    #: leaves are int32 words its operator folds from host partials
+    wide: bool = False
+
+    @property
+    def parts(self) -> Tuple[Tuple[str, str, str], ...]:
+        """``(name, reduce, dtype)`` of each accumulator part folded from
+        host partials (a wide table's layout): one per leaf, unless the
+        aggregate carries a 64-bit part in two leaves."""
+        return tuple((l.name, l.reduce, l.dtype.str) for l in self.leaves)
+
+    def part_values(self, batch: RecordBatch) -> List[np.ndarray]:
+        """Each part's value per row (host)."""
+        return list(self.map_input_valued(batch))
+
+    def finish_host(self, parts: List[np.ndarray]) -> Dict[str, np.ndarray]:
+        """``finish`` on the host, over each part's values: how a wide
+        table (one holding a 64-bit part) finishes its aggregates."""
+        raise TypeError(f"{type(self).__name__} has no host finish: it "
+                        "cannot share a table with a 64-bit aggregate")
+
+    def bind(self, dtypes) -> "AggregateFunction":
+        """The aggregate for the input columns' types (``{column:
+        dtype}``): itself, unless its accumulator takes the type of its
+        input (``dtype=None``), in which case ``leaves`` is None until
+        it is bound."""
+        return self
 
     def cache_key(self) -> tuple:
         """Identity of the compiled programs this aggregate needs. Two
@@ -238,16 +265,38 @@ class AggregateFunction:
 
 @public
 class SumAggregate(AggregateFunction):
+    """SUM as one leaf of ``dtype``. ``dtype=None`` takes the input
+    column's type at binding: an integer column's SUM is the exact
+    :class:`Int64Aggregate` (a BIGINT: an int32 sum would wrap past
+    2^31), a float32 one this float32 leaf."""
+
     def __init__(self, field: str, dtype=np.float32, output: str = None):
         self.field = field
-        self.leaves = (AccLeaf("sum", dtype, "sum"),)
+        self.leaves = None if dtype is None else (
+            AccLeaf("sum", dtype, "sum"),)
         self.output_names = (output or f"sum_{field}",)
+
+    def bind(self, dtypes):
+        if self.leaves is not None:
+            return self
+        dt = _bound_dtype("SUM", self.field, dtypes)
+        if dt.kind == "f":
+            return SumAggregate(self.field, np.float32, self.output_names[0])
+        return Int64Aggregate("sum", self.field, self.output_names[0],
+                              np.int64)
+
+    @property
+    def retractable(self) -> bool:
+        return True
 
     def map_input(self, batch):
         return (batch[self.field],)
 
     def finish(self, merged):
         return {self.output_names[0]: merged[0]}
+
+    def finish_host(self, parts):
+        return {self.output_names[0]: parts[0]}
 
 
 @public
@@ -263,12 +312,35 @@ class CountAggregate(AggregateFunction):
         return {self.output_names[0]: merged[0]}
 
 
-@public
-class MaxAggregate(AggregateFunction):
+class _Extremum(AggregateFunction):
+    """MAX / MIN. ``dtype=None`` takes the input column's type when the
+    operator binds it to its first batch (SQL's ``MAX(x)`` has the type
+    of ``x``): an integer column up to 32 bits keeps an int32 leaf, a
+    64-bit one the exact two-word :class:`Int64Aggregate`, a float one
+    the float32 leaf every float caller has."""
+
+    reduce = ""
+
     def __init__(self, field: str, dtype=np.float32, output: str = None):
         self.field = field
-        self.leaves = (AccLeaf("max", dtype, "max"),)
-        self.output_names = (output or f"max_{field}",)
+        self.output_names = (output or f"{self.reduce}_{field}",)
+        self.leaves = None if dtype is None else (
+            AccLeaf(self.reduce, dtype, self.reduce),)
+
+    def bind(self, dtypes):
+        if self.leaves is not None:
+            return self
+        dt = _bound_dtype(self.reduce.upper(), self.field, dtypes)
+        if dt.kind == "f":
+            return type(self)(self.field, np.float32, self.output_names[0])
+        if _fits_int32(dt):
+            return type(self)(self.field, np.int32, self.output_names[0])
+        return Int64Aggregate(self.reduce, self.field,
+                              self.output_names[0], dt)
+
+    @property
+    def retractable(self) -> bool:
+        return False
 
     def map_input(self, batch):
         return (batch[self.field],)
@@ -276,30 +348,47 @@ class MaxAggregate(AggregateFunction):
     def finish(self, merged):
         return {self.output_names[0]: merged[0]}
 
+    def finish_host(self, parts):
+        return {self.output_names[0]: parts[0]}
+
 
 @public
-class MinAggregate(AggregateFunction):
-    def __init__(self, field: str, dtype=np.float32, output: str = None):
-        self.field = field
-        self.leaves = (AccLeaf("min", dtype, "min"),)
-        self.output_names = (output or f"min_{field}",)
+class MaxAggregate(_Extremum):
+    reduce = "max"
 
-    def map_input(self, batch):
-        return (batch[self.field],)
 
-    def finish(self, merged):
-        return {self.output_names[0]: merged[0]}
+@public
+class MinAggregate(_Extremum):
+    reduce = "min"
 
 
 @public
 class AvgAggregate(AggregateFunction):
-    def __init__(self, field: str, output: str = None):
+    """AVG as a float32 sum over a float32 count. ``dtype=None`` takes
+    the input column's type at binding: an integer column's AVG is the
+    exact :class:`Int64Aggregate` (a 64-bit sum and count, divided with
+    Java's truncation, in the input's type: SQL's ``AVG(BIGINT)`` is a
+    BIGINT), a float one this float32 form."""
+
+    def __init__(self, field: str, output: str = None, dtype=np.float32):
         self.field = field
-        self.leaves = (
+        self.output_names = (output or f"avg_{field}",)
+        self.leaves = None if dtype is None else (
             AccLeaf("sum", np.float32, "sum"),
             AccLeaf("count", np.float32, "sum", const=1.0),
         )
-        self.output_names = (output or f"avg_{field}",)
+
+    def bind(self, dtypes):
+        if self.leaves is not None:
+            return self
+        dt = _bound_dtype("AVG", self.field, dtypes)
+        if dt.kind == "f":
+            return AvgAggregate(self.field, self.output_names[0])
+        return Int64Aggregate("avg", self.field, self.output_names[0], dt)
+
+    @property
+    def retractable(self) -> bool:
+        return True
 
     def map_input(self, batch):
         return (batch[self.field],)
@@ -307,6 +396,124 @@ class AvgAggregate(AggregateFunction):
     def finish(self, merged):
         s, c = merged
         return {self.output_names[0]: s / jnp.maximum(c, 1.0)}
+
+    def finish_host(self, parts):
+        s, c = parts
+        return {self.output_names[0]: (s / np.maximum(c, 1.0)).astype(
+            np.float32)}
+
+
+def _bound_dtype(func: str, field: str, dtypes) -> np.dtype:
+    """The type ``func(field)`` binds to: the column's, where the device
+    carries it exactly (an integer type, or a float of at most 32 bits).
+    A wider float would be carried as a float32 (JAX without x64): that
+    is refused, never narrowed silently."""
+    dt = np.dtype(dtypes[field])
+    if dt.kind == "f" and device_truncates(dt):
+        raise TypeError(
+            f"{func}({field}) over {dt}: the device (JAX without x64) "
+            "would accumulate it as a float32; cast the column to a "
+            "float32 or an integer type first")
+    if dt.kind not in "iubf":
+        raise TypeError(f"{func}({field}) over {dt}: not a number")
+    return dt
+
+
+def _fits_int32(dt: np.dtype) -> bool:
+    """An integer type whose every value an int32 leaf holds."""
+    return dt.kind in "ib" and dt.itemsize <= 4 or \
+        dt.kind == "u" and dt.itemsize <= 2
+
+
+def device_truncates(dtype) -> bool:
+    """Whether a device array of ``dtype`` would lose width: JAX runs
+    without x64 and carries a 64-bit type as its 32-bit sibling."""
+    import jax
+
+    return np.dtype(dtype).itemsize > 4 and not jax.config.jax_enable_x64
+
+
+@public
+class Int64Aggregate(AggregateFunction):
+    """An exact 64-bit integer aggregate on the x32 device: SQL's BIGINT
+    ``MAX`` / ``MIN`` / ``SUM`` / ``AVG`` and a 64-bit ``COUNT``.
+
+    Each 64-bit part of the accumulator rides two int32 leaves, its low
+    word and then its high word. No scatter folds them (a word-wise add
+    loses the carry, a word-wise max the order): the operator sums,
+    maxes or mins each batch's rows per slot on the host in int64, and
+    one device program combines those partials with the stored words,
+    carry and order included (``stateplane.families.wide_fold``).
+    ``finish_host`` turns the words back into int64 on the host: ``AVG``
+    is the sum divided by the count, truncated toward zero as Java's
+    ``long`` division is, in ``result_dtype`` (the input's type). A
+    window engine folds leaves by scatter and cannot run it:
+    ``map_input`` refuses."""
+
+    wide = True
+    PARTS = {"max": (("max", "max"),), "min": (("min", "min"),),
+             "sum": (("sum", "sum"),),
+             "avg": (("sum", "sum"), ("count", "sum")),
+             "count": (("count", "sum"),)}
+
+    def __init__(self, kind: str, field: str = None, output: str = None,
+                 result_dtype=np.int64):
+        if kind not in self.PARTS:
+            raise ValueError(f"unknown 64-bit aggregate {kind!r}; known: "
+                             f"{', '.join(self.PARTS)}")
+        dt = np.dtype(result_dtype)
+        if dt.kind == "u" and dt.itemsize == 8:
+            raise TypeError(
+                f"{kind.upper()}({field}) over uint64: values past 2^63 "
+                "have no exact int64 accumulator")
+        self.kind = kind
+        self.field = field
+        self.result_dtype = dt
+        self.leaves = tuple(
+            AccLeaf(f"{name}_{word}", np.int32, "sum")
+            for name, _ in self.PARTS[kind] for word in ("lo", "hi"))
+        self.output_names = (output or f"{kind}_{field}",)
+
+    @property
+    def parts(self):
+        return tuple((name, reduce, "<i8")
+                     for name, reduce in self.PARTS[self.kind])
+
+    @property
+    def retractable(self) -> bool:
+        return all(reduce == "sum" for _, reduce, _ in self.parts)
+
+    def map_input(self, batch):
+        raise TypeError(
+            f"{self.kind.upper()}({self.field}) is an exact 64-bit "
+            "aggregate: it folds host partials (GroupAggOperator), not a "
+            "window engine's scatter")
+
+    def part_values(self, batch: RecordBatch) -> List[np.ndarray]:
+        """Each part's int64 value per row (a count's is 1)."""
+        out = []
+        for name, _, _ in self.parts:
+            if name == "count":
+                out.append(np.ones(len(batch), dtype=np.int64))
+            else:
+                v = np.asarray(batch[self.field])
+                if v.dtype.kind == "u" and v.dtype.itemsize == 8:
+                    raise TypeError(
+                        f"{self.kind.upper()}({self.field}) over uint64: "
+                        "values past 2^63 have no exact int64 accumulator")
+                out.append(v.astype(np.int64, copy=False))
+        return out
+
+    def finish_host(self, parts: List[np.ndarray]) -> Dict[str, np.ndarray]:
+        """The result column from each part's int64 values."""
+        if self.kind != "avg":
+            return {self.output_names[0]: parts[0].astype(
+                self.result_dtype, copy=False)}
+        s, c = parts
+        q = np.abs(s) // np.maximum(np.abs(c), 1)
+        q = np.where((s < 0) != (c < 0), -q, q)
+        return {self.output_names[0]: q.astype(self.result_dtype,
+                                               copy=False)}
 
 
 @public
@@ -316,8 +523,21 @@ class MultiAggregate(AggregateFunction):
 
     def __init__(self, aggs: Sequence[AggregateFunction]):
         self.aggs = list(aggs)
-        leaves: List[AccLeaf] = []
         outs: List[str] = []
+        for a in self.aggs:
+            outs.extend(a.output_names)
+        self.output_names = tuple(outs)
+        self.wide = any(getattr(a, "wide", False) for a in self.aggs)
+        if self.wide:
+            # a COUNT beside a 64-bit part is counted as one too
+            self.aggs = [Int64Aggregate("count", None, a.output_names[0])
+                         if isinstance(a, CountAggregate) else a
+                         for a in self.aggs]
+        if any(a.leaves is None for a in self.aggs):
+            # bound to the input's types at the first batch (``bind``)
+            self.leaves = None
+            return
+        leaves: List[AccLeaf] = []
         self._spans = []
         for i, a in enumerate(self.aggs):
             start = len(leaves)
@@ -326,9 +546,20 @@ class MultiAggregate(AggregateFunction):
                 for l in a.leaves
             )
             self._spans.append((start, len(leaves)))
-            outs.extend(a.output_names)
         self.leaves = tuple(leaves)
-        self.output_names = tuple(outs)
+        if self.wide:
+            for a in self.aggs:
+                if type(a).finish_host is AggregateFunction.finish_host:
+                    a.finish_host([])   # the refusal, named
+
+    def bind(self, dtypes):
+        if self.leaves is not None:
+            return self
+        return MultiAggregate([a.bind(dtypes) for a in self.aggs])
+
+    @property
+    def retractable(self) -> bool:
+        return all(a.retractable for a in self.aggs)
 
     def cache_key(self):
         return ("multi", tuple(a.cache_key() for a in self.aggs))
@@ -338,6 +569,21 @@ class MultiAggregate(AggregateFunction):
         for a in self.aggs:
             vals.extend(a.map_input(batch))
         return tuple(vals)
+
+    @property
+    def parts(self):
+        return tuple(p for a in self.aggs for p in a.parts)
+
+    def part_values(self, batch):
+        return [v for a in self.aggs for v in a.part_values(batch)]
+
+    def finish_host(self, parts):
+        out: Dict[str, np.ndarray] = {}
+        at = 0
+        for a in self.aggs:
+            out.update(a.finish_host(parts[at:at + len(a.parts)]))
+            at += len(a.parts)
+        return out
 
     def finish(self, merged):
         out: Dict[str, jnp.ndarray] = {}

@@ -81,7 +81,8 @@ def test_insert_into_filesystem_then_select_back(tmp_path):
         f"WITH ('connector'='filesystem', 'path'='{out}', "
         "'format'='json')")
     back = tenv2.execute_sql(
-        "SELECT key, SUM(total) AS s FROM warehouse GROUP BY key"
+        "SELECT key, SUM(CAST(total AS FLOAT)) AS s FROM warehouse "
+        "GROUP BY key"
     ).collect()
     per_key = collections.defaultdict(float)
     for (k, _), v in oracle.items():

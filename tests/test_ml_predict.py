@@ -138,7 +138,7 @@ class TestSqlMlPredict:
             "orders", t_env.from_collection(_rows(), timestamp_field="ts"))
         t_env.create_temporary_model("scorer", doubler_model())
         out = t_env.execute_sql(
-            "SELECT qty, SUM(doubled) AS s FROM ML_PREDICT("
+            "SELECT qty, SUM(CAST(doubled AS FLOAT)) AS s FROM ML_PREDICT("
             "TABLE orders, MODEL scorer, DESCRIPTOR(price)) "
             "GROUP BY qty").collect()
         got = {r["qty"]: r["s"] for r in out}

@@ -367,7 +367,8 @@ class TestInsertInto:
         sink = ChangelogSink()
         t_env.create_sink_table("out", sink, columns=["k", "s"])
         t_env.execute_sql(
-            "INSERT INTO out SELECT k, SUM(v) AS s FROM src GROUP BY k")
+            "INSERT INTO out SELECT k, SUM(CAST(v AS FLOAT)) AS s FROM src "
+            "GROUP BY k")
         batch = sink.result()
         # the row-kind column must survive so the consumer can apply
         # retractions; folding the changelog gives the true final sums

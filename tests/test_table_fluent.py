@@ -23,7 +23,7 @@ def _t_env():
 def _bids(t_env, n=4000):
     rng = np.random.default_rng(3)
     rows = [{"auction": int(rng.integers(30)),
-             "price": float(rng.integers(1, 100)),
+             "price": np.float32(rng.integers(1, 100)),
              "t": i * 5} for i in range(n)]
     table = t_env.from_collection(rows, timestamp_field="t")
     t_env.create_temporary_view("bid", table)
