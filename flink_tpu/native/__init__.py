@@ -279,6 +279,8 @@ def load_slotmap() -> Optional[ctypes.CDLL]:
         lib.sm_flat_fuse.restype = None
         lib.sm_flat_fuse.argtypes = [i64, P(i32), P(i32), P(i64), i64,
                                      P(i32)]
+        lib.sm_group_rows.restype = i64
+        lib.sm_group_rows.argtypes = [i64, P(i64), P(i64), P(i64)]
         _lib = lib
         return _lib
 

@@ -118,6 +118,10 @@ KNOWN_SPAN_KINDS = (
     "agg.fold",            # an unwindowed GROUP BY's batch folded into
                            # its table and the touched keys' new values
                            # read back (work: rows)
+    "agg.groups",          # the distinct keys a GROUP BY's batch took
+                           # to its table, grouped from its rows in one
+                           # hash pass (instant inside agg.fold; work:
+                           # keys)
     "agg.retract",         # retraction rows (UPDATE_BEFORE / DELETE) a
                            # GROUP BY folded with their negative sign
                            # (instant inside agg.fold; work: rows)

@@ -35,8 +35,14 @@ through one device program (``stateplane.families.wide_fold``), which
 returns the parts' new words: the host finishes them (``finish_host``).
 A narrow leaf the device would carry at half its width is refused.
 
+A batch's rows are first grouped by key id in one hash pass
+(``slot_table.group_rows``, no sort): the slot lookup and the check of
+the kept key columns run once per distinct key, and the partials are
+taken per key through each row's group number.
+
 Flight recorder: ``agg.fold`` (the batch into the table and the touched
-keys' new values back; work: rows), ``agg.retract`` (retraction rows it
+keys' new values back; work: rows), ``agg.groups`` (the distinct keys
+taken to the table; work: keys), ``agg.retract`` (retraction rows it
 folded; work: rows), ``agg.emit`` (the changelog built on the host) and
 ``agg.changelog`` (rows emitted; work: rows).
 """
@@ -60,7 +66,7 @@ from flink_tpu.core.records import (
 )
 from flink_tpu.observe import flight_recorder as flight
 from flink_tpu.runtime.operators import Operator
-from flink_tpu.state.slot_table import SlotTable
+from flink_tpu.state.slot_table import SlotTable, group_rows
 from flink_tpu.windowing.aggregates import (
     AggregateFunction,
     device_truncates,
@@ -171,20 +177,26 @@ class GroupAggOperator(Operator):
                 g[: len(arr)] = arr
                 store[name] = g
 
-    def _carry_keys(self, slots: np.ndarray, batch: RecordBatch) -> None:
+    def _carry_keys(self, slots: np.ndarray, group: np.ndarray,
+                    first: np.ndarray, batch: RecordBatch) -> None:
         """Each new key's column values kept at its slot; every row's
-        checked against its key's (two keys whose hash collides would
-        share a slot: that is refused, never folded together)."""
+        checked against its group's first row, and that row against its
+        key's kept values (two keys whose hash collides would share a
+        slot: that is refused, never folded together). ``slots``: the
+        batch's distinct keys' slots, ``group`` each row's key among
+        them, ``first`` each key's first row."""
         new = self._row_counts[slots] == 0
         new &= ~self._emitted_mask[slots]
         for name in self.key_columns:
             v = np.asarray(batch[name])
+            head = v[first]
             store = self._key_cols.get(name)
             if store is None:
                 store = self._key_cols[name] = np.zeros(
                     len(self._row_counts), dtype=v.dtype)
-            store[slots[new]] = v[new]
-            if not np.array_equal(store[slots], v):
+            store[slots[new]] = head[new]
+            if not (np.array_equal(head[group], v)
+                    and np.array_equal(store[slots], head)):
                 raise RuntimeError(
                     f"two GROUP BY keys of ({', '.join(self.key_columns)}) "
                     "hash to one key id: their groups would fold together")
@@ -230,19 +242,22 @@ class GroupAggOperator(Operator):
         if batch.has_timestamps:
             self._max_ts = max(self._max_ts, int(batch.timestamps.max()))
         with flight.span("agg.fold") as fold:
+            # the batch's rows grouped by key: the table, the key check
+            # and the partials see each key once
+            group, first = group_rows(batch.key_ids)
+            key_ids = np.asarray(batch.key_ids)[first]
             if self.key_field in batch.columns:
                 keys = batch[self.key_field]
                 if keys.dtype.kind not in "iu":
                     self._keys_hashed = True
-                    kid = batch.key_ids
-                    uniq, first = np.unique(kid, return_index=True)
-                    for i, j in zip(uniq.tolist(), first.tolist()):
+                    for i, j in zip(key_ids.tolist(), first.tolist()):
                         self._key_values.setdefault(i, keys[j])
-            namespaces = np.full(len(batch), _GLOBAL_NS, dtype=np.int64)
-            slots = self.table.lookup_or_insert(batch.key_ids, namespaces)
+            slots = self.table.lookup_or_insert(
+                key_ids, np.full(len(first), _GLOBAL_NS, dtype=np.int64))
+            flight.instant("agg.groups", work=len(first))
             self._ensure_host_capacity(int(slots.max()) + 1)
             if self.key_columns:
-                self._carry_keys(slots, batch)
+                self._carry_keys(slots, group, first, batch)
             kinds = batch.row_kinds
             signs = None if kinds is None else rowkind_signs(
                 np.asarray(kinds))
@@ -260,19 +275,24 @@ class GroupAggOperator(Operator):
                 flight.instant("agg.retract", work=int((signs < 0).sum()))
             emit = not self.emit_on_watermark_only
             if self.agg.wide:
-                uniq, results = self._fold_wide(slots, batch, signs)
+                uniq, results = self._fold_wide(slots, group, first, batch,
+                                                signs)
             else:
+                row_slots = slots[group]
                 if signs is None:
                     # append-only input: the plain scatter path works for
                     # every aggregate, incl. MAX/MIN
-                    self.table.scatter(slots, self.agg.map_input(batch))
+                    self.table.scatter(row_slots, self.agg.map_input(batch))
                 else:
                     self.table.scatter_signed(
-                        slots, self.agg.map_input_signed(batch, signs))
-                uniq = np.unique(slots)
+                        row_slots, self.agg.map_input_signed(batch, signs))
+                uniq = np.sort(slots)
                 results = self._values(uniq) if emit else None
-            np.add.at(self._row_counts, slots,
-                      1 if signs is None else signs.astype(np.int64))
+            counts = np.bincount(group, minlength=len(first))
+            if signs is not None:
+                counts -= 2 * np.bincount(group[signs < 0],
+                                          minlength=len(first))
+            self._row_counts[slots] += counts
             fold.work = len(batch)
         if self.ttl_ms:
             self._last_update[slots] = self._clock()
@@ -283,21 +303,42 @@ class GroupAggOperator(Operator):
         out = self._emit_slots(uniq, results)
         return [out] if out is not None else []
 
-    def _fold_wide(self, slots: np.ndarray, batch: RecordBatch, signs
-                   ) -> tuple:
-        """The exact 64-bit fold: per distinct slot, each part's partial
-        summed, maxed or mined on the host in int64, combined with the
-        table's words by one device program, whose new words come back
-        (one harvest) and are finished on the host. Returns the distinct
-        slots and their result columns."""
+    def _fold_wide(self, slots: np.ndarray, group: np.ndarray,
+                   first: np.ndarray, batch: RecordBatch, signs) -> tuple:
+        """The exact 64-bit fold: per distinct key (``slots``; ``group``
+        each row's key among them, ``first`` each key's first row), each
+        part's partial summed, maxed or mined on the host in int64,
+        combined with the table's words by one device program, whose new
+        words come back (one harvest) and are finished on the host.
+        Returns the distinct slots, ascending, and their result
+        columns."""
+        order = np.argsort(slots)
+        parts = []
+        for (_, r, dt), v in zip(self.agg.parts, self.agg.part_values(batch)):
+            dt = np.dtype(dt)
+            v = np.asarray(v, dtype=np.int64 if dt.kind in "iu"
+                           else np.float64)
+            if signs is not None:
+                v = v * signs.astype(v.dtype)
+            if r == "sum":
+                part = np.zeros(len(first), dtype=v.dtype)
+                np.add.at(part, group, v)
+            else:
+                part = v[first]
+                {"max": np.maximum, "min": np.minimum}[r].at(part, group, v)
+            parts.append(part[order])
+        return self._fold_partials(slots[order], parts)
+
+    def _fold_partials(self, uniq: np.ndarray, parts: List[np.ndarray]
+                       ) -> tuple:
+        """Each part's partial of the distinct slots ``uniq`` (ascending)
+        combined with the table's words by ``wide_fold``; the new words'
+        harvest finished on the host. Returns ``uniq`` and the result
+        columns."""
         from flink_tpu.ops.segment_ops import sticky_bucket
         from flink_tpu.runtime.pending import PendingFire
         from flink_tpu.stateplane.families import wide_fold
 
-        order = np.argsort(slots, kind="stable")
-        ordered = slots[order]
-        starts = np.flatnonzero(np.r_[True, ordered[1:] != ordered[:-1]])
-        uniq = ordered[starts]
         layout = tuple((r, dt) for _, r, dt in self.agg.parts)
         size = sticky_bucket(len(uniq), self._fold_bucket)
         self._fold_bucket = size
@@ -307,16 +348,11 @@ class GroupAggOperator(Operator):
         fresh = np.zeros(size, dtype=bool)
         fresh[:n] = self._row_counts[uniq] == 0
         words = []
-        for (r, dt), v in zip(layout, self.agg.part_values(batch)):
+        for (r, dt), p in zip(layout, parts):
             dt = np.dtype(dt)
             wide = dt.kind in "iu" and dt.itemsize == 8
-            v = np.asarray(v, dtype=np.int64 if wide or dt.kind in "iu"
-                           else np.float64)
-            if signs is not None:
-                v = v * signs.astype(v.dtype)
-            fn = {"sum": np.add, "max": np.maximum, "min": np.minimum}[r]
             part = np.zeros(size, dtype=np.int64 if wide else dt)
-            part[:n] = fn.reduceat(v[order], starts)
+            part[:n] = p
             if wide:
                 w = part.view(np.int32).reshape(-1, 2)
                 words += [np.ascontiguousarray(w[:, 0]),

@@ -112,6 +112,35 @@ def unique_pairs(
     return key_ids[first_pos], namespaces[first_pos], inverse
 
 
+def group_rows(key_ids: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """A batch's rows grouped by key id in one native hash pass
+    (``sm_group_rows``, native/slotmap.cpp), with no sort: ``(group,
+    first)``, each row's group numbered in the order the keys first
+    appear, and each group's first row (ascending). Under
+    ``FLINK_TPU_NO_NATIVE=1`` ``np.unique`` gives the same groups, in the
+    same numbering."""
+    from flink_tpu.native import load_slotmap, native_disabled
+
+    keys = np.ascontiguousarray(key_ids, dtype=np.int64)
+    lib = None if native_disabled() else load_slotmap()
+    if lib is None:
+        _, first, inverse = np.unique(keys, return_index=True,
+                                      return_inverse=True)
+        order = np.argsort(first)
+        rank = np.empty(len(first), dtype=np.int64)
+        rank[order] = np.arange(len(first))
+        return rank[inverse.reshape(-1)], first[order].astype(np.int64)
+    n = len(keys)
+    group = np.empty(n, dtype=np.int64)
+    first = np.empty(n, dtype=np.int64)
+    k = lib.sm_group_rows(n, keys.ctypes.data_as(_I64P),
+                          group.ctypes.data_as(_I64P),
+                          first.ctypes.data_as(_I64P))
+    if k < 0:
+        raise ValueError(f"{n} rows: more than an int32 group number holds")
+    return group, first[:k]
+
+
 class SlotTableFullError(RuntimeError):
     """Device slot budget exhausted — the owner may evict and retry."""
 

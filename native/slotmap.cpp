@@ -790,6 +790,42 @@ inline bool sweep_pass_a(SweepScratch* w, int64_t n, const int64_t* vals,
   return true;
 }
 
+// ---- a batch's rows grouped by key (sm_group_rows)
+
+// one bucket of the grouping pass's batch-local table (16 bytes)
+struct RowGroup {
+  int64_t key;
+  uint32_t stamp;  // the call that wrote it; any other reads as empty
+  int32_t group;
+};
+
+// The pass's table, kept per thread from call to call: the stamp tells
+// this call's entries from older ones, so nothing is cleared per call.
+struct RowGroupScratch {
+  RowGroup* tab = nullptr;
+  uint64_t size = 0;  // power of two, >= 4 * the call's groups
+  uint32_t stamp = 0;
+  ~RowGroupScratch() { free(tab); }
+};
+
+thread_local RowGroupScratch row_groups;
+
+// fourfold; this call's entries move over, older ones are left behind
+void row_groups_grow(RowGroupScratch* w) {
+  RowGroup* old = w->tab;
+  const uint64_t old_size = w->size;
+  w->size = old_size * 4;
+  w->tab = (RowGroup*)calloc(w->size, sizeof(RowGroup));
+  const uint64_t mask = w->size - 1;
+  for (uint64_t j = 0; j < old_size; j++) {
+    if (old[j].stamp != w->stamp) continue;
+    uint64_t b = key_hash(old[j].key) & mask;
+    while (w->tab[b].stamp == w->stamp) b = (b + 1) & mask;
+    w->tab[b] = old[j];
+  }
+  free(old);
+}
+
 }  // namespace
 
 extern "C" {
@@ -1476,6 +1512,55 @@ void sm_flat_fuse(int64_t n, const int32_t* cols, const int32_t* sinv,
   for (int64_t i = 0; i < n; i++) {
     out_flat[i] = (int32_t)(rowmap[sinv[i]] * capacity + (int64_t)cols[i]);
   }
+}
+
+// One batch's rows grouped by key in one hash pass, with no sort and
+// no map handle: each row's group (out_group, numbered in the order the
+// keys first appear) and each group's first row (out_first, ascending;
+// the caller gives both n places). Returns the groups, or -1 where n
+// passes what an int32 group number holds.
+int64_t sm_group_rows(int64_t n, const int64_t* keys, int64_t* out_group,
+                      int64_t* out_first) {
+  if (n > INT32_MAX) return -1;
+  RowGroupScratch* w = &row_groups;
+  if (!w->tab) {
+    w->size = 1024;
+    w->tab = (RowGroup*)calloc(w->size, sizeof(RowGroup));
+  }
+  if (++w->stamp == 0) {  // the stamp came round: forget every entry
+    memset(w->tab, 0, sizeof(RowGroup) * w->size);
+    w->stamp = 1;
+  }
+  const uint32_t stamp = w->stamp;
+  uint64_t mask = w->size - 1;
+  int64_t k = 0;
+  for (int64_t i = 0; i < n; i++) {
+    const int64_t key = keys[i];
+    uint64_t b = key_hash(key) & mask;
+    for (;;) {
+      RowGroup& e = w->tab[b];
+      if (e.stamp != stamp) {  // a key the batch has not met
+        if ((uint64_t)(k + 1) * 4 > w->size) {  // a quarter full at most
+          row_groups_grow(w);
+          mask = w->size - 1;
+          b = key_hash(key) & mask;
+          continue;
+        }
+        e.key = key;
+        e.stamp = stamp;
+        e.group = (int32_t)k;
+        out_first[k] = i;
+        out_group[i] = k++;
+        break;
+      }
+      if (e.key == key) {
+        out_group[i] = e.group;
+        break;
+      }
+      b = (b + 1) & mask;
+    }
+  }
+  return k;
 }
 
 // ---- the fire path's carried slot matrix -------------------------------

@@ -946,3 +946,73 @@ def test_sharded_sweep_full_shard_raises_with_the_others_level():
     assert indexes[1].num_used < 2047
     assert resolve_slices_sharded(indexes, keys[:50], ts[:50].astype(
         np.int64), table, 0, W, -(1 << 62)) is not None
+
+
+# ---- a batch's rows grouped by key (sm_group_rows) ---------------------
+
+
+def _low_bit_twins(count, bits=10):
+    """``count`` keys whose slot-map hash (splitmix64's finalizer) agrees
+    in its low ``bits``: one probe chain in the grouping pass's table."""
+    x = np.arange(1 << 20, dtype=np.uint64)
+    x ^= x >> np.uint64(30)
+    x *= np.uint64(0xBF58476D1CE4E5B9)
+    x ^= x >> np.uint64(27)
+    x *= np.uint64(0x94D049BB133111EB)
+    x ^= x >> np.uint64(31)
+    home = x & np.uint64((1 << bits) - 1)
+    twins = np.flatnonzero(home == home[0])[:count]
+    assert len(twins) == count
+    return twins.astype(np.int64)
+
+
+_I64 = np.iinfo(np.int64)
+GROUP_CASES = {
+    # name: the keys of a batch, from a generator
+    "no_rows": lambda rng: np.zeros(0, dtype=np.int64),
+    "one_row": lambda rng: np.array([42]),
+    "one_key": lambda rng: np.full(5000, -3),
+    "all_distinct": lambda rng: rng.permutation(50_000) * 7919 - 10 ** 9,
+    "negative_and_extremes": lambda rng: rng.choice(np.array(
+        [_I64.min, _I64.min + 1, -2, -1, 0, 1, _I64.max - 1, _I64.max]),
+        3000),
+    "hash_low_bits_collide": lambda rng: rng.choice(_low_bit_twins(150),
+                                                    4000),
+    "key_low_bits_equal": lambda rng: rng.integers(0, 300, 4000) << 40,
+    "random_200k": lambda rng: rng.integers(0, 60_000, 200_000),
+}
+
+
+@pytest.mark.parametrize("native", [True, False], ids=["native", "numpy"])
+@pytest.mark.parametrize("case", sorted(GROUP_CASES))
+def test_grouping_pass_equals_np_unique(case, native, monkeypatch):
+    from flink_tpu.state.slot_table import group_rows
+
+    if native and not slotmap_available():
+        pytest.skip("native slotmap not built")
+    if not native:
+        monkeypatch.setenv("FLINK_TPU_NO_NATIVE", "1")
+    rng = np.random.default_rng(sorted(GROUP_CASES).index(case))
+    keys = np.asarray(GROUP_CASES[case](rng), dtype=np.int64)
+    group, first = group_rows(keys)
+    _, want_first, _ = np.unique(keys, return_index=True,
+                                 return_inverse=True)
+    assert group.dtype == first.dtype == np.int64
+    assert len(group) == len(keys)
+    # one group per distinct key, led by the key's first row ...
+    np.testing.assert_array_equal(first, np.sort(want_first))
+    # ... every row in the group of its own key ...
+    np.testing.assert_array_equal(keys[first][group], keys)
+    # ... numbered in the order the keys first appear
+    np.testing.assert_array_equal(group[first], np.arange(len(first)))
+
+
+@needs_native
+def test_grouping_pass_keeps_nothing_from_one_batch_to_the_next():
+    from flink_tpu.state.slot_table import group_rows
+
+    group_rows(np.arange(5000, dtype=np.int64))   # the table grows
+    group, first = group_rows(np.array([9, 3, 9, 4999], dtype=np.int64))
+    assert group.tolist() == [0, 1, 0, 2] and first.tolist() == [0, 1, 3]
+    group, first = group_rows(np.array([3], dtype=np.int64))
+    assert group.tolist() == [0] and first.tolist() == [0]

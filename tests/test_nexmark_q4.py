@@ -125,6 +125,32 @@ def test_the_seeded_stream_equals_its_reference(seed):
     assert (got["kind"] == q4.UPDATE_BEFORE).any()
 
 
+@pytest.mark.parametrize("native", [True, False], ids=["native", "numpy"])
+@pytest.mark.parametrize("seed", [5, 2_147_483_677])
+def test_the_hash_grouped_folds_emit_the_sort_grouped_changelog(
+        seed, native, monkeypatch):
+    """Both GROUP BYs (the MAX per auction, the AVG that retracts) with
+    each batch's rows grouped by key in one hash pass emit, row for row
+    and in the same order, what the sort by slot emitted."""
+    from flink_tpu.runtime.group_agg import GroupAggOperator
+    from tests.test_wide_aggregates import SortGroupedFold
+
+    if not native:
+        monkeypatch.setenv("FLINK_TPU_NO_NATIVE", "1")
+    n = 8 * q4.boundary_events(O)
+    cols, _ = q4.make_generator(seed, O)(0, n)
+    cuts = range(q4.boundary_events(O), n + 1, q4.boundary_events(O))
+    got = run_sql(cols, cuts)
+    monkeypatch.setattr(GroupAggOperator, "process_batch",
+                        SortGroupedFold.process_batch)
+    want = run_sql(cols, cuts)
+    assert (got["kind"] == q4.UPDATE_BEFORE).any()
+    assert list(got) == list(want)
+    for c in want:
+        assert got[c].dtype == want[c].dtype, c
+        np.testing.assert_array_equal(got[c], want[c], err_msg=c)
+
+
 def test_an_average_emitted_every_other_batch_reads_wrong(monkeypatch):
     """A second GROUP BY that folds every batch but emits only every
     other one — each emission merging two batches' changes, its

@@ -8,11 +8,13 @@ import pytest
 
 from flink_tpu.core.records import (
     KEY_ID_FIELD,
+    ROWKIND_DELETE,
     ROWKIND_FIELD,
     ROWKIND_INSERT,
     ROWKIND_UPDATE_AFTER,
     ROWKIND_UPDATE_BEFORE,
     RecordBatch,
+    rowkind_signs,
 )
 from flink_tpu.runtime.group_agg import GroupAggOperator
 from flink_tpu.runtime.operators import OperatorContext
@@ -223,3 +225,192 @@ def test_the_operator_restores_its_bound_table():
     fresh.restore_state(snap)
     outs = fresh.process_batch(_batch([1, 2], [(1 << 40) + 1, 3]))
     assert _final(outs, "r") == {1: (1 << 40) + 1}
+
+
+# ------------------- the fold's rows grouped by key, against the sort
+
+
+class SortGroupedFold(GroupAggOperator):
+    """The fold as it grouped a batch before the hash pass, kept as the
+    reference: every row looked up in the table and checked against its
+    slot's key columns, the partials by a stable sort by slot and
+    ``reduceat``, the row counts by ``np.add.at`` over the rows."""
+
+    def process_batch(self, batch, input_index=0):
+        if len(batch) == 0:
+            return []
+        if self.table is None:
+            self._bind({n: np.asarray(batch[n]).dtype
+                        for n in batch.names()})
+        if batch.has_timestamps:
+            self._max_ts = max(self._max_ts, int(batch.timestamps.max()))
+        keys = batch.columns.get(self.key_field)
+        if keys is not None and keys.dtype.kind not in "iu":
+            self._keys_hashed = True
+            uniq, first = np.unique(batch.key_ids, return_index=True)
+            for i, j in zip(uniq.tolist(), first.tolist()):
+                self._key_values.setdefault(i, keys[j])
+        slots = self.table.lookup_or_insert(
+            batch.key_ids, np.zeros(len(batch), dtype=np.int64))
+        self._ensure_host_capacity(int(slots.max()) + 1)
+        new = (self._row_counts[slots] == 0) & ~self._emitted_mask[slots]
+        for name in self.key_columns:
+            v = np.asarray(batch[name])
+            store = self._key_cols.setdefault(
+                name, np.zeros(len(self._row_counts), dtype=v.dtype))
+            store[slots[new]] = v[new]
+            if not np.array_equal(store[slots], v):
+                raise RuntimeError("two GROUP BY keys hash to one key id")
+        kinds = batch.row_kinds
+        signs = None if kinds is None else rowkind_signs(np.asarray(kinds))
+        if signs is not None and not (signs < 0).any():
+            signs = None
+        if signs is not None and not self.agg.retractable:
+            raise ValueError("requires retractable accumulators")
+        emit = not self.emit_on_watermark_only
+        if self.agg.wide:
+            order = np.argsort(slots, kind="stable")
+            ordered = slots[order]
+            starts = np.flatnonzero(np.r_[True, ordered[1:] != ordered[:-1]])
+            parts = []
+            for (_, r, dt), v in zip(self.agg.parts,
+                                     self.agg.part_values(batch)):
+                v = np.asarray(v, dtype=np.int64 if np.dtype(dt).kind
+                               in "iu" else np.float64)
+                if signs is not None:
+                    v = v * signs.astype(v.dtype)
+                fn = {"sum": np.add, "max": np.maximum, "min": np.minimum}[r]
+                parts.append(fn.reduceat(v[order], starts))
+            uniq, results = self._fold_partials(ordered[starts], parts)
+        else:
+            if signs is None:
+                self.table.scatter(slots, self.agg.map_input(batch))
+            else:
+                self.table.scatter_signed(
+                    slots, self.agg.map_input_signed(batch, signs))
+            uniq = np.unique(slots)
+            results = self._values(uniq) if emit else None
+        np.add.at(self._row_counts, slots,
+                  1 if signs is None else signs.astype(np.int64))
+        if not emit:
+            self._dirty.update(uniq.tolist())
+            return []
+        out = self._emit_slots(uniq, results)
+        return [out] if out is not None else []
+
+
+def _same_changelog(got, want):
+    """Row for row, in the same order, every column and its type; an
+    integer exactly, a float32 to a few of its ulps (the partials of a
+    float part are summed in row order now, pairwise by ``reduceat``
+    before)."""
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert sorted(g.names()) == sorted(w.names())
+        for name in w.names():
+            a, b = np.asarray(g[name]), np.asarray(w[name])
+            assert a.dtype == b.dtype, name
+            if a.dtype.kind == "f":
+                tol = 8 * np.finfo(a.dtype).eps
+                np.testing.assert_allclose(a, b, rtol=tol, atol=tol,
+                                           err_msg=name)
+            else:
+                np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+def _signed_stream(rng, n_batches, retract):
+    """Batches of a few hundred rows over ≈60 keys, with two key columns
+    each key id is made from, and UPDATE_BEFORE / DELETE rows where
+    ``retract``."""
+    out = []
+    for _ in range(n_batches):
+        n = int(rng.integers(1, 600))
+        a = rng.integers(-30, 30, n)
+        b = rng.integers(0, 2, n)
+        kid = a * 7 + b
+        cols = {"k": kid, KEY_ID_FIELD: kid, "a": a, "b": b,
+                "x": rng.integers(-(1 << 50), 1 << 50, n) | 0xFFFFFFFF,
+                "f": rng.standard_normal(n).astype(np.float32)}
+        if retract:
+            cols[ROWKIND_FIELD] = rng.choice(np.array(
+                [ROWKIND_INSERT, ROWKIND_UPDATE_AFTER, ROWKIND_UPDATE_BEFORE,
+                 ROWKIND_DELETE], dtype=np.int8), n, p=[.5, .2, .2, .1])
+        out.append(RecordBatch(cols))
+    return out
+
+
+@pytest.mark.parametrize("aggs, retract", [
+    (lambda: [Int64Aggregate("max", "x", "r")], False),
+    (lambda: [Int64Aggregate("min", "x", "r")], False),
+    (lambda: [Int64Aggregate("sum", "x", "r")], True),
+    (lambda: [Int64Aggregate("avg", "x", "r")], True),
+    (lambda: [Int64Aggregate("max", "x", "m"), Int64Aggregate("avg", "x", "r"),
+              CountAggregate("c")], False),
+    (lambda: [Int64Aggregate("sum", "x", "s"), Int64Aggregate("avg", "x", "r"),
+              CountAggregate("c")], True),
+    (lambda: [CountAggregate("c")], True),
+    (lambda: [CountAggregate("c"), SumAggregate("f", output="s")], True),
+    (lambda: [MaxAggregate("f", output="m")], False),
+    (lambda: [Int64Aggregate("max", "x", "m"), SumAggregate("f", output="s")],
+     False),
+], ids=["max", "min", "sum-signed", "avg-signed", "max-avg-count",
+        "sum-avg-count-signed", "count-signed", "count-float-sum-signed",
+        "float-max", "max-float-sum"])
+@pytest.mark.parametrize("seed", [1, 2_147_483_701])
+def test_the_hash_grouped_fold_emits_the_sort_grouped_changelog(
+        aggs, retract, seed):
+    rng = np.random.default_rng(seed)
+    got_op = _op(MultiAggregate(aggs()), key_columns=("a", "b"))
+    want_op = SortGroupedFold(MultiAggregate(aggs()), "k", capacity=1 << 10,
+                              key_columns=("a", "b"))
+    want_op.open(OperatorContext())
+    for batch in _signed_stream(rng, 12, retract):
+        _same_changelog(got_op.process_batch(batch),
+                        want_op.process_batch(batch))
+    np.testing.assert_array_equal(got_op._row_counts, want_op._row_counts)
+
+
+def _keyed(kid, a, x):
+    return RecordBatch({"k": np.asarray(kid), KEY_ID_FIELD: np.asarray(kid),
+                        "a": np.asarray(a), "b": np.zeros(len(kid), int),
+                        "x": np.asarray(x)})
+
+
+@pytest.mark.parametrize("agg", [
+    lambda: MultiAggregate([Int64Aggregate("max", "x", "r")]),
+    lambda: MultiAggregate([CountAggregate("c")])], ids=["wide", "count"])
+def test_a_collision_inside_one_batch_on_a_new_slot_is_refused(agg):
+    op = _op(agg(), key_columns=("a", "b"))
+    op.process_batch(_keyed([1, 2], [10, 20], [5, 6]))
+    # key id 7 is new; its first row says a=1, a later one a=2, with
+    # other keys between them
+    with pytest.raises(RuntimeError, match="hash to one key id"):
+        op.process_batch(_keyed([7, 1, 2, 7], [1, 10, 20, 2], [1, 2, 3, 4]))
+
+
+@pytest.mark.parametrize("agg", [
+    lambda: MultiAggregate([Int64Aggregate("max", "x", "r")]),
+    lambda: MultiAggregate([CountAggregate("c")])], ids=["wide", "count"])
+def test_a_collision_across_batches_is_refused(agg):
+    op = _op(agg(), key_columns=("a", "b"))
+    op.process_batch(_keyed([7, 8], [1, 3], [5, 6]))
+    # every row of the batch agrees with its group's first row; the
+    # key's kept columns do not
+    with pytest.raises(RuntimeError, match="hash to one key id"):
+        op.process_batch(_keyed([8, 7, 7], [3, 2, 2], [1, 2, 3]))
+
+
+def test_each_fold_records_the_distinct_keys_it_took_to_its_table():
+    from flink_tpu.observe import flight_recorder as flight
+
+    rec = flight.recorder()
+    rec.clear()
+    op = _op(MultiAggregate([Int64Aggregate("max", "x", "r")]))
+    op.process_batch(_batch([4, 4, 9, 4, -1], [1, 2, 3, 4, 5]))
+    op.process_batch(_batch([9, 9], [7, 8]))
+    totals = rec.kind_totals()
+    assert totals["agg.groups"]["count"] == totals["agg.fold"]["count"] == 2
+    assert totals["agg.groups"]["work"] == 3 + 1
+    assert totals["agg.fold"]["work"] == 5 + 2
+    assert {r.parent for r in rec.snapshot()
+            if r.kind == "agg.groups"} == {"agg.fold"}
